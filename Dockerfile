@@ -9,8 +9,8 @@
 #   docker run torchft-tpu torchft-tpu-launcher --num-replica-groups 2 \
 #       -- python examples/train_ddp.py
 #
-# For real TPU hosts, base on a TPU-enabled JAX image instead and drop
-# JAX_PLATFORMS (libtpu discovers the chips).
+# For real TPU hosts, base on a TPU-enabled JAX image instead and set
+# JAX_PLATFORMS=tpu (a missing chip is then an error, not a CPU fallback).
 FROM python:3.12-slim
 
 RUN apt-get update && apt-get install -y --no-install-recommends \
@@ -26,6 +26,11 @@ RUN pip install --no-cache-dir "jax[cpu]" optax ml_dtypes \
 ENV JAX_PLATFORMS=cpu NUM_STEPS=30
 # One-process demo: in-process lighthouse, single replica group. Multi-group
 # deployments run one container per replica group pointed at a shared
-# lighthouse via TORCHFT_LIGHTHOUSE (docs/OPERATIONS.md).
+# lighthouse via TORCHFT_LIGHTHOUSE (docs/OPERATIONS.md). On a TPU host a
+# chip belongs to one process: either one container per group with its
+# own chips, or one launcher per host with --chips-per-group N, which
+# pins group g (and every restart of it) to chips [g*N, (g+1)*N) before
+# the group's first backend initialisation. The launcher itself never
+# touches the JAX backend. `python chip_smoke.py` proves the layout.
 CMD ["torchft-tpu-launcher", "--num-replica-groups", "1", \
      "python", "examples/train_ddp.py"]

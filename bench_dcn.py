@@ -115,9 +115,6 @@ def _worker_host(rank: int, store_addr: str, mode: str) -> None:
 
 
 def _worker_xla(rank: int, store_addr: str, mode: str) -> None:
-    from torchft_tpu.platform import apply_jax_platform_env
-
-    apply_jax_platform_env()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -216,9 +213,6 @@ def _worker_xla(rank: int, store_addr: str, mode: str) -> None:
 
 
 def _worker_iso(rank: int, store_addr: str, mode: str) -> None:
-    from torchft_tpu.platform import apply_jax_platform_env
-
-    apply_jax_platform_env()
     import jax
     import jax.numpy as jnp
 

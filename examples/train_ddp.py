@@ -27,11 +27,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from torchft_tpu.platform import (  # noqa: E402
     apply_compilation_cache_env,
-    apply_jax_platform_env,
     standby_gate,
 )
 
-apply_jax_platform_env()
 apply_compilation_cache_env()  # restarted groups skip the re-jit (heal path)
 
 import jax  # noqa: E402

@@ -18,8 +18,8 @@
 // splits its payload into `stripes` contiguous sub-ranges; stripe s runs the
 // full ring schedule over its own sub-range on its own connection pair, on
 // its own thread. A single TCP connection is window-limited on
-// high-bandwidth-delay paths (the DCN/tunneled links these collectives
-// actually cross), so striping multiplies achievable throughput the way
+// high-bandwidth-delay paths (the DCN links these collectives actually
+// cross), so striping multiplies achievable throughput the way
 // NCCL channels or multi-stream object fetches do.
 //
 // HIERARCHICAL TOPOLOGY (configure with a region and/or host map): on a
@@ -154,7 +154,7 @@ enum class HierWire : int {
 // TORCHFT_HC_WIRE_CAP_INTRA_MBPS). Two uses: QoS — cap the gradient ring's
 // per-connection rate so it cannot starve heal/checkpoint traffic on a
 // shared NIC — and transport validation, emulating a per-connection-limited
-// path (TCP window / BDP cap, tunnel throttling, a wide-area inter-region
+// path (TCP window / BDP cap, a throttled or wide-area inter-region
 // hop) on loopback so the stripe and hierarchy sweeps can measure where the
 // real win lives. Pure pacing: no wire-format or schedule effect, so
 // members need NOT agree on it.

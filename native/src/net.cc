@@ -265,7 +265,7 @@ void set_nonblocking(int fd) {
 }
 
 // Large kernel buffers: the bulk ring crosses high-bandwidth-delay paths
-// (DCN, tunneled links) where a default-window TCP connection caps
+// (DCN, wide-area links) where a default-window TCP connection caps
 // throughput at window/RTT, and on any path a deeper buffer halves the
 // poll/send wakeup count per MB. Must run BEFORE the handshake (before
 // ::connect on the client, on the listening fd for accepted sockets) —

@@ -1,0 +1,103 @@
+"""Placement, cache and smoke-script contracts that need no chip.
+
+``chip_smoke.py`` itself only passes on a TPU; what the CPU can check is
+that it fails loudly without one, that its worker loop is sound (run here
+at ``tiny_config()``), and that the two pure functions it stands on —
+which chips a group gets, where the compile cache lives — behave.
+"""
+
+import dataclasses
+import os
+import subprocess
+import sys
+
+import pytest
+
+import chip_smoke
+from torchft_tpu import Lighthouse
+from torchft_tpu.launcher import chip_env, launch, replica_group_spec
+from torchft_tpu.models import tiny_config
+from torchft_tpu.platform import COMPILE_CACHE_DIR, compilation_cache_dir
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class TestPlacement:
+    def _spec(self, group, chips):
+        return replica_group_spec(
+            ["python", "x.py"], group, 4, "http://lh:1", chips=chips
+        )
+
+    def test_groups_get_disjoint_chips(self):
+        envs = [self._spec(g, [g])["env"] for g in range(4)]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+        for e in envs:
+            # each group is its own one-process topology: that is what
+            # lets libtpu load once per group, and why a peer's death can
+            # never wedge this group's device runtime
+            assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+            assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+
+    def test_restart_reuses_the_groups_chips(self):
+        # launch() builds each spec once and respawns from it verbatim, so
+        # "same chips after a restart" is "the spec is a pure function"
+        assert self._spec(2, [2]) == self._spec(2, [2])
+
+    def test_no_chips_means_no_device_env(self):
+        env = self._spec(0, ())["env"]
+        assert not any(k.startswith("TPU_") for k in env)
+
+    def test_multi_chip_groups_are_refused_for_now(self):
+        with pytest.raises(ValueError, match="one chip per replica group"):
+            chip_env([0, 1])
+
+
+    def test_hot_spares_cannot_share_their_primarys_chips(self):
+        with pytest.raises(ValueError, match="one process"):
+            launch(["true"], 1, "http://lh:1", hot_spare=True, chips_per_group=1)
+
+
+class TestCompileCache:
+    def test_env_var_wins_and_nothing_is_set_in_code(self):
+        assert compilation_cache_dir(
+            {"JAX_COMPILATION_CACHE_DIR": "/some/dir"}
+        ) is None
+
+    def test_unset_resolves_to_the_fixed_in_checkout_path(self):
+        assert compilation_cache_dir({}) == COMPILE_CACHE_DIR
+        assert COMPILE_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+        ignored = open(os.path.join(REPO, ".gitignore")).read().split()
+        assert ".jax_cache/" in ignored
+
+
+class TestChipSmoke:
+    def test_without_a_chip_it_fails_and_says_so(self):
+        # the tier-1 environment pins the CPU; the script overrides that
+        # for its children (JAX_PLATFORMS=tpu) and must find no chip
+        out = subprocess.run(
+            [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+            capture_output=True, text=True, timeout=300,
+        )
+        assert out.returncode != 0
+        assert "no TPU chip found" in out.stderr
+        assert '"ok"' not in out.stdout
+
+    def test_worker_loop_at_tiny_config(self, tmp_path):
+        lighthouse = Lighthouse(bind="[::]:0", min_replicas=1)
+        try:
+            records = chip_smoke.run_group(
+                dataclasses.replace(tiny_config(), use_flash=True),
+                (4, 65), str(tmp_path), group=0, num_groups=1,
+                lighthouse_addr=lighthouse.address(),
+            )
+        finally:
+            lighthouse.shutdown()
+        sync, plan = records
+        assert sync["event"] == "sync" and sync["step"] == chip_smoke.SYNC_STEPS
+        assert sync["loss_after"] < sync["loss_first"]
+        assert plan["event"] == "plan_q8"
+        assert plan["step"] == chip_smoke.SYNC_STEPS + chip_smoke.PLAN_STEPS
+        # host pack on the CPU backend: full-width bytes cross "d2h"
+        assert plan["device_pack"] is False
+        assert plan["d2h_bytes"] == plan["payload_bytes"]

@@ -22,6 +22,7 @@ from torchft_tpu.collectives import (
     HostCollectives,
     ReduceOp,
 )
+from torchft_tpu.quantize import np_quantize_ef as _np_quantize_ef
 
 
 @pytest.fixture
@@ -67,24 +68,6 @@ def _run_all(cols, fn):
     if errors:
         raise errors[0][1]
     return results
-
-
-def _np_quantize_ef(leaf, res):
-    """Pure-numpy mirror of quantize.quantize_with_feedback (and of the
-    native plan EF): the FMA-free reference both implementations are
-    tested against. (The jitted jax version may differ from either at the
-    last ulp of the residual — XLA contracts ``d - q*scale`` into an fma —
-    which is exactly why the plan's native EF is the wire contract.)"""
-    d = (leaf.astype(np.float32) + res).astype(np.float32)
-    absmax = np.max(np.abs(d)) if d.size else np.float32(0)
-    if not np.isfinite(absmax):
-        nan = np.float32(np.nan)
-        return np.full_like(d, nan), np.full_like(d, nan)
-    scale = np.maximum(np.float32(absmax) / np.float32(127.0),
-                       np.float32(1e-12))
-    q = np.clip(np.round(d / scale), -127, 127).astype(np.float32)
-    dq = (q * scale).astype(np.float32)
-    return dq, (d - dq).astype(np.float32)
 
 
 def _trees(world_size, rng_seed=7):

@@ -8,13 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import HAS_SHARD_MAP, SHARD_MAP_SKIP
-
-if not HAS_SHARD_MAP:
-    # context_parallel imports jax.shard_map at module load, so the guard
-    # must run before the import or collection itself errors.
-    pytest.skip(SHARD_MAP_SKIP, allow_module_level=True)
-
 from torchft_tpu.context_parallel import ring_attention
 from torchft_tpu.parallel import make_mesh
 

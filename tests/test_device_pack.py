@@ -22,7 +22,7 @@ from datetime import timedelta
 import numpy as np
 import pytest
 
-from test_comm_plan import _np_quantize_ef
+from torchft_tpu.quantize import np_quantize_ef as _np_quantize_ef
 from test_quantize_kernels import _pallas_probe
 
 _SKIP = _pallas_probe()

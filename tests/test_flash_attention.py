@@ -11,13 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import HAS_SHARD_MAP, SHARD_MAP_SKIP
-
-if not HAS_SHARD_MAP:
-    # the flash kernel's sharded entry imports jax.shard_map at module
-    # load, so the guard must run before the import or collection errors
-    pytest.skip(SHARD_MAP_SKIP, allow_module_level=True)
-
 from torchft_tpu.ops import flash_attention
 
 

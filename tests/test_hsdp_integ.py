@@ -19,14 +19,6 @@ variants: sharded_integ.py.
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-from conftest import HAS_SHARD_MAP, SHARD_MAP_SKIP
-
-if not HAS_SHARD_MAP:
-    # the flagship sharded train step routes attention through the
-    # shard_map'd flash kernel
-    pytest.skip(SHARD_MAP_SKIP, allow_module_level=True)
 
 from torchft_tpu.models import (
     init_params,

@@ -36,8 +36,6 @@ from datetime import timedelta
 import numpy as np
 import pytest
 
-from conftest import CPU_MULTIPROCESS_SKIP, HAS_CPU_MULTIPROCESS
-
 from torchft_tpu import _native
 from torchft_tpu.collectives import (
     HostCollectives,
@@ -1050,7 +1048,6 @@ _WORKER = textwrap.dedent(
 ).format(repo=REPO)
 
 
-@pytest.mark.skipif(not HAS_CPU_MULTIPROCESS, reason=CPU_MULTIPROCESS_SKIP)
 class TestIsolatedPsumPath:
     def test_psum_bit_identity_vs_inprocess_xla(self):
         store = _native.Store()

@@ -22,14 +22,6 @@ from torchft_tpu.parallel import (
     shard_pytree,
 )
 
-from conftest import HAS_SHARD_MAP, SHARD_MAP_SKIP
-
-# Tests that route through the shard_map'd flash/ring-attention kernels;
-# the rest of this module runs fine on old jax.
-requires_shard_map = pytest.mark.skipif(
-    not HAS_SHARD_MAP, reason=SHARD_MAP_SKIP
-)
-
 
 @pytest.fixture(scope="module")
 def cfg():
@@ -123,7 +115,6 @@ class TestShardedTraining:
         loss, _ = grad_step(sharded, tokens)
         assert abs(float(loss) - expected) < 5e-2  # bf16 matmul tolerance
 
-    @requires_shard_map
     def test_context_parallel_train_step_dp_sp_tp(self, cfg):
         # Full 3D intra-group sharding: batch over "data", sequence ring
         # over "seq" (ring attention), heads over "model" — one jitted
@@ -178,14 +169,12 @@ class TestGraftEntry:
         logits = jax.jit(fn)(*args)
         assert logits.shape[0] == args[1].shape[0]
 
-    @requires_shard_map
     def test_dryrun_multichip(self):
         import __graft_entry__
 
         __graft_entry__.dryrun_multichip(8)
 
 
-@requires_shard_map
 def test_remat_policy_prunes_flash_fwd_recompute():
     """The point of save_attn + flash: the backward replay must NOT
     relaunch the forward flash kernel. Counted in the lowered HLO: one
@@ -235,7 +224,6 @@ def test_bad_config_knobs_rejected():
         dataclasses.replace(tiny_config(), remat_policy="save-attn")
 
 
-@requires_shard_map
 def test_remat_policy_save_attn_matches_plain():
     """save_attn remat keeps numerics identical (it only changes what
     backward recomputes) for both dense and flash attention paths."""

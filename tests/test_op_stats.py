@@ -23,8 +23,6 @@ from datetime import timedelta
 import numpy as np
 import pytest
 
-from conftest import CPU_MULTIPROCESS_SKIP, HAS_CPU_MULTIPROCESS
-
 from torchft_tpu._native import Store
 from torchft_tpu.collectives import HostCollectives, ReduceOp
 
@@ -301,7 +299,6 @@ _XLA_STATS_WORKER = textwrap.dedent(
 ).format(repo=REPO)
 
 
-@pytest.mark.skipif(not HAS_CPU_MULTIPROCESS, reason=CPU_MULTIPROCESS_SKIP)
 class TestXLABackendStats:
     def test_xla_entries_carry_the_parity_keys(self, store):
         procs = [

@@ -12,13 +12,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import HAS_SHARD_MAP, SHARD_MAP_SKIP
-
-if not HAS_SHARD_MAP:
-    # pipeline_blocks resolves jax.shard_map at trace time: every test
-    # here drives it, so skip the module wholesale
-    pytest.skip(SHARD_MAP_SKIP, allow_module_level=True)
-
 from torchft_tpu.parallel import make_mesh, shard_pytree
 from torchft_tpu.pipeline import pipeline_blocks, stack_blocks, stage_specs
 

@@ -12,13 +12,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-from conftest import HAS_SHARD_MAP, SHARD_MAP_SKIP
-
-if not HAS_SHARD_MAP:
-    # the pipelined group step shard_maps over the pipe axis
-    pytest.skip(SHARD_MAP_SKIP, allow_module_level=True)
 
 from torchft_tpu.models.transformer import (
     _block,

@@ -15,7 +15,7 @@ kernel and skips with the precise failure when Pallas cannot execute here
 import numpy as np
 import pytest
 
-from test_comm_plan import _np_quantize_ef
+from torchft_tpu.quantize import np_quantize_ef as _np_quantize_ef
 
 
 def _pallas_probe():
@@ -40,7 +40,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from torchft_tpu.ops.quantize_kernels import (  # noqa: E402
     _SCALE_FLOOR,
-    _absmax,
+    _quantize_tiles,
     cast_bf16,
     dequantize_q8,
     quantize_q8,
@@ -173,19 +173,17 @@ class TestCastBf16:
         assert out.shape == (13, 9) and out.dtype == jnp.bfloat16
 
 
-class TestGridAccumulation:
-    def test_multi_block_absmax_matches_single(self):
-        # The TPU path splits big payloads into _BLOCK_ROWS grids whose
-        # revisited (1,1) accumulator the interpret single-block path
-        # never exercises — drive the multi-block grid explicitly.
+class TestMultiBlockGrid:
+    def test_multi_block_quantize_matches_single_block(self):
+        # Compiled, big payloads run a _BLOCK_ROWS grid; interpret mode
+        # always picks one block (_grid_shape), so drive the multi-block
+        # grid explicitly: per-block codes and carry must equal the
+        # single-block run, with the absmax living in the LAST block.
         rng = np.random.default_rng(9)
-        tiles = jnp.asarray(rng.standard_normal((64, 128)).astype(np.float32))
-        multi = np.asarray(_absmax(tiles, 16, True))[0, 0]
-        single = np.asarray(_absmax(tiles, 64, True))[0, 0]
-        want = np.max(np.abs(np.asarray(tiles)))
-        assert multi == want == single
-
-    def test_multi_block_absmax_max_in_late_block(self):
-        x = np.zeros((64, 128), np.float32)
-        x[60, 5] = -7.5  # lives in the LAST block: accumulate must see it
-        assert np.asarray(_absmax(jnp.asarray(x), 16, True))[0, 0] == 7.5
+        x = rng.standard_normal((128, 128)).astype(np.float32)
+        x[120, 5] = -7.5
+        res = rng.standard_normal((128, 128)).astype(np.float32) * 0.01
+        single = _quantize_tiles(jnp.asarray(x), jnp.asarray(res), 128, True)
+        multi = _quantize_tiles(jnp.asarray(x), jnp.asarray(res), 32, True)
+        for a, b in zip(single, multi):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()

@@ -15,15 +15,6 @@ import textwrap
 from datetime import timedelta
 
 import numpy as np
-import pytest
-
-from conftest import CPU_MULTIPROCESS_SKIP, HAS_CPU_MULTIPROCESS
-
-if not HAS_CPU_MULTIPROCESS:
-    # every test here runs cross-process CPU computations in worker
-    # subprocesses; without a CPU collectives backend they all raise
-    # "Multiprocess computations aren't implemented on the CPU backend"
-    pytest.skip(CPU_MULTIPROCESS_SKIP, allow_module_level=True)
 
 from torchft_tpu import Store
 

@@ -30,17 +30,6 @@ from .train_state import FTTrainState, _to_device_tree
 logger: logging.Logger = logging.getLogger(__name__)
 
 
-def _device_pack_available() -> bool:
-    """Whether the Pallas wire-compression kernels import here (the
-    capability gate for AdaptiveDDP's device-pack probe candidate)."""
-    try:
-        from .ops import quantize_q8_ef  # noqa: F401
-
-        return True
-    except Exception:  # noqa: BLE001 - any import failure = unavailable
-        return False
-
-
 class DistributedDataParallel:
     """Averages gradient pytrees across replica groups, fault-tolerantly.
 
@@ -112,9 +101,8 @@ class PipelinedDDP:
     - ``compress="int8"``: the int8 payload itself ({q, scale} leaves)
       rides a managed device-packed ALLGATHER and is dequantize-averaged
       on settle. The DEVICE<->HOST link carries int8 bytes — the mode for
-      hosts where that link (PCIe / a tunneled runtime) is the
-      bottleneck. Allgather traffic grows with cohort size; intended for
-      small cohorts.
+      hosts where that link (PCIe) is the bottleneck. Allgather traffic
+      grows with cohort size; intended for small cohorts.
     - ``compress="q8"``: the dequantized (f32, int8-gridded) gradients
       ride the native ring's quantized wire (int8 chunks + per-chunk
       scales, dequant-accumulated per hop): TCP bytes are ~4x below f32
@@ -930,7 +918,6 @@ class AdaptiveDDP:
         if (
             self._devpack_setting is None  # TORCHFT_DEVICE_PACK=auto
             and "plan" in self._candidates
-            and _device_pack_available()
         ):
             # Probe device pack against host pack with the same lockstep
             # vote that picks the schedule; "plan" itself pins host pack

@@ -1298,9 +1298,6 @@ def _child_configure(state: _ChildState, req: dict) -> dict:
         return {"ok": True, "path": "store",
                 "init_s": time.perf_counter() - t0}
 
-    from .platform import apply_jax_platform_env
-
-    apply_jax_platform_env()
     import jax
     import jax.numpy as jnp
 
@@ -1593,9 +1590,6 @@ def _zygote_main() -> None:
     is a pipe write (~ms) and the replacement spare forks right after,
     off the requester's critical path — the hot-spare discipline applied
     one level down, at the child-process granularity."""
-    from .platform import apply_jax_platform_env
-
-    apply_jax_platform_env()
     import jax  # noqa: F401
     import jax.numpy  # noqa: F401
 

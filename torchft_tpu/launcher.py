@@ -9,10 +9,17 @@ for external schedulers (GKE/xpk-style), and ``launch``/the CLI supervise
 locally with restart-on-failure — the restart half of the recovery story
 (the healing half is the Manager's).
 
+A chip belongs to one process at a time, so on a TPU host the launcher
+also decides placement: ``--chips-per-group N`` pins each group, before
+its first backend initialisation, to its own chips (``chip_env``), and
+this supervisor itself never initialises a JAX backend.
+
 CLI::
 
     python -m torchft_tpu.launcher --num-replica-groups 2 -- \
         python examples/train_ddp.py
+    python -m torchft_tpu.launcher --num-replica-groups 4 \
+        --chips-per-group 1 -- python train.py      # a four-chip TPU host
 """
 
 from __future__ import annotations
@@ -29,6 +36,33 @@ from typing import Dict, Optional, Sequence
 logger = logging.getLogger(__name__)
 
 
+def chip_env(chips: Sequence[int]) -> Dict[str, str]:
+    """The environment that makes libtpu see ONLY ``chips`` (host-local
+    chip indices) — must be in place before the process's first backend
+    initialisation. Each replica group is an independent one-process TPU
+    topology: groups never share a device runtime, so a dead group is a
+    closed socket to its peers, never a wedged device collective.
+
+    Established on a four-chip v5e host (libtpu 0.0.34):
+    ``TPU_VISIBLE_CHIPS`` alone is not enough — concurrent processes then
+    fail on libtpu's multi-process lockfile; declaring the process a
+    1x1x1 SUBSET of the host's chips is what lets several libtpu
+    instances load side by side. No per-process port or task id is
+    needed, and a SIGKILLed holder frees its chip at once.
+
+    One chip per group for now (the 2 groups x 2 chips layout needs the
+    chip-grid bounds of the host; ROADMAP S2)."""
+    if len(chips) != 1:
+        raise ValueError(
+            f"one chip per replica group is supported, got chips={list(chips)}"
+        )
+    return {
+        "TPU_VISIBLE_CHIPS": str(int(chips[0])),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 def replica_group_spec(
     cmd: Sequence[str],
     replica_group: int,
@@ -36,24 +70,18 @@ def replica_group_spec(
     lighthouse_addr: str,
     env: Optional[Dict[str, str]] = None,
     max_restarts: int = 10,
+    chips: Sequence[int] = (),
 ) -> Dict[str, object]:
     """Process spec for one replica group (the reference's torchx role,
-    torchx.py:37-69): command, env, and restart budget."""
+    torchx.py:37-69): command, env, and restart budget. ``chips`` pins
+    the group to those host-local TPU chips (:func:`chip_env`); the spec
+    is reused verbatim for every restart, so a restarted group comes back
+    on the chips its predecessor held. Empty = no pinning (CPU runs, or
+    one group owning the whole host)."""
     spec_env = {
         "TORCHFT_LIGHTHOUSE": lighthouse_addr,
         "REPLICA_GROUP_ID": str(replica_group),
         "NUM_REPLICA_GROUPS": str(num_replica_groups),
-        # Shared persistent jit cache: a RESTARTED group reloads the
-        # executables compiled before it died instead of re-jitting, the
-        # main lever on heal latency (platform.apply_compilation_cache_env;
-        # entry scripts opt in by calling it). Overridable; "0" disables.
-        "TORCHFT_COMPILE_CACHE": os.environ.get(
-            "TORCHFT_COMPILE_CACHE",
-            os.path.join(
-                os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-                "torchft_tpu", "jax_cache",
-            ),
-        ),
         # Isolated-data-plane knobs ride the spec explicitly so external
         # schedulers (which don't inherit this supervisor's environment)
         # deploy every group with the same child-respawn discipline: the
@@ -64,6 +92,7 @@ def replica_group_spec(
             for knob in ("TORCHFT_ISO_ZYGOTE", "TORCHFT_ISO_LIVENESS_MS")
             if knob in os.environ
         },
+        **(chip_env(chips) if chips else {}),
         **(env or {}),
     }
     return {
@@ -82,7 +111,7 @@ def _can_lift_priority(
     CAP_SYS_NICE or an RLIMIT_NICE allowance; setting nice is always
     allowed, which is exactly the trap: a supervisor that warms standbys
     at nice 19 but cannot lift a promoted one leaves it training at
-    idle priority forever (VERDICT item 4). Probed once at spawn time so
+    idle priority forever. Probed once at spawn time so
     the decision is made BEFORE any standby is niced.
 
     The kernel's can_nice() check is CAPABILITY-based, so CapEff is the
@@ -152,6 +181,7 @@ def launch(
     hot_spare: bool = False,
     regions: int = 0,
     root_addrs: str = "",
+    chips_per_group: int = 0,
 ) -> int:
     """Runs one process per replica group locally, restarting any that exit
     non-zero up to ``max_restarts`` times (torchelastic's role in the
@@ -181,10 +211,20 @@ def launch(
     ROOT FAILOVER SET — the active root plus its warm standbys (durable
     control plane). The whole list rides ``TORCHFT_LIGHTHOUSE_ROOT`` into
     every group and into the region tier's upstream, so a root kill fails
-    the fleet over to a standby without any relaunch."""
+    the fleet over to a standby without any relaunch.
+
+    ``chips_per_group > 0`` pins group ``g`` to host-local TPU chips
+    ``[g * n, (g + 1) * n)`` (:func:`chip_env`), restarts included. This
+    supervisor must itself stay off the JAX backend — a parent that has
+    initialised one holds the chips its children need."""
     import tempfile
     import uuid as _uuid
 
+    if hot_spare and chips_per_group:
+        raise ValueError(
+            "hot_spare with chips_per_group: a standby would warm up on the "
+            "chips its primary owns, and a chip belongs to one process"
+        )
     standby_dir = tempfile.mkdtemp(prefix="torchft_standby_") if hot_spare else None
     root_addrs = root_addrs or os.environ.get(
         "TORCHFT_LIGHTHOUSE_ROOT", ""
@@ -237,6 +277,9 @@ def launch(
                 replica_group_spec(
                     cmd, g, num_replica_groups, group_lighthouse, group_env,
                     max_restarts,
+                    chips=range(
+                        g * chips_per_group, (g + 1) * chips_per_group
+                    ),
                 )
             )
         )
@@ -473,6 +516,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         "The command must call torchft_tpu.platform.standby_gate() after "
         "warm-up, before creating its Manager.",
     )
+    parser.add_argument(
+        "--chips-per-group",
+        type=int,
+        default=0,
+        help="pin group g to host-local TPU chips [g*N, (g+1)*N); 0 (the "
+        "default) pins nothing — CPU runs, or one group owning the host",
+    )
     parser.add_argument("cmd", nargs="+", help="command to run per group")
     args = parser.parse_args(argv)
 
@@ -493,6 +543,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             max_restarts=args.max_restarts,
             hot_spare=args.hot_spare,
             regions=args.regions,
+            chips_per_group=args.chips_per_group,
         )
     finally:
         if lighthouse is not None:

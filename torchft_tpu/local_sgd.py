@@ -670,11 +670,10 @@ class AsyncDiLoCo(DiLoCo):
         ``overlap=False`` completes the sync AT the boundary instead of one
         window later (the reconciliation degenerates to θ = G', i.e. exact
         synchronous DiLoCo, but through the same jitted ops). Use it on
-        hosts where device↔host transfers contend with compute dispatch
-        (e.g. a tunneled/proxied device runtime): there, an in-flight
-        transfer under a stream of async dispatches can starve for far
-        longer than its serial wall time, and a blocking boundary sync is
-        strictly faster."""
+        hosts where device↔host transfers contend with compute dispatch:
+        there, an in-flight transfer under a stream of async dispatches
+        can starve for far longer than its serial wall time, and a
+        blocking boundary sync is strictly faster."""
         if compress not in (None, "bf16", "int8", "q8"):
             raise ValueError(f"unsupported compress mode: {compress}")
         super().__init__(manager, state, outer_tx, sync_every)

@@ -3,6 +3,7 @@ from torchft_tpu.models.cnn import CNNConfig, tiny_cnn_config
 from torchft_tpu.models.moe import MoEConfig, tiny_moe_config
 from torchft_tpu.models.transformer import (
     TransformerConfig,
+    big_config,
     forward,
     init_params,
     loss_fn,
@@ -15,6 +16,7 @@ __all__ = [
     "CNNConfig",
     "MoEConfig",
     "TransformerConfig",
+    "big_config",
     "cnn",
     "tiny_cnn_config",
     "forward",

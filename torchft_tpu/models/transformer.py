@@ -59,8 +59,8 @@ class TransformerConfig:
     # attention runs through the kernel. Ignored by cp_strategy="ring"
     # (that path fuses its own online-softmax loop).
     use_flash: bool = False
-    # Flash-kernel VMEM tile overrides (None = the kernel's v5e-measured
-    # auto sizes, ops/flash_attention.py); in-model winners can differ
+    # Flash-kernel VMEM tile overrides (None = the kernel's auto sizes,
+    # ops/flash_attention.py); in-model winners can differ
     # from standalone sweeps (fusion/VMEM interactions), so the bench
     # tunes these against whole-step throughput.
     flash_block_q: Any = None
@@ -115,6 +115,18 @@ def tiny_config() -> TransformerConfig:
     return TransformerConfig(
         vocab_size=256, d_model=64, n_heads=4, n_layers=2, d_ff=128,
         max_seq_len=128,
+    )
+
+
+def big_config() -> TransformerConfig:
+    """The 111M-parameter dense LM, the one configuration with a history
+    on the chip: MXU-shaped (d_model 1024, 16 heads x 64, d_ff 4096), run
+    at batch 16 x sequence 2048 with ``use_flash=True``. Shared by
+    ``bench.py`` and ``chip_smoke.py``. A stand-in, not a public
+    architecture (ROADMAP S1)."""
+    return TransformerConfig(
+        vocab_size=8192, d_model=1024, n_heads=16, n_layers=8, d_ff=4096,
+        max_seq_len=2048,
     )
 
 
@@ -358,10 +370,8 @@ def make_train_step(
     """ONE-program train step: loss, grad, and optimizer apply fused into
     a single jitted executable with buffer donation.
 
-    Measured on v5e (111M-param big config, B8 S2048): 216 ms/step fused
-    vs 235 ms as separate grad and apply programs; a device-side
-    ``lax.scan`` over steps gains nothing further, so the win is the
-    program-boundary cost, not host dispatch. Use with
+    Fusing saves the program-boundary cost of separate grad and apply
+    programs (how much is not measured on the current chip). Use with
     ``LocalSGD.step_applied``-style window accounting — per-step
     cross-group work (the DDP ring) inherently needs the split programs.
 

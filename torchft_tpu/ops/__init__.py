@@ -8,26 +8,13 @@ kernels (quantize/dequantize/cast) move gradient-sync packing onto the
 accelerator so d2h bytes scale with the wire size, not the f32 size.
 """
 
+from .flash_attention import flash_attention
 from .quantize_kernels import (
     cast_bf16,
     dequantize_q8,
     quantize_q8,
     quantize_q8_ef,
 )
-
-try:
-    from .flash_attention import flash_attention
-except ImportError as _e:  # old jax without jax.shard_map: the flash
-    # kernel's sharded entry is unimportable there, but the wire-
-    # compression kernels above have no mesh dependency and must keep
-    # serving the device-pack path. Callers get the original error.
-    _flash_import_error = _e
-
-    def flash_attention(*args, **kwargs):  # type: ignore[misc]
-        raise ImportError(
-            "torchft_tpu.ops.flash_attention is unavailable: "
-            f"{_flash_import_error}"
-        )
 
 __all__ = [
     "flash_attention",

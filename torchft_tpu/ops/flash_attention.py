@@ -25,9 +25,7 @@ S×S scale multiplies; autodiff of the fold rescales dq automatically.
 The causal path splits every tile loop into UNMASKED interior tiles plus
 one masked diagonal tile (requires block_q == block_k, the auto default):
 strictly-below-diagonal tiles are fully live, so the interior body skips
-the iota/compare/select mask passes entirely — measured 57% of the
-flagship step was attention, and the mask/guard VPU passes were a third
-of the kernel (experiments/mfu_breakdown.py). The fast path also uses a
+the iota/compare/select mask passes entirely. The fast path also uses a
 finite -1e30 mask value instead of -inf, which removes every
 ``isfinite`` guard from the online-softmax recurrence: with at least one
 live key per query row (guaranteed on the causal path — every row
@@ -491,14 +489,11 @@ def flash_attention(
             requirement — a requested 64 runs as 128 on hardware;
             interpret mode honors small blocks exactly). Default auto:
             (512, 512) when the sublane-padded sequence length reaches
-            2048, else (128, 128). Measured IN-MODEL on v5e (8-layer
-            111M-param LM at padded S 2048, fused train step, head_dim
-            64; FLASH_ABLATION.json): at B8 the (512, 512) kernel runs
-            the step at 64.6 param-TFLOP/s vs 47.5 dense and 38.3 for
-            (128, 128); at B4 58.0 vs 40.8 dense; at B16 70.0 (dense
-            fails to compile). Standalone kernel sweeps rank tiles
-            differently (fusion/VMEM interactions dominate) — trust
-            whole-step timings.
+            2048, else (128, 128). The choice dates from an earlier
+            runtime and is not measured on the current chip (ROADMAP
+            S4); standalone kernel sweeps rank tiles differently from
+            whole-step timings (fusion/VMEM interactions) — trust the
+            latter when re-tuning.
         interpret: force pallas interpret mode; default: on iff the backend
             is not TPU (CPU tests / virtual-device dryruns).
         mesh/batch_axis/head_axis: when ``mesh`` is given the kernel runs
@@ -534,18 +529,15 @@ def flash_attention(
         )(q, k, v)
 
     interp = _pick_interpret(interpret)
-    # Auto tile sizes (v5e-measured, see docstring); arbitrary S is
+    # Auto tile sizes (see docstring); arbitrary S is
     # handled by zero-padding the sequence up to the block multiple —
     # padded keys are masked in-kernel, padded queries carry zero
     # cotangents, so numerics are exact.
     # Tile choice keys on the PADDED sublane length, not raw S:
     # language-model training slices the last token off (tokens[:, :-1]),
     # so the flagship in-model sequence is 2047 — a raw-S `>= 2048` test
-    # once dropped it onto the 128-tile path and cost 1.7x whole-step
-    # throughput, while sequences just over a power of two would pay ~50%
-    # padding on the large-tile path. s8 >= 2048 admits exactly the
-    # 2048-class shapes the measurements cover (FLASH_ABLATION.json at
-    # padded S 2048; standalone 512-tile win at S 8192).
+    # would drop it onto the 128-tile path, while sequences just over a
+    # power of two would pay ~50% padding on the large-tile path.
     # On hardware the lse row is sliced along the LANE dim in block_q-wide
     # stores, so blocks must be 128-multiples (Mosaic rejects misaligned
     # vector stores — observed at S=99 on v5e); interpret mode only needs

@@ -2,9 +2,9 @@
 
 Every compressed wire used to pack on the HOST after a full-f32
 device-to-host transfer, so compression saved network bytes but never the
-device-link leg — the leg ``pop_op_stats`` flags as dominant on tunneled
-TPU runtimes. These kernels emit the packed wire buffer on the
-accelerator, so d2h bytes scale with the WIRE size, not the f32 size:
+device-link leg (``pop_op_stats`` reports it as ``d2h``). These kernels
+emit the packed wire buffer on the accelerator, so d2h bytes scale with
+the WIRE size, not the f32 size:
 
 - :func:`quantize_q8` / :func:`quantize_q8_ef`: symmetric per-leaf int8
   quantization (absmax/127 scale, floored at 1e-12), the EF variant with
@@ -45,6 +45,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 # Rows per grid block: 256x128 f32 = 128 KiB per VMEM buffer, and a
@@ -53,6 +54,9 @@ _BLOCK_ROWS = 256
 # Scale floor, shared with quantize.quantize_with_feedback and the native
 # plan_pack_ef: an all-zero leaf stays representable.
 _SCALE_FLOOR = 1e-12
+# The per-leaf scale rides scalar memory: the kernels read it once per
+# block as a scalar operand, which is what SMEM is for.
+_SCALAR_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _pick_interpret(interpret: Optional[bool]) -> bool:
@@ -88,67 +92,49 @@ def _to_tiles(x: jax.Array, rows_pad: int) -> jax.Array:
     return jnp.pad(flat, (0, total - flat.size)).reshape(rows_pad, _LANES)
 
 
-def _absmax_kernel(x_ref, out_ref):
-    # Revisited (1, 1) output block: the TPU grid is sequential, so the
-    # running max is deterministic; max() propagates NaN/Inf, which is the
-    # non-finite signal the scale computation turns into a NaN scale.
-    i = pl.program_id(0)
-    m = jnp.max(jnp.abs(x_ref[...]))
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[0, 0] = m
-
-    @pl.when(i > 0)
-    def _acc():
-        out_ref[0, 0] = jnp.maximum(out_ref[0, 0], m)
+def _split_scale(s: jax.Array) -> jax.Array:
+    """(3,) f32 ``[s, s_hi, s_lo]``: the scale and its 12-bit mantissa
+    halves, split by masking (exact; ``s_hi + s_lo == s``). Computed in
+    XLA because Mosaic has no scalar bitcast; the kernel reads the three
+    words from SMEM."""
+    bits = jax.lax.bitcast_convert_type(s, jnp.uint32)
+    s_hi = jax.lax.bitcast_convert_type(
+        bits & jnp.uint32(0xFFFFF000), jnp.float32
+    )
+    return jnp.stack([s, s_hi, s - s_hi])
 
 
-def _absmax(tiles: jax.Array, block: int, interpret: bool) -> jax.Array:
-    rows = tiles.shape[0]
-    return pl.pallas_call(
-        _absmax_kernel,
-        grid=(rows // block,),
-        in_specs=[pl.BlockSpec((block, _LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=interpret,
-    )(tiles)
-
-
-def _round32_mul(qf, s):
+def _round32_mul(qf, s_hi, s_lo):
     """round_f32(qf * s), immune to fma contraction — the decode the ring
     peers run is a plain single-rounded f32 multiply, and the residual
     needs ``d - round32(qf*s)`` with TWO roundings; a compiler-contracted
     ``fma(-qf, s, d)`` rounds once and drifts the carry at the last ulp
     (XLA's loop fusion contracts straight through optimization_barrier on
-    CPU). Split ``s`` into 12-bit mantissa halves by masking (exact);
+    CPU). With ``s`` split into 12-bit mantissa halves (_split_scale),
     both partial products are EXACT (|qf| <= 127 has <= 7 significand
     bits, each half <= 12), so the single f32 add performs the one
     rounding — and contracting either multiply into an fma cannot change
     an exact product's value."""
-    bits = jax.lax.bitcast_convert_type(s, jnp.uint32)
-    s_hi = jax.lax.bitcast_convert_type(
-        bits & jnp.uint32(0xFFFFF000), jnp.float32
-    )
-    s_lo = s - s_hi  # exact: the masked-off low mantissa bits
     return qf * s_hi + qf * s_lo
 
 
 def _quant_kernel(d_ref, scale_ref, q_ref, res_out_ref):
     # d_ref already holds the EF-adjusted payload (x + res, one exact
-    # elementwise add). scale_ref holds the RAW scale max(absmax/127,
-    # floor): finite for a finite leaf, NaN/Inf when the leaf diverged.
-    # On the poison path the codes zero and the caller's NaN scale
-    # carries the signal (0 * NaN decodes to NaN on every element — the
-    # host EF's whole-leaf propagation); the residual poisons here.
-    s = scale_ref[0, 0]
+    # elementwise add). scale_ref holds [s, s_hi, s_lo] for the RAW scale
+    # max(absmax/127, floor): finite for a finite leaf, NaN/Inf when the
+    # leaf diverged. On the poison path the codes zero and the caller's
+    # NaN scale carries the signal (0 * NaN decodes to NaN on every
+    # element — the host EF's whole-leaf propagation); the residual
+    # poisons here.
+    s = scale_ref[0]
     d = d_ref[...]
     v = jnp.clip(jnp.round(d / s), -127.0, 127.0)
     qf = jnp.where(jnp.isfinite(v), v, 0.0)
     q_ref[...] = qf.astype(jnp.int8)
     res_out_ref[...] = jnp.where(
-        jnp.isfinite(s), d - _round32_mul(qf, s), jnp.nan
+        jnp.isfinite(s),
+        d - _round32_mul(qf, scale_ref[1], scale_ref[2]),
+        jnp.nan,
     )
 
 
@@ -162,7 +148,11 @@ def _quantize_tiles(
     # absmax (and therefore the scale) is over d = x + res, not x. One
     # exact elementwise f32 add, identical to the oracle's.
     d = tiles + res_tiles
-    absmax = _absmax(d, block, interpret)[0, 0]
+    # Plain XLA reduce, not a kernel: Mosaic cannot store a scalar into a
+    # VMEM block, and a full-payload max is one fused pass either way.
+    # max() propagates NaN/Inf — the non-finite signal the scale
+    # computation below turns into a NaN scale.
+    absmax = jnp.max(jnp.abs(d))
     # The denominator is made DATA-DEPENDENT (0*x cannot be folded away
     # for floats — x may be NaN/Inf) because XLA compiles division by a
     # LITERAL constant into a reciprocal multiply under jit, which
@@ -177,7 +167,7 @@ def _quantize_tiles(
         grid=(rows // block,),
         in_specs=[
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            _SCALAR_SPEC,
         ],
         out_specs=[
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
@@ -188,7 +178,7 @@ def _quantize_tiles(
             jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(d, scale_raw.reshape(1, 1))
+    )(d, _split_scale(scale_raw))
     scale = jnp.where(jnp.isfinite(scale_raw), scale_raw, jnp.nan)
     return q, scale, res_out
 
@@ -233,7 +223,7 @@ def quantize_q8(
 
 
 def _dequant_kernel(q_ref, scale_ref, o_ref):
-    o_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[0, 0]
+    o_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[0]
 
 
 def dequantize_q8(
@@ -255,12 +245,12 @@ def dequantize_q8(
         grid=(rows_pad // block,),
         in_specs=[
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            _SCALAR_SPEC,
         ],
         out_specs=pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, _LANES), jnp.float32),
         interpret=interpret,
-    )(tiles, jnp.asarray(scale, jnp.float32).reshape(1, 1))
+    )(tiles, jnp.asarray(scale, jnp.float32).reshape(1))
     return out.reshape(-1)[:n].reshape(q.shape)
 
 

@@ -19,12 +19,46 @@ DEVICE with a device-resident carry — so on the plan transport this
 jitted host implementation is off the per-step path entirely (it remains
 the wire contract's executable spec, and the int8 allgather transport
 still runs it). All three are pinned bit-identical to the FMA-free numpy
-oracle in tests/test_comm_plan.py and tests/test_device_pack.py.
+oracle :func:`np_quantize_ef` by tests/test_comm_plan.py and
+tests/test_device_pack.py, and on the chip by ``chip_smoke.py``.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
+
+import numpy as np
+
+
+def np_quantize_codes(
+    leaf: np.ndarray, res: np.ndarray
+) -> Tuple[np.ndarray, np.ndarray, np.float32]:
+    """``(d, q, scale)`` of the pure-numpy EF reference: the EF-adjusted
+    payload ``d = leaf + res``, its integer codes (as f32) and the wire
+    scale — NaN, with zero codes, when the leaf is not finite."""
+    d = (leaf.astype(np.float32) + res).astype(np.float32)
+    absmax = np.max(np.abs(d)) if d.size else np.float32(0)
+    if not np.isfinite(absmax):
+        return d, np.zeros_like(d), np.float32(np.nan)
+    scale = np.maximum(np.float32(absmax) / np.float32(127.0),
+                       np.float32(1e-12))
+    return d, np.clip(np.round(d / scale), -127, 127).astype(np.float32), scale
+
+
+def np_quantize_ef(
+    leaf: np.ndarray, res: np.ndarray
+) -> Tuple[np.ndarray, np.ndarray]:
+    """``(dq, new_res)``: the pure-numpy, FMA-free reference of the EF
+    quantization that the jitted, native and Pallas implementations are
+    all tested against. (The jitted jax version may differ from it at the
+    last ulp of the residual — XLA contracts ``d - q*scale`` into an fma —
+    which is exactly why the plan's native EF is the wire contract.)"""
+    d, q, scale = np_quantize_codes(leaf, res)
+    if not np.isfinite(scale):
+        nan = np.float32(np.nan)
+        return np.full_like(d, nan), np.full_like(d, nan)
+    dq = (q * scale).astype(np.float32)
+    return dq, (d - dq).astype(np.float32)
 
 
 def quantize_with_feedback(tree: Any, residual: Any) -> Dict[str, Any]:
