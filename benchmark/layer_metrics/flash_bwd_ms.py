@@ -1,0 +1,9 @@
+"""Kernels: device time a traced step in the fused flash-attention
+backward kernel (every layer's ``flash_bwd`` custom call), from the
+trace's breakdown."""
+
+from benchmark.reduce import program
+
+
+def read(facts):
+    return program.kernel_ms(facts, "flash_bwd")

@@ -1,0 +1,9 @@
+"""Kernels: device time a traced step in the flash-attention forward
+kernel (every layer's ``flash_fwd`` custom call), from the trace's
+breakdown."""
+
+from benchmark.reduce import program
+
+
+def read(facts):
+    return program.kernel_ms(facts, "flash_fwd")

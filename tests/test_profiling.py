@@ -2,23 +2,34 @@
 
 Closes SURVEY.md §5's tracing gap; the reference has no analog, so these
 tests pin OUR contract: captures are step-windowed, env-configurable,
-failure-tolerant, and spans are no-ops without an active session.
+failure-tolerant, spans are no-ops without an active session, and one
+timed region shows under one name in every sink (the profiler span
+``torchft::<name>`` with the step as a stat, and the ``Metrics`` timer or
+the ``pop_op_stats`` keys).
 """
 
 import glob
 import os
+import threading
+import time
+from concurrent.futures import Future, ThreadPoolExecutor
+from datetime import timedelta
+from unittest.mock import MagicMock, patch
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from torchft_tpu.profiling import Profiler, span, step_span
+from torchft_tpu._native import Store, StoreClient
+from torchft_tpu.collectives import HostCollectives, ReduceOp, Work
+from torchft_tpu.metrics import Metrics
+from torchft_tpu.profiling import Profiler, span
 
 
 def test_span_noop_without_capture():
     with span("torchft::test"):
         pass
-    with step_span(3):
+    with span("torchft::test", 3):
         jnp.ones(4).sum()
 
 
@@ -31,7 +42,7 @@ def test_windowed_capture_writes_trace(tmp_path):
     assert prof.state == "idle"
     prof.on_step(2)  # starts
     assert prof.state == "active"
-    with step_span(2), span("torchft::quorum"):
+    with span("torchft::quorum", 2):
         jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
     prof.on_step(3)
     assert prof.state == "active"  # stop_after = start + num = 4
@@ -90,3 +101,243 @@ def test_double_start_is_swallowed(tmp_path):
     b.shutdown()
     assert a.state == "done"
     assert b.state == "done"
+
+
+# -- one primitive, one name in every sink --------------------------------
+
+
+def _captured(logdir, body):
+    """Runs ``body`` under a CPU capture; returns the ``torchft::*`` and
+    ``test::*`` host events as (thread line, name, start_ns, end_ns,
+    stats)."""
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(logdir), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(("torchft::", "test::")):
+                    events.append((
+                        i, e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        dict(e.stats),
+                    ))
+    return events
+
+
+def _one(events, name):
+    found = [e for e in events if e[1] == name]
+    assert len(found) == 1, (name, [e[1] for e in events])
+    return found[0]
+
+
+def test_timed_writes_the_timer_and_a_span_carrying_the_step(tmp_path):
+    metrics = Metrics()
+    metrics.step = 7
+
+    def body():
+        with metrics.timed("x"):
+            jnp.ones(4).block_until_ready()
+
+    event = _one(_captured(tmp_path, body), "torchft::x")  # the bare name
+    assert event[4] == {"step": 7}
+    timer = metrics.snapshot()["timers_s"]["x"]
+    assert timer["n"] == 1
+    # the same statements on two clocks
+    assert timer["total_s"] == pytest.approx((event[3] - event[2]) / 1e9, abs=2e-3)
+
+
+def test_work_wait_span_is_on_the_callers_thread(tmp_path):
+    metrics = Metrics()
+    plain, managed = Future(), Future()
+
+    def body():
+        releasers = [
+            threading.Timer(0.02, plain.set_result, (1,)),
+            threading.Timer(0.06, managed.set_result, (2,)),
+        ]
+        with span("test::caller"):
+            for r in releasers:
+                r.start()
+            assert Work(plain).wait() == 1
+            assert Work(managed, metrics).then(lambda v: v + 1).wait() == 3
+            for r in releasers:
+                r.join()
+
+    events = _captured(tmp_path, body)
+    caller = _one(events, "test::caller")
+    waits = [e for e in events if e[1] == "torchft::work_wait"]
+    assert len(waits) == 2
+    for wait in waits:
+        assert wait[0] == caller[0]  # the thread that called wait()
+        assert caller[2] <= wait[2] and wait[3] <= caller[3]
+    # a Work that knows its manager's Metrics also feeds the timer; a
+    # wait on finished work is no sample
+    assert Work(managed, metrics).wait() == 2
+    assert metrics.snapshot()["timers_s"]["work_wait"]["n"] == 1
+
+
+def test_wait_quorum_span_is_on_the_callers_thread(tmp_path):
+    from torchft_tpu._native import QuorumResult
+    from torchft_tpu.collectives import DummyCollectives
+    from torchft_tpu.manager import MANAGER_ADDR_KEY, REPLICA_ID_KEY, Manager
+
+    store = Store()
+    client = StoreClient(store.address())
+    client.set(MANAGER_ADDR_KEY, b"mock://manager")
+    client.set(REPLICA_ID_KEY, b"testrep")
+    with patch("torchft_tpu.manager.ManagerClient") as cls:
+        result = QuorumResult(
+            quorum_id=1, replica_rank=0, replica_world_size=2,
+            recover_src_manager_address="", recover_src_rank=None,
+            recover_dst_ranks=[], store_address="localhost:0", max_step=0,
+            max_rank=0, max_world_size=2, heal=False,
+        )
+        # slow enough that the caller really waits: a settled quorum holds
+        # nobody and is no sample
+        cls.return_value.quorum.side_effect = (
+            lambda **_: (time.sleep(0.05), result)[1]
+        )
+        manager = Manager(
+            collectives=DummyCollectives(), load_state_dict=None,
+            state_dict=None, min_replica_size=2, rank=1, world_size=2,
+            timeout=timedelta(seconds=10), store_addr=store.address(),
+            checkpoint_transport=MagicMock(
+                metadata=MagicMock(return_value="meta")
+            ),
+        )
+        manager.load_state_dict({"step": 5, "batches_committed": 0})
+        try:
+            def body():
+                with span("test::caller"):
+                    manager.start_quorum()
+                    manager.wait_quorum()
+
+            events = _captured(tmp_path, body)
+        finally:
+            manager.shutdown()
+            store.shutdown()
+    caller = _one(events, "test::caller")
+    wait = _one(events, "torchft::quorum_wait")
+    quorum = _one(events, "torchft::quorum")
+    assert wait[0] == caller[0] and quorum[0] != caller[0]
+    assert wait[4] == quorum[4] == {"step": 5}
+    timers = manager.metrics().snapshot()["timers_s"]
+    assert timers["quorum_wait"]["n"] == 1 and timers["quorum"]["n"] == 1
+
+
+def _ring_pair(store, prefix, **kwargs):
+    cols = [
+        HostCollectives(timeout=timedelta(seconds=15), **kwargs)
+        for _ in range(2)
+    ]
+    addr = f"{store.address()}/{prefix}"
+    with ThreadPoolExecutor(max_workers=2) as ex:
+        for f in [ex.submit(cols[r].configure, addr, r, 2) for r in range(2)]:
+            f.result()
+    return cols
+
+
+def _tree():
+    return {"a": jnp.ones((64, 8), jnp.float32), "b": jnp.ones((32,), jnp.float32)}
+
+
+# op -> (the call on one member, the keys the hand-timed code recorded)
+_CONVERTED_OPS = {
+    "allreduce": (
+        lambda c: c.allreduce(_tree(), ReduceOp.SUM).wait(),
+        {"op", "bytes", "d2h_bytes", "chunks", "pack", "d2h", "ring", "h2d",
+         "buckets"},
+    ),
+    "allreduce_q8": (
+        lambda c: c.allreduce(_tree(), ReduceOp.SUM, wire="q8").wait(),
+        {"op", "bytes", "wire_bytes", "d2h_bytes", "d2h", "ring", "h2d",
+         "stripe_s"},
+    ),
+    "allgather": (
+        lambda c: c.allgather(_tree()).wait(),
+        {"op", "bytes", "d2h_bytes", "pack", "d2h", "host_copy", "ring",
+         "h2d", "stripe_s"},
+    ),
+    "reduce_scatter": (
+        lambda c: c.reduce_scatter(_tree(), ReduceOp.SUM).wait(),
+        {"op", "bytes", "shard_bytes", "wire_bytes", "d2h_bytes", "d2h",
+         "ring", "h2d", "stripe_s"},
+    ),
+    "allgather_into": (
+        lambda c: c.allgather_into(
+            c.reduce_scatter(_tree(), ReduceOp.SUM).wait()
+        ).wait(),
+        {"op", "bytes", "wire_bytes", "d2h_bytes", "ring", "h2d", "stripe_s"},
+    ),
+    "plan_allreduce": (
+        lambda c: c.plan_allreduce(_tree()).wait(),
+        {"op", "wire", "device_pack", "bytes", "wire_bytes", "d2h_bytes",
+         "d2h", "ring", "buckets", "py_staging_allocs", "plan_execs"},
+    ),
+    "plan_reduce_scatter": (
+        lambda c: c.plan_reduce_scatter(_tree()).wait(),
+        {"op", "wire", "bytes", "shard_bytes", "wire_bytes", "d2h_bytes",
+         "d2h", "ring", "h2d", "buckets", "py_staging_allocs", "plan_execs"},
+    ),
+    "plan_allgather_into": (
+        lambda c: c.plan_allgather_into(
+            c.plan_reduce_scatter(_tree()).wait()
+        ).wait(),
+        {"op", "wire", "bytes", "wire_bytes", "d2h_bytes", "d2h", "ring",
+         "h2d", "buckets", "plan_execs"},
+    ),
+}
+
+
+@pytest.mark.parametrize("op", sorted(_CONVERTED_OPS))
+def test_op_context_phases_nest_and_keep_the_old_keys(tmp_path, op):
+    call, keys = _CONVERTED_OPS[op]
+    store = Store()
+    cols = _ring_pair(store, f"prof_{op}")
+    cols[0].trace_step = 11
+    try:
+        def body():
+            with ThreadPoolExecutor(max_workers=2) as ex:
+                for f in [ex.submit(call, c) for c in cols]:
+                    f.result()
+
+        events = _captured(tmp_path, body)
+        entries = [s for s in cols[0].pop_op_stats() if s["op"] == op]
+    finally:
+        for c in cols:
+            c.shutdown()
+        store.shutdown()
+    assert len(entries) == 1 and set(entries[0]) == keys
+    entry = entries[0]
+    # member 0's op span (the one stamped with its step) and what nests in it
+    (whole,) = [
+        e for e in events
+        if e[1] == f"torchft::{op}" and e[4].get("step") == 11
+    ]
+    phases = [
+        e for e in events
+        if e[1].startswith(f"torchft::{op}/") and e[0] == whole[0]
+        and whole[2] <= e[2] and e[3] <= whole[3]
+    ]
+    names = {e[1].rsplit("/", 1)[1] for e in phases}
+    assert names == keys & {"pack", "d2h", "host_copy", "ring", "h2d"}
+    assert all(e[4] == {"step": 11} for e in phases)
+    # the phases' sum is within the op, on both clocks
+    assert sum(e[3] - e[2] for e in phases) <= whole[3] - whole[2]
+    recorded = sum(entry[n] for n in names)
+    assert recorded <= (whole[3] - whole[2]) / 1e9 + 1e-4
+    assert recorded == pytest.approx(
+        sum(e[3] - e[2] for e in phases) / 1e9, abs=5e-3
+    )

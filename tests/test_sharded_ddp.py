@@ -42,6 +42,7 @@ import optax
 from torchft_tpu import FTTrainState, ShardedDDP, ShardedOptimizerWrapper
 from torchft_tpu._native import Store
 from torchft_tpu.collectives import HostCollectives, ReduceOp
+from torchft_tpu.metrics import Metrics
 from torchft_tpu.parallel import build_shard_apply_step
 
 
@@ -93,9 +94,13 @@ class _PlanRingManager:
         self.qid = quorum_id
         self.commit = True
         self.opt_bytes_reports: list = []
+        self._metrics = Metrics()
 
     def start_quorum(self, **kw):
         pass
+
+    def metrics(self):
+        return self._metrics
 
     def _div(self, op):
         return float(self._col.size()) if op == ReduceOp.AVG else None

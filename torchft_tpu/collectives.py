@@ -39,6 +39,7 @@ import numpy as np
 
 from . import _native
 from ._native import _check, _lib, _ms
+from .profiling import span
 
 
 class ReduceOp(IntEnum):
@@ -77,13 +78,23 @@ class Work:
     reference (torchft/process_group.py:318-330).
     """
 
-    def __init__(self, future: "Future[Any]") -> None:
+    def __init__(self, future: "Future[Any]", metrics: Any = None) -> None:
         self._future = future
+        # the owning Manager's Metrics, where there is one: ``wait`` then
+        # also feeds its ``work_wait`` timer
+        self._metrics = metrics
 
     def wait(self, timeout: Optional[timedelta] = None) -> Any:
-        return self._future.result(
-            timeout=timeout.total_seconds() if timeout is not None else None
-        )
+        seconds = timeout.total_seconds() if timeout is not None else None
+        if self._future.done():  # nothing to wait for: no span, no sample
+            return self._future.result(timeout=seconds)
+        # The caller's thread blocked on a collective: the exposed
+        # synchronisation of every schedule (they all end in ``.wait()``).
+        with (
+            self._metrics.timed("work_wait") if self._metrics is not None
+            else span("torchft::work_wait")
+        ):
+            return self._future.result(timeout=seconds)
 
     def result(self, timeout: Optional[timedelta] = None) -> Any:
         return self.wait(timeout)
@@ -112,7 +123,7 @@ class Work:
                 out.set_exception(e)
 
         self._future.add_done_callback(_chain)
-        return Work(out)
+        return Work(out, self._metrics)
 
 
 def _completed(value: Any) -> Work:
@@ -203,6 +214,11 @@ class Collectives(ABC):
     Reference interface: torchft/process_group.py:109-166 (configure /
     allreduce / allgather / broadcast / size).
     """
+
+    # The step the owning Manager is on (it keeps this current): the
+    # ``step`` stat of the ``torchft::<op>`` spans a backend emits, which
+    # pairs a phase on the exchange thread with the trainer's step.
+    trace_step: Optional[int] = None
 
     @abstractmethod
     def configure(
@@ -849,15 +865,79 @@ class _ShardedPlan:
         self.ag_wire_bytes = self.total * (2 if ag_wire == "bf16" else 4)
 
 
+class _OpPhase:
+    """One phase of an op: the span ``torchft::<op>/<name>`` and one
+    perf_counter pair, added to the op's ``seconds[name]`` on exit (a
+    phase entered once per chunk accumulates). ``seconds`` is this
+    entry's own share, for per-bucket accounting."""
+
+    def __init__(self, op: "_OpSpan", name: str) -> None:
+        self._op = op
+        self._name = name
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_OpPhase":
+        self._span = span(
+            f"torchft::{self._op.op}/{self._name}", self._op.step
+        )
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        totals = self._op.seconds
+        totals[self._name] = totals.get(self._name, 0.0) + self.seconds
+
+
+class _OpSpan:
+    """One collective op, timed once for both sinks: as a ``with`` it is
+    the profiler span ``torchft::<op>``; ``phase(name)`` nests
+    ``torchft::<op>/<name>`` in it; ``record(**fields)`` files the
+    ``pop_op_stats()`` entry - ``op``, the fields, and the seconds of
+    every phase under the phase's own name."""
+
+    def __init__(self, owner: "OpStatsMixin", op: str) -> None:
+        self._owner = owner
+        self.op = op
+        self.step = owner.trace_step  # a backend is a Collectives
+        self.seconds: Dict[str, float] = {}
+
+    def __enter__(self) -> "_OpSpan":
+        self._span = span(f"torchft::{self.op}", self.step)
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        self._span.__exit__(*exc)
+
+    def phase(self, name: str) -> _OpPhase:
+        return _OpPhase(self, name)
+
+    def record(self, **fields: Any) -> dict:
+        stats = {"op": self.op, **fields, **self.seconds}
+        self._owner._record_op_stats(stats)
+        return stats
+
+
 class OpStatsMixin:
     """Per-op phase-timing recorder shared by every data-plane backend
     (host ring, XLA, isolated XLA): the accounting contract AdaptiveDDP's
     probe comparisons and the diagnosis tooling rely on is that EVERY
     backend's ops drain through one ``pop_op_stats`` with the same core
     keys — ``op``, ``bytes`` (payload) and ``d2h_bytes`` (what actually
-    crossed the device link) — plus backend-specific phase timings."""
+    crossed the device link) — plus backend-specific phase timings.
+
+    The host ring times its ops through ``_op(name)`` (one ``with`` per
+    op and per phase, which is also the profiler span); the XLA planes
+    call ``_record_op_stats`` with a dict they timed themselves."""
 
     _op_stats: List[dict]
+    trace_step: Optional[int]  # Collectives', kept current by the Manager
+
+    def _op(self, name: str) -> _OpSpan:
+        return _OpSpan(self, name)
 
     def _record_op_stats(self, stats: dict) -> None:
         if not hasattr(self, "_op_stats"):
@@ -1257,65 +1337,67 @@ class HostCollectives(OpStatsMixin, Collectives):
         if not leaves:
             return tree
         all_jax = all(_is_jax_array(l) for l in leaves)
-        if all_jax:
-            key = (
-                "q8", treedef,
-                tuple((l.shape, np.dtype(l.dtype)) for l in leaves),
-            )
-            packer = self._packers.get(key)
-            if packer is None:
-                packer = self._packers[key] = _DevicePacker(
-                    leaves, force_f32=True
+        with self._op("allreduce_q8") as timing:
+            if all_jax:
+                key = (
+                    "q8", treedef,
+                    tuple((l.shape, np.dtype(l.dtype)) for l in leaves),
                 )
-            t0 = time.perf_counter()
-            buf = np.asarray(packer.pack(leaves)[str(np.dtype(np.float32))])
-            if not buf.flags.writeable or not buf.flags.c_contiguous:
-                buf = np.array(buf)
-            d2h_s = time.perf_counter() - t0
-        else:
-            arrays = [_as_numpy(l) for l in leaves]
-            buf = np.concatenate(
-                [a.astype(np.float32, copy=False).ravel() for a in arrays]
-            )
-        t1 = time.perf_counter()
-        _check(
-            _lib.tft_hc_allreduce_q8(
-                self._handle,
-                buf.ctypes.data_as(ctypes.c_void_p),
-                buf.size,
-                timeout_ms,
-            )
-        )
-        stripe_s = self._last_stripe_seconds()
-        if divisor is not None:
-            buf /= divisor
-        ring_s = time.perf_counter() - t1
-        if all_jax:
-            import jax.numpy as jnp
+                packer = self._packers.get(key)
+                if packer is None:
+                    packer = self._packers[key] = _DevicePacker(
+                        leaves, force_f32=True
+                    )
+                with timing.phase("d2h"):
+                    buf = np.asarray(
+                        packer.pack(leaves)[str(np.dtype(np.float32))]
+                    )
+                    if not buf.flags.writeable or not buf.flags.c_contiguous:
+                        buf = np.array(buf)
+            else:
+                arrays = [_as_numpy(l) for l in leaves]
+                buf = np.concatenate(
+                    [a.astype(np.float32, copy=False).ravel() for a in arrays]
+                )
+            with timing.phase("ring"):
+                _check(
+                    _lib.tft_hc_allreduce_q8(
+                        self._handle,
+                        buf.ctypes.data_as(ctypes.c_void_p),
+                        buf.size,
+                        timeout_ms,
+                    )
+                )
+                stripe_s = self._last_stripe_seconds()
+                if divisor is not None:
+                    buf /= divisor
+            if all_jax:
+                import jax.numpy as jnp
 
-            out = _unflatten(
-                treedef,
-                packer.unpack({str(np.dtype(np.float32)): jnp.asarray(buf)}),
-            )
-            self._record_op_stats({
-                "op": "allreduce_q8", "bytes": buf.nbytes,
-                # TCP wire ships int8 chunks + per-chunk f32 scales + the
-                # op header, not the f32 device payload — the sidecar is
-                # counted (one scale per stripe x ring chunk x phase) so
-                # the compression ratio is honest.
-                "wire_bytes": buf.size + _q8_wire_overhead(
-                    _effective_stripes(buf.size, self._stripes),
-                    self._world_size,
-                ),
-                # Host-side quantization: the device link still carried
-                # the FULL f32 payload (the device-pack plan path is what
-                # shrinks this).
-                "d2h_bytes": buf.nbytes,
-                "d2h": d2h_s, "ring": ring_s,
-                "h2d": time.perf_counter() - t1 - ring_s,
-                "stripe_s": stripe_s,
-            })
-            return out
+                with timing.phase("h2d"):
+                    out = _unflatten(
+                        treedef,
+                        packer.unpack(
+                            {str(np.dtype(np.float32)): jnp.asarray(buf)}
+                        ),
+                    )
+                timing.record(
+                    bytes=buf.nbytes,
+                    # TCP wire ships int8 chunks + per-chunk f32 scales +
+                    # the op header, not the f32 device payload — the
+                    # sidecar is counted (one scale per stripe x ring chunk
+                    # x phase) so the compression ratio is honest.
+                    wire_bytes=buf.size + _q8_wire_overhead(
+                        _effective_stripes(buf.size, self._stripes),
+                        self._world_size,
+                    ),
+                    # Host-side quantization: the device link still carried
+                    # the FULL f32 payload (the device-pack plan path is
+                    # what shrinks this).
+                    d2h_bytes=buf.nbytes,
+                    stripe_s=stripe_s,
+                )
+                return out
         out_leaves = []
         offset = 0
         for a in arrays:
@@ -1433,86 +1515,81 @@ class HostCollectives(OpStatsMixin, Collectives):
         packer = self._packers.get(key)
         if packer is None:
             packer = self._packers[key] = _DevicePacker(leaves)
-        t_pack = time.perf_counter()
-        bufs = packer.pack(leaves)
-        names = sorted(bufs)  # deterministic bucket order = the op schedule
+        with self._op("allreduce") as timing:
+            with timing.phase("pack"):
+                bufs = packer.pack(leaves)
+                names = sorted(bufs)  # deterministic bucket order = the op schedule
 
-        # Chunk schedule across ALL buckets. Chunk boundaries depend only
-        # on (size, pipeline config), both store-negotiated, so every rank
-        # issues the identical sequence of native ring ops.
-        schedule: List[Tuple[str, Any]] = []
-        for name in names:
-            dev = bufs[name]
-            itemsize = np.dtype(dev.dtype).itemsize
-            k = self._pipeline_chunks
-            if k <= 1 or dev.size * itemsize < self._pipeline_min_bytes:
-                schedule.append((name, dev))
-            else:
-                bounds = [dev.size * i // k for i in range(k + 1)]
-                schedule.extend(
-                    (name, dev[a:b]) for a, b in zip(bounds, bounds[1:])
-                )
-        for _, c in schedule:
-            c.copy_to_host_async()  # queue every DMA before the first block
-        pack_s = time.perf_counter() - t_pack
+                # Chunk schedule across ALL buckets. Chunk boundaries depend
+                # only on (size, pipeline config), both store-negotiated, so
+                # every rank issues the identical sequence of native ring ops.
+                schedule: List[Tuple[str, Any]] = []
+                for name in names:
+                    dev = bufs[name]
+                    itemsize = np.dtype(dev.dtype).itemsize
+                    k = self._pipeline_chunks
+                    if k <= 1 or dev.size * itemsize < self._pipeline_min_bytes:
+                        schedule.append((name, dev))
+                    else:
+                        bounds = [dev.size * i // k for i in range(k + 1)]
+                        schedule.extend(
+                            (name, dev[a:b]) for a, b in zip(bounds, bounds[1:])
+                        )
+                for _, c in schedule:
+                    c.copy_to_host_async()  # queue every DMA before the first block
 
-        out_chunks: dict = {name: [] for name in names}
-        buckets: dict = {
-            name: {"bytes": 0, "d2h": 0.0, "ring": 0.0, "h2d": 0.0,
-                   "stripe_s": [], "stripe_wall": 0.0}
-            for name in names
-        }
-        for name, c in schedule:
-            st = buckets[name]
-            t0 = time.perf_counter()
-            arr = np.asarray(c)  # completes when THIS chunk's DMA lands
-            if not arr.flags.writeable or not arr.flags.c_contiguous:
-                arr = np.array(arr)  # ring reduces in place
-            t1 = time.perf_counter()
-            self._ring_chunk(arr, native_op, timeout_ms)
-            stripe_s = self._last_stripe_seconds()
-            if divisor is not None:
-                arr = self._apply_divisor(arr, divisor)
-            t2 = time.perf_counter()
-            # Async dispatch: the upload starts now and overlaps the next
-            # chunk's (possibly next bucket's) ring pass.
-            out_chunks[name].append(jnp.asarray(arr))
-            st["bytes"] += arr.nbytes
-            st["d2h"] += t1 - t0
-            st["ring"] += t2 - t1
-            st["h2d"] += time.perf_counter() - t2
-            # elementwise-sum the per-stripe ring seconds over the
-            # bucket's chunks (chunks can use fewer effective stripes)
-            acc = st["stripe_s"]
-            for i, s in enumerate(stripe_s):
-                if i < len(acc):
-                    acc[i] += s
-                else:
-                    acc.append(s)
-            # pure transport wall: the slowest stripe bounds each chunk's
-            # ring pass; summing per-chunk maxima excludes the peer-skew
-            # wait the `ring` phase absorbs at the op-header sync, so this
-            # is the number a stripe-count sweep compares
-            if stripe_s:
-                st["stripe_wall"] += max(stripe_s)
-        dev_bufs = {
-            name: (chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks))
-            for name, chunks in out_chunks.items()
-        }
-        total_bytes = sum(b["bytes"] for b in buckets.values())
-        self._record_op_stats({
-            "op": "allreduce",
-            "bytes": total_bytes,
-            # native dtypes ride both legs at full width
-            "d2h_bytes": total_bytes,
-            "chunks": len(schedule),
-            "pack": pack_s,
-            "d2h": sum(b["d2h"] for b in buckets.values()),
-            "ring": sum(b["ring"] for b in buckets.values()),
-            "h2d": sum(b["h2d"] for b in buckets.values()),
-            "buckets": buckets,
-        })
-        return _unflatten(treedef, packer.unpack(dev_bufs))
+            out_chunks: dict = {name: [] for name in names}
+            buckets: dict = {
+                name: {"bytes": 0, "d2h": 0.0, "ring": 0.0, "h2d": 0.0,
+                       "stripe_s": [], "stripe_wall": 0.0}
+                for name in names
+            }
+            for name, c in schedule:
+                st = buckets[name]
+                with timing.phase("d2h") as d2h:
+                    arr = np.asarray(c)  # completes when THIS chunk's DMA lands
+                    if not arr.flags.writeable or not arr.flags.c_contiguous:
+                        arr = np.array(arr)  # ring reduces in place
+                with timing.phase("ring") as ring:
+                    self._ring_chunk(arr, native_op, timeout_ms)
+                    stripe_s = self._last_stripe_seconds()
+                    if divisor is not None:
+                        arr = self._apply_divisor(arr, divisor)
+                with timing.phase("h2d") as h2d:
+                    # Async dispatch: the upload starts now and overlaps the
+                    # next chunk's (possibly next bucket's) ring pass.
+                    out_chunks[name].append(jnp.asarray(arr))
+                st["bytes"] += arr.nbytes
+                st["d2h"] += d2h.seconds
+                st["ring"] += ring.seconds
+                st["h2d"] += h2d.seconds
+                # elementwise-sum the per-stripe ring seconds over the
+                # bucket's chunks (chunks can use fewer effective stripes)
+                acc = st["stripe_s"]
+                for i, s in enumerate(stripe_s):
+                    if i < len(acc):
+                        acc[i] += s
+                    else:
+                        acc.append(s)
+                # pure transport wall: the slowest stripe bounds each chunk's
+                # ring pass; summing per-chunk maxima excludes the peer-skew
+                # wait the `ring` phase absorbs at the op-header sync, so
+                # this is the number a stripe-count sweep compares
+                if stripe_s:
+                    st["stripe_wall"] += max(stripe_s)
+            dev_bufs = {
+                name: (chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks))
+                for name, chunks in out_chunks.items()
+            }
+            total_bytes = sum(b["bytes"] for b in buckets.values())
+            timing.record(
+                bytes=total_bytes,
+                # native dtypes ride both legs at full width
+                d2h_bytes=total_bytes,
+                chunks=len(schedule),
+                buckets=buckets,
+            )
+            return _unflatten(treedef, packer.unpack(dev_bufs))
 
     def _apply_divisor(self, arr: np.ndarray, divisor) -> np.ndarray:
         if arr.dtype == _BF16:
@@ -1690,126 +1767,122 @@ class HostCollectives(OpStatsMixin, Collectives):
         all_jax = all(_is_jax_array(l) for l in leaves)
         f32 = np.dtype(np.float32)
 
-        t0 = time.perf_counter()
-        if all_jax:
-            key = (
-                "hier_q8" if wire == "q8" else "hier", treedef,
-                tuple((l.shape, np.dtype(l.dtype)) for l in leaves),
-            )
-            packer = self._packers.get(key)
-            if packer is None:
-                packer = self._packers[key] = _DevicePacker(
-                    leaves, force_f32=(wire == "q8")
-                )
-            bufs = packer.pack(leaves)
-            names = sorted(bufs)
-            for name in names:  # queue every DMA before blocking on one
-                bufs[name].copy_to_host_async()
-            host = {}
-            for name in names:
-                arr = np.asarray(bufs[name])
-                if not arr.flags.writeable or not arr.flags.c_contiguous:
-                    arr = np.array(arr)  # the schedule reduces in place
-                host[name] = arr
-            arrays = was_jax = None
-        else:
-            packer = None
-            arrays = [_as_numpy(l) for l in leaves]
-            was_jax = [_is_jax_array(l) for l in leaves]
-            groups: dict = {}
-            for i, a in enumerate(arrays):
-                if wire == "q8":
-                    acc = f32  # the quantized inter hop reduces ONE f32 group
+        with self._op("allreduce_hier") as timing:
+            with timing.phase("d2h"):
+                if all_jax:
+                    key = (
+                        "hier_q8" if wire == "q8" else "hier", treedef,
+                        tuple((l.shape, np.dtype(l.dtype)) for l in leaves),
+                    )
+                    packer = self._packers.get(key)
+                    if packer is None:
+                        packer = self._packers[key] = _DevicePacker(
+                            leaves, force_f32=(wire == "q8")
+                        )
+                    bufs = packer.pack(leaves)
+                    names = sorted(bufs)
+                    for name in names:  # queue every DMA before blocking on one
+                        bufs[name].copy_to_host_async()
+                    host = {}
+                    for name in names:
+                        arr = np.asarray(bufs[name])
+                        if not arr.flags.writeable or not arr.flags.c_contiguous:
+                            arr = np.array(arr)  # the schedule reduces in place
+                        host[name] = arr
+                    arrays = was_jax = None
                 else:
-                    acc = (a.dtype if a.dtype in _NATIVE_DTYPES else f32)
-                groups.setdefault(str(acc), []).append(i)
-            host = {
-                name: np.concatenate(
-                    [arrays[i].astype(np.dtype(name), copy=False).ravel()
-                     for i in idxs]
-                )
-                for name, idxs in groups.items()
-            }
-            names = sorted(host)
-        d2h_s = time.perf_counter() - t0
+                    packer = None
+                    arrays = [_as_numpy(l) for l in leaves]
+                    was_jax = [_is_jax_array(l) for l in leaves]
+                    groups: dict = {}
+                    for i, a in enumerate(arrays):
+                        if wire == "q8":
+                            acc = f32  # the quantized inter hop reduces ONE f32 group
+                        else:
+                            acc = (a.dtype if a.dtype in _NATIVE_DTYPES else f32)
+                        groups.setdefault(str(acc), []).append(i)
+                    host = {
+                        name: np.concatenate(
+                            [arrays[i].astype(np.dtype(name), copy=False).ravel()
+                             for i in idxs]
+                        )
+                        for name, idxs in groups.items()
+                    }
+                    names = sorted(host)
 
-        t1 = time.perf_counter()
-        hier_stats: Optional[dict] = None
-        for name in names:
-            buf = host[name]
-            # The wire applies where it means something: the q8 grouping
-            # is a single f32 buffer by construction, and bf16 compresses
-            # f32 groups only (others ride the inter hop at native width).
-            if wire == "q8":
-                gw = _HIER_WIRES["q8"]
-            elif wire == "bf16" and buf.dtype == f32:
-                gw = _HIER_WIRES["bf16"]
-            else:
-                gw = _HIER_WIRES[None]
-            _check(
-                _lib.tft_hc_allreduce_hier(
-                    self._handle,
-                    buf.ctypes.data_as(ctypes.c_void_p),
-                    buf.size,
-                    _NATIVE_DTYPES[buf.dtype],
-                    native_op,
-                    gw,
-                    timeout_ms,
-                )
-            )
-            hier_stats = self._merge_hier_stats(
-                hier_stats, self._last_hier_dict()
-            )
-            if divisor is not None and divisor != 1:
-                host[name] = self._apply_divisor(buf, divisor)
-        ring_s = time.perf_counter() - t1
+            with timing.phase("ring"):
+                hier_stats: Optional[dict] = None
+                for name in names:
+                    buf = host[name]
+                    # The wire applies where it means something: the q8 grouping
+                    # is a single f32 buffer by construction, and bf16 compresses
+                    # f32 groups only (others ride the inter hop at native width).
+                    if wire == "q8":
+                        gw = _HIER_WIRES["q8"]
+                    elif wire == "bf16" and buf.dtype == f32:
+                        gw = _HIER_WIRES["bf16"]
+                    else:
+                        gw = _HIER_WIRES[None]
+                    _check(
+                        _lib.tft_hc_allreduce_hier(
+                            self._handle,
+                            buf.ctypes.data_as(ctypes.c_void_p),
+                            buf.size,
+                            _NATIVE_DTYPES[buf.dtype],
+                            native_op,
+                            gw,
+                            timeout_ms,
+                        )
+                    )
+                    hier_stats = self._merge_hier_stats(
+                        hier_stats, self._last_hier_dict()
+                    )
+                    if divisor is not None and divisor != 1:
+                        host[name] = self._apply_divisor(buf, divisor)
 
-        t2 = time.perf_counter()
-        if all_jax:
-            import jax.numpy as jnp
+            with timing.phase("h2d"):
+                if all_jax:
+                    import jax.numpy as jnp
 
-            out = _unflatten(
-                treedef,
-                packer.unpack(
-                    {name: jnp.asarray(host[name]) for name in names}
+                    out = _unflatten(
+                        treedef,
+                        packer.unpack(
+                            {name: jnp.asarray(host[name]) for name in names}
+                        ),
+                    )
+                else:
+                    out_leaves: List[Any] = [None] * len(arrays)
+                    for name, idxs in groups.items():
+                        buf = host[name]
+                        offset = 0
+                        for i in idxs:
+                            n = arrays[i].size
+                            leaf = (
+                                buf[offset:offset + n]
+                                .reshape(arrays[i].shape)
+                                .astype(arrays[i].dtype, copy=False)
+                            )
+                            offset += n
+                            if was_jax[i]:
+                                import jax.numpy as jnp
+
+                                leaf = jnp.asarray(leaf)
+                            out_leaves[i] = leaf
+                    out = _unflatten(treedef, out_leaves)
+            total_bytes = sum(host[n].nbytes for n in names)
+            timing.record(
+                wire=wire,
+                bytes=total_bytes,
+                d2h_bytes=total_bytes if all_jax else 0,
+                # MEASURED traffic this member sent, per tier (duplex's
+                # per-connection counters, summed) — the number that shows
+                # the inter-tier byte reduction directly, not a model.
+                **(
+                    self._hier_stats_fields(hier_stats)
+                    if hier_stats is not None else {}
                 ),
             )
-        else:
-            out_leaves: List[Any] = [None] * len(arrays)
-            for name, idxs in groups.items():
-                buf = host[name]
-                offset = 0
-                for i in idxs:
-                    n = arrays[i].size
-                    leaf = (
-                        buf[offset:offset + n]
-                        .reshape(arrays[i].shape)
-                        .astype(arrays[i].dtype, copy=False)
-                    )
-                    offset += n
-                    if was_jax[i]:
-                        import jax.numpy as jnp
-
-                        leaf = jnp.asarray(leaf)
-                    out_leaves[i] = leaf
-            out = _unflatten(treedef, out_leaves)
-        total_bytes = sum(host[n].nbytes for n in names)
-        st: dict = {
-            "op": "allreduce_hier",
-            "wire": wire,
-            "bytes": total_bytes,
-            "d2h_bytes": total_bytes if all_jax else 0,
-            # MEASURED traffic this member sent, per tier (duplex's
-            # per-connection counters, summed) — the number that shows
-            # the inter-tier byte reduction directly, not a model.
-            "d2h": d2h_s,
-            "ring": ring_s,
-            "h2d": time.perf_counter() - t2,
-        }
-        if hier_stats is not None:
-            st.update(self._hier_stats_fields(hier_stats))
-        self._record_op_stats(st)
-        return out
+            return out
 
     # -- planned ops --
 
@@ -1983,55 +2056,54 @@ class HostCollectives(OpStatsMixin, Collectives):
             raise ValueError(
                 "plan wire 'bf16' requires native-dtype leaves"
             )
-        t0 = time.perf_counter()
-        staging_allocs = 0
-        refs = []  # keep host views alive across the native call
-        in_ptrs = plan.in_ptrs
-        for i, l in enumerate(leaves):
-            a = np.asarray(l)  # zero-copy for numpy / CPU jax leaves
-            if not a.flags.c_contiguous:
-                a = np.ascontiguousarray(a)
-                staging_allocs += 1
-            refs.append(a)
-            in_ptrs[i] = a.ctypes.data
-        t1 = time.perf_counter()
-        outs = plan.out_sets[plan.flip]
-        out_ptrs = plan.out_ptrs[plan.flip]
-        plan.flip ^= 1
-        _check(
-            _lib.tft_plan_execute(
-                self._handle,
-                plan.plan_id,
-                in_ptrs,
-                out_ptrs,
-                float(divisor if divisor is not None else 1.0),
-                0 if divisor is None else 1,
-                timeout_ms,
+        with self._op("plan_allreduce") as timing:
+            # pointer gather; host leaves make it ~free
+            with timing.phase("d2h"):
+                staging_allocs = 0
+                refs = []  # keep host views alive across the native call
+                in_ptrs = plan.in_ptrs
+                for i, l in enumerate(leaves):
+                    a = np.asarray(l)  # zero-copy for numpy / CPU jax leaves
+                    if not a.flags.c_contiguous:
+                        a = np.ascontiguousarray(a)
+                        staging_allocs += 1
+                    refs.append(a)
+                    in_ptrs[i] = a.ctypes.data
+            # the single native call: pack+ring+unpack
+            with timing.phase("ring"):
+                outs = plan.out_sets[plan.flip]
+                out_ptrs = plan.out_ptrs[plan.flip]
+                plan.flip ^= 1
+                _check(
+                    _lib.tft_plan_execute(
+                        self._handle,
+                        plan.plan_id,
+                        in_ptrs,
+                        out_ptrs,
+                        float(divisor if divisor is not None else 1.0),
+                        0 if divisor is None else 1,
+                        timeout_ms,
+                    )
+                )
+            del refs
+            plan.execs += 1
+            timing.record(
+                wire=wire,
+                device_pack=False,
+                bytes=plan.bytes,
+                wire_bytes=plan.wire_bytes,
+                # Host pack reads every leaf at full source width: the device
+                # link pays f32-size bytes regardless of the wire encoding.
+                d2h_bytes=plan.bytes,
+                # Per-bucket phases, fetched raw here and decoded lazily at
+                # pop_op_stats: the JSON parse stays off the per-step path.
+                _buckets_json=self._plan_stats_json(plan.plan_id),
+                # The zero-allocation contract: after warmup, no Python-side
+                # staging buffer is allocated on this path (only forced
+                # copies of non-contiguous inputs would count here).
+                py_staging_allocs=staging_allocs,
+                plan_execs=plan.execs,
             )
-        )
-        ring_s = time.perf_counter() - t1
-        del refs
-        plan.execs += 1
-        self._record_op_stats({
-            "op": "plan_allreduce",
-            "wire": wire,
-            "device_pack": False,
-            "bytes": plan.bytes,
-            "wire_bytes": plan.wire_bytes,
-            # Host pack reads every leaf at full source width: the device
-            # link pays f32-size bytes regardless of the wire encoding.
-            "d2h_bytes": plan.bytes,
-            "d2h": t1 - t0,  # pointer gather; host leaves make it ~free
-            "ring": ring_s,  # the single native call: pack+ring+unpack
-            # Per-bucket phases, fetched raw here and decoded lazily at
-            # pop_op_stats: the JSON parse stays off the per-step path.
-            "_buckets_json": self._plan_stats_json(plan.plan_id),
-            # The zero-allocation contract: after warmup, no Python-side
-            # staging buffer is allocated on this path (only forced
-            # copies of non-contiguous inputs would count here).
-            "py_staging_allocs": staging_allocs,
-            "plan_execs": plan.execs,
-        })
         return _unflatten(treedef, outs)
 
     def _plan_hier_sync(
@@ -2069,53 +2141,52 @@ class HostCollectives(OpStatsMixin, Collectives):
             raise ValueError(
                 "hier plan wire 'bf16' requires native-dtype leaves"
             )
-        t0 = time.perf_counter()
-        staging_allocs = 0
-        refs = []  # keep host views alive across the native call
-        in_ptrs = plan.in_ptrs
-        for i, l in enumerate(leaves):
-            a = np.asarray(l)  # zero-copy for numpy / CPU jax leaves
-            if not a.flags.c_contiguous:
-                a = np.ascontiguousarray(a)
-                staging_allocs += 1
-            refs.append(a)
-            in_ptrs[i] = a.ctypes.data
-        t1 = time.perf_counter()
-        outs = plan.out_sets[plan.flip]
-        out_ptrs = plan.out_ptrs[plan.flip]
-        plan.flip ^= 1
-        _check(
-            _lib.tft_plan_execute(
-                self._handle,
-                plan.plan_id,
-                in_ptrs,
-                out_ptrs,
-                float(divisor if divisor is not None else 1.0),
-                0 if divisor is None else 1,
-                timeout_ms,
+        with self._op("plan_allreduce") as timing:
+            # pointer gather; host leaves make it ~free
+            with timing.phase("d2h"):
+                staging_allocs = 0
+                refs = []  # keep host views alive across the native call
+                in_ptrs = plan.in_ptrs
+                for i, l in enumerate(leaves):
+                    a = np.asarray(l)  # zero-copy for numpy / CPU jax leaves
+                    if not a.flags.c_contiguous:
+                        a = np.ascontiguousarray(a)
+                        staging_allocs += 1
+                    refs.append(a)
+                    in_ptrs[i] = a.ctypes.data
+            # the single native call: the whole schedule
+            with timing.phase("ring"):
+                outs = plan.out_sets[plan.flip]
+                out_ptrs = plan.out_ptrs[plan.flip]
+                plan.flip ^= 1
+                _check(
+                    _lib.tft_plan_execute(
+                        self._handle,
+                        plan.plan_id,
+                        in_ptrs,
+                        out_ptrs,
+                        float(divisor if divisor is not None else 1.0),
+                        0 if divisor is None else 1,
+                        timeout_ms,
+                    )
+                )
+            del refs
+            plan.execs += 1
+            timing.record(
+                wire=wire,
+                hier=True,
+                device_pack=False,
+                bytes=plan.bytes,
+                d2h_bytes=plan.bytes,
+                _buckets_json=self._plan_stats_json(plan.plan_id),
+                py_staging_allocs=staging_allocs,
+                plan_execs=plan.execs,
+                **(
+                    self._hier_stats_fields(self._last_hier_dict())
+                    if self._world_size > 1
+                    else {"wire_bytes": plan.wire_bytes}
+                ),
             )
-        )
-        ring_s = time.perf_counter() - t1
-        del refs
-        plan.execs += 1
-        st: dict = {
-            "op": "plan_allreduce",
-            "wire": wire,
-            "hier": True,
-            "device_pack": False,
-            "bytes": plan.bytes,
-            "d2h_bytes": plan.bytes,
-            "d2h": t1 - t0,  # pointer gather; host leaves make it ~free
-            "ring": ring_s,  # the single native call: the whole schedule
-            "_buckets_json": self._plan_stats_json(plan.plan_id),
-            "py_staging_allocs": staging_allocs,
-            "plan_execs": plan.execs,
-        }
-        if self._world_size > 1:
-            st.update(self._hier_stats_fields(self._last_hier_dict()))
-        else:
-            st["wire_bytes"] = plan.wire_bytes
-        self._record_op_stats(st)
         return _unflatten(treedef, outs)
 
     def _plan_execute_device(
@@ -2134,67 +2205,65 @@ class HostCollectives(OpStatsMixin, Collectives):
         prepacked native plan decodes them straight into its staging —
         ring and unpack are the host-pack plan's own, so results are
         bit-identical to host packing."""
-        t0 = time.perf_counter()
-        payloads, scales = packer.pack_step(leaves)
-        for a in payloads:
-            a.copy_to_host_async()
-        for a in scales:
-            a.copy_to_host_async()
-        t1 = time.perf_counter()
-        staging_allocs = 0
-        host_payloads: List[np.ndarray] = []
-        for a in payloads:
-            h = np.asarray(a)
-            if not h.flags.c_contiguous:
-                h = np.ascontiguousarray(h)
-                staging_allocs += 1
-            host_payloads.append(h)
-        host_scales = [
-            np.ascontiguousarray(np.asarray(a)) for a in scales
-        ]
-        t2 = time.perf_counter()
-        gin, gaux = plan.group_in, plan.group_aux
-        q8 = wire in ("q8", "q8ef")
-        for gi, h in enumerate(host_payloads):
-            gin[gi] = h.ctypes.data
-            gaux[gi] = host_scales[gi].ctypes.data if q8 else None
-        outs = plan.out_sets[plan.flip]
-        out_ptrs = plan.out_ptrs[plan.flip]
-        plan.flip ^= 1
-        _check(
-            _lib.tft_plan_execute_pre(
-                self._handle,
-                plan.plan_id,
-                gin,
-                gaux,
-                out_ptrs,
-                float(divisor if divisor is not None else 1.0),
-                0 if divisor is None else 1,
-                timeout_ms,
+        with self._op("plan_allreduce") as timing:
+            # device kernel dispatch + DMA enqueue
+            with timing.phase("pack"):
+                payloads, scales = packer.pack_step(leaves)
+                for a in payloads:
+                    a.copy_to_host_async()
+                for a in scales:
+                    a.copy_to_host_async()
+            # blocking readback of the wire buffers
+            with timing.phase("d2h"):
+                staging_allocs = 0
+                host_payloads: List[np.ndarray] = []
+                for a in payloads:
+                    h = np.asarray(a)
+                    if not h.flags.c_contiguous:
+                        h = np.ascontiguousarray(h)
+                        staging_allocs += 1
+                    host_payloads.append(h)
+                host_scales = [
+                    np.ascontiguousarray(np.asarray(a)) for a in scales
+                ]
+            # the single native call: decode+ring+unpack
+            with timing.phase("ring"):
+                gin, gaux = plan.group_in, plan.group_aux
+                q8 = wire in ("q8", "q8ef")
+                for gi, h in enumerate(host_payloads):
+                    gin[gi] = h.ctypes.data
+                    gaux[gi] = host_scales[gi].ctypes.data if q8 else None
+                outs = plan.out_sets[plan.flip]
+                out_ptrs = plan.out_ptrs[plan.flip]
+                plan.flip ^= 1
+                _check(
+                    _lib.tft_plan_execute_pre(
+                        self._handle,
+                        plan.plan_id,
+                        gin,
+                        gaux,
+                        out_ptrs,
+                        float(divisor if divisor is not None else 1.0),
+                        0 if divisor is None else 1,
+                        timeout_ms,
+                    )
+                )
+            plan.execs += 1
+            timing.record(
+                wire=wire,
+                device_pack=True,
+                bytes=plan.bytes,
+                wire_bytes=plan.wire_bytes,
+                # The tentpole number: the device link carried the WIRE
+                # encoding (int8 codes + scale sidecar / bf16 words), not the
+                # full-width leaves.
+                d2h_bytes=sum(h.nbytes for h in host_payloads) + sum(
+                    h.nbytes for h in host_scales
+                ),
+                _buckets_json=self._plan_stats_json(plan.plan_id),
+                py_staging_allocs=staging_allocs,
+                plan_execs=plan.execs,
             )
-        )
-        ring_s = time.perf_counter() - t2
-        plan.execs += 1
-        d2h_bytes = sum(h.nbytes for h in host_payloads) + sum(
-            h.nbytes for h in host_scales
-        )
-        self._record_op_stats({
-            "op": "plan_allreduce",
-            "wire": wire,
-            "device_pack": True,
-            "bytes": plan.bytes,
-            "wire_bytes": plan.wire_bytes,
-            # The tentpole number: the device link carried the WIRE
-            # encoding (int8 codes + scale sidecar / bf16 words), not the
-            # full-width leaves.
-            "d2h_bytes": d2h_bytes,
-            "pack": t1 - t0,   # device kernel dispatch + DMA enqueue
-            "d2h": t2 - t1,    # blocking readback of the wire buffers
-            "ring": ring_s,    # the single native call: decode+ring+unpack
-            "_buckets_json": self._plan_stats_json(plan.plan_id),
-            "py_staging_allocs": staging_allocs,
-            "plan_execs": plan.execs,
-        })
         return _unflatten(treedef, outs)
 
     def _plan_stats_json(self, plan_id: int) -> str:
@@ -2286,51 +2355,51 @@ class HostCollectives(OpStatsMixin, Collectives):
             packer = self._packers[key] = _DevicePacker(
                 leaves, exact_dtypes=True
             )
-        t0 = time.perf_counter()
-        bufs = packer.pack(leaves)
-        names = sorted(bufs)  # deterministic group order on the wire
-        for name in names:  # queue every DMA before blocking on the first
-            bufs[name].copy_to_host_async()
-        t1 = time.perf_counter()
-        host = {name: np.ascontiguousarray(np.asarray(bufs[name]))
-                for name in names}
-        t2 = time.perf_counter()
-        packed = b"".join(host[name].tobytes() for name in names)
-        nbytes = len(packed)
-        inbuf = ctypes.create_string_buffer(packed, nbytes) if nbytes else None
-        out = np.empty(max(nbytes * self._world_size, 1), dtype=np.uint8)
-        t2b = time.perf_counter()  # host staging copies are not the wire
-        _check(
-            _lib.tft_hc_allgather(
-                self._handle,
-                inbuf,
-                out.ctypes.data_as(ctypes.c_void_p),
-                nbytes,
-                timeout_ms,
-            )
-        )
-        t3 = time.perf_counter()
-        stripe_s = self._last_stripe_seconds()
-        results: List[Any] = []
-        for r in range(self._world_size):
-            offset = r * nbytes
-            member_bufs = {}
-            for name in names:
-                a = host[name]
-                member_bufs[name] = jnp.asarray(
-                    out[offset : offset + a.nbytes].view(a.dtype)
+        with self._op("allgather") as timing:
+            with timing.phase("pack"):
+                bufs = packer.pack(leaves)
+                names = sorted(bufs)  # deterministic group order on the wire
+                for name in names:  # queue every DMA before blocking on the first
+                    bufs[name].copy_to_host_async()
+            with timing.phase("d2h"):
+                host = {name: np.ascontiguousarray(np.asarray(bufs[name]))
+                        for name in names}
+            # host staging copies are not the wire
+            with timing.phase("host_copy"):
+                packed = b"".join(host[name].tobytes() for name in names)
+                nbytes = len(packed)
+                inbuf = ctypes.create_string_buffer(packed, nbytes) if nbytes else None
+                out = np.empty(max(nbytes * self._world_size, 1), dtype=np.uint8)
+            with timing.phase("ring"):
+                _check(
+                    _lib.tft_hc_allgather(
+                        self._handle,
+                        inbuf,
+                        out.ctypes.data_as(ctypes.c_void_p),
+                        nbytes,
+                        timeout_ms,
+                    )
                 )
-                offset += a.nbytes
-            results.append(_unflatten(treedef, packer.unpack(member_bufs)))
-        self._record_op_stats({
-            "op": "allgather", "bytes": nbytes,
-            # this rank's packed groups cross down once; the gathered
-            # members come back on the h2d leg
-            "d2h_bytes": nbytes,
-            "pack": t1 - t0, "d2h": t2 - t1, "host_copy": t2b - t2,
-            "ring": t3 - t2b, "h2d": time.perf_counter() - t3,
-            "stripe_s": stripe_s,
-        })
+            with timing.phase("h2d"):
+                stripe_s = self._last_stripe_seconds()
+                results: List[Any] = []
+                for r in range(self._world_size):
+                    offset = r * nbytes
+                    member_bufs = {}
+                    for name in names:
+                        a = host[name]
+                        member_bufs[name] = jnp.asarray(
+                            out[offset : offset + a.nbytes].view(a.dtype)
+                        )
+                        offset += a.nbytes
+                    results.append(_unflatten(treedef, packer.unpack(member_bufs)))
+            timing.record(
+                bytes=nbytes,
+                # this rank's packed groups cross down once; the gathered
+                # members come back on the h2d leg
+                d2h_bytes=nbytes,
+                stripe_s=stripe_s,
+            )
         return results
 
     # -- sharded (split) ops --
@@ -2400,128 +2469,124 @@ class HostCollectives(OpStatsMixin, Collectives):
         all_jax = all(_is_jax_array(l) for l in leaves)
         native_op = int(op)
 
-        t0 = time.perf_counter()
-        if all_jax:
-            key = ("rsq8" if wire == "q8" else "rs", treedef, sig)
-            packer = self._packers.get(key)
-            if packer is None:
-                packer = self._packers[key] = _DevicePacker(
-                    leaves, force_f32=(wire == "q8")
-                )
-            bufs = packer.pack(leaves)
-            names = sorted(bufs)
-            for name in names:  # queue every DMA before blocking on one
-                bufs[name].copy_to_host_async()
-            host = {}
-            for name in names:
-                arr = np.asarray(bufs[name])
-                if not arr.flags.writeable or not arr.flags.c_contiguous:
-                    arr = np.array(arr)  # ring reduces in place
-                host[name] = arr
-            groups = {str(acc): idxs for acc, idxs in packer.groups.items()}
-            was_jax = None
-        else:
-            packer = None
-            arrays = [_as_numpy(l) for l in leaves]
-            was_jax = [_is_jax_array(l) for l in leaves]
-            groups = {}
-            for i, a in enumerate(arrays):
-                if wire == "q8":
-                    acc = np.dtype(np.float32)
+        with self._op("reduce_scatter") as timing:
+            with timing.phase("d2h"):
+                if all_jax:
+                    key = ("rsq8" if wire == "q8" else "rs", treedef, sig)
+                    packer = self._packers.get(key)
+                    if packer is None:
+                        packer = self._packers[key] = _DevicePacker(
+                            leaves, force_f32=(wire == "q8")
+                        )
+                    bufs = packer.pack(leaves)
+                    names = sorted(bufs)
+                    for name in names:  # queue every DMA before blocking on one
+                        bufs[name].copy_to_host_async()
+                    host = {}
+                    for name in names:
+                        arr = np.asarray(bufs[name])
+                        if not arr.flags.writeable or not arr.flags.c_contiguous:
+                            arr = np.array(arr)  # ring reduces in place
+                        host[name] = arr
+                    groups = {str(acc): idxs for acc, idxs in packer.groups.items()}
+                    was_jax = None
                 else:
-                    acc = (a.dtype if a.dtype in _NATIVE_DTYPES
-                           else np.dtype(np.float32))
-                groups.setdefault(str(acc), []).append(i)
-            host = {
-                name: np.concatenate(
-                    [arrays[i].astype(np.dtype(name), copy=False).ravel()
-                     for i in idxs]
-                )
-                for name, idxs in groups.items()
-            }
-            names = sorted(host)
-        d2h_s = time.perf_counter() - t0
+                    packer = None
+                    arrays = [_as_numpy(l) for l in leaves]
+                    was_jax = [_is_jax_array(l) for l in leaves]
+                    groups = {}
+                    for i, a in enumerate(arrays):
+                        if wire == "q8":
+                            acc = np.dtype(np.float32)
+                        else:
+                            acc = (a.dtype if a.dtype in _NATIVE_DTYPES
+                                   else np.dtype(np.float32))
+                        groups.setdefault(str(acc), []).append(i)
+                    host = {
+                        name: np.concatenate(
+                            [arrays[i].astype(np.dtype(name), copy=False).ravel()
+                             for i in idxs]
+                        )
+                        for name, idxs in groups.items()
+                    }
+                    names = sorted(host)
 
-        t1 = time.perf_counter()
-        values: Dict[str, Any] = {}
-        counts: Dict[str, int] = {}
-        ranges: Dict[str, List[Tuple[int, int]]] = {}
-        layout: Dict[str, int] = {}
-        dtypes: Dict[str, Any] = {}
-        stripe_s: List[float] = []
-        for name in names:
-            buf = host[name]
-            count = buf.size
-            esize = 1 if wire == "q8" else buf.itemsize
-            eff = _effective_stripes(count * esize, self._stripes)
-            counts[name] = count
-            layout[name] = eff
-            dtypes[name] = buf.dtype
-            rng = self._shard_ranges(count, esize, eff)
-            ranges[name] = rng
-            shard = np.empty(sum(l for _, l in rng), dtype=buf.dtype)
-            if self._world_size == 1:
-                shard[:] = buf
-            elif wire == "q8":
-                _check(
-                    _lib.tft_hc_reduce_scatter_q8(
-                        self._handle,
-                        buf.ctypes.data_as(ctypes.c_void_p),
-                        count,
-                        shard.ctypes.data_as(ctypes.c_void_p),
-                        1 if grid_shard else 0,
-                        eff,
-                        timeout_ms,
-                    )
-                )
-            else:
-                _check(
-                    _lib.tft_hc_reduce_scatter(
-                        self._handle,
-                        buf.ctypes.data_as(ctypes.c_void_p),
-                        count,
-                        _NATIVE_DTYPES[buf.dtype],
-                        native_op,
-                        shard.ctypes.data_as(ctypes.c_void_p),
-                        eff,
-                        timeout_ms,
-                    )
-                )
-            if self._world_size > 1:
-                stripe_s.extend(self._last_stripe_seconds())
-            if divisor is not None and divisor != 1:
-                shard = self._apply_divisor(shard, divisor)
-            values[name] = shard
-        ring_s = time.perf_counter() - t1
+            with timing.phase("ring"):
+                values: Dict[str, Any] = {}
+                counts: Dict[str, int] = {}
+                ranges: Dict[str, List[Tuple[int, int]]] = {}
+                layout: Dict[str, int] = {}
+                dtypes: Dict[str, Any] = {}
+                stripe_s: List[float] = []
+                for name in names:
+                    buf = host[name]
+                    count = buf.size
+                    esize = 1 if wire == "q8" else buf.itemsize
+                    eff = _effective_stripes(count * esize, self._stripes)
+                    counts[name] = count
+                    layout[name] = eff
+                    dtypes[name] = buf.dtype
+                    rng = self._shard_ranges(count, esize, eff)
+                    ranges[name] = rng
+                    shard = np.empty(sum(l for _, l in rng), dtype=buf.dtype)
+                    if self._world_size == 1:
+                        shard[:] = buf
+                    elif wire == "q8":
+                        _check(
+                            _lib.tft_hc_reduce_scatter_q8(
+                                self._handle,
+                                buf.ctypes.data_as(ctypes.c_void_p),
+                                count,
+                                shard.ctypes.data_as(ctypes.c_void_p),
+                                1 if grid_shard else 0,
+                                eff,
+                                timeout_ms,
+                            )
+                        )
+                    else:
+                        _check(
+                            _lib.tft_hc_reduce_scatter(
+                                self._handle,
+                                buf.ctypes.data_as(ctypes.c_void_p),
+                                count,
+                                _NATIVE_DTYPES[buf.dtype],
+                                native_op,
+                                shard.ctypes.data_as(ctypes.c_void_p),
+                                eff,
+                                timeout_ms,
+                            )
+                        )
+                    if self._world_size > 1:
+                        stripe_s.extend(self._last_stripe_seconds())
+                    if divisor is not None and divisor != 1:
+                        shard = self._apply_divisor(shard, divisor)
+                    values[name] = shard
 
-        t2 = time.perf_counter()
-        if all_jax:
-            import jax.numpy as jnp
+            with timing.phase("h2d"):
+                if all_jax:
+                    import jax.numpy as jnp
 
-            values = {name: jnp.asarray(v) for name, v in values.items()}
-        self._record_op_stats({
-            "op": "reduce_scatter",
-            "bytes": sum(host[n].nbytes for n in names),
-            "shard_bytes": sum(
-                np.asarray(v).nbytes for v in values.values()
-            ),
-            # q8 counts its scale sidecar (reduce-scatter runs ONE
-            # quantized phase) + the op header, like every q8 path
-            "wire_bytes": sum(
-                counts[n] + _q8_wire_overhead(
-                    layout[n], self._world_size, phases=1
-                ) if wire == "q8" else counts[n] * host[n].itemsize
-                for n in names
-            ),
-            # the full tree crosses down once (when it started on
-            # device); only the shard returns
-            "d2h_bytes": (
-                sum(host[n].nbytes for n in names) if all_jax else 0
-            ),
-            "d2h": d2h_s, "ring": ring_s,
-            "h2d": time.perf_counter() - t2,
-            "stripe_s": stripe_s,
-        })
+                    values = {name: jnp.asarray(v) for name, v in values.items()}
+            timing.record(
+                bytes=sum(host[n].nbytes for n in names),
+                shard_bytes=sum(
+                    np.asarray(v).nbytes for v in values.values()
+                ),
+                # q8 counts its scale sidecar (reduce-scatter runs ONE
+                # quantized phase) + the op header, like every q8 path
+                wire_bytes=sum(
+                    counts[n] + _q8_wire_overhead(
+                        layout[n], self._world_size, phases=1
+                    ) if wire == "q8" else counts[n] * host[n].itemsize
+                    for n in names
+                ),
+                # the full tree crosses down once (when it started on
+                # device); only the shard returns
+                d2h_bytes=(
+                    sum(host[n].nbytes for n in names) if all_jax else 0
+                ),
+                stripe_s=stripe_s,
+            )
         return TreeShard(
             values=values, counts=counts, ranges=ranges, layout=layout,
             dtypes=dtypes, groups=groups, treedef=treedef, sig=sig,
@@ -2548,94 +2613,91 @@ class HostCollectives(OpStatsMixin, Collectives):
         half the bytes; every member (including the owner) adopts the
         decoded bf16 words, so the gathered tree is still bit-identical
         across ranks."""
-        t0 = time.perf_counter()
-        out_bufs: Dict[str, np.ndarray] = {}
-        stripe_s: List[float] = []
-        wire_bytes = 0
-        d2h_bytes = 0
-        for name in sorted(shard.counts):
-            count = shard.counts[name]
-            gdtype = np.dtype(shard.dtypes[name])
-            eff = shard.layout[name]
-            if _is_jax_array(shard.values[name]):
-                d2h_bytes += np.asarray(shard.values[name]).nbytes
-            vals = np.ascontiguousarray(np.asarray(shard.values[name]))
-            if vals.dtype != gdtype:
-                vals = vals.astype(gdtype)
-            expected = sum(l for _, l in shard.ranges[name])
-            if vals.size != expected:
-                raise ValueError(
-                    f"shard group {name!r} has {vals.size} elements, layout "
-                    f"expects {expected} — pass the TreeShard from "
-                    "reduce_scatter (values replaced, layout intact)"
-                )
-            wdtype = gdtype
-            if wire == "bf16":
-                if gdtype == np.dtype(np.float32):
-                    wdtype = _BF16
-                elif gdtype != _BF16:
-                    raise ValueError(
-                        "wire='bf16' applies to f32/bf16 groups only"
-                    )
-            wvals = np.ascontiguousarray(vals.astype(wdtype, copy=False))
-            full = np.empty(count, dtype=wdtype)
-            if self._world_size == 1:
-                full[:] = wvals
-            else:
-                _check(
-                    _lib.tft_hc_allgather_into(
-                        self._handle,
-                        wvals.ctypes.data_as(ctypes.c_void_p),
-                        full.ctypes.data_as(ctypes.c_void_p),
-                        count,
-                        _NATIVE_DTYPES[np.dtype(wdtype)],
-                        eff,
-                        timeout_ms,
-                    )
-                )
-                stripe_s.extend(self._last_stripe_seconds())
-            wire_bytes += count * np.dtype(wdtype).itemsize
-            if np.dtype(wdtype) != gdtype:
-                full = full.astype(gdtype)
-            out_bufs[name] = full
-        ring_s = time.perf_counter() - t0
+        with self._op("allgather_into") as timing:
+            with timing.phase("ring"):
+                out_bufs: Dict[str, np.ndarray] = {}
+                stripe_s: List[float] = []
+                wire_bytes = 0
+                d2h_bytes = 0
+                for name in sorted(shard.counts):
+                    count = shard.counts[name]
+                    gdtype = np.dtype(shard.dtypes[name])
+                    eff = shard.layout[name]
+                    if _is_jax_array(shard.values[name]):
+                        d2h_bytes += np.asarray(shard.values[name]).nbytes
+                    vals = np.ascontiguousarray(np.asarray(shard.values[name]))
+                    if vals.dtype != gdtype:
+                        vals = vals.astype(gdtype)
+                    expected = sum(l for _, l in shard.ranges[name])
+                    if vals.size != expected:
+                        raise ValueError(
+                            f"shard group {name!r} has {vals.size} elements, layout "
+                            f"expects {expected} — pass the TreeShard from "
+                            "reduce_scatter (values replaced, layout intact)"
+                        )
+                    wdtype = gdtype
+                    if wire == "bf16":
+                        if gdtype == np.dtype(np.float32):
+                            wdtype = _BF16
+                        elif gdtype != _BF16:
+                            raise ValueError(
+                                "wire='bf16' applies to f32/bf16 groups only"
+                            )
+                    wvals = np.ascontiguousarray(vals.astype(wdtype, copy=False))
+                    full = np.empty(count, dtype=wdtype)
+                    if self._world_size == 1:
+                        full[:] = wvals
+                    else:
+                        _check(
+                            _lib.tft_hc_allgather_into(
+                                self._handle,
+                                wvals.ctypes.data_as(ctypes.c_void_p),
+                                full.ctypes.data_as(ctypes.c_void_p),
+                                count,
+                                _NATIVE_DTYPES[np.dtype(wdtype)],
+                                eff,
+                                timeout_ms,
+                            )
+                        )
+                        stripe_s.extend(self._last_stripe_seconds())
+                    wire_bytes += count * np.dtype(wdtype).itemsize
+                    if np.dtype(wdtype) != gdtype:
+                        full = full.astype(gdtype)
+                    out_bufs[name] = full
 
-        t1 = time.perf_counter()
-        if shard.packer is not None:
-            import jax.numpy as jnp
+            with timing.phase("h2d"):
+                if shard.packer is not None:
+                    import jax.numpy as jnp
 
-            dev = {name: jnp.asarray(b) for name, b in out_bufs.items()}
-            out = _unflatten(shard.treedef, shard.packer.unpack(dev))
-        else:
-            out_leaves: List[Any] = [None] * len(shard.sig)
-            for name, idxs in shard.groups.items():
-                buf = out_bufs[name]
-                off = 0
-                for i in idxs:
-                    shape, dt = shard.sig[i]
-                    n = int(np.prod(shape)) if shape else 1
-                    leaf = buf[off:off + n].reshape(shape).astype(
-                        dt, copy=False
-                    )
-                    off += n
-                    if shard.was_jax is not None and shard.was_jax[i]:
-                        import jax.numpy as jnp
+                    dev = {name: jnp.asarray(b) for name, b in out_bufs.items()}
+                    out = _unflatten(shard.treedef, shard.packer.unpack(dev))
+                else:
+                    out_leaves: List[Any] = [None] * len(shard.sig)
+                    for name, idxs in shard.groups.items():
+                        buf = out_bufs[name]
+                        off = 0
+                        for i in idxs:
+                            shape, dt = shard.sig[i]
+                            n = int(np.prod(shape)) if shape else 1
+                            leaf = buf[off:off + n].reshape(shape).astype(
+                                dt, copy=False
+                            )
+                            off += n
+                            if shard.was_jax is not None and shard.was_jax[i]:
+                                import jax.numpy as jnp
 
-                        leaf = jnp.asarray(leaf)
-                    out_leaves[i] = leaf
-            out = _unflatten(shard.treedef, out_leaves)
-        self._record_op_stats({
-            "op": "allgather_into",
-            "bytes": sum(b.nbytes for b in out_bufs.values()),
-            "wire_bytes": wire_bytes,
-            # only this rank's (updated) shard crosses down; the full
-            # gathered tree returns on the h2d leg
-            "d2h_bytes": d2h_bytes,
-            "ring": ring_s,
-            "h2d": time.perf_counter() - t1,
-            "stripe_s": stripe_s,
-        })
-        return out
+                                leaf = jnp.asarray(leaf)
+                            out_leaves[i] = leaf
+                    out = _unflatten(shard.treedef, out_leaves)
+            timing.record(
+                bytes=sum(b.nbytes for b in out_bufs.values()),
+                wire_bytes=wire_bytes,
+                # only this rank's (updated) shard crosses down; the full
+                # gathered tree returns on the h2d leg
+                d2h_bytes=d2h_bytes,
+                stripe_s=stripe_s,
+            )
+            return out
 
     def plan_reduce_scatter(
         self,
@@ -2706,63 +2768,59 @@ class HostCollectives(OpStatsMixin, Collectives):
                 "weights — the DiLoCo sharded-outer constraint — or use "
                 "the fused plan path)"
             )
-        t0 = time.perf_counter()
-        staging_allocs = 0
-        refs = []  # keep host views alive across the native call
-        in_ptrs = plan.in_ptrs
-        all_jax = True
-        for i, l in enumerate(leaves):
-            a = np.asarray(l)  # zero-copy for numpy / CPU jax leaves
-            if not a.flags.c_contiguous:
-                a = np.ascontiguousarray(a)
-                staging_allocs += 1
-            refs.append(a)
-            in_ptrs[i] = a.ctypes.data
-            all_jax = all_jax and _is_jax_array(l)
-        t1 = time.perf_counter()
-        # Shards double-buffer like plan outputs: the caller may still
-        # hold step k's shard while step k+1 reduces; older shards are
-        # clobbered.
-        shard_buf = plan.shard_sets[plan.shard_flip]
-        plan.shard_flip ^= 1
-        _check(
-            _lib.tft_plan_execute_rs(
-                self._handle,
-                plan.plan_id,
-                in_ptrs,
-                shard_buf.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-                float(divisor if divisor is not None else 1.0),
-                0 if divisor is None else 1,
-                timeout_ms,
-            )
-        )
-        ring_s = time.perf_counter() - t1
-        del refs
-        plan.execs += 1
-        t2 = time.perf_counter()
-        values: Dict[str, Any] = {"float32": shard_buf}
-        if all_jax:
-            import jax.numpy as jnp
+        # Its own op key: the grad leg bills separately from the param leg
+        # (and from any fused plan op) in pop_op_stats.
+        with self._op("plan_reduce_scatter") as timing:
+            with timing.phase("d2h"):
+                staging_allocs = 0
+                refs = []  # keep host views alive across the native call
+                in_ptrs = plan.in_ptrs
+                all_jax = True
+                for i, l in enumerate(leaves):
+                    a = np.asarray(l)  # zero-copy for numpy / CPU jax leaves
+                    if not a.flags.c_contiguous:
+                        a = np.ascontiguousarray(a)
+                        staging_allocs += 1
+                    refs.append(a)
+                    in_ptrs[i] = a.ctypes.data
+                    all_jax = all_jax and _is_jax_array(l)
+            with timing.phase("ring"):
+                # Shards double-buffer like plan outputs: the caller may still
+                # hold step k's shard while step k+1 reduces; older shards are
+                # clobbered.
+                shard_buf = plan.shard_sets[plan.shard_flip]
+                plan.shard_flip ^= 1
+                _check(
+                    _lib.tft_plan_execute_rs(
+                        self._handle,
+                        plan.plan_id,
+                        in_ptrs,
+                        shard_buf.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+                        float(divisor if divisor is not None else 1.0),
+                        0 if divisor is None else 1,
+                        timeout_ms,
+                    )
+                )
+            del refs
+            plan.execs += 1
+            with timing.phase("h2d"):
+                values: Dict[str, Any] = {"float32": shard_buf}
+                if all_jax:
+                    import jax.numpy as jnp
 
-            values = {"float32": jnp.asarray(shard_buf)}
-        self._record_op_stats({
-            # Its own phase key: the grad leg bills separately from the
-            # param leg (and from any fused plan op) in pop_op_stats.
-            "op": "plan_reduce_scatter",
-            "wire": wire,
-            "bytes": plan.bytes,
-            "shard_bytes": plan.shard_count * 4,
-            "wire_bytes": plan.rs_wire_bytes,
-            # the full tree crosses down once (when it started on
-            # device); only the shard returns
-            "d2h_bytes": plan.bytes if all_jax else 0,
-            "d2h": t1 - t0,
-            "ring": ring_s,
-            "h2d": time.perf_counter() - t2,
-            "_buckets_json": self._plan_stats_json(plan.plan_id),
-            "py_staging_allocs": staging_allocs,
-            "plan_execs": plan.execs,
-        })
+                    values = {"float32": jnp.asarray(shard_buf)}
+            timing.record(
+                wire=wire,
+                bytes=plan.bytes,
+                shard_bytes=plan.shard_count * 4,
+                wire_bytes=plan.rs_wire_bytes,
+                # the full tree crosses down once (when it started on
+                # device); only the shard returns
+                d2h_bytes=plan.bytes if all_jax else 0,
+                _buckets_json=self._plan_stats_json(plan.plan_id),
+                py_staging_allocs=staging_allocs,
+                plan_execs=plan.execs,
+            )
         return TreeShard(
             values=values,
             counts={"float32": plan.total},
@@ -2814,62 +2872,58 @@ class HostCollectives(OpStatsMixin, Collectives):
                 "pass the TreeShard from plan_reduce_scatter (values "
                 "replaced, layout intact)"
             )
-        t0 = time.perf_counter()
-        d2h_bytes = 0
-        if _is_jax_array(vals):
-            d2h_bytes = np.asarray(vals).nbytes
-        v = np.ascontiguousarray(np.asarray(vals))
-        if v.dtype != np.dtype(np.float32):
-            v = v.astype(np.float32)
-        if v.size != plan.shard_count:
-            raise ValueError(
-                f"shard has {v.size} elements, the plan's layout expects "
-                f"{plan.shard_count} — pass the TreeShard from "
-                "plan_reduce_scatter (values replaced, layout intact)"
-            )
-        t1 = time.perf_counter()
-        outs = plan.out_sets[plan.flip]
-        out_ptrs = plan.out_ptrs[plan.flip]
-        plan.flip ^= 1
-        _check(
-            _lib.tft_plan_execute_ag(
-                self._handle,
-                plan.plan_id,
-                v.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-                out_ptrs,
-                timeout_ms,
-            )
-        )
-        ring_s = time.perf_counter() - t1
-        plan.execs += 1
-        t2 = time.perf_counter()
-        out_leaves: List[Any] = []
-        for i in range(len(plan.sig)):
-            leaf: Any = outs[i]
-            if shard.was_jax is not None and shard.was_jax[i]:
-                import jax.numpy as jnp
+        # The param leg's own op key, billed at the AG wire. Its buckets
+        # (leg=2) append after the grad leg's (leg=1) in the plan's stat
+        # window, so the pair reads as one step.
+        with self._op("plan_allgather_into") as timing:
+            with timing.phase("d2h"):
+                d2h_bytes = 0
+                if _is_jax_array(vals):
+                    d2h_bytes = np.asarray(vals).nbytes
+                v = np.ascontiguousarray(np.asarray(vals))
+                if v.dtype != np.dtype(np.float32):
+                    v = v.astype(np.float32)
+                if v.size != plan.shard_count:
+                    raise ValueError(
+                        f"shard has {v.size} elements, the plan's layout expects "
+                        f"{plan.shard_count} — pass the TreeShard from "
+                        "plan_reduce_scatter (values replaced, layout intact)"
+                    )
+            with timing.phase("ring"):
+                outs = plan.out_sets[plan.flip]
+                out_ptrs = plan.out_ptrs[plan.flip]
+                plan.flip ^= 1
+                _check(
+                    _lib.tft_plan_execute_ag(
+                        self._handle,
+                        plan.plan_id,
+                        v.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+                        out_ptrs,
+                        timeout_ms,
+                    )
+                )
+            plan.execs += 1
+            with timing.phase("h2d"):
+                out_leaves: List[Any] = []
+                for i in range(len(plan.sig)):
+                    leaf: Any = outs[i]
+                    if shard.was_jax is not None and shard.was_jax[i]:
+                        import jax.numpy as jnp
 
-                leaf = jnp.asarray(leaf)
-            out_leaves.append(leaf)
-        out = _unflatten(shard.treedef, out_leaves)
-        self._record_op_stats({
-            # The param leg's own phase key, billed at the AG wire. Its
-            # buckets (leg=2) append after the grad leg's (leg=1) in the
-            # plan's stat window, so the pair reads as one step.
-            "op": "plan_allgather_into",
-            "wire": wire,
-            "bytes": plan.bytes,
-            "wire_bytes": plan.ag_wire_bytes,
-            # only this rank's (updated) shard crosses down; the full
-            # gathered tree returns on the h2d leg
-            "d2h_bytes": d2h_bytes,
-            "d2h": t1 - t0,
-            "ring": ring_s,
-            "h2d": time.perf_counter() - t2,
-            "_buckets_json": self._plan_stats_json(plan.plan_id),
-            "plan_execs": plan.execs,
-        })
-        return out
+                        leaf = jnp.asarray(leaf)
+                    out_leaves.append(leaf)
+                out = _unflatten(shard.treedef, out_leaves)
+            timing.record(
+                wire=wire,
+                bytes=plan.bytes,
+                wire_bytes=plan.ag_wire_bytes,
+                # only this rank's (updated) shard crosses down; the full
+                # gathered tree returns on the h2d leg
+                d2h_bytes=d2h_bytes,
+                _buckets_json=self._plan_stats_json(plan.plan_id),
+                plan_execs=plan.execs,
+            )
+            return out
 
     def broadcast(self, tree: Any, root: int = 0) -> Work:
         timeout_ms = _ms(self._timeout)

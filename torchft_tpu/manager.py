@@ -241,6 +241,8 @@ class Manager:
         self._participating_rank: Optional[int] = None
         self._participating_world_size: int = 0
         self._metrics = Metrics()
+        # sampled only when a call really blocks (wait_quorum, Work.wait)
+        self._metrics.declare("quorum_wait", "work_wait")
         # Last measured effective wire throughput (MB/s), updated by
         # observe_op_stats(); None until a ring op has been observed.
         self._last_wire_eff_mbps: Optional[float] = None
@@ -346,6 +348,9 @@ class Manager:
         """
         if self._profiler is not None:
             self._profiler.on_step(self._step)
+        # every torchft::* span of this step, on whichever thread, carries
+        # the step it began on
+        self._metrics.step = self._collectives.trace_step = self._step
         if self._quorum_future is not None:
             # Wait for the previous quorum (and any healing) to finish. Its
             # errors were already surfaced through allreduce/should_commit;
@@ -380,7 +385,14 @@ class Manager:
         assert (
             self._quorum_future is not None
         ), "must call start_quorum before wait_quorum"
-        self._quorum_future.result()
+        if self._quorum_future.done():  # settled: nobody is held
+            self._quorum_future.result()
+            return
+        # how long the CALLER was held by the quorum (a step asks several
+        # times; only the call that blocks is a sample); the RPC itself is
+        # `quorum`, on the quorum thread
+        with self._metrics.timed("quorum_wait"):
+            self._quorum_future.result()
 
     def _async_quorum(
         self, allow_heal: bool, shrink_only: bool, quorum_timeout: timedelta
@@ -392,7 +404,7 @@ class Manager:
             force_reconfigure = self._force_reconfigure
             self._force_reconfigure = False
         try:
-            with self._metrics.timed("quorum"), span("torchft::quorum"):
+            with self._metrics.timed("quorum"):
                 result = self._client.quorum(
                     rank=self._rank,
                     step=self._step,
@@ -482,9 +494,7 @@ class Manager:
             cfg_kwargs: Dict[str, Any] = {"regions": regions or None}
             if hosts and any(hosts) and self._configure_takes_hosts():
                 cfg_kwargs["hosts"] = hosts
-            with self._metrics.timed("reconfigure"), span(
-                "torchft::reconfigure"
-            ):
+            with self._metrics.timed("reconfigure"):
                 self._collectives.configure(
                     prefix, result.replica_rank, result.replica_world_size,
                     **cfg_kwargs,
@@ -523,7 +533,7 @@ class Manager:
                 self._logger.info(
                     f"peers need recovery from us {result.recover_dst_ranks}"
                 )
-                with span("torchft::send_checkpoint"):
+                with self._metrics.timed("send_checkpoint"):
                     self._checkpoint_transport.send_checkpoint(
                         dst_ranks=result.recover_dst_ranks,
                         step=result.max_step,
@@ -552,9 +562,7 @@ class Manager:
                     self._rank, timeout=self._timeout
                 )
                 assert result.recover_src_rank is not None
-                with self._metrics.timed("heal_fetch"), span(
-                    "torchft::recv_checkpoint"
-                ):
+                with self._metrics.timed("heal_fetch"):
                     checkpoint = self._checkpoint_transport.recv_checkpoint(
                         src_rank=result.recover_src_rank,
                         metadata=checkpoint_metadata,
@@ -994,7 +1002,7 @@ class Manager:
                     lambda l: l * 0 if hasattr(l, "__mul__") else l, tree
                 )
             t0 = time.perf_counter()
-            with span(f"torchft::{op_name}_dispatch"):
+            with span(f"torchft::{op_name}_dispatch", self._step):
                 work = dispatch(tree)
             work.add_done_callback(
                 lambda _f: self._metrics.record(
@@ -1037,7 +1045,7 @@ class Manager:
                     out.set_result(f.result())
 
             timed._future.add_done_callback(on_done)
-            return Work(out)
+            return Work(out, self._metrics)
 
         wrapped = swallow()
         self._pending_work.append(wrapped)
@@ -1101,7 +1109,7 @@ class Manager:
             self._errored is None
             and self.num_participants() >= self._min_replica_size
         )
-        with self._metrics.timed("commit_vote"), span("torchft::commit_vote"):
+        with self._metrics.timed("commit_vote"):
             should_commit = self._client.should_commit(
                 self._rank,
                 self._step,

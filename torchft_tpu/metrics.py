@@ -11,6 +11,10 @@ dashboards, or tests::
     manager.metrics().snapshot()
     # {"counters": {"commits": 98, "aborts": 2, "heals": 1, ...},
     #  "timers_s": {"quorum": {"n":100,"p50":0.0012,"p90":0.003,...}, ...}}
+
+``Metrics.timed(name)`` is the step path's span primitive: one ``with``
+records the timer ``name`` here and shows as ``torchft::<name>`` in an
+active profiler capture (profiling.py), stamped with ``Metrics.step``.
 """
 
 from __future__ import annotations
@@ -18,7 +22,9 @@ from __future__ import annotations
 import threading
 import time
 from collections import defaultdict, deque
-from typing import Any, Dict
+from typing import Any, Dict, Optional
+
+from .profiling import span
 
 
 class _Timer:
@@ -97,6 +103,9 @@ class Metrics:
         self._counters: Dict[str, int] = defaultdict(int)
         self._timers: Dict[str, _Timer] = {}
         self._events: Dict[str, _EventWindow] = {}
+        # the owner's step (the Manager keeps it current): the stat that
+        # pairs a ``timed`` span on any thread with the trainer's step
+        self.step: Optional[int] = None
 
     def incr(self, name: str, by: int = 1) -> None:
         with self._lock:
@@ -126,7 +135,17 @@ class Metrics:
             window = self._events.get(name)
             return 0.0 if window is None else window.rate_per_min(window_s)
 
+    def declare(self, *names: str) -> None:
+        """Creates the timers ``names`` empty, so that a region which has
+        not run yet reads ``{"n": 0}`` in the snapshot instead of being
+        absent (a wait that never had to wait is a finding, not a gap)."""
+        with self._lock:
+            for name in names:
+                self._timers.setdefault(name, _Timer())
+
     def timed(self, name: str) -> "_TimedBlock":
+        """One timed region, two sinks: the timer ``name`` and the
+        profiler span ``torchft::<name>``, over the same statements."""
         return _TimedBlock(self, name)
 
     def snapshot(self) -> Dict[str, Any]:
@@ -148,8 +167,12 @@ class _TimedBlock:
         self._name = name
 
     def __enter__(self) -> "_TimedBlock":
+        self._span = span("torchft::" + self._name, self._metrics.step)
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: object) -> None:
-        self._metrics.record(self._name, time.perf_counter() - self._t0)
+        seconds = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self._metrics.record(self._name, seconds)

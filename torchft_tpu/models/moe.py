@@ -211,12 +211,15 @@ def moe_layer(
 def _block(
     cfg: MoEConfig, i: int, p: Dict[str, Any], x: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
-    x = x + _attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"]))
-    h = _rmsnorm(x, p["ln2"]["scale"])
-    if cfg.is_moe_block(i):
-        y, aux = moe_layer(cfg, p["moe"], h)
-        return x + y, aux
-    return x + mlp_apply(cfg, p["mlp"], h), jnp.float32(0.0)
+    # the dense family's scope names (transformer._block): metadata only
+    with jax.named_scope("attn"):
+        x = x + _attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"]))
+    with jax.named_scope("mlp"):
+        h = _rmsnorm(x, p["ln2"]["scale"])
+        if cfg.is_moe_block(i):
+            y, aux = moe_layer(cfg, p["moe"], h)
+            return x + y, aux
+        return x + mlp_apply(cfg, p["mlp"], h), jnp.float32(0.0)
 
 
 def forward(

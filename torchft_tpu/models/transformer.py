@@ -191,8 +191,9 @@ def embed_tokens(
 ) -> jax.Array:
     """(B, S) int32 -> (B, S, D) activations in cfg.dtype."""
     S = tokens.shape[1]
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    return x + params["pos_embed"].astype(cfg.dtype)[:S]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        return x + params["pos_embed"].astype(cfg.dtype)[:S]
 
 
 def readout(
@@ -200,8 +201,9 @@ def readout(
 ) -> jax.Array:
     """Final norm + weight-tied readout; f32 logits for a stable
     softmax."""
-    x = _rmsnorm(x, params["ln_f"]["scale"])
-    return (x @ params["embed"].astype(cfg.dtype).T).astype(jnp.float32)
+    with jax.named_scope("readout"):
+        x = _rmsnorm(x, params["ln_f"]["scale"])
+        return (x @ params["embed"].astype(cfg.dtype).T).astype(jnp.float32)
 
 
 def mlp_apply(
@@ -212,9 +214,10 @@ def mlp_apply(
 
 
 def next_token_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return -jnp.mean(ll)
 
 
 def attn_sublayer_specs() -> Dict[str, Any]:
@@ -331,8 +334,14 @@ def _attention_impl(cfg: TransformerConfig, p: Dict[str, Any], x: jax.Array) -> 
 
 
 def _block(cfg: TransformerConfig, p: Dict[str, Any], x: jax.Array) -> jax.Array:
-    x = x + _attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"]))
-    return x + mlp_apply(cfg, p["mlp"], _rmsnorm(x, p["ln2"]["scale"]))
+    # Scopes are metadata: they name the operations in a device trace
+    # (``attn``, ``mlp``; ``embed``, ``readout`` and ``loss`` in their own
+    # functions) and change no instruction. JAX wraps the backward pass's
+    # copy of each in ``transpose(jvp(...))``.
+    with jax.named_scope("attn"):
+        x = x + _attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"]))
+    with jax.named_scope("mlp"):
+        return x + mlp_apply(cfg, p["mlp"], _rmsnorm(x, p["ln2"]["scale"]))
 
 
 def remat_wrap(cfg: TransformerConfig, fn, static_argnums=(0,)):
@@ -399,15 +408,19 @@ def make_train_step(
             loss, grads = jax.value_and_grad(
                 lambda p: loss_fn(cfg, p, tokens)
             )(compute_params)
-            # master update in f32 regardless of wire/grad dtype
-            grads = jax.tree_util.tree_map(
-                lambda g, m: g.astype(m.dtype), grads, params
-            )
         else:
             loss, grads = jax.value_and_grad(
                 lambda p: loss_fn(cfg, p, tokens)
             )(params)
-        updates, new_opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), new_opt_state, loss
+        # the same scope as train_state.make_apply_fn's separate program,
+        # so a device trace splits the fused step the same way
+        with jax.named_scope("optimizer"):
+            if bf16_params:
+                # master update in f32 regardless of wire/grad dtype
+                grads = jax.tree_util.tree_map(
+                    lambda g, m: g.astype(m.dtype), grads, params
+                )
+            updates, new_opt_state = tx.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), new_opt_state, loss
 
     return jax.jit(one_step, donate_argnums=(0, 1))

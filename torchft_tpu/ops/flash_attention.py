@@ -269,6 +269,7 @@ def _flash_fwd_call(
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",  # the kernel's name in a device trace
     )(q, k, v)
 
 
@@ -393,6 +394,7 @@ def _flash_bwd_call(
             jax.ShapeDtypeStruct((BH, S, D), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd",
     )(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk, dv
 

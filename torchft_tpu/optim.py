@@ -45,11 +45,14 @@ class OptimizerWrapper:
         """Votes, then applies ``grads`` iff every rank committed (reference
         optim.py:52-54). ``should_commit`` applies any pending recovery
         checkpoint into ``self.state`` first, so the update always starts
-        from the healed weights. Returns whether the step committed."""
-        if not self.manager.should_commit():
-            return False
-        self.state.apply_gradients(grads)
-        return True
+        from the healed weights. Returns whether the step committed.
+        Timed as ``optimizer_step`` (the vote and the update's dispatch;
+        the update itself runs on the device after it returns)."""
+        with self.manager.metrics().timed("optimizer_step"):
+            if not self.manager.should_commit():
+                return False
+            self.state.apply_gradients(grads)
+            return True
 
 
 class ShardedOptimizerWrapper:
@@ -97,8 +100,10 @@ class ShardedOptimizerWrapper:
 
     def step(self, grads: Any) -> bool:
         """Runs the sharded transaction for ``grads``; applies iff the
-        cohort committed. Returns whether it did."""
-        return self._core.apply_gradients(grads)
+        cohort committed. Returns whether it did. Timed as
+        ``optimizer_step``, like :meth:`OptimizerWrapper.step`."""
+        with self.manager.metrics().timed("optimizer_step"):
+            return self._core.apply_gradients(grads)
 
     @property
     def last_commit(self) -> Optional[bool]:

@@ -1,30 +1,35 @@
-"""Tracing/profiling hooks: jax profiler spans around the FT transaction.
+"""Tracing and profiling: the one span primitive of the step path, and
+the windowed profiler capture.
 
-The reference has NO tracing or profiling subsystem (SURVEY.md §5:
-"Tracing / profiling: none... Gap we may close on TPU with jax profiler
-hooks") — observability there is logs + dashboard. This module closes the
-gap the TPU-native way: the runtime's phase boundaries (quorum,
-reconfigure, allreduce dispatch, checkpoint send/recv, commit vote) are
-annotated with
-``jax.profiler.TraceAnnotation`` spans so they appear on the host track of
-a TensorBoard/XProf capture alongside XLA's device ops, and step
-boundaries with ``StepTraceAnnotation`` so XProf's step-time breakdown
-(compute vs host vs comms) works out of the box.
+The reference has no tracing subsystem (SURVEY.md section 5); this module
+is ours. A timed region of the program is written once, as one ``with``,
+and shows under one name in every sink:
 
-Capture is driven either programmatically::
+- ``span("torchft::<name>", step)`` is a ``jax.profiler.TraceAnnotation``:
+  a host event on the profiler's clock (the device trace's), carrying the
+  manager's step as the stat ``step``. With no capture active it is a
+  flag test.
+- ``Metrics.timed("<name>")`` (metrics.py) enters ``torchft::<name>`` and
+  records the same seconds under the timer ``<name>``.
+- a collective's op context (``OpStatsMixin._op``, collectives.py) enters
+  ``torchft::<op>`` with ``torchft::<op>/<phase>`` nested in it and
+  records the same seconds under the ``pop_op_stats()`` keys.
+
+The names an operator sees in an XProf capture, and the ``Metrics``
+timer or op-stats key each equals, are tabled in docs/OPERATIONS.md
+("Profiling"). On the device side the model's layers, the optimizer and
+the flash kernels carry ``jax.named_scope`` / kernel names instead
+(models/transformer.py, train_state.py, ops/flash_attention.py).
+
+A capture is started by whoever wants one: ``jax.profiler.start_trace``
+directly, or the step-windowed ``Profiler`` below::
 
     prof = Profiler(logdir="/tmp/trace", start_step=10, num_steps=5)
     manager = Manager(..., profiler=prof)   # or prof.on_step(step) by hand
 
-or zero-code via environment variables (the config surface style of the
-reference, SURVEY.md §5 config/flags)::
-
-    TORCHFT_PROFILE_DIR=/tmp/trace TORCHFT_PROFILE_START=10 \
-        TORCHFT_PROFILE_STEPS=5 python train.py
-
-``span(name)`` is safe (and near-free) when no capture is active —
-TraceAnnotation without an active session is a no-op — so the Manager
-annotates unconditionally.
+or with no code, ``TORCHFT_PROFILE_DIR=/tmp/trace TORCHFT_PROFILE_START=10
+TORCHFT_PROFILE_STEPS=5 python train.py``. ``jax.profiler`` is imported
+on first use: the launcher's parent and the lighthouse hold no JAX.
 """
 
 from __future__ import annotations
@@ -41,21 +46,17 @@ _ENV_START = "TORCHFT_PROFILE_START"
 _ENV_STEPS = "TORCHFT_PROFILE_STEPS"
 
 
-def span(name: str):
-    """Named host-track span; shows up in an active jax profiler capture.
+def span(name: str, step: Optional[int] = None):
+    """Named host-track span; shows up in an active jax profiler capture
+    under ``name``, with ``step`` (where given) as a stat of the event.
 
-    Usage: ``with span("torchft::quorum"): ...``
+    Usage: ``with span("torchft::quorum", step): ...``
     """
     import jax.profiler
 
-    return jax.profiler.TraceAnnotation(name)
-
-
-def step_span(step: int):
-    """XProf step annotation: ``with step_span(step): train_step(...)``."""
-    import jax.profiler
-
-    return jax.profiler.StepTraceAnnotation("torchft_step", step_num=step)
+    if step is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, step=step)
 
 
 class Profiler:

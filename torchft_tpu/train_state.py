@@ -16,6 +16,8 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from .profiling import span
+
 
 def _to_device_tree(tree: Any) -> Any:
     """Checkpointed leaves arrive as host numpy; rebuild jax arrays (same
@@ -37,15 +39,19 @@ def make_apply_fn(tx: Any) -> Any:
     import optax
 
     def apply(params: Any, opt_state: Any, grads: Any):
-        # Mixed-precision-friendly: grads may arrive in a lower wire/compute
-        # dtype (bf16 ring payloads, models.make_train_step(bf16_params=True));
-        # the master update always runs in the params' own (f32) dtype.
-        grads = jax.tree_util.tree_map(
-            lambda g, p: g.astype(p.dtype) if g.dtype != p.dtype else g,
-            grads, params,
-        )
-        updates, new_opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), new_opt_state
+        # the scope the device trace files the update's operations under,
+        # here and in models.make_train_step's fused program alike
+        with jax.named_scope("optimizer"):
+            # Mixed-precision-friendly: grads may arrive in a lower
+            # wire/compute dtype (bf16 ring payloads,
+            # models.make_train_step(bf16_params=True)); the master update
+            # always runs in the params' own (f32) dtype.
+            grads = jax.tree_util.tree_map(
+                lambda g, p: g.astype(p.dtype) if g.dtype != p.dtype else g,
+                grads, params,
+            )
+            updates, new_opt_state = tx.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), new_opt_state
 
     return jax.jit(apply, donate_argnums=(0, 1))
 
@@ -137,6 +143,8 @@ class FTTrainState:
         ``state_dict`` snapshot-lifetime note)."""
         if self._apply_jit is None:
             self._apply_jit = make_apply_fn(self.tx)
-        self.params, self.opt_state = self._apply_jit(
-            self.params, self.opt_state, grads
-        )
+        # dispatch only: the call returns before the device has run it
+        with span("torchft::apply_gradients"):
+            self.params, self.opt_state = self._apply_jit(
+                self.params, self.opt_state, grads
+            )
