@@ -331,6 +331,9 @@ def child_kernels() -> None:
     # other two code paths at a reduced batch: the general (windowed)
     # loop, and a ragged short sequence on the 128-wide tiles
     _check_flash("big", BATCH, SEQ - 1, 16, 64)
+    # the benchmark's shape (GPT-2: 1024 positions, 12 heads of 64): the
+    # whole sequence resident, the static causal schedule
+    _check_flash("gpt2", 2, 1024, 12, 64)
     _check_flash("head_dim128", 8, SEQ - 1, 16, 128)
     _check_flash("windowed", 2, SEQ - 1, 4, 64, window=512)
     _check_flash("ragged", 2, 99, 4, 64)

@@ -36,8 +36,22 @@ def rand_qkv(key, shape, dtype=jnp.float32):
     return tuple(jax.random.normal(k, shape, dtype) for k in ks)
 
 
+# The causal calls with block_q a multiple of block_k run the two-level
+# schedule (resident block of block_q rows, sub-tiles of block_k):
+# (64, 32) at S 128 has two blocks of two row groups (the dynamic loop over
+# the blocks to the left, a wide unmasked tile, a diagonal sub-tile);
+# (128, 32) is the whole sequence resident, all static, as on the chip at
+# S 1024; 127 and 129 end just under and just over a block multiple.
+NESTED = [
+    (128, (64, 32)), (128, (128, 32)), (127, (64, 32)), (129, (64, 32)),
+    (192, (64, 16)),
+]
+
+
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("S,blocks", [(128, (64, 64)), (96, (32, 32))])
+@pytest.mark.parametrize(
+    "S,blocks", [(128, (64, 64)), (96, (32, 32))] + NESTED
+)
 def test_forward_matches_dense(causal, S, blocks):
     q, k, v = rand_qkv(jax.random.PRNGKey(0), (2, S, 2, 32))
     out = flash_attention(
@@ -47,11 +61,13 @@ def test_forward_matches_dense(causal, S, blocks):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_grads_match_dense():
-    q, k, v = rand_qkv(jax.random.PRNGKey(1), (1, 64, 2, 16))
+@pytest.mark.parametrize("S,blocks", [(64, (32, 32))] + NESTED)
+def test_grads_match_dense(S, blocks):
+    q, k, v = rand_qkv(jax.random.PRNGKey(1), (1, S, 2, 16))
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, block_q=32, block_k=32) ** 2)
+        out = flash_attention(q, k, v, block_q=blocks[0], block_k=blocks[1])
+        return jnp.sum(out ** 2)
 
     def loss_dense(q, k, v):
         return jnp.sum(dense_attention(q, k, v) ** 2)
@@ -216,3 +232,55 @@ def test_transformer_attn_window():
             tiny_config(), use_flash=True, attn_window=16,
             cp_seq_axis="seq",
         )
+
+
+def _module():
+    import sys
+
+    # the function of the same name shadows the module in torchft_tpu.ops
+    return sys.modules["torchft_tpu.ops.flash_attention"]
+
+
+# (S, interpret) -> tiles of a causal call without a window, at either
+# head size: up to 2048 padded positions the whole sequence is resident
+# and cut into the largest sub-tile of 512 / 256 / 128 that divides it
+# (PERF.md section 6, PR 25); beyond, and on the general path, the tiles
+# of before.
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize(
+    "S,interpret,want",
+    [
+        (99, False, (128, 128)), (256, False, (256, 256)),
+        (1023, False, (1024, 512)), (1024, False, (1024, 512)),
+        (1025, False, (1152, 128)), (2047, False, (2048, 512)),
+        (2048, False, (2048, 512)), (4096, False, (512, 512)),
+        (99, True, (104, 104)), (64, True, (64, 64)),
+        (1024, True, (1024, 512)), (4096, True, (512, 512)),
+    ],
+)
+def test_auto_tiles(S, head_dim, interpret, want):
+    fa = _module()
+    assert fa._auto_tiles(S, head_dim, interpret) == want
+    # a window or a non-causal call keeps the general path and its tiles
+    general = (512, 512) if S >= 2047 else (128, 128)
+    assert fa._auto_tiles(S, head_dim, interpret, nested=False) == general
+
+
+def test_window_and_noncausal_take_the_general_kernels(monkeypatch):
+    fa = _module()
+
+    def refuse(*_args, **_kw):
+        raise AssertionError("the causal schedule ran")
+
+    monkeypatch.setattr(fa, "_fwd_causal_kernel", refuse)
+    monkeypatch.setattr(fa, "_bwd_causal_kernel", refuse)
+    q, k, v = rand_qkv(jax.random.PRNGKey(11), (1, 64, 1, 8))
+
+    def loss(q, **kw):
+        return jnp.sum(flash_attention(q, k, v, **kw) ** 2)
+
+    jax.grad(lambda q: loss(q, window=16))(q)
+    jax.grad(lambda q: loss(q, causal=False))(q)
+    jax.grad(lambda q: loss(q, block_q=16, block_k=32))(q)  # do not nest
+    with pytest.raises(AssertionError, match="causal schedule"):
+        loss(q)

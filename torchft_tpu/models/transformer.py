@@ -59,10 +59,10 @@ class TransformerConfig:
     # attention runs through the kernel. Ignored by cp_strategy="ring"
     # (that path fuses its own online-softmax loop).
     use_flash: bool = False
-    # Flash-kernel VMEM tile overrides (None = the kernel's auto sizes,
-    # ops/flash_attention.py); in-model winners can differ
-    # from standalone sweeps (fusion/VMEM interactions), so the bench
-    # tunes these against whole-step throughput.
+    # Flash-kernel VMEM tile overrides. None = ops.flash_attention's
+    # ``_auto_tiles``, measured on the v5e inside the whole gpt2-small and
+    # gpt2-medium steps (PERF.md section 6, PR 25: (1024, 512) at S 1024);
+    # that sweep ran through these two fields.
     flash_block_q: Any = None
     flash_block_k: Any = None
     # Sliding-window (local) attention width; requires use_flash (the

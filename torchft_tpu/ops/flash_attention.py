@@ -22,21 +22,36 @@ tile. The softmax scale is folded into q OUTSIDE the kernel (exact for
 power-of-two scales, e.g. head_dim 64 → 0.125), removing the per-tile
 S×S scale multiplies; autodiff of the fold rescales dq automatically.
 
-The causal path splits every tile loop into UNMASKED interior tiles plus
-one masked diagonal tile (requires block_q == block_k, the auto default):
-strictly-below-diagonal tiles are fully live, so the interior body skips
-the iota/compare/select mask passes entirely. The fast path also uses a
-finite -1e30 mask value instead of -inf, which removes every
-``isfinite`` guard from the online-softmax recurrence: with at least one
-live key per query row (guaranteed on the causal path — every row
-attends at least its own position; padded query rows attend earlier live
-keys), ``exp(-1e30 - m)`` underflows to exactly 0 and the recurrence
-needs no special cases. The backward kernels apply NO padding mask at
-all: padded k/v rows are zeros, so padded-column score/probability
-garbage contributes exactly 0 to dq (``ds @ k`` hits zero rows) and only
-to dk/dv rows that are sliced off; padded query rows carry zero
-cotangents. The general path (sliding window, unequal blocks,
-non-causal) keeps per-tile masks.
+The causal path (no window, ``block_q`` a multiple of ``block_k``) runs a
+TWO-LEVEL schedule. A grid step holds a resident block of ``block_q``
+rows - the whole sequence up to 2048 positions - cut into row groups of
+``block_k``. Inside the block every trip count is static: a row group
+meets all keys left of its diagonal in ONE wide unmasked tile and then
+its diagonal sub-tile under one constant mask, so nothing above the
+diagonal is computed beyond that sub-tile's triangle, the online-softmax
+recurrence runs at most twice a row, and the compiler sees straight-line
+code. Blocks to the left of the resident one (none when it is the whole
+sequence) run in one dynamic loop of full-width unmasked tiles. The
+backward is the same schedule with keys resident and the scores computed
+TRANSPOSED, (keys, queries): lse and delta live along lanes and
+broadcast over the key sublanes for free, and p.T @ do, ds.T @ q are
+plain matmuls; with one resident block dq leaves the kernel finished, in
+the input dtype. On the v5e at S 1024, head_dim 64 this replaced
+128 x 128 tiles in a ``while`` loop: 14.9 + 25.0 ms a gpt2-small step in
+the two kernels against 71.6 + 95.1 (PERF.md section 6, PR 25).
+
+The causal path also uses a finite -1e30 mask value instead of -inf,
+which removes every ``isfinite`` guard from the online-softmax
+recurrence: with at least one live key per query row in its first tile
+(every row attends key 0; padded query rows attend earlier live keys),
+``exp(-1e30 - m)`` underflows to exactly 0 and the recurrence needs no
+special cases. It applies NO padding mask at all: causality already
+hides a padded key from every live query; in the backward padded k/v
+rows are zeros, so padded-column score/probability garbage contributes
+exactly 0 to dq (``ds @ k`` hits zero rows) and only to dk/dv rows that
+are sliced off; padded query rows carry zero cotangents. The general
+path (sliding window, blocks that do not nest, non-causal) keeps one
+tile a loop step and per-tile masks.
 
 Design notes (pallas_guide.md):
 - all matmuls request ``preferred_element_type=float32`` so the MXU
@@ -48,9 +63,10 @@ Design notes (pallas_guide.md):
   arbitrary sequence lengths ARE padded — up to the block multiple, with
   padded keys masked in-kernel and padded queries carrying zero
   cotangents;
-- causal kernels bound their inner ``fori_loop`` by the block diagonal so
-  masked-out tiles are never computed (dynamic trip counts lower to
-  ``while_loop``).
+- the general causal kernels bound their ``fori_loop`` by the block
+  diagonal so masked-out tiles are never computed (dynamic trip counts
+  lower to ``while_loop``: nothing is unrolled or overlapped across
+  trips, which is what the two-level schedule avoids).
 
 Off-TPU the same kernels run under ``interpret=True`` so CPU tests and the
 virtual-device dryrun exercise the identical code path.
@@ -64,7 +80,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -73,7 +89,7 @@ from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
 _NEG_INF = float("-inf")
-# Finite mask value for the fast (split-diagonal) path: large enough that
+# Finite mask value for the two-level causal schedule: large enough that
 # exp(_NEG_LARGE - m) underflows to exactly 0 for any live row max m
 # (|m| <= ~1e4 in practice), small enough to stay exact in f32.
 _NEG_LARGE = -1e30
@@ -113,7 +129,7 @@ def _tile_mask(
     causal: bool, padded: bool, window: Optional[int] = None,
 ):
     """Validity mask for one (block_q, block_k) score tile, or None when
-    every position is live. Shared by the forward and both backward
+    every position is live. Shared by the general forward and backward
     kernels so the mask semantics cannot drift apart. ``window`` w keeps
     only keys with q_pos - k_pos < w (sliding-window / local attention)."""
     if not (causal or padded or window is not None):
@@ -138,11 +154,82 @@ def _tile_mask(
 # ---------------------------------------------------------------------------
 
 
-def _split_diag(causal: bool, window, block_q: int, block_k: int) -> bool:
-    """True when the tile loops may run as unmasked-interior + one masked
-    diagonal tile (see module docstring). Requires equal blocks so the
-    diagonal tile of query block qi is exactly key block qi."""
-    return causal and window is None and block_q == block_k
+def _nested(causal: bool, window, block_q: int, block_k: int) -> bool:
+    """True when the call runs the two-level causal schedule (module
+    docstring): a resident block of ``block_q`` rows cut into square
+    sub-tiles of edge ``block_k``."""
+    return causal and window is None and block_q % block_k == 0
+
+
+def _triangle(n: int, queries_first: bool):
+    """(n, n) bool causal mask of a diagonal sub-tile (its first query
+    and first key are the same position): query >= key, with queries
+    along rows or, for transposed scores, along columns."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return rows >= cols if queries_first else cols >= rows
+
+
+def _fwd_causal_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+    block_q: int, block_k: int, num_blocks: int,
+):
+    """Two-level causal forward. Grid step (bh, qi) holds ``block_q``
+    query rows as ``block_q // block_k`` row groups, each a straight line
+    of at most two tiles over the block's own keys - every key left of
+    the group's diagonal in ONE wide unmasked tile, then the diagonal
+    sub-tile under a constant mask - after one dynamic loop over the
+    key blocks left of the resident one (none when it is the whole
+    sequence). No padding mask: a padded key is only ever visible to
+    padded query rows, which are sliced off. q arrives pre-scaled."""
+    n_sub = block_q // block_k
+    D = q_ref.shape[-1]
+    # a static origin when there is one block: every slice is static
+    q0 = 0 if num_blocks == 1 else pl.program_id(1) * block_q
+    tri = _triangle(block_k, True)
+    q_rows = [q_ref[0, pl.ds(r * block_k, block_k), :] for r in range(n_sub)]
+
+    def tile(q_blk, state, k_start, width: int, masked: bool):
+        k_blk = k_ref[0, pl.ds(k_start, width), :]
+        v_blk = v_ref[0, pl.ds(k_start, width), :]
+        s = _dot_nt(q_blk, k_blk)  # (block_k, width) f32
+        if masked:
+            s = jnp.where(tri, s, _NEG_LARGE)
+        m, l, acc = state
+        # every row has a live key in its first tile (key 0), so m is
+        # finite from then on and exp(_NEG_LARGE - m) is exactly 0: no
+        # -inf guards
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        acc_new = acc * corr + _dot_f32(p.astype(v_blk.dtype), v_blk)
+        return m_new, l_new, acc_new
+
+    state = [(
+        jnp.full((block_k, 1), _NEG_LARGE, jnp.float32),
+        jnp.zeros((block_k, 1), jnp.float32),
+        jnp.zeros((block_k, D), jnp.float32),
+    )] * n_sub
+    if num_blocks > 1:
+        def interior(j, state):
+            return tuple(
+                tile(q_rows[r], state[r], j * block_q, block_q, False)
+                for r in range(n_sub)
+            )
+
+        state = list(jax.lax.fori_loop(
+            0, pl.program_id(1), interior, tuple(state)
+        ))
+    for r in range(n_sub):
+        st = state[r]
+        if r:
+            st = tile(q_rows[r], st, q0, r * block_k, False)
+        m, l, acc = tile(q_rows[r], st, q0 + r * block_k, block_k, True)
+        o_ref[0, pl.ds(r * block_k, block_k), :] = (acc / l).astype(o_ref.dtype)
+        # lse rides a full-row (1, 1, S) block revisited across the
+        # sequential qi grid dim; each row group writes its slice
+        lse_ref[0, 0, pl.ds(q0 + r * block_k, block_k)] = (m + jnp.log(l))[:, 0]
 
 
 def _fwd_kernel(
@@ -150,44 +237,32 @@ def _fwd_kernel(
     causal: bool, block_q: int, block_k: int, num_k: int,
     kv_len: int, window,
 ):
-    # q arrives PRE-SCALED by sm_scale (folded outside the kernel), so
-    # s = q @ k.T is the final score with no per-tile S x S multiply.
+    """The general forward (sliding window, non-causal, blocks that do
+    not nest): one (block_q, block_k) tile a loop step, per-tile masks.
+    q arrives PRE-SCALED by sm_scale (folded outside the kernel), so
+    s = q @ k.T is the final score with no per-tile S x S multiply."""
     qi = pl.program_id(1)
     q = q_ref[0]  # (block_q, D), input dtype
     D = q.shape[-1]
     padded = kv_len < num_k * block_k
-    fast = _split_diag(causal, window, block_q, block_k)
-    neg = _NEG_LARGE if fast else _NEG_INF
 
-    def tile(j, carry, masked: bool):
+    def tile(j, carry):
         m, l, acc = carry
         k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
         v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = _dot_nt(q, k_blk)  # (block_q, block_k) f32
-        if masked:
-            ok = _tile_mask(
-                qi * block_q, j * block_k, block_q, block_k, kv_len,
-                causal, padded, window,
-            )
-            if ok is not None:
-                s = jnp.where(ok, s, neg)
+        ok = _tile_mask(
+            qi * block_q, j * block_k, block_q, block_k, kv_len,
+            causal, padded, window,
+        )
+        if ok is not None:
+            s = jnp.where(ok, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
-        if fast:
-            # every query row has >= 1 live key (causal: its own position,
-            # or for zero-padded query rows any earlier live key), so
-            # m_new is finite after the first processed tile and the
-            # -inf/isfinite guards of the general path are dead weight:
-            # exp(_NEG_LARGE - m_new) underflows to exactly 0.
-            p = jnp.exp(s - m_new[:, None])
-            corr = jnp.exp(m - m_new)
-        else:
-            # rows with every key masked keep m = -inf; guard
-            # exp(-inf - -inf)
-            safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            p = jnp.where(
-                jnp.isfinite(s), jnp.exp(s - safe_m[:, None]), 0.0
-            )
-            corr = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
+        # rows with every key masked keep m = -inf; guard
+        # exp(-inf - -inf)
+        safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - safe_m[:, None]), 0.0)
+        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
         l_new = l * corr + p.sum(axis=-1)
         acc_new = acc * corr[:, None] + _dot_f32(
             p.astype(v_blk.dtype), v_blk
@@ -195,48 +270,32 @@ def _fwd_kernel(
         return m_new, l_new, acc_new
 
     init = (
-        jnp.full((block_q,), neg, jnp.float32),
+        jnp.full((block_q,), _NEG_INF, jnp.float32),
         jnp.zeros((block_q,), jnp.float32),
         jnp.zeros((block_q, D), jnp.float32),
     )
-    if fast:
-        # interior tiles j < qi are fully below the causal diagonal (and
-        # never reach padded key columns: cols < qi*block_k < kv_len), so
-        # they run with no mask at all; the diagonal tile j == qi carries
-        # the causal mask and (in the last row block) the padding mask.
-        m, l, acc = jax.lax.fori_loop(
-            0, qi, lambda j, c: tile(j, c, False), init
+    num_k_live = _cdiv(kv_len, block_k)  # skip fully-padded key blocks
+    if causal:
+        # key blocks strictly above the block diagonal are fully masked
+        hi = jnp.minimum(
+            num_k_live, ((qi + 1) * block_q + block_k - 1) // block_k
         )
-        m, l, acc = tile(qi, (m, l, acc), True)
     else:
-        num_k_live = _cdiv(kv_len, block_k)  # skip fully-padded key blocks
-        if causal:
-            # key blocks strictly above the block diagonal are fully masked
-            hi = jnp.minimum(
-                num_k_live, ((qi + 1) * block_q + block_k - 1) // block_k
-            )
-        else:
-            hi = num_k_live
-        lo = 0
-        if window is not None:
-            # key blocks fully left of the sliding window are masked for
-            # every query row in this block
-            lo = jnp.maximum(0, (qi * block_q - window + 1) // block_k)
-        m, l, acc = jax.lax.fori_loop(
-            lo, hi, lambda j, c: tile(j, c, True), init
-        )
+        hi = num_k_live
+    lo = 0
+    if window is not None:
+        # key blocks fully left of the sliding window are masked for
+        # every query row in this block
+        lo = jnp.maximum(0, (qi * block_q - window + 1) // block_k)
+    m, l, acc = jax.lax.fori_loop(lo, hi, tile, init)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     # lse rides a full-row (1, 1, S) block revisited across the sequential
     # qi grid dim (a (1, block_q) 2D block violates Mosaic's (8, 128) tile
     # floor); each step writes its slice
-    if fast:
-        # m is finite for every row (see tile()); no -inf bookkeeping
-        lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = m + jnp.log(l_safe)
-    else:
-        lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = jnp.where(
-            jnp.isfinite(m), m + jnp.log(l_safe), _NEG_INF
-        )
+    lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = jnp.where(
+        jnp.isfinite(m), m + jnp.log(l_safe), _NEG_INF
+    )
 
 
 def _flash_fwd_call(
@@ -249,11 +308,17 @@ def _flash_fwd_call(
     out of every softmax."""
     BH, S, D = q.shape
     num_q, num_k = _cdiv(S, block_q), _cdiv(S, block_k)
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal,
-        block_q=block_q, block_k=block_k, num_k=num_k, kv_len=kv_len,
-        window=window,
-    )
+    if _nested(causal, window, block_q, block_k):
+        kernel = functools.partial(
+            _fwd_causal_kernel, block_q=block_q, block_k=block_k,
+            num_blocks=num_q,
+        )
+    else:
+        kernel = functools.partial(
+            _fwd_kernel, causal=causal,
+            block_q=block_q, block_k=block_k, num_k=num_k, kv_len=kv_len,
+            window=window,
+        )
     row = pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0))
     qspec = pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0))
     return pl.pallas_call(
@@ -278,26 +343,109 @@ def _flash_fwd_call(
 # ---------------------------------------------------------------------------
 
 
+def _bwd_causal_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dq_ref, dk_ref, dv_ref, *,
+    block_q: int, block_k: int, num_blocks: int,
+):
+    """Two-level causal backward, the forward's schedule with the roles
+    swapped: grid step (bh, ki) holds ``block_q`` KEY rows as sub-blocks
+    of ``block_k``; each meets its diagonal query sub-tile under the
+    constant mask, then every other query of the block in ONE wide
+    unmasked tile, then the query blocks below in one dynamic loop.
+
+    Scores are computed TRANSPOSED, (keys, queries): lse and delta live
+    along lanes and broadcast over the key sublanes for free, and
+    p.T @ do, ds.T @ q are plain matmuls. No padding mask (see
+    ``_flash_bwd_call``)."""
+    n_sub = block_q // block_k
+    one_block = num_blocks == 1
+    k0 = 0 if one_block else pl.program_id(1) * block_q
+    tri_t = _triangle(block_k, False)
+    k_rows = [k_ref[0, pl.ds(c * block_k, block_k), :] for c in range(n_sub)]
+    v_rows = [v_ref[0, pl.ds(c * block_k, block_k), :] for c in range(n_sub)]
+
+    def tile(c: int, q_start, width: int, masked: bool):
+        """(dk, dv) of key sub-block c and the (width, D) dq of the
+        queries [q_start, q_start + width) from their meeting."""
+        q_blk = q_ref[0, pl.ds(q_start, width), :]
+        do_blk = do_ref[0, pl.ds(q_start, width), :]
+        lse = lse_ref[0, :, pl.ds(q_start, width)]  # (1, width)
+        delta = delta_ref[0, :, pl.ds(q_start, width)]
+        s_t = _dot_nt(k_rows[c], q_blk)  # (keys, queries); q pre-scaled
+        p_t = jnp.exp(s_t - lse)
+        if masked:
+            p_t = jnp.where(tri_t, p_t, 0.0)
+        dv = _dot_f32(p_t.astype(do_blk.dtype), do_blk)
+        dp_t = _dot_nt(v_rows[c], do_blk)
+        ds_t = (p_t * (dp_t - delta)).astype(q_blk.dtype)  # one cast,
+        dk = _dot_f32(ds_t, q_blk)                          # used twice
+        return dk, dv, _dot_tn(ds_t, k_rows[c])
+
+    zeros = jnp.zeros((block_k, q_ref.shape[-1]), jnp.float32)
+    dks, dvs = [zeros] * n_sub, [zeros] * n_sub
+    if not one_block:
+        # dq accumulates into a REVISITED full-row f32 output block: the
+        # TPU grid is sequential, so every ki step of one bh row sees the
+        # same resident VMEM block; zero it on the first step.
+        @pl.when(pl.program_id(1) == 0)
+        def _init_dq():
+            dq_ref[...] = jnp.zeros_like(dq_ref)
+
+        def below(i, carry):
+            dks, dvs = carry
+            out_k, out_v, dq = [], [], 0.0
+            for c in range(n_sub):
+                dk, dv, dq_c = tile(c, i * block_q, block_q, False)
+                out_k.append(dks[c] + dk)
+                out_v.append(dvs[c] + dv)
+                dq = dq + dq_c
+            dq_ref[0, pl.ds(i * block_q, block_q), :] += dq
+            return tuple(out_k), tuple(out_v)
+
+        dks, dvs = map(list, jax.lax.fori_loop(
+            pl.program_id(1) + 1, num_blocks, below, (tuple(dks), tuple(dvs)),
+        ))
+    dqs = [0.0] * n_sub  # of the block's own query row groups, f32
+    for c in range(n_sub):
+        # the diagonal sub-tile, then every query of the block below it
+        for first, count in ((c, 1), (c + 1, n_sub - 1 - c)):
+            if not count:
+                continue
+            dk, dv, dq = tile(
+                c, k0 + first * block_k, count * block_k, first == c
+            )
+            dks[c], dvs[c] = dks[c] + dk, dvs[c] + dv
+            for r in range(count):
+                dqs[first + r] = (
+                    dqs[first + r] + dq[r * block_k:(r + 1) * block_k]
+                )
+    for r in range(n_sub):
+        rows = pl.ds(k0 + r * block_k, block_k)
+        if one_block:  # every key of the row is here: dq is final
+            dq_ref[0, rows, :] = dqs[r].astype(dq_ref.dtype)
+        else:
+            dq_ref[0, rows, :] += dqs[r]
+        dk_ref[0, pl.ds(r * block_k, block_k), :] = dks[r].astype(dk_ref.dtype)
+        dv_ref[0, pl.ds(r * block_k, block_k), :] = dvs[r].astype(dv_ref.dtype)
+
+
 def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, *,
     causal: bool, block_q: int, block_k: int, num_q: int,
     kv_len: int, window,
 ):
+    """The general backward: one (block_q, block_k) tile a loop step."""
     ki = pl.program_id(1)
     k_blk = k_ref[0]  # (block_k, D), input dtype
     v_blk = v_ref[0]
     D = k_blk.shape[-1]
     # Padded QUERY rows need no mask here: their cotangent (do) and delta
-    # are zero, so ds and p.T @ do vanish (their lse is finite on both
-    # paths — causal padded query rows attend earlier live keys — so p
-    # stays finite and 0 * p cannot produce NaN). On the general path,
-    # padded KEY columns are masked; the fast path drops that mask too:
-    # p/ds garbage in padded columns lands only in dk/dv ROWS that the
-    # caller slices off (each dk/dv row is a column-wise independent sum),
-    # so masking them buys nothing.
+    # are zero, so ds and p.T @ do vanish (their lse is finite — causal
+    # padded query rows attend earlier live keys — so p stays finite and
+    # 0 * p cannot produce NaN). Padded KEY columns are masked.
     padded = kv_len < q_ref.shape[1]  # static: S_pad > kv_len
-    fast = _split_diag(causal, window, block_q, block_k)
 
     # dq accumulates into a REVISITED full-row f32 output block: the TPU
     # grid is sequential, so every ki step of one bh row sees the same
@@ -306,7 +454,7 @@ def _bwd_kernel(
     def _init_dq():
         dq_ref[...] = jnp.zeros_like(dq_ref)
 
-    def tile(i, carry, masked: bool):
+    def tile(i, carry):
         dk, dv = carry
         q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
         do_blk = do_ref[0, pl.ds(i * block_q, block_q), :]
@@ -314,13 +462,12 @@ def _bwd_kernel(
         delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
         s = _dot_nt(q_blk, k_blk)  # q pre-scaled by sm_scale
         p = jnp.exp(s - lse[:, None])
-        if masked:
-            ok = _tile_mask(
-                i * block_q, ki * block_k, block_q, block_k, kv_len,
-                causal, padded and not fast, window,
-            )
-            if ok is not None:
-                p = jnp.where(ok, p, 0.0)
+        ok = _tile_mask(
+            i * block_q, ki * block_k, block_q, block_k, kv_len,
+            causal, padded, window,
+        )
+        if ok is not None:
+            p = jnp.where(ok, p, 0.0)
         dv_new = dv + _dot_tn(p.astype(do_blk.dtype), do_blk)
         dp = _dot_nt(do_blk, v_blk)
         ds = (p * (dp - delta[:, None])).astype(q_blk.dtype)  # one cast,
@@ -332,31 +479,20 @@ def _bwd_kernel(
         jnp.zeros((block_k, D), jnp.float32),
         jnp.zeros((block_k, D), jnp.float32),
     )
-    if fast:
-        # diagonal tile i == ki carries the causal mask; query blocks
-        # i > ki are fully below the diagonal (every q_pos >= every
-        # k_pos), so they run unmasked.
-        dk, dv = tile(ki, init, True)
-        dk, dv = jax.lax.fori_loop(
-            ki + 1, num_q, lambda i, c: tile(i, c, False), (dk, dv)
-        )
+    if causal:
+        # query blocks strictly below the block diagonal see none of
+        # this key block
+        lo = (ki * block_k) // block_q
     else:
-        if causal:
-            # query blocks strictly below the block diagonal see none of
-            # this key block
-            lo = (ki * block_k) // block_q
-        else:
-            lo = 0
-        hi = num_q
-        if window is not None:
-            # query blocks fully right of the window (q_min - k_max >= w)
-            # see none of this key block
-            hi = jnp.minimum(
-                num_q, ((ki + 1) * block_k - 1 + window) // block_q + 1
-            )
-        dk, dv = jax.lax.fori_loop(
-            lo, hi, lambda i, c: tile(i, c, True), init
+        lo = 0
+    hi = num_q
+    if window is not None:
+        # query blocks fully right of the window (q_min - k_max >= w)
+        # see none of this key block
+        hi = jnp.minimum(
+            num_q, ((ki + 1) * block_k - 1 + window) // block_q + 1
         )
+    dk, dv = jax.lax.fori_loop(lo, hi, tile, init)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -367,7 +503,7 @@ def _flash_bwd_call(
     interpret: bool, kv_len: int, window,
 ):
     BH, S, D = q.shape
-    num_q, num_k = _cdiv(S, block_q), _cdiv(S, block_k)
+    num_q = S // block_q
     # delta_i = sum_d do_id * o_id — one fused elementwise+reduce, not worth
     # a kernel
     delta = jnp.sum(
@@ -376,20 +512,38 @@ def _flash_bwd_call(
 
     row3 = pl.BlockSpec((1, S, D), lambda bh, i: (bh, 0, 0))
     row2 = pl.BlockSpec((1, 1, S), lambda bh, i: (bh, 0, 0))
-    kblk3 = pl.BlockSpec((1, block_k, D), lambda bh, i: (bh, i, 0))
-
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(
+    nested = _nested(causal, window, block_q, block_k)
+    if nested:
+        # The causal schedule applies NO padding mask: padded k/v rows
+        # are zeros, so padded-column score/probability garbage adds
+        # exactly 0 to dq (``ds @ k`` hits zero rows) and only reaches
+        # dk/dv ROWS that the caller slices off; padded query rows carry
+        # zero cotangents.
+        key_rows = block_q  # the resident block is a key block here
+        kernel = functools.partial(
+            _bwd_causal_kernel, block_q=block_q, block_k=block_k,
+            num_blocks=num_q,
+        )
+    else:
+        key_rows = block_k
+        kernel = functools.partial(
             _bwd_kernel, causal=causal,
             block_q=block_q, block_k=block_k, num_q=num_q, kv_len=kv_len,
             window=window,
-        ),
-        grid=(BH, num_k),
+        )
+    kblk3 = pl.BlockSpec((1, key_rows, D), lambda bh, i: (bh, i, 0))
+
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid=(BH, S // key_rows),
         in_specs=[row3, kblk3, kblk3, row3, row2, row2],
         out_specs=[row3, kblk3, kblk3],
         out_shape=[
-            # dq is the revisited f32 accumulator (cast to q.dtype below)
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
+            # over several key blocks dq is the revisited f32 accumulator
+            # (cast to q.dtype below); one block writes it finished
+            jax.ShapeDtypeStruct(
+                (BH, S, D), q.dtype if nested and num_q == 1 else jnp.float32
+            ),
             jax.ShapeDtypeStruct((BH, S, D), k.dtype),
             jax.ShapeDtypeStruct((BH, S, D), v.dtype),
         ],
@@ -448,6 +602,41 @@ def _pick_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _auto_tiles(
+    seq: int, head_dim: int, interpret: bool, nested: bool = True
+) -> Tuple[int, int]:
+    """The (block_q, block_k) a call runs when it names none, from what
+    the call can see: the sequence length, the head size, whether the
+    kernels are interpreted, and whether the two-level causal schedule
+    applies (``nested``: causal, no window). Measured on the v5e inside
+    the whole training step (PERF.md section 6, PR 25).
+
+    Tiles key on the PADDED length, not raw S: language-model training
+    slices the last token off (tokens[:, :-1]), so an in-model sequence
+    is 1023 or 2047. On hardware the lse row is sliced along the LANE
+    dim in block-wide stores, so blocks are 128-multiples (Mosaic rejects
+    misaligned vector stores - observed at S=99 on v5e); interpret mode
+    only needs the 8-sublane floor.
+
+    Nested, up to 2048 positions: the whole sequence is one resident
+    block, cut into the largest sub-tiles of 512, 256 or 128 that divide
+    it. At S 1024, head_dim 64 that is (1024, 512): 14.9 + 25.0 ms a
+    gpt2-small step in the two kernels against 17.1 + 23.6 at (1024, 256),
+    19.9 + 23.9 at (1024, 128), and 71.6 + 95.1 for the (128, 128) tiles
+    in a dynamic loop that it replaces; head_dim 128 ranks them the same.
+    Longer sequences, and the general path at 2048 and over, keep
+    (512, 512), the general path below that (128, 128): not measured in
+    PR 25."""
+    del head_dim  # 64 and 128 rank the tiles alike (PERF.md, PR 25)
+    unit = 8 if interpret else 128
+    s_pad = _cdiv(seq, unit) * unit
+    if nested and s_pad <= 2048:
+        return s_pad, next(
+            (sub for sub in (512, 256, 128) if s_pad % sub == 0), s_pad
+        )
+    return (512, 512) if s_pad >= 2048 else (128, 128)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -489,13 +678,13 @@ def flash_attention(
         block_q, block_k: VMEM tile sizes; clamped to S, and on real TPU
             rounded UP to 128-multiples (Mosaic's lane-aligned store
             requirement — a requested 64 runs as 128 on hardware;
-            interpret mode honors small blocks exactly). Default auto:
-            (512, 512) when the sublane-padded sequence length reaches
-            2048, else (128, 128). The choice dates from an earlier
-            runtime and is not measured on the current chip (ROADMAP
-            S4); standalone kernel sweeps rank tiles differently from
-            whole-step timings (fusion/VMEM interactions) — trust the
-            latter when re-tuning.
+            interpret mode honors small blocks exactly). A causal call
+            without a window whose ``block_q`` is a multiple of
+            ``block_k`` runs the two-level schedule (module docstring):
+            ``block_q`` rows resident, row groups and sub-tiles of
+            ``block_k``. Default: ``_auto_tiles``, from the padded
+            length - (1024, 512) at S 1024, measured on the v5e inside
+            the whole training step (PERF.md section 6, PR 25).
         interpret: force pallas interpret mode; default: on iff the backend
             is not TPU (CPU tests / virtual-device dryruns).
         mesh/batch_axis/head_axis: when ``mesh`` is given the kernel runs
@@ -531,25 +720,15 @@ def flash_attention(
         )(q, k, v)
 
     interp = _pick_interpret(interpret)
-    # Auto tile sizes (see docstring); arbitrary S is
-    # handled by zero-padding the sequence up to the block multiple —
-    # padded keys are masked in-kernel, padded queries carry zero
+    # Arbitrary S is handled by zero-padding the sequence up to the block
+    # multiple: padded keys are masked in-kernel (on the causal schedule
+    # only padded queries can see them), padded queries carry zero
     # cotangents, so numerics are exact.
-    # Tile choice keys on the PADDED sublane length, not raw S:
-    # language-model training slices the last token off (tokens[:, :-1]),
-    # so the flagship in-model sequence is 2047 — a raw-S `>= 2048` test
-    # would drop it onto the 128-tile path, while sequences just over a
-    # power of two would pay ~50% padding on the large-tile path.
-    # On hardware the lse row is sliced along the LANE dim in block_q-wide
-    # stores, so blocks must be 128-multiples (Mosaic rejects misaligned
-    # vector stores — observed at S=99 on v5e); interpret mode only needs
-    # the 8-sublane floor, and the CPU tests use small blocks.
+    auto_q, auto_k = _auto_tiles(
+        S, D, interp, nested=causal and window is None
+    )
     unit = 8 if interp else 128
     s8 = _cdiv(S, unit) * unit
-    if s8 >= 2048:
-        auto_q, auto_k = 512, 512
-    else:
-        auto_q, auto_k = 128, 128
     block_q = min(block_q or auto_q, s8)
     block_k = min(block_k or auto_k, s8)
     if not interp:
