@@ -323,20 +323,29 @@ def _observe_link() -> Dict[str, float]:
     return out
 
 
+# (name, B, S, H, D, window) of every flash shape the kernels phase runs:
+# the big shape (the model slices the last token off: S 2047) and the
+# head_dim 128 shape of the d_model 2048 point; the benchmark's shapes -
+# GPT-2 (1024 positions, 12 heads of 64: the whole sequence resident, the
+# static causal schedule) and OLMoE (4096 positions, 16 heads of 128:
+# longer than one resident block, (512, 512) tiles); then the kernel's
+# other two code paths at a reduced batch: the general (windowed) loop,
+# and a ragged short sequence on the 128-wide tiles.
+FLASH_CASES = (
+    ("big", BATCH, SEQ - 1, 16, 64, None),
+    ("gpt2", 2, 1024, 12, 64, None),
+    ("head_dim128", 8, SEQ - 1, 16, 128, None),
+    ("olmoe", 2, 4096, 16, 128, None),
+    ("windowed", 2, SEQ - 1, 4, 64, 512),
+    ("ragged", 2, 99, 4, 64, None),
+)
+
+
 def child_kernels() -> None:
     devices, counter = _child_setup()
     _say("kernels", f"on {devices[0].device_kind}")
-    # the big shape (the model slices the last token off: S 2047) and the
-    # head_dim 128 shape of the d_model 2048 point; then the kernel's
-    # other two code paths at a reduced batch: the general (windowed)
-    # loop, and a ragged short sequence on the 128-wide tiles
-    _check_flash("big", BATCH, SEQ - 1, 16, 64)
-    # the benchmark's shape (GPT-2: 1024 positions, 12 heads of 64): the
-    # whole sequence resident, the static causal schedule
-    _check_flash("gpt2", 2, 1024, 12, 64)
-    _check_flash("head_dim128", 8, SEQ - 1, 16, 128)
-    _check_flash("windowed", 2, SEQ - 1, 4, 64, window=512)
-    _check_flash("ragged", 2, 99, 4, 64)
+    for case in FLASH_CASES:
+        _check_flash(*case)
     # the big model's largest leaf (128 grid blocks) and an odd length
     # that ends mid-block
     _check_wire_kernels("big_leaf", (1024, 4096), seed=1)
@@ -424,7 +433,7 @@ def run_group(
         )
     t0 = time.perf_counter()
     loss0, grads0 = jax.block_until_ready(grad_fn(state.params, batch))
-    state.warm(grads0)  # the optimizer-update executable, on copies
+    state.warm(grads0)  # the optimizer-update executable, compiled ahead
     compile_s = time.perf_counter() - t0
     loss0 = float(loss0)
     del grads0

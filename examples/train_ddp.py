@@ -130,8 +130,9 @@ def build_model():
     models.cnn — the reference demo's model family, reference
     train_ddp.py:64-72; pick the dataset with DATA=digits|cifar10|synthetic,
     see make_image_dataset), MODEL=lm (the flagship decoder-only
-    transformer, tiny config), or MODEL=moe (tiny mixture-of-experts LM
-    on synthetic tokens)."""
+    transformer, tiny config), MODEL=moe (tiny mixture-of-experts LM
+    on synthetic tokens, capacity dispatch) or MODEL=olmoe (tiny OLMoE:
+    RoPE / QK-norm attention, dropless top-k SwiGLU experts)."""
     model = os.environ.get("MODEL", "mlp")
     if model == "lm":
         # the flagship decoder-only transformer family (tiny config for
@@ -156,10 +157,14 @@ def build_model():
             return lm_loss(cfg, params, xb)
 
         return params, loss, x, y
-    if model == "moe":
-        from torchft_tpu.models import moe, tiny_moe_config
+    if model in ("moe", "olmoe"):
+        from torchft_tpu import models
 
-        cfg = tiny_moe_config()
+        moe, tiny = {
+            "moe": (models.moe, models.tiny_moe_config),
+            "olmoe": (models.olmoe, models.tiny_olmoe_config),
+        }[model]
+        cfg = tiny()
         rng = np.random.default_rng(0)
         n, seq = 2048, 33
         x = rng.integers(

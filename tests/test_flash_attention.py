@@ -80,6 +80,31 @@ def test_grads_match_dense(S, blocks):
         )
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_olmoe_shape_eight_blocks_a_side(dtype):
+    """OLMoE's shape in the benchmark (B 2, H 16, D 128; chip_smoke's
+    ``olmoe`` case) at a small S: 4096 positions in (512, 512) tiles are
+    eight blocks a side, here 256 in (32, 32). Forward and gradients
+    against the dense path; in bf16 at chip_smoke's tolerance."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(7), (2, 256, 16, 128), dtype)
+
+    def loss(attend, q, k, v):
+        out = attend(q, k, v).astype(jnp.float32)
+        return jnp.sum(out ** 2), out
+
+    flash = functools.partial(flash_attention, block_q=32, block_k=32)
+    dense = lambda q, k, v: dense_attention(*(t.astype(jnp.float32) for t in (q, k, v)))
+    grad = lambda f: jax.jit(jax.value_and_grad(
+        functools.partial(loss, f), argnums=(0, 1, 2), has_aux=True
+    ))
+    (_, out), grads = grad(flash)(q, k, v)
+    (_, ref), ref_grads = grad(dense)(q, k, v)
+    tol = 1e-4 if dtype == jnp.float32 else 0.02
+    for got, want, name in zip((out, *grads), (ref, *ref_grads), ("out", "dq", "dk", "dv")):
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want)))
+        assert err <= tol, (name, err)
+
+
 def test_noncausal_grads_match_dense():
     q, k, v = rand_qkv(jax.random.PRNGKey(5), (1, 64, 1, 16))
     gf = jax.grad(

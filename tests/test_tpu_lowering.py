@@ -31,7 +31,7 @@ def _mosaic_calls(fn, *args) -> int:
 @pytest.mark.parametrize(
     "seq,window",
     [(256, None), (99, None), (2047, None), (1024, 256), (1024, None),
-     (1023, None)],
+     (1023, None), (4096, None)],
 )
 def test_flash_forward_and_backward_lower(head_dim, seq, window):
     q = jnp.ones((2, seq, 2, head_dim), jnp.bfloat16)

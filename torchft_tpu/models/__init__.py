@@ -1,6 +1,7 @@
-from torchft_tpu.models import cnn, moe
+from torchft_tpu.models import cnn, moe, olmoe
 from torchft_tpu.models.cnn import CNNConfig, tiny_cnn_config
 from torchft_tpu.models.moe import MoEConfig, tiny_moe_config
+from torchft_tpu.models.olmoe import OlmoeConfig, tiny_olmoe_config
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     big_config,
@@ -15,6 +16,7 @@ from torchft_tpu.models.transformer import (
 __all__ = [
     "CNNConfig",
     "MoEConfig",
+    "OlmoeConfig",
     "TransformerConfig",
     "big_config",
     "cnn",
@@ -24,7 +26,9 @@ __all__ = [
     "loss_fn",
     "make_train_step",
     "moe",
+    "olmoe",
     "param_sharding_rules",
     "tiny_config",
     "tiny_moe_config",
+    "tiny_olmoe_config",
 ]

@@ -261,10 +261,10 @@ def param_sharding_rules(cfg: TransformerConfig) -> Dict[str, Any]:
     return rules
 
 
-def _rmsnorm(x: jax.Array, scale: jax.Array) -> jax.Array:
+def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + 1e-6) * scale).astype(x.dtype)
+    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
 def _attention(cfg: TransformerConfig, p: Dict[str, Any], x: jax.Array) -> jax.Array:
@@ -374,10 +374,12 @@ def loss_fn(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array) -
 
 
 def make_train_step(
-    cfg: TransformerConfig, tx: Any, bf16_params: bool = False
+    cfg: Any, tx: Any, bf16_params: bool = False
 ) -> Any:
     """ONE-program train step: loss, grad, and optimizer apply fused into
-    a single jitted executable with buffer donation.
+    a single jitted executable with buffer donation. ``cfg`` is this
+    module's configuration or an ``OlmoeConfig`` (the benchmark's raw
+    loop runs both), whose loss is ``olmoe.loss_fn``.
 
     Fusing saves the program-boundary cost of separate grad and apply
     programs (how much is not measured on the current chip). Use with
@@ -398,6 +400,10 @@ def make_train_step(
     """
     import optax
 
+    from . import olmoe  # it imports this module
+
+    model_loss = olmoe.loss_fn if isinstance(cfg, olmoe.OlmoeConfig) else loss_fn
+
     def one_step(params, opt_state, tokens):
         if bf16_params:
             compute_params = jax.tree_util.tree_map(
@@ -406,11 +412,11 @@ def make_train_step(
                 params,
             )
             loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(cfg, p, tokens)
+                lambda p: model_loss(cfg, p, tokens)
             )(compute_params)
         else:
             loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(cfg, p, tokens)
+                lambda p: model_loss(cfg, p, tokens)
             )(params)
         # the same scope as train_state.make_apply_fn's separate program,
         # so a device trace splits the fused step the same way
