@@ -175,17 +175,20 @@ def _dense_attention_f32(q: Any, k: Any, v: Any, window: Optional[int]) -> Any:
 
 
 def _check_flash(
-    name: str, B: int, S: int, H: int, D: int, window: Optional[int] = None
+    name: str, B: int, S: int, H: int, D: int, window: Optional[int] = None,
+    fused: bool = False,
 ) -> None:
     """Flash forward + backward at (B, S, H, D), compiled, against the
     dense float32 reference on a seeded sample of 2 batch rows x 2 heads
     (attention is independent per (batch, head), so the sample's outputs
-    and gradients are exactly the full problem's)."""
+    and gradients are exactly the full problem's). ``fused``: through
+    ``flash_attention_qkv`` on the three laid side by side as a fused
+    projection has them (the dense models' call), not the three arrays."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from torchft_tpu.ops import flash_attention
+    from torchft_tpu.ops import flash_attention, flash_attention_qkv
 
     keys = jax.random.split(jax.random.PRNGKey(B * 1000003 + S * 131 + D), 4)
     q, k, v, cot = (
@@ -193,7 +196,13 @@ def _check_flash(
     )
 
     def flash_loss(q, k, v, cot):
-        out = flash_attention(q, k, v, window=window)
+        if fused:
+            qkv = jnp.concatenate(
+                [t.reshape(B, S, H * D) for t in (q, k, v)], axis=-1
+            )
+            out = flash_attention_qkv(qkv, H).reshape(B, S, H, D)
+        else:
+            out = flash_attention(q, k, v, window=window)
         return jnp.sum(out.astype(jnp.float32) * cot.astype(jnp.float32)), out
 
     def ref_loss(q, k, v, cot):
@@ -346,6 +355,9 @@ def child_kernels() -> None:
     _say("kernels", f"on {devices[0].device_kind}")
     for case in FLASH_CASES:
         _check_flash(*case)
+        # full causal, one resident block: the fused projection's entry too
+        if case[-1] is None and case[2] <= 2048:
+            _check_flash(case[0] + "_qkv", *case[1:], fused=True)
     # the big model's largest leaf (128 grid blocks) and an odd length
     # that ends mid-block
     _check_wire_kernels("big_leaf", (1024, 4096), seed=1)

@@ -4,6 +4,7 @@ Runs in interpret mode on the CPU test mesh (conftest pins JAX_PLATFORMS=cpu
 with 8 virtual devices); on real TPU the same code compiles to Mosaic.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -11,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchft_tpu.ops import flash_attention
+from torchft_tpu.ops import flash_attention, flash_attention_qkv
 
 
 def dense_attention(q, k, v, causal=True, sm_scale=None, window=None):
@@ -309,3 +310,121 @@ def test_window_and_noncausal_take_the_general_kernels(monkeypatch):
     jax.grad(lambda q: loss(q, block_q=16, block_k=32))(q)  # do not nest
     with pytest.raises(AssertionError, match="causal schedule"):
         loss(q)
+
+
+# ---------------------------------------------------------------------------
+# the fused-projection entry: qkv (B, S, 3*H*D) in, (B, S, H*D) out
+# ---------------------------------------------------------------------------
+
+
+def _fused_calls(monkeypatch):
+    """The list that grows by one with every call that takes the fused
+    layout's kernels (and not the three-array way out)."""
+    fa = _module()
+    took = []
+    real = fa._flash_qkv
+    monkeypatch.setattr(
+        fa, "_flash_qkv", lambda *a: took.append("fused") or real(*a)
+    )
+    return took
+
+
+def _split_heads(qkv, n_heads):
+    B, S, width = qkv.shape
+    return tuple(
+        t.reshape(B, S, n_heads, width // (3 * n_heads))
+        for t in jnp.split(qkv, 3, axis=-1)
+    )
+
+
+# Head size 64 with an even count of heads (two heads a 128-lane block),
+# with an odd count (the entry splits and takes the three-array path, by
+# shape), head size 128 (one head a block); one position short of a block
+# multiple (padded) and on it.
+@pytest.mark.parametrize("S", [1023, 1024])
+@pytest.mark.parametrize(
+    "n_heads,head_dim,fused", [(2, 64, True), (3, 64, False), (2, 128, True)],
+    ids=["h2d64", "h3d64-split", "h2d128"],
+)
+def test_qkv_entry_matches_plain_attention(n_heads, head_dim, fused, S, monkeypatch):
+    took = _fused_calls(monkeypatch)
+    qkv = jax.random.normal(
+        jax.random.PRNGKey(12), (1, S, 3 * n_heads * head_dim), jnp.float32
+    )
+
+    def loss(attend, qkv):
+        out = attend(qkv)
+        return jnp.sum(out ** 2), out
+
+    def plain(qkv):
+        return dense_attention(*_split_heads(qkv, n_heads)).reshape(out_shape)
+
+    out_shape = (1, S, n_heads * head_dim)
+    grad = lambda f: jax.value_and_grad(functools.partial(loss, f), has_aux=True)
+    (_, out), dqkv = grad(lambda x: flash_attention_qkv(x, n_heads))(qkv)
+    (_, ref), ref_dqkv = grad(plain)(qkv)
+    assert bool(took) == fused
+    assert out.shape == out_shape and dqkv.shape == qkv.shape
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for got, want, name in zip(
+        jnp.split(dqkv, 3, axis=-1), jnp.split(ref_dqkv, 3, axis=-1), "qkv"
+    ):
+        np.testing.assert_allclose(
+            got, want, atol=1e-4, rtol=1e-4, err_msg=f"d{name}"
+        )
+
+
+@pytest.mark.parametrize("S", [255, 256])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_in_kernel_scale_is_the_outside_fold_bit_for_bit(dtype, S):
+    """Head size 64: the scale is 0.125, a power of two, so scaling q
+    inside the kernels (and dq as it is written) gives the bits of the
+    three-array entry's fold outside them."""
+    n_heads = 4
+    qkv = jax.random.normal(jax.random.PRNGKey(13), (2, S, 3 * n_heads * 64), dtype)
+
+    def split(qkv):
+        out = flash_attention(*_split_heads(qkv, n_heads))
+        return out.reshape(out.shape[0], S, n_heads * 64)
+
+    def run(attend):
+        return jax.value_and_grad(
+            lambda x: jnp.sum(attend(x).astype(jnp.float32) ** 2)
+        )(qkv)
+
+    (loss_f, g_f), (loss_s, g_s) = run(lambda x: flash_attention_qkv(x, n_heads)), run(split)
+    np.testing.assert_array_equal(
+        flash_attention_qkv(qkv, n_heads).astype(np.float32),
+        split(qkv).astype(np.float32),
+    )
+    assert float(loss_f) == float(loss_s)
+    np.testing.assert_array_equal(g_f.astype(np.float32), g_s.astype(np.float32))
+
+
+@pytest.mark.parametrize(
+    "n_heads,fused", [(2, True), (3, False)], ids=["h2-fused", "h3-split"]
+)
+def test_transformer_loss_and_gradient_equal_flash_on_and_off(n_heads, fused, monkeypatch):
+    """Head size 64, as every GPT-2 width has it: ``use_flash`` through
+    the fused-projection entry (and, with an odd count of heads, through
+    its three-array way out) against the dense path."""
+    from torchft_tpu.models import TransformerConfig, init_params, loss_fn
+
+    took = _fused_calls(monkeypatch)
+    cfg = TransformerConfig(
+        vocab_size=256, d_model=64 * n_heads, n_heads=n_heads, n_layers=2,
+        d_ff=128, max_seq_len=128, dtype=jnp.float32,
+    )
+    cfg_flash = dataclasses.replace(cfg, use_flash=True)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.asarray(
+        np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 129)), jnp.int32
+    )
+    grad = lambda c: jax.value_and_grad(lambda p: loss_fn(c, p, tokens))(params)
+    (l_dense, g_dense), (l_flash, g_flash) = grad(cfg), grad(cfg_flash)
+    assert len(took) == (cfg.n_layers if fused else 0)
+    np.testing.assert_allclose(l_flash, l_dense, atol=1e-5, rtol=1e-5)
+    for a, b in zip(
+        jax.tree_util.tree_leaves(g_flash), jax.tree_util.tree_leaves(g_dense)
+    ):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)

@@ -279,6 +279,20 @@ def _attention(cfg: TransformerConfig, p: Dict[str, Any], x: jax.Array) -> jax.A
 def _attention_impl(cfg: TransformerConfig, p: Dict[str, Any], x: jax.Array) -> jax.Array:
     B, S, D = x.shape
     qkv = x @ p["wqkv"].astype(cfg.dtype)  # (B, S, 3D)
+    if (
+        cfg.use_flash and cfg.cp_seq_axis is None and cfg.cp_mesh is None
+        and cfg.attn_window is None
+    ):
+        # one device, full causal attention: the kernels read the heads
+        # where the projection wrote them and write them where the out
+        # projection reads them - no split, transpose or copy between
+        from ..ops import flash_attention_qkv
+
+        out = flash_attention_qkv(
+            qkv, cfg.n_heads,
+            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
+        )
+        return out @ p["wo"].astype(cfg.dtype)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
     k = k.reshape(B, S, cfg.n_heads, cfg.head_dim)

@@ -18,9 +18,33 @@ Fusing matters because this shape is VPU-bound, not MXU-bound (head_dim
 design recomputes probabilities twice per tile pair — once for dq, once
 for dk/dv — and the fused kernel computes them once, cutting the
 dominant exp/elementwise work ~in half and the matmul count 7→5 per
-tile. The softmax scale is folded into q OUTSIDE the kernel (exact for
-power-of-two scales, e.g. head_dim 64 → 0.125), removing the per-tile
-S×S scale multiplies; autodiff of the fold rescales dq automatically.
+tile. The softmax scale is folded into q, never into the scores (exact
+for power-of-two scales, e.g. head_dim 64 → 0.125), removing the per-tile
+S×S scale multiplies: outside the kernels on the three-array entry, where
+autodiff of the fold rescales dq, and inside them, once a resident block
+and once on dq, on the fused-projection entry.
+
+TWO ENTRIES over the same kernel bodies; what selects one is what the
+caller has in hand, never a flag. ``flash_attention_qkv`` takes a fused
+projection ``x @ wqkv`` of (B, S, 3*H*D) and returns (B, S, H*D): the
+dense models' call (``transformer._attention_impl``, and ``models/moe.py``
+through it). Its kernels address heads inside that layout - a grid step
+takes one 128-lane block of the q third (two heads of 64, one of 128) and
+the blocks at the same place in the k and v thirds, and the backward
+writes the (B, S, 3*H*D) cotangent as one array - so nothing is
+transposed, split, concatenated or scaled in HBM between ``x @ wqkv`` and
+``out @ wo``; the softmax scale is applied inside, once a resident block.
+Around the (B*H, S, 64) form those copies were 16-27 ms of a 232-243 ms
+GPT-2 step and 2.6-3.0 GB of HBM, rows of 64 padded to 128 lanes (PERF.md
+section 6, PRs 27 and 28). ``flash_attention`` takes q, k, v of
+(B, S, H, D), KEEPS the transposes to (B*H, S, D) and the scale folded
+outside, and is every other caller's: ``models/olmoe.py`` (q and k leave
+QK-norm and RoPE fusions that write any layout for nothing, and a head of
+128 columns is a full lane block either way), Ulysses context
+parallelism, the ``mesh`` / ``shard_map`` form, windows, non-causal
+calls, and whatever the fused layout cannot express (an odd count of
+64-wide heads, other head sizes, more than one resident block), which
+``flash_attention_qkv`` sends there itself, from shapes.
 
 The causal path (no window, ``block_q`` a multiple of ``block_k``) runs a
 TWO-LEVEL schedule. A grid step holds a resident block of ``block_q``
@@ -86,6 +110,7 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 _NEG_INF = float("-inf")
@@ -149,6 +174,25 @@ def _tile_mask(
     return ok
 
 
+# Two heads of 64 columns in one body hold two heads' temporaries: at
+# 2048 resident positions that is 18.8 MB of VMEM where one head fits the
+# 16 MiB a kernel gets unasked (20.2 MB at head size 256). The chip has
+# 128 MiB.
+_QKV_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 2**20)
+
+
+def _traced_once(*static_argnames: str):
+    """For the functions that build a ``pallas_call``: an inlined
+    ``jax.jit`` leaves the lowered module as it was (every call site
+    still gets its own Mosaic call) and gives the tracing cache, so a
+    model of 24 layers traces each kernel body once for its shape and not
+    24 times - seconds of every set-up, warm or cold (PERF.md section 6,
+    PR 28)."""
+    return functools.partial(
+        jax.jit, inline=True, static_argnames=static_argnames
+    )
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -170,9 +214,26 @@ def _triangle(n: int, queries_first: bool):
     return rows >= cols if queries_first else cols >= rows
 
 
+def _head_lanes(h: int, head_dim: int, heads: int):
+    """Where head ``h`` sits among the lanes of a block that holds
+    ``heads`` heads side by side (a static slice; the whole block when
+    it holds one)."""
+    return slice(None) if heads == 1 else pl.ds(h * head_dim, head_dim)
+
+
+def _scaled(q: jax.Array, sm_scale: Optional[float]) -> jax.Array:
+    """q times the softmax scale as the three-array entry folds it in
+    outside the kernels: an f32 product rounded back once. ``None``: q
+    arrives scaled."""
+    if sm_scale is None:
+        return q
+    return (q * jnp.float32(sm_scale)).astype(q.dtype)
+
+
 def _fwd_causal_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     block_q: int, block_k: int, num_blocks: int,
+    heads: int = 1, sm_scale: Optional[float] = None,
 ):
     """Two-level causal forward. Grid step (bh, qi) holds ``block_q``
     query rows as ``block_q // block_k`` row groups, each a straight line
@@ -181,55 +242,69 @@ def _fwd_causal_kernel(
     sub-tile under a constant mask - after one dynamic loop over the
     key blocks left of the resident one (none when it is the whole
     sequence). No padding mask: a padded key is only ever visible to
-    padded query rows, which are sliced off. q arrives pre-scaled."""
+    padded query rows, which are sliced off.
+
+    The three-array entry hands over one head a step, q pre-scaled
+    (``heads`` 1, ``sm_scale`` None). The fused-projection entry hands
+    over a 128-lane block of the projection, ``heads`` heads side by side
+    (two at head size 64), and the scale: each head runs the same
+    schedule on its own lanes, q scaled once a resident row group."""
     n_sub = block_q // block_k
-    D = q_ref.shape[-1]
+    D = q_ref.shape[-1] // heads
     # a static origin when there is one block: every slice is static
     q0 = 0 if num_blocks == 1 else pl.program_id(1) * block_q
     tri = _triangle(block_k, True)
-    q_rows = [q_ref[0, pl.ds(r * block_k, block_k), :] for r in range(n_sub)]
 
-    def tile(q_blk, state, k_start, width: int, masked: bool):
-        k_blk = k_ref[0, pl.ds(k_start, width), :]
-        v_blk = v_ref[0, pl.ds(k_start, width), :]
-        s = _dot_nt(q_blk, k_blk)  # (block_k, width) f32
-        if masked:
-            s = jnp.where(tri, s, _NEG_LARGE)
-        m, l, acc = state
-        # every row has a live key in its first tile (key 0), so m is
-        # finite from then on and exp(_NEG_LARGE - m) is exactly 0: no
-        # -inf guards
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        acc_new = acc * corr + _dot_f32(p.astype(v_blk.dtype), v_blk)
-        return m_new, l_new, acc_new
+    def one_head(h: int, lanes):
+        q_rows = [
+            _scaled(q_ref[0, pl.ds(r * block_k, block_k), lanes], sm_scale)
+            for r in range(n_sub)
+        ]
 
-    state = [(
-        jnp.full((block_k, 1), _NEG_LARGE, jnp.float32),
-        jnp.zeros((block_k, 1), jnp.float32),
-        jnp.zeros((block_k, D), jnp.float32),
-    )] * n_sub
-    if num_blocks > 1:
-        def interior(j, state):
-            return tuple(
-                tile(q_rows[r], state[r], j * block_q, block_q, False)
-                for r in range(n_sub)
-            )
+        def tile(q_blk, state, k_start, width: int, masked: bool):
+            k_blk = k_ref[0, pl.ds(k_start, width), lanes]
+            v_blk = v_ref[0, pl.ds(k_start, width), lanes]
+            s = _dot_nt(q_blk, k_blk)  # (block_k, width) f32
+            if masked:
+                s = jnp.where(tri, s, _NEG_LARGE)
+            m, l, acc = state
+            # every row has a live key in its first tile (key 0), so m is
+            # finite from then on and exp(_NEG_LARGE - m) is exactly 0: no
+            # -inf guards
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l_new = l * corr + p.sum(axis=-1, keepdims=True)
+            acc_new = acc * corr + _dot_f32(p.astype(v_blk.dtype), v_blk)
+            return m_new, l_new, acc_new
 
-        state = list(jax.lax.fori_loop(
-            0, pl.program_id(1), interior, tuple(state)
-        ))
-    for r in range(n_sub):
-        st = state[r]
-        if r:
-            st = tile(q_rows[r], st, q0, r * block_k, False)
-        m, l, acc = tile(q_rows[r], st, q0 + r * block_k, block_k, True)
-        o_ref[0, pl.ds(r * block_k, block_k), :] = (acc / l).astype(o_ref.dtype)
-        # lse rides a full-row (1, 1, S) block revisited across the
-        # sequential qi grid dim; each row group writes its slice
-        lse_ref[0, 0, pl.ds(q0 + r * block_k, block_k)] = (m + jnp.log(l))[:, 0]
+        state = [(
+            jnp.full((block_k, 1), _NEG_LARGE, jnp.float32),
+            jnp.zeros((block_k, 1), jnp.float32),
+            jnp.zeros((block_k, D), jnp.float32),
+        )] * n_sub
+        if num_blocks > 1:
+            def interior(j, state):
+                return tuple(
+                    tile(q_rows[r], state[r], j * block_q, block_q, False)
+                    for r in range(n_sub)
+                )
+
+            state = list(jax.lax.fori_loop(
+                0, pl.program_id(1), interior, tuple(state)
+            ))
+        for r in range(n_sub):
+            st = state[r]
+            if r:
+                st = tile(q_rows[r], st, q0, r * block_k, False)
+            m, l, acc = tile(q_rows[r], st, q0 + r * block_k, block_k, True)
+            o_ref[0, pl.ds(r * block_k, block_k), lanes] = (acc / l).astype(o_ref.dtype)
+            # lse rides a full-row (1, 1, S) block revisited across the
+            # sequential qi grid dim; each row group writes its slice
+            lse_ref[h, 0, pl.ds(q0 + r * block_k, block_k)] = (m + jnp.log(l))[:, 0]
+
+    for h in range(heads):
+        one_head(h, _head_lanes(h, D, heads))
 
 
 def _fwd_kernel(
@@ -298,6 +373,7 @@ def _fwd_kernel(
     )
 
 
+@_traced_once("causal", "block_q", "block_k", "interpret", "kv_len", "window")
 def _flash_fwd_call(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool, block_q: int, block_k: int,
@@ -338,6 +414,59 @@ def _flash_fwd_call(
     )(q, k, v)
 
 
+def _qkv_blocks(shape: Tuple[int, int, int], n_heads: int):
+    """How the fused-projection kernels cut a (B, S_pad, 3*H*D) array:
+    the grid (batch row, lane block), a lane block's columns and heads,
+    the BlockSpec of a step's block in the q, k or v third (0, 1, 2; the
+    first also places a (B, S_pad, H*D) array's block) and that of its
+    heads' rows of a (B*H, 1, S_pad) array."""
+    B, S, width = shape
+    hd = width // 3
+    D = hd // n_heads
+    lanes = _qkv_lanes(n_heads, D)
+    n_lane = hd // lanes
+    third = lambda t: pl.BlockSpec(
+        (1, S, lanes), lambda b, j: (b, 0, t * n_lane + j)
+    )
+    row = pl.BlockSpec(
+        (lanes // D, 1, S), lambda b, j: (b * n_lane + j, 0, 0)
+    )
+    return (B, n_lane), lanes, lanes // D, third, row
+
+
+@_traced_once("n_heads", "sm_scale", "block_k", "interpret")
+def _flash_fwd_qkv_call(
+    qkv: jax.Array, *, n_heads: int, sm_scale: float,
+    block_k: int, interpret: bool,
+):
+    """qkv (B, S_pad, 3*H*D), the fused projection as it leaves its
+    matmul -> out (B, S_pad, H*D), lse (B*H, 1, S_pad) f32. Causal, the
+    whole padded sequence one resident block. A grid step takes one block
+    of ``lanes`` columns - the narrowest Mosaic's lane rule allows, two
+    heads at head size 64, one at 128 - of q, and the blocks at the same
+    place in the k and v thirds of the same array: heads are on the grid,
+    and the body does not grow with their count."""
+    B, S, width = qkv.shape
+    grid, _, heads, third, row = _qkv_blocks(qkv.shape, n_heads)
+    kernel = functools.partial(
+        _fwd_causal_kernel, block_q=S, block_k=block_k, num_blocks=1,
+        heads=heads, sm_scale=sm_scale,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[third(0), third(1), third(2)],
+        out_specs=[third(0), row],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, S, width // 3), qkv.dtype),
+            jax.ShapeDtypeStruct((B * n_heads, 1, S), jnp.float32),
+        ],
+        compiler_params=_QKV_COMPILER_PARAMS,
+        interpret=interpret,
+        name="flash_fwd",  # as the three-array call: trace readers match it
+    )(qkv, qkv, qkv)
+
+
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
@@ -347,6 +476,7 @@ def _bwd_causal_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, *,
     block_q: int, block_k: int, num_blocks: int,
+    heads: int = 1, sm_scale: Optional[float] = None,
 ):
     """Two-level causal backward, the forward's schedule with the roles
     swapped: grid step (bh, ki) holds ``block_q`` KEY rows as sub-blocks
@@ -357,77 +487,140 @@ def _bwd_causal_kernel(
     Scores are computed TRANSPOSED, (keys, queries): lse and delta live
     along lanes and broadcast over the key sublanes for free, and
     p.T @ do, ds.T @ q are plain matmuls. No padding mask (see
-    ``_flash_bwd_call``)."""
+    ``_flash_bwd_call``).
+
+    ``heads`` and ``sm_scale`` as in ``_fwd_causal_kernel``; with a scale
+    (the fused-projection entry: one resident block) q is scaled once,
+    whole, and dq once as it is written - what autodiff of the outside
+    fold does to the three-array entry's dq."""
     n_sub = block_q // block_k
     one_block = num_blocks == 1
+    assert sm_scale is None or one_block
+    D = q_ref.shape[-1] // heads
     k0 = 0 if one_block else pl.program_id(1) * block_q
     tri_t = _triangle(block_k, False)
-    k_rows = [k_ref[0, pl.ds(c * block_k, block_k), :] for c in range(n_sub)]
-    v_rows = [v_ref[0, pl.ds(c * block_k, block_k), :] for c in range(n_sub)]
 
-    def tile(c: int, q_start, width: int, masked: bool):
-        """(dk, dv) of key sub-block c and the (width, D) dq of the
-        queries [q_start, q_start + width) from their meeting."""
-        q_blk = q_ref[0, pl.ds(q_start, width), :]
-        do_blk = do_ref[0, pl.ds(q_start, width), :]
-        lse = lse_ref[0, :, pl.ds(q_start, width)]  # (1, width)
-        delta = delta_ref[0, :, pl.ds(q_start, width)]
-        s_t = _dot_nt(k_rows[c], q_blk)  # (keys, queries); q pre-scaled
-        p_t = jnp.exp(s_t - lse)
-        if masked:
-            p_t = jnp.where(tri_t, p_t, 0.0)
-        dv = _dot_f32(p_t.astype(do_blk.dtype), do_blk)
-        dp_t = _dot_nt(v_rows[c], do_blk)
-        ds_t = (p_t * (dp_t - delta)).astype(q_blk.dtype)  # one cast,
-        dk = _dot_f32(ds_t, q_blk)                          # used twice
-        return dk, dv, _dot_tn(ds_t, k_rows[c])
+    def one_head(h: int, lanes):
+        k_rows = [k_ref[0, pl.ds(c * block_k, block_k), lanes] for c in range(n_sub)]
+        v_rows = [v_ref[0, pl.ds(c * block_k, block_k), lanes] for c in range(n_sub)]
+        q_all = None if sm_scale is None else _scaled(q_ref[0, :, lanes], sm_scale)
 
-    zeros = jnp.zeros((block_k, q_ref.shape[-1]), jnp.float32)
-    dks, dvs = [zeros] * n_sub, [zeros] * n_sub
-    if not one_block:
-        # dq accumulates into a REVISITED full-row f32 output block: the
-        # TPU grid is sequential, so every ki step of one bh row sees the
-        # same resident VMEM block; zero it on the first step.
-        @pl.when(pl.program_id(1) == 0)
-        def _init_dq():
-            dq_ref[...] = jnp.zeros_like(dq_ref)
+        def tile(c: int, q_start, width: int, masked: bool):
+            """(dk, dv) of key sub-block c and the (width, D) dq of the
+            queries [q_start, q_start + width) from their meeting."""
+            if q_all is None:
+                q_blk = q_ref[0, pl.ds(q_start, width), lanes]
+            else:  # one block: q_start is static
+                q_blk = q_all[q_start:q_start + width]
+            do_blk = do_ref[0, pl.ds(q_start, width), lanes]
+            lse = lse_ref[h, :, pl.ds(q_start, width)]  # (1, width)
+            delta = delta_ref[h, :, pl.ds(q_start, width)]
+            s_t = _dot_nt(k_rows[c], q_blk)  # (keys, queries); q scaled
+            p_t = jnp.exp(s_t - lse)
+            if masked:
+                p_t = jnp.where(tri_t, p_t, 0.0)
+            dv = _dot_f32(p_t.astype(do_blk.dtype), do_blk)
+            dp_t = _dot_nt(v_rows[c], do_blk)
+            ds_t = (p_t * (dp_t - delta)).astype(q_blk.dtype)  # one cast,
+            dk = _dot_f32(ds_t, q_blk)                          # used twice
+            return dk, dv, _dot_tn(ds_t, k_rows[c])
 
-        def below(i, carry):
-            dks, dvs = carry
-            out_k, out_v, dq = [], [], 0.0
-            for c in range(n_sub):
-                dk, dv, dq_c = tile(c, i * block_q, block_q, False)
-                out_k.append(dks[c] + dk)
-                out_v.append(dvs[c] + dv)
-                dq = dq + dq_c
-            dq_ref[0, pl.ds(i * block_q, block_q), :] += dq
-            return tuple(out_k), tuple(out_v)
+        zeros = jnp.zeros((block_k, D), jnp.float32)
+        dks, dvs = [zeros] * n_sub, [zeros] * n_sub
+        if not one_block:
+            # dq accumulates into a REVISITED full-row f32 output block: the
+            # TPU grid is sequential, so every ki step of one bh row sees the
+            # same resident VMEM block; zero it on the first step.
+            @pl.when(pl.program_id(1) == 0)
+            def _init_dq():
+                dq_ref[...] = jnp.zeros_like(dq_ref)
 
-        dks, dvs = map(list, jax.lax.fori_loop(
-            pl.program_id(1) + 1, num_blocks, below, (tuple(dks), tuple(dvs)),
-        ))
-    dqs = [0.0] * n_sub  # of the block's own query row groups, f32
-    for c in range(n_sub):
-        # the diagonal sub-tile, then every query of the block below it
-        for first, count in ((c, 1), (c + 1, n_sub - 1 - c)):
-            if not count:
-                continue
-            dk, dv, dq = tile(
-                c, k0 + first * block_k, count * block_k, first == c
-            )
-            dks[c], dvs[c] = dks[c] + dk, dvs[c] + dv
-            for r in range(count):
-                dqs[first + r] = (
-                    dqs[first + r] + dq[r * block_k:(r + 1) * block_k]
+            def below(i, carry):
+                dks, dvs = carry
+                out_k, out_v, dq = [], [], 0.0
+                for c in range(n_sub):
+                    dk, dv, dq_c = tile(c, i * block_q, block_q, False)
+                    out_k.append(dks[c] + dk)
+                    out_v.append(dvs[c] + dv)
+                    dq = dq + dq_c
+                dq_ref[0, pl.ds(i * block_q, block_q), lanes] += dq
+                return tuple(out_k), tuple(out_v)
+
+            dks, dvs = map(list, jax.lax.fori_loop(
+                pl.program_id(1) + 1, num_blocks, below, (tuple(dks), tuple(dvs)),
+            ))
+        dqs = [0.0] * n_sub  # of the block's own query row groups, f32
+        for c in range(n_sub):
+            # the diagonal sub-tile, then every query of the block below it
+            for first, count in ((c, 1), (c + 1, n_sub - 1 - c)):
+                if not count:
+                    continue
+                dk, dv, dq = tile(
+                    c, k0 + first * block_k, count * block_k, first == c
                 )
-    for r in range(n_sub):
-        rows = pl.ds(k0 + r * block_k, block_k)
-        if one_block:  # every key of the row is here: dq is final
-            dq_ref[0, rows, :] = dqs[r].astype(dq_ref.dtype)
-        else:
-            dq_ref[0, rows, :] += dqs[r]
-        dk_ref[0, pl.ds(r * block_k, block_k), :] = dks[r].astype(dk_ref.dtype)
-        dv_ref[0, pl.ds(r * block_k, block_k), :] = dvs[r].astype(dv_ref.dtype)
+                dks[c], dvs[c] = dks[c] + dk, dvs[c] + dv
+                for r in range(count):
+                    dqs[first + r] = (
+                        dqs[first + r] + dq[r * block_k:(r + 1) * block_k]
+                    )
+        for r in range(n_sub):
+            rows = pl.ds(k0 + r * block_k, block_k)
+            if one_block:  # every key of the row is here: dq is final
+                dq = dqs[r] if sm_scale is None else dqs[r] * jnp.float32(sm_scale)
+                dq_ref[0, rows, lanes] = dq.astype(dq_ref.dtype)
+            else:
+                dq_ref[0, rows, lanes] += dqs[r]
+            dk_ref[0, pl.ds(r * block_k, block_k), lanes] = dks[r].astype(dk_ref.dtype)
+            dv_ref[0, pl.ds(r * block_k, block_k), lanes] = dvs[r].astype(dv_ref.dtype)
+
+    for h in range(heads):
+        one_head(h, _head_lanes(h, D, heads))
+
+
+def _bwd_qkv_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dqkv_ref, dq_buf, dk_buf, dv_buf, sems, **schedule,
+):
+    """The backward of the fused-projection entry: ``_bwd_causal_kernel``
+    on a lane block of the q, k and v thirds, its three results written
+    into ONE cotangent array. A ``pallas_call`` output has one block a
+    grid step and this needs three, a third of the array apart, so the
+    array stays in HBM and the kernel sends the blocks itself, from two
+    VMEM slots: a step's copies run under the next step's compute and
+    are awaited when their slot comes round again (the TPU grid is
+    sequential), the last two at the last step."""
+    n_lane = pl.num_programs(1)
+    b, j = pl.program_id(0), pl.program_id(1)
+    step = b * n_lane + j
+    slot = step % 2
+    lanes = q_ref.shape[-1]
+
+    def copies(slot):
+        return [
+            pltpu.make_async_copy(
+                buf.at[slot],
+                dqkv_ref.at[b, :, pl.ds((third * n_lane + j) * lanes, lanes)],
+                sems.at[slot, third],
+            )
+            for third, buf in enumerate((dq_buf, dk_buf, dv_buf))
+        ]
+
+    def wait(slot):
+        # a wait reads only the semaphore and the size of the copy
+        for copy in copies(slot):
+            copy.wait()
+
+    pl.when(step >= 2)(lambda: wait(slot))
+    _bwd_causal_kernel(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        *(buf.at[pl.ds(slot, 1)] for buf in (dq_buf, dk_buf, dv_buf)),
+        **schedule,
+    )
+    for copy in copies(slot):
+        copy.start()
+    last = step == pl.num_programs(0) * n_lane - 1
+    pl.when(last & (step >= 1))(lambda: wait(1 - slot))
+    pl.when(last)(lambda: wait(slot))
 
 
 def _bwd_kernel(
@@ -497,6 +690,7 @@ def _bwd_kernel(
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+@_traced_once("causal", "block_q", "block_k", "interpret", "kv_len", "window")
 def _flash_bwd_call(
     q, k, v, o, lse, do, *,
     causal: bool, block_q: int, block_k: int,
@@ -553,6 +747,41 @@ def _flash_bwd_call(
     return dq.astype(q.dtype), dk, dv
 
 
+@_traced_once("n_heads", "sm_scale", "block_k", "interpret")
+def _flash_bwd_qkv_call(
+    qkv, o, lse, do, *, n_heads: int, sm_scale: float,
+    block_k: int, interpret: bool,
+):
+    """The cotangent of ``_flash_fwd_qkv_call``'s qkv, (B, S_pad, 3*H*D),
+    written by the one kernel into one array (``_bwd_qkv_kernel``)."""
+    B, S, width = qkv.shape
+    grid, lanes, heads, third, row = _qkv_blocks(qkv.shape, n_heads)
+    # delta in the kernels' own (B*H, 1, S) form, as lse: 4 bytes a row
+    # and head, XLA's to transpose
+    delta = jnp.sum(
+        (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
+            B, S, n_heads, -1
+        ),
+        axis=-1,
+    ).transpose(0, 2, 1).reshape(B * n_heads, 1, S)
+    kernel = functools.partial(
+        _bwd_qkv_kernel, block_q=S, block_k=block_k, num_blocks=1,
+        heads=heads, sm_scale=sm_scale,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[third(0), third(1), third(2), third(0), row, row],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        scratch_shapes=[pltpu.VMEM((2, S, lanes), qkv.dtype)] * 3
+        + [pltpu.SemaphoreType.DMA((2, 3))],
+        compiler_params=_QKV_COMPILER_PARAMS,
+        interpret=interpret,
+        name="flash_bwd",
+    )(qkv, qkv, qkv, do, lse, delta)
+
+
 # ---------------------------------------------------------------------------
 # custom-vjp plumbing on the (BH, S, D) canonical layout
 # ---------------------------------------------------------------------------
@@ -571,15 +800,7 @@ def _flash_fwd_res(cfg, q, k, v):
         block_q=block_q, block_k=block_k, interpret=interpret,
         kv_len=kv_len, window=window,
     )
-    # Name the kernel outputs so a jax.checkpoint policy can SAVE them:
-    # the vjp needs (out, lse) as residuals, and with both saved the remat
-    # backward's forward replay prunes the fwd pallas launch entirely
-    # (q/k/v are re-derived from the cheap qkv projection instead).
-    # checkpoint_name is the identity outside a policy-remat context.
-    from jax.ad_checkpoint import checkpoint_name
-
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    out, lse = _named_residuals(out, lse)
     return out, (q, k, v, out, lse)
 
 
@@ -594,6 +815,47 @@ def _flash_bwd_res(cfg, res, g):
 
 
 _flash.defvjp(_flash_fwd_res, _flash_bwd_res)
+
+
+def _named_residuals(out, lse):
+    """Name the kernel outputs so a jax.checkpoint policy can SAVE them:
+    the vjp needs (out, lse) as residuals, and with both saved the remat
+    backward's forward replay prunes the fwd pallas launch entirely
+    (q/k/v are re-derived from the cheap qkv projection instead).
+    checkpoint_name is the identity outside a policy-remat context."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    return checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
+
+
+# The same plumbing on the fused projection's own layout: one array in,
+# one cotangent out, nothing transposed, split or concatenated between.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_qkv(cfg, qkv):
+    out, _ = _flash_qkv_fwd_res(cfg, qkv)
+    return out
+
+
+def _flash_qkv_fwd_res(cfg, qkv):
+    n_heads, sm_scale, block_k, interpret = cfg
+    out, lse = _named_residuals(*_flash_fwd_qkv_call(
+        qkv, n_heads=n_heads, sm_scale=sm_scale, block_k=block_k,
+        interpret=interpret,
+    ))
+    return out, (qkv, out, lse)
+
+
+def _flash_qkv_bwd_res(cfg, res, g):
+    n_heads, sm_scale, block_k, interpret = cfg
+    return (_flash_bwd_qkv_call(
+        *res, g, n_heads=n_heads, sm_scale=sm_scale, block_k=block_k,
+        interpret=interpret,
+    ),)
+
+
+_flash_qkv.defvjp(_flash_qkv_fwd_res, _flash_qkv_bwd_res)
 
 
 def _pick_interpret(interpret: Optional[bool]) -> bool:
@@ -635,6 +897,96 @@ def _auto_tiles(
             (sub for sub in (512, 256, 128) if s_pad % sub == 0), s_pad
         )
     return (512, 512) if s_pad >= 2048 else (128, 128)
+
+
+def _tiles(
+    seq: int, head_dim: int, interpret: bool,
+    block_q: Optional[int], block_k: Optional[int], nested: bool,
+) -> Tuple[int, int, int]:
+    """(block_q, block_k, padded length) of a call: the blocks it names,
+    else ``_auto_tiles``, clamped to the sequence and rounded to what the
+    hardware stores. Arbitrary S is handled by zero-padding the sequence
+    up to the block multiple: padded keys are masked in-kernel (on the
+    causal schedule only padded queries can see them), padded queries
+    carry zero cotangents, so numerics are exact."""
+    auto_q, auto_k = _auto_tiles(seq, head_dim, interpret, nested=nested)
+    unit = 8 if interpret else 128
+    s8 = _cdiv(seq, unit) * unit
+    block_q = min(block_q or auto_q, s8)
+    block_k = min(block_k or auto_k, s8)
+    if not interpret:
+        block_q = _cdiv(block_q, 128) * 128
+        block_k = _cdiv(block_k, 128) * 128
+    base = block_q * block_k // math.gcd(block_q, block_k)
+    return block_q, block_k, _cdiv(seq, base) * base
+
+
+def _qkv_lanes(n_heads: int, head_dim: int) -> Optional[int]:
+    """Columns of the block one grid step of the fused-projection kernels
+    takes, or None where the projection cannot be cut so. Mosaic's lane
+    rule wants a block's last dimension a multiple of 128 (or the whole
+    array's): a head of 128 columns or a multiple is a block by itself,
+    two heads of 64 share one, and an odd count of those leaves half a
+    block over."""
+    if head_dim % 128 == 0:
+        return head_dim
+    if head_dim == 64 and n_heads % 2 == 0:
+        return 128
+    return None
+
+
+def flash_attention_qkv(
+    qkv: jax.Array,
+    n_heads: int,
+    *,
+    sm_scale: Optional[float] = None,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal multi-head attention on a fused projection, in its layout.
+
+    Args:
+        qkv: (B, S, 3 * n_heads * head_dim): ``x @ wqkv``, the q columns
+            first, then k, then v, each head's columns together.
+        sm_scale, block_q, block_k, interpret: as ``flash_attention``. The
+            scale is applied inside the kernels, rounded as the fold
+            outside would round it (bit-equal at head size 64).
+    Returns:
+        (B, S, n_heads * head_dim), ready for the out projection.
+
+    The kernels address heads inside that layout (``_flash_fwd_qkv_call``),
+    so no transpose, split, pad-to-128-lanes or concatenate is left
+    between the two projections, forward or backward. What the layout
+    cannot express goes through ``flash_attention`` on the split arrays,
+    decided from shapes alone: a head size that is neither 64 nor a
+    multiple of 128, an odd count of 64-wide heads, blocks that leave the
+    sequence in more than one resident block (over 2048 positions) or do
+    not nest."""
+    B, S, width = qkv.shape
+    head_dim = width // (3 * n_heads)
+    if sm_scale is None:
+        sm_scale = head_dim ** -0.5
+    interp = _pick_interpret(interpret)
+    block_q, block_k, S_pad = _tiles(
+        S, head_dim, interp, block_q, block_k, nested=True
+    )
+    if (
+        _qkv_lanes(n_heads, head_dim) is None
+        or block_q != S_pad or block_q % block_k
+    ):
+        q, k, v = (
+            t.reshape(B, S, n_heads, head_dim)
+            for t in jnp.split(qkv, 3, axis=-1)
+        )
+        return flash_attention(
+            q, k, v, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+            interpret=interp,
+        ).reshape(B, S, n_heads * head_dim)
+    if S_pad != S:
+        qkv = jnp.pad(qkv, ((0, 0), (0, S_pad - S), (0, 0)))
+    out = _flash_qkv((n_heads, float(sm_scale), block_k, interp), qkv)
+    return out[:, :S]
 
 
 def flash_attention(
@@ -720,27 +1072,18 @@ def flash_attention(
         )(q, k, v)
 
     interp = _pick_interpret(interpret)
-    # Arbitrary S is handled by zero-padding the sequence up to the block
-    # multiple: padded keys are masked in-kernel (on the causal schedule
-    # only padded queries can see them), padded queries carry zero
-    # cotangents, so numerics are exact.
-    auto_q, auto_k = _auto_tiles(
-        S, D, interp, nested=causal and window is None
+    block_q, block_k, S_pad = _tiles(
+        S, D, interp, block_q, block_k, nested=causal and window is None
     )
-    unit = 8 if interp else 128
-    s8 = _cdiv(S, unit) * unit
-    block_q = min(block_q or auto_q, s8)
-    block_k = min(block_k or auto_k, s8)
-    if not interp:
-        block_q = _cdiv(block_q, 128) * 128
-        block_k = _cdiv(block_k, 128) * 128
-    base = block_q * block_k // math.gcd(block_q, block_k)
-    S_pad = _cdiv(S, base) * base
 
     # (B, S, H, D) -> (B*H, S_pad, D). Blocks always span the full head
     # dim, so Mosaic's "divisible by 128 OR equal to the array dim" lane
     # rule is satisfied without padding D (padding to 128 lanes would
-    # double the QK FLOPs at the flagship head_dim of 64).
+    # double the QK FLOPs at the flagship head_dim of 64). This entry
+    # KEEPS these transposes (and the one back): its callers' q, k, v
+    # come out of other fusions - RoPE, an all-to-all, a shard - that
+    # write the rows wherever they are told. A fused projection goes to
+    # ``flash_attention_qkv``, which has none.
     def to_rows(x):
         x = x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
         if S_pad != S:
