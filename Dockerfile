@@ -28,9 +28,10 @@ ENV JAX_PLATFORMS=cpu NUM_STEPS=30
 # deployments run one container per replica group pointed at a shared
 # lighthouse via TORCHFT_LIGHTHOUSE (docs/OPERATIONS.md). On a TPU host a
 # chip belongs to one process: either one container per group with its
-# own chips, or one launcher per host with --chips-per-group N, which
-# pins group g (and every restart of it) to chips [g*N, (g+1)*N) before
-# the group's first backend initialisation. The launcher itself never
-# touches the JAX backend. `python chip_smoke.py` proves the layout.
+# own chip, or one launcher per host with --chips-per-group 1, which
+# pins group g (and every restart of it) to chip g before the group's
+# first backend initialisation (more than one chip a group is refused:
+# ROADMAP S2). The launcher itself never touches the JAX backend.
+# `python chip_smoke.py` proves the layout; speeds are in PERF.md.
 CMD ["torchft-tpu-launcher", "--num-replica-groups", "1", \
      "python", "examples/train_ddp.py"]

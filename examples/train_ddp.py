@@ -136,7 +136,7 @@ def build_model():
     model = os.environ.get("MODEL", "mlp")
     if model == "lm":
         # the flagship decoder-only transformer family (tiny config for
-        # the CPU demo; the TPU-scale configs live in bench.py)
+        # the CPU demo; the chip's sizes are benchmark/configs/)
         from torchft_tpu.models import (
             TransformerConfig,
             init_params as lm_init,

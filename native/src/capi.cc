@@ -186,8 +186,8 @@ int tft_region_quorum_json(void* handle, char** out) {
 // ---- LeaseClient (persistent lighthouse-protocol client) ----
 
 // A LighthouseClient handle for batch lease renewal / heartbeat / depart
-// over ONE persistent connection — the wire surface bench_lighthouse's
-// simulated groups and host-level renewal batchers ride.
+// over ONE persistent connection — the wire surface host-level renewal
+// batchers and the control-plane tests' simulated groups ride.
 
 void* tft_lease_client_create(const char* addr, int64_t connect_timeout_ms) {
   return new LighthouseClient(addr, connect_timeout_ms);

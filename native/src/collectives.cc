@@ -631,16 +631,12 @@ void HostCollectives::configure(const std::string& store_addr, int64_t rank,
     hier_ = hier;
     topo_hash_ = topo;
     shm_ring_bytes_ = env_shm_ring_bytes();
-    // Per-connection send caps, per tier: the main knob paces the
-    // slow/wide-area links (the flat ring's edges, the inter hop), the
-    // intra knob optionally paces the fast in-region links (0 = unpaced
-    // — the default, and what the fast-intra/slow-inter emulation in
-    // bench_overlap --hier-sweep relies on). Snapshotted here so the
-    // knobs are stable for the lifetime of a ring.
+    // Per-connection send cap: TORCHFT_HC_WIRE_CAP_MBPS paces the
+    // slow/wide-area links (the flat ring's edges, the inter hop); the
+    // fast in-region links (intra, host) are never paced. Snapshotted
+    // here so the knob is stable for the lifetime of a ring.
     const int64_t cap_main =
         cap_to_bps(std::getenv("TORCHFT_HC_WIRE_CAP_MBPS"));
-    const int64_t cap_intra =
-        cap_to_bps(std::getenv("TORCHFT_HC_WIRE_CAP_INTRA_MBPS"));
     auto init_tier = [](RingTier& T, const char* name, int64_t trank,
                         int64_t tworld, int64_t conns, int64_t cap) {
       T.rank = trank;
@@ -658,7 +654,7 @@ void HostCollectives::configure(const std::string& store_addr, int64_t rank,
       // Only HOST LEADERS participate in the intra (and inter) rings;
       // world stays 0 for everyone else so op bodies branch uniformly.
       init_tier(intra_, "intra", intra_rank,
-                is_host_leader ? intra_world : 0, stripes, cap_intra);
+                is_host_leader ? intra_world : 0, stripes, /*cap=*/0);
       init_tier(inter_, "inter", inter_rank, is_leader ? inter_world : 0,
                 stripes_inter, cap_main);
       // The host ring is intra-host by construction: never paced (there

@@ -150,14 +150,12 @@ enum class HierWire : int {
   kQ8 = 2,     // leaders ride the quantized ring (f32 payloads, SUM only)
 };
 
-// Token bucket for per-connection send pacing (TORCHFT_HC_WIRE_CAP_MBPS /
-// TORCHFT_HC_WIRE_CAP_INTRA_MBPS). Two uses: QoS — cap the gradient ring's
-// per-connection rate so it cannot starve heal/checkpoint traffic on a
-// shared NIC — and transport validation, emulating a per-connection-limited
-// path (TCP window / BDP cap, a throttled or wide-area inter-region
-// hop) on loopback so the stripe and hierarchy sweeps can measure where the
-// real win lives. Pure pacing: no wire-format or schedule effect, so
-// members need NOT agree on it.
+// Token bucket for per-connection send pacing (TORCHFT_HC_WIRE_CAP_MBPS).
+// Two uses: QoS — cap the gradient ring's per-connection rate so it cannot
+// starve heal/checkpoint traffic on a shared NIC — and emulating a
+// per-connection-limited path on loopback (scripts/chaos_run.py measures
+// the wire CRC's cost under one). Pure pacing: no wire-format or schedule
+// effect, so members need NOT agree on it.
 struct PaceState {
   double tokens = 0;  // bytes available to send now
   std::chrono::steady_clock::time_point last{};

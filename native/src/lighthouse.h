@@ -154,7 +154,7 @@ class Lighthouse {
   };
   std::map<std::string, RegionInfo> regions_ TFT_GUARDED_BY(mu_);
 
-  // Tick cost counters ("root CPU per tick" in LIGHTHOUSE_BENCH). Idle
+  // Tick cost counters (/status.json `tick.computed` vs `tick.total`). Idle
   // ticks — no registered participant, so no quorum can possibly form —
   // skip the O(groups) membership scan entirely; that is the lease-based
   // replacement for the unconditional per-tick recompute.

@@ -22,7 +22,7 @@
 namespace tft {
 
 // Client for the lighthouse protocol (used by ManagerServer, the region
-// tier's upstream side, bench_lighthouse simulated groups, and tests).
+// tier's upstream side, host-level renewal batchers, and tests).
 class LighthouseClient {
  public:
   LighthouseClient(const std::string& addr, int64_t connect_timeout_ms);

@@ -154,40 +154,6 @@ class TestFaultPlan:
         assert splitmix64(0) == 0xE220A8397B1DCDAF
 
 
-class TestBenchFaultStamp:
-    """The ``fault_plan`` key every bench artifact carries (bench_churn /
-    bench_dcn / bench_policy): whatever produced the run must be
-    replayable from the stamp."""
-
-    def test_explicit_plan_wins(self, monkeypatch):
-        from torchft_tpu.chaos import bench_fault_stamp
-
-        monkeypatch.setenv("TORCHFT_CHAOS_SEED", "999")
-        plan = FaultPlan.random(3, steps=4, members=2)
-        stamp = bench_fault_stamp(plan=plan, bench="x")
-        assert stamp["seed"] == 3
-        assert FaultPlan.from_json(stamp["plan"]) == plan
-        assert stamp["bench"] == "x"
-
-    def test_env_seed_and_plan_contract(self, monkeypatch):
-        from torchft_tpu.chaos import bench_fault_stamp
-
-        monkeypatch.delenv("TORCHFT_CHAOS_PLAN", raising=False)
-        monkeypatch.setenv("TORCHFT_CHAOS_SEED", "77")
-        assert bench_fault_stamp()["seed"] == 77
-        plan = FaultPlan.random(12, steps=4, members=2)
-        monkeypatch.setenv("TORCHFT_CHAOS_PLAN", plan.to_json())
-        stamp = bench_fault_stamp(kill_every=100)
-        assert stamp["seed"] == 12 and stamp["kill_every"] == 100
-
-    def test_unseeded_run_stamps_none(self, monkeypatch):
-        from torchft_tpu.chaos import bench_fault_stamp
-
-        monkeypatch.delenv("TORCHFT_CHAOS_PLAN", raising=False)
-        monkeypatch.delenv("TORCHFT_CHAOS_SEED", raising=False)
-        assert bench_fault_stamp()["seed"] is None
-
-
 class TestNativeFaultEngine:
     def test_arm_disarm_states(self):
         assert not _native.fault_armed()

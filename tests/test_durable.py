@@ -122,6 +122,11 @@ def test_save_restore_roundtrip(rig, tmp_path):
         stats = ckpt2.last_restore_stats
         assert stats is not None and stats["world"] == 1
         assert stats["dropped_tail_bytes"] == 0
+        # every bucket of the cold restore is timed: a resume that cannot
+        # say where its seconds went cannot be tuned
+        for bucket in ("manifest_read_s", "shard_fetch_s", "reshard_s",
+                       "h2d_s"):
+            assert stats[bucket] >= 0.0, bucket
     finally:
         ckpt2.close()
         manager2.shutdown()

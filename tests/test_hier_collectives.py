@@ -13,6 +13,10 @@ bit-compared.
 """
 
 import json
+import os
+import signal
+import subprocess
+import sys
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -852,6 +856,21 @@ HOST_LAYOUTS = [
 ]
 
 
+# Rank 1 of a two-member co-hosted ring: one op to prove the shm tier is
+# up, then it parks until the test SIGKILLs it.
+_COHOSTED_PEER = """
+import sys, time
+from datetime import timedelta
+import numpy as np
+from torchft_tpu.collectives import HostCollectives, ReduceOp
+col = HostCollectives(timeout=timedelta(seconds=60), stripes=1)
+col.configure(sys.argv[1], 1, 2, None, ["hK", "hK"])
+col.allreduce_hier(np.ones(1 << 16, np.float32), ReduceOp.SUM).wait()
+print("ready", flush=True)
+time.sleep(600)
+"""
+
+
 class TestShmTier:
     """The zero-copy intra-host tier: shm rings below the region tiers,
     bit-identity pinned against the three-tier numpy oracle, the
@@ -1060,6 +1079,44 @@ class TestShmTier:
             assert dt < 30.0, f"survivor blocked {dt:.1f}s (deadline leak)"
         for c in cols:
             c.shutdown()
+
+    def test_cohosted_sigkill_surfaces_within_deadline(self, store):
+        # A SIGKILLed co-hosted peer closes no socket and poisons no ring
+        # magic: the survivor's only signal is the pid-liveness probe its
+        # blocked shm waiter runs each futex slice. The death must surface
+        # well inside the op deadline, and the survivor must be able to
+        # reconfigure and commit alone.
+        hosts = ["hK", "hK"]
+        addr = f"{store.address()}/cokill"
+        child = subprocess.Popen(
+            [sys.executable, "-c", _COHOSTED_PEER, addr],
+            stdout=subprocess.PIPE, text=True,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        )
+        col = HostCollectives(timeout=timedelta(seconds=60), stripes=1)
+        try:
+            col.configure(addr, 0, 2, None, hosts)
+            assert col.hier_capable()
+            data = np.ones(1 << 16, np.float32)
+            out = col.allreduce_hier(data.copy(), ReduceOp.SUM).wait()
+            np.testing.assert_array_equal(out, data * 2)
+            assert child.stdout.readline().strip() == "ready"
+            os.kill(child.pid, signal.SIGKILL)
+            child.wait(timeout=30)
+            start = time.perf_counter()
+            with pytest.raises(Exception):  # noqa: B017 - any ring error
+                col.allreduce_hier(data.copy(), ReduceOp.SUM).wait()
+            dt = time.perf_counter() - start
+            assert dt < 20.0, f"survivor blocked {dt:.1f}s of a 60 s deadline"
+            col.configure(f"{store.address()}/cokill_alone", 0, 1)
+            np.testing.assert_array_equal(
+                col.allreduce(data.copy(), ReduceOp.SUM).wait(), data
+            )
+        finally:
+            if child.poll() is None:
+                child.kill()
+            child.wait()
+            col.shutdown()
 
     def test_stale_frame_detected_as_wire_corruption(self, store):
         # The shm_ring bit_flip fault replays a stale frame sequence; the

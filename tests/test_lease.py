@@ -216,7 +216,7 @@ class TestLiveLeases:
 
     def test_status_json_over_http_matches(self):
         # The satellite contract: the JSON view is served NEXT TO the HTML
-        # dashboard and is what bench_lighthouse consumes.
+        # dashboard and is what lighthouse.fetch_status consumes.
         with Lighthouse(min_replicas=1, join_timeout_ms=100) as lh:
             c = LeaseClient(lh.address())
             c.renew([entry("g0", 3000, True)])

@@ -14,6 +14,7 @@ from unittest.mock import MagicMock, patch
 import numpy as np
 import pytest
 
+from tools.graftlint.latch_discipline import MANAGED_OPS
 from torchft_tpu._native import QuorumResult, Store, StoreClient
 from torchft_tpu.collectives import DummyCollectives, ReduceOp, Work
 from torchft_tpu.manager import (
@@ -86,6 +87,7 @@ def _create_manager(
     load_state_dict=None,
     state_dict=None,
     transport=None,
+    iso_collectives=None,
 ):
     collectives = collectives if collectives is not None else DummyCollectives()
     transport = transport if transport is not None else MagicMock()
@@ -108,8 +110,30 @@ def _create_manager(
         world_size=2,
         store_addr=store.address(),
         checkpoint_transport=transport,
+        iso_collectives=iso_collectives,
     )
     return manager, client, collectives, transport
+
+
+class _UntouchableCollectives(DummyCollectives):
+    """A backend whose managed ops record the call and fail: for tests of
+    paths that must return before any backend is reached."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.touched = []
+
+
+def _touch(name):
+    def op(self, *args, **kwargs):
+        self.touched.append(name)
+        raise AssertionError(f"backend op {name} reached")
+
+    return op
+
+
+for _name in sorted(MANAGED_OPS):  # the lint rule's list: one to extend
+    setattr(_UntouchableCollectives, _name, _touch(_name))
 
 
 class TestManagerState:
@@ -336,14 +360,40 @@ class TestErrorHandling:
         assert not m.should_commit()
         m.shutdown()
 
-    def test_errored_allreduce_is_noop(self, store):
-        m, client, col, _ = _create_manager(store)
+    @pytest.mark.parametrize(
+        "op_name, default",
+        [
+            ("allreduce", "tree"),
+            ("plan_allreduce", None),
+            ("allreduce_hier", "tree"),
+            ("iso_allreduce", None),
+            ("reduce_scatter", None),
+            ("allgather_into", None),
+            ("plan_reduce_scatter", None),
+            ("plan_allgather_into", None),
+            ("allgather", "[tree]"),
+        ],
+    )
+    def test_errored_managed_op_is_noop(self, store, op_name, default):
+        # An error latched before the op is issued short-circuits every
+        # managed op to its documented default without touching either
+        # backend: the input tree, None, or [tree].
+        col, iso = _UntouchableCollectives(), _UntouchableCollectives()
+        m, client, _, _ = _create_manager(
+            store, collectives=col, iso_collectives=iso
+        )
         client.quorum.return_value = _quorum_result()
         m.start_quorum()
         m.report_error(RuntimeError("user error"))
-        out = m.allreduce({"g": np.ones(1)}).wait()
-        np.testing.assert_array_equal(out["g"], np.ones(1))
-        assert col.op_count == 0  # never reached the collectives
+        tree = {"g": np.ones(1)}
+        out = getattr(m, op_name)(tree).wait()
+        if default == "tree":
+            assert out is tree
+        elif default == "[tree]":
+            assert len(out) == 1 and out[0] is tree
+        else:
+            assert out is None
+        assert col.touched == [] and iso.touched == []
         m.shutdown()
 
     def test_error_requests_force_reconfigure(self, store):
@@ -678,15 +728,31 @@ class TestShardedManagedDispatch:
         m.shutdown()
 
 
-def test_reduce_scatter_bad_op_raises_eagerly(store):
+@pytest.mark.parametrize(
+    "op_name",
+    [
+        "allreduce",
+        "plan_allreduce",
+        "allreduce_hier",
+        "iso_allreduce",
+        "reduce_scatter",
+        "plan_reduce_scatter",
+    ],
+)
+def test_reduction_bad_op_raises_eagerly(store, op_name):
     # A static usage error must raise at the call site, not be latched as
-    # a cohort data-plane failure.
-    m, client, _, _ = _create_manager(store)
+    # a cohort data-plane failure (which would force a reconfigure and
+    # discard the step).
+    col, iso = _UntouchableCollectives(), _UntouchableCollectives()
+    m, client, _, _ = _create_manager(
+        store, collectives=col, iso_collectives=iso
+    )
     client.quorum.return_value = _quorum_result()
     m.start_quorum()
-    with pytest.raises(ValueError, match="unsupported managed"):
-        m.reduce_scatter({"g": np.ones(2, np.float32)}, op=ReduceOp.MAX)
+    with pytest.raises(ValueError, match=f"unsupported managed {op_name} op"):
+        getattr(m, op_name)({"g": np.ones(2, np.float32)}, op=ReduceOp.MAX)
     assert m.errored() is None
+    assert col.touched == [] and iso.touched == []
     m.shutdown()
 
 

@@ -365,7 +365,8 @@ class TestShardedPlanLegStats:
     reduce-scatter leg and the param allgather leg bill as SEPARATE
     phase keys, each with its own wire_bytes/d2h_bytes, and the plan's
     per-bucket detail tags each bucket with its leg — the data the
-    SHARD_BENCH "wins memory/FLOPs, not bytes" caveat is read from."""
+    "wins memory/FLOPs, not bytes" caveat of docs/OPERATIONS.md is read
+    from."""
 
     def _sharded_step(self, c, tree, wire=None, ag_wire=None):
         sh = c.plan_reduce_scatter(

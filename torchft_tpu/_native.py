@@ -96,7 +96,7 @@ def _load_lib() -> ctypes.CDLL:
     ]
 
     # Persistent lighthouse-protocol client: batched lease renewal /
-    # heartbeat / depart over ONE connection (bench simulated groups).
+    # heartbeat / depart over ONE connection (host-level batchers, tests).
     lib.tft_lease_client_create.restype = ctypes.c_void_p
     lib.tft_lease_client_create.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.tft_lease_client_destroy.argtypes = [ctypes.c_void_p]
@@ -854,8 +854,8 @@ class RegionLighthouse:
 class LeaseClient:
     """Persistent lighthouse-protocol client: batched lease renewals,
     heartbeats and explicit departs over ONE connection. The client surface
-    bench_lighthouse's simulated groups (and host-level renewal batchers)
-    ride; real managers renew through their native server instead."""
+    host-level renewal batchers (and the control-plane tests' simulated
+    groups) ride; real managers renew through their native server instead."""
 
     def __init__(
         self, addr: str, connect_timeout: timedelta = timedelta(seconds=10)

@@ -842,36 +842,3 @@ def tear_shm(name: str) -> None:
     except RuntimeError:
         pass
 
-
-# -- bench artifact stamping -------------------------------------------------
-
-
-def bench_fault_stamp(plan: Optional[FaultPlan] = None, **bench_fields: Any) -> dict:
-    """The ``fault_plan`` key every bench artifact carries: the seeded
-    schedule that produced the run (explicit ``plan``, else the
-    ``TORCHFT_CHAOS_SEED`` / ``TORCHFT_CHAOS_PLAN`` env contract), plus
-    the bench's OWN fault knobs (kill cadence etc.) so a bench-observed
-    anomaly replays via ``scripts/chaos_run.py --seed``."""
-    out: Dict[str, Any] = dict(bench_fields)
-    env_plan = os.environ.get("TORCHFT_CHAOS_PLAN")
-    env_seed = os.environ.get("TORCHFT_CHAOS_SEED")
-    if plan is not None:
-        out.update(plan.fingerprint())
-    elif env_plan:
-        try:
-            out.update(FaultPlan.from_json(env_plan).fingerprint())
-        except (ValueError, KeyError, json.JSONDecodeError):
-            out["plan_parse_error"] = True
-            out["plan"] = env_plan
-    elif env_seed:
-        # Degrade, never raise: the stamp runs at artifact-write time,
-        # the very last step of a potentially hour-long bench — a typo'd
-        # seed must not discard the run's results.
-        try:
-            out["seed"] = int(env_seed)
-        except ValueError:
-            out["seed_parse_error"] = True
-            out["seed"] = env_seed
-    else:
-        out["seed"] = None
-    return out

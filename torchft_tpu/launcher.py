@@ -191,12 +191,12 @@ def launch(
     standby runs the same command with ``TORCHFT_STANDBY_FILE`` set and
     parks at :func:`torchft_tpu.platform.standby_gate` after its imports
     and jit warm-up; on a primary death the supervisor activates it by
-    creating the file (promotion is one poll interval, vs ~14 s of
-    interpreter+import+compile for a cold restart — CHURN_BENCH.json heal
-    breakdown) and spawns a fresh standby in the background. The command
-    must call ``standby_gate()`` before creating its Manager. Constraint:
-    the standby warms on the SAME host as its primary, so this local
-    launcher's hot-spare mode suits CPU workloads and multi-chip hosts;
+    creating the file (promotion is one poll interval, where a cold
+    restart pays interpreter, imports, backend start and compile: 32.8 s
+    on the chip, ROADMAP S5) and spawns a fresh standby in the background.
+    The command must call ``standby_gate()`` before creating its Manager.
+    Constraint: the standby warms on the SAME host as its primary, so this
+    local launcher's hot-spare mode suits CPU workloads and multi-chip hosts;
     on a single-chip accelerator host the standby cannot warm the chip
     the primary owns (see standby_gate's deployment note).
 

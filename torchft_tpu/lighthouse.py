@@ -19,8 +19,8 @@ Three roles (``--role``):
 
 Every role serves ``GET /status.json`` (machine-readable members, lease
 deadlines, last quorum id, tier role) next to the HTML dashboard;
-:func:`fetch_status` is the programmatic consumer (bench_lighthouse uses it
-instead of scraping HTML).
+:func:`fetch_status` is the programmatic consumer (no scraping of the
+HTML).
 """
 
 from __future__ import annotations

@@ -615,23 +615,11 @@ class Manager:
         (``"q8"`` = int8-quantized ring chunks, constant wire bytes in
         world size — see Collectives.allreduce).
         """
+        divisor = self._participant_divisor(op, "allreduce")
+
         def dispatch(zeroed_tree: Any) -> Work:
-            if op == ReduceOp.AVG:
-                # The participant average rides the collectives' divisor
-                # path (applied host-side in the ring, where the bytes
-                # already are) — no extra jit program or device dispatch
-                # per step. Divisor = num_participants, NOT ring size:
-                # healing/spare members contribute zeros and don't count
-                # (reference manager.py:279-291).
-                num_participants = self.num_participants()
-                assert num_participants >= 1
-                divisor: Optional[float] = float(num_participants)
-            elif op == ReduceOp.SUM:
-                divisor = None
-            else:
-                raise ValueError(f"unsupported managed allreduce op: {op}")
             return self._collectives.allreduce(
-                zeroed_tree, ReduceOp.SUM, divisor=divisor, wire=wire
+                zeroed_tree, ReduceOp.SUM, divisor=divisor(), wire=wire
             )
 
         return self._managed_dispatch("allreduce", tree, dispatch, lambda t: t)
@@ -666,19 +654,11 @@ class Manager:
         a usable region map latches the error and the step is discarded
         — the sentinel path AdaptiveDDP's ``plan_hier`` candidate relies
         on, never a crash."""
-        if op not in (ReduceOp.AVG, ReduceOp.SUM):
-            # Static usage error: raise eagerly, don't latch.
-            raise ValueError(f"unsupported managed plan_allreduce op: {op}")
+        divisor = self._participant_divisor(op, "plan_allreduce")
 
         def dispatch(zeroed_tree: Any) -> Work:
-            if op == ReduceOp.AVG:
-                num_participants = self.num_participants()
-                assert num_participants >= 1
-                divisor: Optional[float] = float(num_participants)
-            else:
-                divisor = None
             return self._collectives.plan_allreduce(
-                zeroed_tree, ReduceOp.SUM, divisor=divisor, wire=wire,
+                zeroed_tree, ReduceOp.SUM, divisor=divisor(), wire=wire,
                 device_pack=device_pack, hier=hier,
             )
 
@@ -704,18 +684,11 @@ class Manager:
         region map is unusable (single region, unlabeled members, or a
         backend without the schedule) latches the dispatch error — the
         sentinel discipline, never a crash."""
-        if op not in (ReduceOp.AVG, ReduceOp.SUM):
-            raise ValueError(f"unsupported managed allreduce_hier op: {op}")
+        divisor = self._participant_divisor(op, "allreduce_hier")
 
         def dispatch(zeroed_tree: Any) -> Work:
-            if op == ReduceOp.AVG:
-                num_participants = self.num_participants()
-                assert num_participants >= 1
-                divisor: Optional[float] = float(num_participants)
-            else:
-                divisor = None
             return self._collectives.allreduce_hier(
-                zeroed_tree, ReduceOp.SUM, divisor=divisor, wire=wire
+                zeroed_tree, ReduceOp.SUM, divisor=divisor(), wire=wire
             )
 
         return self._managed_dispatch(
@@ -794,8 +767,7 @@ class Manager:
                 "no isolated data plane: construct the Manager with "
                 "iso_collectives=IsolatedXLACollectives(...)"
             )
-        if op not in (ReduceOp.AVG, ReduceOp.SUM):
-            raise ValueError(f"unsupported managed iso_allreduce op: {op}")
+        divisor = self._participant_divisor(op, "iso_allreduce")
 
         def dispatch(zeroed_tree: Any) -> Work:
             if not self._iso_ok:
@@ -803,14 +775,8 @@ class Manager:
                     "isolated data plane unusable this quorum (its "
                     "configure failed; primary plane unaffected)"
                 )
-            if op == ReduceOp.AVG:
-                num_participants = self.num_participants()
-                assert num_participants >= 1
-                divisor: Optional[float] = float(num_participants)
-            else:
-                divisor = None
             return self._iso_collectives.allreduce(
-                zeroed_tree, ReduceOp.SUM, divisor=divisor, wire=wire
+                zeroed_tree, ReduceOp.SUM, divisor=divisor(), wire=wire
             )
 
         return self._managed_dispatch(
@@ -844,21 +810,11 @@ class Manager:
         SUM; ``wire="q8"`` reduces over the quantized ring (the returned
         shard is full f32 — the fused op's lossy allgather phase never
         runs)."""
-        if op not in (ReduceOp.AVG, ReduceOp.SUM):
-            # Raise eagerly: a static usage error must not be swallowed by
-            # the managed error discipline and masquerade as a cohort
-            # data-plane failure.
-            raise ValueError(f"unsupported managed reduce_scatter op: {op}")
+        divisor = self._participant_divisor(op, "reduce_scatter")
 
         def dispatch(zeroed_tree: Any) -> Work:
-            if op == ReduceOp.AVG:
-                num_participants = self.num_participants()
-                assert num_participants >= 1
-                divisor: Optional[float] = float(num_participants)
-            else:
-                divisor = None
             return self._collectives.reduce_scatter(
-                zeroed_tree, ReduceOp.SUM, divisor=divisor, wire=wire
+                zeroed_tree, ReduceOp.SUM, divisor=divisor(), wire=wire
             )
 
         return self._managed_dispatch(
@@ -908,21 +864,11 @@ class Manager:
         support) latches the dispatch error — the sentinel discipline
         AdaptiveDDP's ``ddp_sharded`` candidate relies on, never a
         crash."""
-        if op not in (ReduceOp.AVG, ReduceOp.SUM):
-            # Static usage error: raise eagerly, don't latch.
-            raise ValueError(
-                f"unsupported managed plan_reduce_scatter op: {op}"
-            )
+        divisor = self._participant_divisor(op, "plan_reduce_scatter")
 
         def dispatch(zeroed_tree: Any) -> Work:
-            if op == ReduceOp.AVG:
-                num_participants = self.num_participants()
-                assert num_participants >= 1
-                divisor: Optional[float] = float(num_participants)
-            else:
-                divisor = None
             return self._collectives.plan_reduce_scatter(
-                zeroed_tree, ReduceOp.SUM, divisor=divisor, wire=wire,
+                zeroed_tree, ReduceOp.SUM, divisor=divisor(), wire=wire,
                 ag_wire=ag_wire,
             )
 
@@ -970,6 +916,31 @@ class Manager:
         return self._managed_dispatch(
             "allgather", tree, self._collectives.allgather, lambda t: [t]
         )
+
+    def _participant_divisor(
+        self, op: ReduceOp, op_name: str
+    ) -> Callable[[], Optional[float]]:
+        """The one divisor rule of the six managed reduction ops. Raises
+        ``ValueError`` HERE, at the call site, for any ``op`` but AVG or
+        SUM: a static usage error must not be swallowed by the managed
+        error discipline and masquerade as a cohort data-plane failure.
+        Returns the rule for the dispatch closure to call once the quorum
+        is joined: AVG -> ``num_participants`` (the live divisor, NOT the
+        ring size: healing/spare members contribute zeros and don't
+        count, reference manager.py:279-291), applied host-side in the
+        ring where the bytes already are, so no extra jit program or
+        device dispatch per step; SUM -> ``None``."""
+        if op not in (ReduceOp.AVG, ReduceOp.SUM):
+            raise ValueError(f"unsupported managed {op_name} op: {op}")
+
+        def divisor() -> Optional[float]:
+            if op == ReduceOp.SUM:
+                return None
+            num_participants = self.num_participants()
+            assert num_participants >= 1
+            return float(num_participants)
+
+        return divisor
 
     def _managed_dispatch(
         self,

@@ -121,9 +121,9 @@ def tiny_config() -> TransformerConfig:
 def big_config() -> TransformerConfig:
     """The 111M-parameter dense LM, the one configuration with a history
     on the chip: MXU-shaped (d_model 1024, 16 heads x 64, d_ff 4096), run
-    at batch 16 x sequence 2048 with ``use_flash=True``. Shared by
-    ``bench.py`` and ``chip_smoke.py``. A stand-in, not a public
-    architecture (ROADMAP S1)."""
+    at batch 16 x sequence 2048 with ``use_flash=True``. What
+    ``chip_smoke.py``'s fleet phase trains. A stand-in, not a public
+    architecture (ROADMAP D7)."""
     return TransformerConfig(
         vocab_size=8192, d_model=1024, n_heads=16, n_layers=8, d_ff=4096,
         max_seq_len=2048,

@@ -12,7 +12,7 @@ import os
 from typing import Mapping, Optional
 
 # The one compile cache of a checkout: every entry point — chip_smoke.py,
-# examples/train_ddp.py, the launcher's children, the bench scripts —
+# examples/train_ddp.py, the launcher's children, benchmark/run.py —
 # resolves here, so a restarted group (or the next run) finds what the
 # last one compiled. The path is part of JAX's cache key; it must not
 # move with the job, the pid or the time.
@@ -30,8 +30,8 @@ def standby_gate() -> None:
 
     This is the process-level analog of ``WorldSizeMode.FIXED_WITH_SPARES``:
     a cold restart pays interpreter + library import + compile before it
-    can heal (~14 s measured under 4-way CPU contention, CHURN_BENCH.json
-    heal breakdown); a promoted standby pays none of it. The launcher's
+    can heal (32.8 s before a restarted group is ready on the chip,
+    ROADMAP S5); a promoted standby pays none of it. The launcher's
     ``--hot-spare`` mode manages the standby lifecycle
     (torchft_tpu.launcher).
 

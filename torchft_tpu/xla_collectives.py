@@ -22,8 +22,8 @@ reference torchft/process_group.py:299-315):
     lives while referenced) and implicit transfers let new jits consume
     them, but they pin old-backend memory and none of this is contractual
     on accelerator backends — snapshot training state to host around a
-    reconfigure. Measured at ~1.0-1.2 s per reconfigure on CPU vs ~1 ms
-    for the host ring (bench_dcn.py, DCN.md).
+    reconfigure. About a second per reconfigure on the CPU backend where
+    the host ring takes about a millisecond (DCN.md).
   * a peer that dies mid-collective wedges the compiled op until the
     distributed-runtime heartbeat gives up (minutes by default) — exactly
     the hazard the reference isolates NCCL in a subprocess for (reference
