@@ -126,3 +126,164 @@ def test_transformer_gradient_has_two_mosaic_calls_a_layer(n_heads, seq, monkeyp
     tokens = jax.ShapeDtypeStruct((2, seq), jnp.int32)
     text = _lowered_text(jax.grad(lambda p, t: loss_fn(cfg, p, t)), params, tokens)
     assert text.count("tpu_custom_call") == 2 * cfg.n_layers
+
+
+# -- the loss stores the readout's product in the width it was computed in ---
+# (PR 30). ``loss_fn`` hands ``next_token_loss`` the bf16 product of the
+# readout matmul; ``forward`` widens it for whoever asks for logits. The
+# softmax still runs in float32, so float32 values of the logits' shape
+# exist INSIDE the elementwise passes; what must not exist is one that is
+# kept: a residual of the backward pass, an operand or result of a matmul,
+# or (compiled for the chip) an array of the program's entry computation.
+
+
+def _family(name):
+    from torchft_tpu import models
+
+    if name == "olmoe":
+        cfg = models.tiny_olmoe_config()
+        return (
+            cfg, models.olmoe.init_params, models.olmoe.loss_fn,
+            lambda c, p, t: models.olmoe.forward(c, p, t)[0],
+        )
+    cfg = models.tiny_config()
+    return cfg, models.init_params, models.loss_fn, models.forward
+
+
+def _bf16_copy(tree):
+    return jax.tree_util.tree_map(
+        lambda l: l.astype(jnp.bfloat16) if l.dtype == jnp.float32 else l, tree
+    )
+
+
+def _small_case(family):
+    """(cfg, loss_fn, forward, bf16 compute copy, tokens (2, 17)) of a
+    family at its tiny sizes: logits of shape (2, 16, vocab)."""
+    cfg, init, loss_fn, forward = _family(family)
+    compute = _bf16_copy(init(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0, cfg.vocab_size)
+    return cfg, loss_fn, forward, compute, tokens
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its
+    equations' parameters (pjit, custom_vjp, remat, scan)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def _kept_f32(loss, compute, shape):
+    """What ``value_and_grad(loss)`` keeps in float32 at ``shape``: the
+    backward pass's residuals, and matmul operands and results."""
+    _, pullback = jax.vjp(loss, compute)
+    kept = [
+        f"residual {leaf.dtype}{list(leaf.shape)}"
+        for leaf in jax.tree_util.tree_leaves(pullback)
+        if getattr(leaf, "shape", None) == shape and leaf.dtype == jnp.float32
+    ]
+    closed = jax.make_jaxpr(jax.value_and_grad(loss))(compute)
+    for eqn in _equations(closed.jaxpr):
+        if eqn.primitive.name == "dot_general":
+            kept += [
+                f"dot_general {v.aval.str_short()}"
+                for v in (*eqn.invars, *eqn.outvars)
+                if v.aval.shape == shape and v.aval.dtype == jnp.float32
+            ]
+    return kept
+
+
+@pytest.mark.parametrize("family", ["dense", "olmoe"])
+def test_the_training_loss_keeps_no_float32_logits(family):
+    cfg, loss_fn, _, compute, tokens = _small_case(family)
+    shape = (2, 16, cfg.vocab_size)
+    assert _kept_f32(lambda p: loss_fn(cfg, p, tokens), compute, shape) == []
+
+
+def test_the_guard_sees_the_formula_this_replaced():
+    """Autodiff through ``log_softmax`` of the widened logits keeps a
+    float32 array of their shape for the backward pass: the guard above
+    must name it, or it guards nothing."""
+    cfg, _, forward, compute, tokens = _small_case("dense")
+
+    def parents_loss(p):
+        logp = jax.nn.log_softmax(forward(cfg, p, tokens[:, :-1]), axis=-1)
+        return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1))
+
+    assert _kept_f32(parents_loss, compute, (2, 16, cfg.vocab_size)) != []
+
+
+@pytest.mark.parametrize("family", ["dense", "olmoe"])
+def test_forward_returns_the_product_widened(family):
+    """float32 logits for whoever asks for them, each a bf16 value, and
+    the loss of them is ``loss_fn``'s: one model, two widths of storage."""
+    cfg, loss_fn, forward, compute, tokens = _small_case(family)
+    logits = forward(cfg, compute, tokens[:, :-1])
+    assert logits.dtype == jnp.float32 and logits.shape == (2, 16, cfg.vocab_size)
+    assert bool(jnp.all(logits == logits.astype(jnp.bfloat16).astype(jnp.float32)))
+    if family == "dense":  # OLMoE's loss adds the router's two terms
+        from torchft_tpu.models.transformer import next_token_loss
+
+        want = next_token_loss(logits, tokens[:, 1:])
+        assert abs(float(loss_fn(cfg, compute, tokens)) - float(want)) <= 1e-6 * float(want)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip for the TPU compiler; made in
+    this fixture and nowhere at import, so every xdist worker collects
+    the same tests and only the one that runs them loads the library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever the library says
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("family", ["dense", "olmoe"])
+def test_the_compiled_gradient_stores_no_float32_logits(family, one_chip):
+    """What the TPU compiler makes of it: an instruction of the entry
+    computation is an array in HBM; those inside a fusion are not. The
+    gradient step at sizes where the logits are the largest array (4 x 512
+    x 8192) must hold them in bf16 only. The parent's held ``f32[B,S,V]
+    fusion(...)``, the shifted logits, written for a gather of one value a
+    row."""
+    import dataclasses
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cfg, init, loss_fn, _ = _family(family)
+    cfg = dataclasses.replace(
+        cfg, vocab_size=8192, **({"max_seq_len": 512} if family == "dense" else {})
+    )
+    params = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), params
+    )
+    tokens = jax.ShapeDtypeStruct((4, 513), jnp.int32, sharding=one_chip)
+
+    def loss_and_grads(masters, tokens):
+        return jax.value_and_grad(lambda q: loss_fn(cfg, q, tokens))(_bf16_copy(masters))
+
+    # such a compile is written to the persistent cache and cannot be read
+    # back without a chip: keep it out
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(loss_and_grads).lower(params, tokens).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    entry = text[text.index("ENTRY"):]
+    logits = re.findall(r"= \(?[^=]*?\b(f32|bf16)\[4,512,8192\]", entry)
+    assert "bf16" in logits, "the logits are not an array of this program at all"
+    assert "f32" not in logits, re.findall(r".*f32\[4,512,8192\].*", entry)[:3]

@@ -260,20 +260,38 @@ def _block(
         return x + y, stats
 
 
-def forward(
+def _hidden(
     cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """tokens (B, S) int32 -> (logits (B, S, vocab) f32, the router's
-    sums over every layer and token)."""
+    """tokens (B, S) int32 -> (the last block's output (B, S, D), the
+    router's sums over every layer and token)."""
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
     total = None
     for p in params["blocks"]:
         x, stats = _block(cfg, p, x)
         total = stats if total is None else jax.tree_util.tree_map(jnp.add, total, stats)
+    return x, total
+
+
+def _readout_product(
+    cfg: OlmoeConfig, params: Dict[str, Any], x: jax.Array
+) -> jax.Array:
+    """Final norm, then the untied readout matmul: logits (..., V) in
+    ``cfg.dtype``, the type the product is computed in. ``forward`` widens
+    it to float32; ``loss_fn`` hands it to ``next_token_loss`` as it is."""
+    x = _rmsnorm(x, params["ln_f"]["scale"], cfg.rms_norm_eps)
+    return x @ params["readout"].astype(cfg.dtype)
+
+
+def forward(
+    cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """tokens (B, S) int32 -> (logits (B, S, vocab) f32, the router's
+    sums over every layer and token)."""
+    x, total = _hidden(cfg, params, tokens)
     with jax.named_scope("readout"):
-        x = _rmsnorm(x, params["ln_f"]["scale"], cfg.rms_norm_eps)
-        logits = (x @ params["readout"].astype(cfg.dtype)).astype(jnp.float32)
+        logits = _readout_product(cfg, params, x).astype(jnp.float32)
     return logits, total
 
 
@@ -290,9 +308,13 @@ def aux_losses(
 def loss_fn(
     cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
 ) -> jax.Array:
-    """Next-token cross entropy + the two weighted router losses."""
+    """Next-token cross entropy + the two weighted router losses. The
+    loss reads the readout's product in ``cfg.dtype``, unwidened
+    (``next_token_loss``)."""
     inputs = tokens[:, :-1]
-    logits, stats = forward(cfg, params, inputs)
+    x, stats = _hidden(cfg, params, inputs)
+    with jax.named_scope("readout"):
+        logits = _readout_product(cfg, params, x)
     with jax.named_scope("loss"), jax.named_scope("aux"):
         balance, z = aux_losses(cfg, stats, inputs.size)
     return (

@@ -196,14 +196,28 @@ def embed_tokens(
         return x + params["pos_embed"].astype(cfg.dtype)[:S]
 
 
+def _readout_product(
+    cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array
+) -> jax.Array:
+    """Final norm, then the weight-tied readout matmul: logits (..., V) in
+    ``cfg.dtype``, the type the product is computed in. ``readout`` /
+    ``forward`` return this widened to float32; ``loss_fn`` hands it to
+    ``next_token_loss`` as it is."""
+    return _rmsnorm(x, params["ln_f"]["scale"]) @ params["embed"].astype(cfg.dtype).T
+
+
 def readout(
     cfg: TransformerConfig, params: Dict[str, Any], x: jax.Array
 ) -> jax.Array:
-    """Final norm + weight-tied readout; f32 logits for a stable
-    softmax."""
+    """Final norm + weight-tied readout: float32 logits, the ``cfg.dtype``
+    product widened for whoever asks for logits (serving, a pipeline's
+    last stage). ``loss_fn`` hands ``next_token_loss`` the product
+    unwidened: a training step stores it in ``cfg.dtype`` either way, and
+    read there the softmax needs no float32 copy. (Jitted alone, the TPU
+    compiler folds this widening into the matmul and keeps the
+    accumulator's extra bits; PERF.md section 7.)"""
     with jax.named_scope("readout"):
-        x = _rmsnorm(x, params["ln_f"]["scale"])
-        return (x @ params["embed"].astype(cfg.dtype).T).astype(jnp.float32)
+        return _readout_product(cfg, params, x).astype(jnp.float32)
 
 
 def mlp_apply(
@@ -213,11 +227,53 @@ def mlp_apply(
     return h @ p["wo"].astype(cfg.dtype)
 
 
+@jax.custom_vjp
+def _cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    return _cross_entropy_fwd(logits, targets)[0]
+
+
+def _cross_entropy_fwd(logits: jax.Array, targets: jax.Array) -> Any:
+    f32 = jnp.float32
+    # a maximum rounds nothing, so it is taken in the logits' own type
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    total = jnp.sum(jnp.exp(logits.astype(f32) - top.astype(f32)), axis=-1)
+    lse = top[..., 0].astype(f32) + jnp.log(total)
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(lse - picked.astype(f32)), (logits, targets, top, total)
+
+
+def _cross_entropy_bwd(res: Any, g: jax.Array) -> Any:
+    # probabilities as exp(l - max) / sum, not exp(l - lse): normalised by
+    # the sum itself a row adds up to 1 whatever the device's log rounds to
+    logits, targets, top, total = res
+    f32 = jnp.float32
+    scale = g / total.size
+    hit = (
+        jax.lax.broadcasted_iota(targets.dtype, logits.shape, logits.ndim - 1)
+        == targets[..., None]
+    )
+    grad = jnp.exp(logits.astype(f32) - top.astype(f32)) * (scale / total)[..., None]
+    return (grad - jnp.where(hit, scale, 0.0)).astype(logits.dtype), None
+
+
+_cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
+
+
 def next_token_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """Mean cross entropy of ``logits`` (..., V) against int ``targets``
+    (...), in float32 whatever float type the logits come in.
+
+    The logits are read and kept in the type they were handed over in -
+    float32 from ``readout`` / ``forward``, ``cfg.dtype`` from the training
+    losses, which pass the readout matmul's product unwidened - and the
+    cotangent comes back in that type. Each pass widens what it reads
+    inside its own loop: forward a row maximum and a sum of exponentials
+    (residuals: the logits as they came and, a position, the maximum and
+    the float32 sum), backward one elementwise pass
+    ``(exp(l - max) / sum - onehot) * g / N``. No float32 array of the
+    logits' shape is stored unless the caller's logits are float32."""
     with jax.named_scope("loss"):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return -jnp.mean(ll)
+        return _cross_entropy(logits, targets)
 
 
 def attn_sublayer_specs() -> Dict[str, Any]:
@@ -372,18 +428,28 @@ def remat_wrap(cfg: TransformerConfig, fn, static_argnums=(0,)):
     return jax.checkpoint(fn, static_argnums=static_argnums)
 
 
-def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
-    """tokens (B, S) int32 -> logits (B, S, vocab) f32."""
+def _hidden(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
+    """tokens (B, S) int32 -> the last block's output (B, S, D)."""
     x = embed_tokens(cfg, params, tokens)
     block = remat_wrap(cfg, _block)
     for p in params["blocks"]:
         x = block(cfg, p, x)
-    return readout(cfg, params, x)
+    return x
+
+
+def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
+    """tokens (B, S) int32 -> logits (B, S, vocab) f32."""
+    return readout(cfg, params, _hidden(cfg, params, tokens))
 
 
 def loss_fn(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
-    """Next-token cross entropy over (B, S) int32 tokens."""
-    logits = forward(cfg, params, tokens[:, :-1])
+    """Next-token cross entropy over (B, S) int32 tokens. The loss reads
+    the readout's product in ``cfg.dtype``, the width it was computed in
+    (``next_token_loss``); ``forward`` is the same model with the logits
+    widened to float32."""
+    x = _hidden(cfg, params, tokens[:, :-1])
+    with jax.named_scope("readout"):
+        logits = _readout_product(cfg, params, x)
     return next_token_loss(logits, tokens[:, 1:])
 
 
