@@ -3,9 +3,13 @@ name, the one compile cache, the refusal to run without the chip, the
 weights and token pools from the seed, the per-step log and the
 profiler window.
 
-Nothing here imports ``bench.py``, ``bench_*.py`` or ``chip_smoke.py``;
-what was sound in them was copied (the start line, the kill marker, the
-Mosaic check, the peak table).
+Nothing here imports ``chip_smoke.py``; what was sound in it was copied
+(the start line, the kill marker, the Mosaic check, the peak table).
+
+Nothing here, and nothing in a generator, holds a number that is true of
+one family only: how many Mosaic calls a lowered step holds, how close its
+losses must come to its reference, what else a reader may want of it -
+each is the family's to say (``load_family``).
 """
 
 from __future__ import annotations
@@ -48,6 +52,26 @@ def load_by_name(directory: str, name: str) -> Any:
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+# What a family states (families/<family>.py; benchmark/README.md says what
+# each is). None has a default: a family that leaves one out is an error.
+FAMILY_STATES = (
+    "build", "init", "loss", "reference_train", "tokens_per_step",
+    "flops_per_step", "flash_calls", "lowered_mosaic_calls", "facts",
+    "LOSS_RTOL", "GRAD_NORM_RTOL",
+)
+
+
+def load_family(name: str) -> Any:
+    """``families/<name>.py``, which must state all of ``FAMILY_STATES``."""
+    family = load_by_name("families", name)
+    missing = [what for what in FAMILY_STATES if not hasattr(family, what)]
+    if missing:
+        raise AttributeError(
+            f"benchmark/families/{name}.py does not state {', '.join(missing)}"
+        )
+    return family
 
 
 def load_cell(name: str) -> Tuple[Dict[str, Any], Dict[str, Any]]:
@@ -130,12 +154,15 @@ def peaks(kind: str) -> Dict[str, float]:
 
 def require_mosaic(lowered: Any, want: int, what: str) -> None:
     """The step that is measured must carry the compiled kernels: a
-    Pallas interpret fallback lowers to no Mosaic custom call."""
+    Pallas interpret fallback lowers to no Mosaic custom call. ``want``
+    is the family's ``lowered_mosaic_calls(cfg)``: how many a step holds
+    follows from its layers' kinds and its kernels' structure, which the
+    family knows and the harness does not."""
     found = lowered.as_text().count("tpu_custom_call")
     if found != want:
         raise Refused(
             f"{what}: lowered module has {found} Mosaic custom call(s), "
-            f"want {want} - the kernels did not compile for the chip"
+            f"the family states {want} - the kernels did not compile for the chip"
         )
 
 
@@ -265,10 +292,9 @@ def check_first_steps(
     trains as many steps; loss k agrees only if the model, its gradient
     and k optimizer updates do. ``grad_norm``, where the generator has
     a gradient to show (``ft-sync``: the tree that crosses groups), is
-    that of step 0. Tolerances and their reason: reference.py."""
+    that of step 0. The tolerances are the family's (``LOSS_RTOL``,
+    ``GRAD_NORM_RTOL``), set and reasoned where its reference is."""
     import jax
-
-    from benchmark import reference
 
     t0 = time.monotonic()
     steps = len(losses)
@@ -289,18 +315,18 @@ def check_first_steps(
         float("inf") if a is None else abs(a - b) / max(abs(b), 1.0)
         for a, b in zip(losses, ref_losses)
     ]
-    ok = all(e <= reference.LOSS_RTOL for e in loss_err)
+    ok = all(e <= family.LOSS_RTOL for e in loss_err)
     out: Dict[str, Any] = {
         "losses": losses, "reference_losses": ref_losses,
-        "loss_rel_err": loss_err,
+        "loss_rel_err": loss_err, "loss_rtol": family.LOSS_RTOL,
     }
     if grad_norm is not None:
         ref_norm = float(ref_norms[0])
         norm_err = abs(grad_norm - ref_norm) / max(abs(ref_norm), 1.0)
-        ok = ok and norm_err <= reference.GRAD_NORM_RTOL
+        ok = ok and norm_err <= family.GRAD_NORM_RTOL
         out.update(
             grad_norm=grad_norm, reference_grad_norm=ref_norm,
-            grad_norm_rel_err=norm_err,
+            grad_norm_rel_err=norm_err, grad_norm_rtol=family.GRAD_NORM_RTOL,
         )
     return dict(out, seconds=time.monotonic() - t0, ok=bool(ok))
 
@@ -357,6 +383,24 @@ def null_span(_name: str) -> Any:
     return contextlib.nullcontext()
 
 
+def reduce_trace(path: str) -> Dict[str, Any]:
+    """What a traced run keeps of its ``.xplane.pb``: busy and idle time
+    and the breakdown (``reduce/xplane.py``), and the trace whole beside
+    them (``reduce/spans.py``, a second parse of the same file): device
+    seconds of every Mosaic kernel by name (``kernels_s``), by scope class
+    (``scopes_s``) and by scope path (``paths_s``). ``parse_s`` is what
+    the two parses cost the run."""
+    from benchmark.reduce import spans, xplane
+
+    t0 = time.monotonic()
+    out = xplane.reduce_file(path)
+    t1 = time.monotonic()
+    whole = spans.reduce_file(path)
+    out.update({k: whole[k] for k in ("kernels_s", "scopes_s", "paths_s")})
+    out["parse_s"] = {"xplane": t1 - t0, "spans": time.monotonic() - t1}
+    return out
+
+
 class Tracer:
     """The profiler window of a traced run: ``start`` .. ``stop`` around
     a few steps, then the reduction of what it wrote. Untraced runs never
@@ -386,15 +430,13 @@ class Tracer:
         jax.profiler.stop_trace()
 
     def reduce(self) -> Optional[Dict[str, Any]]:
-        from benchmark.reduce import xplane
-
         paths = glob.glob(os.path.join(
             self.directory, "plugins", "profile", "*", "*.xplane.pb"
         ))
         if not paths:
             raise RuntimeError(f"the profiler wrote no trace under {self.directory}")
         try:
-            return xplane.reduce_file(paths[0])
+            return reduce_trace(paths[0])
         except ValueError:
             if self.rehearse:  # a CPU trace has no device plane
                 return None

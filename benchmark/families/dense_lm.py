@@ -3,13 +3,19 @@
 
 A family gives the harness the program's configuration built from the
 file of sizes, the weights from a seed, the loss, the plain reference's
-training run (``benchmark/reference.py``), and the tokens and operations
-of one step.
+training run (``benchmark/reference.py``) with the tolerances set beside
+it, the tokens and operations of one step, how many Mosaic custom calls
+the lowered step holds, and whatever else its readers want in a run's
+facts (``common.FAMILY_STATES``).
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict
+
+# how close the measured step's first losses and first gradient norm must
+# come to the reference's; the values and their reason are reference.py's
+from benchmark.reference import GRAD_NORM_RTOL, LOSS_RTOL  # noqa: F401
 
 
 def build(sizes: Dict[str, Any]) -> Any:
@@ -71,6 +77,19 @@ def flops_per_step(cfg: Any, batch: int, seq: int) -> float:
     s = seq - 1
     per_position = 6 * matmul_params(cfg) + 6 * s * cfg.d_model * cfg.n_layers
     return float(batch * s * per_position)
+
+
+def lowered_mosaic_calls(cfg: Any) -> int:
+    """``tpu_custom_call``s in the text of the lowered step (gradient
+    step or fused step alike: the update has none): every layer is one
+    flash forward and one fused flash backward, and nothing else of this
+    model is a kernel."""
+    return 2 * cfg.n_layers
+
+
+def facts(cfg: Any, batch: int, seq: int) -> Dict[str, Any]:
+    """Nothing beyond what every family gives: no reader asks more."""
+    return {}
 
 
 def flash_calls(cfg: Any, batch: int, seq: int) -> Dict[str, float]:

@@ -4,21 +4,28 @@ SwiGLU experts), sized by an OLMoE ``config.json``.
 
 Like ``dense_lm``, it gives the harness the program's configuration from
 the file of sizes, the weights from a seed, the loss, the plain
-reference's training run (``benchmark/reference_olmoe.py``) and the
-tokens and operations of one step; and, new here, ``expert_matmuls``: what
-a step's grouped expert matmuls require, for ``moe_expert_roofline``.
+reference's training run (``benchmark/reference_olmoe.py``) with the
+tolerances set beside it, the tokens and operations of one step and the
+Mosaic calls its lowered text holds; and, in ``facts``, ``expert_matmuls``:
+what a step's grouped expert matmuls require, for the ``moe_*`` readers.
 
 On the TPU ``jax.lax.ragged_dot`` compiles to Mosaic custom calls of
 XLA's own (``ragged-dot-*``), so in this family ``flash_calls`` counts
-EVERY Mosaic call of a step - the two flash kernels and the grouped
-matmuls with their metadata calls - and ``flash_roofline`` there is the
-roofline share of all of them together (``layer_metrics/
-flash_roofline.py`` holds the trace to this count).
+EVERY Mosaic call of a traced step - the two flash kernels and the
+grouped matmuls with their metadata calls - and ``flash_roofline`` there
+is the roofline share of all of them together (``layer_metrics/
+flash_roofline.py`` holds the trace to this count). They become Mosaic
+only in the compiler: the LOWERED text, which ``lowered_mosaic_calls``
+speaks of, still says ``ragged_dot``.
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict
+
+# how close the measured step's first losses and first gradient norm must
+# come to the reference's; reference_olmoe.py says what they are and why
+from benchmark.reference_olmoe import GRAD_NORM_RTOL, LOSS_RTOL  # noqa: F401
 
 # the program's module, imported as the family loads: a checkout whose
 # program lacks this model fails here, as soon as a worker has its backend
@@ -76,8 +83,8 @@ def init(cfg: Any, key: Any) -> Any:
     token in eighteen picks another eighth expert in the bf16 program than
     in the float32 reference, whatever the router's precision; with
     independent experts that token meets another function, the bf16
-    gradient is 5% off, and ``reference.py``'s bound - which the harness
-    applies to every family - fails one run in eight. With copies it never
+    gradient is 5% off, and the dense model's bound, which this family
+    keeps (``reference_olmoe.py``), fails one run in eight. With copies it never
     fails, and never sees a row sent to the wrong expert either. Between
     the two the bound stands at 4.8 times the sound program's rms error
     and a misrouted dispatch is outside it in 21 runs of 22."""
@@ -161,17 +168,27 @@ def expert_matmuls(cfg: Any, batch: int, seq: int) -> Dict[str, Any]:
     }
 
 
+def lowered_mosaic_calls(cfg: Any) -> int:
+    """``tpu_custom_call``s in the text of the lowered step: the flash
+    forward and fused backward of every layer. The grouped matmuls are
+    ``ragged_dot`` there and become Mosaic calls only in the TPU compiler
+    (module docstring), so the traced step's thirteen are two here."""
+    return 2 * cfg.n_layers
+
+
+def facts(cfg: Any, batch: int, seq: int) -> Dict[str, Any]:
+    """What the ``moe_*`` readers want of this family, kept in a run's
+    facts under ``family``."""
+    return {"expert_matmuls": expert_matmuls(cfg, batch, seq)}
+
+
 def flash_calls(cfg: Any, batch: int, seq: int) -> Dict[str, Any]:
-    """What one step's Mosaic custom calls require: the flash-attention
-    forward and fused backward of every layer (2 matmuls forward and 4
-    backward over the causal half of S x S; q, k, v, out forward and q, k,
-    v, out, d_out, dq, dk, dv backward in bf16, the f32 log-sum-exp
-    written once and read once) AND the grouped expert matmuls, which
-    are Mosaic calls too (module docstring). ``expert_matmuls`` rides
-    along, a dict among the numbers, for the three ``moe_*`` readers:
-    this dict (``facts["flash"]``) is all a run's facts keep of the
-    family, until facts carry a family entry of their own (a ``benchmark``
-    PR's edit to ``common.py``; PERF.md section 7)."""
+    """What one traced step's Mosaic custom calls require: the
+    flash-attention forward and fused backward of every layer (2 matmuls
+    forward and 4 backward over the causal half of S x S; q, k, v, out
+    forward and q, k, v, out, d_out, dq, dk, dv backward in bf16, the f32
+    log-sum-exp written once and read once) AND the grouped expert
+    matmuls, which are Mosaic calls too (module docstring)."""
     s, h, dh = seq - 1, cfg.n_heads, cfg.head_dim
     matmul = 2 * s * s * dh / 2  # one S x S x D matmul, causal half
     tensor = s * h * dh * 2
@@ -181,5 +198,4 @@ def flash_calls(cfg: Any, batch: int, seq: int) -> Dict[str, Any]:
         "calls": (2 + RAGGED_CALLS_PER_LAYER) * cfg.n_layers,
         "flops": batch * cfg.n_layers * h * 6 * matmul + experts["flops"],
         "bytes": batch * cfg.n_layers * (12 * tensor + 2 * lse) + experts["bytes"],
-        "expert_matmuls": experts,
     }

@@ -1,6 +1,6 @@
 """Kernels: device time a traced step in the fused flash-attention
 backward kernel (every layer's ``flash_bwd`` custom call), from the
-trace's breakdown."""
+trace's seconds by kernel name."""
 
 from benchmark.reduce import program
 

@@ -1,6 +1,6 @@
 """Kernels: device time a traced step in the flash-attention forward
 kernel (every layer's ``flash_fwd`` custom call), from the trace's
-breakdown."""
+seconds by kernel name."""
 
 from benchmark.reduce import program
 

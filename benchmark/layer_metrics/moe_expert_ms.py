@@ -12,8 +12,11 @@ calls / visible calls (the family's ``calls_by_output`` says how many
 calls write which shape): the reading does not jump when another
 operation crosses the tenth place. In ``olmoe-ft1`` 8 of 9 are visible,
 41.2 ms, and the reading is 46.4 against 46.44 ms summed by hand from
-the whole trace (PERF.md section 5). None where no call is visible, or
-one writes a shape the family did not count."""
+the whole trace (PERF.md section 5). ``facts["trace"]["kernels_s"]
+["ragged-dot-none"]`` holds that unscaled sum of all nine since PR 32;
+this reader keeps its arithmetic so that its ledger line stays one
+quantity. None where no call is visible, or one writes a shape the
+family did not count."""
 
 import re
 
@@ -21,9 +24,9 @@ import re
 def visible(facts):
     """(seconds of the traced steps in the visible ``ragged-dot-none``
     calls, how many of a step's calls those are, the family's
-    ``expert_matmuls``), or None."""
+    ``expert_matmuls`` from ``facts["family"]``), or None."""
     trace = facts.get("trace")
-    experts = (facts.get("flash") or {}).get("expert_matmuls")
+    experts = (facts.get("family") or {}).get("expert_matmuls")
     if not trace or not experts:
         return None
     seconds, calls = 0.0, 0

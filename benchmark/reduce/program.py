@@ -1,8 +1,8 @@
 """What the program's own sinks hold, from a run's facts, for the readers
 of ``layer_metrics/``: a ``Metrics`` timer of group 0's manager, a named
-kernel in the trace's breakdown. Each returns None where the program has
-no such timer or name (the parent of the PR that added it), and the
-metric is then left out.
+kernel or a ``jax.named_scope`` path in the trace. Each returns None where
+the program has no such timer or name (the parent of the PR that added
+it), and the metric is then left out.
 
 A timer is the host-side sink of the span ``torchft::<name>``
 (torchft_tpu/profiling.py): the same ``with``, the same seconds, read
@@ -42,15 +42,31 @@ def wait_ms(facts: Dict[str, Any], name: str) -> Optional[float]:
 
 
 def kernel_ms(facts: Dict[str, Any], name: str) -> Optional[float]:
-    """Device milliseconds a traced step in the Mosaic kernel ``name``,
-    from the breakdown's operations (``<name> <shape> custom-call``). The
-    breakdown keeps the ten longest operations: a kernel below the tenth
-    reads as absent, not as zero."""
-    trace = facts.get("trace")
-    if not trace:
+    """Device milliseconds a traced step in the Mosaic kernel ``name``:
+    every call of it, from the trace's ``kernels_s``. None where the run
+    was not traced, the trace has no such table, or no such kernel ran."""
+    kernels_s = (facts.get("trace") or {}).get("kernels_s")
+    if not kernels_s or not kernels_s.get(name):
+        return None
+    return kernels_s[name] / facts["trace"]["steps"] * 1e3
+
+
+def scope_ms(
+    facts: Dict[str, Any], path: str = "", direction: Optional[str] = None
+) -> Optional[float]:
+    """Device milliseconds a traced step in the operations whose scope
+    path (``reduce/spans.py``: ``attn/qk_norm``, ``mlp/moe/experts``)
+    holds ``path`` as a run of whole names - ``moe`` finds ``mlp/moe/
+    router`` and not ``mlp/moe_gate`` - in one ``direction`` (``forward``,
+    ``backward``, ``optimizer``, ``unscoped``) or in all. The empty path
+    is every operation of the direction. None where the trace has no such
+    table or nothing ran under the path."""
+    paths_s = (facts.get("trace") or {}).get("paths_s")
+    if not paths_s:
         return None
     seconds = sum(
-        s for label, s in trace["device_ops"]
-        if label.split(" ")[0] == name and label.endswith("custom-call")
+        s
+        for which, paths in paths_s.items() if direction in (None, which)
+        for found, s in paths.items() if f"/{path}/" in f"/{found}/" or not path
     )
-    return seconds / trace["steps"] * 1e3 if seconds else None
+    return seconds / facts["trace"]["steps"] * 1e3 if seconds else None

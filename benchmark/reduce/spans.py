@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """From the profiler's ``.xplane.pb`` to the program's own names: host
 seconds per ``torchft::*`` / ``bench::*`` span, and device seconds per
-scope class and per Mosaic kernel.
+scope class, per scope path and per Mosaic kernel. ``common.Tracer.reduce``
+keeps the three device tables of every traced run (``facts["trace"]``:
+``scopes_s``, ``paths_s``, ``kernels_s``); by hand:
 
     python3 benchmark/reduce/spans.py <trace.xplane.pb[.gz]> [--steps N]
 
@@ -15,14 +17,35 @@ the stat ``tf_op`` - JAX's ``op_name``, the scope path, e.g.
 metadata's, so this file reads the protobuf's wire format itself (the
 fields of ``XSpace`` it needs, nothing installed).
 
-Scope classes, from the names the program gives (``jax.named_scope`` in
-models/transformer.py, train_state.py): ``backward`` is anything under
-JAX's ``transpose(...)``; ``optimizer`` anything under ``optimizer``;
-``forward`` anything else under one of the model's scopes; ``unscoped``
-the rest (the bf16 compute copy of the masters, parameter copies). A
-program that has no such names - the parent of PR 24 - reads as
-``backward`` / ``unscoped`` and kernels ``jvp__`` / ``transpose_jvp___``:
-nothing here raises on it.
+Scope classes, from the names the program gives with ``jax.named_scope``
+and from nothing else - no list of a model's scopes is kept here:
+``backward`` is anything under JAX's ``transpose(...)``; ``optimizer``
+anything under a scope called ``optimizer`` (the one name the training
+state gives its update, train_state.py); ``forward`` anything else that
+is under a name at all; ``unscoped`` the rest: operations the compiler
+made under no name (the bf16 compute copy of the masters, copies of
+parameters and of arguments, asynchronous copy and slice starts). A
+program that has no names - the parent of PR 24 - reads as ``backward`` /
+``unscoped`` and kernels ``jvp__`` / ``transpose_jvp___``: nothing here
+raises on it.
+
+Scope paths keep what the classes fold away: ``attn/qk_norm``,
+``mlp/moe/experts``, ``attn/flash_fwd``, each under its class. A path is
+what the program named and nothing else (``scope_path``), so a model
+whose layers are of several kinds is told apart by the names it gives
+them. A fusion is filed where its root is, because that is the ``tf_op``
+the compiler gives it.
+
+One kind of operation loses its names in the compiler: a Mosaic kernel
+that XLA itself puts in place of an operation (``jax.lax.ragged_dot``
+becomes ``ragged-dot-none`` with the ``tf_op`` ``ragged-dot-none:``, no
+``jit(..)/`` before it; a ``pallas_call`` keeps its path). Left unscoped,
+a step's largest matmuls would be in neither pass. Such a kernel is filed
+under the class of the last named operation that started before it on
+the same chip - the pass the step was in - with the kernel's name for its
+path (``paths_s["forward"]["ragged-dot-none"]``). That holds as far as
+the compiler runs a pass's operations together; the kernel's own seconds
+are in ``kernels_s`` whatever its class.
 
 Self time of a span is its duration less that of the spans nested
 directly in it on the same thread.
@@ -41,7 +64,6 @@ DEVICE_PLANE = "/device:TPU:"
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
 SPAN_PREFIXES = ("bench::", "torchft::")
-MODEL_SCOPES = ("embed", "attn", "mlp", "readout", "loss")
 CLASSES = ("forward", "backward", "optimizer", "unscoped")
 
 # -- the protobuf wire format, as far as XSpace needs it --------------------
@@ -183,22 +205,34 @@ def read_planes(path: str) -> List[Dict[str, Any]]:
 
 def scope_class(scope: str) -> str:
     """``forward`` / ``backward`` / ``optimizer`` / ``unscoped`` of an
-    operation's scope path (its ``tf_op``)."""
+    operation's scope path (its ``tf_op``): by ``transpose(``, by the name
+    ``optimizer``, by whether the program named it at all."""
     if "transpose(" in scope:
         return "backward"
-    # the innermost name of each path component: ``attn`` of ``jvp(attn)``;
-    # an argument's name (``masters['embed']``) is no component of a path
-    names = [
-        m.group(1) for m in (
-            re.fullmatch(r"(?:[A-Za-z_]\w*\()*([\w\-]*)\)*", part)
-            for part in scope.rstrip(":").split("/")
-        ) if m
-    ]
+    names = scope_path(scope).split("/")
     if "optimizer" in names:
         return "optimizer"
-    if any(n in MODEL_SCOPES for n in names):
-        return "forward"
-    return "unscoped"
+    return "forward" if names != [""] else "unscoped"
+
+
+def scope_path(scope: str) -> str:
+    """``attn/qk_norm`` of ``jit(loss_and_grads)/transpose(jvp(attn))/
+    qk_norm/mul:``: the ``jax.named_scope`` names between the program's
+    own ``jit(..)`` and the primitive, a transformation's wrapping
+    (``jvp(..)``, ``transpose(..)``) taken off. It ends where the names
+    do: at an inner function (``jit(silu)``), an einsum's equation, or the
+    primitive, which is the last part. ``""`` for an operation under no
+    name, and for an argument's name (``masters['embed']:``)."""
+    parts = scope.partition(";")[0].rstrip(":").split("/")[:-1]
+    while parts and re.fullmatch(r"p?jit\(.*\)", parts[0]):
+        parts.pop(0)
+    names: List[str] = []
+    for part in parts:
+        m = re.fullmatch(r"(?:(?!p?jit\()[A-Za-z_]\w*\()*([A-Za-z_][\w\-.]*)\)*", part)
+        if not m:
+            break
+        names.append(m.group(1))
+    return "/".join(names)
 
 
 def kernel_name(hlo_line: str) -> Optional[str]:
@@ -244,48 +278,67 @@ def host_spans(plane: Dict[str, Any]) -> List[Dict[str, Any]]:
     return out
 
 
-def device_seconds(plane: Dict[str, Any]) -> Tuple[Dict[str, float], Dict[str, float]]:
-    """Seconds of one chip's ``XLA Ops`` by scope class and by Mosaic
-    kernel name."""
+def device_seconds(plane: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
+    """Seconds of one chip's ``XLA Ops``: ``scopes`` by scope class,
+    ``paths`` by ``"<class> <scope path>"`` (every operation in exactly
+    one, so a class is the sum of its paths) and ``kernels`` by Mosaic
+    kernel name. A kernel under no name goes where the last named
+    operation before it went, under its own name (the module's docstring)."""
     classes = dict.fromkeys(CLASSES, 0.0)
+    paths: Dict[str, float] = {}
     kernels: Dict[str, float] = {}
     for line in plane["lines"]:
         if line["name"] != OPS_LINE:
             continue
-        for e in line["events"]:
+        running = "unscoped"  # the class of the last named operation
+        for e in sorted(line["events"], key=lambda e: e["start_ns"]):
             seconds = e["duration_ns"] / 1e9
-            classes[scope_class(e["scope"])] += seconds
+            which, path = scope_class(e["scope"]), scope_path(e["scope"])
             kernel = kernel_name(e["name"])
             if kernel:
                 kernels[kernel] = kernels.get(kernel, 0.0) + seconds
-    return classes, kernels
+            if which != "unscoped":
+                running = which
+            elif kernel and "/" not in e["scope"]:  # the compiler's own name
+                which, path = running, kernel
+            classes[which] += seconds
+            key = f"{which} {path}"
+            paths[key] = paths.get(key, 0.0) + seconds
+    return {"scopes": classes, "paths": paths, "kernels": kernels}
 
 
 def reduce_planes(planes: List[Dict[str, Any]]) -> Dict[str, Any]:
     """``spans`` (host_spans of the host plane), ``steps`` (the step
     stats seen, sorted), and - averaged over the chips that ran anything -
-    ``scopes_s`` and ``kernels_s``. Seconds are totals over the capture;
-    the caller divides by the steps it traced."""
+    ``scopes_s``, ``kernels_s`` and ``paths_s`` (``{class: {scope path:
+    seconds}}``). Seconds are totals over the capture; the caller divides
+    by the steps it traced."""
     spans: List[Dict[str, Any]] = []
     chips = []
     for plane in planes:
         if plane["name"] == HOST_PLANE:
             spans = host_spans(plane)
         elif plane["name"].startswith(DEVICE_PLANE):
-            classes, kernels = device_seconds(plane)
-            if sum(classes.values()) > 0:
-                chips.append((classes, kernels))
+            seconds = device_seconds(plane)
+            if sum(seconds["scopes"].values()) > 0:
+                chips.append(seconds)
 
-    def mean(dicts: List[Dict[str, float]]) -> Dict[str, float]:
-        keys = sorted({k for d in dicts for k in d})
-        return {k: sum(d.get(k, 0.0) for d in dicts) / len(dicts) for k in keys}
+    def mean(table: str) -> Dict[str, float]:
+        keys = sorted({k for c in chips for k in c[table]})
+        return {k: sum(c[table].get(k, 0.0) for c in chips) / len(chips) for k in keys}
+
+    paths_s: Dict[str, Dict[str, float]] = {c: {} for c in CLASSES} if chips else {}
+    for key, seconds in mean("paths").items():
+        which, _, path = key.partition(" ")
+        paths_s[which][path] = seconds
 
     return {
         "chips": len(chips),
         "steps": sorted({s for e in spans for s in e["by_step"]}),
         "spans": spans,
-        "scopes_s": mean([c for c, _ in chips]) if chips else {},
-        "kernels_s": mean([k for _, k in chips]) if chips else {},
+        "scopes_s": mean("scopes"),
+        "kernels_s": mean("kernels"),
+        "paths_s": paths_s,
     }
 
 
@@ -303,6 +356,10 @@ def main(argv: List[str]) -> int:
         "chips": r["chips"], "steps_seen": r["steps"], "divided_by": steps,
         "scopes_ms": {k: v / steps * 1e3 for k, v in r["scopes_s"].items()},
         "kernels_ms": {k: v / steps * 1e3 for k, v in r["kernels_s"].items()},
+        "paths_ms": {
+            c: {k: v / steps * 1e3 for k, v in paths.items()}
+            for c, paths in r["paths_s"].items()
+        },
         "spans_ms": [
             {"thread": e["thread"], "name": e["name"], "n": e["n"],
              "total_ms": e["total_s"] / steps * 1e3,

@@ -31,10 +31,14 @@ the whole step's tokens.
 
 Callers wrap the call in ``jax.default_matmul_precision("highest")``.
 
-TOLERANCES are ``reference.py``'s (``LOSS_RTOL``, ``GRAD_NORM_RTOL``):
-``common.check_first_steps`` reads those names for every family. They
-were set on a dense model, and one thing is new here: the top-K choice
-is discrete. The program's router sees activations that came through
+TOLERANCES (``LOSS_RTOL``, ``GRAD_NORM_RTOL`` below, which
+``common.check_first_steps`` reads through the family) are
+``reference.py``'s values, 2e-4 and 1e-2. They are this family's to set
+now; they stay the dense model's because the experts' start was chosen
+to meet them (``families/olmoe_lm.EXPERT_SPREAD``), and a wider bound
+with independent experts is a change of the cell that needs its own
+readings (PERF.md section 7). They were set on a dense model, and one
+thing is new here: the top-K choice is discrete. The program's router sees activations that came through
 bf16 arithmetic, so for the tokens whose K-th and (K+1)-th probabilities
 lie closer than that rounding - one in eighteen at these sizes - it
 picks another expert than this reference does, with nearly the same
@@ -59,6 +63,12 @@ from __future__ import annotations
 from typing import Any, Tuple
 
 from benchmark import reference
+
+# the dense model's values, kept (docstring, TOLERANCES): at EXPERT_SPREAD
+# 0.5 the sound program's losses 1 and 2 stand 4.1e-5 rms, 1.24e-4 at most,
+# from this reference over 22 seeds, a misrouted dispatch outside in 21
+LOSS_RTOL = reference.LOSS_RTOL
+GRAD_NORM_RTOL = reference.GRAD_NORM_RTOL
 
 
 def _rmsnorm(x: Any, scale: Any, eps: float) -> Any:

@@ -4,8 +4,9 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 prints, as the last line of its standard output, one JSON object with
-the keys ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
-and, traced, ``breakdown``: the cell's end-to-end metrics with
+the keys ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``,
+traced ``breakdown``, and last ``compared`` (each number held to the
+reference, beside its limit): the cell's end-to-end metrics with
 ``--trace 0``, its per-layer metrics with ``--trace 1``. It exits
 non-zero, and prints no result, without the chips the cell asks for.
 
@@ -118,6 +119,19 @@ def read_metrics(directory: str, wanted: List[Dict[str, Any]], facts: Dict[str, 
     return metrics
 
 
+def compared(facts: Dict[str, Any]) -> Dict[str, List[float]]:
+    """Each number ``correct`` compared with the plain reference, beside
+    its limit: ``{name: [number, limit]}``."""
+    ref = facts.get("reference") or {}
+    out = {
+        f"loss{i}_rel_err": [err, ref["loss_rtol"]]
+        for i, err in enumerate(ref.get("loss_rel_err", []))
+    }
+    if "grad_norm_rel_err" in ref:
+        out["grad_norm0_rel_err"] = [ref["grad_norm_rel_err"], ref["grad_norm_rtol"]]
+    return out
+
+
 def plain(name: str) -> str:
     return re.sub(r"[^A-Za-z0-9_.:\-]", "_", name)
 
@@ -172,6 +186,7 @@ def main() -> int:
             key: [[plain(name), s] for name, s in trace[key]]
             for key in ("device_ops", "idle_gaps")
         }
+    result["compared"] = compared(facts)  # the last key of the line
 
     # what follows the last phase the generator marked: the reduction
     # (raw), the fleet's whole life (ft-sync's parent)
@@ -185,7 +200,7 @@ def main() -> int:
                 "t_open", "setup_s", "window", "kill",
                 "groups", "reference", "manager_metrics", "op_stats", "raw",
                 "worker_phases", "trace", "tokens_per_step", "discarded_at_kill",
-                "memory_stats",
+                "memory_stats", "flops_per_step", "flash", "family", "peaks",
             )},
         }, f)
     shutil.rmtree(cell["scratch"], ignore_errors=True)
@@ -194,6 +209,8 @@ def main() -> int:
     ))
     common.say("checks " + json.dumps(facts["checks"]))
     common.say("window " + json.dumps(facts["window"]))
+    for name, (number, limit) in result["compared"].items():
+        print(f"compared {name} {number} limit {limit}", file=sys.stderr, flush=True)
     if args.rehearse:
         common.say("REHEARSAL on the CPU, not a measurement: " + json.dumps(result))
         return 0 if correct else 1

@@ -70,7 +70,12 @@ def test_flash_calls_count_every_mosaic_call(cfg):
     tensor, lse = 4096 * 16 * 128 * 2, 4096 * 16 * 4
     assert f["flops"] == flash_flops + e["flops"]
     assert f["bytes"] == 4 * (12 * tensor + 2 * lse) + e["bytes"]
-    assert f["expert_matmuls"] == e
+    # calls, flops, bytes and nothing else: what the readers of the expert
+    # matmuls want is the family's entry in the facts
+    assert set(f) == {"calls", "flops", "bytes"}
+    assert family.facts(cfg, BATCH, SEQ) == {"expert_matmuls": e}
+    # the thirteen are the traced step's; its lowered text holds two
+    assert family.lowered_mosaic_calls(cfg) == 2
 
 
 def test_init_draws_the_experts_closer():
@@ -108,6 +113,7 @@ def _facts(ops, cfg, steps=5):
         "trace": {"device_ops": ops, "steps": steps},
         "peaks": V5E,
         "flash": family.flash_calls(cfg, BATCH, SEQ),
+        "family": family.facts(cfg, BATCH, SEQ),
     }
 
 
@@ -142,7 +148,7 @@ def test_dispatch_reader(cfg):
     ], cfg)
     assert dispatch_ms.read(facts) == pytest.approx(20.0)
     assert dispatch_ms.read({"trace": None}) is None
-    dense = dict(facts, flash={"calls": 24, "flops": 1.0, "bytes": 1.0})
+    dense = dict(facts, flash={"calls": 24, "flops": 1.0, "bytes": 1.0}, family={})
     assert dispatch_ms.read(dense) is None
 
 
@@ -154,7 +160,7 @@ def test_readers_absent(cfg):
     assert expert_roofline.read({"trace": None}) is None
     # a family that counts no expert matmuls (dense_lm's facts)
     dense = _facts([["ragged-dot-none bf16[8,8] custom-call", 0.05]], cfg)
-    dense["flash"] = {"calls": 24, "flops": 1.0, "bytes": 1.0}
+    dense["flash"], dense["family"] = {"calls": 24, "flops": 1.0, "bytes": 1.0}, {}
     assert expert_ms.read(dense) is None and expert_roofline.read(dense) is None
     # a grouped matmul of a shape the family did not count
     odd = _facts([["ragged-dot-none bf16[7,7] custom-call", 0.05]], cfg)

@@ -168,6 +168,14 @@ def test_self_time_by_hand():
     ("masters['blocks'][9]['mlp']['wi']", "unscoped"),  # an argument's name
     ("jit(apply)/add:", "unscoped"),  # an executable cached before the names
     ("", "unscoped"),
+    # a family names its layers as it likes: no list of scopes decides
+    ("jit(loss_and_grads)/jvp(kda)/chunk_scan/pallas_call:", "forward"),
+    ("jit(loss_and_grads)/transpose(jvp(kda))/chunk_scan/pallas_call:", "backward"),
+    ("jit(loss_and_grads)/jvp(mla)/kv_up/dot_general:", "forward"),
+    ("jit(loss_and_grads)/jvp(blocks)/3/ffn/jit(silu)/logistic:", "forward"),
+    ("jit(one_step)/jit(main)/optimizer/adamw/mul:", "optimizer"),
+    ("jit(loss_and_grads)/jvp()/slice:", "unscoped"),
+    ("ragged-dot-none:", "unscoped"),  # alone: its neighbours decide (device_seconds)
 ])
 def test_scope_class(scope, want):
     assert spans.scope_class(scope) == want

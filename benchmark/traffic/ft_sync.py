@@ -156,6 +156,7 @@ def collect(
         "tokens_per_step": lead["tokens_per_step"],
         "flops_per_step": lead["flops_per_step"],
         "flash": lead["flash"],
+        "family": lead["family"],
         "trace": lead.get("trace"),
         "reference": lead["reference"],
         "manager_metrics": lead["manager_metrics"],
@@ -232,7 +233,7 @@ def worker(cell: Dict[str, Any]) -> None:
     from torchft_tpu.serving import tree_digest
 
     phases.mark("imports_and_backend_init")
-    family = common.load_by_name("families", cell["sizes"]["family"])
+    family = common.load_family(cell["sizes"]["family"])
     cfg = family.build(cell["sizes"])
     batch, seq = cell["sizes"]["batch"], cell["sizes"]["seq"]
     tx = optax.adamw(1e-3)
@@ -246,7 +247,9 @@ def worker(cell: Dict[str, Any]) -> None:
     loss_and_grads = common.mixed_precision_grad(family, cfg)
     lowered = jax.jit(loss_and_grads).lower(state.params, batches[0])
     if not cell["rehearse"]:
-        common.require_mosaic(lowered, 2 * cfg.n_layers, "ft-sync gradient step")
+        common.require_mosaic(
+            lowered, family.lowered_mosaic_calls(cfg), "ft-sync gradient step"
+        )
     grad_fn = lowered.compile()
     _, grads0 = grad_fn(state.params, batches[0])
     state.warm(grads0)  # the optimizer-update executable, on copies
@@ -430,6 +433,7 @@ def worker(cell: Dict[str, Any]) -> None:
         "tokens_per_step": family.tokens_per_step(batch, seq),
         "flops_per_step": family.flops_per_step(cfg, batch, seq),
         "flash": family.flash_calls(cfg, batch, seq),
+        "family": family.facts(cfg, batch, seq),
     }
     if open_at is None:
         del closing["open_at"]
@@ -447,7 +451,7 @@ def _raw_after(
     transaction's."""
     import optax
 
-    from benchmark import estimator, reference
+    from benchmark import estimator
     from benchmark.traffic import raw as raw_kind
 
     batch, seq = cell["sizes"]["batch"], cell["sizes"]["seq"]
@@ -470,7 +474,7 @@ def _raw_after(
         "tokens_per_s": numbers and numbers["tokens_per_s"],
         "first_losses": losses[:5], "ft_first_losses": ft_losses[:5],
         "first_losses_match": len(pairs) == 5 and all(
-            a is not None and abs(a - b) <= reference.LOSS_RTOL * abs(b)
+            a is not None and abs(a - b) <= family.LOSS_RTOL * abs(b)
             for a, b in pairs
         ),
     }

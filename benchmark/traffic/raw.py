@@ -101,7 +101,7 @@ def run(cell: Dict[str, Any]) -> Dict[str, Any]:
     phases.mark("imports")
     device = common.require_tpu(cell["chips"], cell["rehearse"])
     phases.mark("backend_init")
-    family = common.load_by_name("families", cell["sizes"]["family"])
+    family = common.load_family(cell["sizes"]["family"])
     cfg = family.build(cell["sizes"])
     batch, seq = cell["sizes"]["batch"], cell["sizes"]["seq"]
     params, batches, opt_state = common.make_state(
@@ -111,7 +111,7 @@ def run(cell: Dict[str, Any]) -> Dict[str, Any]:
     step = build_step(family, cfg)
     lowered = step.lower(params, opt_state, batches[0])
     if not cell["rehearse"]:
-        common.require_mosaic(lowered, 2 * cfg.n_layers, "raw train step")
+        common.require_mosaic(lowered, family.lowered_mosaic_calls(cfg), "raw train step")
     compiled = lowered.compile()
     phases.mark("compile_or_cache_load")
 
@@ -151,6 +151,7 @@ def run(cell: Dict[str, Any]) -> Dict[str, Any]:
         "tokens_per_step": family.tokens_per_step(batch, seq),
         "flops_per_step": family.flops_per_step(cfg, batch, seq),
         "flash": family.flash_calls(cfg, batch, seq),
+        "family": family.facts(cfg, batch, seq),
         "trace": out["trace"],
         "checks": checks,
         "reference": reference,
