@@ -428,3 +428,140 @@ def test_transformer_loss_and_gradient_equal_flash_on_and_off(n_heads, fused, mo
         jax.tree_util.tree_leaves(g_flash), jax.tree_util.tree_leaves(g_dense)
     ):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the diagonal sub-tile as a staircase of chunks (PR 35)
+# ---------------------------------------------------------------------------
+
+
+def _attend(entry, n_heads, **blocks):
+    """qkv (B, S, 3*H*D) -> (B, S, H*D) through either entry."""
+    if entry == "fused":
+        return lambda qkv: flash_attention_qkv(qkv, n_heads, **blocks)
+
+    def three(qkv):
+        out = flash_attention(*_split_heads(qkv, n_heads), **blocks)
+        return out.reshape(*qkv.shape[:2], -1)
+
+    return three
+
+
+def _out_and_cotangent(attend, qkv):
+    def loss(x):
+        out = attend(x)
+        return jnp.sum(out ** 2), out
+
+    (_, out), dqkv = jax.value_and_grad(loss, has_aux=True)(qkv)
+    return out, dqkv
+
+
+# S 1024 and 1023 (padded) run (1024, 512): two row groups, a wide tile
+# and two staircases; S 512 is one row group, the staircase alone. An
+# edge of 512 is ``block_k``, the whole sub-tile under one mask: the
+# kernels of before. The last case cuts the sequence into four resident
+# blocks, so the dynamic loop over the blocks to the left meets the
+# staircase (the fused entry sends it to the three-array kernels itself).
+@pytest.mark.parametrize(
+    "S,blocks",
+    [(S, {"block_diag": e}) for S in (1024, 1023, 512) for e in (128, 256, 512)]
+    + [(1024, {"block_q": 256, "block_k": 256, "block_diag": 128})],
+    ids=lambda v: v if isinstance(v, int) else "-".join(
+        f"{k[6:]}{n}" for k, n in v.items()
+    ),
+)
+@pytest.mark.parametrize("head_dim", [64, 128], ids=["d64", "d128"])
+@pytest.mark.parametrize("entry", ["fused", "three"])
+def test_staircase_matches_plain_attention(entry, head_dim, S, blocks, monkeypatch):
+    fa = _module()
+    n_heads = 2 if head_dim == 64 else 1
+    took = _fused_calls(monkeypatch)
+    edges = []  # what the four calls that build a kernel are handed
+    for name in ("_flash_fwd_call", "_flash_bwd_call", "_flash_fwd_qkv_call", "_flash_bwd_qkv_call"):
+        def spy(*a, _real=getattr(fa, name), **kw):
+            edges.append(kw["edge"])
+            return _real(*a, **kw)
+
+        monkeypatch.setattr(fa, name, spy)
+    qkv = jax.random.normal(
+        jax.random.PRNGKey(35), (1, S, 3 * n_heads * head_dim), jnp.float32
+    )
+    out, dqkv = _out_and_cotangent(_attend(entry, n_heads, **blocks), qkv)
+    ref, ref_dqkv = _out_and_cotangent(
+        lambda x: dense_attention(*_split_heads(x, n_heads)).reshape(out.shape), qkv
+    )
+    assert bool(took) == (entry == "fused" and "block_q" not in blocks)
+    assert len(edges) >= 2 and set(edges) == {blocks["block_diag"]}
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for got, want, name in zip(
+        jnp.split(dqkv, 3, axis=-1), jnp.split(ref_dqkv, 3, axis=-1), "qkv"
+    ):
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("j", [127, 128, 255, 256, 511, 512])
+@pytest.mark.parametrize("entry", ["fused", "three"])
+def test_staircase_is_causal_at_the_chunk_edges(entry, j):
+    """Key j lies on an edge of a chunk, of a sub-tile or just inside one:
+    rows before it must not see it. The cotangent of every key from j on,
+    from a loss on the query rows before j, is exactly 0 - a chunk that
+    met a row it should not, or a mask one off, leaves a number there -
+    and those rows' output does not change with the keys."""
+    n_heads, S = 2, 1024
+    attend = _attend(entry, n_heads, block_diag=128)
+    qkv = jax.random.normal(jax.random.PRNGKey(j), (1, S, 3 * n_heads * 64), jnp.float32)
+    early = lambda x: attend(x)[:, :j]
+    out, pullback = jax.vjp(early, qkv)
+    (dqkv,) = pullback(jnp.ones_like(out))
+    dq, dk, dv = jnp.split(dqkv, 3, axis=-1)
+    assert not np.any(np.asarray(dk[:, j:])) and not np.any(np.asarray(dv[:, j:]))
+    assert np.all(np.any(np.asarray(dk[:, :j]) != 0, axis=-1))  # the others do count
+    late = jnp.arange(S)[None, :, None] >= j
+    k_v = jnp.arange(qkv.shape[-1])[None, None, :] >= qkv.shape[-1] // 3
+    np.testing.assert_array_equal(early(jnp.where(late & k_v, 7.0, qkv)), out)
+
+
+# (block_k, head size) -> the staircase's edges (forward, backward), from
+# this PR's whole-step measurements (PERF.md section 6, PR 35): the
+# backward the finest the lanes allow, the forward whole at head size 64
+# and 256 at 128; a sub-tile the edge does not divide stays whole.
+@pytest.mark.parametrize(
+    "block_k,head_dim,want",
+    [
+        (512, 64, (512, 128)), (512, 128, (256, 128)), (256, 64, (256, 128)),
+        (256, 128, (256, 128)), (128, 128, (128, 128)), (32, 64, (32, 32)),
+        (384, 128, (384, 128)),
+    ],
+)
+def test_auto_edges(block_k, head_dim, want):
+    fa = _module()
+    assert fa._auto_edges(block_k, head_dim) == want
+    assert all(block_k % e == 0 for e in want)
+
+
+def test_staircase_edge_divides_the_sub_tile():
+    q, k, v = rand_qkv(jax.random.PRNGKey(3), (1, 128, 1, 8))
+    with pytest.raises(ValueError, match="block_diag"):
+        flash_attention(q, k, v, block_q=64, block_k=64, block_diag=24)
+    # the general path has no diagonal sub-tile to cut
+    flash_attention(q, k, v, causal=False, block_q=64, block_k=64, block_diag=24)
+
+
+# the benchmark's two shapes: GPT-2's (1024, 512) and OLMoE's (512, 512) at
+# S 4096, at the whole sub-tile (the kernels until PR 35) and both edges
+@pytest.mark.parametrize(
+    "S,block_q,block_k,edge,want",
+    [
+        (1024, 1024, 512, 512, 786_432), (1024, 1024, 512, 256, 655_360),
+        (1024, 1024, 512, 128, 589_824), (4096, 512, 512, 512, 9_437_184),
+        (4096, 512, 512, 256, 8_912_896), (4096, 512, 512, 128, 8_650_752),
+        (1024, 1024, 128, 128, 589_824), (1024, 128, 128, 128, 589_824),
+    ],
+)
+def test_scores_computed(S, block_q, block_k, edge, want):
+    """The engagement figure: the area a head computes, from the schedule
+    alone. It never falls under the causal half, which an edge of 1 would
+    reach."""
+    fa = _module()
+    assert fa._scores_computed(S, block_q, block_k, edge) == want
+    assert fa._scores_computed(S, block_q, block_k, 1) == S * (S + 1) // 2 <= want

@@ -67,11 +67,11 @@ def _lowered_text(fn, *args) -> str:
     return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
 
 
-def _qkv_grad_text(n_heads: int, head_dim: int = 64) -> str:
+def _qkv_grad_text(n_heads: int, head_dim: int = 64, **blocks) -> str:
     qkv = jnp.ones((2, 1024, 3 * n_heads * head_dim), jnp.bfloat16)
 
     def loss(qkv):
-        out = flash_attention_qkv(qkv, n_heads, interpret=False)
+        out = flash_attention_qkv(qkv, n_heads, interpret=False, **blocks)
         return out.astype(jnp.float32).sum()
 
     return _lowered_text(jax.grad(loss), qkv)
@@ -104,6 +104,61 @@ def test_fused_projection_body_is_at_most_twice_the_three_array_one(head_dim):
     fused = _qkv_grad_text(2, head_dim)
     assert fused.count("tpu_custom_call") == three.count("tpu_custom_call") == 2
     assert len(fused) <= (1.7 if head_dim == 64 else 1.4) * len(three)
+
+
+def test_staircase_body_is_bounded_against_the_whole_sub_tile():
+    """The diagonal sub-tile as a staircase is more and smaller operations
+    (PR 35): the fused gradient at S 1024 with the shapes' own edges (the
+    backward's 128, the forward whole) against the sub-tile whole in both
+    (``block_diag`` 512, the body until then). Cross-lowered here: 26.1 KB
+    whole, 35.6 KB as the shapes choose, 49.8 KB with 128 in both; the TPU
+    compiler takes the same 2-3 s for each. What grows set-up is a body
+    that grows with the model (the two tests above); this one keeps the
+    staircase from growing unseen."""
+    whole, stairs = _qkv_grad_text(12, block_diag=512), _qkv_grad_text(12)
+    assert whole.count("tpu_custom_call") == stairs.count("tpu_custom_call") == 2
+    assert len(whole) < len(stairs) <= 1.5 * len(whole), (len(whole), len(stairs))
+
+
+# the benchmark's three attention shapes: gpt2-small and gpt2-medium through
+# the fused entry, OLMoE (eight resident blocks a side) through the other
+@pytest.mark.parametrize(
+    "n_heads,head_dim,seq", [(12, 64, 1024), (16, 64, 1024), (16, 128, 4096)],
+    ids=["gpt2-small", "gpt2-medium", "olmoe"],
+)
+def test_benchmark_shapes_gradient_holds_two_mosaic_calls(n_heads, head_dim, seq):
+    if head_dim == 64:
+        text = _qkv_grad_text(n_heads)
+    else:
+        q = jnp.ones((1, seq, n_heads, head_dim), jnp.bfloat16)
+        text = _lowered_text(jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, interpret=False)
+            .astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ), q, q, q)
+    assert text.count("tpu_custom_call") == 2
+    assert text.count('kernel_name = "flash_fwd"') == 1
+    assert text.count('kernel_name = "flash_bwd"') == 1
+
+
+@pytest.mark.parametrize("config", ["gpt2-small", "olmoe-1b-7b-l1"])
+def test_the_lowered_gradient_holds_what_the_family_states(config, monkeypatch):
+    """The benchmark's own case (``benchmark/tests``, not part of tier-1
+    by itself) for both families: the program its generators lower holds
+    the Mosaic calls the family states, or every run of the cell is
+    refused before it starts."""
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "tests", "test_family_contract.py",
+    )
+    spec = importlib.util.spec_from_file_location("benchmark_family_contract", path)
+    contract = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(contract)
+    assert config in contract.TINY
+    contract.test_the_lowered_gradient_holds_what_the_family_states(config, monkeypatch)
 
 
 @pytest.mark.parametrize("n_heads,seq", [(2, 1025), (2, 1024), (3, 1025)])

@@ -51,18 +51,32 @@ TWO-LEVEL schedule. A grid step holds a resident block of ``block_q``
 rows - the whole sequence up to 2048 positions - cut into row groups of
 ``block_k``. Inside the block every trip count is static: a row group
 meets all keys left of its diagonal in ONE wide unmasked tile and then
-its diagonal sub-tile under one constant mask, so nothing above the
-diagonal is computed beyond that sub-tile's triangle, the online-softmax
-recurrence runs at most twice a row, and the compiler sees straight-line
-code. Blocks to the left of the resident one (none when it is the whole
-sequence) run in one dynamic loop of full-width unmasked tiles. The
-backward is the same schedule with keys resident and the scores computed
-TRANSPOSED, (keys, queries): lse and delta live along lanes and
-broadcast over the key sublanes for free, and p.T @ do, ds.T @ q are
-plain matmuls; with one resident block dq leaves the kernel finished, in
-the input dtype. On the v5e at S 1024, head_dim 64 this replaced
-128 x 128 tiles in a ``while`` loop: 14.9 + 25.0 ms a gpt2-small step in
-the two kernels against 71.6 + 95.1 (PERF.md section 6, PR 25).
+its diagonal sub-tile, so the online-softmax recurrence runs at most
+twice a row and the compiler sees straight-line code. Blocks to the left
+of the resident one (none when it is the whole sequence) run in one
+dynamic loop of full-width unmasked tiles. The backward is the same
+schedule with keys resident and the scores computed TRANSPOSED, (keys,
+queries): lse and delta live along lanes and broadcast over the key
+sublanes for free, and p.T @ do, ds.T @ q are plain matmuls; with one
+resident block dq leaves the kernel finished, in the input dtype. On the
+v5e at S 1024, head_dim 64 this replaced 128 x 128 tiles in a ``while``
+loop: 14.9 + 25.0 ms a gpt2-small step in the two kernels against
+71.6 + 95.1 (PERF.md section 6, PR 25).
+
+The diagonal sub-tile is a STAIRCASE of chunks of edge ``e`` along the
+stationary operand, each meeting only the rows that can see it: key chunk
+j meets the row group's rows from j * e on (forward), query chunk i the
+sub-block's keys up to (i + 1) * e (backward). The sub-blocks wholly
+above the diagonal are never computed and only the e x e blocks ON it are
+masked; row groups, wide tiles and the dynamic loop are as they were,
+every slice a static multiple of 128 rows. With ``e = block_k`` the
+sub-tile is one chunk under one mask, bit for bit the kernels of before;
+a head at S 1024, (1024, 512) then computes 786,432 scores, 589,824 at
+``e`` 128, for a causal half of 524,800 (``_scores_computed``). ``e``
+follows the shapes, per kernel (``_auto_edges``): the backward, bound by
+the MXU, takes 128 and 24.8 -> 19.5 ms a gpt2-small step; the
+forward, whose time is its longest piece's and not its area's, stays
+whole at head size 64 (PERF.md section 6, PR 35).
 
 The causal path also uses a finite -1e30 mask value instead of -inf,
 which removes every ``isfinite`` guard from the online-softmax
@@ -230,19 +244,49 @@ def _scaled(q: jax.Array, sm_scale: Optional[float]) -> jax.Array:
     return (q * jnp.float32(sm_scale)).astype(q.dtype)
 
 
+def _rows(x: jax.Array, lo: int, hi: int) -> jax.Array:
+    """Rows [lo, hi) of a value (static, multiples of the sublane tile);
+    the value itself when that is all of it."""
+    return x if (lo, hi) == (0, x.shape[0]) else x[lo:hi]
+
+
+def _stack(parts) -> jax.Array:
+    """The parts one under another (a lone part as it is)."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _add_rows(total: jax.Array, part: jax.Array, lo: int) -> jax.Array:
+    """``total`` with ``part`` added to its rows from ``lo`` on: how a
+    piece of the staircase meets an accumulator of the whole sub-tile."""
+    hi = lo + part.shape[0]
+    return _stack([
+        *([total[:lo]] if lo else []),
+        _rows(total, lo, hi) + part,
+        *([total[hi:]] if hi < total.shape[0] else []),
+    ])
+
+
 def _fwd_causal_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-    block_q: int, block_k: int, num_blocks: int,
+    block_q: int, block_k: int, num_blocks: int, edge: int,
     heads: int = 1, sm_scale: Optional[float] = None,
 ):
     """Two-level causal forward. Grid step (bh, qi) holds ``block_q``
     query rows as ``block_q // block_k`` row groups, each a straight line
     of at most two tiles over the block's own keys - every key left of
     the group's diagonal in ONE wide unmasked tile, then the diagonal
-    sub-tile under a constant mask - after one dynamic loop over the
-    key blocks left of the resident one (none when it is the whole
-    sequence). No padding mask: a padded key is only ever visible to
-    padded query rows, which are sliced off.
+    sub-tile as a staircase of key chunks of ``edge`` - after one dynamic
+    loop over the key blocks left of the resident one (none when it is
+    the whole sequence). No padding mask: a padded key is only ever
+    visible to padded query rows, which are sliced off.
+
+    The staircase: key chunk j of the diagonal sub-tile meets the group's
+    rows [j * edge, block_k) in one matmul, the chunk the stationary
+    operand, and only its top ``edge`` x ``edge`` block, which is ON the
+    diagonal, is masked: what lies wholly above the diagonal is never
+    computed (``_scores_computed``). The recurrence still runs once a
+    row for the sub-tile. ``edge == block_k`` is one chunk: the whole
+    sub-tile under one mask.
 
     The three-array entry hands over one head a step, q pre-scaled
     (``heads`` 1, ``sm_scale`` None). The fused-projection entry hands
@@ -253,7 +297,7 @@ def _fwd_causal_kernel(
     D = q_ref.shape[-1] // heads
     # a static origin when there is one block: every slice is static
     q0 = 0 if num_blocks == 1 else pl.program_id(1) * block_q
-    tri = _triangle(block_k, True)
+    tri = _triangle(edge, True)
 
     def one_head(h: int, lanes):
         q_rows = [
@@ -261,32 +305,74 @@ def _fwd_causal_kernel(
             for r in range(n_sub)
         ]
 
-        def tile(q_blk, state, k_start, width: int, masked: bool):
-            k_blk = k_ref[0, pl.ds(k_start, width), lanes]
-            v_blk = v_ref[0, pl.ds(k_start, width), lanes]
-            s = _dot_nt(q_blk, k_blk)  # (block_k, width) f32
-            if masked:
-                s = jnp.where(tri, s, _NEG_LARGE)
-            m, l, acc = state
-            # every row has a live key in its first tile (key 0), so m is
-            # finite from then on and exp(_NEG_LARGE - m) is exactly 0: no
-            # -inf guards
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + p.sum(axis=-1, keepdims=True)
-            acc_new = acc * corr + _dot_f32(p.astype(v_blk.dtype), v_blk)
-            return m_new, l_new, acc_new
+        def tile(q_blk, state, k_start, width: int, diagonal: bool = False):
+            """One step of the recurrence for a row group, whose state is
+            its strips' (m, l, acc): over ``width`` keys in one unmasked
+            piece, or over the group's own keys (``diagonal``) in the
+            staircase's pieces. A strip's statistics stay (edge, 1)
+            values as the reductions leave them, the same in every lane:
+            stacked or sliced they would have to be spread over the lanes
+            again for every block they meet."""
+            chunk = edge if diagonal else width
+            v_chunks, s = [], []
+            for j in range(width // chunk):
+                keys = pl.ds(k_start + j * chunk, chunk)
+                v_chunks.append(v_ref[0, keys, lanes])
+                # the rows from the chunk's first query on, (rows, chunk) f32
+                first = j * edge if diagonal else 0
+                s.append(_dot_nt(_rows(q_blk, first, block_k), k_ref[0, keys, lanes]))
 
-        state = [(
-            jnp.full((block_k, 1), _NEG_LARGE, jnp.float32),
-            jnp.zeros((block_k, 1), jnp.float32),
-            jnp.zeros((block_k, D), jnp.float32),
-        )] * n_sub
+            def blocks(pieces, i: int):
+                """Strip i's (edge, chunk) block of every piece that
+                reaches it, the one ON the diagonal last."""
+                out = []
+                for piece in pieces[:i + 1] if diagonal else pieces:
+                    lo = i * edge - (block_k - piece.shape[0])
+                    out.append(_rows(piece, lo, lo + edge))
+                return out
+
+            new_state, p = [], []
+            for i, (m, l, acc) in enumerate(state):
+                s_i = blocks(s, i)
+                if diagonal:
+                    s_i[-1] = jnp.where(tri, s_i[-1], _NEG_LARGE)
+                # every row has a live key in its first tile (key 0), so m
+                # is finite from then on and exp(_NEG_LARGE - m) is exactly
+                # 0: no -inf guards
+                m_new = jnp.maximum(
+                    m, functools.reduce(jnp.maximum, s_i).max(axis=-1, keepdims=True)
+                )
+                p_i = [jnp.exp(x - m_new) for x in s_i]
+                corr = jnp.exp(m - m_new)
+                l_new = l * corr + functools.reduce(jnp.add, p_i).sum(
+                    axis=-1, keepdims=True
+                )
+                p.append(p_i)
+                new_state.append((m_new, l_new, acc * corr))
+            # a piece's matmul takes its strips' blocks one under another:
+            # the chunk of v is the stationary operand, the stream as long
+            # as the piece
+            pv = [
+                _dot_f32(
+                    _stack([p_i[j] for p_i in p if j < len(p_i)]).astype(v_j.dtype), v_j
+                )
+                for j, v_j in enumerate(v_chunks)
+            ]
+            return tuple(
+                (m, l, functools.reduce(jnp.add, [acc, *blocks(pv, i)]))
+                for i, (m, l, acc) in enumerate(new_state)
+            )
+
+        strips = block_k // edge
+        state = [((
+            jnp.full((edge, 1), _NEG_LARGE, jnp.float32),
+            jnp.zeros((edge, 1), jnp.float32),
+            jnp.zeros((edge, D), jnp.float32),
+        ),) * strips] * n_sub
         if num_blocks > 1:
             def interior(j, state):
                 return tuple(
-                    tile(q_rows[r], state[r], j * block_q, block_q, False)
+                    tile(q_rows[r], state[r], j * block_q, block_q)
                     for r in range(n_sub)
                 )
 
@@ -296,12 +382,14 @@ def _fwd_causal_kernel(
         for r in range(n_sub):
             st = state[r]
             if r:
-                st = tile(q_rows[r], st, q0, r * block_k, False)
-            m, l, acc = tile(q_rows[r], st, q0 + r * block_k, block_k, True)
-            o_ref[0, pl.ds(r * block_k, block_k), lanes] = (acc / l).astype(o_ref.dtype)
-            # lse rides a full-row (1, 1, S) block revisited across the
-            # sequential qi grid dim; each row group writes its slice
-            lse_ref[h, 0, pl.ds(q0 + r * block_k, block_k)] = (m + jnp.log(l))[:, 0]
+                st = tile(q_rows[r], st, q0, r * block_k)
+            st = tile(q_rows[r], st, q0 + r * block_k, block_k, True)
+            for i, (m, l, acc) in enumerate(st):
+                rows = r * block_k + i * edge
+                o_ref[0, pl.ds(rows, edge), lanes] = (acc / l).astype(o_ref.dtype)
+                # lse rides a full-row (1, 1, S) block revisited across the
+                # sequential qi grid dim; each strip writes its slice
+                lse_ref[h, 0, pl.ds(q0 + rows, edge)] = (m + jnp.log(l))[:, 0]
 
     for h in range(heads):
         one_head(h, _head_lanes(h, D, heads))
@@ -373,10 +461,12 @@ def _fwd_kernel(
     )
 
 
-@_traced_once("causal", "block_q", "block_k", "interpret", "kv_len", "window")
+@_traced_once(
+    "causal", "block_q", "block_k", "edge", "interpret", "kv_len", "window"
+)
 def _flash_fwd_call(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
-    causal: bool, block_q: int, block_k: int,
+    causal: bool, block_q: int, block_k: int, edge: Optional[int],
     interpret: bool, kv_len: int, window,
 ):
     """q (pre-scaled)/k/v: (BH, S_pad, D) -> out (BH, S_pad, D),
@@ -387,7 +477,7 @@ def _flash_fwd_call(
     if _nested(causal, window, block_q, block_k):
         kernel = functools.partial(
             _fwd_causal_kernel, block_q=block_q, block_k=block_k,
-            num_blocks=num_q,
+            num_blocks=num_q, edge=edge,
         )
     else:
         kernel = functools.partial(
@@ -434,10 +524,10 @@ def _qkv_blocks(shape: Tuple[int, int, int], n_heads: int):
     return (B, n_lane), lanes, lanes // D, third, row
 
 
-@_traced_once("n_heads", "sm_scale", "block_k", "interpret")
+@_traced_once("n_heads", "sm_scale", "block_k", "edge", "interpret")
 def _flash_fwd_qkv_call(
     qkv: jax.Array, *, n_heads: int, sm_scale: float,
-    block_k: int, interpret: bool,
+    block_k: int, edge: int, interpret: bool,
 ):
     """qkv (B, S_pad, 3*H*D), the fused projection as it leaves its
     matmul -> out (B, S_pad, H*D), lse (B*H, 1, S_pad) f32. Causal, the
@@ -450,7 +540,7 @@ def _flash_fwd_qkv_call(
     grid, _, heads, third, row = _qkv_blocks(qkv.shape, n_heads)
     kernel = functools.partial(
         _fwd_causal_kernel, block_q=S, block_k=block_k, num_blocks=1,
-        heads=heads, sm_scale=sm_scale,
+        edge=edge, heads=heads, sm_scale=sm_scale,
     )
     return pl.pallas_call(
         kernel,
@@ -475,19 +565,29 @@ def _flash_fwd_qkv_call(
 def _bwd_causal_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, *,
-    block_q: int, block_k: int, num_blocks: int,
+    block_q: int, block_k: int, num_blocks: int, edge: int,
     heads: int = 1, sm_scale: Optional[float] = None,
 ):
     """Two-level causal backward, the forward's schedule with the roles
     swapped: grid step (bh, ki) holds ``block_q`` KEY rows as sub-blocks
-    of ``block_k``; each meets its diagonal query sub-tile under the
-    constant mask, then every other query of the block in ONE wide
-    unmasked tile, then the query blocks below in one dynamic loop.
+    of ``block_k``; each meets its diagonal query sub-tile as a staircase
+    of query chunks of ``edge``, then every other query of the block in
+    ONE wide unmasked tile, then the query blocks below in one dynamic
+    loop.
 
     Scores are computed TRANSPOSED, (keys, queries): lse and delta live
     along lanes and broadcast over the key sublanes for free, and
     p.T @ do, ds.T @ q are plain matmuls. No padding mask (see
     ``_flash_bwd_call``).
+
+    The staircase, the forward's mirrored: query chunk i of the diagonal
+    sub-tile meets the sub-block's key rows [0, (i + 1) * edge), the
+    chunk the stationary operand of four matmuls, and only the last
+    ``edge`` of those rows, the block ON the diagonal, are masked. s, p,
+    dp and ds exist for those pieces alone, so the five matmuls shrink
+    together; the fifth, dq, takes the pieces of ds a KEY strip at a
+    time, that strip of k stationary. ``edge == block_k`` is one chunk:
+    the whole sub-tile under one mask.
 
     ``heads`` and ``sm_scale`` as in ``_fwd_causal_kernel``; with a scale
     (the fused-projection entry: one resident block) q is scaled once,
@@ -498,16 +598,21 @@ def _bwd_causal_kernel(
     assert sm_scale is None or one_block
     D = q_ref.shape[-1] // heads
     k0 = 0 if one_block else pl.program_id(1) * block_q
-    tri_t = _triangle(block_k, False)
+    tri_t = _triangle(edge, False)
 
     def one_head(h: int, lanes):
         k_rows = [k_ref[0, pl.ds(c * block_k, block_k), lanes] for c in range(n_sub)]
         v_rows = [v_ref[0, pl.ds(c * block_k, block_k), lanes] for c in range(n_sub)]
         q_all = None if sm_scale is None else _scaled(q_ref[0, :, lanes], sm_scale)
 
-        def tile(c: int, q_start, width: int, masked: bool):
-            """(dk, dv) of key sub-block c and the (width, D) dq of the
-            queries [q_start, q_start + width) from their meeting."""
+        def tile(c: int, q_start, width: int, chunk: Optional[int] = None):
+            """(dk, dv) of key sub-block c from its meeting with the
+            queries [q_start, q_start + width), and that meeting's ds
+            transposed, (keys, queries), for their dq. All of the
+            sub-block's keys, unmasked; or, for query chunk ``chunk`` of
+            the staircase (``edge`` queries), its first rows up to the
+            chunk's own positions, those under the mask."""
+            keys = block_k if chunk is None else (chunk + 1) * edge
             if q_all is None:
                 q_blk = q_ref[0, pl.ds(q_start, width), lanes]
             else:  # one block: q_start is static
@@ -515,15 +620,18 @@ def _bwd_causal_kernel(
             do_blk = do_ref[0, pl.ds(q_start, width), lanes]
             lse = lse_ref[h, :, pl.ds(q_start, width)]  # (1, width)
             delta = delta_ref[h, :, pl.ds(q_start, width)]
-            s_t = _dot_nt(k_rows[c], q_blk)  # (keys, queries); q scaled
+            k_blk, v_blk = _rows(k_rows[c], 0, keys), _rows(v_rows[c], 0, keys)
+            s_t = _dot_nt(k_blk, q_blk)  # (keys, queries); q scaled
             p_t = jnp.exp(s_t - lse)
-            if masked:
-                p_t = jnp.where(tri_t, p_t, 0.0)
+            if chunk is not None:
+                p_t = _stack([
+                    *([p_t[:keys - edge]] if keys > edge else []),
+                    jnp.where(tri_t, _rows(p_t, keys - edge, keys), 0.0),
+                ])
             dv = _dot_f32(p_t.astype(do_blk.dtype), do_blk)
-            dp_t = _dot_nt(v_rows[c], do_blk)
+            dp_t = _dot_nt(v_blk, do_blk)
             ds_t = (p_t * (dp_t - delta)).astype(q_blk.dtype)  # one cast,
-            dk = _dot_f32(ds_t, q_blk)                          # used twice
-            return dk, dv, _dot_tn(ds_t, k_rows[c])
+            return _dot_f32(ds_t, q_blk), dv, ds_t              # used twice
 
         zeros = jnp.zeros((block_k, D), jnp.float32)
         dks, dvs = [zeros] * n_sub, [zeros] * n_sub
@@ -539,10 +647,10 @@ def _bwd_causal_kernel(
                 dks, dvs = carry
                 out_k, out_v, dq = [], [], 0.0
                 for c in range(n_sub):
-                    dk, dv, dq_c = tile(c, i * block_q, block_q, False)
+                    dk, dv, ds_t = tile(c, i * block_q, block_q)
                     out_k.append(dks[c] + dk)
                     out_v.append(dvs[c] + dv)
-                    dq = dq + dq_c
+                    dq = dq + _dot_tn(ds_t, k_rows[c])
                 dq_ref[0, pl.ds(i * block_q, block_q), lanes] += dq
                 return tuple(out_k), tuple(out_v)
 
@@ -550,18 +658,35 @@ def _bwd_causal_kernel(
                 pl.program_id(1) + 1, num_blocks, below, (tuple(dks), tuple(dvs)),
             ))
         dqs = [0.0] * n_sub  # of the block's own query row groups, f32
+        stairs = block_k // edge
         for c in range(n_sub):
-            # the diagonal sub-tile, then every query of the block below it
-            for first, count in ((c, 1), (c + 1, n_sub - 1 - c)):
-                if not count:
-                    continue
-                dk, dv, dq = tile(
-                    c, k0 + first * block_k, count * block_k, first == c
+            # the diagonal sub-tile, a query chunk of the staircase at a
+            # time, each over the keys it can see
+            ds_chunks = []
+            for i in range(stairs):
+                dk, dv, ds_t = tile(c, k0 + c * block_k + i * edge, edge, i)
+                dks[c], dvs[c] = _add_rows(dks[c], dk, 0), _add_rows(dvs[c], dv, 0)
+                ds_chunks.append(ds_t)
+            # its dq a KEY strip at a time, the strip of k the stationary
+            # operand once for every chunk that sees it (a chunk at a time
+            # would load each strip again for every chunk below it)
+            for j in range(stairs):
+                strip = slice(j * edge, (j + 1) * edge)
+                ds_t = [ds_chunks[i][strip] for i in range(j, stairs)]
+                dq = _dot_tn(
+                    ds_t[0] if len(ds_t) == 1 else jnp.concatenate(ds_t, axis=1),
+                    k_rows[c][strip],
                 )
+                # (strip 0 is seen by every chunk: the whole row group's dq)
+                dqs[c] = _add_rows(dqs[c], dq, j * edge) if j else dqs[c] + dq
+            if c < n_sub - 1:  # then every query of the block below it
+                count = n_sub - 1 - c
+                dk, dv, ds_t = tile(c, k0 + (c + 1) * block_k, count * block_k)
                 dks[c], dvs[c] = dks[c] + dk, dvs[c] + dv
+                dq = _dot_tn(ds_t, k_rows[c])
                 for r in range(count):
-                    dqs[first + r] = (
-                        dqs[first + r] + dq[r * block_k:(r + 1) * block_k]
+                    dqs[c + 1 + r] = (
+                        dqs[c + 1 + r] + dq[r * block_k:(r + 1) * block_k]
                     )
         for r in range(n_sub):
             rows = pl.ds(k0 + r * block_k, block_k)
@@ -690,10 +815,12 @@ def _bwd_kernel(
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-@_traced_once("causal", "block_q", "block_k", "interpret", "kv_len", "window")
+@_traced_once(
+    "causal", "block_q", "block_k", "edge", "interpret", "kv_len", "window"
+)
 def _flash_bwd_call(
     q, k, v, o, lse, do, *,
-    causal: bool, block_q: int, block_k: int,
+    causal: bool, block_q: int, block_k: int, edge: Optional[int],
     interpret: bool, kv_len: int, window,
 ):
     BH, S, D = q.shape
@@ -716,7 +843,7 @@ def _flash_bwd_call(
         key_rows = block_q  # the resident block is a key block here
         kernel = functools.partial(
             _bwd_causal_kernel, block_q=block_q, block_k=block_k,
-            num_blocks=num_q,
+            num_blocks=num_q, edge=edge,
         )
     else:
         key_rows = block_k
@@ -747,10 +874,10 @@ def _flash_bwd_call(
     return dq.astype(q.dtype), dk, dv
 
 
-@_traced_once("n_heads", "sm_scale", "block_k", "interpret")
+@_traced_once("n_heads", "sm_scale", "block_k", "edge", "interpret")
 def _flash_bwd_qkv_call(
     qkv, o, lse, do, *, n_heads: int, sm_scale: float,
-    block_k: int, interpret: bool,
+    block_k: int, edge: int, interpret: bool,
 ):
     """The cotangent of ``_flash_fwd_qkv_call``'s qkv, (B, S_pad, 3*H*D),
     written by the one kernel into one array (``_bwd_qkv_kernel``)."""
@@ -766,7 +893,7 @@ def _flash_bwd_qkv_call(
     ).transpose(0, 2, 1).reshape(B * n_heads, 1, S)
     kernel = functools.partial(
         _bwd_qkv_kernel, block_q=S, block_k=block_k, num_blocks=1,
-        heads=heads, sm_scale=sm_scale,
+        edge=edge, heads=heads, sm_scale=sm_scale,
     )
     return pl.pallas_call(
         kernel,
@@ -794,23 +921,23 @@ def _flash(cfg, q, k, v):
 
 
 def _flash_fwd_res(cfg, q, k, v):
-    causal, block_q, block_k, interpret, kv_len, window = cfg
+    causal, block_q, block_k, edges, interpret, kv_len, window = cfg
     out, lse = _flash_fwd_call(
         q, k, v, causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-        kv_len=kv_len, window=window,
+        block_q=block_q, block_k=block_k, edge=edges and edges[0],
+        interpret=interpret, kv_len=kv_len, window=window,
     )
     out, lse = _named_residuals(out, lse)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_res(cfg, res, g):
-    causal, block_q, block_k, interpret, kv_len, window = cfg
+    causal, block_q, block_k, edges, interpret, kv_len, window = cfg
     q, k, v, out, lse = res
     return _flash_bwd_call(
         q, k, v, out, lse, g, causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-        kv_len=kv_len, window=window,
+        block_q=block_q, block_k=block_k, edge=edges and edges[1],
+        interpret=interpret, kv_len=kv_len, window=window,
     )
 
 
@@ -839,19 +966,19 @@ def _flash_qkv(cfg, qkv):
 
 
 def _flash_qkv_fwd_res(cfg, qkv):
-    n_heads, sm_scale, block_k, interpret = cfg
+    n_heads, sm_scale, block_k, edges, interpret = cfg
     out, lse = _named_residuals(*_flash_fwd_qkv_call(
         qkv, n_heads=n_heads, sm_scale=sm_scale, block_k=block_k,
-        interpret=interpret,
+        edge=edges[0], interpret=interpret,
     ))
     return out, (qkv, out, lse)
 
 
 def _flash_qkv_bwd_res(cfg, res, g):
-    n_heads, sm_scale, block_k, interpret = cfg
+    n_heads, sm_scale, block_k, edges, interpret = cfg
     return (_flash_bwd_qkv_call(
         *res, g, n_heads=n_heads, sm_scale=sm_scale, block_k=block_k,
-        interpret=interpret,
+        edge=edges[1], interpret=interpret,
     ),)
 
 
@@ -886,6 +1013,10 @@ def _auto_tiles(
     gpt2-small step in the two kernels against 17.1 + 23.6 at (1024, 256),
     19.9 + 23.9 at (1024, 128), and 71.6 + 95.1 for the (128, 128) tiles
     in a dynamic loop that it replaces; head_dim 128 ranks them the same.
+    Smaller row groups compute less above the diagonal (1.5, 1.25, 1.125
+    times the causal half) and lose more by their shorter streams in the
+    wide tiles; the diagonal sub-tile alone is cut finer by
+    ``_auto_edges`` (PR 35), which leaves the row groups 512 tall.
     Longer sequences, and the general path at 2048 and over, keep
     (512, 512), the general path below that (128, 128): not measured in
     PR 25."""
@@ -899,16 +1030,67 @@ def _auto_tiles(
     return (512, 512) if s_pad >= 2048 else (128, 128)
 
 
+def _auto_edges(block_k: int, head_dim: int) -> Tuple[int, int]:
+    """The edge of the chunks that the diagonal sub-tile's staircase is
+    cut into, (forward, backward), for a call that names none: from the
+    sub-tile's edge and the head size, as ``_auto_tiles`` chooses the
+    sub-tile; the sub-tile whole where the edge does not divide it.
+    Measured on the v5e inside the whole training step (PERF.md section
+    6, PR 35; ms a step in the kernel, forward | backward):
+
+        edge    S 1024, D 64, (1024, 512)    S 4096, D 128, (512, 512)
+        whole       16.24 | 24.81                2.84 | 4.86
+        256         16.78 | 22.50                2.74 | 4.80
+        128         17.63 | 21.31, 19.54         2.80 | 4.74, 4.70
+
+    (the second backward figure with the staircase's dq taken a key strip
+    at a time, as committed; the others a chunk at a time). The backward
+    is bound by the MXU (93% of its issue slots whole, by the compiler's
+    own schedule), so the area it leaves out is time it saves: 128, the
+    finest the lanes allow, at either head size. The forward is not: its
+    matmul and softmax phases alternate, a phase's pieces go to one MXU
+    each, so its time is that of the LONGEST piece and the staircase's
+    shorter ones save nothing, while every strip adds a reduction to
+    wait for. At head size 64 it stays whole; at 128 an edge of 256 is
+    the best of the three by a little. Other shapes run the nearest
+    regime's choice, not measured."""
+    forward = 256 if head_dim >= 128 and block_k % 256 == 0 else block_k
+    backward = 128 if block_k % 128 == 0 else block_k
+    return forward, backward
+
+
+def _scores_computed(s_pad: int, block_q: int, block_k: int, edge: int) -> int:
+    """Scores (query, key) pairs a head computes on the two-level causal
+    schedule, forward or backward (one is the other transposed): the
+    engagement figure of a schedule that is static. Per resident block:
+    ``block_q`` squared for every block to its left, a wide tile of
+    ``r * block_k`` keys for row group r, and the diagonal sub-tile's
+    staircase, chunk j of ``edge`` keys meeting ``block_k - j * edge``
+    rows: ``block_k * (block_k + edge) / 2``. The causal half is
+    ``s_pad * (s_pad + 1) / 2``. At S 1024, (1024, 512): 786,432 with
+    ``edge`` 512 (the whole sub-tile, until PR 35), 655,360 at 256,
+    589,824 at 128, for a causal half of 524,800; at S 4096, (512, 512):
+    9,437,184, 8,912,896, 8,650,752 for 8,390,656."""
+    blocks, n_sub = s_pad // block_q, block_q // block_k
+    left = block_q * block_q * (blocks * (blocks - 1) // 2)
+    wide = block_k * block_k * (n_sub * (n_sub - 1) // 2)
+    stairs = n_sub * (block_k * (block_k + edge) // 2)
+    return left + blocks * (wide + stairs)
+
+
 def _tiles(
     seq: int, head_dim: int, interpret: bool,
-    block_q: Optional[int], block_k: Optional[int], nested: bool,
-) -> Tuple[int, int, int]:
-    """(block_q, block_k, padded length) of a call: the blocks it names,
-    else ``_auto_tiles``, clamped to the sequence and rounded to what the
-    hardware stores. Arbitrary S is handled by zero-padding the sequence
-    up to the block multiple: padded keys are masked in-kernel (on the
-    causal schedule only padded queries can see them), padded queries
-    carry zero cotangents, so numerics are exact."""
+    block_q: Optional[int], block_k: Optional[int],
+    block_diag: Optional[int], nested: bool,
+) -> Tuple[int, int, int, Optional[Tuple[int, int]]]:
+    """(block_q, block_k, padded length, staircase edges) of a call: the
+    blocks it names, else ``_auto_tiles``, clamped to the sequence and
+    rounded to what the hardware stores. Arbitrary S is handled by
+    zero-padding the sequence up to the block multiple: padded keys are
+    masked in-kernel (on the causal schedule only padded queries can see
+    them), padded queries carry zero cotangents, so numerics are exact.
+    The edges are (forward, backward) where the blocks nest
+    (``_auto_edges``, or ``block_diag`` for both), else None."""
     auto_q, auto_k = _auto_tiles(seq, head_dim, interpret, nested=nested)
     unit = 8 if interpret else 128
     s8 = _cdiv(seq, unit) * unit
@@ -918,7 +1100,19 @@ def _tiles(
         block_q = _cdiv(block_q, 128) * 128
         block_k = _cdiv(block_k, 128) * 128
     base = block_q * block_k // math.gcd(block_q, block_k)
-    return block_q, block_k, _cdiv(seq, base) * base
+    edges = None
+    if nested and block_q % block_k == 0:
+        edges = _auto_edges(block_k, head_dim)
+        if block_diag:
+            edge = min(block_diag, block_k)
+            if not interpret:
+                edge = _cdiv(edge, 128) * 128
+            if block_k % edge:
+                raise ValueError(
+                    f"block_diag {edge} does not divide block_k {block_k}"
+                )
+            edges = (edge, edge)
+    return block_q, block_k, _cdiv(seq, base) * base, edges
 
 
 def _qkv_lanes(n_heads: int, head_dim: int) -> Optional[int]:
@@ -942,6 +1136,7 @@ def flash_attention_qkv(
     sm_scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    block_diag: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal multi-head attention on a fused projection, in its layout.
@@ -949,7 +1144,8 @@ def flash_attention_qkv(
     Args:
         qkv: (B, S, 3 * n_heads * head_dim): ``x @ wqkv``, the q columns
             first, then k, then v, each head's columns together.
-        sm_scale, block_q, block_k, interpret: as ``flash_attention``. The
+        sm_scale, block_q, block_k, block_diag, interpret: as
+            ``flash_attention``. The
             scale is applied inside the kernels, rounded as the fold
             outside would round it (bit-equal at head size 64).
     Returns:
@@ -968,8 +1164,8 @@ def flash_attention_qkv(
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
     interp = _pick_interpret(interpret)
-    block_q, block_k, S_pad = _tiles(
-        S, head_dim, interp, block_q, block_k, nested=True
+    block_q, block_k, S_pad, edges = _tiles(
+        S, head_dim, interp, block_q, block_k, block_diag, nested=True
     )
     if (
         _qkv_lanes(n_heads, head_dim) is None
@@ -981,11 +1177,11 @@ def flash_attention_qkv(
         )
         return flash_attention(
             q, k, v, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-            interpret=interp,
+            block_diag=block_diag, interpret=interp,
         ).reshape(B, S, n_heads * head_dim)
     if S_pad != S:
         qkv = jnp.pad(qkv, ((0, 0), (0, S_pad - S), (0, 0)))
-    out = _flash_qkv((n_heads, float(sm_scale), block_k, interp), qkv)
+    out = _flash_qkv((n_heads, float(sm_scale), block_k, edges, interp), qkv)
     return out[:, :S]
 
 
@@ -998,6 +1194,7 @@ def flash_attention(
     sm_scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    block_diag: Optional[int] = None,
     interpret: Optional[bool] = None,
     mesh: Any = None,
     batch_axis: Optional[str] = "data",
@@ -1027,6 +1224,10 @@ def flash_attention(
             bit-equal to an unfused baseline that scales the f32
             logits. Numerically benign for training; pass f32 q/k/v or
             a power-of-two scale when exactness matters.
+        block_diag: edge of the chunks the two-level schedule cuts its
+            diagonal sub-tile into, for both kernels; a divisor of
+            ``block_k``, rounded as the blocks are. Default:
+            ``_auto_edges``, from ``block_k`` and the head size.
         block_q, block_k: VMEM tile sizes; clamped to S, and on real TPU
             rounded UP to 128-multiples (Mosaic's lane-aligned store
             requirement — a requested 64 runs as 128 on hardware;
@@ -1061,8 +1262,8 @@ def flash_attention(
         spec = P(batch_axis, None, head_axis, None)
         local = functools.partial(
             flash_attention, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            window=window,
+            block_q=block_q, block_k=block_k, block_diag=block_diag,
+            interpret=interpret, window=window,
         )
         # check_vma=False: pallas out_shapes carry no varying-mesh-axes
         # annotation, which the new shard_map VMA typing would reject
@@ -1072,8 +1273,9 @@ def flash_attention(
         )(q, k, v)
 
     interp = _pick_interpret(interpret)
-    block_q, block_k, S_pad = _tiles(
-        S, D, interp, block_q, block_k, nested=causal and window is None
+    block_q, block_k, S_pad, edges = _tiles(
+        S, D, interp, block_q, block_k, block_diag,
+        nested=causal and window is None,
     )
 
     # (B, S, H, D) -> (B*H, S_pad, D). Blocks always span the full head
@@ -1091,7 +1293,7 @@ def flash_attention(
         return x
 
     cfg = (
-        bool(causal), block_q, block_k, interp, S,
+        bool(causal), block_q, block_k, edges, interp, S,
         None if window is None else int(window),
     )
     # sm_scale folded into q OUTSIDE the custom_vjp: one cheap (S, D)
