@@ -337,14 +337,19 @@ def _observe_link() -> Dict[str, float]:
 # head_dim 128 shape of the d_model 2048 point; the benchmark's shapes -
 # GPT-2 (1024 positions, 12 heads of 64: the whole sequence resident, the
 # static causal schedule) and OLMoE (4096 positions, 16 heads of 128:
-# longer than one resident block, (512, 512) tiles); then the kernel's
-# other two code paths at a reduced batch: the general (windowed) loop,
-# and a ragged short sequence on the 128-wide tiles.
+# longer than one resident block, (512, 512) tiles) and Mellum2 (8192
+# positions of head size 128, a window of 1024 and none: the general loop
+# and the causal schedule with 2 MiB key/value rows resident, the
+# backward's rows past the default VMEM limit); then the kernel's other
+# two code paths at a reduced batch: the general (windowed) loop, and a
+# ragged short sequence on the 128-wide tiles.
 FLASH_CASES = (
     ("big", BATCH, SEQ - 1, 16, 64, None),
     ("gpt2", 2, 1024, 12, 64, None),
     ("head_dim128", 8, SEQ - 1, 16, 128, None),
     ("olmoe", 2, 4096, 16, 128, None),
+    ("mellum_sliding", 1, 8192, 8, 128, 1024),
+    ("mellum_full", 1, 8192, 8, 128, None),
     ("windowed", 2, SEQ - 1, 4, 64, 512),
     ("ragged", 2, 99, 4, 64, None),
 )

@@ -71,17 +71,23 @@ class TestCompileCache:
 
 
 class TestChipSmoke:
-    @pytest.mark.parametrize("config", ["gpt2-small", "gpt2-medium", "olmoe-1b-7b-l1"])
+    @pytest.mark.parametrize(
+        "config",
+        ["gpt2-small", "gpt2-medium", "olmoe-1b-7b-l1", "mellum2-12b-a2.5b-l4-ep8"],
+    )
     def test_kernels_phase_runs_the_benchmarks_flash_shapes(self, config):
         """Every configuration of the benchmark meets the flash kernels at
-        some (positions, head size): the kernels phase compiles and checks
-        that pair on the chip (the head count is a batch of the kernel)."""
+        some (positions, head size, window), one for each kind of layer it
+        has: the kernels phase compiles and checks each on the chip (the
+        head count is a batch of the kernel)."""
         from benchmark import common
 
         sizes = common.load_json("configs", config + ".json")
         cfg = common.load_by_name("families", sizes["family"]).build(sizes)
-        shapes = {(S, D) for _, _, S, _, D, window in chip_smoke.FLASH_CASES if window is None}
-        assert (sizes["seq"] - 1, cfg.head_dim) in shapes
+        shapes = {(S, D, window) for _, _, S, _, D, window in chip_smoke.FLASH_CASES}
+        windows = {kind.window for kind in getattr(cfg, "kinds", ())} or {None}
+        for window in windows:
+            assert (sizes["seq"] - 1, cfg.head_dim, window) in shapes
 
     def test_without_a_chip_it_fails_and_says_so(self):
         # the tier-1 environment pins the CPU; the script overrides that

@@ -152,10 +152,10 @@ def _wrong_half_rotated(cfg, params, tokens, monkeypatch):
     """Pairs (2i, 2i + 1), the interleaved form, instead of (i, i + half)."""
     right = olmoe.rope
 
-    def interleaved(x, theta):
+    def interleaved(x, theta, yarn=None):
         half = x.shape[-1] // 2
         mix = jnp.stack([jnp.arange(half), jnp.arange(half) + half], -1).reshape(-1)
-        return right(x[..., jnp.argsort(mix)], theta)[..., mix]
+        return right(x[..., jnp.argsort(mix)], theta, yarn)[..., mix]
 
     monkeypatch.setattr(olmoe, "rope", interleaved)
     return olmoe.loss_fn(cfg, params, tokens)

@@ -1,4 +1,4 @@
-from torchft_tpu.models import cnn, moe, olmoe
+from torchft_tpu.models import cnn, mellum, moe, olmoe
 from torchft_tpu.models.cnn import CNNConfig, tiny_cnn_config
 from torchft_tpu.models.moe import MoEConfig, tiny_moe_config
 from torchft_tpu.models.olmoe import OlmoeConfig, tiny_olmoe_config
@@ -25,6 +25,7 @@ __all__ = [
     "init_params",
     "loss_fn",
     "make_train_step",
+    "mellum",
     "moe",
     "olmoe",
     "param_sharding_rules",

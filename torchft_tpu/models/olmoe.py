@@ -34,19 +34,69 @@ For activations ``x`` (B, S, D), per layer, pre-norm::
 Pure-functional like the dense family: f32 master parameters in a pytree,
 matmuls in ``cfg.dtype``; the router's matmul, softmax and top-k stay in
 float32, because a rounding there changes which experts a token gets.
+
+What is DATA in the configuration, with OLMoE's form as every default, so
+that a second sparse decoder is a configuration and not a copy of this
+file (``models/mellum.py`` is one):
+
+- a KIND per layer (``layer_kinds``: ``AttentionKind``): a sliding window
+  or none, YaRN's blend of the rotary frequencies or none, and a name,
+  under which the layer's attention is scoped (``attn/<name>/..``);
+- grouped-query attention (``n_kv_heads`` key/value heads, each shared by
+  ``n_heads / n_kv_heads`` consecutive query heads and repeated to them
+  before the kernel), a head size that is stated and not derived
+  (``head_dim``), QK-norm over each head (``qk_norm_per_head``);
+- the top-K weights divided by their sum (``renormalize_top_k``);
+- ONE RANK'S SHARE of an expert-parallel layer (``held_experts``: first,
+  count): the router still scores all ``n_experts``, the weights are
+  (held, d, f), and the layer returns the part of the result its own
+  experts give - what the absent experts would add is left out, and no
+  code stands in for them or their exchange (``_held_dense``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import math
 from dataclasses import dataclass
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops import flash_attention
 from .transformer import _dense_init, _rmsnorm, next_token_loss
+
+
+@dataclass(frozen=True)
+class Yarn:
+    """YaRN's blend of rotary frequencies (Peng et al., arXiv:2309.00071,
+    as ``transformers``' ``_compute_yarn_parameters`` has it): pair ``i``
+    turns at ``(1 - r_i) f_i + r_i f_i / factor`` with ``f_i`` the plain
+    frequency and ``r_i`` a ramp from 0 at the pair that turns
+    ``beta_fast`` times over ``original_positions`` to 1 at the pair that
+    turns ``beta_slow`` times; cos and sin are both multiplied by
+    ``attention_factor``."""
+
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """What one layer's attention is: ``window`` keys back (``q_pos -
+    k_pos < window``) or all of them, and YaRN's blend of the rotary
+    frequencies or the plain ones. ``name`` is the ``jax.named_scope`` the
+    layer's attention runs under, inside ``attn``; the unnamed kind is
+    OLMoE's and adds no scope."""
+
+    name: Optional[str] = None
+    window: Optional[int] = None
+    yarn: Optional[Yarn] = None
 
 
 @dataclass(frozen=True)
@@ -66,11 +116,39 @@ class OlmoeConfig:
     balance_coef: float = 0.01
     z_coef: float = 0.001
     dtype: Any = jnp.bfloat16  # activation/matmul dtype; params stay f32
+    # what OLMoE does not vary (module docstring); None is OLMoE's form
+    n_kv_heads: Optional[int] = None  # n_heads
+    head_dim: Optional[int] = None  # d_model / n_heads
+    qk_norm_per_head: bool = False
+    layer_kinds: Optional[Tuple[AttentionKind, ...]] = None  # all unnamed
+    renormalize_top_k: bool = False
+    held_experts: Optional[Tuple[int, int]] = None  # (first, count): all
+
+    def __post_init__(self) -> None:
+        if self.head_dim is None:
+            if self.d_model % self.n_heads:
+                raise ValueError("d_model is no multiple of n_heads: state head_dim")
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        first, count = self.held
+        if self.n_heads % self.kv_heads:
+            raise ValueError("n_heads is no multiple of n_kv_heads")
+        if len(self.kinds) != self.n_layers:
+            raise ValueError("layer_kinds names another number of layers than n_layers")
+        if first < 0 or count < 1 or first + count > self.n_experts:
+            raise ValueError(f"held_experts {self.held_experts} lie outside the {self.n_experts}")
 
     @property
-    def head_dim(self) -> int:
-        assert self.d_model % self.n_heads == 0
-        return self.d_model // self.n_heads
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def kinds(self) -> Tuple[AttentionKind, ...]:
+        return self.layer_kinds or (AttentionKind(),) * self.n_layers
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts this rank holds in every layer."""
+        return self.held_experts or (0, self.n_experts)
 
 
 def tiny_olmoe_config() -> OlmoeConfig:
@@ -84,11 +162,13 @@ def tiny_olmoe_config() -> OlmoeConfig:
 def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
     """f32 master params; matmuls cast to cfg.dtype at use."""
     d, f, e = cfg.d_model, cfg.expert_width, cfg.n_experts
+    q_width, kv_width = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    held = cfg.held[1]
     keys = jax.random.split(key, 2 + cfg.n_layers)
     scale = d ** -0.5
 
-    def ones() -> jax.Array:
-        return jnp.ones((d,), jnp.float32)
+    def ones(width: int = d) -> jax.Array:
+        return jnp.ones((width,), jnp.float32)
 
     blocks = []
     for i in range(cfg.n_layers):
@@ -96,19 +176,19 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
         blocks.append({
             "ln1": {"scale": ones()},
             "attn": {
-                "wq": _dense_init(bk[0], (d, d), scale),
-                "wk": _dense_init(bk[1], (d, d), scale),
-                "wv": _dense_init(bk[2], (d, d), scale),
-                "wo": _dense_init(bk[3], (d, d), scale),
-                "q_norm": ones(),
-                "k_norm": ones(),
+                "wq": _dense_init(bk[0], (d, q_width), scale),
+                "wk": _dense_init(bk[1], (d, kv_width), scale),
+                "wv": _dense_init(bk[2], (d, kv_width), scale),
+                "wo": _dense_init(bk[3], (q_width, d), q_width ** -0.5),
+                "q_norm": ones(cfg.head_dim if cfg.qk_norm_per_head else q_width),
+                "k_norm": ones(cfg.head_dim if cfg.qk_norm_per_head else kv_width),
             },
             "ln2": {"scale": ones()},
             "moe": {
                 "router": _dense_init(bk[4], (d, e), scale),
-                "w_gate": _dense_init(bk[5], (e, d, f), scale),
-                "w_up": _dense_init(bk[6], (e, d, f), scale),
-                "w_down": _dense_init(bk[7], (e, f, d), f ** -0.5),
+                "w_gate": _dense_init(bk[5], (held, d, f), scale),
+                "w_up": _dense_init(bk[6], (held, d, f), scale),
+                "w_down": _dense_init(bk[7], (held, f, d), f ** -0.5),
             },
         })
     return {
@@ -119,31 +199,62 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
     }
 
 
-def rope(x: jax.Array, theta: float) -> jax.Array:
+def _yarn_ramp(yarn: Yarn, theta: float, head_dim: int) -> jax.Array:
+    """``r_i`` of ``Yarn`` for the ``head_dim / 2`` pairs: 0 up to the pair
+    ``low``, 1 from the pair ``high`` on, linear between."""
+
+    def pair_that_turns(times: float) -> float:
+        return head_dim * math.log(
+            yarn.original_positions / (times * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_that_turns(yarn.beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(yarn.beta_slow)), head_dim - 1)
+    pairs = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    return jnp.clip((pairs - low) / max(high - low, 1e-3), 0.0, 1.0)
+
+
+def rope(x: jax.Array, theta: float, yarn: Optional[Yarn] = None) -> jax.Array:
     """Rotary embedding of ``x`` (B, S, H, head_dim) at positions 0..S-1:
     the pair (``i``, ``i + head_dim / 2``) turns by ``pos * theta ** (-2 i
-    / head_dim)``. Computed in float32, rounded once."""
+    / head_dim)``, or by ``yarn``'s blend of that frequency (``Yarn``).
+    Computed in float32, rounded once."""
     S, half = x.shape[1], x.shape[-1] // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is not None:
+        ramp = _yarn_ramp(yarn, theta, 2 * half)
+        inv_freq = (1.0 - ramp) * inv_freq + ramp * inv_freq / yarn.factor
     angle = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq  # (S, half)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return turned.astype(x.dtype)
 
 
-def attention(cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array) -> jax.Array:
-    B, S, D = x.shape
+def attention(
+    cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array,
+    kind: AttentionKind = AttentionKind(),
+) -> jax.Array:
+    B, S, _ = x.shape
+    h, kv, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     q, k, v = (x @ p[w].astype(cfg.dtype) for w in ("wq", "wk", "wv"))
     with jax.named_scope("qk_norm"):
+        if cfg.qk_norm_per_head:  # each head's own dh, one scale for all
+            q, k = q.reshape(B, S, h, dh), k.reshape(B, S, kv, dh)
         q = _rmsnorm(q, p["q_norm"], cfg.rms_norm_eps)
         k = _rmsnorm(k, p["k_norm"], cfg.rms_norm_eps)
-    q, k, v = (t.reshape(B, S, cfg.n_heads, cfg.head_dim) for t in (q, k, v))
+    q, k, v = (t.reshape(B, S, n, dh) for t, n in ((q, h), (k, kv), (v, kv)))
     with jax.named_scope("rope"):
-        q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+        q, k = (rope(t, cfg.rope_theta, kind.yarn) for t in (q, k))
+    if kv != h:
+        # query head i meets key/value head i // (h / kv): each is repeated
+        # to its query heads (a grouped kernel would read it once)
+        k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
     # the fused kernel everywhere: compiled on a TPU, interpreted elsewhere
-    out = flash_attention(q, k, v)
-    return out.reshape(B, S, D) @ p["wo"].astype(cfg.dtype)
+    out = flash_attention(q, k, v, window=kind.window)
+    return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -189,10 +300,85 @@ def _unsort_bwd(order, g):
 _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 
 
+def _experts(
+    cfg: OlmoeConfig, p: Dict[str, Any], rows: jax.Array, group_sizes: jax.Array
+) -> jax.Array:
+    """``W_down,e (silu(W_gate,e x) * W_up,e x)`` of every row, the rows
+    of expert ``e`` being the ``group_sizes[e]`` that follow those of the
+    experts before it: three grouped matmuls."""
+
+    def grouped(lhs: jax.Array, w: jax.Array) -> jax.Array:
+        return jax.lax.ragged_dot(lhs, w.astype(cfg.dtype), group_sizes)
+
+    hidden = jax.nn.silu(grouped(rows, p["w_gate"])) * grouped(rows, p["w_up"])
+    return grouped(hidden, p["w_down"])
+
+
+def _held_dense(
+    cfg: OlmoeConfig, p: Dict[str, Any], tokens: jax.Array,
+    weights: jax.Array, chosen: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the layer's output, (N, D) float32, and
+    how many of the N x K claims they hold: every held expert applied to
+    every token and kept, times its weight, where the token chose it.
+    Exact and dropless by construction; its work is N x held rows whatever
+    the routing."""
+    first, held = cfg.held
+    with jax.named_scope("dispatch"):
+        # (N, held): the token's weight on each held expert, 0 where it
+        # chose another
+        mine = (chosen - first)[:, :, None] == jnp.arange(held)
+        gate = jnp.sum(jnp.where(mine, weights[:, :, None], 0.0), axis=1)
+    with jax.named_scope("experts"):
+        def into(w: str) -> jax.Array:  # (held, N, f)
+            return jnp.einsum("nd,edf->enf", tokens, p[w].astype(cfg.dtype))
+
+        hidden = jax.nn.silu(into("w_gate")) * into("w_up")
+    with jax.named_scope("combine"):
+        hidden = (hidden * gate.T[:, :, None]).astype(cfg.dtype)
+    with jax.named_scope("experts"):
+        y = jnp.einsum(
+            "enf,efd->nd", hidden, p["w_down"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+    return y, jnp.sum(mine)
+
+
+def _every_expert(
+    cfg: OlmoeConfig, p: Dict[str, Any], tokens: jax.Array,
+    weights: jax.Array, chosen: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """The whole layer where every expert is held, (N, D) float32, and how
+    many claims each expert got: the claims sorted by expert, three
+    grouped matmuls over all N x K rows, each row back to its token."""
+    N, D = tokens.shape
+    E, K = cfg.n_experts, cfg.experts_per_token
+
+    with jax.named_scope("dispatch"):
+        # claims are numbered token-major (claim c belongs to token c // K);
+        # sorted by expert, each expert's rows are one contiguous group
+        expert_of_claim = chosen.reshape(N * K)
+        order = jnp.argsort(expert_of_claim, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.zeros((E,), jnp.int32).at[expert_of_claim].add(1)
+        rows = _to_claims(tokens, order, inverse, K)  # (N * K, D)
+
+    with jax.named_scope("experts"):
+        out_rows = _experts(cfg, p, rows, group_sizes)  # (N * K, D)
+
+    with jax.named_scope("combine"):
+        back = _unsort(out_rows, order, inverse).reshape(N, K, D)
+        y = jnp.einsum(
+            "nkd,nk->nd", back, weights, preferred_element_type=jnp.float32
+        )
+    return y, group_sizes
+
+
 def moe_layer(
     cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Dropless top-K routed SwiGLU experts.
+    """Dropless top-K routed SwiGLU experts, or the held experts' share of
+    them (``cfg.held_experts``, ``_held_dense``).
 
     Args:
         x: (B, S, D) activations.
@@ -200,7 +386,9 @@ def moe_layer(
         the (B, S, D) output, and the router's sums over this layer's
         tokens for the auxiliary losses: ``claims`` (E,) how many tokens
         chose each expert, ``probs`` (E,) the sum of ``p[:, e]``, ``z``
-        the sum of ``logsumexp(r) ** 2``.
+        the sum of ``logsumexp(r) ** 2``; and ``held_claims``, how many of
+        the N x K claims fell on an expert held here (all of them where
+        every expert is).
     """
     B, S, D = x.shape
     N, E, K = B * S, cfg.n_experts, cfg.experts_per_token
@@ -214,47 +402,39 @@ def moe_layer(
             precision=jax.lax.Precision.HIGHEST,
         )  # (N, E)
         probs = jax.nn.softmax(logits, axis=-1)
-        weights, chosen = jax.lax.top_k(probs, K)  # (N, K), not renormalised
+        weights, chosen = jax.lax.top_k(probs, K)  # (N, K)
+        if cfg.renormalize_top_k:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
 
-    with jax.named_scope("dispatch"):
-        # claims are numbered token-major (claim c belongs to token c // K);
-        # sorted by expert, each expert's rows are one contiguous group
-        expert_of_claim = chosen.reshape(N * K)
-        order = jnp.argsort(expert_of_claim, stable=True)
-        inverse = jnp.argsort(order)
-        group_sizes = jnp.zeros((E,), jnp.int32).at[expert_of_claim].add(1)
-        rows = _to_claims(tokens, order, inverse, K)  # (N * K, D)
-
-    with jax.named_scope("experts"):
-        def grouped(lhs: jax.Array, w: jax.Array) -> jax.Array:
-            return jax.lax.ragged_dot(lhs, w.astype(cfg.dtype), group_sizes)
-
-        hidden = jax.nn.silu(grouped(rows, p["w_gate"])) * grouped(rows, p["w_up"])
-        out_rows = grouped(hidden, p["w_down"])  # (N * K, D)
-
-    with jax.named_scope("combine"):
-        back = _unsort(out_rows, order, inverse).reshape(N, K, D)
-        y = jnp.einsum(
-            "nkd,nk->nd", back, weights, preferred_element_type=jnp.float32
-        )
+    if cfg.held_experts is None:
+        y, claims = _every_expert(cfg, p, tokens, weights, chosen)
+        held_claims = N * K
+    else:
+        y, held_claims = _held_dense(cfg, p, tokens, weights, chosen)
+        claims = jnp.zeros((E,), jnp.int32).at[chosen.reshape(N * K)].add(1)
 
     stats = {
-        "claims": group_sizes.astype(jnp.float32),
+        "claims": claims.astype(jnp.float32),
         "probs": jnp.sum(probs, axis=0),
         "z": jnp.sum(jax.nn.logsumexp(logits, axis=-1) ** 2),
+        "held_claims": jnp.asarray(held_claims, jnp.float32),
     }
     return y.reshape(B, S, D).astype(x.dtype), stats
 
 
 def _block(
-    cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array
+    cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array,
+    kind: AttentionKind = AttentionKind(),
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     # the dense family's scope names (transformer._block) with the new
     # mechanisms nested in them: attn/qk_norm, attn/rope, mlp/moe/router,
-    # mlp/moe/dispatch, mlp/moe/experts, mlp/moe/combine. Metadata only.
+    # mlp/moe/dispatch, mlp/moe/experts, mlp/moe/combine; a named kind of
+    # layer puts its name between: attn/sliding/rope, attn/full/flash_fwd.
+    # Metadata only.
     eps = cfg.rms_norm_eps
-    with jax.named_scope("attn"):
-        x = x + attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"], eps))
+    of_kind = jax.named_scope(kind.name) if kind.name else contextlib.nullcontext()
+    with jax.named_scope("attn"), of_kind:
+        x = x + attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"], eps), kind)
     with jax.named_scope("mlp"), jax.named_scope("moe"):
         y, stats = moe_layer(cfg, p["moe"], _rmsnorm(x, p["ln2"]["scale"], eps))
         return x + y, stats
@@ -268,8 +448,8 @@ def _hidden(
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
     total = None
-    for p in params["blocks"]:
-        x, stats = _block(cfg, p, x)
+    for kind, p in zip(cfg.kinds, params["blocks"]):
+        x, stats = _block(cfg, p, x, kind)
         total = stats if total is None else jax.tree_util.tree_map(jnp.add, total, stats)
     return x, total
 

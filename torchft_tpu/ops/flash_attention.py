@@ -194,6 +194,29 @@ def _tile_mask(
 # 128 MiB.
 _QKV_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 2**20)
 
+# Mosaic's scoped VMEM limit for a call that names none.
+_VMEM_DEFAULT = 16 * 2**20
+
+
+def _resident_params(*blocks) -> dict:
+    """``compiler_params`` of a three-array call, from the blocks
+    (``(shape, dtype)`` each) its specs keep in VMEM, each with its second
+    buffer. Up to 8,192 / head_dim x 128 rows a resident row - every shape
+    until PR 36 - they leave the body a quarter of the default limit and
+    more, and the call names no limit (its lowered text is what it was).
+    Past that the limit is the buffers plus the default for the body: at
+    8,192 positions of head size 128 the backward's resident q, dO and f32
+    dq rows are 16.1 MiB buffered and the compiler refused the call by
+    1 MiB (compiled for a described v5e, PR 36)."""
+    buffers = 2 * sum(
+        math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in blocks
+    )
+    if buffers <= 3 * _VMEM_DEFAULT // 4:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=buffers + _VMEM_DEFAULT
+    )}
+
 
 def _traced_once(*static_argnames: str):
     """For the functions that build a ``pallas_call``: an inlined
@@ -501,6 +524,10 @@ def _flash_fwd_call(
         ],
         interpret=interpret,
         name="flash_fwd",  # the kernel's name in a device trace
+        **_resident_params(
+            ((block_q, D), q.dtype), ((S, D), k.dtype), ((S, D), v.dtype),
+            ((block_q, D), q.dtype), ((S,), jnp.float32),
+        ),
     )(q, k, v)
 
 
@@ -853,6 +880,9 @@ def _flash_bwd_call(
             window=window,
         )
     kblk3 = pl.BlockSpec((1, key_rows, D), lambda bh, i: (bh, i, 0))
+    # over several key blocks dq is the revisited f32 accumulator (cast to
+    # q.dtype below); one block writes it finished
+    dq_dtype = q.dtype if nested and num_q == 1 else jnp.float32
 
     dq, dk, dv = pl.pallas_call(
         kernel,
@@ -860,16 +890,17 @@ def _flash_bwd_call(
         in_specs=[row3, kblk3, kblk3, row3, row2, row2],
         out_specs=[row3, kblk3, kblk3],
         out_shape=[
-            # over several key blocks dq is the revisited f32 accumulator
-            # (cast to q.dtype below); one block writes it finished
-            jax.ShapeDtypeStruct(
-                (BH, S, D), q.dtype if nested and num_q == 1 else jnp.float32
-            ),
+            jax.ShapeDtypeStruct((BH, S, D), dq_dtype),
             jax.ShapeDtypeStruct((BH, S, D), k.dtype),
             jax.ShapeDtypeStruct((BH, S, D), v.dtype),
         ],
         interpret=interpret,
         name="flash_bwd",
+        **_resident_params(
+            ((S, D), q.dtype), ((S, D), do.dtype), ((S, D), dq_dtype),
+            ((S,), jnp.float32), ((S,), jnp.float32),
+            *[((key_rows, D), k.dtype)] * 4,
+        ),
     )(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk, dv
 
