@@ -338,10 +338,13 @@ def _observe_link() -> Dict[str, float]:
 # GPT-2 (1024 positions, 12 heads of 64: the whole sequence resident, the
 # static causal schedule) and OLMoE (4096 positions, 16 heads of 128:
 # longer than one resident block, (512, 512) tiles) and Mellum2 (8192
-# positions of head size 128, a window of 1024 and none: the general loop
-# and the causal schedule with 2 MiB key/value rows resident, the
-# backward's rows past the default VMEM limit); then the kernel's other
-# two code paths at a reduced batch: the general (windowed) loop, and a
+# positions of head size 128, a window of 1024 and none: the causal
+# schedule cut to the window's band, (1024, 1024) blocks and the two
+# staircases, and the same schedule whole, both with 2 MiB key/value rows
+# resident, the backward's rows past the default VMEM limit); then, at a
+# reduced batch, a window of one 512 sub-tile over 2047 positions at head
+# size 64 (the band again, ending in a padded block), a window that no
+# tile divides (the general kernels: one masked tile a loop trip), and a
 # ragged short sequence on the 128-wide tiles.
 FLASH_CASES = (
     ("big", BATCH, SEQ - 1, 16, 64, None),
@@ -351,6 +354,7 @@ FLASH_CASES = (
     ("mellum_sliding", 1, 8192, 8, 128, 1024),
     ("mellum_full", 1, 8192, 8, 128, None),
     ("windowed", 2, SEQ - 1, 4, 64, 512),
+    ("general", 2, SEQ - 1, 4, 64, 500),
     ("ragged", 2, 99, 4, 64, None),
 )
 

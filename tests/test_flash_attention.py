@@ -292,24 +292,175 @@ def test_auto_tiles(S, head_dim, interpret, want):
     assert fa._auto_tiles(S, head_dim, interpret, nested=False) == general
 
 
-def test_window_and_noncausal_take_the_general_kernels(monkeypatch):
+# (S, window) -> tiles of a causal call with a window: the largest
+# sub-tile of 1024 / 512 / 256 / 128 that divides the window, one a
+# resident block (PERF.md section 6, PR 37); a window that none divides,
+# or as long as the sequence, keeps the general path's tiles.
+@pytest.mark.parametrize("interpret", [False, True])
+@pytest.mark.parametrize(
+    "S,window,want",
+    [
+        (8192, 1024, (1024, 1024)), (2047, 512, (512, 512)),
+        (1024, 256, (256, 256)), (3000, 1024, (1024, 1024)),
+        (4096, 384, (128, 128)), (8192, 4096, (1024, 1024)),
+        (8192, 1000, (512, 512)), (8192, 8192, (512, 512)),
+        (1024, 1024, (128, 128)), (1023, 2048, (128, 128)),
+    ],
+)
+def test_auto_tiles_of_a_window(S, window, interpret, want):
     fa = _module()
+    assert fa._auto_tiles(S, 128, interpret, nested=False, window=window) == want
+    block_q, block_k, s_pad, edges = fa._tiles(
+        S, 128, interpret, None, None, None, True, window
+    )
+    assert (block_q, block_k) == want and s_pad % block_q == 0
+    banded = fa._banded(True, window, block_q, block_k, s_pad)
+    assert banded == (window % block_k == 0 and window < S)
+    assert (edges is not None) == banded
 
-    def refuse(*_args, **_kw):
-        raise AssertionError("the causal schedule ran")
 
-    monkeypatch.setattr(fa, "_fwd_causal_kernel", refuse)
-    monkeypatch.setattr(fa, "_bwd_causal_kernel", refuse)
-    q, k, v = rand_qkv(jax.random.PRNGKey(11), (1, 64, 1, 8))
+# What a call runs falls out of its shapes (S 64 here): the two-level
+# schedule whole, the same cut to a window's band, or the general kernels.
+@pytest.mark.parametrize(
+    "kw,want",
+    [
+        ({}, "causal"),
+        ({"window": 32, "block_q": 32, "block_k": 16}, "band"),
+        ({"window": 16, "block_q": 16, "block_k": 16}, "band"),
+        ({"window": 32, "block_q": 64, "block_k": 16}, "band"),  # one block
+        ({"window": 16}, "general"),  # (64, 64) tiles: no multiple of them
+        ({"window": 24, "block_q": 32, "block_k": 16}, "general"),
+        ({"window": 64, "block_q": 32, "block_k": 32}, "general"),  # the whole sequence
+        ({"window": 32, "block_q": 16, "block_k": 32}, "general"),  # do not nest
+        ({"causal": False}, "general"),
+        ({"block_q": 16, "block_k": 32}, "general"),  # do not nest
+    ],
+    ids=lambda v: ("-".join(f"{k}{n}" for k, n in v.items()) or "plain")
+    if isinstance(v, dict) else v,
+)
+def test_which_kernels_a_call_takes(kw, want, monkeypatch):
+    fa = _module()
+    took = set()
+    for name, kind in (
+        ("_fwd_causal_kernel", "causal"), ("_bwd_causal_kernel", "causal"),
+        ("_fwd_kernel", "general"), ("_bwd_kernel", "general"),
+    ):
+        def spy(*a, _real=getattr(fa, name), _kind=kind, **kernel_kw):
+            banded = _kind == "causal" and kernel_kw.get("window") is not None
+            took.add((_real.__name__, "band" if banded else _kind))
+            return _real(*a, **kernel_kw)
 
-    def loss(q, **kw):
-        return jnp.sum(flash_attention(q, k, v, **kw) ** 2)
+        monkeypatch.setattr(fa, name, spy)
+    # a head size no other test has: the calls that build a kernel are
+    # traced once a shape
+    q, k, v = rand_qkv(jax.random.PRNGKey(11), (1, 64, 1, 24))
+    jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, **kw) ** 2))(q)
+    prefix = "_" if want == "general" else "_causal_"
+    assert took == {(f"_fwd{prefix}kernel", want), (f"_bwd{prefix}kernel", want)}
+    assert fa._banded(
+        kw.get("causal", True), kw.get("window"), kw.get("block_q", 64),
+        kw.get("block_k", 64), 64,
+    ) == (want == "band")
 
-    jax.grad(lambda q: loss(q, window=16))(q)
-    jax.grad(lambda q: loss(q, causal=False))(q)
-    jax.grad(lambda q: loss(q, block_q=16, block_k=32))(q)  # do not nest
-    with pytest.raises(AssertionError, match="causal schedule"):
-        loss(q)
+
+# ---------------------------------------------------------------------------
+# a window on the two-level schedule: the band (PR 37)
+# ---------------------------------------------------------------------------
+
+
+# (S, window, blocks, edge). Row group g of ``block_k`` rows meets its
+# diagonal staircase, min(g, window / block_k - 1) unmasked sub-tiles and,
+# from group window / block_k on, the window's edge as the mirrored
+# staircase: 8 groups at (256, 64, (32, 32)) are the first two, short of
+# pieces, and six interior ones; an edge of ``block_k`` is the sub-tiles
+# whole; window 32 = ``block_k`` has no unmasked tile, 96 has two; (64, 32)
+# is two row groups a resident block; 250 and 100 end inside a block
+# (padded); (128, 32) at S 128 is ONE resident block, every place static.
+BANDED = [
+    (256, 64, (32, 32), 8), (256, 64, (32, 32), 32), (256, 32, (32, 32), 16),
+    (250, 64, (64, 32), 16), (192, 96, (64, 32), 32), (128, 64, (128, 32), 16),
+    (100, 32, (32, 32), 16), (256, 128, (64, 64), 8),
+]
+_banded_id = lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v)
+
+
+def _band_spy(monkeypatch):
+    """The (window, edge) the two-level kernels are built with."""
+    fa = _module()
+    built = []
+    for name in ("_fwd_causal_kernel", "_bwd_causal_kernel"):
+        def spy(*a, _real=getattr(fa, name), **kw):
+            built.append((kw.get("window"), kw["edge"]))
+            return _real(*a, **kw)
+
+        monkeypatch.setattr(fa, name, spy)
+    return built
+
+
+@pytest.mark.parametrize("S,window,blocks,edge", BANDED, ids=_banded_id)
+def test_band_forward_matches_dense(S, window, blocks, edge, monkeypatch):
+    built = _band_spy(monkeypatch)
+    q, k, v = rand_qkv(jax.random.PRNGKey(S + window), (2, S, 2, 40))
+    out = flash_attention(
+        q, k, v, window=window, block_q=blocks[0], block_k=blocks[1], block_diag=edge
+    )
+    assert built == [(window, edge)]
+    np.testing.assert_allclose(out, dense_windowed(q, k, v, window), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("S,window,blocks,edge", BANDED, ids=_banded_id)
+def test_band_grads_match_dense(S, window, blocks, edge, monkeypatch):
+    built = _band_spy(monkeypatch)
+    q, k, v = rand_qkv(jax.random.PRNGKey(S + window + 1), (1, S, 2, 24))
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v) ** 2)
+
+    flash = functools.partial(
+        flash_attention, window=window, block_q=blocks[0], block_k=blocks[1],
+        block_diag=edge,
+    )
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(
+        loss(functools.partial(dense_windowed, window=window)), argnums=(0, 1, 2)
+    )(q, k, v)
+    assert built == [(window, edge)] * 2
+    for a, b, name in zip(gf, gd, "qkv"):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
+
+
+# window 64 in (32, 32) sub-tiles, chunks of 8: the key ``window`` back from
+# query row 64 is key 0 (the first row group that has a window's edge), from
+# 96 a sub-tile's first, from 104 a chunk's first, from 100 and 131 inside a
+# chunk, from 127 a sub-tile's last, from 71 a chunk's last; rows 40 and 63
+# still see key 0; 255 is the last row.
+@pytest.mark.parametrize("row", [40, 63, 64, 71, 96, 100, 104, 127, 131, 255])
+def test_band_is_exact_at_its_edges(row):
+    """Query ``row`` sees keys (row - window, row] and no other: a loss on
+    that row alone leaves a cotangent on exactly those keys and values -
+    key ``row - window`` exactly 0, key ``row - window + 1`` not (a mask one
+    off, or a chunk of the window's edge that met a row it should not,
+    shows here; the benchmark's ``correct`` does not see it: PERF.md
+    section 6) - and the row's output does not change with the others."""
+    S, window = 256, 64
+    attend = functools.partial(
+        flash_attention, window=window, block_q=32, block_k=32, block_diag=8
+    )
+    q, k, v = rand_qkv(jax.random.PRNGKey(row), (1, S, 2, 16))
+    one_row = lambda k, v: attend(q, k, v)[:, row]
+    out, pullback = jax.vjp(one_row, k, v)
+    dk, dv = pullback(jnp.ones_like(out))
+    keys = np.arange(S)
+    seen = (keys <= row) & (row - keys < window)
+    for name, grad in (("dk", dk), ("dv", dv)):
+        moved = np.any(np.asarray(grad) != 0, axis=-1)  # (1, S, heads)
+        np.testing.assert_array_equal(
+            moved, np.broadcast_to(seen[None, :, None], moved.shape), err_msg=name
+        )
+    hidden = jnp.asarray(~seen)[None, :, None, None]
+    np.testing.assert_array_equal(
+        one_row(jnp.where(hidden, 7.0, k), jnp.where(hidden, -7.0, v)), out
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -547,21 +698,34 @@ def test_staircase_edge_divides_the_sub_tile():
     flash_attention(q, k, v, causal=False, block_q=64, block_k=64, block_diag=24)
 
 
-# the benchmark's two shapes: GPT-2's (1024, 512) and OLMoE's (512, 512) at
-# S 4096, at the whole sub-tile (the kernels until PR 35) and both edges
+# the benchmark's shapes: GPT-2's (1024, 512), OLMoE's (512, 512) at S 4096
+# and Mellum2's window of 1024 at S 8192, at the whole sub-tile (the kernels
+# until PR 35; with a window the area of the general kernels' 45 tiles) and
+# both edges
 @pytest.mark.parametrize(
-    "S,block_q,block_k,edge,want",
+    "S,block_q,block_k,edge,window,want",
     [
-        (1024, 1024, 512, 512, 786_432), (1024, 1024, 512, 256, 655_360),
-        (1024, 1024, 512, 128, 589_824), (4096, 512, 512, 512, 9_437_184),
-        (4096, 512, 512, 256, 8_912_896), (4096, 512, 512, 128, 8_650_752),
-        (1024, 1024, 128, 128, 589_824), (1024, 128, 128, 128, 589_824),
+        (1024, 1024, 512, 512, None, 786_432), (1024, 1024, 512, 256, None, 655_360),
+        (1024, 1024, 512, 128, None, 589_824), (4096, 512, 512, 512, None, 9_437_184),
+        (4096, 512, 512, 256, None, 8_912_896), (4096, 512, 512, 128, None, 8_650_752),
+        (1024, 1024, 128, 128, None, 589_824), (1024, 128, 128, 128, None, 589_824),
+        (8192, 512, 512, 512, 1024, 11_796_480), (8192, 512, 512, 256, 1024, 9_830_400),
+        (8192, 512, 512, 128, 1024, 8_847_360), (8192, 1024, 512, 128, 1024, 8_847_360),
+        (2048, 512, 512, 128, 512, 1_146_880), (1024, 128, 128, 128, 256, 344_064),
     ],
 )
-def test_scores_computed(S, block_q, block_k, edge, want):
+def test_scores_computed(S, block_q, block_k, edge, window, want):
     """The engagement figure: the area a head computes, from the schedule
-    alone. It never falls under the causal half, which an edge of 1 would
-    reach."""
+    alone. It never falls under what the mask leaves - the causal half,
+    or the band ``q - k < window`` of it, 7,864,832 pairs at Mellum2's
+    shape - which an edge of 1 would reach, but for the pairs ON the
+    window's edge: masked, in blocks that are met."""
     fa = _module()
-    assert fa._scores_computed(S, block_q, block_k, edge) == want
-    assert fa._scores_computed(S, block_q, block_k, 1) == S * (S + 1) // 2 <= want
+    assert fa._scores_computed(S, block_q, block_k, edge, window) == want
+    finest = fa._scores_computed(S, block_q, block_k, 1, window)
+    if window is None:
+        assert finest == S * (S + 1) // 2 <= want
+    else:
+        band = sum(min(q + 1, window) for q in range(S))
+        assert band + (S - window) == finest <= want
+        assert (S, window) != (8192, 1024) or band == 7_864_832

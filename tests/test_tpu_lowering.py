@@ -120,6 +120,28 @@ def test_staircase_body_is_bounded_against_the_whole_sub_tile():
     assert len(whole) < len(stairs) <= 1.5 * len(whole), (len(whole), len(stairs))
 
 
+@pytest.mark.parametrize("seq,window", [(8192, 1024), (8192, 2048), (2047, 512)])
+def test_band_body_is_bounded_against_the_whole_schedule(seq, window):
+    """A window's band is the two-level schedule with static pieces in
+    place of the dynamic loop (PR 37): Mellum2's sliding layer (a row
+    group of 1024 a resident block, its two staircases) lowers to 39 KB
+    where its full layer (one row group of 512 and the loop) is 25 KB.
+    Tracing, lowering and loading follow that size in every run's set-up,
+    warm or cold, once a layer; this keeps the band from growing unseen -
+    by a window of more sub-tiles, or a body unrolled over the grid."""
+    q = jnp.ones((1, seq, 2, 128), jnp.bfloat16)
+
+    def grad_text(**kw):
+        def loss(q, k, v):
+            return flash_attention(q, k, v, interpret=False, **kw).astype(jnp.float32).sum()
+
+        return _lowered_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+    whole, band = grad_text(), grad_text(window=window)
+    assert whole.count("tpu_custom_call") == band.count("tpu_custom_call") == 2
+    assert len(band) <= 2 * len(whole), (len(whole), len(band))
+
+
 # the benchmark's three attention shapes: gpt2-small and gpt2-medium through
 # the fused entry, OLMoE (eight resident blocks a side) through the other
 @pytest.mark.parametrize(

@@ -46,10 +46,10 @@ calls, and whatever the fused layout cannot express (an odd count of
 64-wide heads, other head sizes, more than one resident block), which
 ``flash_attention_qkv`` sends there itself, from shapes.
 
-The causal path (no window, ``block_q`` a multiple of ``block_k``) runs a
-TWO-LEVEL schedule. A grid step holds a resident block of ``block_q``
-rows - the whole sequence up to 2048 positions - cut into row groups of
-``block_k``. Inside the block every trip count is static: a row group
+The causal path (``block_q`` a multiple of ``block_k``; with a window,
+below) runs a TWO-LEVEL schedule. A grid step holds a resident block of
+``block_q`` rows - the whole sequence up to 2048 positions - cut into
+row groups of ``block_k``. Inside the block every trip count is static: a row group
 meets all keys left of its diagonal in ONE wide unmasked tile and then
 its diagonal sub-tile, so the online-softmax recurrence runs at most
 twice a row and the compiler sees straight-line code. Blocks to the left
@@ -78,18 +78,41 @@ the MXU, takes 128 and 24.8 -> 19.5 ms a gpt2-small step; the
 forward, whose time is its longest piece's and not its area's, stays
 whole at head size 64 (PERF.md section 6, PR 35).
 
+A causal call WITH A WINDOW runs the same schedule cut to the band
+``q_pos - k_pos < window`` (``_banded``, from shapes alone: the blocks
+nest, the window is whole sub-tiles and shorter than the padded
+sequence). A row group then meets three kinds of piece and no loop:
+its diagonal sub-tile FIRST, the staircase above; the ``window /
+block_k - 1`` key blocks before it, wholly inside the band, unmasked; and
+the sub-tile ``window`` keys back, visible strictly above its own
+diagonal, as the MIRRORED staircase (key chunk j meets the rows up to
+(j + 1) * e, the e x e block on the window's edge alone masked). One
+body a kernel, its key origins from ``program_id``; the first row groups
+of the sequence (the last key blocks, in the backward) lack the pieces
+that would start before key 0 (past the last query) and skip them
+(``_when``). At S 8192, window 1024 a head computes 8,847,360 scores at
+``e`` 128 and 9,830,400 at 256 - on sub-tiles of 512 as on the 1024
+that ``_auto_tiles`` chooses, where the band is the two staircases alone
+- for a band of 7,864,832, where the general kernels' 45 masked tiles
+of 512 x 512 are 11,796,480 (``_scores_computed``): Mellum2's three
+sliding layers, 5.25 + 7.30 ms a layer in the general kernels and
+3.53 + 4.88 on the band (PERF.md section 6, PR 37). A window that is no
+multiple of ``block_k`` or as long as the sequence, blocks that do not
+nest and non-causal calls keep the general kernels below.
+
 The causal path also uses a finite -1e30 mask value instead of -inf,
 which removes every ``isfinite`` guard from the online-softmax
 recurrence: with at least one live key per query row in its first tile
-(every row attends key 0; padded query rows attend earlier live keys),
+(every row attends key 0, or itself where a window puts the diagonal
+first; padded query rows attend earlier live keys),
 ``exp(-1e30 - m)`` underflows to exactly 0 and the recurrence needs no
 special cases. It applies NO padding mask at all: causality already
 hides a padded key from every live query; in the backward padded k/v
 rows are zeros, so padded-column score/probability garbage contributes
 exactly 0 to dq (``ds @ k`` hits zero rows) and only to dk/dv rows that
 are sliced off; padded query rows carry zero cotangents. The general
-path (sliding window, blocks that do not nest, non-causal) keeps one
-tile a loop step and per-tile masks.
+path (a window the band cannot express, blocks that do not nest,
+non-causal) keeps one tile a loop step and per-tile masks.
 
 Design notes (pallas_guide.md):
 - all matmuls request ``preferred_element_type=float32`` so the MXU
@@ -198,7 +221,7 @@ _QKV_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 2**20)
 _VMEM_DEFAULT = 16 * 2**20
 
 
-def _resident_params(*blocks) -> dict:
+def _resident_params(*blocks, wide_body: bool = False) -> dict:
     """``compiler_params`` of a three-array call, from the blocks
     (``(shape, dtype)`` each) its specs keep in VMEM, each with its second
     buffer. Up to 8,192 / head_dim x 128 rows a resident row - every shape
@@ -207,11 +230,15 @@ def _resident_params(*blocks) -> dict:
     Past that the limit is the buffers plus the default for the body: at
     8,192 positions of head size 128 the backward's resident q, dO and f32
     dq rows are 16.1 MiB buffered and the compiler refused the call by
-    1 MiB (compiled for a described v5e, PR 36)."""
+    1 MiB (compiled for a described v5e, PR 36). ``wide_body``: a body
+    that a quarter of the default does not hold, so the limit is always
+    named - a window's band on sub-tiles of 1024, whose forward with two
+    row groups a block the chip's compiler refused by 180 KB inside the
+    step where the same call alone had compiled (PR 37)."""
     buffers = 2 * sum(
         math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in blocks
     )
-    if buffers <= 3 * _VMEM_DEFAULT // 4:
+    if buffers <= 3 * _VMEM_DEFAULT // 4 and not wide_body:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=buffers + _VMEM_DEFAULT
@@ -240,6 +267,27 @@ def _nested(causal: bool, window, block_q: int, block_k: int) -> bool:
     docstring): a resident block of ``block_q`` rows cut into square
     sub-tiles of edge ``block_k``."""
     return causal and window is None and block_q % block_k == 0
+
+
+def _banded(causal: bool, window, block_q: int, block_k: int, s_pad: int) -> bool:
+    """True when a causal call WITH a window runs the two-level schedule
+    cut to the band (module docstring): the blocks nest, the window is
+    whole sub-tiles and shorter than the padded sequence. From shapes
+    alone; every other windowed call keeps the general kernels."""
+    return (
+        causal and window is not None and block_q % block_k == 0
+        and window % block_k == 0 and window < s_pad
+    )
+
+
+def _when(live, piece, state):
+    """``piece(state)`` where ``live`` holds, else ``state`` as it is:
+    how the banded schedule leaves out the pieces that the first query
+    blocks (forward) and the last key blocks (backward) do not have.
+    ``live`` is a Python bool where the block's place is static."""
+    if isinstance(live, bool):
+        return piece(state) if live else state
+    return jax.lax.cond(live, piece, lambda state: state, state)
 
 
 def _triangle(n: int, queries_first: bool):
@@ -293,6 +341,7 @@ def _fwd_causal_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     block_q: int, block_k: int, num_blocks: int, edge: int,
     heads: int = 1, sm_scale: Optional[float] = None,
+    window: Optional[int] = None,
 ):
     """Two-level causal forward. Grid step (bh, qi) holds ``block_q``
     query rows as ``block_q // block_k`` row groups, each a straight line
@@ -315,11 +364,26 @@ def _fwd_causal_kernel(
     (``heads`` 1, ``sm_scale`` None). The fused-projection entry hands
     over a 128-lane block of the projection, ``heads`` heads side by side
     (two at head size 64), and the scale: each head runs the same
-    schedule on its own lanes, q scaled once a resident row group."""
+    schedule on its own lanes, q scaled once a resident row group.
+
+    With a ``window`` (``_banded``: n_win whole sub-tiles of ``block_k``)
+    the schedule is cut to the band. Row group g of the sequence meets,
+    in this order: its DIAGONAL sub-tile first, the staircase as above
+    (every row meets itself there, so every row has a live key in its
+    first piece and the finite mask value needs no guard; the window's
+    edge first would not do: its last row sees none of it); the
+    ``n_win - 1`` key blocks before it, wholly inside the band, unmasked;
+    and the sub-tile ``window`` keys back, visible strictly above ITS
+    diagonal, as the mirrored staircase: key chunk j meets the group's
+    rows [0, (j + 1) * edge) and only its last ``edge`` x ``edge``
+    block, ON the window's edge, is masked. No dynamic loop: one body,
+    its key origins from ``program_id``; the first ``n_win`` row groups
+    of the sequence have fewer pieces (``_when``)."""
     n_sub = block_q // block_k
     D = q_ref.shape[-1] // heads
     # a static origin when there is one block: every slice is static
-    q0 = 0 if num_blocks == 1 else pl.program_id(1) * block_q
+    block = 0 if num_blocks == 1 else pl.program_id(1)
+    q0 = block * block_q
     tri = _triangle(edge, True)
 
     def one_head(h: int, lanes):
@@ -328,40 +392,59 @@ def _fwd_causal_kernel(
             for r in range(n_sub)
         ]
 
-        def tile(q_blk, state, k_start, width: int, diagonal: bool = False):
+        def tile(q_blk, state, k_start, width: int, stair: Optional[str] = None):
             """One step of the recurrence for a row group, whose state is
             its strips' (m, l, acc): over ``width`` keys in one unmasked
-            piece, or over the group's own keys (``diagonal``) in the
-            staircase's pieces. A strip's statistics stay (edge, 1)
-            values as the reductions leave them, the same in every lane:
-            stacked or sliced they would have to be spread over the lanes
-            again for every block they meet."""
-            chunk = edge if diagonal else width
+            piece, or over a sub-tile of ``block_k`` keys in a
+            staircase's pieces - the group's own keys (``diagonal``) or
+            those ``window`` back (``edge``). A strip's statistics stay
+            (edge, 1) values as the reductions leave them, the same in
+            every lane: stacked or sliced they would have to be spread
+            over the lanes again for every block they meet."""
+            chunk = edge if stair else width
+            pieces = width // chunk
+
+            def met(j: int) -> Tuple[int, int]:
+                """The group's rows that piece j meets: from the chunk's
+                first query on, up to the last query that sees it, or
+                all."""
+                if stair == "diagonal":
+                    return j * edge, block_k
+                return 0, ((j + 1) * edge if stair == "edge" else block_k)
+
+            def reach(i: int) -> range:
+                """The pieces that meet strip i, the masked one last on
+                the diagonal and first on the window's edge."""
+                if stair == "diagonal":
+                    return range(i + 1)
+                return range(i if stair == "edge" else 0, pieces)
+
             v_chunks, s = [], []
-            for j in range(width // chunk):
+            for j in range(pieces):
                 keys = pl.ds(k_start + j * chunk, chunk)
                 v_chunks.append(v_ref[0, keys, lanes])
-                # the rows from the chunk's first query on, (rows, chunk) f32
-                first = j * edge if diagonal else 0
-                s.append(_dot_nt(_rows(q_blk, first, block_k), k_ref[0, keys, lanes]))
+                # (rows met, chunk) f32
+                s.append(_dot_nt(_rows(q_blk, *met(j)), k_ref[0, keys, lanes]))
 
-            def blocks(pieces, i: int):
+            def blocks(vals, i: int):
                 """Strip i's (edge, chunk) block of every piece that
-                reaches it, the one ON the diagonal last."""
+                reaches it."""
                 out = []
-                for piece in pieces[:i + 1] if diagonal else pieces:
-                    lo = i * edge - (block_k - piece.shape[0])
-                    out.append(_rows(piece, lo, lo + edge))
+                for j in reach(i):
+                    lo = i * edge - met(j)[0]
+                    out.append(_rows(vals[j], lo, lo + edge))
                 return out
 
             new_state, p = [], []
             for i, (m, l, acc) in enumerate(state):
                 s_i = blocks(s, i)
-                if diagonal:
+                if stair == "diagonal":
                     s_i[-1] = jnp.where(tri, s_i[-1], _NEG_LARGE)
-                # every row has a live key in its first tile (key 0), so m
-                # is finite from then on and exp(_NEG_LARGE - m) is exactly
-                # 0: no -inf guards
+                elif stair == "edge":  # visible strictly above: key > query
+                    s_i[0] = jnp.where(tri, _NEG_LARGE, s_i[0])
+                # every row has a live key in its first tile (key 0, or
+                # itself in the band), so m is finite from then on and
+                # exp(_NEG_LARGE - m) is exactly 0: no -inf guards
                 m_new = jnp.maximum(
                     m, functools.reduce(jnp.maximum, s_i).max(axis=-1, keepdims=True)
                 )
@@ -377,7 +460,11 @@ def _fwd_causal_kernel(
             # as the piece
             pv = [
                 _dot_f32(
-                    _stack([p_i[j] for p_i in p if j < len(p_i)]).astype(v_j.dtype), v_j
+                    _stack([
+                        p_i[reach(i).index(j)]
+                        for i, p_i in enumerate(p) if j in reach(i)
+                    ]).astype(v_j.dtype),
+                    v_j,
                 )
                 for j, v_j in enumerate(v_chunks)
             ]
@@ -392,7 +479,7 @@ def _fwd_causal_kernel(
             jnp.zeros((edge, 1), jnp.float32),
             jnp.zeros((edge, D), jnp.float32),
         ),) * strips] * n_sub
-        if num_blocks > 1:
+        if window is None and num_blocks > 1:
             def interior(j, state):
                 return tuple(
                     tile(q_rows[r], state[r], j * block_q, block_q)
@@ -403,10 +490,23 @@ def _fwd_causal_kernel(
                 0, pl.program_id(1), interior, tuple(state)
             ))
         for r in range(n_sub):
-            st = state[r]
-            if r:
-                st = tile(q_rows[r], st, q0, r * block_k)
-            st = tile(q_rows[r], st, q0 + r * block_k, block_k, True)
+            st, q_blk = state[r], q_rows[r]
+            if window is None:
+                if r:
+                    st = tile(q_blk, st, q0, r * block_k)
+                st = tile(q_blk, st, q0 + r * block_k, block_k, "diagonal")
+            else:
+                group = block * n_sub + r  # of the sequence's row groups
+                st = tile(q_blk, st, group * block_k, block_k, "diagonal")
+                for back in range(1, window // block_k + 1):
+                    st = _when(
+                        r >= back or group >= back,  # static where r tells
+                        lambda st, back=back: tile(
+                            q_blk, st, (group - back) * block_k, block_k,
+                            "edge" if back * block_k == window else None,
+                        ),
+                        st,
+                    )
             for i, (m, l, acc) in enumerate(st):
                 rows = r * block_k + i * edge
                 o_ref[0, pl.ds(rows, edge), lanes] = (acc / l).astype(o_ref.dtype)
@@ -497,10 +597,11 @@ def _flash_fwd_call(
     out of every softmax."""
     BH, S, D = q.shape
     num_q, num_k = _cdiv(S, block_q), _cdiv(S, block_k)
-    if _nested(causal, window, block_q, block_k):
+    banded = _banded(causal, window, block_q, block_k, S)
+    if banded or _nested(causal, window, block_q, block_k):
         kernel = functools.partial(
             _fwd_causal_kernel, block_q=block_q, block_k=block_k,
-            num_blocks=num_q, edge=edge,
+            num_blocks=num_q, edge=edge, window=window,
         )
     else:
         kernel = functools.partial(
@@ -526,7 +627,7 @@ def _flash_fwd_call(
         name="flash_fwd",  # the kernel's name in a device trace
         **_resident_params(
             ((block_q, D), q.dtype), ((S, D), k.dtype), ((S, D), v.dtype),
-            ((block_q, D), q.dtype), ((S,), jnp.float32),
+            ((block_q, D), q.dtype), ((S,), jnp.float32), wide_body=banded,
         ),
     )(q, k, v)
 
@@ -594,6 +695,7 @@ def _bwd_causal_kernel(
     dq_ref, dk_ref, dv_ref, *,
     block_q: int, block_k: int, num_blocks: int, edge: int,
     heads: int = 1, sm_scale: Optional[float] = None,
+    window: Optional[int] = None,
 ):
     """Two-level causal backward, the forward's schedule with the roles
     swapped: grid step (bh, ki) holds ``block_q`` KEY rows as sub-blocks
@@ -619,27 +721,50 @@ def _bwd_causal_kernel(
     ``heads`` and ``sm_scale`` as in ``_fwd_causal_kernel``; with a scale
     (the fused-projection entry: one resident block) q is scaled once,
     whole, and dq once as it is written - what autodiff of the outside
-    fold does to the three-array entry's dq."""
+    fold does to the three-array entry's dq.
+
+    With a ``window`` (``_banded``) key sub-block g of the sequence meets
+    query block g under the staircase, the ``window / block_k - 1`` query
+    blocks after it unmasked, and the block ``window`` further on under
+    the MIRRORED staircase (visible where key > query, counted from the
+    sub-tile's corner): query chunk i meets the key rows from i * edge
+    on, the first ``edge`` of them, ON the window's edge, masked. No
+    recurrence, so no order to keep, and no dynamic loop; the last key
+    blocks of the sequence have fewer pieces (``_when``). Every piece
+    past the diagonal adds its dq into the revisited f32 row."""
     n_sub = block_q // block_k
     one_block = num_blocks == 1
     assert sm_scale is None or one_block
     D = q_ref.shape[-1] // heads
-    k0 = 0 if one_block else pl.program_id(1) * block_q
+    block = 0 if one_block else pl.program_id(1)
+    k0 = block * block_q
     tri_t = _triangle(edge, False)
+    # dq is the revisited accumulator, not written finished by one step
+    shared_dq = window is not None or not one_block
 
     def one_head(h: int, lanes):
         k_rows = [k_ref[0, pl.ds(c * block_k, block_k), lanes] for c in range(n_sub)]
         v_rows = [v_ref[0, pl.ds(c * block_k, block_k), lanes] for c in range(n_sub)]
         q_all = None if sm_scale is None else _scaled(q_ref[0, :, lanes], sm_scale)
 
-        def tile(c: int, q_start, width: int, chunk: Optional[int] = None):
+        def tile(
+            c: int, q_start, width: int, chunk: Optional[int] = None,
+            mirrored: bool = False,
+        ):
             """(dk, dv) of key sub-block c from its meeting with the
             queries [q_start, q_start + width), and that meeting's ds
             transposed, (keys, queries), for their dq. All of the
             sub-block's keys, unmasked; or, for query chunk ``chunk`` of
-            the staircase (``edge`` queries), its first rows up to the
-            chunk's own positions, those under the mask."""
-            keys = block_k if chunk is None else (chunk + 1) * edge
+            a staircase (``edge`` queries), the key rows it can see: up
+            to the chunk's own positions, those under the mask, or
+            (``mirrored``, the window's edge) from them on."""
+            if chunk is None:
+                lo, hi = 0, block_k
+            elif mirrored:
+                lo, hi = chunk * edge, block_k
+            else:
+                lo, hi = 0, (chunk + 1) * edge
+            keys = hi - lo
             if q_all is None:
                 q_blk = q_ref[0, pl.ds(q_start, width), lanes]
             else:  # one block: q_start is static
@@ -647,10 +772,15 @@ def _bwd_causal_kernel(
             do_blk = do_ref[0, pl.ds(q_start, width), lanes]
             lse = lse_ref[h, :, pl.ds(q_start, width)]  # (1, width)
             delta = delta_ref[h, :, pl.ds(q_start, width)]
-            k_blk, v_blk = _rows(k_rows[c], 0, keys), _rows(v_rows[c], 0, keys)
+            k_blk, v_blk = _rows(k_rows[c], lo, hi), _rows(v_rows[c], lo, hi)
             s_t = _dot_nt(k_blk, q_blk)  # (keys, queries); q scaled
             p_t = jnp.exp(s_t - lse)
-            if chunk is not None:
+            if mirrored:  # visible strictly below the corner: key > query
+                p_t = _stack([
+                    jnp.where(tri_t, 0.0, _rows(p_t, 0, edge)),
+                    *([p_t[edge:]] if keys > edge else []),
+                ])
+            elif chunk is not None:
                 p_t = _stack([
                     *([p_t[:keys - edge]] if keys > edge else []),
                     jnp.where(tri_t, _rows(p_t, keys - edge, keys), 0.0),
@@ -660,9 +790,43 @@ def _bwd_causal_kernel(
             ds_t = (p_t * (dp_t - delta)).astype(q_blk.dtype)  # one cast,
             return _dot_f32(ds_t, q_blk), dv, ds_t              # used twice
 
+        def staircase(c: int, chunk_start, dk, dv, dq, mirrored: bool = False):
+            """``dk``, ``dv`` of key sub-block c and ``dq`` of a query
+            block, whose chunk i starts at ``chunk_start(i)``, each with
+            what their meeting under a staircase adds: the diagonal
+            sub-tile's, or (``mirrored``) the window's edge's. (A
+            callable, so that the diagonal's origins lower to the
+            operations they were before the window had a staircase.)"""
+            # a query chunk at a time, each over the keys it can see
+            ds_chunks = []
+            for i in range(stairs):
+                dk_i, dv_i, ds_t = tile(c, chunk_start(i), edge, i, mirrored)
+                first = i * edge if mirrored else 0
+                dk, dv = _add_rows(dk, dk_i, first), _add_rows(dv, dv_i, first)
+                ds_chunks.append(ds_t)
+            # its dq a KEY strip at a time, the strip of k the stationary
+            # operand once for every chunk that sees it (a chunk at a time
+            # would load each strip again for every chunk below it); the
+            # strip that every chunk sees first: the whole row group's dq
+            for j in reversed(range(stairs)) if mirrored else range(stairs):
+                chunks = range(j + 1) if mirrored else range(j, stairs)
+                ds_t = []
+                for i in chunks:  # rows of strip j in chunk i's piece
+                    lo = (j - i) * edge if mirrored else j * edge
+                    ds_t.append(ds_chunks[i][lo:lo + edge])
+                dq_j = _dot_tn(
+                    ds_t[0] if len(ds_t) == 1 else jnp.concatenate(ds_t, axis=1),
+                    k_rows[c][j * edge:(j + 1) * edge],
+                )
+                if len(chunks) == stairs:
+                    dq = dq + dq_j
+                else:
+                    dq = _add_rows(dq, dq_j, 0 if mirrored else j * edge)
+            return dk, dv, dq
+
         zeros = jnp.zeros((block_k, D), jnp.float32)
         dks, dvs = [zeros] * n_sub, [zeros] * n_sub
-        if not one_block:
+        if shared_dq:
             # dq accumulates into a REVISITED full-row f32 output block: the
             # TPU grid is sequential, so every ki step of one bh row sees the
             # same resident VMEM block; zero it on the first step.
@@ -670,6 +834,7 @@ def _bwd_causal_kernel(
             def _init_dq():
                 dq_ref[...] = jnp.zeros_like(dq_ref)
 
+        if window is None and not one_block:
             def below(i, carry):
                 dks, dvs = carry
                 out_k, out_v, dq = [], [], 0.0
@@ -687,26 +852,36 @@ def _bwd_causal_kernel(
         dqs = [0.0] * n_sub  # of the block's own query row groups, f32
         stairs = block_k // edge
         for c in range(n_sub):
-            # the diagonal sub-tile, a query chunk of the staircase at a
-            # time, each over the keys it can see
-            ds_chunks = []
-            for i in range(stairs):
-                dk, dv, ds_t = tile(c, k0 + c * block_k + i * edge, edge, i)
-                dks[c], dvs[c] = _add_rows(dks[c], dk, 0), _add_rows(dvs[c], dv, 0)
-                ds_chunks.append(ds_t)
-            # its dq a KEY strip at a time, the strip of k the stationary
-            # operand once for every chunk that sees it (a chunk at a time
-            # would load each strip again for every chunk below it)
-            for j in range(stairs):
-                strip = slice(j * edge, (j + 1) * edge)
-                ds_t = [ds_chunks[i][strip] for i in range(j, stairs)]
-                dq = _dot_tn(
-                    ds_t[0] if len(ds_t) == 1 else jnp.concatenate(ds_t, axis=1),
-                    k_rows[c][strip],
-                )
-                # (strip 0 is seen by every chunk: the whole row group's dq)
-                dqs[c] = _add_rows(dqs[c], dq, j * edge) if j else dqs[c] + dq
-            if c < n_sub - 1:  # then every query of the block below it
+            # the diagonal sub-tile
+            dks[c], dvs[c], dqs[c] = staircase(
+                c, lambda i: k0 + c * block_k + i * edge, dks[c], dvs[c], dqs[c]
+            )
+            if window is not None:
+                group = block * n_sub + c  # of the sequence's key sub-blocks
+
+                def meet(acc, ahead: int):
+                    """Key sub-block c and the query block ``ahead``
+                    further on: unmasked, or the window's edge."""
+                    q_start = (group + ahead) * block_k
+                    if ahead * block_k == window:
+                        dk, dv, dq = staircase(
+                            c, lambda i: q_start + i * edge, *acc, 0.0, True
+                        )
+                    else:
+                        dk, dv, ds_t = tile(c, q_start, block_k)
+                        dk, dv = acc[0] + dk, acc[1] + dv
+                        dq = _dot_tn(ds_t, k_rows[c])
+                    dq_ref[0, pl.ds(q_start, block_k), lanes] += dq
+                    return dk, dv
+
+                for ahead in range(1, window // block_k + 1):
+                    dks[c], dvs[c] = _when(
+                        # static where the block holds those queries too
+                        c + ahead < n_sub or group + ahead < num_blocks * n_sub,
+                        functools.partial(meet, ahead=ahead),
+                        (dks[c], dvs[c]),
+                    )
+            elif c < n_sub - 1:  # then every query of the block below it
                 count = n_sub - 1 - c
                 dk, dv, ds_t = tile(c, k0 + (c + 1) * block_k, count * block_k)
                 dks[c], dvs[c] = dks[c] + dk, dvs[c] + dv
@@ -717,11 +892,11 @@ def _bwd_causal_kernel(
                     )
         for r in range(n_sub):
             rows = pl.ds(k0 + r * block_k, block_k)
-            if one_block:  # every key of the row is here: dq is final
+            if shared_dq:
+                dq_ref[0, rows, lanes] += dqs[r]
+            else:  # every key of the row is here: dq is final
                 dq = dqs[r] if sm_scale is None else dqs[r] * jnp.float32(sm_scale)
                 dq_ref[0, rows, lanes] = dq.astype(dq_ref.dtype)
-            else:
-                dq_ref[0, rows, lanes] += dqs[r]
             dk_ref[0, pl.ds(r * block_k, block_k), lanes] = dks[r].astype(dk_ref.dtype)
             dv_ref[0, pl.ds(r * block_k, block_k), lanes] = dvs[r].astype(dv_ref.dtype)
 
@@ -861,7 +1036,8 @@ def _flash_bwd_call(
     row3 = pl.BlockSpec((1, S, D), lambda bh, i: (bh, 0, 0))
     row2 = pl.BlockSpec((1, 1, S), lambda bh, i: (bh, 0, 0))
     nested = _nested(causal, window, block_q, block_k)
-    if nested:
+    banded = _banded(causal, window, block_q, block_k, S)
+    if nested or banded:
         # The causal schedule applies NO padding mask: padded k/v rows
         # are zeros, so padded-column score/probability garbage adds
         # exactly 0 to dq (``ds @ k`` hits zero rows) and only reaches
@@ -870,7 +1046,7 @@ def _flash_bwd_call(
         key_rows = block_q  # the resident block is a key block here
         kernel = functools.partial(
             _bwd_causal_kernel, block_q=block_q, block_k=block_k,
-            num_blocks=num_q, edge=edge,
+            num_blocks=num_q, edge=edge, window=window,
         )
     else:
         key_rows = block_k
@@ -899,7 +1075,7 @@ def _flash_bwd_call(
         **_resident_params(
             ((S, D), q.dtype), ((S, D), do.dtype), ((S, D), dq_dtype),
             ((S,), jnp.float32), ((S,), jnp.float32),
-            *[((key_rows, D), k.dtype)] * 4,
+            *[((key_rows, D), k.dtype)] * 4, wide_body=banded,
         ),
     )(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk, dv
@@ -1023,13 +1199,15 @@ def _pick_interpret(interpret: Optional[bool]) -> bool:
 
 
 def _auto_tiles(
-    seq: int, head_dim: int, interpret: bool, nested: bool = True
+    seq: int, head_dim: int, interpret: bool, nested: bool = True,
+    window: Optional[int] = None,
 ) -> Tuple[int, int]:
     """The (block_q, block_k) a call runs when it names none, from what
     the call can see: the sequence length, the head size, whether the
-    kernels are interpreted, and whether the two-level causal schedule
-    applies (``nested``: causal, no window). Measured on the v5e inside
-    the whole training step (PERF.md section 6, PR 25).
+    kernels are interpreted, whether the whole sequence may be the one
+    resident block (``nested``: causal, no window) and a causal call's
+    ``window``. Measured on the v5e inside the whole training step
+    (PERF.md section 6, PR 25; a window's, PR 37).
 
     Tiles key on the PADDED length, not raw S: language-model training
     slices the last token off (tokens[:, :-1]), so an in-model sequence
@@ -1050,7 +1228,23 @@ def _auto_tiles(
     ``_auto_edges`` (PR 35), which leaves the row groups 512 tall.
     Longer sequences, and the general path at 2048 and over, keep
     (512, 512), the general path below that (128, 128): not measured in
-    PR 25."""
+    PR 25.
+
+    A window shorter than the sequence: the LARGEST sub-tile of 1024,
+    512, 256 or 128 that divides it, one a resident block, so that the
+    band is as few pieces as can be (a window of one sub-tile is the two
+    staircases and no tile between them). Mellum2's layer (S 8192, D 128,
+    window 1024; ms a layer in the kernels, forward | backward, the
+    staircases' edges as ``_auto_edges`` has them): (1024, 1024)
+    3.53 | 4.88, (1024, 512) 4.23 | 5.58, (512, 512) 4.48 | 6.07,
+    (1024, 256) 6.11 | 8.30, and 5.25 | 7.30 for the general kernels at
+    (512, 512) that it replaces: a grid step costs about a 512 x 512
+    tile's time whatever it holds, and short pieces fill the MXUs worse.
+    Two row groups a block, (2048, 1024), read 3.37 | 4.55 and were NOT
+    taken: twice the body, a second more of every run's set-up on top of
+    the second that (1024, 1024) adds to the general kernels', and a
+    forward within 180 KB of the default VMEM limit. A window that none
+    of them divides keeps the general path's tiles."""
     del head_dim  # 64 and 128 rank the tiles alike (PERF.md, PR 25)
     unit = 8 if interpret else 128
     s_pad = _cdiv(seq, unit) * unit
@@ -1058,6 +1252,10 @@ def _auto_tiles(
         return s_pad, next(
             (sub for sub in (512, 256, 128) if s_pad % sub == 0), s_pad
         )
+    if window is not None and window < s_pad:
+        for sub in (1024, 512, 256, 128):
+            if window % sub == 0:
+                return sub, sub
     return (512, 512) if s_pad >= 2048 else (128, 128)
 
 
@@ -1090,7 +1288,10 @@ def _auto_edges(block_k: int, head_dim: int) -> Tuple[int, int]:
     return forward, backward
 
 
-def _scores_computed(s_pad: int, block_q: int, block_k: int, edge: int) -> int:
+def _scores_computed(
+    s_pad: int, block_q: int, block_k: int, edge: int,
+    window: Optional[int] = None,
+) -> int:
     """Scores (query, key) pairs a head computes on the two-level causal
     schedule, forward or backward (one is the other transposed): the
     engagement figure of a schedule that is static. Per resident block:
@@ -1101,18 +1302,33 @@ def _scores_computed(s_pad: int, block_q: int, block_k: int, edge: int) -> int:
     ``s_pad * (s_pad + 1) / 2``. At S 1024, (1024, 512): 786,432 with
     ``edge`` 512 (the whole sub-tile, until PR 35), 655,360 at 256,
     589,824 at 128, for a causal half of 524,800; at S 4096, (512, 512):
-    9,437,184, 8,912,896, 8,650,752 for 8,390,656."""
+    9,437,184, 8,912,896, 8,650,752 for 8,390,656.
+
+    With a ``window`` (``_banded``), per row group g of ``block_k`` rows:
+    the diagonal's staircase, ``min(g, window / block_k - 1)`` whole
+    sub-tiles, and from group ``window / block_k`` on the window's edge,
+    a staircase of the same area as the diagonal's. At S 8192, window
+    1024, (512, 512): 11,796,480 with the sub-tiles whole (the general
+    kernels' 45 tiles of 512 x 512), 9,830,400 at ``edge`` 256, 8,847,360
+    at 128, for a band of 7,864,832 (1.50, 1.25, 1.125 times). An edge
+    of 1 leaves the band and, of every window-edge sub-tile, the
+    ``block_k`` pairs ON the edge: masked, but in a block that is met."""
+    stair = block_k * (block_k + edge) // 2
+    if window is not None:
+        groups, n_win = s_pad // block_k, window // block_k
+        whole = sum(min(g, n_win - 1) for g in range(groups))
+        return (2 * groups - n_win) * stair + whole * block_k * block_k
     blocks, n_sub = s_pad // block_q, block_q // block_k
     left = block_q * block_q * (blocks * (blocks - 1) // 2)
     wide = block_k * block_k * (n_sub * (n_sub - 1) // 2)
-    stairs = n_sub * (block_k * (block_k + edge) // 2)
-    return left + blocks * (wide + stairs)
+    return left + blocks * (wide + n_sub * stair)
 
 
 def _tiles(
     seq: int, head_dim: int, interpret: bool,
     block_q: Optional[int], block_k: Optional[int],
-    block_diag: Optional[int], nested: bool,
+    block_diag: Optional[int], causal: bool = True,
+    window: Optional[int] = None,
 ) -> Tuple[int, int, int, Optional[Tuple[int, int]]]:
     """(block_q, block_k, padded length, staircase edges) of a call: the
     blocks it names, else ``_auto_tiles``, clamped to the sequence and
@@ -1120,9 +1336,13 @@ def _tiles(
     zero-padding the sequence up to the block multiple: padded keys are
     masked in-kernel (on the causal schedule only padded queries can see
     them), padded queries carry zero cotangents, so numerics are exact.
-    The edges are (forward, backward) where the blocks nest
-    (``_auto_edges``, or ``block_diag`` for both), else None."""
-    auto_q, auto_k = _auto_tiles(seq, head_dim, interpret, nested=nested)
+    The edges are (forward, backward) where the call runs the two-level
+    schedule, whole or cut to a window's band (``_nested``, ``_banded``:
+    ``_auto_edges``, or ``block_diag`` for both), else None."""
+    auto_q, auto_k = _auto_tiles(
+        seq, head_dim, interpret, nested=causal and window is None,
+        window=window if causal else None,
+    )
     unit = 8 if interpret else 128
     s8 = _cdiv(seq, unit) * unit
     block_q = min(block_q or auto_q, s8)
@@ -1131,8 +1351,11 @@ def _tiles(
         block_q = _cdiv(block_q, 128) * 128
         block_k = _cdiv(block_k, 128) * 128
     base = block_q * block_k // math.gcd(block_q, block_k)
+    s_pad = _cdiv(seq, base) * base
     edges = None
-    if nested and block_q % block_k == 0:
+    if _nested(causal, window, block_q, block_k) or _banded(
+        causal, window, block_q, block_k, s_pad
+    ):
         edges = _auto_edges(block_k, head_dim)
         if block_diag:
             edge = min(block_diag, block_k)
@@ -1143,7 +1366,7 @@ def _tiles(
                     f"block_diag {edge} does not divide block_k {block_k}"
                 )
             edges = (edge, edge)
-    return block_q, block_k, _cdiv(seq, base) * base, edges
+    return block_q, block_k, s_pad, edges
 
 
 def _qkv_lanes(n_heads: int, head_dim: int) -> Optional[int]:
@@ -1196,7 +1419,7 @@ def flash_attention_qkv(
         sm_scale = head_dim ** -0.5
     interp = _pick_interpret(interpret)
     block_q, block_k, S_pad, edges = _tiles(
-        S, head_dim, interp, block_q, block_k, block_diag, nested=True
+        S, head_dim, interp, block_q, block_k, block_diag
     )
     if (
         _qkv_lanes(n_heads, head_dim) is None
@@ -1239,10 +1462,14 @@ def flash_attention(
         causal: apply the autoregressive mask.
         window: sliding-window (local) attention — each query attends
             only the most recent ``window`` keys (q_pos - k_pos < window);
-            tiles fully outside the window are skipped by the loop
-            bounds, so computed tiles scale with S*window instead of
-            S^2/2 (wall-clock gains show once S/window is large).
-            Requires ``causal``.
+            computed tiles scale with S*window instead of S^2/2.
+            Requires ``causal``. A window of whole ``block_k`` sub-tiles,
+            shorter than the padded sequence, on blocks that nest runs
+            the two-level schedule cut to the band (static pieces, the
+            staircases on the diagonal and on the window's edge: module
+            docstring); any other keeps the general kernels, which skip
+            the tiles wholly outside the window by their loop bounds and
+            mask every tile they meet.
         sm_scale: score scale; default ``head_dim ** -0.5``. The scale
             is folded into ``q`` OUTSIDE the kernel as one f32 multiply
             rounded back to the input dtype (it removes a per-tile
@@ -1256,19 +1483,23 @@ def flash_attention(
             logits. Numerically benign for training; pass f32 q/k/v or
             a power-of-two scale when exactness matters.
         block_diag: edge of the chunks the two-level schedule cuts its
-            diagonal sub-tile into, for both kernels; a divisor of
+            diagonal sub-tile (and a window's edge) into, for both
+            kernels; a divisor of
             ``block_k``, rounded as the blocks are. Default:
             ``_auto_edges``, from ``block_k`` and the head size.
         block_q, block_k: VMEM tile sizes; clamped to S, and on real TPU
             rounded UP to 128-multiples (Mosaic's lane-aligned store
             requirement — a requested 64 runs as 128 on hardware;
             interpret mode honors small blocks exactly). A causal call
-            without a window whose ``block_q`` is a multiple of
-            ``block_k`` runs the two-level schedule (module docstring):
-            ``block_q`` rows resident, row groups and sub-tiles of
-            ``block_k``. Default: ``_auto_tiles``, from the padded
-            length - (1024, 512) at S 1024, measured on the v5e inside
-            the whole training step (PERF.md section 6, PR 25).
+            whose ``block_q`` is a multiple of ``block_k`` runs the
+            two-level schedule (module docstring): ``block_q`` rows
+            resident, row groups and sub-tiles of ``block_k`` - whole
+            without a window, cut to the band with one that
+            ``block_k`` divides. Default: ``_auto_tiles``, from the
+            padded length - (1024, 512) at S 1024, measured on the v5e
+            inside the whole training step (PERF.md section 6, PR 25);
+            with a window the largest sub-tile up to 1024 that divides
+            it (PR 37).
         interpret: force pallas interpret mode; default: on iff the backend
             is not TPU (CPU tests / virtual-device dryruns).
         mesh/batch_axis/head_axis: when ``mesh`` is given the kernel runs
@@ -1305,8 +1536,7 @@ def flash_attention(
 
     interp = _pick_interpret(interpret)
     block_q, block_k, S_pad, edges = _tiles(
-        S, D, interp, block_q, block_k, block_diag,
-        nested=causal and window is None,
+        S, D, interp, block_q, block_k, block_diag, causal, window
     )
 
     # (B, S, H, D) -> (B*H, S_pad, D). Blocks always span the full head
