@@ -743,3 +743,130 @@ def test_range_capture_pinned_defers_wire_cast():
     pinned.write_range(bp, begin, end)
     assert be.getvalue() == bp.getvalue()
     assert pinned.range_crc32c(begin, end) == eager.range_crc32c(begin, end)
+
+
+# -- the state transfer on the span primitive ------------------------------
+
+
+def _host_events(logdir, body):
+    """``body`` under a CPU capture: the ``torchft::*`` and ``test::*``
+    host events as (thread line, name, start_ns, end_ns, stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(logdir), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    return [
+        (i, e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU"
+        for i, line in enumerate(plane.lines)
+        for e in line.events
+        if e.name.startswith(("torchft::", "test::"))
+    ]
+
+
+def test_streamed_fetch_phases_nest_under_heal_fetch(tmp_path):
+    """``meta_s``, ``fetch_s`` and ``h2d_s`` are phases of the healer's
+    ``torchft::heal_fetch``, on its thread, with the owner's step; the
+    donor's staging and each range it serves are spans under
+    ``torchft::send_checkpoint``'s name on the SERVING threads, filed in
+    the donor's timers ``send_stage`` / ``send_serve`` and its counter
+    ``send_bytes``, which matches the bytes fetched."""
+    from torchft_tpu.metrics import Metrics
+
+    donor = CheckpointServer(timeout=timedelta(seconds=10))
+    healer = CheckpointServer(timeout=timedelta(seconds=10))
+    # what a Manager does with the transport it owns
+    donor.metrics, healer.metrics = Metrics(), Metrics()
+    donor.metrics.step, healer.metrics.step = 21, 0
+    try:
+        def body():
+            donor.send_checkpoint([1], step=21, state_dict=_donor_state(),
+                                  timeout=timedelta(seconds=10))
+            with healer.metrics.timed("heal_fetch"):
+                healer.recv_checkpoint(
+                    0, donor.metadata(), 21, timeout=timedelta(seconds=10)
+                )
+
+        events = _host_events(tmp_path, body)
+    finally:
+        donor.shutdown()
+        healer.shutdown()
+
+    stats = healer.last_fetch_stats
+    assert stats["path"] == "stream"
+    assert {"meta_s", "fetch_s", "h2d_s"} <= set(stats)
+    assert 0 < stats["meta_s"] < stats["fetch_s"]
+    (whole,) = [e for e in events if e[1] == "torchft::heal_fetch"]
+    by_name = {}
+    for name in ("meta", "stream", "h2d"):
+        (by_name[name],) = [
+            e for e in events if e[1] == f"torchft::heal_fetch/{name}"
+        ]
+    for e in by_name.values():
+        assert e[0] == whole[0] and whole[2] <= e[2] and e[3] <= whole[3]
+        assert e[4] == {"step": 0}
+    meta, stream, h2d = (by_name[n] for n in ("meta", "stream", "h2d"))
+    assert meta[3] <= stream[2] and stream[3] <= h2d[2]
+    ns = 1e9
+    assert stats["meta_s"] == pytest.approx((meta[3] - meta[2]) / ns, abs=5e-3)
+    # fetch_s is what it was: the layout fetch to the last byte
+    assert stats["fetch_s"] == pytest.approx((stream[3] - meta[2]) / ns, abs=5e-3)
+    assert stats["h2d_s"] == pytest.approx((h2d[3] - h2d[2]) / ns, abs=5e-3)
+
+    # the donor: one staging, one span a range, bytes that add up
+    snap = donor.metrics.snapshot()
+    assert snap["timers_s"]["send_stage"]["n"] == 1
+    assert snap["timers_s"]["send_serve"]["n"] == stats["streams"]
+    assert snap["counters"]["send_bytes"] == stats["bytes"]
+    (stage,) = [e for e in events if e[1] == "torchft::send_checkpoint/stage"]
+    serves = [e for e in events if e[1] == "torchft::send_checkpoint/serve"]
+    assert stage[4] == {"step": 21} and stage[0] != whole[0]
+    assert len(serves) == stats["streams"]
+    assert all(e[4]["step"] == 21 for e in serves)
+    assert sum(e[4]["bytes"] for e in serves) == stats["bytes"]
+    assert snap["timers_s"]["send_stage"]["total_s"] == pytest.approx(
+        (stage[3] - stage[2]) / ns, abs=5e-3
+    )
+    # the healer served nothing
+    assert "send_serve" not in healer.metrics.snapshot()["timers_s"]
+
+
+@pytest.mark.parametrize("path", ["striped", "single"])
+def test_pickled_fallbacks_time_their_fetch_as_a_phase(server, tmp_path, monkeypatch, path):
+    """Against a peer without the stream endpoint the fetch that
+    succeeds is the phase ``torchft::heal_fetch/<path>``, and ``fetch_s``
+    is its seconds."""
+    def no_stream(*a, **k):
+        raise urllib.error.HTTPError("u", 404, "no stream endpoint", {}, None)
+
+    monkeypatch.setattr(CheckpointServer, "_load_stream", no_stream)
+    server.send_checkpoint([1], step=2, state_dict={"w": np.ones(64, np.float32)},
+                           timeout=timedelta(seconds=10))
+    got = {}
+
+    def body():
+        got["out"], got["stats"] = CheckpointServer._fetch(
+            f"{server.address()}2", timeout=timedelta(seconds=10),
+            stripes=4 if path == "striped" else 1, step=5,
+        )
+
+    events = _host_events(tmp_path, body)
+    assert got["stats"]["path"] == path
+    (phase,) = [e for e in events if e[1] == f"torchft::heal_fetch/{path}"]
+    assert phase[4] == {"step": 5}
+    assert got["stats"]["fetch_s"] == pytest.approx(
+        (phase[3] - phase[2]) / 1e9, abs=5e-3
+    )
+    np.testing.assert_array_equal(got["out"]["w"], np.ones(64, np.float32))

@@ -797,11 +797,40 @@ class TestPolicySignals:
         drained = m.observe_op_stats()
         assert len(drained) == 2  # pop semantics preserved for callers
         sig = m.signals()
-        # 4 MiB over 2 s = 2 MB/s effective, 1 MB/s per connection
+        # 4 MiB over 2 s = 2 MB/s effective
         assert abs(sig["wire_eff_MBps"] - 2.0) < 1e-6
-        timers = m.metrics().snapshot()["timers_s"]
-        assert abs(timers["wire_conn_MBps"]["p50"] - 1.0) < 1e-6
+        # the signal is the sink; no rate is filed among the timers
+        assert not any(
+            "MBps" in name for name in m.metrics().snapshot()["timers_s"]
+        )
         m.shutdown()
+
+    def test_the_transport_files_in_the_managers_metrics(self, store):
+        """The Manager hands the transport it owns its ``Metrics``: the
+        donor's serving threads time into the manager's timers, stamped
+        with the manager's step."""
+        from torchft_tpu.checkpointing import CheckpointServer
+
+        transport = CheckpointServer(timeout=timedelta(seconds=5))
+        own = transport.metrics  # a bare transport has its own
+        m, _, _, _ = _create_manager(store, transport=transport)
+        try:
+            assert transport.metrics is m.metrics() and own is not m.metrics()
+            m.metrics().step = 3
+            transport.send_checkpoint(
+                [1], step=3, state_dict={"w": np.ones(16, np.float32)},
+                timeout=timedelta(seconds=5),
+            )
+            transport.recv_checkpoint(
+                0, transport.metadata(), 3, timeout=timedelta(seconds=5)
+            )
+            snap = m.metrics().snapshot()
+            assert snap["timers_s"]["send_stage"]["n"] == 1
+            assert snap["counters"]["send_bytes"] == (
+                transport.last_fetch_stats["bytes"]
+            )
+        finally:
+            m.shutdown()
 
     def test_signals_heal_none_until_healed(self, store):
         transport = MagicMock()

@@ -104,6 +104,37 @@ class TestDevicePackedStats:
         for c in cols:
             c.shutdown()
 
+    def test_allreduce_waits_are_split_by_cause(self, store):
+        """What PR 39 put in the entry: ``ready`` (the device still
+        computing), the op's own ``op_s``, ``ring_transport`` (the wire
+        without the wait for peers) and ``d2h_calls``; the phases lie
+        inside the op and the wire inside the ring."""
+        import jax.numpy as jnp
+
+        cols = _ring(store, "st_split", pipeline_chunks=4, pipeline_min_bytes=0)
+        tree = {
+            "w": jnp.ones(10007, jnp.float32),
+            "n": jnp.ones(501, jnp.int32),
+        }
+        try:
+            _run_all(cols, lambda r, c: c.allreduce(tree).wait())
+            (st,) = [
+                s for s in cols[0].pop_op_stats() if s["op"] == "allreduce"
+            ]
+        finally:
+            for c in cols:
+                c.shutdown()
+        for key in ("ready", "op_s", "ring_transport", "d2h_calls"):
+            assert key in st, key
+        phases = sum(st[k] for k in ("pack", "ready", "d2h", "ring", "h2d"))
+        assert 0 < phases <= st["op_s"]
+        assert 0 < st["ring_transport"] <= st["ring"]
+        # the buckets' own sum, kept for its readers
+        assert st["ring_transport"] == pytest.approx(
+            sum(b["stripe_wall"] for b in st["buckets"].values())
+        )
+        assert st["d2h_calls"] == st["chunks"] == 8  # one blocking read a chunk
+
     def test_chunk_pipelined_chunk_count_and_bytes(self, store):
         import jax.numpy as jnp
 

@@ -253,12 +253,15 @@ def _tree():
     return {"a": jnp.ones((64, 8), jnp.float32), "b": jnp.ones((32,), jnp.float32)}
 
 
-# op -> (the call on one member, the keys the hand-timed code recorded)
+# op -> (the call on one member, the keys the hand-timed code recorded and,
+# on a line of their own, what PR 39 put beside them; every entry also has
+# the op's own seconds, ``op_s``)
 _CONVERTED_OPS = {
     "allreduce": (
         lambda c: c.allreduce(_tree(), ReduceOp.SUM).wait(),
         {"op", "bytes", "d2h_bytes", "chunks", "pack", "d2h", "ring", "h2d",
-         "buckets"},
+         "buckets",
+         "ready", "d2h_calls", "ring_transport"},
     ),
     "allreduce_q8": (
         lambda c: c.allreduce(_tree(), ReduceOp.SUM, wire="q8").wait(),
@@ -268,7 +271,8 @@ _CONVERTED_OPS = {
     "allgather": (
         lambda c: c.allgather(_tree()).wait(),
         {"op", "bytes", "d2h_bytes", "pack", "d2h", "host_copy", "ring",
-         "h2d", "stripe_s"},
+         "h2d", "stripe_s",
+         "ready", "d2h_calls"},
     ),
     "reduce_scatter": (
         lambda c: c.reduce_scatter(_tree(), ReduceOp.SUM).wait(),
@@ -319,7 +323,7 @@ def test_op_context_phases_nest_and_keep_the_old_keys(tmp_path, op):
         for c in cols:
             c.shutdown()
         store.shutdown()
-    assert len(entries) == 1 and set(entries[0]) == keys
+    assert len(entries) == 1 and set(entries[0]) == keys | {"op_s"}
     entry = entries[0]
     # member 0's op span (the one stamped with its step) and what nests in it
     (whole,) = [
@@ -332,12 +336,91 @@ def test_op_context_phases_nest_and_keep_the_old_keys(tmp_path, op):
         and whole[2] <= e[2] and e[3] <= whole[3]
     ]
     names = {e[1].rsplit("/", 1)[1] for e in phases}
-    assert names == keys & {"pack", "d2h", "host_copy", "ring", "h2d"}
+    assert names == keys & {"pack", "ready", "d2h", "host_copy", "ring", "h2d"}
     assert all(e[4] == {"step": 11} for e in phases)
     # the phases' sum is within the op, on both clocks
     assert sum(e[3] - e[2] for e in phases) <= whole[3] - whole[2]
     recorded = sum(entry[n] for n in names)
-    assert recorded <= (whole[3] - whole[2]) / 1e9 + 1e-4
+    assert recorded <= entry["op_s"]
+    # the op's own seconds are its span's: the same statements on two clocks
+    assert entry["op_s"] == pytest.approx((whole[3] - whole[2]) / 1e9, abs=5e-3)
     assert recorded == pytest.approx(
         sum(e[3] - e[2] for e in phases) / 1e9, abs=5e-3
     )
+
+
+def test_timed_span_reads_what_its_span_covers(tmp_path):
+    from torchft_tpu.profiling import timed_span
+
+    def body():
+        with timed_span("torchft::heal_fetch/meta", 3, bytes=5) as t:
+            jnp.ones(4).block_until_ready()
+        body.seconds = t.seconds
+
+    event = _one(_captured(tmp_path, body), "torchft::heal_fetch/meta")
+    assert event[4] == {"step": 3, "bytes": 5}
+    assert body.seconds == pytest.approx((event[3] - event[2]) / 1e9, abs=2e-3)
+
+
+def test_timed_files_one_timer_under_anothers_span_name(tmp_path):
+    metrics = Metrics()
+    metrics.step = 9
+
+    def body():
+        with metrics.timed("send_serve", span="send_checkpoint/serve", bytes=64):
+            pass
+
+    events = _captured(tmp_path, body)
+    assert _one(events, "torchft::send_checkpoint/serve")[4] == {
+        "step": 9, "bytes": 64,
+    }
+    assert not [e for e in events if e[1] == "torchft::send_serve"]
+    assert metrics.snapshot()["timers_s"]["send_serve"]["n"] == 1
+
+
+def test_allreduce_ready_parts_the_devices_wait_from_the_link(tmp_path):
+    """``ready`` sits between ``pack`` and the first ``d2h``, nested in
+    the op with the step, and holds the wait for the device: a gradient
+    still being computed when the op starts is in ``ready``, not ``d2h``."""
+    store = Store()
+    cols = _ring_pair(store, "prof_ready", pipeline_chunks=1)
+    cols[0].trace_step = 4
+    slow = jax.jit(lambda x: jax.lax.fori_loop(
+        0, 300, lambda _, a: jnp.tanh(a @ a) * 0.5 + 0.1, x
+    ))
+    slow(jnp.eye(256)).block_until_ready()  # compiled outside the op
+    try:
+        def body():
+            trees = [{"a": slow(jnp.eye(256))} for _ in cols]
+            with ThreadPoolExecutor(max_workers=2) as ex:
+                for f in [
+                    ex.submit(lambda c, t: c.allreduce(t).wait(), c, t)
+                    for c, t in zip(cols, trees)
+                ]:
+                    f.result()
+
+        events = _captured(tmp_path, body)
+        (entry,) = [s for s in cols[0].pop_op_stats() if s["op"] == "allreduce"]
+    finally:
+        for c in cols:
+            c.shutdown()
+        store.shutdown()
+    (whole,) = [
+        e for e in events
+        if e[1] == "torchft::allreduce" and e[4].get("step") == 4
+    ]
+
+    def phase(name):
+        (found,) = [
+            e for e in events
+            if e[1] == f"torchft::allreduce/{name}" and e[0] == whole[0]
+            and whole[2] <= e[2] and e[3] <= whole[3]
+        ]
+        return found
+
+    pack, ready, d2h = phase("pack"), phase("ready"), phase("d2h")
+    assert ready[4] == {"step": 4}
+    assert pack[3] <= ready[2] and ready[3] <= d2h[2]
+    assert entry["ready"] == pytest.approx((ready[3] - ready[2]) / 1e9, abs=5e-3)
+    # the device's work is in ``ready``; the read after it is a copy
+    assert entry["ready"] > entry["d2h"]

@@ -90,6 +90,8 @@ from ._native import (
     crc32c_combine as _crc32c_combine,
     crc32c_update as _crc32c_update,
 )
+from .metrics import Metrics
+from .profiling import timed_span
 
 logger: logging.Logger = logging.getLogger(__name__)
 
@@ -97,7 +99,13 @@ T = TypeVar("T")
 
 
 class CheckpointTransport(Generic[T], ABC):
-    """Pluggable live-recovery transport. Reference checkpointing.py:34-88."""
+    """Pluggable live-recovery transport. Reference checkpointing.py:34-88.
+
+    ``metrics`` is the owning Manager's ``Metrics`` once a Manager owns
+    the transport (it sets the attribute): where a transport times its
+    own work, it files it there and stamps its spans with that step."""
+
+    metrics: Optional[Metrics] = None
 
     @abstractmethod
     def metadata(self) -> str:
@@ -697,10 +705,13 @@ class CheckpointServer(CheckpointTransport[T]):
         # drain them before the training loop may mutate the dict.
         self._stream_inflight = 0
         self._stream_cv = threading.Condition()
-        # What the last recv_checkpoint measured (path taken, fetch/h2d
-        # seconds, bytes, wire, streams) — benches fold this into their
-        # heal breakdowns.
+        # What the last recv_checkpoint measured (path taken, meta/fetch/
+        # h2d seconds, bytes, wire, streams) — benches fold this into
+        # their heal breakdowns.
         self.last_fetch_stats: Optional[Dict[str, Any]] = None
+        # its own until a Manager hands it the Manager's: the donor's
+        # timers ``send_stage`` / ``send_serve`` and counter ``send_bytes``
+        self.metrics = Metrics()
 
         # Gate starts held: nothing readable until the first send_checkpoint.
         self.disallow_checkpoint()
@@ -871,11 +882,16 @@ class CheckpointServer(CheckpointTransport[T]):
                         ckpt_server._stagings_step = step
                     staging = ckpt_server._stagings.get(wire)
                     if staging is None:
-                        staging = _StreamStaging(
-                            ckpt_server._state_dict,
-                            wire,
-                            seq=ckpt_server._publish_seq,
-                        )
+                        # the d2h of the whole state, once a publish, on
+                        # whichever serving thread asks first
+                        with ckpt_server.metrics.timed(
+                            "send_stage", span="send_checkpoint/stage"
+                        ):
+                            staging = _StreamStaging(
+                                ckpt_server._state_dict,
+                                wire,
+                                seq=ckpt_server._publish_seq,
+                            )
                         ckpt_server._stagings[wire] = staging
                     if track:
                         with ckpt_server._stream_cv:
@@ -907,21 +923,27 @@ class CheckpointServer(CheckpointTransport[T]):
                 try:
                     begin = staging.total * i // n
                     end = staging.total * (i + 1) // n
-                    self.send_response(200)
-                    self.send_header(
-                        "Content-Type", "application/octet-stream"
-                    )
-                    self.send_header("Content-Length", str(end - begin))
-                    # Per-range CRC32C (same polynomial as the ring
-                    # frames): the receiver verifies before trusting the
-                    # bytes — a flipped bit on a heal range otherwise
-                    # installs corrupted weights with no vote to catch it.
-                    self.send_header(
-                        "X-TFT-Crc32c",
-                        f"{staging.range_crc32c(begin, end):08x}",
-                    )
-                    self.end_headers()
-                    staging.write_range(self.wfile, begin, end)
+                    with ckpt_server.metrics.timed(
+                        "send_serve", span="send_checkpoint/serve",
+                        bytes=end - begin,
+                    ):
+                        self.send_response(200)
+                        self.send_header(
+                            "Content-Type", "application/octet-stream"
+                        )
+                        self.send_header("Content-Length", str(end - begin))
+                        # Per-range CRC32C (same polynomial as the ring
+                        # frames): the receiver verifies before trusting
+                        # the bytes — a flipped bit on a heal range
+                        # otherwise installs corrupted weights with no
+                        # vote to catch it.
+                        self.send_header(
+                            "X-TFT-Crc32c",
+                            f"{staging.range_crc32c(begin, end):08x}",
+                        )
+                        self.end_headers()
+                        staging.write_range(self.wfile, begin, end)
+                    ckpt_server.metrics.incr("send_bytes", end - begin)
                 finally:
                     with ckpt_server._stream_cv:
                         ckpt_server._stream_inflight -= 1
@@ -982,10 +1004,17 @@ class CheckpointServer(CheckpointTransport[T]):
         wire: Optional[str] = "env",
         streams: Optional[int] = None,
         device_put: Optional[bool] = None,
+        step: Optional[int] = None,
     ) -> Tuple[T, Dict[str, Any]]:
         """load_from_address returning ``(tree, stats)`` — the stats dict
-        names the path taken and its fetch/h2d seconds for heal-latency
-        attribution."""
+        names the path taken and its meta/fetch/h2d seconds for
+        heal-latency attribution. Each of those seconds is a phase
+        ``torchft::heal_fetch/<phase>`` stamped with ``step`` (the
+        owner's; the Manager's ``torchft::heal_fetch`` lies around them):
+        ``meta``, ``stream``, ``h2d``, and ``striped`` / ``single`` on
+        the pickled fallbacks, where ``fetch_s`` is the fetch that
+        succeeded (a stream attempt that failed before it shows as its
+        own phases)."""
         if stripes is None:
             stripes = int(os.environ.get("TORCHFT_CKPT_STRIPES", "4"))
         stripes = max(1, min(int(stripes), 64))
@@ -1000,9 +1029,10 @@ class CheckpointServer(CheckpointTransport[T]):
             f"fetching checkpoint from {address} "
             f"(streams={streams}, wire={wire}, pickle stripes={stripes})"
         )
-        t0 = time.perf_counter()
         try:
-            return cls._load_stream(address, timeout, wire, streams, device_put)
+            return cls._load_stream(
+                address, timeout, wire, streams, device_put, step
+            )
         except urllib.error.HTTPError as e:
             if e.code not in (404, 500):
                 raise
@@ -1044,11 +1074,12 @@ class CheckpointServer(CheckpointTransport[T]):
             )
         if stripes > 1:
             try:
-                out = cls._load_striped(address, timeout, stripes)
+                with timed_span("torchft::heal_fetch/striped", step) as fetch:
+                    out = cls._load_striped(address, timeout, stripes)
                 return out, {
                     "path": "striped",
                     "stripes": stripes,
-                    "fetch_s": time.perf_counter() - t0,
+                    "fetch_s": fetch.seconds,
                 }
             except urllib.error.HTTPError as e:
                 if e.code not in (404, 500):
@@ -1068,13 +1099,14 @@ class CheckpointServer(CheckpointTransport[T]):
                     f"striped checkpoint fetch failed ({e!r}); "
                     "falling back to single-stream fetch"
                 )
-        with urllib.request.urlopen(
-            address, timeout=timeout.total_seconds()
-        ) as f:
-            # incremental unpickle off the response stream (http.client
-            # de-chunks transparently): bounded memory on the receiver too
-            out = load_state_dict_stream(f)
-        return out, {"path": "single", "fetch_s": time.perf_counter() - t0}
+        with timed_span("torchft::heal_fetch/single", step) as fetch:
+            with urllib.request.urlopen(
+                address, timeout=timeout.total_seconds()
+            ) as f:
+                # incremental unpickle off the response stream (http.client
+                # de-chunks transparently): bounded memory on the receiver too
+                out = load_state_dict_stream(f)
+        return out, {"path": "single", "fetch_s": fetch.seconds}
 
     @classmethod
     def _load_stream(
@@ -1084,6 +1116,7 @@ class CheckpointServer(CheckpointTransport[T]):
         wire: Optional[str],
         streams: int,
         device_put: Optional[bool],
+        step: Optional[int] = None,
     ) -> Tuple[T, Dict[str, Any]]:
         """The zero-copy receiver: layout fetch, ``streams`` parallel
         range readers ``readinto``-ing one preallocated buffer, and a
@@ -1091,7 +1124,12 @@ class CheckpointServer(CheckpointTransport[T]):
         copies) the moment its bytes are covered — dispatching its async
         device upload while later ranges are still on the wire. Raises
         ``urllib.error.HTTPError(404)`` against pre-stream peers (the
-        caller falls back)."""
+        caller falls back).
+
+        Three phases under ``torchft::heal_fetch``: ``meta`` (the layout
+        fetch), ``stream`` (the buffer the ranges land in, the first
+        range request to the last byte) and ``h2d`` (the upload drain
+        after the last byte). ``fetch_s`` is the first two."""
         import jax
 
         if device_put is None:
@@ -1101,12 +1139,53 @@ class CheckpointServer(CheckpointTransport[T]):
             device_put = True
         deadline = time.monotonic() + timeout.total_seconds()
         wire_tok = wire if wire is not None else "none"
-        t0 = time.perf_counter()
-        with urllib.request.urlopen(
-            f"{address}/streammeta/{wire_tok}",
-            timeout=timeout.total_seconds(),
-        ) as f:
-            meta = _SafeUnpickler(f).load()
+        with timed_span("torchft::heal_fetch/meta", step) as meta_t:
+            with urllib.request.urlopen(
+                f"{address}/streammeta/{wire_tok}",
+                timeout=timeout.total_seconds(),
+            ) as f:
+                meta = _SafeUnpickler(f).load()
+        with timed_span("torchft::heal_fetch/stream", step) as stream_t:
+            treedef, out_leaves, device_leaves = cls._pull_ranges(
+                address, timeout, deadline, wire_tok, streams, device_put,
+                meta,
+            )
+        h2d_s = 0.0
+        if device_leaves:
+            # The residual upload drain AFTER the last byte arrived — the
+            # part of h2d the overlap could not hide.
+            with timed_span("torchft::heal_fetch/h2d", step) as h2d_t:
+                jax.block_until_ready(device_leaves)
+            h2d_s = h2d_t.seconds
+        return (
+            jax.tree_util.tree_unflatten(treedef, out_leaves),
+            {
+                "path": "stream",
+                "wire": wire,
+                "streams": streams,
+                "bytes": int(meta["total"]),
+                "meta_s": meta_t.seconds,
+                "fetch_s": meta_t.seconds + stream_t.seconds,
+                "h2d_s": h2d_s,
+            },
+        )
+
+    @classmethod
+    def _pull_ranges(
+        cls,
+        address: str,
+        timeout: timedelta,
+        deadline: float,
+        wire_tok: str,
+        streams: int,
+        device_put: bool,
+        meta: Dict[str, Any],
+    ) -> Tuple[Any, List[Any], List[Any]]:
+        """``_load_stream``'s middle: pulls the payload ``meta`` lays out
+        and returns ``(treedef, leaves, the leaves whose upload was
+        dispatched)`` once the last byte is in."""
+        import jax
+
         total = int(meta["total"])
         seq = int(meta.get("seq", 0))
         skeleton = meta["skeleton"]
@@ -1258,25 +1337,7 @@ class CheckpointServer(CheckpointTransport[T]):
             # in-flight reader count against its next disallow.
             cancel.set()
             raise
-        fetch_s = time.perf_counter() - t0
-        h2d_s = 0.0
-        if device_leaves:
-            # The residual upload drain AFTER the last byte arrived — the
-            # part of h2d the overlap could not hide.
-            t1 = time.perf_counter()
-            jax.block_until_ready(device_leaves)
-            h2d_s = time.perf_counter() - t1
-        return (
-            jax.tree_util.tree_unflatten(treedef, out_leaves),
-            {
-                "path": "stream",
-                "wire": wire,
-                "streams": streams,
-                "bytes": total,
-                "fetch_s": fetch_s,
-                "h2d_s": h2d_s,
-            },
-        )
+        return treedef, out_leaves, device_leaves
 
     @classmethod
     def _load_striped(cls, address: str, timeout: timedelta, stripes: int) -> T:
@@ -1377,7 +1438,9 @@ class CheckpointServer(CheckpointTransport[T]):
     def recv_checkpoint(
         self, src_rank: int, metadata: str, step: int, timeout: timedelta
     ) -> T:
-        out, stats = self._fetch(f"{metadata}{step}", timeout)
+        out, stats = self._fetch(
+            f"{metadata}{step}", timeout, step=self.metrics.step
+        )
         self.last_fetch_stats = stats
         return out
 

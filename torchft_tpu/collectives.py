@@ -27,7 +27,6 @@ import ctypes
 import json
 import os
 import threading
-import time
 from abc import ABC, abstractmethod
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, replace
@@ -39,7 +38,7 @@ import numpy as np
 
 from . import _native
 from ._native import _check, _lib, _ms
-from .profiling import span
+from .profiling import span, timed_span
 
 
 class ReduceOp(IntEnum):
@@ -865,60 +864,62 @@ class _ShardedPlan:
         self.ag_wire_bytes = self.total * (2 if ag_wire == "bf16" else 4)
 
 
-class _OpPhase:
-    """One phase of an op: the span ``torchft::<op>/<name>`` and one
-    perf_counter pair, added to the op's ``seconds[name]`` on exit (a
-    phase entered once per chunk accumulates). ``seconds`` is this
-    entry's own share, for per-bucket accounting."""
+class _OpPhase(timed_span):
+    """One phase of an op: the span ``torchft::<op>/<name>`` and its
+    seconds, added to the op's ``phases[name]`` on exit (a phase
+    entered once per chunk accumulates). ``seconds`` is this entry's own
+    share, for per-bucket accounting."""
 
     def __init__(self, op: "_OpSpan", name: str) -> None:
-        self._op = op
+        super().__init__(f"torchft::{op.op}/{name}", op.step)
+        self._totals = op.phases
         self._name = name
-        self.seconds = 0.0
-
-    def __enter__(self) -> "_OpPhase":
-        self._span = span(
-            f"torchft::{self._op.op}/{self._name}", self._op.step
-        )
-        self._span.__enter__()
-        self._t0 = time.perf_counter()
-        return self
 
     def __exit__(self, *exc: object) -> None:
-        self.seconds = time.perf_counter() - self._t0
-        self._span.__exit__(*exc)
-        totals = self._op.seconds
-        totals[self._name] = totals.get(self._name, 0.0) + self.seconds
+        super().__exit__(*exc)
+        self._totals[self._name] = (
+            self._totals.get(self._name, 0.0) + self.seconds
+        )
 
 
-class _OpSpan:
+class _OpSpan(timed_span):
     """One collective op, timed once for both sinks: as a ``with`` it is
     the profiler span ``torchft::<op>``; ``phase(name)`` nests
-    ``torchft::<op>/<name>`` in it; ``record(**fields)`` files the
+    ``torchft::<op>/<name>`` in it; ``record(**fields)`` makes the
     ``pop_op_stats()`` entry - ``op``, the fields, and the seconds of
-    every phase under the phase's own name."""
+    every phase under the phase's own name - which the ``with`` files on
+    its way out with its own seconds as ``op_s``, so that what lies
+    between the phases (``op_s`` less their sum) is a number."""
 
     def __init__(self, owner: "OpStatsMixin", op: str) -> None:
+        self.step = owner.trace_step  # a backend is a Collectives
+        super().__init__(f"torchft::{op}", self.step)
         self._owner = owner
         self.op = op
-        self.step = owner.trace_step  # a backend is a Collectives
-        self.seconds: Dict[str, float] = {}
-
-    def __enter__(self) -> "_OpSpan":
-        self._span = span(f"torchft::{self.op}", self.step)
-        self._span.__enter__()
-        return self
+        self.phases: Dict[str, float] = {}
+        self._stats: Optional[dict] = None
 
     def __exit__(self, *exc: object) -> None:
-        self._span.__exit__(*exc)
+        super().__exit__(*exc)
+        if self._stats is not None:
+            self._stats["op_s"] = self.seconds
+            self._owner._record_op_stats(self._stats)
 
     def phase(self, name: str) -> _OpPhase:
         return _OpPhase(self, name)
 
-    def record(self, **fields: Any) -> dict:
-        stats = {"op": self.op, **fields, **self.seconds}
-        self._owner._record_op_stats(stats)
-        return stats
+    def ready(self, arrays: Any) -> None:
+        """The phase ``ready``: the wait until the DEVICE has computed
+        ``arrays`` (the packed buffers, their host copies already
+        queued). The first blocking read would have waited for the same
+        event; taken here, ``d2h`` after it is the link alone."""
+        import jax
+
+        with self.phase("ready"):
+            jax.block_until_ready(arrays)
+
+    def record(self, **fields: Any) -> None:
+        self._stats = {"op": self.op, **fields, **self.phases}
 
 
 class OpStatsMixin:
@@ -1085,9 +1086,14 @@ class HostCollectives(OpStatsMixin, Collectives):
         n = _lib.tft_hc_last_stripe_ns(self._handle, buf, _MAX_STRIPES)
         return [buf[i] / 1e9 for i in range(min(n, _MAX_STRIPES))]
 
-    # pop_op_stats: OpStatsMixin. Host-ring entries record ``pack``
-    # (jitted concat dispatch), ``d2h`` (the blocking device→host read),
-    # ``ring`` (the native TCP op), ``h2d`` (result upload + unpack
+    # pop_op_stats: OpStatsMixin. Host-ring entries record ``op_s`` (the
+    # op whole), ``pack`` (jitted concat dispatch), ``ready`` (the wait
+    # for the device to finish what was packed: ``allreduce``, the
+    # device-packed ``plan_allreduce``, ``allgather``; the other ops'
+    # ``d2h`` still holds it), ``d2h`` (the blocking device→host reads,
+    # ``d2h_calls`` of them), ``ring`` (the native TCP op; ``allreduce``
+    # also ``ring_transport``, its slowest stripes alone, so that the
+    # rest of ``ring`` is the wait for peers), ``h2d`` (result upload + unpack
     # DISPATCH — jax uploads asynchronously, so the actual transfer
     # completes under the caller's next use/drain and is charged there),
     # ``wire_bytes`` where the TCP wire ships a different encoding, and
@@ -1537,6 +1543,9 @@ class HostCollectives(OpStatsMixin, Collectives):
                         )
                 for _, c in schedule:
                     c.copy_to_host_async()  # queue every DMA before the first block
+            # the backward pass and the pack are still running on the
+            # device: that wait is the chip's, not the link's
+            timing.ready([c for _, c in schedule])
 
             out_chunks: dict = {name: [] for name in names}
             buckets: dict = {
@@ -1586,7 +1595,10 @@ class HostCollectives(OpStatsMixin, Collectives):
                 bytes=total_bytes,
                 # native dtypes ride both legs at full width
                 d2h_bytes=total_bytes,
+                d2h_calls=len(schedule),  # one blocking read a chunk
                 chunks=len(schedule),
+                # the wire alone; ``ring`` less this is the wait for peers
+                ring_transport=sum(b["stripe_wall"] for b in buckets.values()),
                 buckets=buckets,
             )
             return _unflatten(treedef, packer.unpack(dev_bufs))
@@ -2213,6 +2225,7 @@ class HostCollectives(OpStatsMixin, Collectives):
                     a.copy_to_host_async()
                 for a in scales:
                     a.copy_to_host_async()
+            timing.ready([*payloads, *scales])
             # blocking readback of the wire buffers
             with timing.phase("d2h"):
                 staging_allocs = 0
@@ -2260,6 +2273,7 @@ class HostCollectives(OpStatsMixin, Collectives):
                 d2h_bytes=sum(h.nbytes for h in host_payloads) + sum(
                     h.nbytes for h in host_scales
                 ),
+                d2h_calls=len(host_payloads) + len(host_scales),
                 _buckets_json=self._plan_stats_json(plan.plan_id),
                 py_staging_allocs=staging_allocs,
                 plan_execs=plan.execs,
@@ -2361,6 +2375,7 @@ class HostCollectives(OpStatsMixin, Collectives):
                 names = sorted(bufs)  # deterministic group order on the wire
                 for name in names:  # queue every DMA before blocking on the first
                     bufs[name].copy_to_host_async()
+            timing.ready([bufs[name] for name in names])
             with timing.phase("d2h"):
                 host = {name: np.ascontiguousarray(np.asarray(bufs[name]))
                         for name in names}
@@ -2398,6 +2413,7 @@ class HostCollectives(OpStatsMixin, Collectives):
                 # this rank's packed groups cross down once; the gathered
                 # members come back on the h2d leg
                 d2h_bytes=nbytes,
+                d2h_calls=len(names),
                 stripe_s=stripe_s,
             )
         return results

@@ -243,6 +243,10 @@ class Manager:
         self._metrics = Metrics()
         # sampled only when a call really blocks (wait_quorum, Work.wait)
         self._metrics.declare("quorum_wait", "work_wait")
+        # the transport times its own work (the donor's staging and the
+        # ranges it serves) on its serving threads: into our timers,
+        # stamped with our step
+        self._checkpoint_transport.metrics = self._metrics
         # Last measured effective wire throughput (MB/s), updated by
         # observe_op_stats(); None until a ring op has been observed.
         self._last_wire_eff_mbps: Optional[float] = None
@@ -1144,9 +1148,7 @@ class Manager:
         THROUGH the manager, folding ring entries into the rolling
         effective-bandwidth estimate ``signals()`` reports: per op,
         ``wire_bytes / ring_s`` is the achieved wire throughput (the number
-        the policy cost model divides by) and its per-connection share
-        (divided by the op's stripe count) is what operators compare
-        against ``TORCHFT_HC_WIRE_CAP_MBPS``. Returns the drained entries,
+        the policy cost model divides by). Returns the drained entries,
         so a caller that wants the raw breakdown (benches, diagnosis
         tooling) consumes the SAME drain — pop semantics are preserved,
         just routed. A backend without op stats yields ``[]``."""
@@ -1170,18 +1172,14 @@ class Manager:
                         )
                     moved = t.get("tx_bytes") or t.get("shm_bytes") or 0
                     if phase_s > 0 and moved > 0:
-                        tier_eff = moved / phase_s / (1 << 20)
-                        self._last_tier_mbps[name] = tier_eff
-                        self._metrics.record(f"tier_{name}_MBps", tier_eff)
+                        self._last_tier_mbps[name] = (
+                            moved / phase_s / (1 << 20)
+                        )
             ring_s = st.get("ring")
             wire_bytes = st.get("wire_bytes") or st.get("bytes")
             if not ring_s or not wire_bytes or ring_s <= 0:
                 continue
-            eff = wire_bytes / ring_s / (1 << 20)
-            stripes = len(st.get("stripe_s") or ()) or 1
-            self._metrics.record("wire_eff_MBps", eff)
-            self._metrics.record("wire_conn_MBps", eff / stripes)
-            self._last_wire_eff_mbps = eff
+            self._last_wire_eff_mbps = wire_bytes / ring_s / (1 << 20)
         return entries
 
     def signals(self, churn_window_s: float = 600.0) -> Dict[str, Any]:

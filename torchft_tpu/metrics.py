@@ -24,7 +24,7 @@ import time
 from collections import defaultdict, deque
 from typing import Any, Dict, Optional
 
-from .profiling import span
+from .profiling import timed_span
 
 
 class _Timer:
@@ -143,10 +143,15 @@ class Metrics:
             for name in names:
                 self._timers.setdefault(name, _Timer())
 
-    def timed(self, name: str) -> "_TimedBlock":
+    def timed(
+        self, name: str, span: Optional[str] = None, **stats: int
+    ) -> "_TimedBlock":
         """One timed region, two sinks: the timer ``name`` and the
-        profiler span ``torchft::<name>``, over the same statements."""
-        return _TimedBlock(self, name)
+        profiler span ``torchft::<name>``, over the same statements.
+        ``span`` names the span otherwise, for a region that belongs
+        under another's name (``send_checkpoint/stage``); ``stats`` go
+        on the span beside the step."""
+        return _TimedBlock(self, name, span or name, stats)
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -161,18 +166,14 @@ class Metrics:
             }
 
 
-class _TimedBlock:
-    def __init__(self, metrics: Metrics, name: str) -> None:
+class _TimedBlock(timed_span):
+    def __init__(
+        self, metrics: Metrics, name: str, span: str, stats: Dict[str, int]
+    ) -> None:
+        super().__init__("torchft::" + span, metrics.step, **stats)
         self._metrics = metrics
         self._name = name
 
-    def __enter__(self) -> "_TimedBlock":
-        self._span = span("torchft::" + self._name, self._metrics.step)
-        self._span.__enter__()
-        self._t0 = time.perf_counter()
-        return self
-
     def __exit__(self, *exc: object) -> None:
-        seconds = time.perf_counter() - self._t0
-        self._span.__exit__(*exc)
-        self._metrics.record(self._name, seconds)
+        super().__exit__(*exc)
+        self._metrics.record(self._name, self.seconds)

@@ -9,11 +9,26 @@ and shows under one name in every sink:
   a host event on the profiler's clock (the device trace's), carrying the
   manager's step as the stat ``step``. With no capture active it is a
   flag test.
+- ``timed_span(name, step)`` is that span with one clock pair around the
+  same statements: ``seconds`` is what the host-side sink files. The
+  three below are built on it.
 - ``Metrics.timed("<name>")`` (metrics.py) enters ``torchft::<name>`` and
   records the same seconds under the timer ``<name>``.
 - a collective's op context (``OpStatsMixin._op``, collectives.py) enters
   ``torchft::<op>`` with ``torchft::<op>/<phase>`` nested in it and
-  records the same seconds under the ``pop_op_stats()`` keys.
+  records the same seconds under the ``pop_op_stats()`` keys: ``op_s``
+  for the op, the phase's name for a phase (``pack``, ``ready`` - the
+  DEVICE still computing what the op will read -, ``d2h``, ``host_copy``,
+  ``ring``, ``h2d``), all on the exchange thread.
+- the state transfer (checkpointing.py): on the healer's quorum thread,
+  nested in ``torchft::heal_fetch``, ``/meta``, ``/stream`` and ``/h2d``
+  (``/striped``, ``/single`` on the pickled fallbacks), filed in the
+  transport's ``last_fetch_stats`` as ``meta_s``, ``fetch_s``, ``h2d_s``;
+  on the donor's SERVING threads ``torchft::send_checkpoint/stage`` and
+  one ``torchft::send_checkpoint/serve`` a range, filed in the owning
+  manager's timers ``send_stage``, ``send_serve`` and counter
+  ``send_bytes``. They share ``torchft::send_checkpoint``'s name and
+  step, not its interval: the quorum thread only publishes.
 
 The names an operator sees in an XProf capture, and the ``Metrics``
 timer or op-stats key each equals, are tabled in docs/OPERATIONS.md
@@ -37,6 +52,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Optional
 
 logger = logging.getLogger(__name__)
@@ -46,17 +62,39 @@ _ENV_START = "TORCHFT_PROFILE_START"
 _ENV_STEPS = "TORCHFT_PROFILE_STEPS"
 
 
-def span(name: str, step: Optional[int] = None):
+def span(name: str, step: Optional[int] = None, **stats: int):
     """Named host-track span; shows up in an active jax profiler capture
-    under ``name``, with ``step`` (where given) as a stat of the event.
+    under ``name``, with ``step`` (where given) and ``stats`` (a range's
+    ``bytes``) as stats of the event.
 
     Usage: ``with span("torchft::quorum", step): ...``
     """
     import jax.profiler
 
     if step is None:
-        return jax.profiler.TraceAnnotation(name)
-    return jax.profiler.TraceAnnotation(name, step=step)
+        return jax.profiler.TraceAnnotation(name, **stats)
+    return jax.profiler.TraceAnnotation(name, step=step, **stats)
+
+
+class timed_span:
+    """``span(name, step)`` and one ``perf_counter`` pair over the same
+    statements: after the ``with``, ``seconds`` is what the span covers
+    in a capture, for the sink a run without a capture reads."""
+
+    def __init__(
+        self, name: str, step: Optional[int] = None, **stats: int
+    ) -> None:
+        self._span = span(name, step, **stats)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "timed_span":
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
 
 
 class Profiler:
