@@ -361,6 +361,180 @@ def test_a_shares_gradients_are_the_references_whatever_the_routing(picks, held_
 
 
 # ---------------------------------------------------------------------------
+# the grouped form of a share: one buffer of R rows in tiles of T
+# ---------------------------------------------------------------------------
+
+
+# 256 tokens under top-2 of 8 experts, 2 held: 128 claims expected, so a
+# buffer of R = 192 rows in 12 tiles of T = 16, and an expert is heavy past
+# L = 81 claims; each case is how many tokens pick held expert 0 and how
+# many held expert 1 (as their other choice), and the share of the two that
+# is heavy, applied to every token in place
+PLANTED = {
+    "even": (64, 64, 0.0),
+    "every_claim_on_one_expert": (192, 0, 0.5),
+    "no_held_claim": (0, 0, 0.0),
+    "fills_the_buffer_to_its_last_tile": (81, 81, 0.0),  # 6 tiles (15 rows empty) + 6
+    "one_claim_more": (82, 81, 0.5),  # expert 0 leaves the buffer
+    "a_group_ends_on_a_tile_boundary": (64, 48, 0.0),
+    "every_held_expert_heavy": (200, 150, 1.0),
+}
+
+
+def _planted(on_first, on_second, n=256):
+    """A layer of 8 experts whose router reads a token's first 8
+    coordinates, and inputs that plant each token's two picks there: the
+    first ``on_first`` tokens pick held expert 0 and the last ``on_second``
+    held expert 1, every other pick is an expert that is not held; the
+    logits differ from token to token, so the weights do."""
+    cfg, p, _ = _whole_layer(8)
+    token = np.arange(n)
+    first = np.where(token < on_first, 0, 2 + token % 3)
+    second = np.where(token >= n - on_second, 1, 5 + token % 3)
+    logits = np.zeros((n, 8), np.float32)
+    logits[token, first] = 4.0 + 0.3 * (token % 5)
+    logits[token, second] = 3.0 - 0.2 * (token % 7)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, n, cfg.d_model), jnp.float32)
+    x = x.at[0, :, :8].set(logits)
+    router = jnp.zeros_like(p["router"]).at[:8].set(jnp.eye(8))
+    return _share(cfg, dict(p, router=router), 0, 2) + (x,)
+
+
+def _dense_share(cfg, p, tokens, weights, chosen):
+    y, held_claims = olmoe._held_dense(cfg, p, tokens, weights, chosen)
+    return y, held_claims, jnp.zeros((), jnp.float32)
+
+
+@pytest.mark.parametrize("routing", sorted(PLANTED))
+def test_the_grouped_share_is_the_dense_share_and_the_references(routing, monkeypatch):
+    """Output and gradients (inputs, router, the three expert weights) of
+    the share under a planted routing: the grouped form's, the dense
+    form's and the reference's are one; an expert is applied to every
+    token only past ``L`` claims, and the layer says how many were."""
+    on_first, on_second, heavy = PLANTED[routing]
+    held, mine, x = _planted(on_first, on_second)
+    n = x.shape[1]
+    assert olmoe._share_buffer(held, n) == (192, 16, 81)
+
+    def program(p, x):
+        y, stats = olmoe.moe_layer(held, p, x)
+        return jnp.sum(y * jnp.cos(y)), (y, stats)
+
+    def plain(p, x):
+        y, _ = reference_mellum._moe(held, x.reshape(n, -1), p)
+        return jnp.sum(y * jnp.cos(y)), y
+
+    with jax.default_matmul_precision("highest"):
+        run = jax.jit(jax.value_and_grad(program, argnums=(0, 1), has_aux=True))
+        (_, (y, stats)), got = run(mine, x)
+        (_, want_y), want = jax.value_and_grad(plain, argnums=(0, 1), has_aux=True)(mine, x)
+        monkeypatch.setattr(olmoe, "_held_share", _dense_share)
+        (_, (dense_y, _)), dense = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)(mine, x)
+    assert float(stats["held_claims"]) == on_first + on_second
+    assert float(stats["held_dense_layers"]) == heavy
+    scale = max(float(jnp.max(jnp.abs(want_y))), 1e-6)
+    for other in (want_y, dense_y):
+        np.testing.assert_allclose(y.reshape(n, -1), other.reshape(n, -1), rtol=0, atol=1e-5 * scale)
+    for other in (want, dense):
+        for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(other)):
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-4 * max(float(jnp.max(jnp.abs(b))), 1e-6))
+
+
+def test_a_held_shares_gradient_step_has_no_grouped_kernel(monkeypatch):
+    """Plain XLA operations alone: no ``ragged_dot`` and no ``pallas_call``
+    in the share's gradient, and in the whole model's gradient step the
+    Pallas calls that the dense share leaves too - the flash kernels."""
+    held, mine, x = _planted(64, 64)
+    share = str(jax.make_jaxpr(jax.grad(
+        lambda p, x: jnp.sum(olmoe.moe_layer(held, p, x)[0]), argnums=(0, 1)
+    ))(mine, x))
+    assert "while" in share and "dot_general" in share
+    assert "ragged_dot" not in share and "pallas_call" not in share
+
+    def step():
+        return str(jax.make_jaxpr(jax.grad(
+            lambda p: mellum.loss_fn(F32, p, _tokens())
+        ))(_weights()))
+
+    grouped = step()
+    monkeypatch.setattr(olmoe, "_held_share", _dense_share)
+    assert "ragged_dot" not in grouped
+    assert grouped.count("pallas_call") == step().count("pallas_call") > 0
+
+
+def _matmul_flops(jaxpr, trips):
+    """Operations of every ``dot_general`` in ``jaxpr``; one inside a loop
+    is counted ``trips[rows]`` times, ``rows`` being the largest key of
+    ``trips`` among the edges of the loop's matmuls (every token, or a
+    tile's rows)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            depth = np.prod([eqn.invars[0].aval.shape[i] for i in contract])
+            total += 2 * int(depth) * int(np.prod(eqn.outvars[0].aval.shape))
+        inner = sum(_matmul_flops(j, trips) for j in jax.core.jaxprs_in_params(eqn.params))
+        if eqn.primitive.name == "while" and inner:
+            edges = _matmul_edges(eqn.params["body_jaxpr"].jaxpr)
+            inner *= trips[max(rows for rows in trips if rows in edges)]
+        total += inner
+    return total
+
+
+def _matmul_edges(jaxpr):
+    edges = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            edges.update(d for v in eqn.invars for d in v.aval.shape)
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            edges |= _matmul_edges(inner)
+    return edges
+
+
+def test_the_grouped_share_multiplies_a_fifth_of_the_dense_shares_rows():
+    """At Mellum2's widths (abstract shapes, traced and not compiled):
+    16,384 positions, 8 of 64 experts held, a buffer of 24,576 rows in 48
+    tiles of 512 where the dense form has 131,072 rows. With EVERY tile
+    in use and no expert heavy, forward and backward multiply under a
+    fifth of what the dense form does (nine matmuls a layer in both); a
+    heavy expert costs its eighth of the dense form and its products of
+    gate and up once more (two matmuls of eleven)."""
+    cfg = dataclasses.replace(_published(num_experts=64), held_experts=(0, 8))
+    n, d, f = 2 * 8192, cfg.d_model, cfg.expert_width
+    rows, tile, light_up_to = olmoe._share_buffer(cfg, n)
+    assert (rows, tile, light_up_to) == (24576, 512, 2561)
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    of = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype)
+    tiles = rows // tile
+
+    def flops(trips, share, *args):
+        total = lambda *a: jnp.sum(share(*a))
+        jaxpr = jax.make_jaxpr(jax.grad(total, argnums=(0, 1, 2)))(*args)
+        return _matmul_flops(jaxpr.jaxpr, trips)
+
+    grouped = lambda tokens, w_in, w_down, gate, *layout: olmoe._held_experts(
+        cfg, tokens, w_in, w_down, gate, layout
+    )
+    abstract = (
+        bf16(n, d), bf16(8, d, 2 * f), bf16(8, f, d), of(jnp.float32, 8, n),
+        of(jnp.int32, tiles, tile), of(jnp.int32, tiles, tile), of(jnp.int32, tiles),
+        of(jnp.int32), of(jnp.int32, 8), of(jnp.int32),
+    )
+    light = flops({tile: tiles, n: 0}, grouped, *abstract)
+    one_heavy = flops({tile: 0, n: 1}, grouped, *abstract)
+    dense = flops(
+        {}, lambda tokens, w_in, w_down, *routing: olmoe._held_dense(
+            cfg, {"w_gate": w_in[..., :f], "w_up": w_in[..., f:], "w_down": w_down},
+            tokens, *routing,
+        )[0],
+        bf16(n, d), bf16(8, d, 2 * f), bf16(8, f, d), of(jnp.float32, n, 8), of(jnp.int32, n, 8),
+    )
+    assert dense == 9 * 2 * n * 8 * d * f
+    assert light == 9 * 2 * rows * d * f < dense // 5
+    assert one_heavy == 11 * 2 * n * d * f
+
+
+# ---------------------------------------------------------------------------
 # the mechanisms, one by one
 # ---------------------------------------------------------------------------
 

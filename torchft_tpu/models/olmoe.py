@@ -51,7 +51,21 @@ file (``models/mellum.py`` is one):
   count): the router still scores all ``n_experts``, the weights are
   (held, d, f), and the layer returns the part of the result its own
   experts give - what the absent experts would add is left out, and no
-  code stands in for them or their exchange (``_held_dense``).
+  code stands in for them or their exchange. The share does the work of
+  the claims it holds (``_held_share``, ``_held_experts``), in plain XLA
+  operations and no grouped kernel: an expert that many tokens chose (more
+  than ``L`` of them: ``_share_buffer``, from shapes alone, about 1.25
+  times an expert's load under even routing) is applied to every token in
+  place, a token that did not choose it weighted 0; the claims on the
+  other held experts are laid out, expert after expert and each group from
+  a tile boundary on, in one buffer of ``R`` rows in tiles of ``T`` (``R``
+  one and a half times the claims the held experts expect), where they fit
+  whatever the routing, and a loop that ends with the tiles IN USE
+  multiplies each tile by its expert's weights. No claim is dropped and no
+  form is chosen by hand; ``moe_layer``'s ``held_dense_layers`` is the
+  share of the layer's held experts that were applied to every token.
+  ``_held_dense`` - every held expert on every token - is what this
+  replaced in the step and what the tests hold it to.
 """
 
 from __future__ import annotations
@@ -322,7 +336,8 @@ def _held_dense(
     how many of the N x K claims they hold: every held expert applied to
     every token and kept, times its weight, where the token chose it.
     Exact and dropless by construction; its work is N x held rows whatever
-    the routing."""
+    the routing. The step runs ``_held_share``; this is the form the tests
+    hold it to, output and gradients."""
     first, held = cfg.held
     with jax.named_scope("dispatch"):
         # (N, held): the token's weight on each held expert, 0 where it
@@ -342,6 +357,255 @@ def _held_dense(
             preferred_element_type=jnp.float32,
         )
     return y, jnp.sum(mine)
+
+
+# The widest tile of a share's buffer, in rows: a multiple of the MXU's 128
+# at which a tile's matmul against its expert's (d, f) weights stays above
+# the v5e's ridge of 240 FLOP a byte (2 T d f operations over 2 (d f + T d +
+# T f) bytes: 285 at Mellum2's 2304 x 896). A smaller step falls under it.
+_TILE_ROWS = 512
+
+
+def _share_buffer(cfg: OlmoeConfig, n: int) -> Tuple[int, int, int]:
+    """(R, T, L) of a held share over ``n`` tokens, from shapes alone. A
+    tile is ``T`` rows of one expert: the largest power of two up to
+    ``_TILE_ROWS`` at which the padding of every group to a tile boundary,
+    under ``held x T`` rows, stays within a quarter of the claims the held
+    experts EXPECT under even routing, ``n K held / E`` (``4 T E <= n K``).
+    ``R`` rows, in whole tiles, hold one and a half times the expected
+    claims. An expert with more than ``L`` claims is HEAVY and applied to
+    every token in place; ``L`` is the most at which ``held`` light groups,
+    each padded to a tile boundary, fit ``R`` whatever the routing, so no
+    claim is ever without a row: about 1.25 times an expert's expected
+    load. (A row of the tile loop costs about five times a row of a
+    matmul over all tokens on a v5e, its two scatter-adds above all:
+    PERF.md section 6, PR 40; an expert that a fifth of the tokens chose
+    is cheaper applied to all.)"""
+    E, K, held = cfg.n_experts, cfg.experts_per_token, cfg.held[1]
+    tile = 8
+    while tile < _TILE_ROWS and 8 * tile * E <= n * K:
+        tile *= 2
+    rows = -(-3 * n * K * held // (2 * E * tile)) * tile
+    return rows, tile, max(rows // held - (tile - 1), 0)
+
+
+def _tile(stack: jax.Array, c: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_index_in_dim(stack, c, 0, keepdims=False)
+
+
+def _swiglu(cfg: OlmoeConfig, into: jax.Array) -> jax.Array:
+    """``silu(gate) * up`` of the (rows, 2 f) products of gate and up."""
+    f = cfg.expert_width
+    return jax.nn.silu(into[:, :f]) * into[:, f:]
+
+
+# What ``_held_share`` hands the experts beside the tokens and the weights:
+# the light experts' buffer - (tiles, T) the token of every row (one past N
+# where it has none) and its claim (expert-major: claim e N + n is token n's
+# on held expert e; held N where the row has none), (tiles,) the expert of
+# every tile, how many tiles are in use - and the heavy experts: (held,) the
+# held experts with the heavy ones first, and how many those are.
+_Layout = Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(
+    cfg: OlmoeConfig, tokens: jax.Array, w_in: jax.Array, w_down: jax.Array,
+    gate: jax.Array, layout: _Layout,
+) -> jax.Array:
+    """The held experts' part of the layer's output, (N, D) float32, from
+    the tokens, gate and up side by side (``w_in`` (held, D, 2 f)), the
+    weights down, the (held, N) weight of every token on every held expert
+    (0 where it chose another) and ``_held_share``'s layout. Two loops,
+    each as long as the routing makes it:
+
+    - over the light experts' tiles in use: a tile's rows gathered from
+      ``tokens``, gate and up in one matmul, the claim's weight applied
+      where ``_held_dense`` applies it, down, the tile's float32 rows
+      added to their tokens (one scatter-add a tile, inside the loop:
+      eight tiles at a time in a loop of their own took twice as long a
+      row on a v5e, PERF.md section 6, PR 40);
+    - over the heavy experts: the same arithmetic on ALL tokens in place,
+      no gather and no scatter-add, a token that did not choose the expert
+      weighted 0.
+
+    The backward pass is the same two loops, written out below. It keeps
+    the tiles' (tiles, T, 2 f) products of gate and up and computes a
+    heavy expert's again: kept, they are the dense form's 0.47 GB a layer
+    in a buffer that exists whatever the routing."""
+    return _held_experts_fwd(cfg, tokens, w_in, w_down, gate, layout)[0]
+
+
+def _into(cfg: OlmoeConfig, rows: jax.Array, w_in: jax.Array, e: jax.Array) -> jax.Array:
+    return jnp.dot(rows, _tile(w_in, e), preferred_element_type=jnp.float32).astype(cfg.dtype)
+
+
+def _held_experts_fwd(cfg, tokens, w_in, w_down, gate, layout):
+    token_of_row, claim_of_row, tile_expert, used, heavy_first, heavy = layout
+    with jax.named_scope("dispatch"):
+        weight_of_row = gate.reshape(-1).at[claim_of_row].get(mode="fill", fill_value=0.0)
+
+    def out_of(into, weight, e):  # (rows, D) float32
+        with jax.named_scope("experts"):
+            hidden = _swiglu(cfg, into)
+        with jax.named_scope("combine"):  # rounded as ``_held_dense`` rounds it
+            hidden = (hidden * weight[:, None]).astype(cfg.dtype)
+        with jax.named_scope("experts"):
+            return jnp.dot(hidden, _tile(w_down, e), preferred_element_type=jnp.float32)
+
+    def tile(c, carry):
+        y, kept = carry  # (N, D) float32, (tiles, T, 2 f)
+        e, of_tile = tile_expert[c], token_of_row[c]
+        with jax.named_scope("dispatch"):
+            rows = tokens.at[of_tile].get(mode="clip")  # no token: weight 0
+        with jax.named_scope("experts"):
+            into = _into(cfg, rows, w_in, e)
+        out = out_of(into, weight_of_row[c], e)
+        with jax.named_scope("combine"):  # a row of no token is dropped
+            y = y.at[of_tile].add(out, mode="drop")
+        return y, jax.lax.dynamic_update_index_in_dim(kept, into, c, 0)
+
+    def expert(j, y):
+        e = heavy_first[j]
+        with jax.named_scope("experts"):
+            into = _into(cfg, tokens, w_in, e)
+        return y + out_of(into, gate[e], e)
+
+    y, kept = jax.lax.fori_loop(0, used, tile, (
+        jnp.zeros(tokens.shape, jnp.float32),
+        jnp.zeros(token_of_row.shape + w_in.shape[2:], cfg.dtype),
+    ))
+    y = jax.lax.fori_loop(0, heavy, expert, y)
+    return y, (tokens, w_in, w_down, gate, layout, kept)
+
+
+def _held_experts_bwd(cfg, res, g):
+    tokens, w_in, w_down, gate, layout, kept = res
+    token_of_row, claim_of_row, tile_expert, used, heavy_first, heavy = layout
+    g = g.astype(cfg.dtype)  # what the matmuls below multiply in
+    with jax.named_scope("dispatch"):
+        weight_of_row = gate.reshape(-1).at[claim_of_row].get(mode="fill", fill_value=0.0)
+
+    def back(rows, into, weight, g_out, e, d_in, d_down):
+        """One group of rows of expert ``e``: the gradient of its rows
+        (float32) and of its weights on them, the expert's weight
+        gradients added in ``d_in`` and ``d_down``."""
+        with jax.named_scope("experts"):
+            plain, undo = jax.vjp(functools.partial(_swiglu, cfg), into)
+        with jax.named_scope("combine"):
+            hidden = (plain * weight[:, None]).astype(cfg.dtype)
+        with jax.named_scope("experts"):
+            g_hidden = jnp.dot(g_out, _tile(w_down, e).T, preferred_element_type=jnp.float32)
+            d_down_e = _tile(d_down, e) + jnp.dot(
+                hidden.T, g_out, preferred_element_type=jnp.float32
+            )
+        with jax.named_scope("combine"):
+            d_weight = jnp.sum(g_hidden * plain.astype(jnp.float32), axis=1)
+            g_plain = (g_hidden * weight[:, None]).astype(cfg.dtype)
+        with jax.named_scope("experts"):
+            g_into, = undo(g_plain)
+            g_rows = jnp.dot(g_into, _tile(w_in, e).T, preferred_element_type=jnp.float32)
+            d_in_e = _tile(d_in, e) + jnp.dot(
+                rows.T, g_into, preferred_element_type=jnp.float32
+            )
+        return (
+            g_rows, d_weight,
+            jax.lax.dynamic_update_index_in_dim(d_in, d_in_e, e, 0),
+            jax.lax.dynamic_update_index_in_dim(d_down, d_down_e, e, 0),
+        )
+
+    def tile(c, carry):
+        d_tokens, d_in, d_down, d_weights = carry
+        e, of_tile = tile_expert[c], token_of_row[c]
+        with jax.named_scope("combine"):
+            g_out = g.at[of_tile].get(mode="clip")  # (T, D)
+        with jax.named_scope("dispatch"):
+            rows = tokens.at[of_tile].get(mode="clip")
+        g_rows, d_weight, d_in, d_down = back(
+            rows, _tile(kept, c), weight_of_row[c], g_out, e, d_in, d_down
+        )
+        with jax.named_scope("dispatch"):
+            d_tokens = d_tokens.at[of_tile].add(g_rows, mode="drop")
+        return d_tokens, d_in, d_down, jax.lax.dynamic_update_index_in_dim(d_weights, d_weight, c, 0)
+
+    def expert(j, carry):
+        d_tokens, d_in, d_down, d_gate = carry
+        e = heavy_first[j]
+        with jax.named_scope("experts"):
+            into = _into(cfg, tokens, w_in, e)
+        g_rows, d_weight, d_in, d_down = back(tokens, into, gate[e], g, e, d_in, d_down)
+        return d_tokens + g_rows, d_in, d_down, jax.lax.dynamic_update_index_in_dim(d_gate, d_weight, e, 0)
+
+    d_tokens, d_in, d_down, d_weights = jax.lax.fori_loop(0, used, tile, (
+        jnp.zeros(tokens.shape, jnp.float32), jnp.zeros(w_in.shape, jnp.float32),
+        jnp.zeros(w_down.shape, jnp.float32), jnp.zeros(token_of_row.shape, jnp.float32),
+    ))
+    with jax.named_scope("dispatch"):  # a light claim's weight; a heavy expert's below
+        d_gate = jnp.zeros((gate.size,), jnp.float32).at[claim_of_row].add(
+            d_weights, mode="drop"
+        ).reshape(gate.shape)
+    d_tokens, d_in, d_down, d_gate = jax.lax.fori_loop(
+        0, heavy, expert, (d_tokens, d_in, d_down, d_gate)
+    )
+    return (
+        d_tokens.astype(tokens.dtype), d_in.astype(w_in.dtype),
+        d_down.astype(w_down.dtype), d_gate, None,
+    )
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def _held_share(
+    cfg: OlmoeConfig, p: Dict[str, Any], tokens: jax.Array,
+    weights: jax.Array, chosen: jax.Array,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The held experts' part of the layer's output, (N, D) float32, how
+    many of the N x K claims they hold, and how many of the held experts
+    were heavy.
+
+    An expert with more than ``L`` claims (``_share_buffer``) is applied to
+    every token in place. The claims on the others are laid out in one
+    buffer of ``R`` rows, expert after expert, each expert's group from a
+    tile boundary on and in the order of its tokens: a cumulative count
+    per expert gives a claim its row, no sort; they fit whatever the
+    routing. The work follows the tiles in use and the heavy experts, not
+    N x held rows (``_held_experts``); no claim is dropped and none is
+    computed twice."""
+    N, K = chosen.shape
+    first, held = cfg.held
+    rows, tile, light_up_to = _share_buffer(cfg, N)
+    with jax.named_scope("dispatch"):
+        mine = (chosen - first)[:, :, None] == jnp.arange(held)  # (N, K, held)
+        gate = jnp.sum(jnp.where(mine, weights[:, :, None], 0.0), axis=1).T  # (held, N)
+        hit = jnp.any(mine, axis=1).T  # (held, N): the token chose the expert
+        is_heavy = jnp.sum(hit, axis=1) > light_up_to
+        light = hit & ~is_heavy[:, None]
+        rank = jnp.cumsum(light, axis=1, dtype=jnp.int32)  # 1 for its first claim
+        padded = -(-rank[:, -1] // tile) * tile
+        ends = jnp.cumsum(padded)
+        claim = jnp.arange(held * N, dtype=jnp.int32).reshape(held, N)
+        # a claim's row; no row (past the buffer, each its own) for the rest
+        row_of_claim = jnp.where(light, (ends - padded)[:, None] + rank - 1, rows + claim)
+        claim_of_row = jnp.full((rows,), held * N, jnp.int32).at[
+            row_of_claim.reshape(-1)
+        ].set(claim.reshape(-1), mode="drop", unique_indices=True)
+        # a row's token; past N, each its own, where it has none
+        token_of_row = jnp.where(
+            claim_of_row < held * N, claim_of_row % N, N + jnp.arange(rows)
+        )
+        tile_expert = jnp.minimum(
+            jnp.sum(jnp.arange(0, rows, tile)[:, None] >= ends, axis=1), held - 1
+        )
+        heavy_first = jnp.argsort(~is_heavy, stable=True).astype(jnp.int32)
+        heavy = jnp.sum(is_heavy, dtype=jnp.int32)
+    with jax.named_scope("experts"):
+        w_in = jnp.concatenate([p["w_gate"], p["w_up"]], axis=-1).astype(cfg.dtype)
+    y = _held_experts(cfg, tokens, w_in, p["w_down"].astype(cfg.dtype), gate, (
+        token_of_row.reshape(-1, tile), claim_of_row.reshape(-1, tile),
+        tile_expert, ends[-1] // tile, heavy_first, heavy,
+    ))
+    return y, jnp.sum(hit), heavy
 
 
 def _every_expert(
@@ -378,7 +642,8 @@ def moe_layer(
     cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Dropless top-K routed SwiGLU experts, or the held experts' share of
-    them (``cfg.held_experts``, ``_held_dense``).
+    them (``cfg.held_experts``, ``_held_share``: tile by tile over the
+    claims held, an expert that many tokens chose over all of them).
 
     Args:
         x: (B, S, D) activations.
@@ -388,7 +653,9 @@ def moe_layer(
         chose each expert, ``probs`` (E,) the sum of ``p[:, e]``, ``z``
         the sum of ``logsumexp(r) ** 2``; and ``held_claims``, how many of
         the N x K claims fell on an expert held here (all of them where
-        every expert is).
+        every expert is), and ``held_dense_layers``, the share of the held
+        experts that were applied to every token (0.0 to 1.0; summed over
+        the layers, how many layers' worth of the dense form a step ran).
     """
     B, S, D = x.shape
     N, E, K = B * S, cfg.n_experts, cfg.experts_per_token
@@ -408,9 +675,9 @@ def moe_layer(
 
     if cfg.held_experts is None:
         y, claims = _every_expert(cfg, p, tokens, weights, chosen)
-        held_claims = N * K
+        held_claims, heavy = N * K, 0
     else:
-        y, held_claims = _held_dense(cfg, p, tokens, weights, chosen)
+        y, held_claims, heavy = _held_share(cfg, p, tokens, weights, chosen)
         claims = jnp.zeros((E,), jnp.int32).at[chosen.reshape(N * K)].add(1)
 
     stats = {
@@ -418,6 +685,7 @@ def moe_layer(
         "probs": jnp.sum(probs, axis=0),
         "z": jnp.sum(jax.nn.logsumexp(logits, axis=-1) ** 2),
         "held_claims": jnp.asarray(held_claims, jnp.float32),
+        "held_dense_layers": jnp.asarray(heavy, jnp.float32) / cfg.held[1],
     }
     return y.reshape(B, S, D).astype(x.dtype), stats
 
