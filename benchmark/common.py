@@ -61,6 +61,9 @@ FAMILY_STATES = (
     "flops_per_step", "flash_calls", "lowered_mosaic_calls", "facts",
     "LOSS_RTOL", "GRAD_NORM_RTOL",
 )
+# What a family MAY state besides (README.md): ``routing(cfg, params, tokens)
+# -> {name: array of (pool,)}``, which ``ft_sync`` reads on the state after
+# the window and around a trace. A family without it runs what it ran.
 
 
 def load_family(name: str) -> Any:

@@ -7,14 +7,35 @@ sized by a Mellum2 ``config.json`` and the deployment its file states.
 Like ``olmoe_lm`` it gives the harness everything in
 ``common.FAMILY_STATES``; in ``facts`` it keeps what the seven readers of
 the ``attn_*`` and ``moe_held_*`` metrics want: the flash kernels' least
-work by kind of layer and the held experts' grouped matmuls, both from
-shapes (``kind_flash``, ``held_expert_matmuls``). The functions that
-count operations and bytes live here; the readers only divide.
+work by kind of layer and the held experts' matmuls, both from shapes
+(``kind_flash``, ``held_expert_matmuls``). The functions that count
+operations and bytes live here; the readers only multiply and divide.
 
 A traced step's Mosaic calls (``flash_calls``) are the two flash kernels
-of every layer and nothing else: the program runs a rank's share DENSE
-(``olmoe._held_dense``: every held expert on every token, plain batched
-matmuls), whose cost does not move with the routing.
+of every layer and nothing else: a rank's share (``olmoe._held_share``,
+since PR 40) is plain XLA and does the work of the claims it holds - the
+tiles its light experts' claims fill, and every token by each expert that
+more than about 1.25 times its even load of tokens chose - so ITS COST
+FOLLOWS THE ROUTING. That is why this family states the optional
+``routing`` (``common.py``): the program's own forward pass over the pool's
+batches with ``moe_layer``'s sums, which the generator runs on the state
+after the window and, in a traced run, on both sides of the traced steps,
+so that every run says what routing it timed and the held share's roofline
+reads the rows that were claimed. The timed program is not touched, and
+``routing`` calls nothing of the program that its modules do not export
+(``mellum.forward``; the sums are those ``moe_layer`` documents).
+
+The routing is the seed's own and it is not a deployment's even share
+(PERF.md section 6, PRs 40 and 42). At the seed's draw a layer's held
+claims read 0.6 to 1.4 of the expected: from random weights attention's
+output carries each SEQUENCE's own mean, so an expert's claims differ by a
+fifth (layer 0) to four fifths (layer 3) from one batch of two sequences to
+the next, and no labelling of which experts the rank holds makes the
+batches alike (one was built and measured). Under the generator's AdamW at
+1e-3 every router then collapses onto whole experts within ten steps and
+goes on moving between them all window long: this rank's routers see the
+held experts' gradient alone. So the cell's step is held under a metric of
+its own with a wide bound (``step_p90_routed_ms``, PERF.md section 2).
 """
 
 from __future__ import annotations
@@ -70,6 +91,31 @@ def init(cfg: Any, key: Any) -> Any:
 
 def loss(cfg: Any, params: Any, tokens: Any) -> Any:
     return mellum.loss_fn(cfg, params, tokens)
+
+
+def routing(cfg: Any, params: Any, tokens: Any) -> Dict[str, Any]:
+    """What routing the step runs under ``params`` on each of the pool's
+    batches ``tokens`` (int32[pool, batch, seq]): the program's own forward
+    pass (``mellum.forward``), a batch at a time at the step's own shapes
+    and in the step's own types (the bf16 copy of the masters), for
+    ``moe_layer``'s sums over a step's layers; the logits are not asked
+    for, so the readout is never computed. Arrays of (pool,), one number
+    a batch: ``held_claims``, the claims the layers put on held experts
+    over the expected ones; ``heavy_experts``, how many of the layers'
+    held experts were applied to every token."""
+    import jax
+    import jax.numpy as jnp
+
+    compute = jax.tree_util.tree_map(
+        lambda l: l.astype(jnp.bfloat16) if l.dtype == jnp.float32 else l, params
+    )
+    sums = jax.lax.map(lambda b: mellum.forward(cfg, compute, b[:, :-1])[1], tokens)
+    positions = tokens.shape[1] * (tokens.shape[2] - 1)
+    return {
+        "held_claims": sums["held_claims"]
+        / (cfg.n_layers * expected_held_claims(cfg, positions)),
+        "heavy_experts": sums["held_dense_layers"] * cfg.held[1],
+    }
 
 
 def reference_train(cfg: Any, params: Any, batches: Any) -> Any:
@@ -147,23 +193,29 @@ def flops_per_step(cfg: Any, batch: int, seq: int) -> float:
 
 
 def held_expert_matmuls(cfg: Any, batch: int, seq: int) -> Dict[str, Any]:
-    """What one step's matmuls over the held experts REQUIRE, from shapes,
-    at the expected held claims (``rows``): a layer has three forward
-    (gate, up, down) and for each two backward (the rows' gradient, the
-    weights'): nine, each 2 x rows x d x f operations. Bytes, bf16: each
-    reads or writes one rows x d and one rows x f matrix and the held
-    experts' held x d x f weights. ``computed_flops`` is what the dense
-    form multiplies for them: every position by every held expert,
-    ``n_experts / K`` times the requirement."""
+    """What one step's matmuls over the held experts REQUIRE, from shapes:
+    a layer has three forward (gate, up, down) and for each two backward
+    (the rows' gradient, the weights'): nine, each 2 x rows x d x f
+    operations. Bytes, bf16: each reads or writes one rows x d and one
+    rows x f matrix and the held experts' held x d x f weights. ``flops``
+    and ``bytes`` are those at ``rows``, the EXPECTED held claims a layer;
+    ``flops_per_row``, ``bytes_per_row`` and ``bytes_weights`` are their
+    terms, for a reader that knows the rows a run realised (its
+    ``routing``). What the share MULTIPLIES for them since PR 40 (whole
+    tiles of its light experts' claims, every position by a heavy expert)
+    follows the routing and is not to be had from shapes."""
     positions = batch * (seq - 1)
     rows = expected_held_claims(cfg, positions)
     d, f, held = cfg.d_model, cfg.expert_width, cfg.held[1]
     calls = 9 * cfg.n_layers
+    per_row, weights = float(calls * 2 * (d + f)), float(calls * 2 * held * d * f)
     return {
         "calls": calls,
         "flops": float(calls * 2 * rows * d * f),
-        "bytes": float(calls * 2 * (rows * d + rows * f + held * d * f)),
-        "computed_flops": float(calls * 2 * positions * held * d * f),
+        "bytes": per_row * rows + weights,
+        "flops_per_row": float(calls * 2 * d * f),
+        "bytes_per_row": per_row,
+        "bytes_weights": weights,
         "rows": rows,
     }
 
@@ -189,7 +241,7 @@ def kind_flash(cfg: Any, batch: int, seq: int) -> Dict[str, Dict[str, float]]:
 
 def lowered_mosaic_calls(cfg: Any) -> int:
     """``tpu_custom_call``s in the text of the lowered step: the flash
-    forward and fused backward of every layer; the dense share has none."""
+    forward and fused backward of every layer; the held share has none."""
     return 2 * cfg.n_layers
 
 

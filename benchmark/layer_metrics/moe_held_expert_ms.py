@@ -1,7 +1,10 @@
 """Model step: device time a traced step in the held experts' matmuls
-and their activation (``moe/experts``: every held expert on every token,
-the form a rank's share has), forward and backward, every layer. None
-where the family states no held share or nothing ran under the name."""
+and their activation (any path with ``experts`` after ``moe``: since PR 40
+the tiles the light experts' claims fill and every token by each heavy
+expert, inside the share's two loops, and gate and up laid side by side),
+forward and backward, every layer. It follows the routing: the run's
+``routing`` says which it timed. None where the family states no held
+share or nothing ran under the name."""
 
 from benchmark import common
 

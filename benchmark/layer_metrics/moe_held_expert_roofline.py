@@ -1,16 +1,34 @@
 """Kernels: the least time the chip could take for the matmuls a step
-REQUIRES over the held experts - nine a layer at the EXPECTED held claims
+REQUIRES over the held experts - nine a layer over the held claims
 (``facts["family"]["held_expert_matmuls"]``, from shapes: the larger of
 FLOPs over the bf16 peak and bytes over the HBM peak) - over the traced
-time of ``moe_held_expert_ms``. The dense form multiplies ``n_experts / K``
-times the requirement, so it reads at most that fraction of its matmuls'
-own efficiency (an eighth at 64 experts under top-8). The realised claims
-differ from the expected by the seed's routing; PERF.md gives them beside
-this reading."""
+time of ``moe_held_expert_ms``. The claims are those the traced steps
+REALISED: the run's ``routing`` read on the state just before the traced
+steps and just after them, on the pool's batches those steps were fed
+(``facts["trace"]["batches"]``), the mean of the two. Since PR 40 the share
+does the work of the claims it holds, so the EXPECTED claims' least time
+over the realised claims' time read the routing and could pass 100 by
+routing alone; a run that says no routing is therefore not read. What is
+left under 100 is the padding of every expert's claims to whole tiles, a
+heavy expert's rows that no token claimed, the loop's gathers and
+scatter-adds, and the matmuls' own efficiency. The routers move from step
+to step under the generator's rate, so the two readings bracket the traced
+steps and do not repeat them: PERF.md section 3 says by how much."""
 
 from benchmark import common
 
 moe_held_expert_ms = common.load_by_name("layer_metrics", "moe_held_expert_ms")
+
+
+def realised_rows(facts, expected):
+    """Held claims a layer in the traced steps, as the run's routing
+    bracketed them; None where the run read no routing around a trace."""
+    routing, trace = facts.get("routing") or {}, facts.get("trace") or {}
+    ends = [routing[end]["held_claims"] for end in ("traced", "open") if end in routing]
+    fed = trace.get("batches")
+    if len(ends) < 2 or not fed:
+        return None
+    return expected * sum(end[b] for end in ends for b in fed) / (len(ends) * len(fed))
 
 
 def read(facts):
@@ -18,8 +36,11 @@ def read(facts):
     if not peaks or not ms:
         return None
     experts = facts["family"]["held_expert_matmuls"]
+    rows = realised_rows(facts, experts["rows"])
+    if rows is None:
+        return None
     least = max(
-        experts["flops"] / peaks["bf16_flops_per_s"],
-        experts["bytes"] / peaks["hbm_bytes_per_s"],
+        experts["flops_per_row"] * rows / peaks["bf16_flops_per_s"],
+        (experts["bytes_weights"] + experts["bytes_per_row"] * rows) / peaks["hbm_bytes_per_s"],
     )
     return 100.0 * least / (ms * 1e-3)

@@ -65,6 +65,14 @@ def main() -> int:
 
     loss_and_grads = common.mixed_precision_grad(family, cfg)
     programs = {}
+    if hasattr(family, "routing"):  # read after the window and around a trace
+        pool = entry["mix"]["params"]["pool"]
+        programs["routing"] = jax.jit(
+            lambda p, t: family.routing(cfg, p, t)
+        ).lower(
+            on_chip(params),
+            jax.ShapeDtypeStruct((pool, batch, seq), jnp.int32, sharding=chip),
+        )
     if entry["mix"]["generator"] == "raw":
         from benchmark.traffic import raw
 

@@ -108,12 +108,20 @@ def window_facts(cell: Dict[str, Any], facts: Dict[str, Any]) -> None:
 
 def read_metrics(directory: str, wanted: List[Dict[str, Any]], facts: Dict[str, Any]) -> Dict[str, Any]:
     """Each metric is read by the file of its own name; a reader that
-    finds nothing to read returns None and the metric is left out."""
+    finds nothing to read returns None and the metric is left out. A
+    quantity entered once for each end-to-end metric its cells report
+    (``<quantity>.<which>``: ``mfu.routed`` beside ``mfu``) is read by the
+    quantity's one file where the entry has none of its own."""
     from benchmark import common
 
     metrics = {}
     for m in wanted:
-        value = common.load_by_name(directory, m["name"]).read(facts)
+        name = m["name"]
+        if "." in name and not os.path.isfile(
+            os.path.join(common.BENCH, directory, name + ".py")
+        ):
+            name = name.rsplit(".", 1)[0]
+        value = common.load_by_name(directory, name).read(facts)
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return metrics
@@ -201,6 +209,7 @@ def main() -> int:
                 "groups", "reference", "manager_metrics", "op_stats", "raw",
                 "worker_phases", "trace", "tokens_per_step", "discarded_at_kill",
                 "memory_stats", "flops_per_step", "flash", "family", "peaks",
+                "routing",
             )},
         }, f)
     shutil.rmtree(cell["scratch"], ignore_errors=True)
@@ -209,6 +218,8 @@ def main() -> int:
     ))
     common.say("checks " + json.dumps(facts["checks"]))
     common.say("window " + json.dumps(facts["window"]))
+    if facts.get("routing"):  # what routing the run timed (ft_sync says where it reads)
+        common.say("routing " + json.dumps(facts["routing"]))
     for name, (number, limit) in result["compared"].items():
         print(f"compared {name} {number} limit {limit}", file=sys.stderr, flush=True)
     if args.rehearse:

@@ -38,7 +38,8 @@ def cfg():
     assert (entry["chips"], entry["traffic"]) == (1, "ft-sync-1")
     for name in READERS:
         metric = next(m for m in contract["per_layer"] if m["name"] == name)
-        assert metric["workloads"] == ["mellum2-ft1"] and metric["moves"] == "step_p90_ms"
+        # since PR 42 the cell's step is held by a metric of its own
+        assert metric["workloads"] == ["mellum2-ft1"] and metric["moves"] == "step_p90_routed_ms"
     return family.build(entry["sizes"])
 
 
@@ -82,11 +83,25 @@ def test_the_seeded_weights_are_the_programs_but_for_the_routers_spread():
 def test_the_cell_is_on_every_accepted_metric_whose_layer_it_runs():
     contract, _ = common.load_cell("mellum2-ft1")
     listed = {m["name"] for m in contract["per_layer"] if "mellum2-ft1" in m.get("workloads", ())}
-    assert listed == set(READERS) | {
+    # the metrics it shares with other cells under the name that moves ITS
+    # end-to-end metric (PR 42: ``<name>.routed``, read by ``<name>``'s file)
+    shared = {
         "quorum_ms", "commit_vote_ms", "ft_over_raw", "optimizer_step_host_ms",
         "quorum_wait_ms", "exposed_wait_ms", "flash_fwd_ms", "flash_bwd_ms",
-        "forward_ms", "backward_ms", "optimizer_ms",
+        "forward_ms", "backward_ms", "optimizer_ms", "step_median_ms",
+        "step_device_ms", "mfu", "flash_roofline", "peak_hbm_gb", "window_tokens_per_s",
     }
+    assert listed == set(READERS) | {name + ".routed" for name in shared}
+    by_name = {m["name"]: m for m in contract["per_layer"]}
+    for name in shared:
+        ours, theirs = by_name[name + ".routed"], by_name[name]
+        assert ours["workloads"] == ["mellum2-ft1"] and "mellum2-ft1" not in theirs["workloads"]
+        assert (ours["moves"], theirs["moves"]) == ("step_p90_routed_ms", "step_p90_ms")
+        assert all(ours[k] == theirs[k] for k in ("unit", "better", "source", "layer"))
+    e2e = {m["name"]: m for m in contract["end_to_end"]}
+    assert e2e["step_p90_routed_ms"]["workloads"] == ["mellum2-ft1"]
+    assert "mellum2-ft1" not in e2e["step_p90_ms"]["workloads"] and len(e2e["step_p90_ms"]["workloads"]) == 5
+    assert e2e["step_p90_ms"]["bound"] == 0.01 and "workloads" not in e2e["setup_s"]
 
 
 def test_parameters(cfg):
@@ -130,9 +145,11 @@ def test_held_expert_matmuls(cfg):
     assert (e["calls"], e["rows"]) == (36, 16384)
     assert e["flops"] == 36 * 2 * 16384 * 2304 * 896  # 2.44 TFLOP a step
     assert e["bytes"] == 36 * 2 * (16384 * 2304 + 16384 * 896 + 8 * 2304 * 896) == 4_963_958_784
-    # the dense form multiplies every position by all 8 held experts:
-    # 64 / 8 times the requirement
-    assert e["computed_flops"] == 8 * e["flops"]
+    # its terms, for a reader that knows the rows a run realised
+    assert e["flops_per_row"] * e["rows"] == e["flops"]
+    assert e["bytes_per_row"] * e["rows"] + e["bytes_weights"] == e["bytes"]
+    # what the share multiplies since PR 40 follows the routing: not stated
+    assert "computed_flops" not in e
     # compute-bound at 2,048 rows an expert: 12.36 ms against 6.06 ms
     assert e["flops"] / V5E["bf16_flops_per_s"] > e["bytes"] / V5E["hbm_bytes_per_s"]
 
@@ -148,8 +165,8 @@ def test_flash_calls(cfg):
     assert kinds["sliding"]["flops"] == 2 * 3 * 32 * 12 * 128 * 7_864_832 == 2_319_433_334_784
     layer_bytes = 2 * (402_653_184 + 50_331_648 + 2_097_152)
     assert kinds["full"]["bytes"] == layer_bytes and kinds["sliding"]["bytes"] == 3 * layer_bytes
-    # the step's only Mosaic calls: two flash kernels a layer (the dense
-    # share is plain matmuls)
+    # the step's only Mosaic calls: two flash kernels a layer (the held
+    # share is plain XLA)
     flash = family.flash_calls(cfg, BATCH, SEQ)
     assert flash["calls"] == family.lowered_mosaic_calls(cfg) == 8
     assert flash["flops"] == 3_298_937_536_512 + 2_319_433_334_784
@@ -190,7 +207,12 @@ def test_the_readers_on_facts_built_by_hand(cfg):
     # sliding: 2.3194 TFLOP at 197 TFLOP/s is 11.774 ms of the 24 traced
     assert ms("attn_sliding_flash_roofline") == pytest.approx(100 * 11.7738 / 24.0, rel=1e-4)
     assert ms("attn_full_flash_roofline") == pytest.approx(100 * 16.7459 / 20.0, rel=1e-4)
-    assert ms("moe_held_expert_roofline") == pytest.approx(100 * 12.3617 / 12.0, rel=1e-4)
+    # the held share's is over the claims the run counted around its traced
+    # steps (test_mellum_routing.py), and not read where it counted none
+    assert ms("moe_held_expert_roofline") is None
+    around = {end: {"held_claims": [0.5] * 8} for end in ("traced", "open")}
+    facts = dict(facts, routing=around, trace=dict(facts["trace"], batches=[0, 1, 2, 3, 4]))
+    assert ms("moe_held_expert_roofline") == pytest.approx(100 * 12.3617 / 2 / 12.0, rel=1e-4)
 
 
 @pytest.mark.parametrize("name", READERS)

@@ -158,6 +158,7 @@ def collect(
         "flash": lead["flash"],
         "family": lead["family"],
         "trace": lead.get("trace"),
+        "routing": lead.get("routing"),
         "reference": lead["reference"],
         "manager_metrics": lead["manager_metrics"],
         "op_stats": lead["op_stats"],
@@ -256,6 +257,12 @@ def worker(cell: Dict[str, Any]) -> None:
     # the measured program's own gradient of step 0, for the reference
     grad_norm0 = jax.jit(common.tree_norm)(grads0) if leader and life == 0 else None
     del grads0
+    # what routing the run times, where the family can say: read on the
+    # state after the window's close and, in a traced run, on both sides of
+    # the traced steps (the reader says why an untraced run reads no more)
+    read_routing = None
+    if leader and life == 0 and hasattr(family, "routing"):
+        read_routing = _routing_reader(family, cfg, state, batches)
     phases.mark("compile_or_cache_load")
     ready_t = time.monotonic()  # a backend and a compiled step in hand
 
@@ -328,6 +335,7 @@ def worker(cell: Dict[str, Any]) -> None:
     # -- warm-up: the window opens at the stamp of its last step -----------
     trace = None
     open_at = None
+    routing: Dict[str, Any] = {}
     if life == 0:
         first = time.monotonic()
         strong = 0
@@ -337,6 +345,9 @@ def worker(cell: Dict[str, Any]) -> None:
                 raise RuntimeError("warm-up never reached full strength")
         if cell["trace"]:
             log.drain()
+            if read_routing:
+                routing["traced"] = read_routing()
+            traced_from = len(log.records)
             if tracer:
                 tracer.start()
             for _ in range(p["trace_steps"]):
@@ -347,6 +358,11 @@ def worker(cell: Dict[str, Any]) -> None:
                 trace = tracer.reduce()
                 if trace:
                     trace["steps"] = p["trace_steps"]
+                    trace["batches"] = [
+                        (traced_from + j) % len(batches) for j in range(p["trace_steps"])
+                    ]
+            if read_routing:
+                routing["open"] = read_routing()
             ft_step()
         collectives.pop_op_stats()  # the window's own entries from here
         open_at = len(log.records) - 1
@@ -394,6 +410,8 @@ def worker(cell: Dict[str, Any]) -> None:
     final_step = manager.current_step()
     digest = tree_digest(state.params)
     memory_peak = common.peak_memory_bytes()
+    if read_routing:
+        routing["close"] = read_routing()
     op_stats = [
         {k: v for k, v in s.items() if k != "buckets"}
         for s in collectives.pop_op_stats() if s.get("op") == "allreduce"
@@ -434,6 +452,7 @@ def worker(cell: Dict[str, Any]) -> None:
         "flops_per_step": family.flops_per_step(cfg, batch, seq),
         "flash": family.flash_calls(cfg, batch, seq),
         "family": family.facts(cfg, batch, seq),
+        "routing": routing or None,
     }
     if open_at is None:
         del closing["open_at"]
@@ -478,6 +497,28 @@ def _raw_after(
             for a, b in pairs
         ),
     }
+
+
+def _routing_reader(family: Any, cfg: Any, state: Any, batches: List[Any]) -> Any:
+    """``() -> {name: numbers}``: the family's ``routing`` of the state as
+    it stands when called, over the pool's batches, as one jitted program;
+    blocks until the numbers are on the host. The program is built at the
+    first call. An untraced run, the only kind that reports ``setup_s``,
+    makes that call after its window has closed, beside the reference's: in
+    set-up the program's load and one read before the window cost 2.0 s of
+    26.8, three quarters of what ``setup_s`` may move (PERF.md section 6, PR
+    42). A traced run calls it first in its warm-up, before the traced steps."""
+    import jax
+    import jax.numpy as jnp
+
+    program = jax.jit(lambda params, pool: family.routing(cfg, params, jnp.stack(pool)))
+
+    def read() -> Dict[str, Any]:
+        return {
+            k: v.tolist() for k, v in jax.device_get(program(state.params, batches)).items()
+        }
+
+    return read
 
 
 def _read_float(path: str) -> float:
