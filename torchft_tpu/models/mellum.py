@@ -24,7 +24,7 @@ For activations ``x`` (B, S, 2304), per layer, pre-norm, RMSNorm eps
   loss, no z-loss.
 
 A rank of an expert-parallel deployment holds ``held_experts`` of each
-layer (``olmoe._held_dense``) and its rows of the vocabulary.
+layer (``olmoe._held_share``) and its rows of the vocabulary.
 """
 
 from __future__ import annotations

@@ -65,7 +65,27 @@ file (``models/mellum.py`` is one):
   form is chosen by hand; ``moe_layer``'s ``held_dense_layers`` is the
   share of the layer's held experts that were applied to every token.
   ``_held_dense`` - every held expert on every token - is what this
-  replaced in the step and what the tests hold it to.
+  replaced in the step and what the tests hold it to;
+- the block's other variations: a feed-forward KIND per layer
+  (``dense_ff``: the experts, or one dense SwiGLU of a stated width,
+  ``W_down (silu(W_gate x) * W_up x)``, under the scope ``mlp`` alone),
+  attention without QK-norm (``qk_norm``), and a sandwich of norms
+  (``sandwich_norms``): ``h = x + N2(Attn(N1(x)))``, ``y = h + N4(FF(N3(h)))``;
+- a LOOPED stack (``passes`` = T > 1; ``models/ouro.py`` is one): the
+  layers run T times on the SAME weights, ``u_t = Stack(h_{t-1})``, ``h_0``
+  the embedding; the final norm closes every pass and its OUTPUT is what
+  the next pass starts from, ``h_t = RMSNorm_f(u_t)``. Every pass ends in
+  an exit: logits ``z_t = h_t W_out`` (the one readout) and a gate ``g_t =
+  sigmoid(w_g . h_t + b_g)``. A position leaves at exit ``t`` with
+  probability ``p_t = g_t prod_{j<t} (1 - g_j)``, at the last with what
+  is left, ``p_T = prod_{j<T} (1 - g_j)``, and the training loss is the
+  mean over positions of ``sum_t p_t CE_t - exit_entropy_coef x H(p)``,
+  ``CE_t`` the next-token cross entropy of exit ``t`` and ``H`` the
+  entropy of ``p`` over the T exits, in float32 (plus the router losses
+  over every pass where a layer has experts). ``_looped`` says how it
+  runs: one ``lax.scan`` over the passes, each pass recomputed in the
+  backward pass, the weights' gradient summed over the passes in float32.
+  T = 1 is the plain model above: no gate, no loop.
 """
 
 from __future__ import annotations
@@ -74,13 +94,13 @@ import contextlib
 import functools
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops import flash_attention
-from .transformer import _dense_init, _rmsnorm, next_token_loss
+from .transformer import _dense_init, _rmsnorm, next_token_loss, next_token_losses
 
 
 @dataclass(frozen=True)
@@ -137,6 +157,11 @@ class OlmoeConfig:
     layer_kinds: Optional[Tuple[AttentionKind, ...]] = None  # all unnamed
     renormalize_top_k: bool = False
     held_experts: Optional[Tuple[int, int]] = None  # (first, count): all
+    qk_norm: bool = True  # False: q and k go to the rotary embedding as projected
+    dense_ff: Optional[Tuple[Optional[int], ...]] = None  # a layer's dense width; None: experts
+    sandwich_norms: bool = False
+    passes: int = 1  # T: how many times the stack runs, on the same weights
+    exit_entropy_coef: float = 0.0  # what the entropy of the exits is worth (T > 1)
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -146,8 +171,10 @@ class OlmoeConfig:
         first, count = self.held
         if self.n_heads % self.kv_heads:
             raise ValueError("n_heads is no multiple of n_kv_heads")
-        if len(self.kinds) != self.n_layers:
-            raise ValueError("layer_kinds names another number of layers than n_layers")
+        if len(self.kinds) != self.n_layers or len(self.ff) != self.n_layers:
+            raise ValueError("layer_kinds or dense_ff names another number of layers than n_layers")
+        if self.passes < 1:
+            raise ValueError("the stack runs at least once")
         if first < 0 or count < 1 or first + count > self.n_experts:
             raise ValueError(f"held_experts {self.held_experts} lie outside the {self.n_experts}")
 
@@ -158,6 +185,15 @@ class OlmoeConfig:
     @property
     def kinds(self) -> Tuple[AttentionKind, ...]:
         return self.layer_kinds or (AttentionKind(),) * self.n_layers
+
+    @property
+    def ff(self) -> Tuple[Optional[int], ...]:
+        """Per layer, the width of its dense SwiGLU, or None for experts."""
+        return self.dense_ff or (None,) * self.n_layers
+
+    @property
+    def expert_layers(self) -> int:
+        return sum(width is None for width in self.ff)
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -185,32 +221,51 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
         return jnp.ones((width,), jnp.float32)
 
     blocks = []
-    for i in range(cfg.n_layers):
+    for i, width in enumerate(cfg.ff):
         bk = jax.random.split(keys[2 + i], 8)
-        blocks.append({
+        block = {
             "ln1": {"scale": ones()},
             "attn": {
                 "wq": _dense_init(bk[0], (d, q_width), scale),
                 "wk": _dense_init(bk[1], (d, kv_width), scale),
                 "wv": _dense_init(bk[2], (d, kv_width), scale),
                 "wo": _dense_init(bk[3], (q_width, d), q_width ** -0.5),
-                "q_norm": ones(cfg.head_dim if cfg.qk_norm_per_head else q_width),
-                "k_norm": ones(cfg.head_dim if cfg.qk_norm_per_head else kv_width),
             },
             "ln2": {"scale": ones()},
-            "moe": {
+        }
+        if cfg.qk_norm:
+            block["attn"].update(
+                q_norm=ones(cfg.head_dim if cfg.qk_norm_per_head else q_width),
+                k_norm=ones(cfg.head_dim if cfg.qk_norm_per_head else kv_width),
+            )
+        if cfg.sandwich_norms:  # the second norm of each sublayer
+            block.update(ln1_post={"scale": ones()}, ln2_post={"scale": ones()})
+        if width is None:
+            block["moe"] = {
                 "router": _dense_init(bk[4], (d, e), scale),
                 "w_gate": _dense_init(bk[5], (held, d, f), scale),
                 "w_up": _dense_init(bk[6], (held, d, f), scale),
                 "w_down": _dense_init(bk[7], (held, f, d), f ** -0.5),
-            },
-        })
-    return {
+            }
+        else:
+            block["mlp"] = {
+                "w_gate": _dense_init(bk[5], (d, width), scale),
+                "w_up": _dense_init(bk[6], (d, width), scale),
+                "w_down": _dense_init(bk[7], (width, d), width ** -0.5),
+            }
+        blocks.append(block)
+    params = {
         "embed": _dense_init(keys[0], (cfg.vocab_size, d), scale),
         "blocks": blocks,
         "ln_f": {"scale": ones()},
         "readout": _dense_init(keys[1], (d, cfg.vocab_size), scale),
     }
+    if cfg.passes > 1:  # the exits' gate: one map of d to 1, with a bias
+        gate_key = jax.random.fold_in(key, 2 + cfg.n_layers)
+        params["exit_gate"] = {
+            "w": _dense_init(gate_key, (d,), scale), "b": jnp.zeros((), jnp.float32),
+        }
+    return params
 
 
 def _yarn_ramp(yarn: Yarn, theta: float, head_dim: int) -> jax.Array:
@@ -254,11 +309,12 @@ def attention(
     B, S, _ = x.shape
     h, kv, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     q, k, v = (x @ p[w].astype(cfg.dtype) for w in ("wq", "wk", "wv"))
-    with jax.named_scope("qk_norm"):
-        if cfg.qk_norm_per_head:  # each head's own dh, one scale for all
-            q, k = q.reshape(B, S, h, dh), k.reshape(B, S, kv, dh)
-        q = _rmsnorm(q, p["q_norm"], cfg.rms_norm_eps)
-        k = _rmsnorm(k, p["k_norm"], cfg.rms_norm_eps)
+    if cfg.qk_norm:
+        with jax.named_scope("qk_norm"):
+            if cfg.qk_norm_per_head:  # each head's own dh, one scale for all
+                q, k = q.reshape(B, S, h, dh), k.reshape(B, S, kv, dh)
+            q = _rmsnorm(q, p["q_norm"], cfg.rms_norm_eps)
+            k = _rmsnorm(k, p["k_norm"], cfg.rms_norm_eps)
     q, k, v = (t.reshape(B, S, n, dh) for t, n in ((q, h), (k, kv), (v, kv)))
     with jax.named_scope("rope"):
         q, k = (rope(t, cfg.rope_theta, kind.yarn) for t in (q, k))
@@ -690,36 +746,131 @@ def moe_layer(
     return y.reshape(B, S, D).astype(x.dtype), stats
 
 
+def dense_mlp(cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array) -> jax.Array:
+    """``W_down (silu(W_gate x) * W_up x)``: a layer's dense SwiGLU."""
+    hidden = jax.nn.silu(x @ p["w_gate"].astype(cfg.dtype)) * (x @ p["w_up"].astype(cfg.dtype))
+    return hidden @ p["w_down"].astype(cfg.dtype)
+
+
 def _block(
     cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array,
-    kind: AttentionKind = AttentionKind(),
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    kind: AttentionKind = AttentionKind(), width: Optional[int] = None,
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     # the dense family's scope names (transformer._block) with the new
     # mechanisms nested in them: attn/qk_norm, attn/rope, mlp/moe/router,
     # mlp/moe/dispatch, mlp/moe/experts, mlp/moe/combine; a named kind of
-    # layer puts its name between: attn/sliding/rope, attn/full/flash_fwd.
+    # layer puts its name between: attn/sliding/rope, attn/full/flash_fwd;
+    # a dense feed-forward (``width``) is ``mlp`` alone and has no sums.
     # Metadata only.
     eps = cfg.rms_norm_eps
+
+    def second(y: jax.Array, name: str) -> jax.Array:
+        """A sublayer's output through the sandwich's second norm."""
+        return _rmsnorm(y, p[name]["scale"], eps) if cfg.sandwich_norms else y
+
     of_kind = jax.named_scope(kind.name) if kind.name else contextlib.nullcontext()
     with jax.named_scope("attn"), of_kind:
-        x = x + attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"], eps), kind)
-    with jax.named_scope("mlp"), jax.named_scope("moe"):
-        y, stats = moe_layer(cfg, p["moe"], _rmsnorm(x, p["ln2"]["scale"], eps))
-        return x + y, stats
+        y = attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"], eps), kind)
+        x = x + second(y, "ln1_post")
+    with jax.named_scope("mlp"):
+        if width is not None:
+            y = dense_mlp(cfg, p["mlp"], _rmsnorm(x, p["ln2"]["scale"], eps))
+            return x + second(y, "ln2_post"), None
+        with jax.named_scope("moe"):
+            y, stats = moe_layer(cfg, p["moe"], _rmsnorm(x, p["ln2"]["scale"], eps))
+            return x + second(y, "ln2_post"), stats
+
+
+def _stack(
+    cfg: OlmoeConfig, blocks: Any, x: jax.Array
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """The layers in order: (B, S, D) -> (the last block's output, the
+    routers' sums over the layers that have experts; None where none has)."""
+    total = None
+    for kind, width, p in zip(cfg.kinds, cfg.ff, blocks):
+        x, stats = _block(cfg, p, x, kind, width)
+        if stats is not None:
+            total = stats if total is None else jax.tree_util.tree_map(jnp.add, total, stats)
+    return x, total
+
+
+def _embed(cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
+    with jax.named_scope("embed"):
+        return params["embed"].astype(cfg.dtype)[tokens]
 
 
 def _hidden(
     cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """tokens (B, S) int32 -> (the last block's output (B, S, D), the
-    router's sums over every layer and token)."""
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
-    total = None
-    for kind, p in zip(cfg.kinds, params["blocks"]):
-        x, stats = _block(cfg, p, x, kind)
-        total = stats if total is None else jax.tree_util.tree_map(jnp.add, total, stats)
-    return x, total
+    router's sums over every layer and token), the stack run once."""
+    return _stack(cfg, params["blocks"], _embed(cfg, params, tokens))
+
+
+def _looped(
+    cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array,
+    exit_of: Callable[[jax.Array], Any],
+) -> Tuple[jax.Array, Any, Optional[Dict[str, jax.Array]]]:
+    """The stack ``cfg.passes`` times on the same weights. Returns, each
+    with the passes as its first axis, the gates' logits (T, B, S) in
+    float32 and ``exit_of(h_t)``, what the caller wants of every exit -
+    computed INSIDE the pass, so that a pass's logits do not outlive it -
+    and the routers' sums over every pass and layer (None without experts).
+
+    One ``lax.scan`` over the passes with the weights closed over: the
+    compiled program holds one stack however many times it runs. The body
+    is under ``jax.checkpoint``: the backward pass keeps ``h_t`` alone
+    between the passes and computes one pass's activations again when it
+    comes to it, so the memory is one pass's whatever T is. The weights'
+    gradient is the sum over the passes, and a scan sums the cotangent of
+    what it closes over in that value's own type; the stack's weights are
+    therefore handed in widened to float32 (a bf16 compute copy comes back
+    as it was at every use, ``astype(cfg.dtype)``), each pass's gradient is
+    added in float32 and the sum rounded once, as a framework that keeps
+    float32 ``.grad`` under bf16 autocast sums it. What ``exit_of`` closes
+    over - the readout - is NOT widened: its T contributions are added in
+    the type it comes in, bf16 for a bf16 compute copy (widened too, the
+    gradient program of ``ouro-2.6b-l6`` takes 7.61 GB of temporaries for
+    7.00 by the compiler's memory analysis, PR 43). Scopes: ``loop`` holds
+    the scan, the layers' ``attn`` and ``mlp`` inside it as ever, with
+    ``exits`` (the gate) and whatever ``exit_of`` names."""
+    blocks, ln_f, gate = jax.tree_util.tree_map(
+        lambda w: w.astype(jnp.float32),
+        (params["blocks"], params["ln_f"]["scale"], params["exit_gate"]),
+    )
+
+    def one_pass(h: jax.Array, _: None) -> Tuple[jax.Array, Any]:
+        u, stats = _stack(cfg, blocks, h)
+        h = _rmsnorm(u, ln_f, cfg.rms_norm_eps)
+        with jax.named_scope("exits"):
+            logit = jnp.einsum(
+                "bsd,d->bs", h, gate["w"].astype(h.dtype),
+                preferred_element_type=jnp.float32,
+            ) + gate["b"]
+        return h, (logit, exit_of(h), stats)
+
+    h = _embed(cfg, params, tokens)
+    with jax.named_scope("loop"):
+        _, (logits, exits, stats) = jax.lax.scan(
+            # a scan's body is not CSE'd with its backward pass
+            jax.checkpoint(one_pass, prevent_cse=False), h, None, length=cfg.passes,
+        )
+    if stats is not None:
+        stats = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), stats)
+    return logits, exits, stats
+
+
+def exit_log_probs(gate_logits: jax.Array) -> jax.Array:
+    """``log p_t`` (T, ...) of the exit distribution from the gates' logits
+    ``a_t`` (T, ...), float32: ``p_t = g_t prod_{j<t} (1 - g_j)`` below the
+    last exit, which takes what is left, ``p_T = prod_{j<T} (1 - g_j)``; its
+    own gate is not asked. In logarithms, ``log g = log_sigmoid(a)`` and
+    ``log (1 - g) = log_sigmoid(-a)``, so a gate driven to 0 or 1 gives a
+    small number and no ``log 0``."""
+    asked = gate_logits[:-1]
+    none = jnp.zeros_like(gate_logits[:1])
+    stayed = jnp.concatenate([none, jnp.cumsum(jax.nn.log_sigmoid(-asked), axis=0)])
+    return stayed + jnp.concatenate([jax.nn.log_sigmoid(asked), none])
 
 
 def _readout_product(
@@ -736,36 +887,77 @@ def forward(
     cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """tokens (B, S) int32 -> (logits (B, S, vocab) f32, the router's
-    sums over every layer and token)."""
-    x, total = _hidden(cfg, params, tokens)
-    with jax.named_scope("readout"):
-        logits = _readout_product(cfg, params, x).astype(jnp.float32)
-    return logits, total
+    sums over every layer and token). A looped model (``cfg.passes`` = T >
+    1) gives every exit's logits, (T, B, S, vocab), and with the sums
+    ``exit_probs`` (T,): the exit distribution's mean over the positions."""
+    if cfg.passes == 1:
+        x, total = _hidden(cfg, params, tokens)
+        with jax.named_scope("readout"):
+            logits = _readout_product(cfg, params, x).astype(jnp.float32)
+        return logits, total
+
+    def exit_of(h: jax.Array) -> jax.Array:  # the pass's norm is the final norm
+        with jax.named_scope("readout"):
+            return (h @ params["readout"].astype(cfg.dtype)).astype(jnp.float32)
+
+    gates, logits, total = _looped(cfg, params, tokens, exit_of)
+    with jax.named_scope("exits"):
+        exit_probs = jnp.mean(jnp.exp(exit_log_probs(gates)), axis=(1, 2))
+    return logits, dict(total or {}, exit_probs=exit_probs)
 
 
 def aux_losses(
     cfg: OlmoeConfig, stats: Dict[str, jax.Array], n_tokens: int
 ) -> Tuple[jax.Array, jax.Array]:
     """(balance loss, router z-loss) from the router's sums over
-    ``n_tokens`` tokens a layer."""
-    n = float(n_tokens * cfg.n_layers)
+    ``n_tokens`` tokens a layer with experts and pass."""
+    n = float(n_tokens * cfg.expert_layers * cfg.passes)
     balance = cfg.n_experts * jnp.sum((stats["claims"] / n) * (stats["probs"] / n))
     return balance, stats["z"] / n
+
+
+def _exits_loss(
+    cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """A looped model's loss over its exits (module docstring) and the
+    routers' sums: each pass hands out its exit's cross entropy a position
+    (``next_token_losses``: the readout's product unwidened, read once)
+    and its gate's logit, two (B, S) float32 arrays."""
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+
+    def exit_of(h: jax.Array) -> jax.Array:
+        with jax.named_scope("readout"):
+            logits = h @ params["readout"].astype(cfg.dtype)
+        return next_token_losses(logits, targets)
+
+    gates, nll, stats = _looped(cfg, params, inputs, exit_of)
+    with jax.named_scope("exits"):
+        log_p = exit_log_probs(gates)
+        p = jnp.exp(log_p)
+        expected = jnp.sum(p * nll, axis=0)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        return jnp.mean(expected - cfg.exit_entropy_coef * entropy), stats
 
 
 def loss_fn(
     cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
 ) -> jax.Array:
-    """Next-token cross entropy + the two weighted router losses. The
-    loss reads the readout's product in ``cfg.dtype``, unwidened
-    (``next_token_loss``)."""
+    """Next-token cross entropy - over the exits, where the model is looped
+    (``_exits_loss``) - + the two weighted router losses where a layer has
+    experts. The loss reads the readout's product in ``cfg.dtype``,
+    unwidened (``next_token_loss``)."""
     inputs = tokens[:, :-1]
-    x, stats = _hidden(cfg, params, inputs)
-    with jax.named_scope("readout"):
-        logits = _readout_product(cfg, params, x)
-    with jax.named_scope("loss"), jax.named_scope("aux"):
-        balance, z = aux_losses(cfg, stats, inputs.size)
-    return (
-        next_token_loss(logits, tokens[:, 1:])
-        + cfg.balance_coef * balance + cfg.z_coef * z
-    )
+    if cfg.passes > 1:
+        loss, stats = _exits_loss(cfg, params, tokens)
+    else:
+        x, stats = _hidden(cfg, params, inputs)
+        with jax.named_scope("readout"):
+            logits = _readout_product(cfg, params, x)
+    if stats is not None:  # a layer has experts
+        with jax.named_scope("loss"), jax.named_scope("aux"):
+            balance, z = aux_losses(cfg, stats, inputs.size)
+    if cfg.passes == 1:  # after the router losses, where it has always been traced
+        loss = next_token_loss(logits, tokens[:, 1:])
+    if stats is None:
+        return loss
+    return loss + cfg.balance_coef * balance + cfg.z_coef * z

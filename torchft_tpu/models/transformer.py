@@ -227,36 +227,67 @@ def mlp_apply(
     return h @ p["wo"].astype(cfg.dtype)
 
 
-@jax.custom_vjp
-def _cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
-    return _cross_entropy_fwd(logits, targets)[0]
-
-
-def _cross_entropy_fwd(logits: jax.Array, targets: jax.Array) -> Any:
+def _position_losses(logits: jax.Array, targets: jax.Array) -> Any:
+    """One sweep over the logits: each position's cross entropy in float32,
+    and what the backward pass wants of it (the row maximum, the float32
+    sum of exponentials)."""
     f32 = jnp.float32
     # a maximum rounds nothing, so it is taken in the logits' own type
     top = jnp.max(logits, axis=-1, keepdims=True)
     total = jnp.sum(jnp.exp(logits.astype(f32) - top.astype(f32)), axis=-1)
     lse = top[..., 0].astype(f32) + jnp.log(total)
     picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - picked.astype(f32)), (logits, targets, top, total)
+    return lse - picked.astype(f32), top, total
 
 
-def _cross_entropy_bwd(res: Any, g: jax.Array) -> Any:
+def _softmax_less_target(res: Any, scale: jax.Array) -> jax.Array:
+    """``(softmax(logits) - onehot(targets)) * scale`` in the logits' type,
+    ``scale`` one number or one a position."""
     # probabilities as exp(l - max) / sum, not exp(l - lse): normalised by
     # the sum itself a row adds up to 1 whatever the device's log rounds to
     logits, targets, top, total = res
     f32 = jnp.float32
-    scale = g / total.size
     hit = (
         jax.lax.broadcasted_iota(targets.dtype, logits.shape, logits.ndim - 1)
         == targets[..., None]
     )
     grad = jnp.exp(logits.astype(f32) - top.astype(f32)) * (scale / total)[..., None]
-    return (grad - jnp.where(hit, scale, 0.0)).astype(logits.dtype), None
+    at_hit = scale[..., None] if scale.ndim else scale
+    return (grad - jnp.where(hit, at_hit, 0.0)).astype(logits.dtype)
+
+
+@jax.custom_vjp
+def _cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    return _cross_entropy_fwd(logits, targets)[0]
+
+
+def _cross_entropy_fwd(logits: jax.Array, targets: jax.Array) -> Any:
+    losses, top, total = _position_losses(logits, targets)
+    return jnp.mean(losses), (logits, targets, top, total)
+
+
+def _cross_entropy_bwd(res: Any, g: jax.Array) -> Any:
+    return _softmax_less_target(res, g / res[3].size), None
 
 
 _cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
+
+
+@jax.custom_vjp
+def _cross_entropies(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    return _position_losses(logits, targets)[0]
+
+
+def _cross_entropies_fwd(logits: jax.Array, targets: jax.Array) -> Any:
+    losses, top, total = _position_losses(logits, targets)
+    return losses, (logits, targets, top, total)
+
+
+def _cross_entropies_bwd(res: Any, g: jax.Array) -> Any:
+    return _softmax_less_target(res, g), None
+
+
+_cross_entropies.defvjp(_cross_entropies_fwd, _cross_entropies_bwd)
 
 
 def next_token_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -274,6 +305,16 @@ def next_token_loss(logits: jax.Array, targets: jax.Array) -> jax.Array:
     logits' shape is stored unless the caller's logits are float32."""
     with jax.named_scope("loss"):
         return _cross_entropy(logits, targets)
+
+
+def next_token_losses(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """Each position's cross entropy (...), float32: ``next_token_loss``
+    before its mean, by the same sweep and under the same rules - the
+    logits read and kept in the type they came in, the cotangent (one
+    number a position) back in that type. For a loss that weights the
+    positions (``olmoe.loss_fn`` over a looped model's exits)."""
+    with jax.named_scope("loss"):
+        return _cross_entropies(logits, targets)
 
 
 def attn_sublayer_specs() -> Dict[str, Any]:
