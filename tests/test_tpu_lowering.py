@@ -46,6 +46,23 @@ def test_flash_forward_and_backward_lower(head_dim, seq, window):
     assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 2
 
 
+# (L, B): the benchmark's shape; a block that is no power of two (a vector
+# division in the mask) on tiles that a copy's end and a block straddle; one
+# block a copy; a padded length
+@pytest.mark.parametrize("length,block", [(4096, 4), (960, 6), (1024, 1024), (200, 8)])
+def test_block_mask_forward_and_backward_lower(length, block):
+    q = jnp.ones((2, 2 * length, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=False, block_mask=(block, length), interpret=False
+        )
+        return out.astype(jnp.float32).sum()
+
+    assert _mosaic_calls(loss, q, q, q) == 1
+    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 2
+
+
 # one element, a single ragged block, exactly one block, the big model's
 # 1024x4096 leaf (128 blocks), and an odd multi-block length
 @pytest.mark.parametrize(
@@ -175,11 +192,19 @@ TINY_FROM_HERE = {
         "sliding_window": 512, "batch": 2, "seq": 1025,
         "published": {"num_hidden_layers": 28, "num_experts": 4, "vocab_size": 2048},
     },
+    # two layers under the block mask, 512 tokens a sequence (1,024 positions)
+    "sdar-30b-a3b-l4-ep8": {
+        "vocab_size": 256, "hidden_size": 256, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "num_hidden_layers": 2, "num_experts": 2,
+        "num_experts_per_tok": 2, "moe_intermediate_size": 128, "batch": 2, "seq": 512,
+        "published": {"num_hidden_layers": 48, "num_experts": 4, "vocab_size": 2048},
+    },
 }
 
 
 @pytest.mark.parametrize(
-    "config", ["gpt2-small", "olmoe-1b-7b-l1", "mellum2-12b-a2.5b-l4-ep8"]
+    "config",
+    ["gpt2-small", "olmoe-1b-7b-l1", "mellum2-12b-a2.5b-l4-ep8", "sdar-30b-a3b-l4-ep8"],
 )
 def test_the_lowered_gradient_holds_what_the_family_states(config, monkeypatch):
     """The benchmark's own case (``benchmark/tests``, not part of tier-1

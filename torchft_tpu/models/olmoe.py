@@ -87,6 +87,28 @@ file (``models/mellum.py`` is one):
   backward pass but for what ``KEPT`` names (a dense layer's down
   product), the weights' gradient summed over the passes in float32.
   T = 1 is the plain model above: no gate, no loop.
+- BLOCK-DIFFUSION training (``diffusion_block`` = B; ``models/sdar.py`` is
+  one; Arriola et al., arXiv:2503.09573): the loss is not next-token. A
+  step draws, a sequence ``x`` of L tokens, ``t ~ U[noise_floor, 1]`` and
+  ``m_i ~ Bernoulli(t)`` a position (``_noise``: from the BATCH, below),
+  and runs the stack on 2 L positions, ``x`` whole (the clean copy) and
+  after it ``x~`` with ``mask_token_id`` where ``m_i`` (the noised copy),
+  both at rotary positions 0..L-1 (``rope`` at stated positions), under
+  the block mask of ``flash_attention(block_mask=(B, L))``: a clean query
+  sees the clean keys of its own and earlier blocks, a noised query the
+  clean keys of EARLIER blocks and the noised keys of its own
+  (``AttentionKind.block``; scope ``attn/block``). The readout runs over
+  the noised copy alone, and the loss is ``(1 / (batch L)) sum_seq (1 / t)
+  sum_i m_i CE(z_i, x_i)`` - position i's OWN token, no shift, the masked
+  positions alone (``masked_token_loss``) - plus the router losses over
+  the 2 L positions. ``forward`` gives the noised copy's logits and, with
+  the routers' sums, ``masked_share``. The noise is a function of the
+  batch: its key is ``fold_in(PRNGKey(noise_seed), checksum(tokens))``, so
+  the step stays a pure function of (state, batch) - a healed group, a
+  step tried again after an abort, the raw loop and a reference all draw
+  the same ``(t, m)`` for the same tokens, and nothing rides the train
+  state or a checkpoint. Other noise for a batch seen again is another
+  ``noise_seed``.
 """
 
 from __future__ import annotations
@@ -102,7 +124,13 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import flash_attention
-from .transformer import _dense_init, _rmsnorm, next_token_loss, next_token_losses
+from .transformer import (
+    _dense_init,
+    _rmsnorm,
+    masked_token_loss,
+    next_token_loss,
+    next_token_losses,
+)
 
 
 @dataclass(frozen=True)
@@ -128,11 +156,15 @@ class AttentionKind:
     k_pos < window``) or all of them, and YaRN's blend of the rotary
     frequencies or the plain ones. ``name`` is the ``jax.named_scope`` the
     layer's attention runs under, inside ``attn``; the unnamed kind is
-    OLMoE's and adds no scope."""
+    OLMoE's and adds no scope. ``block`` = B: the layer's input is a clean
+    and a noised copy of a sequence, L positions each, both at rotary
+    positions 0..L-1, under the block-diffusion mask in blocks of B
+    (``flash_attention``: ``block_mask``) and no causal one."""
 
     name: Optional[str] = None
     window: Optional[int] = None
     yarn: Optional[Yarn] = None
+    block: Optional[int] = None
 
 
 @dataclass(frozen=True)
@@ -164,6 +196,11 @@ class OlmoeConfig:
     sandwich_norms: bool = False
     passes: int = 1  # T: how many times the stack runs, on the same weights
     exit_entropy_coef: float = 0.0  # what the entropy of the exits is worth (T > 1)
+    # block-diffusion training (module docstring); None: next-token training
+    diffusion_block: Optional[int] = None  # B, which every layer's kind carries too
+    mask_token_id: Optional[int] = None  # what a noised position holds
+    noise_seed: int = 0  # with the batch's checksum, the key of (t, m)
+    noise_floor: float = 1e-3  # t ~ U[noise_floor, 1]
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -179,6 +216,13 @@ class OlmoeConfig:
             raise ValueError("the stack runs at least once")
         if first < 0 or count < 1 or first + count > self.n_experts:
             raise ValueError(f"held_experts {self.held_experts} lie outside the {self.n_experts}")
+        if {kind.block for kind in self.kinds} != {self.diffusion_block}:
+            raise ValueError("every layer's kind carries diffusion_block, or none carries a block")
+        if self.diffusion_block is not None and (
+            self.passes > 1 or self.mask_token_id is None
+            or not 0 <= self.mask_token_id < self.vocab_size
+        ):
+            raise ValueError("a diffusion model is not looped and names a mask token of its vocabulary")
 
     @property
     def kv_heads(self) -> int:
@@ -285,17 +329,22 @@ def _yarn_ramp(yarn: Yarn, theta: float, head_dim: int) -> jax.Array:
     return jnp.clip((pairs - low) / max(high - low, 1e-3), 0.0, 1.0)
 
 
-def rope(x: jax.Array, theta: float, yarn: Optional[Yarn] = None) -> jax.Array:
-    """Rotary embedding of ``x`` (B, S, H, head_dim) at positions 0..S-1:
-    the pair (``i``, ``i + head_dim / 2``) turns by ``pos * theta ** (-2 i
-    / head_dim)``, or by ``yarn``'s blend of that frequency (``Yarn``).
-    Computed in float32, rounded once."""
+def rope(
+    x: jax.Array, theta: float, yarn: Optional[Yarn] = None,
+    positions: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Rotary embedding of ``x`` (B, S, H, head_dim) at ``positions`` (S,),
+    0..S-1 where none are stated: the pair (``i``, ``i + head_dim / 2``)
+    turns by ``pos * theta ** (-2 i / head_dim)``, or by ``yarn``'s blend
+    of that frequency (``Yarn``). Computed in float32, rounded once."""
     S, half = x.shape[1], x.shape[-1] // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     if yarn is not None:
         ramp = _yarn_ramp(yarn, theta, 2 * half)
         inv_freq = (1.0 - ramp) * inv_freq + ramp * inv_freq / yarn.factor
-    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq  # (S, half)
+    if positions is None:
+        positions = jnp.arange(S, dtype=jnp.float32)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq  # (S, half)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     if yarn is not None:
         cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
@@ -318,14 +367,19 @@ def attention(
             q = _rmsnorm(q, p["q_norm"], cfg.rms_norm_eps)
             k = _rmsnorm(k, p["k_norm"], cfg.rms_norm_eps)
     q, k, v = (t.reshape(B, S, n, dh) for t, n in ((q, h), (k, kv), (v, kv)))
+    # the two copies of a diffusion model's sequence both count 0..L-1
+    stated = {} if kind.block is None else {"positions": jnp.tile(jnp.arange(S // 2), 2)}
     with jax.named_scope("rope"):
-        q, k = (rope(t, cfg.rope_theta, kind.yarn) for t in (q, k))
+        q, k = (rope(t, cfg.rope_theta, kind.yarn, **stated) for t in (q, k))
     if kv != h:
         # query head i meets key/value head i // (h / kv): each is repeated
         # to its query heads (a grouped kernel would read it once)
         k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
     # the fused kernel everywhere: compiled on a TPU, interpreted elsewhere
-    out = flash_attention(q, k, v, window=kind.window)
+    if kind.block is None:
+        out = flash_attention(q, k, v, window=kind.window)
+    else:
+        out = flash_attention(q, k, v, causal=False, block_mask=(kind.block, S // 2))
     return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
 
 
@@ -812,6 +866,42 @@ def _hidden(
     return _stack(cfg, params["blocks"], _embed(cfg, params, tokens))
 
 
+def _noise(cfg: OlmoeConfig, tokens: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """A diffusion step's noise for ``tokens`` (B, L): ``t`` (B,) float32,
+    uniform on [noise_floor, 1], and ``m`` (B, L) bool, each position masked
+    with its sequence's probability ``t``. Drawn from the BATCH (module
+    docstring): the key is ``cfg.noise_seed`` with a checksum of the tokens
+    folded in - a sum of uint32 products, each token by an odd number of its
+    place, which wraps alike in whatever order it is added, jitted or not."""
+    with jax.named_scope("noise"):
+        words = tokens.reshape(-1).astype(jnp.uint32)
+        place = jnp.arange(words.size, dtype=jnp.uint32)
+        checksum = jnp.sum(
+            (words + 1) * (place * jnp.uint32(2654435761) + jnp.uint32(40503)),
+            dtype=jnp.uint32,
+        )
+        key = jax.random.fold_in(jax.random.PRNGKey(cfg.noise_seed), checksum)
+        t_key, m_key = jax.random.split(key)
+        t = jax.random.uniform(
+            t_key, tokens.shape[:1], jnp.float32, cfg.noise_floor, 1.0
+        )
+        return t, jax.random.uniform(m_key, tokens.shape, jnp.float32) < t[:, None]
+
+
+def _noised_hidden(
+    cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array, masked: jax.Array
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """A diffusion model's stack on the 2 L positions of ``tokens`` (B, L):
+    the clean copy, then the copy with the mask token where ``masked``.
+    Returns the NOISED copy's last hidden state (B, L, D) and the routers'
+    sums over BOTH copies' positions."""
+    with jax.named_scope("noise"):
+        noised = jnp.where(masked, jnp.int32(cfg.mask_token_id), tokens)
+        both = jnp.concatenate([tokens, noised], axis=1)
+    x, stats = _hidden(cfg, params, both)
+    return x[:, tokens.shape[1]:], stats
+
+
 # What a looped pass KEEPS for the backward scan beside ``h_t``: the values
 # under these ``checkpoint_name``s; every other operation of the pass is
 # computed again. A kept byte is worth the operations it saves, and the
@@ -929,7 +1019,16 @@ def forward(
     """tokens (B, S) int32 -> (logits (B, S, vocab) f32, the router's
     sums over every layer and token). A looped model (``cfg.passes`` = T >
     1) gives every exit's logits, (T, B, S, vocab), and with the sums
-    ``exit_probs`` (T,): the exit distribution's mean over the positions."""
+    ``exit_probs`` (T,): the exit distribution's mean over the positions.
+    A diffusion model (``cfg.diffusion_block``) gives the NOISED copy's
+    logits (B, S, vocab) under the batch's own noise and, with the sums
+    (over both copies), ``masked_share``: the share of positions masked."""
+    if cfg.diffusion_block is not None:
+        _, masked = _noise(cfg, tokens)
+        x, total = _noised_hidden(cfg, params, tokens, masked)
+        with jax.named_scope("readout"):
+            logits = _readout_product(cfg, params, x).astype(jnp.float32)
+        return logits, dict(total or {}, masked_share=jnp.mean(masked, dtype=jnp.float32))
     if cfg.passes == 1:
         x, total = _hidden(cfg, params, tokens)
         with jax.named_scope("readout"):
@@ -979,13 +1078,35 @@ def _exits_loss(
         return jnp.mean(expected - cfg.exit_entropy_coef * entropy), stats
 
 
+def _diffusion_loss(
+    cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
+) -> jax.Array:
+    """A diffusion model's loss on ``tokens`` (B, L), every one a target
+    (module docstring): the masked positions' cross entropy of their OWN
+    token under the batch's noise, each sequence weighted by ``1 / t``, over
+    all B L positions; + the weighted router losses over the 2 L."""
+    t, masked = _noise(cfg, tokens)
+    x, stats = _noised_hidden(cfg, params, tokens, masked)
+    with jax.named_scope("readout"):
+        logits = _readout_product(cfg, params, x)
+    loss = masked_token_loss(logits, tokens, masked, 1.0 / t)
+    if stats is None:
+        return loss
+    with jax.named_scope("loss"), jax.named_scope("aux"):
+        balance, z = aux_losses(cfg, stats, 2 * tokens.size)
+    return loss + cfg.balance_coef * balance + cfg.z_coef * z
+
+
 def loss_fn(
     cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
 ) -> jax.Array:
     """Next-token cross entropy - over the exits, where the model is looped
     (``_exits_loss``) - + the two weighted router losses where a layer has
     experts. The loss reads the readout's product in ``cfg.dtype``,
-    unwidened (``next_token_loss``)."""
+    unwidened (``next_token_loss``). A diffusion model's loss is
+    ``_diffusion_loss``: no shift, the masked positions alone."""
+    if cfg.diffusion_block is not None:
+        return _diffusion_loss(cfg, params, tokens)
     inputs = tokens[:, :-1]
     if cfg.passes > 1:
         loss, stats = _exits_loss(cfg, params, tokens)

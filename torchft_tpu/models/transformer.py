@@ -317,6 +317,19 @@ def next_token_losses(logits: jax.Array, targets: jax.Array) -> jax.Array:
         return _cross_entropies(logits, targets)
 
 
+def masked_token_loss(
+    logits: jax.Array, targets: jax.Array, masked: jax.Array, weight: jax.Array
+) -> jax.Array:
+    """A masked-diffusion step's loss: the cross entropy of ``logits``
+    (B, L, V) against the position's OWN token ``targets`` (B, L) at the
+    positions ``masked`` (B, L) alone, each sequence's weighted by
+    ``weight`` (B,), summed and divided by ALL B L positions; float32, by
+    ``next_token_losses``' sweep and under its rules."""
+    nll = next_token_losses(logits, targets)
+    with jax.named_scope("loss"):
+        return jnp.sum(jnp.where(masked, nll, 0.0) * weight[:, None]) / masked.size
+
+
 def attn_sublayer_specs() -> Dict[str, Any]:
     """Megatron attention PartitionSpecs; shared with the MoE family."""
     return {

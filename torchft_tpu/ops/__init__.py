@@ -8,7 +8,11 @@ kernels (quantize/dequantize/cast) move gradient-sync packing onto the
 accelerator so d2h bytes scale with the wire size, not the f32 size.
 """
 
-from .flash_attention import flash_attention, flash_attention_qkv
+from .flash_attention import (
+    block_scores_computed,
+    flash_attention,
+    flash_attention_qkv,
+)
 from .quantize_kernels import (
     cast_bf16,
     dequantize_q8,
@@ -17,6 +21,7 @@ from .quantize_kernels import (
 )
 
 __all__ = [
+    "block_scores_computed",
     "flash_attention",
     "flash_attention_qkv",
     "cast_bf16",

@@ -100,6 +100,22 @@ sliding layers, 5.25 + 7.30 ms a layer in the general kernels and
 multiple of ``block_k`` or as long as the sequence, blocks that do not
 nest and non-causal calls keep the general kernels below.
 
+A call under the BLOCK-DIFFUSION mask (``block_mask`` = (B, L): a clean
+and a noised copy of one sequence in the 2 L rows, a query seeing later
+keys of its own block; not causal) runs the general kernels below with
+their tile loop taught the mask: with the clean copy first every visible
+key is at most B - 1 past its query, so a query tile walks the clean key
+tiles up to its diagonal and then its own noised blocks' tiles
+(``_block_key_runs``; the backward the same runs transposed,
+``_block_query_runs``), the empty quadrant and everything between the
+runs is never met, the tiles strictly under a quadrant's diagonal take
+the recurrence without mask or guard (``_block_whole``) and only the
+tiles on an edge are masked (``_tile_mask``). At L 4096, B 4 a head
+computes 80 tiles of 512 x 512 for ``L^2 + L B`` = 16,793,600 visible
+pairs (``block_scores_computed``: 1.25 times), where a sweep of the
+causal half of the 2 L rows is 136. The two-level static schedule for
+this mask is not written (ROADMAP S13).
+
 The causal path also uses a finite -1e30 mask value instead of -inf,
 which removes every ``isfinite`` guard from the online-softmax
 recurrence: with at least one live key per query row in its first tile
@@ -186,14 +202,150 @@ def _dot_tn(a: jax.Array, b: jax.Array) -> jax.Array:
     )
 
 
+def _blocks_in(x, size: int):
+    """``x // size`` of non-negative int32 positions (a value or a traced
+    scalar): a shift where ``size`` is a power of two, as a diffusion
+    block's length is in practice."""
+    if size & (size - 1) == 0:
+        return x >> (size.bit_length() - 1)
+    return jax.lax.div(x, jnp.int32(size))
+
+
+def _block_whole(q0, k0, block_q: int, block_k: int, block: Tuple[int, int]):
+    """True where the block-diffusion mask shows EVERY pair of the tile of
+    queries ``q0 .. q0 + block_q`` and keys ``k0 .. k0 + block_k``, so the
+    tile needs no mask: clean keys under clean queries whose blocks all
+    come no earlier, clean keys under noised queries whose blocks all come
+    later, or one block's noised rows against themselves. From scalars
+    (Python ints or traced); a tile that crosses a copy's end is never
+    whole."""
+    size, length = block
+    q1, k1 = q0 + block_q, k0 + block_k
+
+    def blk(pos):  # of a position in either copy
+        return _blocks_in(jnp.where(pos >= length, pos - length, pos), size)
+
+    clean_keys, noised_keys = k1 <= length, (k0 >= length) & (k1 <= 2 * length)
+    noised_rows = (q0 >= length) & (q1 <= 2 * length)
+    return (
+        ((q1 <= length) & clean_keys & (blk(k1 - 1) <= blk(q0)))
+        | (noised_rows & clean_keys & (blk(k1 - 1) < blk(q0)))
+        | (noised_rows & noised_keys & (blk(k0) == blk(q1 - 1)) & (blk(k1 - 1) == blk(q0)))
+    )
+
+
+def _runs(*runs):
+    """Runs of tile numbers ``(lo, hi)`` in ascending order, each cut to
+    start no earlier than the one before it ended (an empty or swallowed
+    run comes out with ``lo == hi``): no tile is in two of them."""
+    out, end = [], 0
+    for live, lo, hi in runs:
+        lo = jnp.maximum(jnp.where(live, lo, 0), end)
+        end = jnp.maximum(jnp.where(live, hi, 0), lo)
+        out.append((lo, end))
+    return out
+
+
+def _own_blocks_run(p0, p1, tile: int, block: Tuple[int, int]):
+    """The run of tiles (``tile`` rows each) that hold the noised copy's
+    blocks of the noised positions among ``p0 .. p1``: what those rows
+    and keys see of each other. ``(live, lo, hi)``."""
+    size, length = block
+    first_blk = _blocks_in(jnp.clip(p0, length, 2 * length - 1) - length, size)
+    last_blk = _blocks_in(jnp.clip(p1, length + 1, 2 * length) - 1 - length, size)
+    return (
+        (p1 > length) & (p0 < 2 * length), (length + first_blk * size) // tile,
+        _cdiv(length + jnp.minimum((last_blk + 1) * size, length), tile),
+    )
+
+
+def _block_key_runs(q0, block_q: int, block_k: int, block: Tuple[int, int]):
+    """The key tiles that a tile of queries from ``q0`` on meets under the
+    block-diffusion mask, as two runs of tile numbers: the clean keys any
+    of its rows sees (from key 0 to the end of the last clean row's own
+    block, or of the block before the last noised row's) and the noised
+    keys of its noised rows' own blocks. With the clean copy first every
+    visible key is at most B - 1 past its query, so the first run ends by
+    the diagonal tile and the second is the noised quadrant's diagonal
+    tiles alone. What lies between the runs is hidden and never met."""
+    size, length = block
+    q1 = q0 + block_q
+    own = _own_blocks_run(q0, q1, block_k, block)
+    last_clean_blk = _blocks_in(jnp.clip(q1, 1, length) - 1, size)
+    last_noised_blk = _blocks_in(jnp.clip(q1, length + 1, 2 * length) - 1 - length, size)
+    seen_clean = jnp.maximum(
+        jnp.where(q0 < length, jnp.minimum((last_clean_blk + 1) * size, length), 0),
+        jnp.where(own[0], last_noised_blk * size, 0),
+    )
+    return _runs((True, 0, _cdiv(seen_clean, block_k)), own)
+
+
+def _block_query_runs(k0, block_q: int, block_k: int, block: Tuple[int, int]):
+    """``_block_key_runs`` transposed: the query tiles that meet a tile of
+    keys from ``k0`` on, as three runs: the clean rows from its first
+    clean key's block on, the noised rows of its noised keys' own blocks,
+    and the noised rows of the blocks AFTER its first clean key's."""
+    size, length = block
+    has_clean = k0 < length
+    first_clean_blk = _blocks_in(jnp.minimum(k0, length - 1), size)
+    return _runs(
+        (has_clean, first_clean_blk * size // block_q, _cdiv(length, block_q)),
+        _own_blocks_run(k0, k0 + block_k, block_q, block),
+        (
+            has_clean, (length + jnp.minimum((first_clean_blk + 1) * size, length)) // block_q,
+            _cdiv(2 * length, block_q),
+        ),
+    )
+
+
+def _walk(runs):
+    """``(tile_of, steps)`` of a walk over ``runs`` one after another:
+    the tile number of its step ``t``, and how many steps it has."""
+    firsts, steps = [], 0
+    for lo, hi in runs:
+        firsts.append(steps)
+        steps = steps + hi - lo
+
+    def tile_of(t):
+        tile = runs[-1][0] + t - firsts[-1]
+        for (lo, _), first, nxt in zip(runs[-2::-1], firsts[-2::-1], firsts[:0:-1]):
+            tile = jnp.where(t < nxt, lo + t - first, tile)
+        return tile
+
+    return tile_of, steps
+
+
 def _tile_mask(
     q_start, k_start, block_q: int, block_k: int, kv_len: int,
     causal: bool, padded: bool, window: Optional[int] = None,
+    block: Optional[Tuple[int, int]] = None,
 ):
     """Validity mask for one (block_q, block_k) score tile, or None when
     every position is live. Shared by the general forward and backward
     kernels so the mask semantics cannot drift apart. ``window`` w keeps
-    only keys with q_pos - k_pos < w (sliding-window / local attention)."""
+    only keys with q_pos - k_pos < w (sliding-window / local attention).
+    ``block`` (B, L) is the block-diffusion mask over a clean copy (rows
+    0..L-1) and a noised copy (rows L..2L-1) of one sequence in blocks of
+    B (``flash_attention``: ``block_mask``): two compares a pair on codes
+    computed a row and a column - a clean key carries its block's number,
+    a noised key that number past every clean one; a query the last clean
+    block it sees (its own, or the one before where it is noised) and the
+    one noised code it sees (its own block's; none where it is clean). A
+    padded key (past 2L; L is whole blocks) carries a code no live query
+    sees, so this mask needs no ``padded`` term."""
+    if block is not None:
+        size, length = block
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        past = _cdiv(length, size) + 1  # above every clean block's number
+        q_noised, k_noised = q_pos >= length, k_pos >= length
+        q_blk = _blocks_in(q_pos - jnp.where(q_noised, length, 0), size)
+        k_code = _blocks_in(k_pos - jnp.where(k_noised, length, 0), size) + jnp.where(
+            k_noised, past, 0
+        )
+        q_clean_up_to = q_blk - q_noised.astype(jnp.int32)
+        q_noised_is = jnp.where(q_noised, q_blk + past, -1)
+        return (k_code <= q_clean_up_to) | (k_code == q_noised_is)
     if not (causal or padded or window is not None):
         return None
     k_pos = k_start + jax.lax.broadcasted_iota(
@@ -521,12 +673,20 @@ def _fwd_causal_kernel(
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     causal: bool, block_q: int, block_k: int, num_k: int,
-    kv_len: int, window,
+    kv_len: int, window, block=None,
 ):
     """The general forward (sliding window, non-causal, blocks that do
-    not nest): one (block_q, block_k) tile a loop step, per-tile masks.
+    not nest, the block-diffusion mask): one (block_q, block_k) tile a
+    loop step, per-tile masks.
     q arrives PRE-SCALED by sm_scale (folded outside the kernel), so
-    s = q @ k.T is the final score with no per-tile S x S multiply."""
+    s = q @ k.T is the final score with no per-tile S x S multiply.
+
+    Under ``block`` (B, L) the loop walks the tiles the mask shows and no
+    other (``_block_key_runs``), and a tile it shows whole
+    (``_block_whole``) takes the recurrence without mask or guard: every
+    score there is finite. A row whose visible keys all lie in one tile -
+    the first noised block sees only itself - starts from that tile with
+    the guards on."""
     qi = pl.program_id(1)
     q = q_ref[0]  # (block_q, D), input dtype
     D = q.shape[-1]
@@ -539,7 +699,7 @@ def _fwd_kernel(
         s = _dot_nt(q, k_blk)  # (block_q, block_k) f32
         ok = _tile_mask(
             qi * block_q, j * block_k, block_q, block_k, kv_len,
-            causal, padded, window,
+            causal, padded, window, block,
         )
         if ok is not None:
             s = jnp.where(ok, s, _NEG_INF)
@@ -561,7 +721,26 @@ def _fwd_kernel(
         jnp.zeros((block_q, D), jnp.float32),
     )
     num_k_live = _cdiv(kv_len, block_k)  # skip fully-padded key blocks
-    if causal:
+    if block is not None:
+        def whole_tile(j, carry):
+            m, l, acc = carry
+            k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
+            v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
+            s = _dot_nt(q, k_blk)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.exp(s - m_new[:, None])
+            corr = jnp.exp(m - m_new)  # 0 from the initial -inf
+            return m_new, l * corr + p.sum(axis=-1), acc * corr[:, None] + _dot_f32(
+                p.astype(v_blk.dtype), v_blk
+            )
+
+        tile_of, hi = _walk(_block_key_runs(qi * block_q, block_q, block_k, block))
+
+        def step(t, carry):  # step t of the walk
+            j = tile_of(t)
+            whole = _block_whole(qi * block_q, j * block_k, block_q, block_k, block)
+            return jax.lax.cond(whole, whole_tile, tile, j, carry)
+    elif causal:
         # key blocks strictly above the block diagonal are fully masked
         hi = jnp.minimum(
             num_k_live, ((qi + 1) * block_q + block_k - 1) // block_k
@@ -573,7 +752,7 @@ def _fwd_kernel(
         # key blocks fully left of the sliding window are masked for
         # every query row in this block
         lo = jnp.maximum(0, (qi * block_q - window + 1) // block_k)
-    m, l, acc = jax.lax.fori_loop(lo, hi, tile, init)
+    m, l, acc = jax.lax.fori_loop(lo, hi, tile if block is None else step, init)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     # lse rides a full-row (1, 1, S) block revisited across the sequential
@@ -585,12 +764,13 @@ def _fwd_kernel(
 
 
 @_traced_once(
-    "causal", "block_q", "block_k", "edge", "interpret", "kv_len", "window"
+    "causal", "block_q", "block_k", "edge", "interpret", "kv_len", "window",
+    "block",
 )
 def _flash_fwd_call(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool, block_q: int, block_k: int, edge: Optional[int],
-    interpret: bool, kv_len: int, window,
+    interpret: bool, kv_len: int, window, block=None,
 ):
     """q (pre-scaled)/k/v: (BH, S_pad, D) -> out (BH, S_pad, D),
     lse (BH, 1, S_pad) f32. Positions >= kv_len are zero padding, masked
@@ -607,7 +787,7 @@ def _flash_fwd_call(
         kernel = functools.partial(
             _fwd_kernel, causal=causal,
             block_q=block_q, block_k=block_k, num_k=num_k, kv_len=kv_len,
-            window=window,
+            window=window, block=block,
         )
     row = pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0))
     qspec = pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0))
@@ -954,9 +1134,11 @@ def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, *,
     causal: bool, block_q: int, block_k: int, num_q: int,
-    kv_len: int, window,
+    kv_len: int, window, block=None,
 ):
-    """The general backward: one (block_q, block_k) tile a loop step."""
+    """The general backward: one (block_q, block_k) tile a loop step;
+    under ``block`` the forward's walk transposed (``_block_query_runs``),
+    a tile the mask shows whole left unmasked."""
     ki = pl.program_id(1)
     k_blk = k_ref[0]  # (block_k, D), input dtype
     v_blk = v_ref[0]
@@ -982,12 +1164,18 @@ def _bwd_kernel(
         delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
         s = _dot_nt(q_blk, k_blk)  # q pre-scaled by sm_scale
         p = jnp.exp(s - lse[:, None])
-        ok = _tile_mask(
-            i * block_q, ki * block_k, block_q, block_k, kv_len,
-            causal, padded, window,
-        )
-        if ok is not None:
-            p = jnp.where(ok, p, 0.0)
+        def masked(p):
+            ok = _tile_mask(
+                i * block_q, ki * block_k, block_q, block_k, kv_len,
+                causal, padded, window, block,
+            )
+            return p if ok is None else jnp.where(ok, p, 0.0)
+
+        if block is None:
+            p = masked(p)
+        else:
+            whole = _block_whole(i * block_q, ki * block_k, block_q, block_k, block)
+            p = jax.lax.cond(whole, lambda p: p, masked, p)
         dv_new = dv + _dot_tn(p.astype(do_blk.dtype), do_blk)
         dp = _dot_nt(do_blk, v_blk)
         ds = (p * (dp - delta[:, None])).astype(q_blk.dtype)  # one cast,
@@ -1012,18 +1200,23 @@ def _bwd_kernel(
         hi = jnp.minimum(
             num_q, ((ki + 1) * block_k - 1 + window) // block_q + 1
         )
-    dk, dv = jax.lax.fori_loop(lo, hi, tile, init)
+    if block is None:
+        dk, dv = jax.lax.fori_loop(lo, hi, tile, init)
+    else:
+        tile_of, steps = _walk(_block_query_runs(ki * block_k, block_q, block_k, block))
+        dk, dv = jax.lax.fori_loop(0, steps, lambda t, carry: tile(tile_of(t), carry), init)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 @_traced_once(
-    "causal", "block_q", "block_k", "edge", "interpret", "kv_len", "window"
+    "causal", "block_q", "block_k", "edge", "interpret", "kv_len", "window",
+    "block",
 )
 def _flash_bwd_call(
     q, k, v, o, lse, do, *,
     causal: bool, block_q: int, block_k: int, edge: Optional[int],
-    interpret: bool, kv_len: int, window,
+    interpret: bool, kv_len: int, window, block=None,
 ):
     BH, S, D = q.shape
     num_q = S // block_q
@@ -1053,7 +1246,7 @@ def _flash_bwd_call(
         kernel = functools.partial(
             _bwd_kernel, causal=causal,
             block_q=block_q, block_k=block_k, num_q=num_q, kv_len=kv_len,
-            window=window,
+            window=window, block=block,
         )
     kblk3 = pl.BlockSpec((1, key_rows, D), lambda bh, i: (bh, i, 0))
     # over several key blocks dq is the revisited f32 accumulator (cast to
@@ -1128,23 +1321,23 @@ def _flash(cfg, q, k, v):
 
 
 def _flash_fwd_res(cfg, q, k, v):
-    causal, block_q, block_k, edges, interpret, kv_len, window = cfg
+    causal, block_q, block_k, edges, interpret, kv_len, window, block = cfg
     out, lse = _flash_fwd_call(
         q, k, v, causal=causal,
         block_q=block_q, block_k=block_k, edge=edges and edges[0],
-        interpret=interpret, kv_len=kv_len, window=window,
+        interpret=interpret, kv_len=kv_len, window=window, block=block,
     )
     out, lse = _named_residuals(out, lse)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_res(cfg, res, g):
-    causal, block_q, block_k, edges, interpret, kv_len, window = cfg
+    causal, block_q, block_k, edges, interpret, kv_len, window, block = cfg
     q, k, v, out, lse = res
     return _flash_bwd_call(
         q, k, v, out, lse, g, causal=causal,
         block_q=block_q, block_k=block_k, edge=edges and edges[1],
-        interpret=interpret, kv_len=kv_len, window=window,
+        interpret=interpret, kv_len=kv_len, window=window, block=block,
     )
 
 
@@ -1324,6 +1517,36 @@ def _scores_computed(
     return left + blocks * (wide + n_sub * stair)
 
 
+def block_scores_computed(
+    block_mask: Tuple[int, int], head_dim: int, *, backward: bool = False,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> int:
+    """(query, key) pairs ONE head computes in a ``flash_attention`` call
+    under ``block_mask`` (B, L), in its forward kernel or its
+    ``backward``: the tiles the kernel's walk meets (the very runs the
+    kernel walks, on the tiles the call would choose) times a tile's area.
+    The mask shows L^2 + L B of them; the rest is what whole tiles cost on
+    the edges of the three visible regions. At L 4096, B 4 on (512, 512)
+    tiles: 80 tiles, 20,971,520 pairs for 16,793,600 (1.25); a sweep of
+    the causal half of the 2 L rows would be 136 tiles (2.12)."""
+    size, length = block_mask
+    block_q, block_k, s_pad, _ = _tiles(
+        2 * length, head_dim, _pick_interpret(interpret), block_q, block_k,
+        None, causal=False,
+    )
+    runs_of, along, across = (
+        (_block_query_runs, block_k, block_q) if backward
+        else (_block_key_runs, block_q, block_k)
+    )
+    tiles = sum(
+        int(hi) - int(lo)
+        for start in range(0, s_pad, along)
+        for lo, hi in runs_of(start, block_q, block_k, block_mask)
+    )
+    return tiles * along * across
+
+
 def _tiles(
     seq: int, head_dim: int, interpret: bool,
     block_q: Optional[int], block_k: Optional[int],
@@ -1454,6 +1677,7 @@ def flash_attention(
     batch_axis: Optional[str] = "data",
     head_axis: Optional[str] = None,
     window: Optional[int] = None,
+    block_mask: Optional[Tuple[int, int]] = None,
 ) -> jax.Array:
     """Fused multi-head causal attention.
 
@@ -1470,6 +1694,19 @@ def flash_attention(
             docstring); any other keeps the general kernels, which skip
             the tiles wholly outside the window by their loop bounds and
             mask every tile they meet.
+        block_mask: ``(B, L)``: the block-diffusion mask (Arriola et al.,
+            arXiv:2503.09573) over the 2 L positions of a call - a CLEAN
+            copy of a sequence of L tokens in rows 0..L-1 and a NOISED
+            copy in rows L..2L-1, both cut into blocks of B (``blk(i) = i
+            // B``; L is whole blocks). A clean query at i sees the clean
+            keys j with ``blk(j) <= blk(i)`` and nothing noised; a noised
+            query at i sees the clean keys with ``blk(j) < blk(i)`` and
+            the noised keys with ``blk(j) == blk(i)``: L^2 + L B pairs a
+            head, later keys of a query's own block among them, so the
+            call is not ``causal`` and says so. The general kernels walk
+            the tiles the mask shows (``block_scores_computed`` counts
+            them), leave unmasked the tiles it shows whole, and a hidden
+            pair adds exactly 0, forward and backward.
         sm_scale: score scale; default ``head_dim ** -0.5``. The scale
             is folded into ``q`` OUTSIDE the kernel as one f32 multiply
             rounded back to the input dtype (it removes a per-tile
@@ -1519,13 +1756,21 @@ def flash_attention(
             )
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
+    if block_mask is not None:
+        size, length = block_mask = (int(block_mask[0]), int(block_mask[1]))
+        if causal or window is not None:
+            raise ValueError("block_mask is its own mask: causal=False and no window")
+        if size < 1 or length % size or S != 2 * length:
+            raise ValueError(
+                f"block_mask {block_mask}: want 2 x L = {S} positions in whole blocks"
+            )
 
     if mesh is not None:
         spec = P(batch_axis, None, head_axis, None)
         local = functools.partial(
             flash_attention, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, block_diag=block_diag,
-            interpret=interpret, window=window,
+            interpret=interpret, window=window, block_mask=block_mask,
         )
         # check_vma=False: pallas out_shapes carry no varying-mesh-axes
         # annotation, which the new shard_map VMA typing would reject
@@ -1555,7 +1800,7 @@ def flash_attention(
 
     cfg = (
         bool(causal), block_q, block_k, edges, interp, S,
-        None if window is None else int(window),
+        None if window is None else int(window), block_mask,
     )
     # sm_scale folded into q OUTSIDE the custom_vjp: one cheap (S, D)
     # multiply replaces a per-tile (S_q, S_k) multiply in every kernel,
