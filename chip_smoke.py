@@ -155,10 +155,17 @@ def child_probe() -> None:
 # ---------------------------------------------------------------------------
 
 
-def _dense_attention_f32(q: Any, k: Any, v: Any, window: Optional[int]) -> Any:
+def _dense_attention_f32(
+    q: Any, k: Any, v: Any, window: Optional[int],
+    block_mask: Optional[Tuple[int, int]] = None,
+) -> Any:
     """The model's dense path (models/transformer.py _attention_impl: scaled
     scores, causal mask, f32 softmax, probs @ v) kept in float32 end to
-    end, with the kernel's sliding window as one more mask term."""
+    end, with the kernel's sliding window as one more mask term; or, under
+    ``block_mask`` (B, L), the block-diffusion mask written out pair by
+    pair: a clean query (rows 0..L-1) sees the clean keys of its own block
+    and of earlier ones, a noised query (L..2L-1) the clean keys of earlier
+    blocks and the noised keys of its own."""
     import jax
     import jax.numpy as jnp
 
@@ -169,6 +176,14 @@ def _dense_attention_f32(q: Any, k: Any, v: Any, window: Optional[int]) -> Any:
     mask = q_pos >= k_pos
     if window is not None:
         mask = mask & (q_pos - k_pos < window)
+    if block_mask is not None:
+        size, length = block_mask
+        q_blk, k_blk = (q_pos % length) // size, (k_pos % length) // size
+        q_noised, k_noised = q_pos >= length, k_pos >= length
+        mask = jnp.where(
+            k_noised, q_noised & (k_blk == q_blk),
+            jnp.where(q_noised, k_blk < q_blk, k_blk <= q_blk),
+        )
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -176,14 +191,15 @@ def _dense_attention_f32(q: Any, k: Any, v: Any, window: Optional[int]) -> Any:
 
 def _check_flash(
     name: str, B: int, S: int, H: int, D: int, window: Optional[int] = None,
-    fused: bool = False,
+    block_mask: Optional[Tuple[int, int]] = None, fused: bool = False,
 ) -> None:
     """Flash forward + backward at (B, S, H, D), compiled, against the
     dense float32 reference on a seeded sample of 2 batch rows x 2 heads
     (attention is independent per (batch, head), so the sample's outputs
     and gradients are exactly the full problem's). ``fused``: through
     ``flash_attention_qkv`` on the three laid side by side as a fused
-    projection has them (the dense models' call), not the three arrays."""
+    projection has them (the dense models' call), not the three arrays.
+    ``block_mask``: the block-diffusion mask in place of the causal one."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -202,11 +218,13 @@ def _check_flash(
             )
             out = flash_attention_qkv(qkv, H).reshape(B, S, H, D)
         else:
-            out = flash_attention(q, k, v, window=window)
+            out = flash_attention(
+                q, k, v, window=window, causal=block_mask is None, block_mask=block_mask
+            )
         return jnp.sum(out.astype(jnp.float32) * cot.astype(jnp.float32)), out
 
     def ref_loss(q, k, v, cot):
-        out = _dense_attention_f32(q, k, v, window)
+        out = _dense_attention_f32(q, k, v, window, block_mask)
         return jnp.sum(out * cot), out
 
     def grad_of(loss):
@@ -247,7 +265,7 @@ def _check_flash(
                 f"reference by {err:.4f} of max|ref| (tolerance {FLASH_TOL})"
             )
     _say("kernels", (
-        f"flash {name} B{B} S{S} H{H} D{D} window={window}: compiled in "
+        f"flash {name} B{B} S{S} H{H} D{D} window={window} block_mask={block_mask}: compiled in "
         f"{compile_s:.1f}s, max err / max|ref| {errs} <= {FLASH_TOL}"
     ))
 
@@ -332,7 +350,8 @@ def _observe_link() -> Dict[str, float]:
     return out
 
 
-# (name, B, S, H, D, window) of every flash shape the kernels phase runs:
+# (name, B, S, H, D, window[, block_mask]) of every flash shape the kernels
+# phase runs:
 # the big shape (the model slices the last token off: S 2047) and the
 # head_dim 128 shape of the d_model 2048 point; the benchmark's shapes -
 # GPT-2 (1024 positions, 12 heads of 64: the whole sequence resident, the
@@ -341,7 +360,10 @@ def _observe_link() -> Dict[str, float]:
 # positions of head size 128, a window of 1024 and none: the causal
 # schedule cut to the window's band, (1024, 1024) blocks and the two
 # staircases, and the same schedule whole, both with 2 MiB key/value rows
-# resident, the backward's rows past the default VMEM limit); then, at a
+# resident, the backward's rows past the default VMEM limit) and SDAR (a
+# clean and a noised copy of 4096 positions in blocks of 4, head size 128,
+# under the block-diffusion mask: the static schedule with both copies' row
+# groups of 1024 a grid step); then, at a
 # reduced batch, a window of one 512 sub-tile over 2047 positions at head
 # size 64 (the band again, ending in a padded block), a window that no
 # tile divides (the general kernels: one masked tile a loop trip), and a
@@ -353,6 +375,7 @@ FLASH_CASES = (
     ("olmoe", 2, 4096, 16, 128, None),
     ("mellum_sliding", 1, 8192, 8, 128, 1024),
     ("mellum_full", 1, 8192, 8, 128, None),
+    ("sdar_block", 1, 8192, 8, 128, None, (4, 4096)),
     ("windowed", 2, SEQ - 1, 4, 64, 512),
     ("general", 2, SEQ - 1, 4, 64, 500),
     ("ragged", 2, 99, 4, 64, None),
@@ -365,7 +388,7 @@ def child_kernels() -> None:
     for case in FLASH_CASES:
         _check_flash(*case)
         # full causal, one resident block: the fused projection's entry too
-        if case[-1] is None and case[2] <= 2048:
+        if case[5:] == (None,) and case[2] <= 2048:
             _check_flash(case[0] + "_qkv", *case[1:], fused=True)
     # the big model's largest leaf (128 grid blocks) and an odd length
     # that ends mid-block

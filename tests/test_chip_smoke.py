@@ -84,10 +84,33 @@ class TestChipSmoke:
 
         sizes = common.load_json("configs", config + ".json")
         cfg = common.load_by_name("families", sizes["family"]).build(sizes)
-        shapes = {(S, D, window) for _, _, S, _, D, window in chip_smoke.FLASH_CASES}
+        shapes = {tuple(case[i] for i in (2, 4, 5)) for case in chip_smoke.FLASH_CASES}
         windows = {kind.window for kind in getattr(cfg, "kinds", ())} or {None}
         for window in windows:
             assert (sizes["seq"] - 1, cfg.head_dim, window) in shapes
+
+    def test_kernels_phase_runs_the_block_diffusion_shape(self):
+        """``sdar-ft1``'s layer - both copies of its sequence under the
+        block mask, which runs the static schedule there - is among the
+        cases, and checked against the mask written out pair by pair."""
+        import numpy as np
+
+        from benchmark import common
+
+        sizes = common.load_json("configs", "sdar-30b-a3b-l4-ep8.json")
+        cfg = common.load_by_name("families", sizes["family"]).build(sizes)
+        want = (2 * sizes["seq"], cfg.head_dim, None, (cfg.diffusion_block, sizes["seq"]))
+        assert want in {tuple(case[i] for i in (2, 4, 5, 6)) for case in chip_smoke.FLASH_CASES if len(case) > 6}
+        # the mask the check is made against is the reference's own: with v
+        # the identity the output is the probabilities
+        from benchmark import reference_sdar
+
+        x = np.zeros((1, 48, 1, 8), np.float32)
+        flat = np.asarray(chip_smoke._dense_attention_f32(
+            x, x, np.eye(48, dtype=np.float32)[None, :, None, :], None, (4, 24)
+        ))[0, :, 0, :]
+        seen = np.asarray(reference_sdar.visible(24, 4))
+        np.testing.assert_array_equal(flat > 0, seen)
 
     def test_without_a_chip_it_fails_and_says_so(self):
         # the tier-1 environment pins the CPU; the script overrides that

@@ -650,6 +650,46 @@ def test_staircase_matches_plain_attention(entry, head_dim, S, blocks, monkeypat
         np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize("queries_first", [True, False], ids=["forward", "transposed"])
+def test_the_causal_pieces_trace_the_text_they_traced_before_the_block_mask(queries_first):
+    """PR 47 taught the causal kernels' helpers the block-diffusion mask
+    (``_triangle``'s steps of a block and its strict form, a mask a copy,
+    a copy's rows). With their defaults, and with the causal values said
+    aloud, they trace the operations the causal kernels always had: two
+    iotas and ONE compare, queries against keys, for the triangle; one
+    triangle under the key None for a call with no ``block_mask``; a
+    position handed back as it came, with no operation on it."""
+    fa = _module()
+
+    def plain(n):  # ``_triangle`` as it stood
+        rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+        return rows >= cols if queries_first else cols >= rows
+
+    want = str(jax.make_jaxpr(lambda: plain(128))())
+    assert str(jax.make_jaxpr(lambda: fa._triangle(128, queries_first))()) == want
+    assert str(jax.make_jaxpr(
+        lambda: fa._triangle(128, queries_first, size=1, strict=False)
+    )()) == want
+    assert str(jax.make_jaxpr(
+        lambda: fa._block_masks(128, None, queries_first)[0][None]
+    )()) == want
+    masks, own = fa._block_masks(128, None, queries_first)
+    assert list(masks) == [None] and own is None
+    # a block of 1 is the causal triangle too; the strict one is not
+    np.testing.assert_array_equal(fa._triangle(16, queries_first, 1), plain(16))
+    assert not bool(jnp.any(jnp.diagonal(fa._triangle(16, queries_first, 1, strict=True))))
+    pos = jnp.int32(7)
+    assert fa._copy_rows(None, None, pos) is pos and fa._copy_rows((4, 64), 0, pos) is pos
+    assert int(fa._copy_rows((4, 64), 1, pos)) == 71
+    # and the kernels whole: no block_mask, and None said aloud
+    q = jnp.ones((2, 256, 16), jnp.float32)
+    static = dict(causal=True, block_q=256, block_k=128, edge=64, interpret=True, kv_len=256, window=None)
+    assert str(jax.make_jaxpr(lambda: fa._flash_fwd_call(q, q, q, **static))()) == str(
+        jax.make_jaxpr(lambda: fa._flash_fwd_call(q, q, q, block=None, **static))()
+    )
+
+
 @pytest.mark.parametrize("j", [127, 128, 255, 256, 511, 512])
 @pytest.mark.parametrize("entry", ["fused", "three"])
 def test_staircase_is_causal_at_the_chunk_edges(entry, j):

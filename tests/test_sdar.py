@@ -24,6 +24,7 @@ test_olmoe.py``'s bounds).
 import dataclasses
 import json
 import os
+import sys
 from datetime import timedelta
 
 import jax
@@ -42,6 +43,8 @@ from torchft_tpu import (
 )
 from torchft_tpu.models import mellum, olmoe, ouro, sdar
 from torchft_tpu.ops import block_scores_computed, flash_attention
+
+flash_module = sys.modules["torchft_tpu.ops.flash_attention"]
 
 BF16 = sdar.tiny_sdar_config()
 F32 = dataclasses.replace(BF16, dtype=jnp.float32)
@@ -193,27 +196,57 @@ def _qkv(length, heads=2, dh=16, seed=0):
     return [jax.random.normal(k, (1, 2 * length, heads, dh), jnp.float32) for k in keys]
 
 
-# (L, B, block_q, block_k): blocks inside tiles; B = 1; B = L (one block a
-# copy: the own-quadrant tiles are shown whole); a copy that ends inside a
-# tile (L 24 on tiles of 16); a block that straddles a tile's edge (B 6 on
-# tiles of 8 and 16); a padded length (2 L = 40 on tiles of 16); two row
-# groups of keys a query tile (32, 16) and of queries a key tile (8, 16)
+# (L, B, block_q, block_k, block_diag, static): the shapes that TILE run the
+# two-level static schedule (``static``) - blocks inside sub-tiles; B = 1; two
+# row groups a resident block (32, 16), with B the edge (16), a finer edge (8)
+# and the whole one; B = L = the one sub-tile of a copy; one resident block a
+# copy; three of them (the loop runs 0, 1 and 2 trips) - and every other
+# keeps the general walk: B = L over several tiles (the own-quadrant tiles
+# shown whole) and B over a sub-tile; a copy that ends inside a tile (L 24 on
+# tiles of 16); a block that straddles a tile's edge (B 6 on tiles of 8 and
+# 16; B 12 on 8 and 16); a padded length (2 L = 40 on tiles of 16); blocks
+# that do not nest (8, 16), two row groups of queries a key tile
 CASES = [
-    (32, 4, 16, 16), (32, 1, 16, 16), (32, 32, 16, 16), (16, 16, 8, 8),
-    (24, 4, 16, 16), (24, 6, 16, 8), (20, 4, 16, 16), (48, 12, 8, 16),
-    (64, 4, 32, 16),
+    (32, 4, 16, 16, None, True), (32, 1, 16, 16, None, True), (64, 4, 32, 16, None, True),
+    (64, 16, 32, 16, None, True), (64, 4, 32, 16, 8, True), (64, 8, 32, 16, 16, True),
+    (16, 16, 16, 16, None, True), (32, 2, 32, 8, 4, True), (48, 4, 16, 16, 8, True),
+    (32, 32, 16, 16, None, False), (16, 16, 8, 8, None, False),
+    (24, 4, 16, 16, None, False), (24, 6, 16, 8, None, False), (20, 4, 16, 16, None, False),
+    (48, 12, 8, 16, None, False), (32, 4, 8, 16, None, False),
 ]
 
 
-@pytest.mark.parametrize("length,block,block_q,block_k", CASES)
-def test_the_block_mask_is_dense_attention_under_the_mask(length, block, block_q, block_k):
+def _schedule(length, block, block_q=None, block_k=None, head_dim=16, interpret=True):
+    """(block_q, block_k, static): the tiles such a call runs, and whether
+    on the static schedule - the predicate the call and the counter ask."""
+    block_q, block_k, s_pad, edges = flash_module._tiles(
+        2 * length, head_dim, interpret, block_q, block_k, None, causal=False,
+        block_mask=(block, length),
+    )
+    static = flash_module._blocked((block, length), block_q, block_k, s_pad)
+    assert static == (edges is not None)
+    return block_q, block_k, static
+
+
+@pytest.mark.parametrize("length,block,block_q,block_k,block_diag,static", CASES)
+def test_the_block_mask_is_dense_attention_under_the_mask(
+    length, block, block_q, block_k, block_diag, static, monkeypatch
+):
     q, k, v = _qkv(length, seed=length * block)
     seen = _visible(length, block)
     assert seen.sum() == length * length + length * block
+    assert _schedule(length, block, block_q, block_k)[-1] == static
+    # which kernels a call traces: the general ones walk, the causal ones do not
+    walks = []
+    real_walk = flash_module._walk
+    monkeypatch.setattr(
+        flash_module, "_walk", lambda runs: walks.append(len(runs)) or real_walk(runs)
+    )
 
     def flash(q, k, v):
         return flash_attention(
-            q, k, v, causal=False, block_mask=(block, length), block_q=block_q, block_k=block_k
+            q, k, v, causal=False, block_mask=(block, length), block_q=block_q,
+            block_k=block_k, block_diag=block_diag,
         )
 
     weights = jax.random.normal(jax.random.PRNGKey(7), q.shape)
@@ -229,8 +262,10 @@ def test_the_block_mask_is_dense_attention_under_the_mask(length, block, block_q
     np.testing.assert_allclose(out, want_out, rtol=0, atol=KERNEL_ATOL_F32)
     for a, b in zip(grads, want):
         np.testing.assert_allclose(a, b, rtol=0, atol=KERNEL_ATOL_F32 * max(1.0, float(jnp.max(jnp.abs(b)))))
-    # the walk computes whole tiles: never fewer pairs than the mask shows,
-    # and the backward's walk is the forward's transposed where nothing is padded
+    assert bool(walks) != static, walks  # forward and backward, or neither
+    # either schedule computes whole pieces: never fewer pairs than the mask
+    # shows, and the backward's walk is the forward's transposed where nothing
+    # is padded (the static schedule's: at one edge)
     fwd, bwd = (
         block_scores_computed((block, length), 16, block_q=block_q, block_k=block_k, backward=b)
         for b in (False, True)
@@ -238,13 +273,35 @@ def test_the_block_mask_is_dense_attention_under_the_mask(length, block, block_q
     assert fwd >= seen.sum() and bwd >= seen.sum()
     if (2 * length) % max(block_q, block_k) == 0:
         assert fwd == bwd
+    if static and block_diag is None:
+        # the static schedule's pieces, counted by hand: each copy's causal
+        # schedule and the own quadrant's diagonal chunks of one edge
+        edge = block_k
+        groups = length // block_k
+        left = sum(r * block_k * block_k for r in range(groups))
+        stairs = groups * sum(block_k - j * edge for j in range(block_k // edge)) * edge
+        assert fwd == 2 * (left + stairs) + length * edge
 
 
 L, B = 16, 4
+# the tiles of the three exactness tests below: two that run the static
+# schedule (one row group a resident block, and two) and one that keeps the
+# general walk (blocks that do not nest), on which they ran before PR 47
+TILES = {"static": (8, 8), "static_two_row_groups": (16, 8), "walk": (8, 16)}
+_tiles_now = TILES["static"]
+
+
+@pytest.fixture(params=sorted(TILES))
+def tiles(request, monkeypatch):
+    monkeypatch.setattr(sys.modules[__name__], "_tiles_now", TILES[request.param])
+    assert _schedule(L, B, *TILES[request.param])[-1] == request.param.startswith("static")
 
 
 def _flash(q, k, v):
-    return flash_attention(q, k, v, causal=False, block_mask=(B, L), block_q=8, block_k=8)
+    block_q, block_k = _tiles_now
+    return flash_attention(
+        q, k, v, causal=False, block_mask=(B, L), block_q=block_q, block_k=block_k
+    )
 
 
 def _moved(key_at, rows):
@@ -258,7 +315,7 @@ def _moved(key_at, rows):
     return bool(jnp.any(before != after)), touched
 
 
-def test_a_noised_query_sees_the_clean_keys_of_earlier_blocks_alone():
+def test_a_noised_query_sees_the_clean_keys_of_earlier_blocks_alone(tiles):
     """Exact, forward and backward: the noised copy's row of position 9
     (block 2) is unmoved, bit for bit, by the clean keys of its OWN block
     (8..11) - the answer does not leak - and of later ones, and moved by
@@ -273,7 +330,7 @@ def test_a_noised_query_sees_the_clean_keys_of_earlier_blocks_alone():
         assert _moved(k, row) == (seen, seen), k
 
 
-def test_a_clean_query_sees_no_noised_key():
+def test_a_clean_query_sees_no_noised_key(tiles):
     rows = slice(0, L)
     for k in range(L, 2 * L):
         assert _moved(k, rows) == (False, False), k
@@ -281,7 +338,7 @@ def test_a_clean_query_sees_no_noised_key():
     assert _moved(11, 8) == (True, True) and _moved(12, 8) == (False, False)
 
 
-def test_the_first_noised_block_sees_only_itself():
+def test_the_first_noised_block_sees_only_itself(tiles):
     """Rows whose visible keys all lie in one tile, and not the first the
     walk could meet: the softmax over the block's own four noised keys."""
     q, k, v = _qkv(L)
@@ -304,17 +361,39 @@ def test_the_call_says_what_it_cannot_be():
         flash_attention(q, k, v, causal=False, block_mask=(B, L // 2))
 
 
-def test_the_schedule_at_the_cells_shape():
-    """L 4096, B 4 on the chip's (512, 512) tiles: 80 tiles a head, forward
-    and backward, 1.25 times the mask's 16,793,600 pairs, where a sweep of
-    the causal half of the 8,192 rows is 136 tiles."""
-    for backward in (False, True):
-        assert block_scores_computed((4, 4096), 128, backward=backward, interpret=False) == 80 * 512 * 512
+def test_the_schedule_at_the_cells_shape(monkeypatch):
+    """L 4096, B 4 runs the static schedule on the chip's (1024, 1024) tiles:
+    each copy's causal schedule (4 resident blocks: 6 whole tiles and 4
+    staircases) and the own quadrant's 4,096 / edge diagonal chunks, at the
+    forward's edge of 256 and the backward's of 128: 1.124 and 1.061 times
+    the mask's 16,793,600 pairs; the count follows the edge and not the tiles.
+    Until PR 47 the general kernels walked 80 tiles of 512 x 512 (1.25), and
+    would still (the count with the predicate held off); they do walk a shape
+    that does not tile - B 2048, which no sub-tile holds: 96 tiles, all whole.
+    A sweep of the causal half of the 8,192 rows is 136."""
+    assert _schedule(4096, 4, head_dim=128, interpret=False) == (1024, 1024, True)
+    assert _schedule(4096, 2048, head_dim=128, interpret=False) == (512, 512, False)
+    whole, stair = 1024 * 1024, lambda edge, tile=1024: tile * (tile + edge) // 2
+    for backward, edge, pairs in ((False, 256, 18_874_368), (True, 128, 17_825_792)):
+        assert block_scores_computed(
+            (4, 4096), 128, backward=backward, interpret=False
+        ) == 2 * (6 * whole + 4 * stair(edge)) + 4096 * edge == pairs
+        assert block_scores_computed(
+            (4, 4096), 128, backward=backward, interpret=False, block_q=512, block_k=512
+        ) == 2 * (28 * 512 * 512 + 8 * stair(edge, 512)) + 4096 * edge == pairs
+        assert block_scores_computed(
+            (2048, 4096), 128, backward=backward, interpret=False
+        ) == 96 * 512 * 512 == 4096 * 4096 + 4096 * 2048
     family = common.load_family("sdar_lm")
     cfg = family.build(_sizes())
     assert family.block_flash(cfg, 4096) == {
-        "required_pairs": 16_793_600, "computed_pairs": 20_971_520,
+        "required_pairs": 16_793_600, "computed_pairs": 18_874_368,
     }
+    monkeypatch.setattr(flash_module, "_blocked", lambda *_: False)
+    for backward in (False, True):
+        assert block_scores_computed(
+            (4, 4096), 128, backward=backward, interpret=False
+        ) == 80 * 512 * 512
 
 
 # ---------------------------------------------------------------------------

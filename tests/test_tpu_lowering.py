@@ -10,6 +10,8 @@ TPU compiler proper (VMEM budget, layout inference) — ``chip_smoke.py``'s
 ``kernels`` phase covers that on hardware.
 """
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -46,16 +48,37 @@ def test_flash_forward_and_backward_lower(head_dim, seq, window):
     assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == 2
 
 
-# (L, B): the benchmark's shape; a block that is no power of two (a vector
-# division in the mask) on tiles that a copy's end and a block straddle; one
-# block a copy; a padded length
-@pytest.mark.parametrize("length,block", [(4096, 4), (960, 6), (1024, 1024), (200, 8)])
-def test_block_mask_forward_and_backward_lower(length, block):
+def _runs_the_static_schedule(length, block, tile=None, head_dim=128):
+    """Whether a ``block_mask`` call of these shapes, on the chip's tiles
+    (or sub-tiles of ``tile``), runs the two-level static schedule: the
+    predicate the call asks."""
+    flash = sys.modules["torchft_tpu.ops.flash_attention"]
+    block_q, block_k, s_pad, _ = flash._tiles(
+        2 * length, head_dim, False, tile, tile, None, causal=False,
+        block_mask=(block, length),
+    )
+    return flash._blocked((block, length), block_q, block_k, s_pad)
+
+
+# (L, B, sub-tile, static): the benchmark's shape, which runs the two-level
+# static schedule; one block a copy and one sub-tile (the staircases whole);
+# a block that is no power of two (a vector division in the masks) on
+# sub-tiles that hold it, which a call has to name; and three that keep the
+# general walk: such a block on the tiles a call chooses, which a copy's end
+# and a block straddle; one block a copy, over four sub-tiles; a padded length
+@pytest.mark.parametrize(
+    "length,block,tile,static",
+    [(4096, 4, None, True), (1024, 1024, None, True), (3072, 6, 384, True),
+     (960, 6, None, False), (2048, 2048, None, False), (200, 8, None, False)],
+)
+def test_block_mask_forward_and_backward_lower(length, block, tile, static):
+    assert _runs_the_static_schedule(length, block, tile) == static
     q = jnp.ones((2, 2 * length, 2, 128), jnp.bfloat16)
 
     def loss(q, k, v):
         out = flash_attention(
-            q, k, v, causal=False, block_mask=(block, length), interpret=False
+            q, k, v, causal=False, block_mask=(block, length), interpret=False,
+            block_q=tile, block_k=tile,
         )
         return out.astype(jnp.float32).sum()
 
@@ -157,6 +180,35 @@ def test_band_body_is_bounded_against_the_whole_schedule(seq, window):
     whole, band = grad_text(), grad_text(window=window)
     assert whole.count("tpu_custom_call") == band.count("tpu_custom_call") == 2
     assert len(band) <= 2 * len(whole), (len(whole), len(band))
+
+
+def _block_pair(q, k, v):
+    out = flash_attention(q, k, v, causal=False, block_mask=(4, 4096), interpret=False)
+    return out.astype(jnp.float32).sum()
+
+
+def test_block_schedule_body_is_bounded_against_the_causal_schedule():
+    """The block-diffusion mask on the static schedule (PR 47) is the causal
+    schedule with each piece twice - a clean and a noised row group of the
+    same positions a grid step, one loop for both - and the own quadrant's
+    diagonal chunks: at ``sdar-ft1``'s shape (L 4096, B 4, head size 128) it
+    lowers to 58.9 KB - a row group of 1024, four strips of 256 forward and
+    eight chunks of 128 backward - where the causal call over the same 8,192
+    rows (row groups of 512) is 27.0 KB; on row groups of 512 it is 39.0 KB.
+    Tracing, lowering and loading follow that size in every run's set-up;
+    this keeps the body from growing unseen - by a resident block of more
+    row groups, or pieces unrolled over the grid."""
+    q = jnp.ones((1, 8192, 2, 128), jnp.bfloat16)
+
+    def causal(q, k, v):
+        return flash_attention(q, k, v, interpret=False).astype(jnp.float32).sum()
+
+    whole, block = (
+        _lowered_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+        for loss in (causal, _block_pair)
+    )
+    assert whole.count("tpu_custom_call") == block.count("tpu_custom_call") == 2
+    assert len(whole) < len(block) <= 2.5 * len(whole), (len(whole), len(block))
 
 
 # the benchmark's three attention shapes: gpt2-small and gpt2-medium through
@@ -408,3 +460,26 @@ def test_the_compiled_gradient_stores_no_float32_logits(family, one_chip):
     logits = re.findall(r"= \(?[^=]*?\b(f32|bf16)\[4,512,8192\]", entry)
     assert "bf16" in logits, "the logits are not an array of this program at all"
     assert "f32" not in logits, re.findall(r".*f32\[4,512,8192\].*", entry)[:3]
+
+
+def test_the_block_schedule_compiles_for_the_chip(one_chip):
+    """``sdar-ft1``'s flash pair (L 4096, B 4, head size 128; two heads, as
+    the kernels hold one a grid step) through the TPU compiler for the
+    described chip: a body that does not fit the VMEM it names, or a
+    layout Mosaic accepts and the compiler does not, is refused here and
+    not on the chip (about 4 s). What this cannot see is the last per
+    cent of scoped VMEM inside the whole step (PR 37: 180 KB)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    q = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16, sharding=one_chip)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(
+            jax.grad(_block_pair, argnums=(0, 1, 2))
+        ).lower(q, q, q).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

@@ -102,19 +102,46 @@ nest and non-causal calls keep the general kernels below.
 
 A call under the BLOCK-DIFFUSION mask (``block_mask`` = (B, L): a clean
 and a noised copy of one sequence in the 2 L rows, a query seeing later
-keys of its own block; not causal) runs the general kernels below with
-their tile loop taught the mask: with the clean copy first every visible
-key is at most B - 1 past its query, so a query tile walks the clean key
-tiles up to its diagonal and then its own noised blocks' tiles
-(``_block_key_runs``; the backward the same runs transposed,
-``_block_query_runs``), the empty quadrant and everything between the
-runs is never met, the tiles strictly under a quadrant's diagonal take
-the recurrence without mask or guard (``_block_whole``) and only the
-tiles on an edge are masked (``_tile_mask``). At L 4096, B 4 a head
-computes 80 tiles of 512 x 512 for ``L^2 + L B`` = 16,793,600 visible
-pairs (``block_scores_computed``: 1.25 times), where a sweep of the
-causal half of the 2 L rows is 136. The two-level static schedule for
-this mask is not written (ROADMAP S13).
+keys of its own block; not causal) runs the same two-level schedule laid
+over that mask where its shapes tile (``_blocked``, from shapes alone:
+the blocks nest, a copy is whole resident blocks with nothing padded, a
+sub-tile holds whole diffusion blocks). The mask is the causal one
+twice, in steps of B: a clean row group sees the clean keys left of its
+diagonal whole and its diagonal sub-tile under ``blk(k) <= blk(q)``; the
+NOISED row group of the same positions sees the SAME clean keys whole,
+the diagonal sub-tile under ``blk(k) < blk(q)``, and of its own copy the
+B x B blocks on the diagonal alone; the clean rows' noised quadrant is
+empty. So a grid step holds BOTH copies' row groups of its positions (q
+and out seen as (BH, 2, L, D), which moves nothing): one dynamic loop of
+wide unmasked tiles serves both, each takes its staircase with the
+e x e blocks ON the diagonal under a triangle of blocks, and a noised
+row group first meets its own ``e`` keys a strip - the one piece the
+causal schedule lacks, block-diagonal - so that every row has a live key
+in its first piece (the first noised block sees no clean key at all) and
+the finite mask value needs no guard. The backward is the same with keys
+resident: a clean key block meets both copies' queries from its diagonal
+on, under the two staircases; a noised one its own positions' queries, a
+chunk against the same chunk. Still ONE ``flash_fwd`` and ONE
+``flash_bwd`` call a layer. At L 4096, B 4, head size 128 - on
+(1024, 1024), edges 256 | 128 - a head computes 18,874,368 scores
+forward and 17,825,792 backward for ``L^2 + L B`` = 16,793,600 visible
+pairs (``block_scores_computed``: 1.124, 1.061), 5.03 + 8.21 ms a layer
+of 64 head-rows where the general kernels' walk took 10.02 + 15.07
+(PERF.md section 6, PR 47).
+
+Every other ``block_mask`` call - a copy that ends inside a tile, a
+diffusion block that straddles a sub-tile's edge, a padded length -
+runs the general kernels below with their tile loop taught the mask:
+with the clean copy first every visible key is at most B - 1 past its
+query, so a query tile walks the clean key tiles up to its diagonal and
+then its own noised blocks' tiles (``_block_key_runs``; the backward the
+same runs transposed, ``_block_query_runs``), the empty quadrant and
+everything between the runs is never met, the tiles strictly under a
+quadrant's diagonal take the recurrence without mask or guard
+(``_block_whole``) and only the tiles on an edge are masked
+(``_tile_mask``): at L 4096, B 4 that walk is 80 tiles of 512 x 512
+(1.25 times the visible pairs), where a sweep of the causal half of the
+2 L rows is 136.
 
 The causal path also uses a finite -1e30 mask value instead of -inf,
 which removes every ``isfinite`` guard from the online-softmax
@@ -432,6 +459,22 @@ def _banded(causal: bool, window, block_q: int, block_k: int, s_pad: int) -> boo
     )
 
 
+def _blocked(block, block_q: int, block_k: int, s_pad: int) -> bool:
+    """True when a call under the block-diffusion mask ``block`` (B, L)
+    runs the two-level schedule laid over that mask (module docstring):
+    the blocks nest, each copy is whole resident blocks with nothing
+    padded, and a sub-tile holds whole diffusion blocks, so that every
+    staircase's edge can. From shapes alone; every other ``block_mask``
+    call keeps the general kernels' walk."""
+    if block is None:
+        return False
+    size, length = block
+    return (
+        block_q % block_k == 0 and length % block_q == 0
+        and 2 * length == s_pad and block_k % size == 0
+    )
+
+
 def _when(live, piece, state):
     """``piece(state)`` where ``live`` holds, else ``state`` as it is:
     how the banded schedule leaves out the pieces that the first query
@@ -442,13 +485,48 @@ def _when(live, piece, state):
     return jax.lax.cond(live, piece, lambda state: state, state)
 
 
-def _triangle(n: int, queries_first: bool):
+def _triangle(n: int, queries_first: bool, size: int = 1, strict: bool = False):
     """(n, n) bool causal mask of a diagonal sub-tile (its first query
     and first key are the same position): query >= key, with queries
-    along rows or, for transposed scores, along columns."""
+    along rows or, for transposed scores, along columns. In diffusion
+    blocks of ``size`` (a divisor of n) the steps are blocks: a query
+    sees the keys of its own block and of earlier ones, or (``strict``)
+    of earlier ones alone."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    return rows >= cols if queries_first else cols >= rows
+    if size != 1:
+        rows, cols = _blocks_in(rows, size), _blocks_in(cols, size)
+    queries, keys = (rows, cols) if queries_first else (cols, rows)
+    return queries > keys if strict else queries >= keys
+
+
+def _block_masks(edge: int, block_mask, queries_first: bool):
+    """The masks of the pieces that a schedule's edges cut, by the copy
+    of the queries (None: a causal call's one triangle; 0 clean, 1
+    noised under ``block_mask``), and the mask ON the diagonal of the
+    noised copy's own quadrant (None without one): a query sees the keys
+    of its own diffusion block, the later ones among them - what the
+    clean triangle shows and the strict one does not."""
+    if block_mask is None:
+        return {None: _triangle(edge, queries_first)}, None
+    size, _ = block_mask
+    clean = _triangle(edge, queries_first, size)
+    noised = _triangle(edge, queries_first, size, strict=True)
+    return {0: clean, 1: noised}, clean & ~noised
+
+
+def _copy_index(copy, rows, lanes):
+    """The index of ``rows`` and ``lanes`` in a grid step's block of a
+    causal call (``copy`` None: (1, rows, D)) or, under a block mask, of
+    ``copy`` in its block of both copies ((1, 2, rows, D))."""
+    return (0, rows, lanes) if copy is None else (0, copy, rows, lanes)
+
+
+def _copy_rows(block_mask, copy, pos):
+    """Where position ``pos`` of ``copy`` sits among a call's rows: as
+    it is in a causal call (``copy`` None) and in the clean copy (0),
+    past the clean copy's L rows in the noised one (1)."""
+    return block_mask[1] + pos if copy else pos
 
 
 def _head_lanes(h: int, head_dim: int, heads: int):
@@ -494,6 +572,7 @@ def _fwd_causal_kernel(
     block_q: int, block_k: int, num_blocks: int, edge: int,
     heads: int = 1, sm_scale: Optional[float] = None,
     window: Optional[int] = None,
+    block_mask: Optional[Tuple[int, int]] = None,
 ):
     """Two-level causal forward. Grid step (bh, qi) holds ``block_q``
     query rows as ``block_q // block_k`` row groups, each a straight line
@@ -530,26 +609,47 @@ def _fwd_causal_kernel(
     rows [0, (j + 1) * edge) and only its last ``edge`` x ``edge``
     block, ON the window's edge, is masked. No dynamic loop: one body,
     its key origins from ``program_id``; the first ``n_win`` row groups
-    of the sequence have fewer pieces (``_when``)."""
+    of the sequence have fewer pieces (``_when``).
+
+    Under ``block_mask`` (B, L; ``_blocked``) a grid step holds the row
+    groups of ``block_q`` positions TWICE: the clean copy's and the
+    noised copy's, in a (1, 2, block_q, D) block of the rows seen as
+    (BH, 2, L, D). Both meet the same clean keys: the blocks to the left
+    in the one dynamic loop, a wide unmasked tile, and the diagonal
+    sub-tile as the staircase above whose steps are diffusion blocks -
+    ``blk(k) <= blk(q)`` on the masked block for the clean rows,
+    ``blk(k) < blk(q)`` for the noised ones. Of its own copy a noised row
+    group sees its diffusion blocks alone: the ``own`` pieces, chunk j of
+    its own ``edge`` keys (L further on) meeting strip j and no other,
+    under the block-diagonal mask. They come FIRST: the first noised
+    block sees no clean key at all, every noised row sees itself there,
+    so every row has a live key in its first piece as above."""
     n_sub = block_q // block_k
     D = q_ref.shape[-1] // heads
     # a static origin when there is one block: every slice is static
     block = 0 if num_blocks == 1 else pl.program_id(1)
     q0 = block * block_q
-    tri = _triangle(edge, True)
+    triangles, own = _block_masks(edge, block_mask, True)
+    # the row groups a step holds: (copy, r), a causal call's copy None
+    groups = [(copy, r) for copy in triangles for r in range(n_sub)]
 
     def one_head(h: int, lanes):
         q_rows = [
-            _scaled(q_ref[0, pl.ds(r * block_k, block_k), lanes], sm_scale)
-            for r in range(n_sub)
+            _scaled(q_ref[_copy_index(copy, pl.ds(r * block_k, block_k), lanes)], sm_scale)
+            for copy, r in groups
         ]
 
-        def tile(q_blk, state, k_start, width: int, stair: Optional[str] = None):
+        def tile(
+            q_blk, state, k_start, width: int, stair: Optional[str] = None,
+            tri=triangles.get(None),
+        ):
             """One step of the recurrence for a row group, whose state is
             its strips' (m, l, acc): over ``width`` keys in one unmasked
             piece, or over a sub-tile of ``block_k`` keys in a
-            staircase's pieces - the group's own keys (``diagonal``) or
-            those ``window`` back (``edge``). A strip's statistics stay
+            staircase's pieces - the group's own keys (``diagonal``),
+            those ``window`` back (``edge``) or the noised copy's at the
+            group's positions (``own``), the blocks an edge cuts under
+            ``tri``. A strip's statistics stay
             (edge, 1) values as the reductions leave them, the same in
             every lane: stacked or sliced they would have to be spread
             over the lanes again for every block they meet."""
@@ -559,9 +659,11 @@ def _fwd_causal_kernel(
             def met(j: int) -> Tuple[int, int]:
                 """The group's rows that piece j meets: from the chunk's
                 first query on, up to the last query that sees it, or
-                all."""
+                all; of the own quadrant, the chunk's own rows."""
                 if stair == "diagonal":
                     return j * edge, block_k
+                if stair == "own":
+                    return j * edge, (j + 1) * edge
                 return 0, ((j + 1) * edge if stair == "edge" else block_k)
 
             def reach(i: int) -> range:
@@ -569,6 +671,8 @@ def _fwd_causal_kernel(
                 the diagonal and first on the window's edge."""
                 if stair == "diagonal":
                     return range(i + 1)
+                if stair == "own":
+                    return range(i, i + 1)
                 return range(i if stair == "edge" else 0, pieces)
 
             v_chunks, s = [], []
@@ -590,7 +694,7 @@ def _fwd_causal_kernel(
             new_state, p = [], []
             for i, (m, l, acc) in enumerate(state):
                 s_i = blocks(s, i)
-                if stair == "diagonal":
+                if stair in ("diagonal", "own"):
                     s_i[-1] = jnp.where(tri, s_i[-1], _NEG_LARGE)
                 elif stair == "edge":  # visible strictly above: key > query
                     s_i[0] = jnp.where(tri, _NEG_LARGE, s_i[0])
@@ -630,23 +734,31 @@ def _fwd_causal_kernel(
             jnp.full((edge, 1), _NEG_LARGE, jnp.float32),
             jnp.zeros((edge, 1), jnp.float32),
             jnp.zeros((edge, D), jnp.float32),
-        ),) * strips] * n_sub
+        ),) * strips] * len(groups)
+        for g, (copy, r) in enumerate(groups):
+            if copy:  # the noised rows' own blocks, before any clean key
+                state[g] = tile(
+                    q_rows[g], state[g],
+                    _copy_rows(block_mask, copy, q0 + r * block_k), block_k, "own", own,
+                )
         if window is None and num_blocks > 1:
             def interior(j, state):
                 return tuple(
-                    tile(q_rows[r], state[r], j * block_q, block_q)
-                    for r in range(n_sub)
+                    tile(q_rows[g], state[g], j * block_q, block_q)
+                    for g in range(len(groups))
                 )
 
             state = list(jax.lax.fori_loop(
                 0, pl.program_id(1), interior, tuple(state)
             ))
-        for r in range(n_sub):
-            st, q_blk = state[r], q_rows[r]
+        for g, (copy, r) in enumerate(groups):
+            st, q_blk = state[g], q_rows[g]
             if window is None:
                 if r:
                     st = tile(q_blk, st, q0, r * block_k)
-                st = tile(q_blk, st, q0 + r * block_k, block_k, "diagonal")
+                st = tile(
+                    q_blk, st, q0 + r * block_k, block_k, "diagonal", triangles[copy]
+                )
             else:
                 group = block * n_sub + r  # of the sequence's row groups
                 st = tile(q_blk, st, group * block_k, block_k, "diagonal")
@@ -661,10 +773,14 @@ def _fwd_causal_kernel(
                     )
             for i, (m, l, acc) in enumerate(st):
                 rows = r * block_k + i * edge
-                o_ref[0, pl.ds(rows, edge), lanes] = (acc / l).astype(o_ref.dtype)
+                o_ref[_copy_index(copy, pl.ds(rows, edge), lanes)] = (
+                    acc / l
+                ).astype(o_ref.dtype)
                 # lse rides a full-row (1, 1, S) block revisited across the
                 # sequential qi grid dim; each strip writes its slice
-                lse_ref[h, 0, pl.ds(q0 + rows, edge)] = (m + jnp.log(l))[:, 0]
+                lse_ref[h, 0, pl.ds(_copy_rows(block_mask, copy, q0 + rows), edge)] = (
+                    m + jnp.log(l)
+                )[:, 0]
 
     for h in range(heads):
         one_head(h, _head_lanes(h, D, heads))
@@ -778,10 +894,19 @@ def _flash_fwd_call(
     BH, S, D = q.shape
     num_q, num_k = _cdiv(S, block_q), _cdiv(S, block_k)
     banded = _banded(causal, window, block_q, block_k, S)
-    if banded or _nested(causal, window, block_q, block_k):
+    blocked = _blocked(block, block_q, block_k, S)
+    qspec = pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0))
+    if blocked:
+        # a grid step holds both copies' row groups of the same positions:
+        # q and out as (BH, 2, L, D), which moves nothing
+        num_q = S // 2 // block_q
+        q = q.reshape(BH, 2, S // 2, D)
+        qspec = pl.BlockSpec((1, 2, block_q, D), lambda bh, qi: (bh, 0, qi, 0))
+    if blocked or banded or _nested(causal, window, block_q, block_k):
         kernel = functools.partial(
             _fwd_causal_kernel, block_q=block_q, block_k=block_k,
             num_blocks=num_q, edge=edge, window=window,
+            block_mask=block if blocked else None,
         )
     else:
         kernel = functools.partial(
@@ -790,8 +915,8 @@ def _flash_fwd_call(
             window=window, block=block,
         )
     row = pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0))
-    qspec = pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0))
-    return pl.pallas_call(
+    held = 2 * block_q if blocked else block_q  # query rows a step holds
+    out, lse = pl.pallas_call(
         kernel,
         grid=(BH, num_q),
         in_specs=[qspec, row, row],
@@ -800,16 +925,18 @@ def _flash_fwd_call(
             pl.BlockSpec((1, 1, S), lambda bh, qi: (bh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",  # the kernel's name in a device trace
         **_resident_params(
-            ((block_q, D), q.dtype), ((S, D), k.dtype), ((S, D), v.dtype),
-            ((block_q, D), q.dtype), ((S,), jnp.float32), wide_body=banded,
+            ((held, D), q.dtype), ((S, D), k.dtype), ((S, D), v.dtype),
+            ((held, D), q.dtype), ((S,), jnp.float32),
+            wide_body=banded or blocked,
         ),
     )(q, k, v)
+    return out.reshape(BH, S, D) if blocked else out, lse
 
 
 def _qkv_blocks(shape: Tuple[int, int, int], n_heads: int):
@@ -876,6 +1003,7 @@ def _bwd_causal_kernel(
     block_q: int, block_k: int, num_blocks: int, edge: int,
     heads: int = 1, sm_scale: Optional[float] = None,
     window: Optional[int] = None,
+    block_mask: Optional[Tuple[int, int]] = None,
 ):
     """Two-level causal backward, the forward's schedule with the roles
     swapped: grid step (bh, ki) holds ``block_q`` KEY rows as sub-blocks
@@ -911,35 +1039,60 @@ def _bwd_causal_kernel(
     on, the first ``edge`` of them, ON the window's edge, masked. No
     recurrence, so no order to keep, and no dynamic loop; the last key
     blocks of the sequence have fewer pieces (``_when``). Every piece
-    past the diagonal adds its dq into the revisited f32 row."""
+    past the diagonal adds its dq into the revisited f32 row.
+
+    Under ``block_mask`` (B, L; ``_blocked``) a grid step holds the key
+    sub-blocks of ``block_q`` positions twice, the clean copy's and the
+    noised copy's, in (1, 2, block_q, D) blocks of k, v, dk and dv seen
+    as (BH, 2, L, D); q, dO and dq stay whole rows. A CLEAN key sub-block
+    meets BOTH copies' queries at every step of the schedule: the blocks
+    below in the one loop, the wide tile, and the diagonal sub-tile under
+    two staircases whose steps are diffusion blocks, ``blk(k) <=
+    blk(q)`` for the clean queries and ``blk(k) < blk(q)`` for the noised
+    ones. A NOISED key sub-block meets its own positions' noised queries
+    alone, ``edge`` of them at a time against the same ``edge`` keys
+    under the block-diagonal mask. dq is the revisited f32 row."""
     n_sub = block_q // block_k
     one_block = num_blocks == 1
     assert sm_scale is None or one_block
     D = q_ref.shape[-1] // heads
     block = 0 if one_block else pl.program_id(1)
     k0 = block * block_q
-    tri_t = _triangle(edge, False)
+    triangles, own = _block_masks(edge, block_mask, False)
+    copies = list(triangles)  # of the queries: None in a causal call
+    # the key sub-blocks a step holds, (copy, c): the clean ones, which
+    # have the schedule, first
+    groups = [(copy, c) for copy in copies for c in range(n_sub)]
     # dq is the revisited accumulator, not written finished by one step
-    shared_dq = window is not None or not one_block
+    shared_dq = window is not None or not one_block or block_mask is not None
 
     def one_head(h: int, lanes):
-        k_rows = [k_ref[0, pl.ds(c * block_k, block_k), lanes] for c in range(n_sub)]
-        v_rows = [v_ref[0, pl.ds(c * block_k, block_k), lanes] for c in range(n_sub)]
+        k_rows, v_rows = (
+            [
+                ref[_copy_index(copy, pl.ds(c * block_k, block_k), lanes)]
+                for copy, c in groups
+            ]
+            for ref in (k_ref, v_ref)
+        )
         q_all = None if sm_scale is None else _scaled(q_ref[0, :, lanes], sm_scale)
 
         def tile(
             c: int, q_start, width: int, chunk: Optional[int] = None,
-            mirrored: bool = False,
+            mirrored: bool = False, tri_t=triangles.get(None), alone: bool = False,
         ):
             """(dk, dv) of key sub-block c from its meeting with the
             queries [q_start, q_start + width), and that meeting's ds
             transposed, (keys, queries), for their dq. All of the
             sub-block's keys, unmasked; or, for query chunk ``chunk`` of
             a staircase (``edge`` queries), the key rows it can see: up
-            to the chunk's own positions, those under the mask, or
-            (``mirrored``, the window's edge) from them on."""
+            to the chunk's own positions, those under the mask
+            ``tri_t``, or (``mirrored``, the window's edge) from them
+            on; or (``alone``, the noised copy's own quadrant) the
+            chunk's own positions and no other."""
             if chunk is None:
                 lo, hi = 0, block_k
+            elif alone:
+                lo, hi = chunk * edge, (chunk + 1) * edge
             elif mirrored:
                 lo, hi = chunk * edge, block_k
             else:
@@ -970,7 +1123,10 @@ def _bwd_causal_kernel(
             ds_t = (p_t * (dp_t - delta)).astype(q_blk.dtype)  # one cast,
             return _dot_f32(ds_t, q_blk), dv, ds_t              # used twice
 
-        def staircase(c: int, chunk_start, dk, dv, dq, mirrored: bool = False):
+        def staircase(
+            c: int, chunk_start, dk, dv, dq, mirrored: bool = False,
+            tri_t=triangles.get(None),
+        ):
             """``dk``, ``dv`` of key sub-block c and ``dq`` of a query
             block, whose chunk i starts at ``chunk_start(i)``, each with
             what their meeting under a staircase adds: the diagonal
@@ -980,7 +1136,7 @@ def _bwd_causal_kernel(
             # a query chunk at a time, each over the keys it can see
             ds_chunks = []
             for i in range(stairs):
-                dk_i, dv_i, ds_t = tile(c, chunk_start(i), edge, i, mirrored)
+                dk_i, dv_i, ds_t = tile(c, chunk_start(i), edge, i, mirrored, tri_t)
                 first = i * edge if mirrored else 0
                 dk, dv = _add_rows(dk, dk_i, first), _add_rows(dv, dv_i, first)
                 ds_chunks.append(ds_t)
@@ -1004,8 +1160,11 @@ def _bwd_causal_kernel(
                     dq = _add_rows(dq, dq_j, 0 if mirrored else j * edge)
             return dk, dv, dq
 
+        def at(copy, pos):
+            return _copy_rows(block_mask, copy, pos)
+
         zeros = jnp.zeros((block_k, D), jnp.float32)
-        dks, dvs = [zeros] * n_sub, [zeros] * n_sub
+        dks, dvs = [zeros] * len(k_rows), [zeros] * len(k_rows)
         if shared_dq:
             # dq accumulates into a REVISITED full-row f32 output block: the
             # TPU grid is sequential, so every ki step of one bh row sees the
@@ -1016,26 +1175,32 @@ def _bwd_causal_kernel(
 
         if window is None and not one_block:
             def below(i, carry):
-                dks, dvs = carry
-                out_k, out_v, dq = [], [], 0.0
-                for c in range(n_sub):
-                    dk, dv, ds_t = tile(c, i * block_q, block_q)
-                    out_k.append(dks[c] + dk)
-                    out_v.append(dvs[c] + dv)
-                    dq = dq + _dot_tn(ds_t, k_rows[c])
-                dq_ref[0, pl.ds(i * block_q, block_q), lanes] += dq
-                return tuple(out_k), tuple(out_v)
+                dks, dvs = map(list, carry)
+                for copy in copies:
+                    dq = 0.0
+                    for c in range(n_sub):
+                        dk, dv, ds_t = tile(c, at(copy, i * block_q), block_q)
+                        dks[c], dvs[c] = dks[c] + dk, dvs[c] + dv
+                        dq = dq + _dot_tn(ds_t, k_rows[c])
+                    dq_ref[0, pl.ds(at(copy, i * block_q), block_q), lanes] += dq
+                return tuple(dks), tuple(dvs)
 
-            dks, dvs = map(list, jax.lax.fori_loop(
-                pl.program_id(1) + 1, num_blocks, below, (tuple(dks), tuple(dvs)),
-            ))
-        dqs = [0.0] * n_sub  # of the block's own query row groups, f32
+            # the key sub-blocks that have the schedule: all of a causal
+            # call's, the clean copy's
+            dks[:n_sub], dvs[:n_sub] = jax.lax.fori_loop(
+                pl.program_id(1) + 1, num_blocks, below,
+                (tuple(dks[:n_sub]), tuple(dvs[:n_sub])),
+            )
+        # of the block's own query row groups, by their copy, f32
+        dqs = {copy: [0.0] * n_sub for copy in copies}
         stairs = block_k // edge
         for c in range(n_sub):
-            # the diagonal sub-tile
-            dks[c], dvs[c], dqs[c] = staircase(
-                c, lambda i: k0 + c * block_k + i * edge, dks[c], dvs[c], dqs[c]
-            )
+            # the diagonal sub-tile, under each copy's staircase
+            for copy in copies:
+                dks[c], dvs[c], dqs[copy][c] = staircase(
+                    c, lambda i, copy=copy: at(copy, k0 + c * block_k + i * edge),
+                    dks[c], dvs[c], dqs[copy][c], tri_t=triangles[copy],
+                )
             if window is not None:
                 group = block * n_sub + c  # of the sequence's key sub-blocks
 
@@ -1063,22 +1228,41 @@ def _bwd_causal_kernel(
                     )
             elif c < n_sub - 1:  # then every query of the block below it
                 count = n_sub - 1 - c
-                dk, dv, ds_t = tile(c, k0 + (c + 1) * block_k, count * block_k)
-                dks[c], dvs[c] = dks[c] + dk, dvs[c] + dv
-                dq = _dot_tn(ds_t, k_rows[c])
-                for r in range(count):
-                    dqs[c + 1 + r] = (
-                        dqs[c + 1 + r] + dq[r * block_k:(r + 1) * block_k]
+                for copy in copies:
+                    dk, dv, ds_t = tile(
+                        c, at(copy, k0 + (c + 1) * block_k), count * block_k
                     )
-        for r in range(n_sub):
-            rows = pl.ds(k0 + r * block_k, block_k)
+                    dks[c], dvs[c] = dks[c] + dk, dvs[c] + dv
+                    dq = _dot_tn(ds_t, k_rows[c])
+                    for r in range(count):
+                        dqs[copy][c + 1 + r] = (
+                            dqs[copy][c + 1 + r] + dq[r * block_k:(r + 1) * block_k]
+                        )
+        if block_mask is not None:
+            # the noised copy's key sub-blocks: their own positions' noised
+            # queries, a chunk against the same chunk of keys
+            for c in range(n_sub):
+                g = n_sub + c
+                for i in range(stairs):
+                    lo = i * edge
+                    dk, dv, ds_t = tile(
+                        g, at(1, k0 + c * block_k + lo), edge, i, tri_t=own, alone=True
+                    )
+                    dks[g], dvs[g] = _add_rows(dks[g], dk, lo), _add_rows(dvs[g], dv, lo)
+                    dqs[1][c] = _add_rows(
+                        dqs[1][c], _dot_tn(ds_t, k_rows[g][lo:lo + edge]), lo
+                    )
+        for g, (copy, r) in enumerate(groups):
+            rows = pl.ds(at(copy, k0 + r * block_k), block_k)
             if shared_dq:
-                dq_ref[0, rows, lanes] += dqs[r]
+                dq_ref[0, rows, lanes] += dqs[copy][r]
             else:  # every key of the row is here: dq is final
-                dq = dqs[r] if sm_scale is None else dqs[r] * jnp.float32(sm_scale)
+                dq = dqs[copy][r]
+                dq = dq if sm_scale is None else dq * jnp.float32(sm_scale)
                 dq_ref[0, rows, lanes] = dq.astype(dq_ref.dtype)
-            dk_ref[0, pl.ds(r * block_k, block_k), lanes] = dks[r].astype(dk_ref.dtype)
-            dv_ref[0, pl.ds(r * block_k, block_k), lanes] = dvs[r].astype(dv_ref.dtype)
+            held = _copy_index(copy, pl.ds(r * block_k, block_k), lanes)
+            dk_ref[held] = dks[g].astype(dk_ref.dtype)
+            dv_ref[held] = dvs[g].astype(dv_ref.dtype)
 
     for h in range(heads):
         one_head(h, _head_lanes(h, D, heads))
@@ -1230,16 +1414,20 @@ def _flash_bwd_call(
     row2 = pl.BlockSpec((1, 1, S), lambda bh, i: (bh, 0, 0))
     nested = _nested(causal, window, block_q, block_k)
     banded = _banded(causal, window, block_q, block_k, S)
-    if nested or banded:
+    blocked = _blocked(block, block_q, block_k, S)
+    if nested or banded or blocked:
         # The causal schedule applies NO padding mask: padded k/v rows
         # are zeros, so padded-column score/probability garbage adds
         # exactly 0 to dq (``ds @ k`` hits zero rows) and only reaches
         # dk/dv ROWS that the caller slices off; padded query rows carry
         # zero cotangents.
         key_rows = block_q  # the resident block is a key block here
+        if blocked:  # a step holds both copies' key blocks (below)
+            num_q = S // 2 // block_q
         kernel = functools.partial(
             _bwd_causal_kernel, block_q=block_q, block_k=block_k,
             num_blocks=num_q, edge=edge, window=window,
+            block_mask=block if blocked else None,
         )
     else:
         key_rows = block_k
@@ -1249,28 +1437,36 @@ def _flash_bwd_call(
             window=window, block=block,
         )
     kblk3 = pl.BlockSpec((1, key_rows, D), lambda bh, i: (bh, i, 0))
+    if blocked:
+        # k, v, dk and dv as (BH, 2, L, D), which moves nothing: the clean
+        # and the noised key block of the same positions a grid step
+        k, v = k.reshape(BH, 2, S // 2, D), v.reshape(BH, 2, S // 2, D)
+        kblk3 = pl.BlockSpec((1, 2, key_rows, D), lambda bh, i: (bh, 0, i, 0))
+    held = 2 * key_rows if blocked else key_rows  # key rows a step holds
     # over several key blocks dq is the revisited f32 accumulator (cast to
     # q.dtype below); one block writes it finished
     dq_dtype = q.dtype if nested and num_q == 1 else jnp.float32
 
     dq, dk, dv = pl.pallas_call(
         kernel,
-        grid=(BH, S // key_rows),
+        grid=(BH, S // held),
         in_specs=[row3, kblk3, kblk3, row3, row2, row2],
         out_specs=[row3, kblk3, kblk3],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, D), dq_dtype),
-            jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
         name="flash_bwd",
         **_resident_params(
             ((S, D), q.dtype), ((S, D), do.dtype), ((S, D), dq_dtype),
             ((S,), jnp.float32), ((S,), jnp.float32),
-            *[((key_rows, D), k.dtype)] * 4, wide_body=banded,
+            *[((held, D), k.dtype)] * 4, wide_body=banded or blocked,
         ),
     )(q, k, v, do, lse, delta)
+    if blocked:
+        dk, dv = dk.reshape(BH, S, D), dv.reshape(BH, S, D)
     return dq.astype(q.dtype), dk, dv
 
 
@@ -1394,6 +1590,7 @@ def _pick_interpret(interpret: Optional[bool]) -> bool:
 def _auto_tiles(
     seq: int, head_dim: int, interpret: bool, nested: bool = True,
     window: Optional[int] = None,
+    block_mask: Optional[Tuple[int, int]] = None,
 ) -> Tuple[int, int]:
     """The (block_q, block_k) a call runs when it names none, from what
     the call can see: the sequence length, the head size, whether the
@@ -1437,7 +1634,22 @@ def _auto_tiles(
     taken: twice the body, a second more of every run's set-up on top of
     the second that (1024, 1024) adds to the general kernels', and a
     forward within 180 KB of the default VMEM limit. A window that none
-    of them divides keeps the general path's tiles."""
+    of them divides keeps the general path's tiles.
+
+    Under ``block_mask`` (B, L): the LARGEST sub-tile of 1024, 512, 256
+    or 128 that divides a copy and holds whole diffusion blocks, one a
+    resident block (``_blocked`` then holds, nothing padded). SDAR's layer
+    (L 4096, B 4, D 128, 64 head-rows; ms a layer in the kernels, forward
+    | backward, edges 256 | 128 unless said; my chip run, PR 47):
+    (1024, 1024) 5.03 | 8.21, (1024, 512) 4.89 | 8.28, (512, 512)
+    5.62 | 9.10, and 10.02 | 15.07 for the general kernels' walk on
+    (512, 512) that it replaces. The pairs computed depend on the edge
+    alone, so larger tiles are fewer trips and grid steps for the same
+    work; two row groups a block, (1024, 512), are no faster than one of
+    1024 and twice the body. The TPU compiler takes 6.4 s for four
+    layers' pairs on (1024, 1024) where it takes 1.5 on (512, 512) (off
+    the chip; a cold set-up's, once a cache). A mask that none of them
+    fits keeps the general path's tiles."""
     del head_dim  # 64 and 128 rank the tiles alike (PERF.md, PR 25)
     unit = 8 if interpret else 128
     s_pad = _cdiv(seq, unit) * unit
@@ -1448,6 +1660,10 @@ def _auto_tiles(
     if window is not None and window < s_pad:
         for sub in (1024, 512, 256, 128):
             if window % sub == 0:
+                return sub, sub
+    if block_mask is not None:
+        for sub in (1024, 512, 256, 128):
+            if _blocked(block_mask, sub, sub, s_pad):
                 return sub, sub
     return (512, 512) if s_pad >= 2048 else (128, 128)
 
@@ -1475,7 +1691,14 @@ def _auto_edges(block_k: int, head_dim: int) -> Tuple[int, int]:
     shorter ones save nothing, while every strip adds a reduction to
     wait for. At head size 64 it stays whole; at 128 an edge of 256 is
     the best of the three by a little. Other shapes run the nearest
-    regime's choice, not measured."""
+    regime's choice, not measured.
+
+    A call under the block-diffusion mask takes the same edges (SDAR's
+    layer, L 4096, B 4, D 128, on (1024, 1024); ms a layer; my chip run,
+    PR 47): the forward 5.03 at 256 and 4.92 at 128, whose eight strips a
+    row group are half as much body again to trace and lower in every
+    set-up for 0.4 ms of a step of 280; the backward 8.21 at 128 (on
+    (512, 512): 9.10 at 128, 9.28 at 256)."""
     forward = 256 if head_dim >= 128 and block_k % 256 == 0 else block_k
     backward = 128 if block_k % 128 == 0 else block_k
     return forward, backward
@@ -1524,17 +1747,26 @@ def block_scores_computed(
 ) -> int:
     """(query, key) pairs ONE head computes in a ``flash_attention`` call
     under ``block_mask`` (B, L), in its forward kernel or its
-    ``backward``: the tiles the kernel's walk meets (the very runs the
-    kernel walks, on the tiles the call would choose) times a tile's area.
-    The mask shows L^2 + L B of them; the rest is what whole tiles cost on
-    the edges of the three visible regions. At L 4096, B 4 on (512, 512)
-    tiles: 80 tiles, 20,971,520 pairs for 16,793,600 (1.25); a sweep of
-    the causal half of the 2 L rows would be 136 tiles (2.12)."""
+    ``backward``, on the schedule that call would run (the predicate the
+    call asks, on the tiles and edges it would choose). The static
+    schedule (``_blocked``): each copy's causal schedule of L rows
+    (``_scores_computed``) and the own quadrant's L / edge chunks of
+    ``edge`` x ``edge``. Else the tiles the general kernels' walk meets
+    (the very runs the kernel walks) times a tile's area. The mask shows
+    L^2 + L B pairs; the rest is what whole pieces cost on the edges of
+    the three visible regions. At L 4096, B 4, head size 128: 18,874,368
+    forward (edge 256) and 17,825,792 backward (128) for 16,793,600
+    (1.124, 1.061); the walk on (512, 512) was 80 tiles, 20,971,520
+    (1.25), and a sweep of the causal half of the 2 L rows would be 136
+    tiles (2.12)."""
     size, length = block_mask
-    block_q, block_k, s_pad, _ = _tiles(
+    block_q, block_k, s_pad, edges = _tiles(
         2 * length, head_dim, _pick_interpret(interpret), block_q, block_k,
-        None, causal=False,
+        None, causal=False, block_mask=block_mask,
     )
+    if _blocked(block_mask, block_q, block_k, s_pad):
+        edge = edges[1 if backward else 0]
+        return 2 * _scores_computed(length, block_q, block_k, edge) + length * edge
     runs_of, along, across = (
         (_block_query_runs, block_k, block_q) if backward
         else (_block_key_runs, block_q, block_k)
@@ -1552,6 +1784,7 @@ def _tiles(
     block_q: Optional[int], block_k: Optional[int],
     block_diag: Optional[int], causal: bool = True,
     window: Optional[int] = None,
+    block_mask: Optional[Tuple[int, int]] = None,
 ) -> Tuple[int, int, int, Optional[Tuple[int, int]]]:
     """(block_q, block_k, padded length, staircase edges) of a call: the
     blocks it names, else ``_auto_tiles``, clamped to the sequence and
@@ -1560,11 +1793,14 @@ def _tiles(
     masked in-kernel (on the causal schedule only padded queries can see
     them), padded queries carry zero cotangents, so numerics are exact.
     The edges are (forward, backward) where the call runs the two-level
-    schedule, whole or cut to a window's band (``_nested``, ``_banded``:
-    ``_auto_edges``, or ``block_diag`` for both), else None."""
+    schedule, whole, cut to a window's band or laid over the
+    block-diffusion mask (``_nested``, ``_banded``, ``_blocked``:
+    ``_auto_edges``, or ``block_diag`` for both), else None. Under
+    ``block_mask`` an edge holds whole diffusion blocks: one of
+    ``_auto_edges``' that does not is the whole sub-tile, which does."""
     auto_q, auto_k = _auto_tiles(
         seq, head_dim, interpret, nested=causal and window is None,
-        window=window if causal else None,
+        window=window if causal else None, block_mask=block_mask,
     )
     unit = 8 if interpret else 128
     s8 = _cdiv(seq, unit) * unit
@@ -1576,10 +1812,13 @@ def _tiles(
     base = block_q * block_k // math.gcd(block_q, block_k)
     s_pad = _cdiv(seq, base) * base
     edges = None
-    if _nested(causal, window, block_q, block_k) or _banded(
+    blocked = _blocked(block_mask, block_q, block_k, s_pad)
+    if blocked or _nested(causal, window, block_q, block_k) or _banded(
         causal, window, block_q, block_k, s_pad
     ):
         edges = _auto_edges(block_k, head_dim)
+        if blocked:
+            edges = tuple(e if e % block_mask[0] == 0 else block_k for e in edges)
         if block_diag:
             edge = min(block_diag, block_k)
             if not interpret:
@@ -1587,6 +1826,10 @@ def _tiles(
             if block_k % edge:
                 raise ValueError(
                     f"block_diag {edge} does not divide block_k {block_k}"
+                )
+            if blocked and edge % block_mask[0]:
+                raise ValueError(
+                    f"block_diag {edge} does not hold whole blocks of {block_mask[0]}"
                 )
             edges = (edge, edge)
     return block_q, block_k, s_pad, edges
@@ -1703,10 +1946,16 @@ def flash_attention(
             query at i sees the clean keys with ``blk(j) < blk(i)`` and
             the noised keys with ``blk(j) == blk(i)``: L^2 + L B pairs a
             head, later keys of a query's own block among them, so the
-            call is not ``causal`` and says so. The general kernels walk
-            the tiles the mask shows (``block_scores_computed`` counts
-            them), leave unmasked the tiles it shows whole, and a hidden
-            pair adds exactly 0, forward and backward.
+            call is not ``causal`` and says so. Where the shapes tile
+            (``_blocked``: blocks that nest, L whole resident blocks,
+            nothing padded, B a divisor of ``block_k`` - the tiles
+            ``_auto_tiles`` chooses where they can) it runs the
+            two-level static schedule over both copies (module
+            docstring); any other keeps the general kernels, which walk
+            the tiles the mask shows and leave unmasked those it shows
+            whole. ``block_scores_computed`` counts what either
+            computes; on both a hidden pair adds exactly 0, forward and
+            backward.
         sm_scale: score scale; default ``head_dim ** -0.5``. The scale
             is folded into ``q`` OUTSIDE the kernel as one f32 multiply
             rounded back to the input dtype (it removes a per-tile
@@ -1781,7 +2030,7 @@ def flash_attention(
 
     interp = _pick_interpret(interpret)
     block_q, block_k, S_pad, edges = _tiles(
-        S, D, interp, block_q, block_k, block_diag, causal, window
+        S, D, interp, block_q, block_k, block_diag, causal, window, block_mask
     )
 
     # (B, S, H, D) -> (B*H, S_pad, D). Blocks always span the full head
