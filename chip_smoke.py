@@ -270,6 +270,75 @@ def _check_flash(
     ))
 
 
+def _check_heads_to_rows(
+    name: str, positions: str, S: int = 8192, H: int = 32, G: int = 4, D: int = 128,
+) -> None:
+    """``olmoe._heads_to_rows`` at the routed cells' shape - B 2, S 8192,
+    32 query heads of 128 over 4 key heads, a norm per head, YaRN's blend
+    (``positions`` "counted") or both halves of the sequence counting from
+    0 ("stated") - compiled, q's pass and k's, against the plain
+    composition ``_rmsnorm`` -> ``rope`` -> ``jnp.repeat`` -> the scale in
+    float32: the rows, ``dx`` and the norm scale's gradient."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchft_tpu.models import olmoe
+    from torchft_tpu.models.transformer import _rmsnorm
+
+    B, theta, eps = 2, 10000.0, 1e-6
+    if positions == "stated":
+        yarn, stated = None, jnp.tile(jnp.arange(S // 2), 2)
+    else:
+        yarn, stated = olmoe.Yarn(16.0, 512, attention_factor=1.2772588722239782), None
+    keys = jax.random.split(jax.random.PRNGKey(S + D), 5)
+    scale = (1.0 + 0.2 * jax.random.normal(keys[4], (D,))).astype(jnp.bfloat16)
+    errs = {}
+    for which, heads, group, mult in (("q", H, 1, D ** -0.5), ("k", G, H // G, 1.0)):
+        spec = olmoe.HeadsToRows(heads, group, True, eps, mult)
+        x = jax.random.normal(keys[0], (B, S, heads * D), jnp.bfloat16)
+        cot = jax.random.normal(keys[1], (B * H, S, D), jnp.bfloat16)
+
+        def mine(x, scale):
+            tables = olmoe.rotary_tables(S, D, theta, yarn, stated)
+            rows = olmoe._heads_to_rows(spec, x, scale, tables)
+            return jnp.sum(rows.astype(jnp.float32) * cot.astype(jnp.float32)), rows
+
+        def plain(x, scale):
+            y = _rmsnorm(x.astype(jnp.float32).reshape(B, S, heads, D), scale.astype(jnp.float32), eps)
+            y = jnp.repeat(olmoe.rope(y, theta, yarn, stated), group, axis=2) * mult
+            rows = y.transpose(0, 2, 1, 3).reshape(-1, S, D)
+            return jnp.sum(rows * cot.astype(jnp.float32)), rows
+
+        def grad_of(loss):
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+
+        lowered = grad_of(mine).lower(x, scale)
+        if "tpu_custom_call" in lowered.as_text():  # the pass is XLA's own
+            raise AssertionError(f"heads_to_rows {name}: a Mosaic call in the pass")
+        (_, rows), grads = jax.block_until_ready(lowered.compile()(x, scale))
+        (_, want), want_grads = jax.block_until_ready(
+            grad_of(plain)(x.astype(jnp.float32), scale.astype(jnp.float32))
+        )
+        for label, got, ref in (
+            ("rows", rows, want), ("dx", grads[0], want_grads[0]), ("dw", grads[1], want_grads[1]),
+        ):
+            got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+            if not np.all(np.isfinite(got)):
+                raise AssertionError(f"heads_to_rows {name}: non-finite {which} {label}")
+            err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+            errs[f"{which}_{label}"] = round(err, 5)
+            if err > FLASH_TOL:
+                raise AssertionError(
+                    f"heads_to_rows {name}: {which} {label} differs from the plain "
+                    f"composition by {err:.4f} of max|ref| (tolerance {FLASH_TOL})"
+                )
+    _say("kernels", (
+        f"heads_to_rows {name} B{B} S{S} H{H}/{G} D{D} positions {positions}: "
+        f"max err / max|ref| {errs} <= {FLASH_TOL}"
+    ))
+
+
 def _check_wire_kernels(name: str, shape: Sequence[int], seed: int) -> None:
     """quantize_q8_ef / dequantize_q8 / cast_bf16 on one payload, compiled,
     against the numpy oracle of the CPU tests
@@ -390,6 +459,10 @@ def child_kernels() -> None:
         # full causal, one resident block: the fused projection's entry too
         if case[5:] == (None,) and case[2] <= 2048:
             _check_flash(case[0] + "_qkv", *case[1:], fused=True)
+    # q and k from their projections to the kernels' rows, at the routed
+    # cells' shape: Mellum2's full layer and SDAR's two copies
+    _check_heads_to_rows("mellum_full", "counted")
+    _check_heads_to_rows("sdar_block", "stated")
     # the big model's largest leaf (128 grid blocks) and an odd length
     # that ends mid-block
     _check_wire_kernels("big_leaf", (1024, 4096), seed=1)

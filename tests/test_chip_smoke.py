@@ -112,6 +112,24 @@ class TestChipSmoke:
         seen = np.asarray(reference_sdar.visible(24, 4))
         np.testing.assert_array_equal(flat > 0, seen)
 
+    @pytest.mark.parametrize("positions", ["counted", "stated"])
+    def test_kernels_phase_checks_the_pass_to_the_kernels_rows(self, positions, monkeypatch):
+        """The kernels phase holds ``olmoe._heads_to_rows`` to the plain
+        composition at the routed cells' shape; here the same check in
+        miniature, and a pass that turns the wrong pairs fails it."""
+        from torchft_tpu.models import olmoe
+
+        chip_smoke._check_heads_to_rows("tiny", positions, S=64, H=4, G=2, D=16)
+        right = olmoe.rotary_tables
+
+        def wrong(*args):
+            cos, sin = right(*args)
+            return cos, -sin
+
+        monkeypatch.setattr(olmoe, "rotary_tables", wrong)
+        with pytest.raises(AssertionError, match="differs from the plain"):
+            chip_smoke._check_heads_to_rows("tiny", positions, S=64, H=4, G=2, D=16)
+
     def test_without_a_chip_it_fails_and_says_so(self):
         # the tier-1 environment pins the CPU; the script overrides that
         # for its children (JAX_PLATFORMS=tpu) and must find no chip

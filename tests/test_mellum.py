@@ -181,14 +181,14 @@ def _faulty(wrong, cfg, params, monkeypatch):
     if wrong == "window_off_by_one":
         return _kinds(cfg, window=lambda w: w + 1), params
     if wrong == "kv_head_h_mod_g":
-        repeat = jnp.repeat
+        right = olmoe._heads_to_rows
 
-        def tiled(x, n, axis=None, **kw):  # head h meets h % G, not h // (H / G)
-            if x.ndim == 4 and axis == 2:
-                return jnp.tile(x, (1, 1, n, 1))
-            return repeat(x, n, axis=axis, **kw)
+        def tiled(spec, x, scale, tables):  # head h meets h % G, not h // (H / G)
+            rows = right(spec, x, scale, tables)
+            copies = rows.reshape(x.shape[0], spec.heads, spec.group, *rows.shape[1:])
+            return copies.swapaxes(1, 2).reshape(rows.shape)
 
-        monkeypatch.setattr(jnp, "repeat", tiled)
+        monkeypatch.setattr(olmoe, "_heads_to_rows", tiled)
         return cfg, params
     if wrong == "yarn_left_off":
         return dataclasses.replace(cfg, layer_kinds=tuple(

@@ -149,15 +149,27 @@ def _renormalised_top_k(cfg, params, tokens, monkeypatch):
 
 
 def _wrong_half_rotated(cfg, params, tokens, monkeypatch):
-    """Pairs (2i, 2i + 1), the interleaved form, instead of (i, i + half)."""
-    right = olmoe.rope
+    """Pairs (2i, 2i + 1), the interleaved form, instead of (i, i + half):
+    the pass ``attention`` runs, given every head's lanes (and the norm's
+    scale with them) in the interleaved order and its rows put back."""
+    right = olmoe._heads_to_rows
 
-    def interleaved(x, theta, yarn=None):
-        half = x.shape[-1] // 2
+    def interleaved(spec, x, scale, tables):
+        if tables is None:  # a value projection turns nothing
+            return right(spec, x, scale, tables)
+        B, S, width = x.shape
+        half = width // spec.heads // 2
         mix = jnp.stack([jnp.arange(half), jnp.arange(half) + half], -1).reshape(-1)
-        return right(x[..., jnp.argsort(mix)], theta, yarn)[..., mix]
+        unmix = jnp.argsort(mix)
 
-    monkeypatch.setattr(olmoe, "rope", interleaved)
+        def lanes(t, order):  # the lanes of every head of (..., heads x dh)
+            return t.reshape(*t.shape[:-1], -1, 2 * half)[..., order].reshape(t.shape)
+
+        if scale is not None:
+            scale = scale[unmix] if spec.per_head else lanes(scale, unmix)
+        return right(spec, lanes(x, unmix), scale, tables)[..., mix]
+
+    monkeypatch.setattr(olmoe, "_heads_to_rows", interleaved)
     return olmoe.loss_fn(cfg, params, tokens)
 
 

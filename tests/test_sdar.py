@@ -481,27 +481,12 @@ def test_stated_positions_are_the_old_rope_at_the_default():
         np.testing.assert_array_equal(twice[:, :24], old)
 
 
-def _old_rope(x, theta, yarn=None):
-    """``olmoe.rope`` as it was before it took positions (PR 46's parent)."""
-    S, half = x.shape[1], x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    if yarn is not None:
-        ramp = olmoe._yarn_ramp(yarn, theta, 2 * half)
-        inv_freq = (1.0 - ramp) * inv_freq + ramp * inv_freq / yarn.factor
-    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
-    if yarn is not None:
-        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return turned.astype(x.dtype)
-
-
 @pytest.mark.parametrize("model", ["olmoe", "mellum2", "ouro"])
 def test_the_other_configurations_lower_to_the_parents_text(model, monkeypatch):
     """A configuration without the new fields takes the old path: the
-    lowered text of its loss's gradient is the text with ``rope`` as the
-    parent had it, ``flash_attention`` as the parent called it (no
+    lowered text of its loss's gradient is the text with the rotation's
+    tables as a model without stated positions asks for them (none
+    stated), the flash kernels as such a model calls them (no
     ``block_mask``, ``causal`` never named) and no noise drawn."""
     cfg = {
         "olmoe": olmoe.tiny_olmoe_config(), "mellum2": mellum.tiny_mellum_config(),
@@ -516,16 +501,21 @@ def test_the_other_configurations_lower_to_the_parents_text(model, monkeypatch):
 
     text = lowered()
     calls = []
+    tables, rows = olmoe.rotary_tables, olmoe.flash_attention_rows
 
-    def as_the_parent_called_it(q, k, v, *, window=None):
+    def counted_from_zero(S, head_dim, theta, yarn=None, positions=None):
+        assert positions is None
+        return tables(S, head_dim, theta, yarn)
+
+    def as_a_causal_model_calls_it(q, k, v, *, window=None):
         calls.append(window)
-        return flash_attention(q, k, v, window=window)
+        return rows(q, k, v, window=window)
 
     def no_noise(*_):
         raise AssertionError("a next-token model draws no noise")
 
-    monkeypatch.setattr(olmoe, "rope", _old_rope)
-    monkeypatch.setattr(olmoe, "flash_attention", as_the_parent_called_it)
+    monkeypatch.setattr(olmoe, "rotary_tables", counted_from_zero)
+    monkeypatch.setattr(olmoe, "flash_attention_rows", as_a_causal_model_calls_it)
     monkeypatch.setattr(olmoe, "_noise", no_noise)
     again = lowered()
     assert len(calls) >= cfg.n_layers

@@ -11,7 +11,11 @@ For activations ``x`` (B, S, D), per layer, pre-norm::
   own scale - and ``v = x Wv``; heads of ``head_dim``; rotary embedding
   with rotate-half pairing (``i`` with ``i + head_dim / 2``) on q and k;
   causal softmax attention scaled by ``head_dim ** -0.5``; ``out Wo``.
-  No biases.
+  No biases. ``_rmsnorm`` and ``rope`` are that arithmetic in plain form;
+  the step runs it as ONE pass from each projection to the flash kernels'
+  rows and one back (``_heads_to_rows``: the norm, the rotation, the
+  softmax scale, a grouped head's copies and the kernels' layout, a
+  backward written by hand), which the tests hold to the plain form.
 - MoE: router logits ``r = x Wg``, ``p = softmax(r)`` over all experts in
   float32, the ``experts_per_token`` largest ``p`` kept with their values
   as weights, not renormalised; expert ``e`` is
@@ -123,7 +127,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import flash_attention
+from ..ops import flash_attention_rows
 from .transformer import (
     _dense_init,
     _rmsnorm,
@@ -329,15 +333,16 @@ def _yarn_ramp(yarn: Yarn, theta: float, head_dim: int) -> jax.Array:
     return jnp.clip((pairs - low) / max(high - low, 1e-3), 0.0, 1.0)
 
 
-def rope(
-    x: jax.Array, theta: float, yarn: Optional[Yarn] = None,
-    positions: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Rotary embedding of ``x`` (B, S, H, head_dim) at ``positions`` (S,),
-    0..S-1 where none are stated: the pair (``i``, ``i + head_dim / 2``)
-    turns by ``pos * theta ** (-2 i / head_dim)``, or by ``yarn``'s blend
-    of that frequency (``Yarn``). Computed in float32, rounded once."""
-    S, half = x.shape[1], x.shape[-1] // 2
+def _rotary_angles(
+    S: int, head_dim: int, theta: float, yarn: Optional[Yarn],
+    positions: Optional[jax.Array],
+) -> Tuple[jax.Array, jax.Array]:
+    """The cosine and the sine, (S, head_dim / 2) float32 each, by which the
+    pair (``i``, ``i + head_dim / 2``) turns at each of ``positions`` (S,),
+    0..S-1 where none are stated: the angle is ``pos * theta ** (-2 i /
+    head_dim)``, or ``pos`` times ``yarn``'s blend of that frequency
+    (``Yarn``), whose ``attention_factor`` multiplies both."""
+    half = head_dim // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     if yarn is not None:
         ramp = _yarn_ramp(yarn, theta, 2 * half)
@@ -345,12 +350,166 @@ def rope(
     if positions is None:
         positions = jnp.arange(S, dtype=jnp.float32)
     angle = positions.astype(jnp.float32)[:, None] * inv_freq  # (S, half)
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
     if yarn is not None:
         cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
+    return cos, sin
+
+
+def rope(
+    x: jax.Array, theta: float, yarn: Optional[Yarn] = None,
+    positions: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Rotary embedding of ``x`` (B, S, H, head_dim) at ``positions`` (S,),
+    0..S-1 where none are stated: the pair (``i``, ``i + head_dim / 2``)
+    turns by ``pos * theta ** (-2 i / head_dim)``, or by ``yarn``'s blend
+    of that frequency (``Yarn``). Computed in float32, rounded once. The
+    plain form: the step runs it inside ``_heads_to_rows``, which the
+    tests hold to this one."""
+    cos, sin = _rotary_angles(x.shape[1], x.shape[-1], theta, yarn, positions)
+    cos, sin = cos[:, None, :], sin[:, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return turned.astype(x.dtype)
+
+
+def rotary_tables(
+    S: int, head_dim: int, theta: float, yarn: Optional[Yarn] = None,
+    positions: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """``rope``'s rotation over WHOLE rows of ``head_dim`` lanes, as two
+    tables (S, head_dim) float32: ``turned = x * cos + swap(x) * sin``,
+    ``swap`` the exchange of a row's two halves, ``cos`` the cosine twice
+    over and ``sin`` the sine with its first half negated - the same pairs
+    and the same products as ``rope``'s, with no row cut in two."""
+    cos, sin = _rotary_angles(S, head_dim, theta, yarn, positions)
+    return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)
+
+
+def _swap_halves(x: jax.Array) -> jax.Array:
+    """The two halves of every row of ``head_dim`` lanes exchanged, as a
+    product with the exchange's 0/1 matrix on the MXU, which the step
+    leaves idle here: exact in any type (an output is one input times 1),
+    and no lane moves in a vector unit, where XLA makes passes of its own
+    of a row's slices (PERF.md section 6, PR 48)."""
+    dh = x.shape[-1]
+    exchange = jnp.roll(jnp.eye(dh, dtype=x.dtype), dh // 2, axis=1)
+    return jnp.einsum(
+        "...d,de->...e", x, exchange, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=x.dtype,
+    )
+
+
+@dataclass(frozen=True)
+class HeadsToRows:
+    """What ``_heads_to_rows`` does to a projection's output besides laying
+    its ``heads`` out as rows: ``group`` copies of every head (a key/value
+    head's query heads), an RMSNorm with ``eps`` over each head
+    (``per_head``) or over the whole projection where the call brings a
+    scale, and ``multiplier`` on the result (the softmax scale, on q)."""
+
+    heads: int
+    group: int = 1
+    per_head: bool = False
+    eps: float = 1e-6
+    multiplier: float = 1.0
+
+    @property
+    def normed_over(self) -> Tuple[int, ...]:
+        """The axes of (B, S, heads, head_dim) one root mean square spans."""
+        return (-1,) if self.per_head else (-2, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _heads_to_rows(
+    spec: HeadsToRows, x: jax.Array, scale: Optional[jax.Array],
+    tables: Optional[Tuple[jax.Array, jax.Array]],
+) -> jax.Array:
+    """A projection's output ``x`` (B, S, heads x head_dim) as the flash
+    kernels' rows (B x heads x group, S, head_dim), in ONE pass over its
+    bytes: the RMSNorm by ``scale`` (none: no norm), the rotation by
+    ``tables`` (``rotary_tables``; none: no rotation, a value projection),
+    ``spec.multiplier`` and the copies of a grouped head, all in float32
+    on values read once and rounded once - ``_rmsnorm`` -> ``rope`` ->
+    ``jnp.repeat`` -> the scale, without their three roundings of q and
+    two of k. The cotangent comes back in one pass too, written by hand
+    (``_heads_to_rows_bwd``): autodiff of the chain kept float32 copies of
+    q between its links and swept them one link at a time (PERF.md section
+    6, PR 48). Residuals: ``x`` as it came, in its own type, and the norm's
+    inverse roots (B, S, heads) in float32."""
+    return _heads_to_rows_fwd(spec, x, scale, tables)[0]
+
+
+def _scale_of(spec: HeadsToRows, scale: jax.Array, head_dim: int) -> jax.Array:
+    shape = (head_dim,) if spec.per_head else (spec.heads, head_dim)
+    return scale.astype(jnp.float32).reshape(shape)
+
+
+def _heads_to_rows_fwd(spec, x, scale, tables):
+    B, S, width = x.shape
+    n, dh = spec.heads, width // spec.heads
+    x = x.reshape(B, S, n, dh)
+    y, inv, w = x.astype(jnp.float32), None, None
+    if scale is not None:
+        inv = jax.lax.rsqrt(
+            jnp.mean(y * y, axis=spec.normed_over, keepdims=True) + spec.eps
+        )
+        w = _scale_of(spec, scale, dh)
+        y = y * w
+    if tables is not None:
+        # rot(x w) = x w cos + swap(x w) sin = x w cos + swap(x) swap(w)
+        # sin: x itself is exchanged, exactly, and the scale's 128 numbers;
+        # the norm's inverse root is one number for both halves of a row
+        cos, sin = tables
+        turned = _swap_halves(x).astype(jnp.float32) * sin[:, None, :]
+        if w is not None:
+            turned = turned * jnp.roll(w, dh // 2, axis=-1)
+        y = y * cos[:, None, :] + turned
+    if inv is not None:
+        y = y * inv
+    if spec.multiplier != 1.0:
+        y = y * jnp.float32(spec.multiplier)
+    y = y.astype(x.dtype).transpose(0, 2, 1, 3)[:, :, None]
+    rows = jnp.broadcast_to(y, (B, n, spec.group, S, dh)).reshape(-1, S, dh)
+    return rows, (x, scale, tables, inv)
+
+
+def _heads_to_rows_bwd(spec, res, g):
+    """One sweep from the rows' cotangent ``g`` to the projection's: a
+    grouped head's copies summed in float32, the rotation's transpose (the
+    rotation by the negative angle), the norm's backward with both of its
+    row sums, ``dx`` rounded once; the scale's gradient is the column sum
+    of ``g x_hat``, in float32."""
+    x, scale, tables, inv = res
+    B, S, n, dh = x.shape
+    g = g.reshape(B, n, spec.group, S, dh)
+    if spec.group > 1:  # a sum of slices, which a fusion reads in place
+        g = sum(g[:, :, j].astype(jnp.float32) for j in range(spec.group))
+    else:
+        g = g[:, :, 0]
+    g = g.transpose(0, 2, 1, 3)
+    if tables is not None:
+        # the transpose of rot: g cos + swap(g sin) = g cos - swap(g) sin,
+        # the signed sine changing its sign with its half; g is exchanged
+        # in the type it came in, exactly
+        cos, sin = tables
+        swapped = _swap_halves(g).astype(jnp.float32)
+        g = g.astype(jnp.float32) * cos[:, None, :] - swapped * sin[:, None, :]
+    g = g.astype(jnp.float32)  # a value projection's, still as it came
+    if spec.multiplier != 1.0:
+        g = g * jnp.float32(spec.multiplier)
+    d_scale = None
+    if scale is not None:
+        x_hat = x.astype(jnp.float32) * inv
+        d_scale = jnp.sum(
+            g * x_hat, axis=(0, 1, 2) if spec.per_head else (0, 1)
+        ).reshape(scale.shape).astype(scale.dtype)
+        g = g * _scale_of(spec, scale, dh)
+        g = inv * (g - x_hat * jnp.mean(g * x_hat, axis=spec.normed_over, keepdims=True))
+    return g.astype(x.dtype).reshape(B, S, n * dh), d_scale, None
+
+
+_heads_to_rows.defvjp(_heads_to_rows_fwd, _heads_to_rows_bwd)
 
 
 def attention(
@@ -360,26 +519,24 @@ def attention(
     B, S, _ = x.shape
     h, kv, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     q, k, v = (x @ p[w].astype(cfg.dtype) for w in ("wq", "wk", "wv"))
-    if cfg.qk_norm:
-        with jax.named_scope("qk_norm"):
-            if cfg.qk_norm_per_head:  # each head's own dh, one scale for all
-                q, k = q.reshape(B, S, h, dh), k.reshape(B, S, kv, dh)
-            q = _rmsnorm(q, p["q_norm"], cfg.rms_norm_eps)
-            k = _rmsnorm(k, p["k_norm"], cfg.rms_norm_eps)
-    q, k, v = (t.reshape(B, S, n, dh) for t, n in ((q, h), (k, kv), (v, kv)))
     # the two copies of a diffusion model's sequence both count 0..L-1
-    stated = {} if kind.block is None else {"positions": jnp.tile(jnp.arange(S // 2), 2)}
-    with jax.named_scope("rope"):
-        q, k = (rope(t, cfg.rope_theta, kind.yarn, **stated) for t in (q, k))
-    if kv != h:
-        # query head i meets key/value head i // (h / kv): each is repeated
-        # to its query heads (a grouped kernel would read it once)
-        k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+    stated = None if kind.block is None else jnp.tile(jnp.arange(S // 2), 2)
+    # query head i meets key/value head i // (h / kv): each is copied to
+    # its query heads (a grouped kernel would read it once)
+    norm = (cfg.qk_norm_per_head, cfg.rms_norm_eps)
+    of_q, of_kv = HeadsToRows(h, 1, *norm, dh ** -0.5), HeadsToRows(kv, h // kv, *norm)
+    scales = (p["q_norm"], p["k_norm"]) if cfg.qk_norm else (None, None)
+    with jax.named_scope("qk_rows"):
+        tables = rotary_tables(S, dh, cfg.rope_theta, kind.yarn, stated)
+        q = _heads_to_rows(of_q, q, scales[0], tables)
+        k = _heads_to_rows(of_kv, k, scales[1], tables)
+        v = _heads_to_rows(of_kv, v, None, None)
     # the fused kernel everywhere: compiled on a TPU, interpreted elsewhere
     if kind.block is None:
-        out = flash_attention(q, k, v, window=kind.window)
+        out = flash_attention_rows(q, k, v, window=kind.window)
     else:
-        out = flash_attention(q, k, v, causal=False, block_mask=(kind.block, S // 2))
+        out = flash_attention_rows(q, k, v, causal=False, block_mask=(kind.block, S // 2))
+    out = out.reshape(B, h, S, dh).transpose(0, 2, 1, 3)
     return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
 
 
@@ -816,9 +973,10 @@ def _block(
     kind: AttentionKind = AttentionKind(), width: Optional[int] = None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     # the dense family's scope names (transformer._block) with the new
-    # mechanisms nested in them: attn/qk_norm, attn/rope, mlp/moe/router,
+    # mechanisms nested in them: attn/qk_rows (the norm, the rotation and the
+    # kernels' layout of q, k, v: ``_heads_to_rows``), mlp/moe/router,
     # mlp/moe/dispatch, mlp/moe/experts, mlp/moe/combine; a named kind of
-    # layer puts its name between: attn/sliding/rope, attn/full/flash_fwd;
+    # layer puts its name between: attn/sliding/qk_rows, attn/full/flash_fwd;
     # a dense feed-forward (``width``) is ``mlp`` alone and has no sums.
     # Metadata only.
     eps = cfg.rms_norm_eps

@@ -12,6 +12,7 @@ from .flash_attention import (
     block_scores_computed,
     flash_attention,
     flash_attention_qkv,
+    flash_attention_rows,
 )
 from .quantize_kernels import (
     cast_bf16,
@@ -24,6 +25,7 @@ __all__ = [
     "block_scores_computed",
     "flash_attention",
     "flash_attention_qkv",
+    "flash_attention_rows",
     "cast_bf16",
     "dequantize_q8",
     "quantize_q8",

@@ -1998,6 +1998,69 @@ def flash_attention(
     B, S, H, D = q.shape
     if sm_scale is None:
         sm_scale = D ** -0.5
+    if mesh is not None:
+        spec = P(batch_axis, None, head_axis, None)
+        local = functools.partial(
+            flash_attention, causal=causal, sm_scale=sm_scale,
+            block_q=block_q, block_k=block_k, block_diag=block_diag,
+            interpret=interpret, window=window, block_mask=block_mask,
+        )
+        # check_vma=False: pallas out_shapes carry no varying-mesh-axes
+        # annotation, which the new shard_map VMA typing would reject
+        return shard_map(
+            local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
+        )(q, k, v)
+
+    # (B, S, H, D) -> (B*H, S, D). Blocks always span the full head
+    # dim, so Mosaic's "divisible by 128 OR equal to the array dim" lane
+    # rule is satisfied without padding D (padding to 128 lanes would
+    # double the QK FLOPs at the flagship head_dim of 64). This entry
+    # KEEPS these transposes (and the one back): its callers' q, k, v
+    # come out of other fusions - RoPE, an all-to-all, a shard - that
+    # write the rows wherever they are told. A fused projection goes to
+    # ``flash_attention_qkv``, which has none, and a caller whose own
+    # fusion writes the rows to ``flash_attention_rows``.
+    def to_rows(x):
+        return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+
+    # sm_scale folded into q OUTSIDE the custom_vjp: one cheap (S, D)
+    # multiply replaces a per-tile (S_q, S_k) multiply in every kernel,
+    # and autodiff of this fold rescales dq automatically (exact for
+    # power-of-two scales — head_dim 64 gives 0.125). The product is
+    # computed with an f32 scalar so the scale itself is never quantized
+    # to bf16; only the single product rounding remains.
+    q_scaled = (q * jnp.float32(sm_scale)).astype(q.dtype)
+    out = flash_attention_rows(
+        to_rows(q_scaled), to_rows(k), to_rows(v), causal=causal,
+        block_q=block_q, block_k=block_k, block_diag=block_diag,
+        interpret=interpret, window=window, block_mask=block_mask,
+    )
+    return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+
+
+def flash_attention_rows(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = True,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    block_diag: Optional[int] = None,
+    interpret: Optional[bool] = None,
+    window: Optional[int] = None,
+    block_mask: Optional[Tuple[int, int]] = None,
+) -> jax.Array:
+    """``flash_attention`` in the kernels' own layout, both ways: q, k, v
+    are (B*H, S, head_dim) rows, one head of one sequence after another,
+    the softmax scale ALREADY in q; the output and the three cotangents
+    are rows too. For a caller whose own fusion writes the rows and reads
+    their cotangents (``models/olmoe.py``: the q/k pass), so that no
+    transpose stands between it and the kernels. ``window`` and
+    ``block_mask`` as ``flash_attention`` checks them; the tiles are the
+    same ``_tiles``."""
+    _, S, D = q.shape
     if window is not None:
         if not causal:
             raise ValueError(
@@ -2013,50 +2076,14 @@ def flash_attention(
             raise ValueError(
                 f"block_mask {block_mask}: want 2 x L = {S} positions in whole blocks"
             )
-
-    if mesh is not None:
-        spec = P(batch_axis, None, head_axis, None)
-        local = functools.partial(
-            flash_attention, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, block_diag=block_diag,
-            interpret=interpret, window=window, block_mask=block_mask,
-        )
-        # check_vma=False: pallas out_shapes carry no varying-mesh-axes
-        # annotation, which the new shard_map VMA typing would reject
-        return shard_map(
-            local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False,
-        )(q, k, v)
-
     interp = _pick_interpret(interpret)
     block_q, block_k, S_pad, edges = _tiles(
         S, D, interp, block_q, block_k, block_diag, causal, window, block_mask
     )
-
-    # (B, S, H, D) -> (B*H, S_pad, D). Blocks always span the full head
-    # dim, so Mosaic's "divisible by 128 OR equal to the array dim" lane
-    # rule is satisfied without padding D (padding to 128 lanes would
-    # double the QK FLOPs at the flagship head_dim of 64). This entry
-    # KEEPS these transposes (and the one back): its callers' q, k, v
-    # come out of other fusions - RoPE, an all-to-all, a shard - that
-    # write the rows wherever they are told. A fused projection goes to
-    # ``flash_attention_qkv``, which has none.
-    def to_rows(x):
-        x = x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-        if S_pad != S:
-            x = jnp.pad(x, ((0, 0), (0, S_pad - S), (0, 0)))
-        return x
-
     cfg = (
         bool(causal), block_q, block_k, edges, interp, S,
         None if window is None else int(window), block_mask,
     )
-    # sm_scale folded into q OUTSIDE the custom_vjp: one cheap (S, D)
-    # multiply replaces a per-tile (S_q, S_k) multiply in every kernel,
-    # and autodiff of this fold rescales dq automatically (exact for
-    # power-of-two scales — head_dim 64 gives 0.125). The product is
-    # computed with an f32 scalar so the scale itself is never quantized
-    # to bf16; only the single product rounding remains.
-    q_scaled = (q * jnp.float32(sm_scale)).astype(q.dtype)
-    out = _flash(cfg, to_rows(q_scaled), to_rows(k), to_rows(v))
-    return out[:, :S].reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    if S_pad != S:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, S_pad - S), (0, 0))) for x in (q, k, v))
+    return _flash(cfg, q, k, v)[:, :S]
