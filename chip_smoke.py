@@ -445,6 +445,9 @@ FLASH_CASES = (
     ("mellum_sliding", 1, 8192, 8, 128, 1024),
     ("mellum_full", 1, 8192, 8, 128, None),
     ("sdar_block", 1, 8192, 8, 128, None, (4, 4096)),
+    # a block mask that does not tile (B 6 straddles every tile's edge):
+    # the general kernels' sweep, which no cell runs
+    ("block_general", 2, 1920, 4, 128, None, (6, 960)),
     ("windowed", 2, SEQ - 1, 4, 64, 512),
     ("general", 2, SEQ - 1, 4, 64, 500),
     ("ragged", 2, 99, 4, 64, None),

@@ -310,57 +310,102 @@ def test_auto_tiles(S, head_dim, interpret, want):
 def test_auto_tiles_of_a_window(S, window, interpret, want):
     fa = _module()
     assert fa._auto_tiles(S, 128, interpret, nested=False, window=window) == want
-    block_q, block_k, s_pad, edges = fa._tiles(
-        S, 128, interpret, None, None, None, True, window
+    schedule = fa._tiles(S, 128, interpret, None, None, None, True, window)
+    assert (schedule.block_q, schedule.block_k) == want
+    assert schedule.s_pad % schedule.block_q == 0
+    banded = window % schedule.block_k == 0 and window < S
+    assert schedule.kind == ("banded" if banded else "general")
+    assert (schedule.edges is not None) == banded
+
+
+# What each cell of BENCHMARK.json runs today, asked of ``_tiles`` at the
+# cell's published attention shapes as the chip is asked (``interpret``
+# False): (in-model positions, head size, window, block mask) -> the kind
+# and the tiles. A PR that moves a cell onto another schedule changes this
+# table and says so. Last, the three block-mask shapes of
+# ``tests/test_tpu_lowering.py`` that do not tile: the general kernels.
+@pytest.mark.parametrize(
+    "cell,S,head_dim,window,block_mask,want",
+    [
+        ("gpt2s-ft1", 1024, 64, None, None, ("nested", 1024, 512, (512, 128))),
+        ("gpt2m-ft1", 1024, 64, None, None, ("nested", 1024, 512, (512, 128))),
+        ("gpt2s-raw", 1024, 64, None, None, ("nested", 1024, 512, (512, 128))),
+        ("gpt2m-raw", 1024, 64, None, None, ("nested", 1024, 512, (512, 128))),
+        ("olmoe-ft1", 4096, 128, None, None, ("nested", 512, 512, (256, 128))),
+        ("ouro-ft1", 4096, 128, None, None, ("nested", 512, 512, (256, 128))),
+        ("mellum2-ft1-sliding", 8192, 128, 1024, None, ("banded", 1024, 1024, (256, 128))),
+        ("mellum2-ft1-full", 8192, 128, None, None, ("nested", 512, 512, (256, 128))),
+        ("sdar-ft1", 8192, 128, None, (4, 4096), ("blocked", 1024, 1024, (256, 128))),
+        ("a-block-over-a-tile", 1920, 128, None, (6, 960), ("general", 128, 128, None)),
+        ("one-block-a-copy", 4096, 128, None, (2048, 2048), ("general", 512, 512, None)),
+        ("a-padded-length", 400, 128, None, (8, 200), ("general", 128, 128, None)),
+    ],
+)
+def test_the_schedule_each_cell_runs(cell, S, head_dim, window, block_mask, want):
+    schedule = _module()._tiles(
+        S, head_dim, False, None, None, None, block_mask is None, window, block_mask
     )
-    assert (block_q, block_k) == want and s_pad % block_q == 0
-    banded = fa._banded(True, window, block_q, block_k, s_pad)
-    assert banded == (window % block_k == 0 and window < S)
-    assert (edges is not None) == banded
+    assert schedule[:3] + (schedule.edges,) == want, cell
+    assert schedule.s_pad == -(-S // 128) * 128 and schedule.kv_len == S
+    # the fused entry (the dense cells') asks the same value
+    assert schedule.one_resident_block == cell.startswith("gpt2")
 
 
-# What a call runs falls out of its shapes (S 64 here): the two-level
-# schedule whole, the same cut to a window's band, or the general kernels.
+# What a call runs falls out of its shapes (S 64 here; under a block mask
+# two copies of L 32): the two-level schedule whole, the same cut to a
+# window's band or laid over the block mask, or the general kernels. The
+# ``kind`` that ``_tiles`` hands the call, and the kernel traced for it in
+# BOTH directions.
 @pytest.mark.parametrize(
     "kw,want",
     [
-        ({}, "causal"),
-        ({"window": 32, "block_q": 32, "block_k": 16}, "band"),
-        ({"window": 16, "block_q": 16, "block_k": 16}, "band"),
-        ({"window": 32, "block_q": 64, "block_k": 16}, "band"),  # one block
+        ({}, "nested"),
+        ({"window": 32, "block_q": 32, "block_k": 16}, "banded"),
+        ({"window": 16, "block_q": 16, "block_k": 16}, "banded"),
+        ({"window": 32, "block_q": 64, "block_k": 16}, "banded"),  # one block
         ({"window": 16}, "general"),  # (64, 64) tiles: no multiple of them
         ({"window": 24, "block_q": 32, "block_k": 16}, "general"),
         ({"window": 64, "block_q": 32, "block_k": 32}, "general"),  # the whole sequence
         ({"window": 32, "block_q": 16, "block_k": 32}, "general"),  # do not nest
         ({"causal": False}, "general"),
         ({"block_q": 16, "block_k": 32}, "general"),  # do not nest
+        ({"block_mask": (4, 32), "block_q": 16, "block_k": 16}, "blocked"),
+        ({"block_mask": (4, 32), "block_q": 32, "block_k": 16}, "blocked"),  # two row groups
+        ({"block_mask": (4, 32)}, "general"),  # (64, 64) tiles: a copy ends inside
+        ({"block_mask": (32, 32), "block_q": 16, "block_k": 16}, "general"),  # B over a sub-tile
+        ({"block_mask": (4, 32), "block_q": 16, "block_k": 32}, "general"),  # do not nest
     ],
     ids=lambda v: ("-".join(f"{k}{n}" for k, n in v.items()) or "plain")
     if isinstance(v, dict) else v,
 )
 def test_which_kernels_a_call_takes(kw, want, monkeypatch):
     fa = _module()
-    took = set()
-    for name, kind in (
-        ("_fwd_causal_kernel", "causal"), ("_bwd_causal_kernel", "causal"),
-        ("_fwd_kernel", "general"), ("_bwd_kernel", "general"),
-    ):
-        def spy(*a, _real=getattr(fa, name), _kind=kind, **kernel_kw):
-            banded = _kind == "causal" and kernel_kw.get("window") is not None
-            took.add((_real.__name__, "band" if banded else _kind))
+    took, handed = set(), []
+    for name in ("_fwd_causal_kernel", "_bwd_causal_kernel", "_fwd_kernel", "_bwd_kernel"):
+        def spy(*a, _real=getattr(fa, name), **kernel_kw):
+            if "causal_kernel" not in _real.__name__:
+                kind = "general"
+            elif kernel_kw.get("window") is not None:
+                kind = "banded"
+            else:
+                kind = "nested" if kernel_kw.get("block_mask") is None else "blocked"
+            took.add((_real.__name__, kind))
             return _real(*a, **kernel_kw)
 
         monkeypatch.setattr(fa, name, spy)
+    real_tiles = fa._tiles
+    monkeypatch.setattr(
+        fa, "_tiles", lambda *a, **k: handed.append(real_tiles(*a, **k)) or handed[-1]
+    )
+    if "block_mask" in kw:
+        kw = {"causal": False, **kw}
     # a head size no other test has: the calls that build a kernel are
     # traced once a shape
     q, k, v = rand_qkv(jax.random.PRNGKey(11), (1, 64, 1, 24))
     jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, **kw) ** 2))(q)
     prefix = "_" if want == "general" else "_causal_"
     assert took == {(f"_fwd{prefix}kernel", want), (f"_bwd{prefix}kernel", want)}
-    assert fa._banded(
-        kw.get("causal", True), kw.get("window"), kw.get("block_q", 64),
-        kw.get("block_k", 64), 64,
-    ) == (want == "band")
+    assert [schedule.kind for schedule in handed] == [want]
 
 
 # ---------------------------------------------------------------------------
@@ -630,7 +675,7 @@ def test_staircase_matches_plain_attention(entry, head_dim, S, blocks, monkeypat
     edges = []  # what the four calls that build a kernel are handed
     for name in ("_flash_fwd_call", "_flash_bwd_call", "_flash_fwd_qkv_call", "_flash_bwd_qkv_call"):
         def spy(*a, _real=getattr(fa, name), **kw):
-            edges.append(kw["edge"])
+            edges.extend(kw["schedule"].edges if "schedule" in kw else [kw["edge"]])
             return _real(*a, **kw)
 
         monkeypatch.setattr(fa, name, spy)
@@ -682,12 +727,17 @@ def test_the_causal_pieces_trace_the_text_they_traced_before_the_block_mask(quer
     pos = jnp.int32(7)
     assert fa._copy_rows(None, None, pos) is pos and fa._copy_rows((4, 64), 0, pos) is pos
     assert int(fa._copy_rows((4, 64), 1, pos)) == 71
-    # and the kernels whole: no block_mask, and None said aloud
+    # and the kernels whole: a causal call's schedule says no mask aloud,
+    # and the causal kernel is built with none
     q = jnp.ones((2, 256, 16), jnp.float32)
-    static = dict(causal=True, block_q=256, block_k=128, edge=64, interpret=True, kv_len=256, window=None)
-    assert str(jax.make_jaxpr(lambda: fa._flash_fwd_call(q, q, q, **static))()) == str(
-        jax.make_jaxpr(lambda: fa._flash_fwd_call(q, q, q, block=None, **static))()
+    schedule = fa._tiles(256, 16, True, 256, 128, 64)
+    assert schedule == fa.Schedule(
+        "nested", 256, 128, 256, (64, 64), causal=True, interpret=True, kv_len=256,
+        window=None, block=None,
     )
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda: fa._flash_fwd_call(q, q, q, schedule=schedule)
+    )())
 
 
 @pytest.mark.parametrize("j", [127, 128, 255, 256, 511, 512])

@@ -400,8 +400,33 @@ def _planted(on_first, on_second, n=256):
     return _share(cfg, dict(p, router=router), 0, 2) + (x,)
 
 
+def _held_dense(cfg, p, tokens, weights, chosen):
+    """The reference that ``olmoe._held_share`` is held to, output and
+    gradients: the held experts' part of the layer's output, (N, D)
+    float32, and how many of the N x K claims they hold - every held
+    expert applied to every token and kept, times its weight, where the
+    token chose it. Exact and dropless by construction; its work is
+    N x held rows whatever the routing. (The step's own form until PR 40.)"""
+    first, held = cfg.held
+    # (N, held): the token's weight on each held expert, 0 where it chose
+    # another
+    mine = (chosen - first)[:, :, None] == jnp.arange(held)
+    gate = jnp.sum(jnp.where(mine, weights[:, :, None], 0.0), axis=1)
+
+    def into(w):  # (held, N, f)
+        return jnp.einsum("nd,edf->enf", tokens, p[w].astype(cfg.dtype))
+
+    hidden = jax.nn.silu(into("w_gate")) * into("w_up")
+    hidden = (hidden * gate.T[:, :, None]).astype(cfg.dtype)
+    y = jnp.einsum(
+        "enf,efd->nd", hidden, p["w_down"].astype(cfg.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    return y, jnp.sum(mine)
+
+
 def _dense_share(cfg, p, tokens, weights, chosen):
-    y, held_claims = olmoe._held_dense(cfg, p, tokens, weights, chosen)
+    y, held_claims = _held_dense(cfg, p, tokens, weights, chosen)
     return y, held_claims, jnp.zeros((), jnp.float32)
 
 
@@ -523,7 +548,7 @@ def test_the_grouped_share_multiplies_a_fifth_of_the_dense_shares_rows():
     light = flops({tile: tiles, n: 0}, grouped, *abstract)
     one_heavy = flops({tile: 0, n: 1}, grouped, *abstract)
     dense = flops(
-        {}, lambda tokens, w_in, w_down, *routing: olmoe._held_dense(
+        {}, lambda tokens, w_in, w_down, *routing: _held_dense(
             cfg, {"w_gate": w_in[..., :f], "w_up": w_in[..., f:], "w_down": w_down},
             tokens, *routing,
         )[0],

@@ -6,7 +6,7 @@ sequences of 32 tokens in blocks of 4 - 64 positions in the stack, a clean
 and a noised copy.
 
 TOLERANCES, and why. In float32 the program and the reference compute the
-same mathematics in another order (flash tiles walked by the mask against a
+same mathematics in another order (flash tiles under the mask against a
 dense softmax under the mask written out pair by pair; the held share's
 tiles against the reference's loop over the held experts; a custom backward
 pass of the cross entropy against autodiff's), so they differ by float32
@@ -201,8 +201,8 @@ def _qkv(length, heads=2, dh=16, seed=0):
 # row groups a resident block (32, 16), with B the edge (16), a finer edge (8)
 # and the whole one; B = L = the one sub-tile of a copy; one resident block a
 # copy; three of them (the loop runs 0, 1 and 2 trips) - and every other
-# keeps the general walk: B = L over several tiles (the own-quadrant tiles
-# shown whole) and B over a sub-tile; a copy that ends inside a tile (L 24 on
+# keeps the general kernels' sweep: B = L over several tiles (the own-quadrant
+# tiles shown whole) and B over a sub-tile; a copy that ends inside a tile (L 24 on
 # tiles of 16); a block that straddles a tile's edge (B 6 on tiles of 8 and
 # 16; B 12 on 8 and 16); a padded length (2 L = 40 on tiles of 16); blocks
 # that do not nest (8, 16), two row groups of queries a key tile
@@ -218,14 +218,15 @@ CASES = [
 
 def _schedule(length, block, block_q=None, block_k=None, head_dim=16, interpret=True):
     """(block_q, block_k, static): the tiles such a call runs, and whether
-    on the static schedule - the predicate the call and the counter ask."""
-    block_q, block_k, s_pad, edges = flash_module._tiles(
+    on the static schedule - ``_tiles``' answer, which the call and the
+    counter read."""
+    schedule = flash_module._tiles(
         2 * length, head_dim, interpret, block_q, block_k, None, causal=False,
         block_mask=(block, length),
     )
-    static = flash_module._blocked((block, length), block_q, block_k, s_pad)
-    assert static == (edges is not None)
-    return block_q, block_k, static
+    assert schedule.kind in ("blocked", "general")
+    assert (schedule.kind == "blocked") == (schedule.edges is not None)
+    return schedule.block_q, schedule.block_k, schedule.kind == "blocked"
 
 
 @pytest.mark.parametrize("length,block,block_q,block_k,block_diag,static", CASES)
@@ -236,11 +237,11 @@ def test_the_block_mask_is_dense_attention_under_the_mask(
     seen = _visible(length, block)
     assert seen.sum() == length * length + length * block
     assert _schedule(length, block, block_q, block_k)[-1] == static
-    # which kernels a call traces: the general ones walk, the causal ones do not
-    walks = []
-    real_walk = flash_module._walk
+    # which path THIS call takes: the schedule it is handed
+    handed = []
+    real_tiles = flash_module._tiles
     monkeypatch.setattr(
-        flash_module, "_walk", lambda runs: walks.append(len(runs)) or real_walk(runs)
+        flash_module, "_tiles", lambda *a, **kw: handed.append(real_tiles(*a, **kw)) or handed[-1]
     )
 
     def flash(q, k, v):
@@ -262,16 +263,16 @@ def test_the_block_mask_is_dense_attention_under_the_mask(
     np.testing.assert_allclose(out, want_out, rtol=0, atol=KERNEL_ATOL_F32)
     for a, b in zip(grads, want):
         np.testing.assert_allclose(a, b, rtol=0, atol=KERNEL_ATOL_F32 * max(1.0, float(jnp.max(jnp.abs(b)))))
-    assert bool(walks) != static, walks  # forward and backward, or neither
+    assert {schedule.kind for schedule in handed} == {"blocked" if static else "general"}
     # either schedule computes whole pieces: never fewer pairs than the mask
-    # shows, and the backward's walk is the forward's transposed where nothing
-    # is padded (the static schedule's: at one edge)
+    # shows, and the backward's sweep is the forward's transposed on square
+    # tiles where nothing is padded (the static schedule's: at one edge)
     fwd, bwd = (
         block_scores_computed((block, length), 16, block_q=block_q, block_k=block_k, backward=b)
         for b in (False, True)
     )
     assert fwd >= seen.sum() and bwd >= seen.sum()
-    if (2 * length) % max(block_q, block_k) == 0:
+    if static or (block_q == block_k and (2 * length) % block_q == 0):
         assert fwd == bwd
     if static and block_diag is None:
         # the static schedule's pieces, counted by hand: each copy's causal
@@ -286,8 +287,8 @@ def test_the_block_mask_is_dense_attention_under_the_mask(
 L, B = 16, 4
 # the tiles of the three exactness tests below: two that run the static
 # schedule (one row group a resident block, and two) and one that keeps the
-# general walk (blocks that do not nest), on which they ran before PR 47
-TILES = {"static": (8, 8), "static_two_row_groups": (16, 8), "walk": (8, 16)}
+# general kernels' sweep (blocks that do not nest)
+TILES = {"static": (8, 8), "static_two_row_groups": (16, 8), "sweep": (8, 16)}
 _tiles_now = TILES["static"]
 
 
@@ -340,7 +341,7 @@ def test_a_clean_query_sees_no_noised_key(tiles):
 
 def test_the_first_noised_block_sees_only_itself(tiles):
     """Rows whose visible keys all lie in one tile, and not the first the
-    walk could meet: the softmax over the block's own four noised keys."""
+    sweep meets: the softmax over the block's own four noised keys."""
     q, k, v = _qkv(L)
     rows = slice(L, L + B)
     got = _flash(q, k, v)[0, rows]
@@ -367,10 +368,11 @@ def test_the_schedule_at_the_cells_shape(monkeypatch):
     staircases) and the own quadrant's 4,096 / edge diagonal chunks, at the
     forward's edge of 256 and the backward's of 128: 1.124 and 1.061 times
     the mask's 16,793,600 pairs; the count follows the edge and not the tiles.
-    Until PR 47 the general kernels walked 80 tiles of 512 x 512 (1.25), and
-    would still (the count with the predicate held off); they do walk a shape
-    that does not tile - B 2048, which no sub-tile holds: 96 tiles, all whole.
-    A sweep of the causal half of the 8,192 rows is 136."""
+    A shape that does not tile - B 2048, which no sub-tile holds - runs the
+    general kernels' sweep on (512, 512): every key tile up to B - 1 past a
+    query tile's last row, 190 of the 256 tiles (the mask shows 96 whole); so
+    would the cell's shape with the predicate held off: 151 tiles, the causal
+    half of the 8,192 rows (136) and the tile past each diagonal."""
     assert _schedule(4096, 4, head_dim=128, interpret=False) == (1024, 1024, True)
     assert _schedule(4096, 2048, head_dim=128, interpret=False) == (512, 512, False)
     whole, stair = 1024 * 1024, lambda edge, tile=1024: tile * (tile + edge) // 2
@@ -383,7 +385,7 @@ def test_the_schedule_at_the_cells_shape(monkeypatch):
         ) == 2 * (28 * 512 * 512 + 8 * stair(edge, 512)) + 4096 * edge == pairs
         assert block_scores_computed(
             (2048, 4096), 128, backward=backward, interpret=False
-        ) == 96 * 512 * 512 == 4096 * 4096 + 4096 * 2048
+        ) == 190 * 512 * 512 >= 96 * 512 * 512 == 4096 * 4096 + 4096 * 2048
     family = common.load_family("sdar_lm")
     cfg = family.build(_sizes())
     assert family.block_flash(cfg, 4096) == {
@@ -393,7 +395,7 @@ def test_the_schedule_at_the_cells_shape(monkeypatch):
     for backward in (False, True):
         assert block_scores_computed(
             (4, 4096), 128, backward=backward, interpret=False
-        ) == 80 * 512 * 512
+        ) == 151 * 512 * 512
 
 
 # ---------------------------------------------------------------------------

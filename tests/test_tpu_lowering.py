@@ -51,20 +51,19 @@ def test_flash_forward_and_backward_lower(head_dim, seq, window):
 def _runs_the_static_schedule(length, block, tile=None, head_dim=128):
     """Whether a ``block_mask`` call of these shapes, on the chip's tiles
     (or sub-tiles of ``tile``), runs the two-level static schedule: the
-    predicate the call asks."""
+    ``kind`` the call is handed."""
     flash = sys.modules["torchft_tpu.ops.flash_attention"]
-    block_q, block_k, s_pad, _ = flash._tiles(
+    return flash._tiles(
         2 * length, head_dim, False, tile, tile, None, causal=False,
         block_mask=(block, length),
-    )
-    return flash._blocked((block, length), block_q, block_k, s_pad)
+    ).kind == "blocked"
 
 
 # (L, B, sub-tile, static): the benchmark's shape, which runs the two-level
 # static schedule; one block a copy and one sub-tile (the staircases whole);
 # a block that is no power of two (a vector division in the masks) on
 # sub-tiles that hold it, which a call has to name; and three that keep the
-# general walk: such a block on the tiles a call chooses, which a copy's end
+# general sweep: such a block on the tiles a call chooses, which a copy's end
 # and a block straddle; one block a copy, over four sub-tiles; a padded length
 @pytest.mark.parametrize(
     "length,block,tile,static",

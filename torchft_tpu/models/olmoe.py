@@ -68,8 +68,8 @@ file (``models/mellum.py`` is one):
   multiplies each tile by its expert's weights. No claim is dropped and no
   form is chosen by hand; ``moe_layer``'s ``held_dense_layers`` is the
   share of the layer's held experts that were applied to every token.
-  ``_held_dense`` - every held expert on every token - is what this
-  replaced in the step and what the tests hold it to;
+  Every held expert on every token is what this replaced in the step and
+  what the tests hold it to (the dense reference in ``tests/test_mellum.py``);
 - the block's other variations: a feed-forward KIND per layer
   (``dense_ff``: the experts, or one dense SwiGLU of a stated width,
   ``W_down (silu(W_gate x) * W_up x)``, under the scope ``mlp`` alone),
@@ -597,37 +597,6 @@ def _experts(
     return grouped(hidden, p["w_down"])
 
 
-def _held_dense(
-    cfg: OlmoeConfig, p: Dict[str, Any], tokens: jax.Array,
-    weights: jax.Array, chosen: jax.Array,
-) -> Tuple[jax.Array, jax.Array]:
-    """The held experts' part of the layer's output, (N, D) float32, and
-    how many of the N x K claims they hold: every held expert applied to
-    every token and kept, times its weight, where the token chose it.
-    Exact and dropless by construction; its work is N x held rows whatever
-    the routing. The step runs ``_held_share``; this is the form the tests
-    hold it to, output and gradients."""
-    first, held = cfg.held
-    with jax.named_scope("dispatch"):
-        # (N, held): the token's weight on each held expert, 0 where it
-        # chose another
-        mine = (chosen - first)[:, :, None] == jnp.arange(held)
-        gate = jnp.sum(jnp.where(mine, weights[:, :, None], 0.0), axis=1)
-    with jax.named_scope("experts"):
-        def into(w: str) -> jax.Array:  # (held, N, f)
-            return jnp.einsum("nd,edf->enf", tokens, p[w].astype(cfg.dtype))
-
-        hidden = jax.nn.silu(into("w_gate")) * into("w_up")
-    with jax.named_scope("combine"):
-        hidden = (hidden * gate.T[:, :, None]).astype(cfg.dtype)
-    with jax.named_scope("experts"):
-        y = jnp.einsum(
-            "enf,efd->nd", hidden, p["w_down"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
-    return y, jnp.sum(mine)
-
-
 # The widest tile of a share's buffer, in rows: a multiple of the MXU's 128
 # at which a tile's matmul against its expert's (d, f) weights stays above
 # the v5e's ridge of 240 FLOP a byte (2 T d f operations over 2 (d f + T d +
@@ -690,7 +659,8 @@ def _held_experts(
 
     - over the light experts' tiles in use: a tile's rows gathered from
       ``tokens``, gate and up in one matmul, the claim's weight applied
-      where ``_held_dense`` applies it, down, the tile's float32 rows
+      where the dense reference (``tests/test_mellum.py``) applies it,
+      down, the tile's float32 rows
       added to their tokens (one scatter-add a tile, inside the loop:
       eight tiles at a time in a loop of their own took twice as long a
       row on a v5e, PERF.md section 6, PR 40);
@@ -717,7 +687,7 @@ def _held_experts_fwd(cfg, tokens, w_in, w_down, gate, layout):
     def out_of(into, weight, e):  # (rows, D) float32
         with jax.named_scope("experts"):
             hidden = _swiglu(cfg, into)
-        with jax.named_scope("combine"):  # rounded as ``_held_dense`` rounds it
+        with jax.named_scope("combine"):  # rounded as the dense reference rounds it
             hidden = (hidden * weight[:, None]).astype(cfg.dtype)
         with jax.named_scope("experts"):
             return jnp.dot(hidden, _tile(w_down, e), preferred_element_type=jnp.float32)

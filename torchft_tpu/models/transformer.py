@@ -59,12 +59,6 @@ class TransformerConfig:
     # attention runs through the kernel. Ignored by cp_strategy="ring"
     # (that path fuses its own online-softmax loop).
     use_flash: bool = False
-    # Flash-kernel VMEM tile overrides. None = ops.flash_attention's
-    # ``_auto_tiles``, measured on the v5e inside the whole gpt2-small and
-    # gpt2-medium steps (PERF.md section 6, PR 25: (1024, 512) at S 1024);
-    # that sweep ran through these two fields.
-    flash_block_q: Any = None
-    flash_block_k: Any = None
     # Sliding-window (local) attention width; requires use_flash (the
     # kernel skips out-of-window tiles). None = full causal attention.
     attn_window: Any = None
@@ -398,10 +392,7 @@ def _attention_impl(cfg: TransformerConfig, p: Dict[str, Any], x: jax.Array) -> 
         # projection reads them - no split, transpose or copy between
         from ..ops import flash_attention_qkv
 
-        out = flash_attention_qkv(
-            qkv, cfg.n_heads,
-            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-        )
+        out = flash_attention_qkv(qkv, cfg.n_heads)
         return out @ p["wo"].astype(cfg.dtype)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
@@ -422,8 +413,6 @@ def _attention_impl(cfg: TransformerConfig, p: Dict[str, Any], x: jax.Array) -> 
                 batch_axis=cfg.cp_batch_axis,
                 head_axis=cfg.cp_head_axis,
                 use_flash=cfg.use_flash,
-                block_q=cfg.flash_block_q,
-                block_k=cfg.flash_block_k,
             ).reshape(B, S, D)
         else:
             out = ring_attention(
@@ -444,8 +433,6 @@ def _attention_impl(cfg: TransformerConfig, p: Dict[str, Any], x: jax.Array) -> 
             batch_axis=cfg.cp_batch_axis if cfg.cp_mesh is not None else None,
             head_axis=cfg.cp_head_axis,
             window=cfg.attn_window,
-            block_q=cfg.flash_block_q,
-            block_k=cfg.flash_block_k,
         ).reshape(B, S, D)
         return out @ p["wo"].astype(cfg.dtype)
 

@@ -96,9 +96,7 @@ that ``_auto_tiles`` chooses, where the band is the two staircases alone
 - for a band of 7,864,832, where the general kernels' 45 masked tiles
 of 512 x 512 are 11,796,480 (``_scores_computed``): Mellum2's three
 sliding layers, 5.25 + 7.30 ms a layer in the general kernels and
-3.53 + 4.88 on the band (PERF.md section 6, PR 37). A window that is no
-multiple of ``block_k`` or as long as the sequence, blocks that do not
-nest and non-causal calls keep the general kernels below.
+3.53 + 4.88 on the band (PERF.md section 6, PR 37).
 
 A call under the BLOCK-DIFFUSION mask (``block_mask`` = (B, L): a clean
 and a noised copy of one sequence in the 2 L rows, a query seeing later
@@ -129,19 +127,23 @@ pairs (``block_scores_computed``: 1.124, 1.061), 5.03 + 8.21 ms a layer
 of 64 head-rows where the general kernels' walk took 10.02 + 15.07
 (PERF.md section 6, PR 47).
 
-Every other ``block_mask`` call - a copy that ends inside a tile, a
-diffusion block that straddles a sub-tile's edge, a padded length -
-runs the general kernels below with their tile loop taught the mask:
-with the clean copy first every visible key is at most B - 1 past its
-query, so a query tile walks the clean key tiles up to its diagonal and
-then its own noised blocks' tiles (``_block_key_runs``; the backward the
-same runs transposed, ``_block_query_runs``), the empty quadrant and
-everything between the runs is never met, the tiles strictly under a
-quadrant's diagonal take the recurrence without mask or guard
-(``_block_whole``) and only the tiles on an edge are masked
-(``_tile_mask``): at L 4096, B 4 that walk is 80 tiles of 512 x 512
-(1.25 times the visible pairs), where a sweep of the causal half of the
-2 L rows is 136.
+WHICH schedule a call runs is decided once, by ``_tiles``, from shapes
+alone, and carried as a ``Schedule`` whose ``kind`` the calls that build
+the kernels, the fused entry and the counter read: the two-level one
+whole (``nested``), cut to a band (``banded``), laid over the block mask
+(``blocked``), or the GENERAL kernels - non-causal calls, blocks that do
+not nest, a window that is no multiple of ``block_k`` or as long as the
+sequence, a block mask whose copy ends inside a tile, whose diffusion
+block straddles a sub-tile's edge or whose length is padded. Those keep
+one (block_q, block_k) tile a loop step under ``_tile_mask``, -inf and
+its guards, the loop bounded by what the mask hides from a whole tile:
+the block diagonal (under a block mask, B - 1 past it: with the clean
+copy first every visible key is at most B - 1 past its query) and the
+window's far edge. A dynamic trip count lowers to ``while_loop``: nothing
+is unrolled or overlapped across trips, which is what the two-level
+schedule avoids. No cell runs them, and no block mask a deployment trains
+on: any power-of-two B up to 1,024 at a length of whole tiles is
+``blocked``.
 
 The causal path also uses a finite -1e30 mask value instead of -inf,
 which removes every ``isfinite`` guard from the online-softmax
@@ -153,9 +155,7 @@ special cases. It applies NO padding mask at all: causality already
 hides a padded key from every live query; in the backward padded k/v
 rows are zeros, so padded-column score/probability garbage contributes
 exactly 0 to dq (``ds @ k`` hits zero rows) and only to dk/dv rows that
-are sliced off; padded query rows carry zero cotangents. The general
-path (a window the band cannot express, blocks that do not nest,
-non-causal) keeps one tile a loop step and per-tile masks.
+are sliced off; padded query rows carry zero cotangents.
 
 Design notes (pallas_guide.md):
 - all matmuls request ``preferred_element_type=float32`` so the MXU
@@ -166,11 +166,7 @@ Design notes (pallas_guide.md):
   128 lanes would double the QK FLOPs at the flagship head_dim of 64);
   arbitrary sequence lengths ARE padded — up to the block multiple, with
   padded keys masked in-kernel and padded queries carrying zero
-  cotangents;
-- the general causal kernels bound their ``fori_loop`` by the block
-  diagonal so masked-out tiles are never computed (dynamic trip counts
-  lower to ``while_loop``: nothing is unrolled or overlapped across
-  trips, which is what the two-level schedule avoids).
+  cotangents.
 
 Off-TPU the same kernels run under ``interpret=True`` so CPU tests and the
 virtual-device dryrun exercise the identical code path.
@@ -184,7 +180,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -236,110 +232,6 @@ def _blocks_in(x, size: int):
     if size & (size - 1) == 0:
         return x >> (size.bit_length() - 1)
     return jax.lax.div(x, jnp.int32(size))
-
-
-def _block_whole(q0, k0, block_q: int, block_k: int, block: Tuple[int, int]):
-    """True where the block-diffusion mask shows EVERY pair of the tile of
-    queries ``q0 .. q0 + block_q`` and keys ``k0 .. k0 + block_k``, so the
-    tile needs no mask: clean keys under clean queries whose blocks all
-    come no earlier, clean keys under noised queries whose blocks all come
-    later, or one block's noised rows against themselves. From scalars
-    (Python ints or traced); a tile that crosses a copy's end is never
-    whole."""
-    size, length = block
-    q1, k1 = q0 + block_q, k0 + block_k
-
-    def blk(pos):  # of a position in either copy
-        return _blocks_in(jnp.where(pos >= length, pos - length, pos), size)
-
-    clean_keys, noised_keys = k1 <= length, (k0 >= length) & (k1 <= 2 * length)
-    noised_rows = (q0 >= length) & (q1 <= 2 * length)
-    return (
-        ((q1 <= length) & clean_keys & (blk(k1 - 1) <= blk(q0)))
-        | (noised_rows & clean_keys & (blk(k1 - 1) < blk(q0)))
-        | (noised_rows & noised_keys & (blk(k0) == blk(q1 - 1)) & (blk(k1 - 1) == blk(q0)))
-    )
-
-
-def _runs(*runs):
-    """Runs of tile numbers ``(lo, hi)`` in ascending order, each cut to
-    start no earlier than the one before it ended (an empty or swallowed
-    run comes out with ``lo == hi``): no tile is in two of them."""
-    out, end = [], 0
-    for live, lo, hi in runs:
-        lo = jnp.maximum(jnp.where(live, lo, 0), end)
-        end = jnp.maximum(jnp.where(live, hi, 0), lo)
-        out.append((lo, end))
-    return out
-
-
-def _own_blocks_run(p0, p1, tile: int, block: Tuple[int, int]):
-    """The run of tiles (``tile`` rows each) that hold the noised copy's
-    blocks of the noised positions among ``p0 .. p1``: what those rows
-    and keys see of each other. ``(live, lo, hi)``."""
-    size, length = block
-    first_blk = _blocks_in(jnp.clip(p0, length, 2 * length - 1) - length, size)
-    last_blk = _blocks_in(jnp.clip(p1, length + 1, 2 * length) - 1 - length, size)
-    return (
-        (p1 > length) & (p0 < 2 * length), (length + first_blk * size) // tile,
-        _cdiv(length + jnp.minimum((last_blk + 1) * size, length), tile),
-    )
-
-
-def _block_key_runs(q0, block_q: int, block_k: int, block: Tuple[int, int]):
-    """The key tiles that a tile of queries from ``q0`` on meets under the
-    block-diffusion mask, as two runs of tile numbers: the clean keys any
-    of its rows sees (from key 0 to the end of the last clean row's own
-    block, or of the block before the last noised row's) and the noised
-    keys of its noised rows' own blocks. With the clean copy first every
-    visible key is at most B - 1 past its query, so the first run ends by
-    the diagonal tile and the second is the noised quadrant's diagonal
-    tiles alone. What lies between the runs is hidden and never met."""
-    size, length = block
-    q1 = q0 + block_q
-    own = _own_blocks_run(q0, q1, block_k, block)
-    last_clean_blk = _blocks_in(jnp.clip(q1, 1, length) - 1, size)
-    last_noised_blk = _blocks_in(jnp.clip(q1, length + 1, 2 * length) - 1 - length, size)
-    seen_clean = jnp.maximum(
-        jnp.where(q0 < length, jnp.minimum((last_clean_blk + 1) * size, length), 0),
-        jnp.where(own[0], last_noised_blk * size, 0),
-    )
-    return _runs((True, 0, _cdiv(seen_clean, block_k)), own)
-
-
-def _block_query_runs(k0, block_q: int, block_k: int, block: Tuple[int, int]):
-    """``_block_key_runs`` transposed: the query tiles that meet a tile of
-    keys from ``k0`` on, as three runs: the clean rows from its first
-    clean key's block on, the noised rows of its noised keys' own blocks,
-    and the noised rows of the blocks AFTER its first clean key's."""
-    size, length = block
-    has_clean = k0 < length
-    first_clean_blk = _blocks_in(jnp.minimum(k0, length - 1), size)
-    return _runs(
-        (has_clean, first_clean_blk * size // block_q, _cdiv(length, block_q)),
-        _own_blocks_run(k0, k0 + block_k, block_q, block),
-        (
-            has_clean, (length + jnp.minimum((first_clean_blk + 1) * size, length)) // block_q,
-            _cdiv(2 * length, block_q),
-        ),
-    )
-
-
-def _walk(runs):
-    """``(tile_of, steps)`` of a walk over ``runs`` one after another:
-    the tile number of its step ``t``, and how many steps it has."""
-    firsts, steps = [], 0
-    for lo, hi in runs:
-        firsts.append(steps)
-        steps = steps + hi - lo
-
-    def tile_of(t):
-        tile = runs[-1][0] + t - firsts[-1]
-        for (lo, _), first, nxt in zip(runs[-2::-1], firsts[-2::-1], firsts[:0:-1]):
-            tile = jnp.where(t < nxt, lo + t - first, tile)
-        return tile
-
-    return tile_of, steps
 
 
 def _tile_mask(
@@ -465,7 +357,7 @@ def _blocked(block, block_q: int, block_k: int, s_pad: int) -> bool:
     the blocks nest, each copy is whole resident blocks with nothing
     padded, and a sub-tile holds whole diffusion blocks, so that every
     staircase's edge can. From shapes alone; every other ``block_mask``
-    call keeps the general kernels' walk."""
+    call keeps the general kernels."""
     if block is None:
         return False
     size, length = block
@@ -473,6 +365,33 @@ def _blocked(block, block_q: int, block_k: int, s_pad: int) -> bool:
         block_q % block_k == 0 and length % block_q == 0
         and 2 * length == s_pad and block_k % size == 0
     )
+
+
+class Schedule(NamedTuple):
+    """A call as ``_tiles`` decides it, once: which of the four schedules
+    it runs - the two-level static one whole (``nested``), cut to a
+    window's band (``banded``) or laid over the block-diffusion mask
+    (``blocked``), or the ``general`` kernels - on which blocks and
+    staircase edges ((forward, backward); None on the general kernels),
+    with what the kernels are built from beside them. The static argument
+    of ``_flash``; nothing after ``_tiles`` asks the predicates again."""
+
+    kind: str
+    block_q: int
+    block_k: int
+    s_pad: int
+    edges: Optional[Tuple[int, int]]
+    causal: bool
+    interpret: bool
+    kv_len: int
+    window: Optional[int]
+    block: Optional[Tuple[int, int]]
+
+    @property
+    def one_resident_block(self) -> bool:
+        """Nested, the whole padded sequence resident: what the fused
+        entry's kernels run, and where the backward writes dq finished."""
+        return self.kind == "nested" and self.block_q == self.s_pad
 
 
 def _when(live, piece, state):
@@ -792,17 +711,15 @@ def _fwd_kernel(
     kv_len: int, window, block=None,
 ):
     """The general forward (sliding window, non-causal, blocks that do
-    not nest, the block-diffusion mask): one (block_q, block_k) tile a
-    loop step, per-tile masks.
+    not nest, a block-diffusion mask whose shapes do not tile): one
+    (block_q, block_k) tile a loop step, per-tile masks.
     q arrives PRE-SCALED by sm_scale (folded outside the kernel), so
     s = q @ k.T is the final score with no per-tile S x S multiply.
 
-    Under ``block`` (B, L) the loop walks the tiles the mask shows and no
-    other (``_block_key_runs``), and a tile it shows whole
-    (``_block_whole``) takes the recurrence without mask or guard: every
-    score there is finite. A row whose visible keys all lie in one tile -
-    the first noised block sees only itself - starts from that tile with
-    the guards on."""
+    Under ``block`` (B, L) the sweep is the causal one, a block wider: a
+    row whose visible keys all lie in a later tile - the first noised
+    block sees only itself - passes the tiles before it under the
+    guards."""
     qi = pl.program_id(1)
     q = q_ref[0]  # (block_q, D), input dtype
     D = q.shape[-1]
@@ -837,29 +754,16 @@ def _fwd_kernel(
         jnp.zeros((block_q, D), jnp.float32),
     )
     num_k_live = _cdiv(kv_len, block_k)  # skip fully-padded key blocks
-    if block is not None:
-        def whole_tile(j, carry):
-            m, l, acc = carry
-            k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-            v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-            s = _dot_nt(q, k_blk)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            p = jnp.exp(s - m_new[:, None])
-            corr = jnp.exp(m - m_new)  # 0 from the initial -inf
-            return m_new, l * corr + p.sum(axis=-1), acc * corr[:, None] + _dot_f32(
-                p.astype(v_blk.dtype), v_blk
-            )
-
-        tile_of, hi = _walk(_block_key_runs(qi * block_q, block_q, block_k, block))
-
-        def step(t, carry):  # step t of the walk
-            j = tile_of(t)
-            whole = _block_whole(qi * block_q, j * block_k, block_q, block_k, block)
-            return jax.lax.cond(whole, whole_tile, tile, j, carry)
-    elif causal:
+    if causal:
         # key blocks strictly above the block diagonal are fully masked
         hi = jnp.minimum(
             num_k_live, ((qi + 1) * block_q + block_k - 1) // block_k
+        )
+    elif block is not None:
+        # under the block mask a visible key is at most B - 1 past its
+        # query
+        hi = jnp.minimum(
+            num_k_live, ((qi + 1) * block_q + block[0] - 1 + block_k - 1) // block_k
         )
     else:
         hi = num_k_live
@@ -868,7 +772,7 @@ def _fwd_kernel(
         # key blocks fully left of the sliding window are masked for
         # every query row in this block
         lo = jnp.maximum(0, (qi * block_q - window + 1) // block_k)
-    m, l, acc = jax.lax.fori_loop(lo, hi, tile if block is None else step, init)
+    m, l, acc = jax.lax.fori_loop(lo, hi, tile, init)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     # lse rides a full-row (1, 1, S) block revisited across the sequential
@@ -879,22 +783,15 @@ def _fwd_kernel(
     )
 
 
-@_traced_once(
-    "causal", "block_q", "block_k", "edge", "interpret", "kv_len", "window",
-    "block",
-)
-def _flash_fwd_call(
-    q: jax.Array, k: jax.Array, v: jax.Array, *,
-    causal: bool, block_q: int, block_k: int, edge: Optional[int],
-    interpret: bool, kv_len: int, window, block=None,
-):
+@_traced_once("schedule")
+def _flash_fwd_call(q: jax.Array, k: jax.Array, v: jax.Array, *, schedule: Schedule):
     """q (pre-scaled)/k/v: (BH, S_pad, D) -> out (BH, S_pad, D),
     lse (BH, 1, S_pad) f32. Positions >= kv_len are zero padding, masked
     out of every softmax."""
     BH, S, D = q.shape
+    kind, block_q, block_k = schedule.kind, schedule.block_q, schedule.block_k
     num_q, num_k = _cdiv(S, block_q), _cdiv(S, block_k)
-    banded = _banded(causal, window, block_q, block_k, S)
-    blocked = _blocked(block, block_q, block_k, S)
+    blocked = kind == "blocked"
     qspec = pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0))
     if blocked:
         # a grid step holds both copies' row groups of the same positions:
@@ -902,17 +799,17 @@ def _flash_fwd_call(
         num_q = S // 2 // block_q
         q = q.reshape(BH, 2, S // 2, D)
         qspec = pl.BlockSpec((1, 2, block_q, D), lambda bh, qi: (bh, 0, qi, 0))
-    if blocked or banded or _nested(causal, window, block_q, block_k):
+    if kind != "general":
         kernel = functools.partial(
             _fwd_causal_kernel, block_q=block_q, block_k=block_k,
-            num_blocks=num_q, edge=edge, window=window,
-            block_mask=block if blocked else None,
+            num_blocks=num_q, edge=schedule.edges[0], window=schedule.window,
+            block_mask=schedule.block if blocked else None,
         )
     else:
         kernel = functools.partial(
-            _fwd_kernel, causal=causal,
-            block_q=block_q, block_k=block_k, num_k=num_k, kv_len=kv_len,
-            window=window, block=block,
+            _fwd_kernel, causal=schedule.causal,
+            block_q=block_q, block_k=block_k, num_k=num_k, kv_len=schedule.kv_len,
+            window=schedule.window, block=schedule.block,
         )
     row = pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0))
     held = 2 * block_q if blocked else block_q  # query rows a step holds
@@ -928,12 +825,12 @@ def _flash_fwd_call(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=schedule.interpret,
         name="flash_fwd",  # the kernel's name in a device trace
         **_resident_params(
             ((held, D), q.dtype), ((S, D), k.dtype), ((S, D), v.dtype),
             ((held, D), q.dtype), ((S,), jnp.float32),
-            wide_body=banded or blocked,
+            wide_body=kind in ("banded", "blocked"),
         ),
     )(q, k, v)
     return out.reshape(BH, S, D) if blocked else out, lse
@@ -1320,9 +1217,8 @@ def _bwd_kernel(
     causal: bool, block_q: int, block_k: int, num_q: int,
     kv_len: int, window, block=None,
 ):
-    """The general backward: one (block_q, block_k) tile a loop step;
-    under ``block`` the forward's walk transposed (``_block_query_runs``),
-    a tile the mask shows whole left unmasked."""
+    """The general backward: one (block_q, block_k) tile a loop step,
+    the forward's sweep transposed under the same per-tile masks."""
     ki = pl.program_id(1)
     k_blk = k_ref[0]  # (block_k, D), input dtype
     v_blk = v_ref[0]
@@ -1348,18 +1244,12 @@ def _bwd_kernel(
         delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
         s = _dot_nt(q_blk, k_blk)  # q pre-scaled by sm_scale
         p = jnp.exp(s - lse[:, None])
-        def masked(p):
-            ok = _tile_mask(
-                i * block_q, ki * block_k, block_q, block_k, kv_len,
-                causal, padded, window, block,
-            )
-            return p if ok is None else jnp.where(ok, p, 0.0)
-
-        if block is None:
-            p = masked(p)
-        else:
-            whole = _block_whole(i * block_q, ki * block_k, block_q, block_k, block)
-            p = jax.lax.cond(whole, lambda p: p, masked, p)
+        ok = _tile_mask(
+            i * block_q, ki * block_k, block_q, block_k, kv_len,
+            causal, padded, window, block,
+        )
+        if ok is not None:
+            p = jnp.where(ok, p, 0.0)
         dv_new = dv + _dot_tn(p.astype(do_blk.dtype), do_blk)
         dp = _dot_nt(do_blk, v_blk)
         ds = (p * (dp - delta[:, None])).astype(q_blk.dtype)  # one cast,
@@ -1375,6 +1265,10 @@ def _bwd_kernel(
         # query blocks strictly below the block diagonal see none of
         # this key block
         lo = (ki * block_k) // block_q
+    elif block is not None:
+        # under the block mask a visible query is at most B - 1 before
+        # its key
+        lo = jnp.maximum(ki * block_k - (block[0] - 1), 0) // block_q
     else:
         lo = 0
     hi = num_q
@@ -1384,26 +1278,17 @@ def _bwd_kernel(
         hi = jnp.minimum(
             num_q, ((ki + 1) * block_k - 1 + window) // block_q + 1
         )
-    if block is None:
-        dk, dv = jax.lax.fori_loop(lo, hi, tile, init)
-    else:
-        tile_of, steps = _walk(_block_query_runs(ki * block_k, block_q, block_k, block))
-        dk, dv = jax.lax.fori_loop(0, steps, lambda t, carry: tile(tile_of(t), carry), init)
+    dk, dv = jax.lax.fori_loop(lo, hi, tile, init)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-@_traced_once(
-    "causal", "block_q", "block_k", "edge", "interpret", "kv_len", "window",
-    "block",
-)
-def _flash_bwd_call(
-    q, k, v, o, lse, do, *,
-    causal: bool, block_q: int, block_k: int, edge: Optional[int],
-    interpret: bool, kv_len: int, window, block=None,
-):
+@_traced_once("schedule")
+def _flash_bwd_call(q, k, v, o, lse, do, *, schedule: Schedule):
     BH, S, D = q.shape
+    kind, block_q, block_k = schedule.kind, schedule.block_q, schedule.block_k
     num_q = S // block_q
+    blocked = kind == "blocked"
     # delta_i = sum_d do_id * o_id — one fused elementwise+reduce, not worth
     # a kernel
     delta = jnp.sum(
@@ -1412,10 +1297,7 @@ def _flash_bwd_call(
 
     row3 = pl.BlockSpec((1, S, D), lambda bh, i: (bh, 0, 0))
     row2 = pl.BlockSpec((1, 1, S), lambda bh, i: (bh, 0, 0))
-    nested = _nested(causal, window, block_q, block_k)
-    banded = _banded(causal, window, block_q, block_k, S)
-    blocked = _blocked(block, block_q, block_k, S)
-    if nested or banded or blocked:
+    if kind != "general":
         # The causal schedule applies NO padding mask: padded k/v rows
         # are zeros, so padded-column score/probability garbage adds
         # exactly 0 to dq (``ds @ k`` hits zero rows) and only reaches
@@ -1426,15 +1308,15 @@ def _flash_bwd_call(
             num_q = S // 2 // block_q
         kernel = functools.partial(
             _bwd_causal_kernel, block_q=block_q, block_k=block_k,
-            num_blocks=num_q, edge=edge, window=window,
-            block_mask=block if blocked else None,
+            num_blocks=num_q, edge=schedule.edges[1], window=schedule.window,
+            block_mask=schedule.block if blocked else None,
         )
     else:
         key_rows = block_k
         kernel = functools.partial(
-            _bwd_kernel, causal=causal,
-            block_q=block_q, block_k=block_k, num_q=num_q, kv_len=kv_len,
-            window=window, block=block,
+            _bwd_kernel, causal=schedule.causal,
+            block_q=block_q, block_k=block_k, num_q=num_q, kv_len=schedule.kv_len,
+            window=schedule.window, block=schedule.block,
         )
     kblk3 = pl.BlockSpec((1, key_rows, D), lambda bh, i: (bh, i, 0))
     if blocked:
@@ -1445,7 +1327,7 @@ def _flash_bwd_call(
     held = 2 * key_rows if blocked else key_rows  # key rows a step holds
     # over several key blocks dq is the revisited f32 accumulator (cast to
     # q.dtype below); one block writes it finished
-    dq_dtype = q.dtype if nested and num_q == 1 else jnp.float32
+    dq_dtype = q.dtype if schedule.one_resident_block else jnp.float32
 
     dq, dk, dv = pl.pallas_call(
         kernel,
@@ -1457,12 +1339,12 @@ def _flash_bwd_call(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        interpret=interpret,
+        interpret=schedule.interpret,
         name="flash_bwd",
         **_resident_params(
             ((S, D), q.dtype), ((S, D), do.dtype), ((S, D), dq_dtype),
             ((S,), jnp.float32), ((S,), jnp.float32),
-            *[((held, D), k.dtype)] * 4, wide_body=banded or blocked,
+            *[((held, D), k.dtype)] * 4, wide_body=kind in ("banded", "blocked"),
         ),
     )(q, k, v, do, lse, delta)
     if blocked:
@@ -1511,30 +1393,18 @@ def _flash_bwd_qkv_call(
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _flash(cfg, q, k, v):
-    out, _ = _flash_fwd_res(cfg, q, k, v)
+def _flash(schedule: Schedule, q, k, v):
+    out, _ = _flash_fwd_res(schedule, q, k, v)
     return out
 
 
-def _flash_fwd_res(cfg, q, k, v):
-    causal, block_q, block_k, edges, interpret, kv_len, window, block = cfg
-    out, lse = _flash_fwd_call(
-        q, k, v, causal=causal,
-        block_q=block_q, block_k=block_k, edge=edges and edges[0],
-        interpret=interpret, kv_len=kv_len, window=window, block=block,
-    )
-    out, lse = _named_residuals(out, lse)
+def _flash_fwd_res(schedule: Schedule, q, k, v):
+    out, lse = _named_residuals(*_flash_fwd_call(q, k, v, schedule=schedule))
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_res(cfg, res, g):
-    causal, block_q, block_k, edges, interpret, kv_len, window, block = cfg
-    q, k, v, out, lse = res
-    return _flash_bwd_call(
-        q, k, v, out, lse, g, causal=causal,
-        block_q=block_q, block_k=block_k, edge=edges and edges[1],
-        interpret=interpret, kv_len=kv_len, window=window, block=block,
-    )
+def _flash_bwd_res(schedule: Schedule, res, g):
+    return _flash_bwd_call(*res, g, schedule=schedule)
 
 
 _flash.defvjp(_flash_fwd_res, _flash_bwd_res)
@@ -1747,36 +1617,38 @@ def block_scores_computed(
 ) -> int:
     """(query, key) pairs ONE head computes in a ``flash_attention`` call
     under ``block_mask`` (B, L), in its forward kernel or its
-    ``backward``, on the schedule that call would run (the predicate the
-    call asks, on the tiles and edges it would choose). The static
-    schedule (``_blocked``): each copy's causal schedule of L rows
+    ``backward``, on the schedule that call would run (``_tiles``' answer
+    for it). ``blocked``: each copy's causal schedule of L rows
     (``_scores_computed``) and the own quadrant's L / edge chunks of
-    ``edge`` x ``edge``. Else the tiles the general kernels' walk meets
-    (the very runs the kernel walks) times a tile's area. The mask shows
-    L^2 + L B pairs; the rest is what whole pieces cost on the edges of
-    the three visible regions. At L 4096, B 4, head size 128: 18,874,368
-    forward (edge 256) and 17,825,792 backward (128) for 16,793,600
-    (1.124, 1.061); the walk on (512, 512) was 80 tiles, 20,971,520
-    (1.25), and a sweep of the causal half of the 2 L rows would be 136
-    tiles (2.12)."""
+    ``edge`` x ``edge``. Else the tiles of the general kernels' sweep -
+    every key tile up to B - 1 past a query tile's last row, every query
+    tile from B - 1 before a key tile's first - times a tile's area. The
+    mask shows L^2 + L B pairs; the rest is what whole pieces cost on the
+    edges of the three visible regions, and on the general kernels the
+    noised rows' hidden clean keys and most of their own quadrant. At
+    L 4096, B 4, head size 128: 18,874,368 forward (edge 256) and
+    17,825,792 backward (128) for 16,793,600 (1.124, 1.061); the sweep on
+    (512, 512) would be 151 tiles, 39,583,744 (2.36)."""
     size, length = block_mask
-    block_q, block_k, s_pad, edges = _tiles(
+    schedule = _tiles(
         2 * length, head_dim, _pick_interpret(interpret), block_q, block_k,
         None, causal=False, block_mask=block_mask,
     )
-    if _blocked(block_mask, block_q, block_k, s_pad):
-        edge = edges[1 if backward else 0]
+    block_q, block_k, s_pad = schedule.block_q, schedule.block_k, schedule.s_pad
+    if schedule.kind == "blocked":
+        edge = schedule.edges[1 if backward else 0]
         return 2 * _scores_computed(length, block_q, block_k, edge) + length * edge
-    runs_of, along, across = (
-        (_block_query_runs, block_k, block_q) if backward
-        else (_block_key_runs, block_q, block_k)
-    )
-    tiles = sum(
-        int(hi) - int(lo)
-        for start in range(0, s_pad, along)
-        for lo, hi in runs_of(start, block_q, block_k, block_mask)
-    )
-    return tiles * along * across
+    num_q, num_k = s_pad // block_q, s_pad // block_k
+    if backward:  # ``_bwd_kernel``'s loop: key tile ki meets query tiles lo..
+        tiles = sum(
+            num_q - max(ki * block_k - (size - 1), 0) // block_q for ki in range(num_k)
+        )
+    else:  # ``_fwd_kernel``'s: query tile qi meets key tiles ..hi
+        live = _cdiv(2 * length, block_k)
+        tiles = sum(
+            min(live, _cdiv((qi + 1) * block_q + size - 1, block_k)) for qi in range(num_q)
+        )
+    return tiles * block_q * block_k
 
 
 def _tiles(
@@ -1785,18 +1657,18 @@ def _tiles(
     block_diag: Optional[int], causal: bool = True,
     window: Optional[int] = None,
     block_mask: Optional[Tuple[int, int]] = None,
-) -> Tuple[int, int, int, Optional[Tuple[int, int]]]:
-    """(block_q, block_k, padded length, staircase edges) of a call: the
+) -> Schedule:
+    """The ``Schedule`` of a call, and the one place that decides it: the
     blocks it names, else ``_auto_tiles``, clamped to the sequence and
-    rounded to what the hardware stores. Arbitrary S is handled by
+    rounded to what the hardware stores; the padded length; and from
+    those shapes alone which schedule it runs (``_blocked``, ``_nested``,
+    ``_banded``, else the general kernels). Arbitrary S is handled by
     zero-padding the sequence up to the block multiple: padded keys are
     masked in-kernel (on the causal schedule only padded queries can see
     them), padded queries carry zero cotangents, so numerics are exact.
     The edges are (forward, backward) where the call runs the two-level
-    schedule, whole, cut to a window's band or laid over the
-    block-diffusion mask (``_nested``, ``_banded``, ``_blocked``:
-    ``_auto_edges``, or ``block_diag`` for both), else None. Under
-    ``block_mask`` an edge holds whole diffusion blocks: one of
+    schedule (``_auto_edges``, or ``block_diag`` for both), else None.
+    Under ``block_mask`` an edge holds whole diffusion blocks: one of
     ``_auto_edges``' that does not is the whole sub-tile, which does."""
     auto_q, auto_k = _auto_tiles(
         seq, head_dim, interpret, nested=causal and window is None,
@@ -1811,28 +1683,35 @@ def _tiles(
         block_k = _cdiv(block_k, 128) * 128
     base = block_q * block_k // math.gcd(block_q, block_k)
     s_pad = _cdiv(seq, base) * base
-    edges = None
-    blocked = _blocked(block_mask, block_q, block_k, s_pad)
-    if blocked or _nested(causal, window, block_q, block_k) or _banded(
-        causal, window, block_q, block_k, s_pad
-    ):
-        edges = _auto_edges(block_k, head_dim)
-        if blocked:
-            edges = tuple(e if e % block_mask[0] == 0 else block_k for e in edges)
-        if block_diag:
-            edge = min(block_diag, block_k)
-            if not interpret:
-                edge = _cdiv(edge, 128) * 128
-            if block_k % edge:
-                raise ValueError(
-                    f"block_diag {edge} does not divide block_k {block_k}"
-                )
-            if blocked and edge % block_mask[0]:
-                raise ValueError(
-                    f"block_diag {edge} does not hold whole blocks of {block_mask[0]}"
-                )
-            edges = (edge, edge)
-    return block_q, block_k, s_pad, edges
+    decided = functools.partial(
+        Schedule, block_q=block_q, block_k=block_k, s_pad=s_pad, causal=causal,
+        interpret=interpret, kv_len=seq, window=window, block=block_mask,
+    )
+    if _nested(causal, window, block_q, block_k):
+        kind = "nested"
+    elif _banded(causal, window, block_q, block_k, s_pad):
+        kind = "banded"
+    elif _blocked(block_mask, block_q, block_k, s_pad):
+        kind = "blocked"
+    else:
+        return decided(kind="general", edges=None)
+    edges = _auto_edges(block_k, head_dim)
+    if kind == "blocked":
+        edges = tuple(e if e % block_mask[0] == 0 else block_k for e in edges)
+    if block_diag:
+        edge = min(block_diag, block_k)
+        if not interpret:
+            edge = _cdiv(edge, 128) * 128
+        if block_k % edge:
+            raise ValueError(
+                f"block_diag {edge} does not divide block_k {block_k}"
+            )
+        if kind == "blocked" and edge % block_mask[0]:
+            raise ValueError(
+                f"block_diag {edge} does not hold whole blocks of {block_mask[0]}"
+            )
+        edges = (edge, edge)
+    return decided(kind=kind, edges=edges)
 
 
 def _qkv_lanes(n_heads: int, head_dim: int) -> Optional[int]:
@@ -1884,24 +1763,21 @@ def flash_attention_qkv(
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
     interp = _pick_interpret(interpret)
-    block_q, block_k, S_pad, edges = _tiles(
-        S, head_dim, interp, block_q, block_k, block_diag
-    )
-    if (
-        _qkv_lanes(n_heads, head_dim) is None
-        or block_q != S_pad or block_q % block_k
-    ):
+    schedule = _tiles(S, head_dim, interp, block_q, block_k, block_diag)
+    if _qkv_lanes(n_heads, head_dim) is None or not schedule.one_resident_block:
         q, k, v = (
             t.reshape(B, S, n_heads, head_dim)
             for t in jnp.split(qkv, 3, axis=-1)
         )
         return flash_attention(
-            q, k, v, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-            block_diag=block_diag, interpret=interp,
+            q, k, v, sm_scale=sm_scale, block_q=schedule.block_q,
+            block_k=schedule.block_k, block_diag=block_diag, interpret=interp,
         ).reshape(B, S, n_heads * head_dim)
-    if S_pad != S:
-        qkv = jnp.pad(qkv, ((0, 0), (0, S_pad - S), (0, 0)))
-    out = _flash_qkv((n_heads, float(sm_scale), block_k, edges, interp), qkv)
+    if schedule.s_pad != S:
+        qkv = jnp.pad(qkv, ((0, 0), (0, schedule.s_pad - S), (0, 0)))
+    out = _flash_qkv(
+        (n_heads, float(sm_scale), schedule.block_k, schedule.edges, interp), qkv
+    )
     return out[:, :S]
 
 
@@ -1930,13 +1806,10 @@ def flash_attention(
         window: sliding-window (local) attention — each query attends
             only the most recent ``window`` keys (q_pos - k_pos < window);
             computed tiles scale with S*window instead of S^2/2.
-            Requires ``causal``. A window of whole ``block_k`` sub-tiles,
-            shorter than the padded sequence, on blocks that nest runs
-            the two-level schedule cut to the band (static pieces, the
-            staircases on the diagonal and on the window's edge: module
-            docstring); any other keeps the general kernels, which skip
-            the tiles wholly outside the window by their loop bounds and
-            mask every tile they meet.
+            Requires ``causal``. Runs the two-level schedule cut to the
+            band where the shapes allow (``_banded``; module docstring),
+            else the general kernels, which skip the tiles wholly
+            outside the window by their loop bounds.
         block_mask: ``(B, L)``: the block-diffusion mask (Arriola et al.,
             arXiv:2503.09573) over the 2 L positions of a call - a CLEAN
             copy of a sequence of L tokens in rows 0..L-1 and a NOISED
@@ -1946,16 +1819,12 @@ def flash_attention(
             query at i sees the clean keys with ``blk(j) < blk(i)`` and
             the noised keys with ``blk(j) == blk(i)``: L^2 + L B pairs a
             head, later keys of a query's own block among them, so the
-            call is not ``causal`` and says so. Where the shapes tile
-            (``_blocked``: blocks that nest, L whole resident blocks,
-            nothing padded, B a divisor of ``block_k`` - the tiles
-            ``_auto_tiles`` chooses where they can) it runs the
-            two-level static schedule over both copies (module
-            docstring); any other keeps the general kernels, which walk
-            the tiles the mask shows and leave unmasked those it shows
-            whole. ``block_scores_computed`` counts what either
-            computes; on both a hidden pair adds exactly 0, forward and
-            backward.
+            call is not ``causal`` and says so. Runs the two-level
+            schedule over both copies where the shapes tile
+            (``_blocked``: the tiles ``_auto_tiles`` chooses where they
+            can), else the general kernels. ``block_scores_computed``
+            counts what either computes; on both a hidden pair adds
+            exactly 0, forward and backward.
         sm_scale: score scale; default ``head_dim ** -0.5``. The scale
             is folded into ``q`` OUTSIDE the kernel as one f32 multiply
             rounded back to the input dtype (it removes a per-tile
@@ -1976,16 +1845,12 @@ def flash_attention(
         block_q, block_k: VMEM tile sizes; clamped to S, and on real TPU
             rounded UP to 128-multiples (Mosaic's lane-aligned store
             requirement — a requested 64 runs as 128 on hardware;
-            interpret mode honors small blocks exactly). A causal call
-            whose ``block_q`` is a multiple of ``block_k`` runs the
-            two-level schedule (module docstring): ``block_q`` rows
-            resident, row groups and sub-tiles of ``block_k`` - whole
-            without a window, cut to the band with one that
-            ``block_k`` divides. Default: ``_auto_tiles``, from the
-            padded length - (1024, 512) at S 1024, measured on the v5e
-            inside the whole training step (PERF.md section 6, PR 25);
-            with a window the largest sub-tile up to 1024 that divides
-            it (PR 37).
+            interpret mode honors small blocks exactly). On the
+            two-level schedule (``block_q`` a multiple of ``block_k``;
+            ``_tiles`` decides) ``block_q`` rows are resident, in row
+            groups and sub-tiles of ``block_k``. Default:
+            ``_auto_tiles``, measured on the v5e inside the whole
+            training step.
         interpret: force pallas interpret mode; default: on iff the backend
             is not TPU (CPU tests / virtual-device dryruns).
         mesh/batch_axis/head_axis: when ``mesh`` is given the kernel runs
@@ -2076,14 +1941,12 @@ def flash_attention_rows(
             raise ValueError(
                 f"block_mask {block_mask}: want 2 x L = {S} positions in whole blocks"
             )
-    interp = _pick_interpret(interpret)
-    block_q, block_k, S_pad, edges = _tiles(
-        S, D, interp, block_q, block_k, block_diag, causal, window, block_mask
-    )
-    cfg = (
-        bool(causal), block_q, block_k, edges, interp, S,
+    schedule = _tiles(
+        S, D, _pick_interpret(interpret), block_q, block_k, block_diag, bool(causal),
         None if window is None else int(window), block_mask,
     )
-    if S_pad != S:
-        q, k, v = (jnp.pad(x, ((0, 0), (0, S_pad - S), (0, 0))) for x in (q, k, v))
-    return _flash(cfg, q, k, v)[:, :S]
+    if schedule.s_pad != S:
+        q, k, v = (
+            jnp.pad(x, ((0, 0), (0, schedule.s_pad - S), (0, 0))) for x in (q, k, v)
+        )
+    return _flash(schedule, q, k, v)[:, :S]
