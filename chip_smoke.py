@@ -445,6 +445,9 @@ FLASH_CASES = (
     ("mellum_sliding", 1, 8192, 8, 128, 1024),
     ("mellum_full", 1, 8192, 8, 128, None),
     ("sdar_block", 1, 8192, 8, 128, None, (4, 4096)),
+    # ling3-ft1's latent-attention layer: q.k 192 and v 128 both padded to
+    # 256 lanes, which is what the kernels are compiled at
+    ("ling_mla", 1, 8192, 8, 256, None),
     # a block mask that does not tile (B 6 straddles every tile's edge):
     # the general kernels' sweep, which no cell runs
     ("block_general", 2, 1920, 4, 128, None, (6, 960)),

@@ -336,6 +336,8 @@ def test_auto_tiles_of_a_window(S, window, interpret, want):
         ("mellum2-ft1-sliding", 8192, 128, 1024, None, ("banded", 1024, 1024, (256, 128))),
         ("mellum2-ft1-full", 8192, 128, None, None, ("nested", 512, 512, (256, 128))),
         ("sdar-ft1", 8192, 128, None, (4, 4096), ("blocked", 1024, 1024, (256, 128))),
+        # latent attention: q.k 192 and v 128, both padded to 256 lanes
+        ("ling3-ft1-mla", 8192, 256, None, None, ("nested", 512, 512, (256, 128))),
         ("a-block-over-a-tile", 1920, 128, None, (6, 960), ("general", 128, 128, None)),
         ("one-block-a-copy", 4096, 128, None, (2048, 2048), ("general", 512, 512, None)),
         ("a-padded-length", 400, 128, None, (8, 200), ("general", 128, 128, None)),

@@ -91,6 +91,38 @@ file (``models/mellum.py`` is one):
   backward pass but for what ``KEPT`` names (a dense layer's down
   product), the weights' gradient summed over the passes in float32.
   T = 1 is the plain model above: no gate, no loop.
+- a layer's MIXER as a kind (``AttentionKind.mixer``; ``models/ling.py`` has
+  both): softmax attention as above, or
+  *Kimi Delta Attention* (``Kda``; Kimi Linear, arXiv:2510.26692;
+  ``kda_mixer``): ``q, k, v = SiLU(conv(W u))`` with ``conv`` a depthwise
+  causal convolution of ``taps`` positions, q and k divided by their L2
+  norm a head and q by ``sqrt(head_dim)``; a log-decay a CHANNEL of the key,
+  ``g = floor x sigmoid(exp(A_h) (W_f u + b))``, and a step ``beta =
+  sigmoid(W_b u)`` a head; a state a head, ``S_t = (I - beta_t k_t k_t^T)
+  Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``, computed in
+  chunks (``ops/delta_rule.py``); ``y = W_o (RMSNorm_head(o) sigmoid(W_g
+  u))``; or *latent attention* (``Mla``; DeepSeek-V2, arXiv:2405.04434;
+  ``mla_mixer``): ``q = W_q u`` (``head_dim + rope_dim`` a head), ``[c, k_r]
+  = W_kva u``, ``[k_nope, v] = W_kvb RMSNorm(c)``, ``k = [k_nope, k_r]`` with
+  the one rotated key for every head, RMSNorm of q and of k over a head's
+  whole width, the rotary embedding of the last ``rope_dim`` in INTERLEAVED
+  pairs, causal softmax at ``(head_dim + rope_dim) ** -0.5`` through the
+  flash kernels (the two widths padded to one, exactly), ``y = W_o (o
+  sigmoid(W_gate u))`` with one gate a head. ``n_heads`` is how many heads a
+  rank HOLDS: a head's part of either mixer's output is its own;
+- the router's other form (``router``: a ``SigmoidRouter``; DeepSeek-V3,
+  arXiv:2412.19437; ``_sigmoid_choice``): each expert's score ``s =
+  sigmoid(r)``; the K chosen on ``s + bias`` (the bias a leaf of ``moe``
+  that enters selection alone) among the experts of the ``kept`` best of
+  ``groups`` (a group's mark the sum of its two best); the weights are the
+  chosen ``s`` and never the bias, times ``scale`` after
+  ``renormalize_top_k``; and one expert every token meets
+  (``shared_width``), whole on every rank, added to the held experts'
+  part. THE BIAS HAS NO GRADIENT OF THE LOSS: what moves it is its expert's
+  excess load, which ``loss_fn`` hangs on the loss as a term without value
+  (``_bias_pull``) so that it rides the gradient tree through the step
+  transaction like any gradient; an optimizer that knows such leaves steps
+  them by ``-gamma sign`` (``models/ling.py``: ``bias_steps``);
 - BLOCK-DIFFUSION training (``diffusion_block`` = B; ``models/sdar.py`` is
   one; Arriola et al., arXiv:2503.09573): the loss is not next-token. A
   step draws, a sequence ``x`` of L tokens, ``t ~ U[noise_floor, 1]`` and
@@ -128,6 +160,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import flash_attention_rows
+from ..ops.delta_rule import LEAST_LOG_DECAY, causal_conv, gated_delta_rule
 from .transformer import (
     _dense_init,
     _rmsnorm,
@@ -155,20 +188,66 @@ class Yarn:
 
 
 @dataclass(frozen=True)
+class Kda:
+    """A mixer that is no softmax attention: Kimi Delta Attention (module
+    docstring), a gated delta rule whose state decays a channel. ``taps``
+    positions under each of the three short convolutions; ``floor`` the
+    lower bound of a position's log-decay, which the chunked scan has to be
+    able to carry (``ops/delta_rule.py``: ``LEAST_LOG_DECAY``)."""
+
+    taps: int = 4
+    floor: float = -5.0
+
+    def __post_init__(self) -> None:
+        if not LEAST_LOG_DECAY <= self.floor < 0:
+            raise ValueError(
+                f"a log-decay's bound of {self.floor} lies outside"
+                f" [{LEAST_LOG_DECAY}, 0): the scan's sub-chunks overflow float32 under it"
+            )
+
+
+@dataclass(frozen=True)
+class Mla:
+    """Latent attention (module docstring): keys and values expanded from
+    one shared vector of ``latent`` numbers a position, and ``rope_dim``
+    rotated numbers, one vector for every head, beside each head's
+    ``head_dim`` unrotated ones, so q.k is ``head_dim + rope_dim`` wide and
+    the value ``head_dim``."""
+
+    latent: int
+    rope_dim: int
+
+
+@dataclass(frozen=True)
+class SigmoidRouter:
+    """The router that is no softmax (module docstring): a sigmoid score an
+    expert, the experts in ``groups`` of which a token's ``kept`` best are
+    open to it, ``scale`` on the chosen experts' weights, and a selection
+    bias an expert that no gradient of the loss moves."""
+
+    groups: int
+    kept: int
+    scale: float
+
+
+@dataclass(frozen=True)
 class AttentionKind:
-    """What one layer's attention is: ``window`` keys back (``q_pos -
-    k_pos < window``) or all of them, and YaRN's blend of the rotary
-    frequencies or the plain ones. ``name`` is the ``jax.named_scope`` the
-    layer's attention runs under, inside ``attn``; the unnamed kind is
-    OLMoE's and adds no scope. ``block`` = B: the layer's input is a clean
-    and a noised copy of a sequence, L positions each, both at rotary
-    positions 0..L-1, under the block-diffusion mask in blocks of B
-    (``flash_attention``: ``block_mask``) and no causal one."""
+    """What one layer's MIXER is. ``mixer`` None: softmax attention over
+    ``window`` keys back (``q_pos - k_pos < window``) or all of them, with
+    YaRN's blend of the rotary frequencies or the plain ones; ``block`` =
+    B: the layer's input is a clean and a noised copy of a sequence, L
+    positions each, both at rotary positions 0..L-1, under the
+    block-diffusion mask in blocks of B (``flash_attention``:
+    ``block_mask``) and no causal one. ``mixer`` a ``Kda`` or an ``Mla``:
+    that mixer, causal, with its own weights (``_MIXERS``). ``name`` is the
+    ``jax.named_scope`` the layer's mixer runs under, inside ``attn``; the
+    unnamed kind is OLMoE's and adds no scope."""
 
     name: Optional[str] = None
     window: Optional[int] = None
     yarn: Optional[Yarn] = None
     block: Optional[int] = None
+    mixer: Optional[Any] = None  # a Kda or an Mla; None: softmax attention
 
 
 @dataclass(frozen=True)
@@ -205,6 +284,8 @@ class OlmoeConfig:
     mask_token_id: Optional[int] = None  # what a noised position holds
     noise_seed: int = 0  # with the batch's checksum, the key of (t, m)
     noise_floor: float = 1e-3  # t ~ U[noise_floor, 1]
+    router: Optional[SigmoidRouter] = None  # None: a softmax over the experts
+    shared_width: Optional[int] = None  # one expert every token meets; None: none
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -227,6 +308,8 @@ class OlmoeConfig:
             or not 0 <= self.mask_token_id < self.vocab_size
         ):
             raise ValueError("a diffusion model is not looped and names a mask token of its vocabulary")
+        if self.router is not None and self.n_experts % self.router.groups:
+            raise ValueError("the experts do not fall into whole groups")
 
     @property
     def kv_heads(self) -> int:
@@ -259,37 +342,96 @@ def tiny_olmoe_config() -> OlmoeConfig:
     )
 
 
+def _ones(width: int) -> jax.Array:
+    return jnp.ones((width,), jnp.float32)
+
+
+def _attention_init(cfg: OlmoeConfig, kind: AttentionKind, bk: jax.Array) -> Dict[str, Any]:
+    """Softmax attention's weights from a layer's first four keys."""
+    d = cfg.d_model
+    q_width, kv_width = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    scale = d ** -0.5
+    p = {
+        "wq": _dense_init(bk[0], (d, q_width), scale),
+        "wk": _dense_init(bk[1], (d, kv_width), scale),
+        "wv": _dense_init(bk[2], (d, kv_width), scale),
+        "wo": _dense_init(bk[3], (q_width, d), q_width ** -0.5),
+    }
+    if cfg.qk_norm:
+        p.update(
+            q_norm=_ones(cfg.head_dim if cfg.qk_norm_per_head else q_width),
+            k_norm=_ones(cfg.head_dim if cfg.qk_norm_per_head else kv_width),
+        )
+    return p
+
+
+def _kda_init(cfg: OlmoeConfig, kind: AttentionKind, bk: jax.Array) -> Dict[str, Any]:
+    """A KDA mixer's weights: five whole maps of ``d`` to the heads' columns
+    (q, k, v, the decay's, the output gate's), the step's map to one number
+    a head, a convolution a channel of q, k and v, the decay's ``A`` a head
+    (``log U(1, 16)``, as Kimi Linear's code draws it) and bias a channel
+    (standard normal, so that the seeded decays spread over the whole of
+    ``(e^floor, 1)``: a head with ``A`` near 0 reads the bias as it is, one
+    near ``log 16`` sits at either end), the head norm's scale, ``wo``."""
+    d, h, width = cfg.d_model, cfg.n_heads, cfg.n_heads * cfg.head_dim
+    taps, scale = kind.mixer.taps, d ** -0.5
+    ks = jax.random.split(bk[0], 12)
+    p = {
+        name: _dense_init(k, (d, width), scale)
+        for name, k in zip(("wq", "wk", "wv", "w_decay", "w_gate"), ks)
+    }
+    p.update({
+        name: _dense_init(k, (taps, width), taps ** -0.5)
+        for name, k in zip(("conv_q", "conv_k", "conv_v"), ks[5:])
+    })
+    p.update(
+        w_beta=_dense_init(ks[8], (d, h), scale),
+        decay_a=jnp.log(jax.random.uniform(ks[9], (h,), jnp.float32, 1.0, 16.0)),
+        decay_bias=_dense_init(ks[10], (width,), 1.0),
+        o_norm=_ones(cfg.head_dim),
+        wo=_dense_init(ks[11], (width, d), width ** -0.5),
+    )
+    return p
+
+
+def _mla_init(cfg: OlmoeConfig, kind: AttentionKind, bk: jax.Array) -> Dict[str, Any]:
+    """Latent attention's weights: q whole (``head_dim + rope_dim`` a head),
+    the map down to the latent and the shared rotated key, the latent's
+    norm, the map up to each head's unrotated key and value, the two
+    QK-norm scales over a head's whole q.k width, the gate's map to one
+    number a head, ``wo``."""
+    d, h, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
+    latent, rope_dim = kind.mixer.latent, kind.mixer.rope_dim
+    ks = jax.random.split(bk[0], 5)
+    return {
+        "wq": _dense_init(ks[0], (d, h * (dh + rope_dim)), d ** -0.5),
+        "w_kva": _dense_init(ks[1], (d, latent + rope_dim), d ** -0.5),
+        "kv_norm": _ones(latent),
+        "w_kvb": _dense_init(ks[2], (latent, h * 2 * dh), latent ** -0.5),
+        "q_norm": _ones(dh + rope_dim),
+        "k_norm": _ones(dh + rope_dim),
+        "w_gate": _dense_init(ks[3], (d, h), d ** -0.5),
+        "wo": _dense_init(ks[4], (h * dh, d), (h * dh) ** -0.5),
+    }
+
+
 def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
     """f32 master params; matmuls cast to cfg.dtype at use."""
     d, f, e = cfg.d_model, cfg.expert_width, cfg.n_experts
-    q_width, kv_width = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
     held = cfg.held[1]
     keys = jax.random.split(key, 2 + cfg.n_layers)
     scale = d ** -0.5
 
-    def ones(width: int = d) -> jax.Array:
-        return jnp.ones((width,), jnp.float32)
-
     blocks = []
-    for i, width in enumerate(cfg.ff):
+    for i, (kind, width) in enumerate(zip(cfg.kinds, cfg.ff)):
         bk = jax.random.split(keys[2 + i], 8)
         block = {
-            "ln1": {"scale": ones()},
-            "attn": {
-                "wq": _dense_init(bk[0], (d, q_width), scale),
-                "wk": _dense_init(bk[1], (d, kv_width), scale),
-                "wv": _dense_init(bk[2], (d, kv_width), scale),
-                "wo": _dense_init(bk[3], (q_width, d), q_width ** -0.5),
-            },
-            "ln2": {"scale": ones()},
+            "ln1": {"scale": _ones(d)},
+            "attn": _MIXERS[type(kind.mixer)][0](cfg, kind, bk),
+            "ln2": {"scale": _ones(d)},
         }
-        if cfg.qk_norm:
-            block["attn"].update(
-                q_norm=ones(cfg.head_dim if cfg.qk_norm_per_head else q_width),
-                k_norm=ones(cfg.head_dim if cfg.qk_norm_per_head else kv_width),
-            )
         if cfg.sandwich_norms:  # the second norm of each sublayer
-            block.update(ln1_post={"scale": ones()}, ln2_post={"scale": ones()})
+            block.update(ln1_post={"scale": _ones(d)}, ln2_post={"scale": _ones(d)})
         if width is None:
             block["moe"] = {
                 "router": _dense_init(bk[4], (d, e), scale),
@@ -297,6 +439,15 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
                 "w_up": _dense_init(bk[6], (held, d, f), scale),
                 "w_down": _dense_init(bk[7], (held, f, d), f ** -0.5),
             }
+            if cfg.router is not None:
+                block["moe"]["bias"] = jnp.zeros((e,), jnp.float32)
+            if cfg.shared_width is not None:
+                sk, fs = jax.random.split(jax.random.fold_in(bk[4], 1), 3), cfg.shared_width
+                block["moe"]["shared"] = {
+                    "w_gate": _dense_init(sk[0], (d, fs), scale),
+                    "w_up": _dense_init(sk[1], (d, fs), scale),
+                    "w_down": _dense_init(sk[2], (fs, d), fs ** -0.5),
+                }
         else:
             block["mlp"] = {
                 "w_gate": _dense_init(bk[5], (d, width), scale),
@@ -307,7 +458,7 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
     params = {
         "embed": _dense_init(keys[0], (cfg.vocab_size, d), scale),
         "blocks": blocks,
-        "ln_f": {"scale": ones()},
+        "ln_f": {"scale": _ones(d)},
         "readout": _dense_init(keys[1], (d, cfg.vocab_size), scale),
     }
     if cfg.passes > 1:  # the exits' gate: one map of d to 1, with a bias
@@ -538,6 +689,118 @@ def attention(
         out = flash_attention_rows(q, k, v, causal=False, block_mask=(kind.block, S // 2))
     out = out.reshape(B, h, S, dh).transpose(0, 2, 1, 3)
     return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
+
+
+def kda_mixer(
+    cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array, kind: AttentionKind
+) -> jax.Array:
+    """Kimi Delta Attention (module docstring) over the held heads: the
+    maps, the three short convolutions with their SiLU and the L2 norms of
+    q and k, the decay and the step, the gated delta rule in chunks
+    (``ops/delta_rule.py``), the head norm under the output gate, ``wo``."""
+    B, S, _ = x.shape
+    h, dh, f32 = cfg.n_heads, cfg.head_dim, jnp.float32
+
+    def heads(t: jax.Array) -> jax.Array:
+        return t.reshape(B, S, h, dh)
+
+    def unit(t: jax.Array) -> jax.Array:
+        return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+    with jax.named_scope("proj"):
+        q, k, v, decay, gate = (
+            x @ p[w].astype(cfg.dtype) for w in ("wq", "wk", "wv", "w_decay", "w_gate")
+        )
+        beta = x @ p["w_beta"].astype(cfg.dtype)
+    with jax.named_scope("conv"):
+        q, k, v = (
+            heads(jax.nn.silu(causal_conv(t, p[w])))
+            for t, w in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v"))
+        )
+        q, k = unit(q) * dh ** -0.5, unit(k)
+        q, k, v = (t.astype(cfg.dtype) for t in (q, k, v))
+    with jax.named_scope("gates"):
+        # float32 from the product on: the decays' running sums are taken of it
+        rate = jnp.exp(p["decay_a"].astype(f32))[:, None]
+        g = kind.mixer.floor * jax.nn.sigmoid(
+            rate * (heads(decay.astype(f32)) + p["decay_bias"].astype(f32).reshape(h, dh))
+        )
+        beta = jax.nn.sigmoid(beta.astype(f32))
+    with jax.named_scope("scan"):
+        out = gated_delta_rule(q, k, v, g, beta)
+    with jax.named_scope("out"):
+        out = _rmsnorm(out, p["o_norm"], cfg.rms_norm_eps).astype(f32)
+        out = (out * jax.nn.sigmoid(heads(gate).astype(f32))).astype(cfg.dtype)
+        return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
+
+
+def _rope_pairs(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding with INTERLEAVED pairs: of ``x`` (B, S, ..., r)
+    float32 the pair (``2 i``, ``2 i + 1``) turns by ``pos * theta ** (-2 i
+    / r)`` at position ``pos`` of axis 1."""
+    S, r = x.shape[1], x.shape[-1]
+    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * theta ** (
+        -jnp.arange(r // 2, dtype=jnp.float32) / (r // 2)
+    )
+    angle = angle.reshape((S,) + (1,) * (x.ndim - 3) + (r // 2,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    pairs = x.reshape(x.shape[:-1] + (r // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1).reshape(x.shape)
+
+
+def mla_mixer(
+    cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array, kind: AttentionKind
+) -> jax.Array:
+    """Latent attention (module docstring) in its training form, nothing
+    absorbed, over the held heads. The flash kernels take one width for q,
+    k and v: q and k (``head_dim + rope_dim`` wide) and v (``head_dim``)
+    are padded with zeros to the next multiple of 128 lanes and the
+    output's pad is dropped, which is exact - a zero lane adds nothing to a
+    score and a zero column of v gives a zero column of the output."""
+    B, S, _ = x.shape
+    h, dh, f32 = cfg.n_heads, cfg.head_dim, jnp.float32
+    latent, r = kind.mixer.latent, kind.mixer.rope_dim
+    width = dh + r
+    with jax.named_scope("proj"):
+        q = (x @ p["wq"].astype(cfg.dtype)).reshape(B, S, h, width)
+        down = x @ p["w_kva"].astype(cfg.dtype)
+        c = _rmsnorm(down[..., :latent], p["kv_norm"], cfg.rms_norm_eps)
+        up = (c @ p["w_kvb"].astype(cfg.dtype)).reshape(B, S, h, 2 * dh)
+        gate = x @ p["w_gate"].astype(cfg.dtype)
+    with jax.named_scope("qk_rows"):
+        # one rotated key a position, the same for every head
+        k_rope = jnp.broadcast_to(down[..., None, latent:], (B, S, h, r))
+        k = jnp.concatenate([up[..., :dh], k_rope], axis=-1)
+
+        def normed_and_turned(t: jax.Array, scale: jax.Array) -> jax.Array:
+            t = _rmsnorm(t.astype(f32), scale, cfg.rms_norm_eps)
+            return jnp.concatenate(
+                [t[..., :dh], _rope_pairs(t[..., dh:], cfg.rope_theta)], axis=-1
+            )
+
+        lanes = -(-width // 128) * 128  # ONE width for q, k and v: v's too
+
+        def rows(t: jax.Array) -> jax.Array:  # (B x h, S, lanes)
+            t = t.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(B * h, S, -1)
+            return jnp.pad(t, ((0, 0), (0, 0), (0, lanes - t.shape[-1])))
+
+        q = rows(normed_and_turned(q, p["q_norm"]) * width ** -0.5)
+        k = rows(normed_and_turned(k, p["k_norm"]))
+        v = rows(up[..., dh:])
+    out = flash_attention_rows(q, k, v)[..., :dh]
+    with jax.named_scope("out"):
+        out = out.reshape(B, h, S, dh).transpose(0, 2, 1, 3).astype(f32)
+        out = (out * jax.nn.sigmoid(gate.astype(f32))[..., None]).astype(cfg.dtype)
+        return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
+
+
+# a kind's ``mixer`` -> (its weights from a layer's keys, the mixer itself)
+_MIXERS = {
+    type(None): (_attention_init, attention),
+    Kda: (_kda_init, kda_mixer),
+    Mla: (_mla_init, mla_mixer),
+}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -877,6 +1140,30 @@ def _every_expert(
     return y, group_sizes
 
 
+def _sigmoid_choice(
+    cfg: OlmoeConfig, logits: jax.Array, bias: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The sigmoid router's choice (module docstring) from the (N, E)
+    float32 logits: each expert's score ``s = sigmoid(r)`` on its own; the
+    K experts are chosen on ``s + bias`` among the experts of the
+    ``cfg.router.kept`` groups whose two best ``s + bias`` sum highest, and
+    weighted by their ``s``, WITHOUT the bias. Returns the scores divided
+    by their sum (what the balance loss reads as probabilities), the chosen
+    experts' ``s`` (N, K) and the choice."""
+    N, E = logits.shape
+    groups, kept = cfg.router.groups, cfg.router.kept
+    score = jax.nn.sigmoid(logits)
+    pick = score + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    grouped = pick.reshape(N, groups, E // groups)
+    mark = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # (N, groups)
+    _, best = jax.lax.top_k(mark, kept)
+    among = jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)  # (N, groups)
+    pick = jnp.where(among[:, :, None], grouped, -jnp.inf).reshape(N, E)
+    _, chosen = jax.lax.top_k(pick, cfg.experts_per_token)
+    weights = jnp.take_along_axis(score, chosen, axis=-1)
+    return score / jnp.sum(score, axis=-1, keepdims=True), weights, chosen
+
+
 def moe_layer(
     cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -907,10 +1194,15 @@ def moe_layer(
             tokens.astype(jnp.float32), p["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )  # (N, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, chosen = jax.lax.top_k(probs, K)  # (N, K)
+        if cfg.router is not None:
+            probs, weights, chosen = _sigmoid_choice(cfg, logits, p["bias"])
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            weights, chosen = jax.lax.top_k(probs, K)  # (N, K)
         if cfg.renormalize_top_k:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if cfg.router is not None:
+            weights = weights * cfg.router.scale
 
     if cfg.held_experts is None:
         y, claims = _every_expert(cfg, p, tokens, weights, chosen)
@@ -926,6 +1218,15 @@ def moe_layer(
         "held_claims": jnp.asarray(held_claims, jnp.float32),
         "held_dense_layers": jnp.asarray(heavy, jnp.float32) / cfg.held[1],
     }
+    if cfg.router is not None:
+        with jax.named_scope("router"):
+            # what ``_bias_pull`` makes the bias's gradient: each expert's
+            # share of the step's claims over the even share
+            excess = jax.lax.stop_gradient(stats["claims"] / (N * K) - 1.0 / E)
+            stats["bias_pull"] = jnp.sum(excess * p["bias"].astype(jnp.float32))
+    if cfg.shared_width is not None:
+        with jax.named_scope("shared"):
+            y = y + dense_mlp(cfg, p["shared"], tokens)
     return y.reshape(B, S, D).astype(x.dtype), stats
 
 
@@ -957,7 +1258,8 @@ def _block(
 
     of_kind = jax.named_scope(kind.name) if kind.name else contextlib.nullcontext()
     with jax.named_scope("attn"), of_kind:
-        y = attention(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"], eps), kind)
+        mixer = _MIXERS[type(kind.mixer)][1]
+        y = mixer(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"], eps), kind)
         x = x + second(y, "ln1_post")
     with jax.named_scope("mlp"):
         if width is not None:
@@ -1183,6 +1485,23 @@ def aux_losses(
     return balance, stats["z"] / n
 
 
+def _bias_pull(loss: jax.Array, stats: Dict[str, jax.Array]) -> jax.Array:
+    """``loss`` with the selection biases' step hung on it, where the model
+    has them: ``t - stop_gradient(t)`` with ``t = sum_e excess_e bias_e``
+    over the layers (``moe_layer``: ``bias_pull``) - nothing in value, and
+    the gradient of each bias is its expert's excess load and nothing of
+    the loss's. So the one quantity that moves a bias rides the gradient
+    tree: it is averaged over the replica groups as the loads of the global
+    batch, voted on, applied with the committed update and dropped with an
+    aborted one, and the bias itself is a leaf of the parameters that a
+    heal and a checkpoint carry (``models/ling.py`` has the optimizer that
+    steps such a leaf by ``-gamma sign``)."""
+    if "bias_pull" not in stats:
+        return loss
+    pull = stats["bias_pull"]
+    return loss + (pull - jax.lax.stop_gradient(pull))
+
+
 def _exits_loss(
     cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
@@ -1222,7 +1541,7 @@ def _diffusion_loss(
         return loss
     with jax.named_scope("loss"), jax.named_scope("aux"):
         balance, z = aux_losses(cfg, stats, 2 * tokens.size)
-    return loss + cfg.balance_coef * balance + cfg.z_coef * z
+    return _bias_pull(loss + cfg.balance_coef * balance + cfg.z_coef * z, stats)
 
 
 def loss_fn(
@@ -1249,4 +1568,4 @@ def loss_fn(
         loss = next_token_loss(logits, tokens[:, 1:])
     if stats is None:
         return loss
-    return loss + cfg.balance_coef * balance + cfg.z_coef * z
+    return _bias_pull(loss + cfg.balance_coef * balance + cfg.z_coef * z, stats)
