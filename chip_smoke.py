@@ -339,6 +339,72 @@ def _check_heads_to_rows(
     ))
 
 
+def _check_delta_rule(name: str, S: int = 8192, H: int = 8, D: int = 128) -> None:
+    """``ops.delta_rule.gated_delta_rule`` at a KDA layer's shape in
+    ``ling3-ft1`` - bf16 q, k and v, float32 decays down to the bound of
+    -5 - compiled under the chip's DEFAULT matmul precision, the output and
+    the five gradients of its hand-written backward against the recurrence
+    a position at a time in float32 at ``highest``
+    (``benchmark/reference_ling.py``). The CPU tests hold the op at
+    ``highest``; here the inverse by products and the solution made with it
+    meet the one-pass bf16 products. Plain XLA: no Mosaic call, no
+    triangular-solve call."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import reference_ling
+    from torchft_tpu.ops.delta_rule import gated_delta_rule
+
+    keys = jax.random.split(jax.random.PRNGKey(S + D), 6)
+    q, k, v, cot = (jax.random.normal(kk, (1, S, H, D)) for kk in keys[:4])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q, k, v, cot = (t.astype(jnp.bfloat16) for t in (q, k, v, cot))
+    g = -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(keys[4], (1, S, H, D)) - 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, S, H)))
+
+    def grad_of(fn):
+        def loss(q, k, v, g, beta):
+            out = fn(q, k, v, g, beta)
+            return jnp.sum(out.astype(jnp.float32) * cot.astype(jnp.float32)), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
+
+    def recurrence(q, k, v, g, beta):
+        return jax.vmap(reference_ling.delta_rule)(q, k, v, jnp.exp(g), beta)
+
+    lowered = grad_of(gated_delta_rule).lower(q, k, v, g, beta)
+    text = lowered.as_text()
+    if "tpu_custom_call" in text or "triangular_solve" in text:
+        raise AssertionError(f"delta rule {name}: a Mosaic or triangular-solve call in the op")
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    compile_s = time.perf_counter() - t0
+    (_, out), grads = jax.block_until_ready(compiled(q, k, v, g, beta))
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = jax.block_until_ready(grad_of(recurrence)(
+            *(t.astype(jnp.float32) for t in (q, k, v)), g, beta
+        ))
+    errs = {}
+    for label, got, ref in zip(
+        ("out", "dq", "dk", "dv", "dg", "dbeta"), (out,) + grads, (want,) + want_grads
+    ):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        if not np.all(np.isfinite(got)):
+            raise AssertionError(f"delta rule {name}: non-finite {label}")
+        err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+        errs[label] = round(err, 5)
+        if err > FLASH_TOL:
+            raise AssertionError(
+                f"delta rule {name}: {label} differs from the recurrence by "
+                f"{err:.4f} of max|ref| (tolerance {FLASH_TOL})"
+            )
+    _say("kernels", (
+        f"delta rule {name} B1 S{S} H{H} D{D}: compiled in {compile_s:.1f}s, "
+        f"max err / max|ref| {errs} <= {FLASH_TOL}"
+    ))
+
+
 def _check_wire_kernels(name: str, shape: Sequence[int], seed: int) -> None:
     """quantize_q8_ef / dequantize_q8 / cast_bf16 on one payload, compiled,
     against the numpy oracle of the CPU tests
@@ -469,6 +535,8 @@ def child_kernels() -> None:
     # cells' shape: Mellum2's full layer and SDAR's two copies
     _check_heads_to_rows("mellum_full", "counted")
     _check_heads_to_rows("sdar_block", "stated")
+    # ling3-ft1's other mixer: the gated delta rule in chunks, plain XLA
+    _check_delta_rule("ling_kda")
     # the big model's largest leaf (128 grid blocks) and an odd length
     # that ends mid-block
     _check_wire_kernels("big_leaf", (1024, 4096), seed=1)

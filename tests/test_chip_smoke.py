@@ -7,10 +7,13 @@ which chips a group gets, where the compile cache lives — behave.
 """
 
 import dataclasses
+import inspect
 import os
 import subprocess
 import sys
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 import chip_smoke
@@ -129,6 +132,36 @@ class TestChipSmoke:
         monkeypatch.setattr(olmoe, "rotary_tables", wrong)
         with pytest.raises(AssertionError, match="differs from the plain"):
             chip_smoke._check_heads_to_rows("tiny", positions, S=64, H=4, G=2, D=16)
+
+    def test_kernels_phase_checks_the_delta_rule(self, monkeypatch, capsys):
+        """The kernels phase holds ``gated_delta_rule`` and its hand-written
+        backward to the recurrence at a KDA layer's shape in ``ling3-ft1``
+        (the ``ling_kda`` line); here the same check in miniature, and a
+        chunk system's inverse that forgets the blocks below the diagonal
+        ones fails it."""
+        from benchmark import common
+        from torchft_tpu.ops import delta_rule
+
+        sizes = common.load_json("configs", "ling3-flash-l6-ep64.json")
+        cfg = common.load_by_name("families", sizes["family"]).build(sizes)
+        S, H, D = (
+            inspect.signature(chip_smoke._check_delta_rule).parameters[n].default
+            for n in ("S", "H", "D")
+        )
+        assert (S, H, D) == (sizes["seq"] - 1, cfg.n_heads, cfg.head_dim)
+        with jax.default_matmul_precision("highest"):
+            chip_smoke._check_delta_rule("tiny", S=200, H=2, D=16)
+        assert "delta rule tiny B1 S200 H2 D16" in capsys.readouterr().out
+        right = delta_rule._unit_lower_inverse
+
+        def wrong(A):
+            T = right(A)
+            block = jnp.arange(T.shape[-1]) // delta_rule._SUB
+            return jnp.where(block[:, None] == block[None, :], T, 0.0)
+
+        monkeypatch.setattr(delta_rule, "_unit_lower_inverse", wrong)
+        with pytest.raises(AssertionError, match="differs from the recurrence"):
+            chip_smoke._check_delta_rule("tiny", S=200, H=2, D=16)
 
     def test_without_a_chip_it_fails_and_says_so(self):
         # the tier-1 environment pins the CPU; the script overrides that

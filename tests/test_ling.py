@@ -23,6 +23,7 @@ carry a bf16 rounding straight into every score.
 import dataclasses
 import json
 import os
+import re
 from datetime import timedelta
 
 import jax
@@ -32,6 +33,7 @@ import optax
 import pytest
 
 from benchmark import common, reference, reference_ling
+from benchmark.reduce import spans
 from torchft_tpu import (
     FTTrainState,
     HostCollectives,
@@ -40,7 +42,7 @@ from torchft_tpu import (
     OptimizerWrapper,
 )
 from torchft_tpu.models import ling, mellum, olmoe, ouro
-from torchft_tpu.ops import flash_attention_rows
+from torchft_tpu.ops import delta_rule, flash_attention_rows
 from torchft_tpu.ops.delta_rule import LEAST_LOG_DECAY, causal_conv, gated_delta_rule
 
 BF16 = ling.tiny_ling_config()
@@ -217,7 +219,8 @@ def test_a_wrong_term_is_caught(wrong, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _scan_inputs(S, decays, seed=0, B=2, H=3, dk=32, dv=16):
+def _scan_inputs(S, decays, seed=0, B=2, H=3, dk=32, dv=16, dtype=jnp.float32):
+    """``dtype`` is q's, k's and v's; the decays and the steps are float32."""
     ks = jax.random.split(jax.random.PRNGKey(seed + S), 5)
     q = jax.random.normal(ks[0], (B, S, H, dk))
     k = jax.random.normal(ks[1], (B, S, H, dk))
@@ -231,11 +234,25 @@ def _scan_inputs(S, decays, seed=0, B=2, H=3, dk=32, dv=16):
         "at the least": jnp.full((B, S, H, dk), LEAST_LOG_DECAY),
     }[decays]
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
-    return q, k, v, g, beta
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
 def _recurrence(q, k, v, g, beta):
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     return jax.vmap(reference_ling.delta_rule)(q, k, v, jnp.exp(g), beta)
+
+
+# what ``_scan_inputs`` is asked for beside the length and the decays, by the
+# words after the decays' name in a case of the test below
+SCAN_SHAPES = {
+    "": {},
+    "batch 2, 3 heads, d_k 16 under d_v 48": dict(B=2, H=3, dk=16, dv=48),
+    "bf16": dict(dtype=jnp.bfloat16),
+}
+# bf16 q, k and v: the output and the three cotangents come back rounded to
+# bf16, 2^-9 of their size each, and the output's rounding passes into every
+# cotangent through the loss's
+SCAN_ATOL_BF16, SCAN_GRAD_RTOL_BF16 = 2e-2, 1e-2
 
 
 # lengths that are whole chunks (128, 192), are not (100, 70), are less than
@@ -244,30 +261,47 @@ def _recurrence(q, k, v, g, beta):
     "S,decays",
     [(128, "spread"), (100, "spread"), (192, "at the bound"), (70, "at the bound"),
      (128, "near 0"), (70, "near 0"), (16, "spread"), (7, "at the bound"),
-     (70, "at the least")],
+     (70, "at the least"),
+     # the hand-written backward: five chunks, so that the state's cotangent
+     # crosses four boundaries on its way back; the same with a padded sixth;
+     # another batch, heads and widths; q, k and v in bf16
+     (320, "spread"), (330, "at the bound"),
+     (192, "spread, batch 2, 3 heads, d_k 16 under d_v 48"),
+     (200, "spread, bf16"), (70, "at the bound, bf16")],
 )
 def test_the_chunked_scan_is_the_recurrence(S, decays):
     """Output and all five gradients of ``ops.delta_rule.gated_delta_rule``
     against the delta rule a position at a time, with every decay at the
     bound of -5 for the whole sequence (where ``e^{-G}`` would pass
     float32's range inside a chunk), every decay near 0, and every decay at
-    the least the sub-chunks carry (``LEAST_LOG_DECAY``)."""
-    args = _scan_inputs(S, decays)
+    the least the sub-chunks carry (``LEAST_LOG_DECAY``). Each cotangent
+    comes back in its argument's type and shape."""
+    decays, _, shape = decays.partition(", ")
+    args = _scan_inputs(S, decays, **SCAN_SHAPES[shape])
+    bf16 = args[0].dtype == jnp.bfloat16
+    out_atol, grad_rtol = (
+        (SCAN_ATOL_BF16, SCAN_GRAD_RTOL_BF16) if bf16 else (SCAN_ATOL, SCAN_GRAD_RTOL)
+    )
     with jax.default_matmul_precision("highest"):
         got, want = gated_delta_rule(*args), _recurrence(*args)
+        assert got.dtype == args[2].dtype and got.shape == args[2].shape
         assert bool(jnp.all(jnp.isfinite(got)))
-        np.testing.assert_allclose(got, want, rtol=0, atol=SCAN_ATOL)
+        np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=0, atol=out_atol)
 
         def of(fn):
-            return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3, 4))(*args)
+            return jax.grad(
+                lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32))), argnums=(0, 1, 2, 3, 4)
+            )(*args)
 
-        for a, b in zip(of(gated_delta_rule), of(_recurrence)):
+        for a, b, x in zip(of(gated_delta_rule), of(_recurrence), args):
+            assert a.dtype == x.dtype and a.shape == x.shape
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
             assert bool(jnp.all(jnp.isfinite(a)))
             # the decay's gradient at the least bound is 5e-5 at most (a
             # position later the state is gone): a difference of sums near 1,
             # held to float32's rounding of those
             scale = max(float(jnp.max(jnp.abs(b))), 1e-3)
-            np.testing.assert_allclose(a, b, rtol=0, atol=SCAN_GRAD_RTOL * scale)
+            np.testing.assert_allclose(a, b, rtol=0, atol=grad_rtol * scale)
 
 
 @pytest.mark.parametrize("floor", [LEAST_LOG_DECAY - 0.5, 0.0, 1.0])
@@ -285,6 +319,105 @@ def test_the_scan_traces_no_loop_over_positions():
     args = _scan_inputs(256, "spread")
     text = str(jax.make_jaxpr(gated_delta_rule)(*args))
     assert "length=4" in text and "length=256" not in text
+
+
+def test_the_gradient_runs_one_loop_forward_and_one_back_and_solves_nothing():
+    """``jax.grad`` through the op at four chunks: two loops of four trips,
+    the forward's and the reverse one of the hand-written backward, and one
+    over the 15 rows a diagonal block's inverse is made by - no second
+    forward (nothing is under ``jax.checkpoint``), no loop over positions,
+    no triangular-solve call."""
+    args = _scan_inputs(256, "spread")
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a)), argnums=(0, 1, 2, 3, 4)))(*args)
+
+    def equations(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for inner in jax.core.jaxprs_in_params(e.params):
+                yield from equations(inner)
+
+    names = [e.primitive.name for e in equations(jaxpr.jaxpr)]
+    scans = [e for e in equations(jaxpr.jaxpr) if e.primitive.name == "scan"]
+    # beside the two over the chunks, the forward's loop over a sub-block's rows
+    assert [(e.params["length"], e.params["reverse"]) for e in scans] == [
+        (delta_rule._SUB - 1, False), (4, False), (4, True)
+    ]
+    # a trip over the chunks is one product and the stacking of what it carried
+    for scan in scans[1:]:
+        body = [e.primitive.name for e in equations(scan.params["jaxpr"].jaxpr)]
+        assert body.count("dot_general") == 1 and "exp" not in body
+    assert "while" not in names and "custom_vjp_call" not in names
+    for absent in ("triangular_solve", "checkpoint", "remat"):
+        assert not [n for n in names if absent in n]
+
+
+def test_the_backward_keeps_the_scans_scope():
+    """The gradient of the mixer under the layer's scopes, compiled: no
+    operation of the program is without a scope, forward or backward, and
+    the reverse loop's body reads ``attn/kda/scan/while/body`` in the
+    backward class. An operation of the ``custom_vjp``'s backward could be
+    named by the scope the op was called under or by none, so with none
+    unscoped ``kda_scan_ms`` reads all of it (``reduce/program.py``:
+    ``scope_ms`` matches whole names of the path)."""
+    cfg = dataclasses.replace(F32, n_layers=1, layer_kinds=(KDA,), dense_ff=(None,))
+    p = ling.init_params(cfg, jax.random.PRNGKey(0))["blocks"][0]["attn"]
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 130, cfg.d_model), jnp.float32)
+
+    def loss(w, x):
+        with jax.named_scope("attn"), jax.named_scope(KDA.name):
+            return jnp.sum(olmoe.kda_mixer(cfg, w, x, KDA))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile().as_text()
+    # an argument's name and a reduction's scalar body carry no ``jit(..)``
+    named = {n for n in re.findall(r'op_name="([^"]*)"', compiled) if n.startswith("jit(")}
+    paths = {(spans.scope_class(n), spans.scope_path(n)) for n in named}
+    assert not [n for n in named if not spans.scope_path(n).startswith("attn/kda")]
+    assert ("forward", "attn/kda/scan/while/body") in paths
+    assert ("backward", "attn/kda/scan/while/body") in paths
+    backward = {path for which, path in paths if which == "backward"}
+    mine = {path for path in backward if f"/{path}/".startswith("/attn/kda/scan/")}
+    assert len(mine) >= 2 and not [path for path in mine if "checkpoint" in path or "remat" in path]
+
+
+def test_what_the_forward_keeps_at_the_cells_shape():
+    """``jax.eval_shape`` of the ``custom_vjp``'s forward at 1 x 8,192 x 8
+    heads x 128: beside the five arguments it keeps every chunk's starting
+    state, ``u``, the system's solution ``U_0 | W``, the inverse ``T`` and
+    the query pairs - 0.20 GB a layer, under the 0.25 GB the five layers'
+    1.0 GB was budgeted from - and the column factors of no sub-chunk."""
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct((1, 8192, 8, 128), t) for t in (jnp.bfloat16,) * 3 + (f32,)]
+    shapes.append(jax.ShapeDtypeStruct((1, 8192, 8), f32))
+    out, (arguments, *kept) = jax.eval_shape(delta_rule._forward, *shapes)
+    assert (out.shape, out.dtype) == ((1, 8192, 8, 128), jnp.bfloat16)
+    assert [(a.shape, a.dtype) for a in arguments] == [(a.shape, a.dtype) for a in shapes]
+    chunks = (128, 1, 8)
+    assert [a.shape for a in kept] == [
+        chunks + (128, 128), chunks + (64, 128), chunks + (64, 256), chunks + (64, 64),
+        chunks + (64, 64),
+    ]
+    assert sum(a.size * a.dtype.itemsize for a in kept) == 201_326_592 <= 0.25e9
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_inverse_by_products_is_the_inverse(seed):
+    """``(I + A)^-1`` by rows inside the blocks of 16 and by block products
+    below them against ``jnp.linalg.inv``, for a strictly lower ``A`` of
+    entries up to 1 either way: the inverse's own entries reach 1e4 there
+    (a unit triangular matrix's condition grows with its size), so the two
+    are held to 5e-6 of the largest; and the blocks of 16 alone, which no
+    matrix product touches, where every row is a sum of the rows above."""
+    A = jnp.tril(jax.random.uniform(
+        jax.random.PRNGKey(seed), (2, 3, 64, 64), minval=-1.0, maxval=1.0), -1)
+    with jax.default_matmul_precision("highest"):
+        got, want = delta_rule._unit_lower_inverse(A), jnp.linalg.inv(jnp.eye(64) + A)
+    assert float(jnp.max(jnp.abs(want))) > 100.0
+    np.testing.assert_allclose(got, want, rtol=0, atol=5e-6 * float(jnp.max(jnp.abs(want))))
+    assert not np.any(np.triu(np.asarray(got), 1)) and np.all(np.diagonal(got, axis1=-2, axis2=-1) == 1.0)
+    ones = jnp.tril(jnp.ones((64, 64)), -1)  # (I + A)^-1 is 1 on the diagonal, -1 under it
+    np.testing.assert_array_equal(
+        delta_rule._unit_lower_inverse(ones), np.eye(64) - np.eye(64, k=-1))
 
 
 def test_conv4_is_the_loop():
@@ -556,15 +689,18 @@ def test_the_six_readers_read_what_the_program_names_and_nothing_else():
         "attn_mla_flash_roofline", "moe_shared_expert_ms",
     )
     read = {name: common.load_by_name("layer_metrics", name).read for name in names}
+    # the scan's paths as the op's ``custom_vjp`` names them in a trace: the
+    # chunk-parallel part under the scope itself, each loop's trip under
+    # ``while/body``, its product one call deeper, forward and backward alike
     paths = {
         "forward": {
-            "attn/kda/proj": 0.010, "attn/kda/scan": 0.030, "attn/kda/scan/checkpoint": 0.004,
-            "attn/kda/scan/closed_call/while/body": 0.020, "attn/mla/flash_fwd": 0.004,
+            "attn/kda/proj": 0.010, "attn/kda/scan": 0.030, "attn/kda/scan/while/body": 0.004,
+            "attn/kda/scan/while/body/closed_call": 0.020, "attn/mla/flash_fwd": 0.004,
             "attn/mla/proj": 0.002, "mlp/moe/shared": 0.003, "mlp/moe/router": 0.001,
         },
         "backward": {
-            "attn/kda/scan/attn/kda/scan/checkpoint/rematted_computation/while/body": 0.020,
-            "attn/kda/scan/checkpoint": 0.050, "attn/kda/out": 0.006,
+            "attn/kda/scan/while/body/closed_call": 0.020, "attn/kda/scan/while/body": 0.010,
+            "attn/kda/scan": 0.040, "attn/kda/out": 0.006,
             "attn/mla/flash_bwd": 0.006, "mlp/moe/shared": 0.005,
         },
     }
@@ -576,8 +712,8 @@ def test_the_six_readers_read_what_the_program_names_and_nothing_else():
             "kind_flash": {"mla": {"layers": 1, "flops": 4e11, "bytes": 2e8}},
         },
     }
-    # every operation under the scope, the loop's body and the recomputed
-    # forward among them; the maps and the gate are not the scan's
+    # every operation under the scope, both loops' bodies among them; the
+    # maps and the gate are not the scan's
     assert read["kda_scan_ms"](facts) == pytest.approx(62.0)
     assert read["attn_kda_ms"](facts) == pytest.approx(70.0)
     assert read["kda_scan_roofline"](facts) == pytest.approx(100 * 2e-3 / 62e-3)
