@@ -490,12 +490,14 @@ def _observe_link() -> Dict[str, float]:
 # the big shape (the model slices the last token off: S 2047) and the
 # head_dim 128 shape of the d_model 2048 point; the benchmark's shapes -
 # GPT-2 (1024 positions, 12 heads of 64: the whole sequence resident, the
-# static causal schedule) and OLMoE (4096 positions, 16 heads of 128:
-# longer than one resident block, (512, 512) tiles) and Mellum2 (8192
+# static causal schedule) and OLMoE and Ouro (4096 positions, 16 heads of
+# 128 at their cells' batches, 64 and 32 head-rows: four resident blocks
+# of 1024 rows, each call naming its VMEM limit) and Mellum2 (8192
 # positions of head size 128, a window of 1024 and none: the causal
 # schedule cut to the window's band, (1024, 1024) blocks and the two
-# staircases, and the same schedule whole, both with 2 MiB key/value rows
-# resident, the backward's rows past the default VMEM limit) and SDAR (a
+# staircases, and the same schedule whole on (1024, 512), both with
+# 2 MiB key/value rows resident, the backward's rows past the default VMEM
+# limit) and SDAR (a
 # clean and a noised copy of 4096 positions in blocks of 4, head size 128,
 # under the block-diffusion mask: the static schedule with both copies' row
 # groups of 1024 a grid step); then, at a
@@ -507,12 +509,13 @@ FLASH_CASES = (
     ("big", BATCH, SEQ - 1, 16, 64, None),
     ("gpt2", 2, 1024, 12, 64, None),
     ("head_dim128", 8, SEQ - 1, 16, 128, None),
-    ("olmoe", 2, 4096, 16, 128, None),
+    ("olmoe", 4, 4096, 16, 128, None),
+    ("ouro", 2, 4096, 16, 128, None),
     ("mellum_sliding", 1, 8192, 8, 128, 1024),
     ("mellum_full", 1, 8192, 8, 128, None),
     ("sdar_block", 1, 8192, 8, 128, None, (4, 4096)),
     # ling3-ft1's latent-attention layer: q.k 192 and v 128 both padded to
-    # 256 lanes, which is what the kernels are compiled at
+    # 256 lanes, which is what the kernels are compiled at, on (512, 512)
     ("ling_mla", 1, 8192, 8, 256, None),
     # a block mask that does not tile (B 6 straddles every tile's edge):
     # the general kernels' sweep, which no cell runs

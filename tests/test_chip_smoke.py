@@ -76,7 +76,8 @@ class TestCompileCache:
 class TestChipSmoke:
     @pytest.mark.parametrize(
         "config",
-        ["gpt2-small", "gpt2-medium", "olmoe-1b-7b-l1", "mellum2-12b-a2.5b-l4-ep8"],
+        ["gpt2-small", "gpt2-medium", "olmoe-1b-7b-l1", "mellum2-12b-a2.5b-l4-ep8",
+         "ouro-2.6b-l6"],
     )
     def test_kernels_phase_runs_the_benchmarks_flash_shapes(self, config):
         """Every configuration of the benchmark meets the flash kernels at

@@ -83,10 +83,11 @@ def test_grads_match_dense(S, blocks):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 def test_olmoe_shape_eight_blocks_a_side(dtype):
-    """OLMoE's shape in the benchmark (B 2, H 16, D 128; chip_smoke's
-    ``olmoe`` case) at a small S: 4096 positions in (512, 512) tiles are
-    eight blocks a side, here 256 in (32, 32). Forward and gradients
-    against the dense path; in bf16 at chip_smoke's tolerance."""
+    """OLMoE's and Ouro's heads in the benchmark (H 16, D 128;
+    chip_smoke's ``olmoe`` and ``ouro`` cases) at a small S: eight blocks
+    a side, 256 positions in (32, 32) as 4096 in (512, 512), their tiles
+    until PR 52. Forward and gradients against the dense path; in bf16
+    at chip_smoke's tolerance."""
     q, k, v = rand_qkv(jax.random.PRNGKey(7), (2, 256, 16, 128), dtype)
 
     def loss(attend, q, k, v):
@@ -104,6 +105,36 @@ def test_olmoe_shape_eight_blocks_a_side(dtype):
     for got, want, name in zip((out, *grads), (ref, *ref_grads), ("out", "dq", "dk", "dv")):
         err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want)))
         assert err <= tol, (name, err)
+
+
+# The long causal calls' form since PR 52, scaled down by 32: S 128 on
+# resident blocks of 32 rows in two row groups of 16, as 4,096 positions
+# on (1024, 512) - four blocks, the last one's loop over the blocks to its
+# left three trips (the backward's first key block meets three query blocks
+# below it), a wide tile and a diagonal sub-tile a row group - with the
+# sub-tile a staircase of chunks of 8 and of 4 (256 forward and 128
+# backward there); 125 ends inside the last block.
+@pytest.mark.parametrize("edge", [8, 4])
+@pytest.mark.parametrize("S", [128, 125])
+def test_four_resident_blocks_of_two_row_groups_match_dense(S, edge):
+    schedule = _module()._tiles(S, 16, True, 32, 16, edge)
+    assert schedule.kind == "nested" and schedule.edges == (edge, edge)
+    assert (schedule.s_pad // schedule.block_q, schedule.block_q // schedule.block_k) == (4, 2)
+    q, k, v = rand_qkv(jax.random.PRNGKey(52), (2, S, 2, 16))
+
+    def loss(attend, q, k, v):
+        out = attend(q, k, v)
+        return jnp.sum(out ** 2), out
+
+    flash = functools.partial(flash_attention, block_q=32, block_k=16, block_diag=edge)
+    grad = lambda f: jax.value_and_grad(
+        functools.partial(loss, f), argnums=(0, 1, 2), has_aux=True
+    )
+    (_, out), grads = grad(flash)(q, k, v)
+    (_, ref), ref_grads = grad(dense_attention)(q, k, v)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for got, want, name in zip(grads, ref_grads, "qkv"):
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
 
 
 def test_noncausal_grads_match_dense():
@@ -267,21 +298,20 @@ def _module():
     return sys.modules["torchft_tpu.ops.flash_attention"]
 
 
-# (S, interpret) -> tiles of a causal call without a window, at either
+# (S, interpret) -> tiles of a causal call without a window, at every
 # head size: up to 2048 padded positions the whole sequence is resident
 # and cut into the largest sub-tile of 512 / 256 / 128 that divides it
-# (PERF.md section 6, PR 25); beyond, and on the general path, the tiles
-# of before.
-@pytest.mark.parametrize("head_dim", [64, 128])
+# (PERF.md section 6, PR 25); the general path keeps the tiles of before.
+@pytest.mark.parametrize("head_dim", [64, 128, 256])
 @pytest.mark.parametrize(
     "S,interpret,want",
     [
         (99, False, (128, 128)), (256, False, (256, 256)),
         (1023, False, (1024, 512)), (1024, False, (1024, 512)),
         (1025, False, (1152, 128)), (2047, False, (2048, 512)),
-        (2048, False, (2048, 512)), (4096, False, (512, 512)),
+        (2048, False, (2048, 512)),
         (99, True, (104, 104)), (64, True, (64, 64)),
-        (1024, True, (1024, 512)), (4096, True, (512, 512)),
+        (1024, True, (1024, 512)),
     ],
 )
 def test_auto_tiles(S, head_dim, interpret, want):
@@ -290,6 +320,35 @@ def test_auto_tiles(S, head_dim, interpret, want):
     # a window or a non-causal call keeps the general path and its tiles
     general = (512, 512) if S >= 2047 else (128, 128)
     assert fa._auto_tiles(S, head_dim, interpret, nested=False) == general
+
+
+# Past 2048 padded positions, at head sizes 64 and 128: resident blocks of
+# 1024 rows in two row groups of 512 where they pad the sequence no
+# further than the (512, 512) of before does - 4,096 and 8,192 positions,
+# the cells' lengths, with and without the token the loss slices off - and
+# (512, 512) where they would: 2,560 and 4,608 positions are five and nine
+# blocks of 512. 256 lanes (``ling3-ft1``'s latent attention as it is
+# padded) keep (512, 512) at every length: the larger block buys 0.44 ms
+# of that step for 9.7 s of its cold set-up (PERF.md section 6, PR 52).
+@pytest.mark.parametrize("head_dim", [64, 128, 256])
+@pytest.mark.parametrize(
+    "S,interpret,want",
+    [
+        (4095, False, (1024, 512)), (4096, False, (1024, 512)),
+        (8191, False, (1024, 512)), (8192, False, (1024, 512)),
+        (3000, False, (1024, 512)), (2560, False, (512, 512)),
+        (4608, False, (512, 512)), (4097, False, (512, 512)),
+        (4096, True, (1024, 512)), (2056, True, (512, 512)),
+    ],
+)
+def test_auto_tiles_past_one_resident_block(S, head_dim, interpret, want):
+    fa = _module()
+    assert fa._auto_tiles(S, head_dim, interpret) == (want if head_dim <= 128 else (512, 512))
+    assert fa._auto_tiles(S, head_dim, interpret, nested=False) == (512, 512)
+    # whatever the rule answers pads the sequence as (512, 512) did
+    schedule = fa._tiles(S, head_dim, interpret, None, None, None)
+    assert schedule.kind == "nested" and not schedule.one_resident_block
+    assert schedule.s_pad == -(-S // 512) * 512
 
 
 # (S, window) -> tiles of a causal call with a window: the largest
@@ -331,10 +390,10 @@ def test_auto_tiles_of_a_window(S, window, interpret, want):
         ("gpt2m-ft1", 1024, 64, None, None, ("nested", 1024, 512, (512, 128))),
         ("gpt2s-raw", 1024, 64, None, None, ("nested", 1024, 512, (512, 128))),
         ("gpt2m-raw", 1024, 64, None, None, ("nested", 1024, 512, (512, 128))),
-        ("olmoe-ft1", 4096, 128, None, None, ("nested", 512, 512, (256, 128))),
-        ("ouro-ft1", 4096, 128, None, None, ("nested", 512, 512, (256, 128))),
+        ("olmoe-ft1", 4096, 128, None, None, ("nested", 1024, 512, (256, 128))),
+        ("ouro-ft1", 4096, 128, None, None, ("nested", 1024, 512, (256, 128))),
         ("mellum2-ft1-sliding", 8192, 128, 1024, None, ("banded", 1024, 1024, (256, 128))),
-        ("mellum2-ft1-full", 8192, 128, None, None, ("nested", 512, 512, (256, 128))),
+        ("mellum2-ft1-full", 8192, 128, None, None, ("nested", 1024, 512, (256, 128))),
         ("sdar-ft1", 8192, 128, None, (4, 4096), ("blocked", 1024, 1024, (256, 128))),
         # latent attention: q.k 192 and v 128, both padded to 256 lanes
         ("ling3-ft1-mla", 8192, 256, None, None, ("nested", 512, 512, (256, 128))),
@@ -793,7 +852,9 @@ def test_staircase_edge_divides_the_sub_tile():
 # the benchmark's shapes: GPT-2's (1024, 512), OLMoE's (512, 512) at S 4096
 # and Mellum2's window of 1024 at S 8192, at the whole sub-tile (the kernels
 # until PR 35; with a window the area of the general kernels' 45 tiles) and
-# both edges
+# both edges; then the long causal calls at 4,096 and 8,192 positions on the
+# blocks of before PR 52 and of after it, at the edges both run: the pairs
+# are the same (the area depends on the staircase's edge alone)
 @pytest.mark.parametrize(
     "S,block_q,block_k,edge,window,want",
     [
@@ -804,6 +865,9 @@ def test_staircase_edge_divides_the_sub_tile():
         (8192, 512, 512, 512, 1024, 11_796_480), (8192, 512, 512, 256, 1024, 9_830_400),
         (8192, 512, 512, 128, 1024, 8_847_360), (8192, 1024, 512, 128, 1024, 8_847_360),
         (2048, 512, 512, 128, 512, 1_146_880), (1024, 128, 128, 128, 256, 344_064),
+        (4096, 1024, 512, 256, None, 8_912_896), (4096, 1024, 512, 128, None, 8_650_752),
+        (8192, 512, 512, 256, None, 34_603_008), (8192, 1024, 512, 256, None, 34_603_008),
+        (8192, 512, 512, 128, None, 34_078_720), (8192, 1024, 512, 128, None, 34_078_720),
     ],
 )
 def test_scores_computed(S, block_q, block_k, edge, window, want):
@@ -821,3 +885,25 @@ def test_scores_computed(S, block_q, block_k, edge, window, want):
         band = sum(min(q + 1, window) for q in range(S))
         assert band + (S - window) == finest <= want
         assert (S, window) != (8192, 1024) or band == 7_864_832
+
+
+# (cell, in-model positions, head size): every causal call without a
+# window that a cell makes. What its schedule computes today, forward and
+# backward, is what (512, 512) computed at the same edges - and any other
+# resident block: the same work, no pair left out (PR 52).
+@pytest.mark.parametrize(
+    "cell,S,head_dim",
+    [
+        ("gpt2", 1024, 64), ("olmoe-ft1", 4096, 128), ("ouro-ft1", 4096, 128),
+        ("mellum2-ft1-full", 8192, 128), ("ling3-ft1-mla", 8192, 256),
+    ],
+)
+def test_scores_computed_do_not_depend_on_the_resident_block(cell, S, head_dim):
+    fa = _module()
+    schedule = fa._tiles(S, head_dim, False, None, None, None)
+    assert schedule.kind == "nested", cell
+    for edge in schedule.edges:
+        today = fa._scores_computed(schedule.s_pad, schedule.block_q, schedule.block_k, edge)
+        for block_q, block_k in ((512, 512), (1024, 512), (1024, 1024), (2048, 1024)):
+            if schedule.s_pad % block_q == 0:
+                assert fa._scores_computed(schedule.s_pad, block_q, block_k, edge) == today

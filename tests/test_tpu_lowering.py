@@ -30,11 +30,19 @@ def _mosaic_calls(fn, *args) -> int:
     return lowered.as_text().count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("head_dim", [64, 128])
+_FLASH_SHAPES = [
+    (256, None), (99, None), (2047, None), (1024, 256), (1024, None),
+    (1023, None), (4096, None), (8192, 1024), (8192, None),
+]
+
+
+# Every (positions, window) at head sizes 64 and 128; the long causal
+# calls also at 256 lanes, ``ling3-ft1``'s latent attention as it is padded:
+# each lowers at the blocks ``_auto_tiles`` gives its length.
 @pytest.mark.parametrize(
-    "seq,window",
-    [(256, None), (99, None), (2047, None), (1024, 256), (1024, None),
-     (1023, None), (4096, None), (8192, 1024), (8192, None)],
+    "head_dim,seq,window",
+    [(head_dim, *shape) for head_dim in (64, 128) for shape in _FLASH_SHAPES]
+    + [(256, 4096, None), (256, 8192, None)],
 )
 def test_flash_forward_and_backward_lower(head_dim, seq, window):
     q = jnp.ones((2, seq, 2, head_dim), jnp.bfloat16)

@@ -303,9 +303,9 @@ def _resident_params(*blocks, wide_body: bool = False) -> dict:
     dq rows are 16.1 MiB buffered and the compiler refused the call by
     1 MiB (compiled for a described v5e, PR 36). ``wide_body``: a body
     that a quarter of the default does not hold, so the limit is always
-    named - a window's band on sub-tiles of 1024, whose forward with two
-    row groups a block the chip's compiler refused by 180 KB inside the
-    step where the same call alone had compiled (PR 37)."""
+    named - the ``banded`` and ``blocked`` kinds (PR 37: (2048, 1024) was
+    refused by 180 KB in the step, not alone) and SEVERAL resident blocks
+    of over 512 rows (PR 52: ``nested`` on 1024 rows, by 188 KB in a step)."""
     buffers = 2 * sum(
         math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in blocks
     )
@@ -830,7 +830,7 @@ def _flash_fwd_call(q: jax.Array, k: jax.Array, v: jax.Array, *, schedule: Sched
         **_resident_params(
             ((held, D), q.dtype), ((S, D), k.dtype), ((S, D), v.dtype),
             ((held, D), q.dtype), ((S,), jnp.float32),
-            wide_body=kind in ("banded", "blocked"),
+            wide_body=kind in ("banded", "blocked") or (num_q > 1 and block_q > 512),
         ),
     )(q, k, v)
     return out.reshape(BH, S, D) if blocked else out, lse
@@ -1343,8 +1343,8 @@ def _flash_bwd_call(q, k, v, o, lse, do, *, schedule: Schedule):
         name="flash_bwd",
         **_resident_params(
             ((S, D), q.dtype), ((S, D), do.dtype), ((S, D), dq_dtype),
-            ((S,), jnp.float32), ((S,), jnp.float32),
-            *[((held, D), k.dtype)] * 4, wide_body=kind in ("banded", "blocked"),
+            ((S,), jnp.float32), ((S,), jnp.float32), *[((held, D), k.dtype)] * 4,
+            wide_body=kind in ("banded", "blocked") or (num_q > 1 and block_q > 512),
         ),
     )(q, k, v, do, lse, delta)
     if blocked:
@@ -1463,70 +1463,70 @@ def _auto_tiles(
     block_mask: Optional[Tuple[int, int]] = None,
 ) -> Tuple[int, int]:
     """The (block_q, block_k) a call runs when it names none, from what
-    the call can see: the sequence length, the head size, whether the
-    kernels are interpreted, whether the whole sequence may be the one
-    resident block (``nested``: causal, no window) and a causal call's
-    ``window``. Measured on the v5e inside the whole training step
-    (PERF.md section 6, PR 25; a window's, PR 37).
+    it can see: the sequence length, the head size, whether the kernels
+    are interpreted, whether the whole sequence may be the one resident
+    block (``nested``: causal, no window), a ``window``, a ``block_mask``.
+    Measured on the v5e inside the whole step (PERF.md section 6).
 
-    Tiles key on the PADDED length, not raw S: language-model training
-    slices the last token off (tokens[:, :-1]), so an in-model sequence
-    is 1023 or 2047. On hardware the lse row is sliced along the LANE
-    dim in block-wide stores, so blocks are 128-multiples (Mosaic rejects
-    misaligned vector stores - observed at S=99 on v5e); interpret mode
-    only needs the 8-sublane floor.
+    Tiles key on the PADDED length, not raw S: training slices the last
+    token off (tokens[:, :-1]), so an in-model sequence is 1023 or 2047.
+    On hardware the lse row is sliced along the LANE dim in block-wide
+    stores, so blocks are 128-multiples (Mosaic rejects misaligned vector
+    stores - observed at S=99 on v5e); interpret mode needs 8 sublanes.
 
     Nested, up to 2048 positions: the whole sequence is one resident
     block, cut into the largest sub-tiles of 512, 256 or 128 that divide
     it. At S 1024, head_dim 64 that is (1024, 512): 14.9 + 25.0 ms a
     gpt2-small step in the two kernels against 17.1 + 23.6 at (1024, 256),
     19.9 + 23.9 at (1024, 128), and 71.6 + 95.1 for the (128, 128) tiles
-    in a dynamic loop that it replaces; head_dim 128 ranks them the same.
-    Smaller row groups compute less above the diagonal (1.5, 1.25, 1.125
-    times the causal half) and lose more by their shorter streams in the
-    wide tiles; the diagonal sub-tile alone is cut finer by
-    ``_auto_edges`` (PR 35), which leaves the row groups 512 tall.
-    Longer sequences, and the general path at 2048 and over, keep
-    (512, 512), the general path below that (128, 128): not measured in
-    PR 25.
+    in a dynamic loop that it replaces; head_dim 128 ranks them the same
+    (smaller row groups compute less above the diagonal and lose more by
+    shorter streams; ``_auto_edges`` cuts the diagonal sub-tile alone).
+
+    Nested, past 2048 positions: resident blocks of 1024 rows in two row
+    groups of 512 where the sequence padded to 512 is whole such blocks,
+    else (512, 512), which pads no further: every such call's tiles
+    until PR 52, and a wider head's still. Ms a step in the kernel inside
+    ``ouro-ft1``'s step (S 4096, D 128, 32 head-rows, 48 forward and 24
+    backward calls, edges 256 | 128; my chip runs, PR 52), forward |
+    backward: (512, 512) 65.92 | 53.86, (1024, 512) 57.72 | 50.32,
+    (1024, 1024) 60.43 | 49.33, (2048, 1024) 54.96 | 48.03: the pairs
+    computed depend on the edge alone (``_scores_computed``), the same
+    work in 4 grid steps and 6 loop trips a head for 8 and 28. The
+    kernels alone, ms a call, same order: S 8192, D 128, 64 head-rows
+    9.53 | 17.39, 8.41 | 16.11, 8.96 | 15.98, 8.08 | 15.13; 256 lanes, 8
+    head-rows 2.02 | 4.20, 1.88 | 3.94, 1.93 | 3.94, not run. NOT taken:
+    (1024, 1024), 1.0 ms of that step better backward, 2.7 worse forward
+    (blocks a kernel, as ``edges`` are, would buy the 1.0); (2048, 1024),
+    four times the body (9.5 s to compile a pair for 2.6), 1.3 s more of
+    that cell's warm set-up; 256 lanes, 0.44 ms of ``ling3-ft1``'s step for
+    9.7 s of its cold one. The general path keeps (512, 512) or (128, 128).
 
     A window shorter than the sequence: the LARGEST sub-tile of 1024,
     512, 256 or 128 that divides it, one a resident block, so that the
-    band is as few pieces as can be (a window of one sub-tile is the two
-    staircases and no tile between them). Mellum2's layer (S 8192, D 128,
-    window 1024; ms a layer in the kernels, forward | backward, the
-    staircases' edges as ``_auto_edges`` has them): (1024, 1024)
-    3.53 | 4.88, (1024, 512) 4.23 | 5.58, (512, 512) 4.48 | 6.07,
-    (1024, 256) 6.11 | 8.30, and 5.25 | 7.30 for the general kernels at
-    (512, 512) that it replaces: a grid step costs about a 512 x 512
-    tile's time whatever it holds, and short pieces fill the MXUs worse.
-    Two row groups a block, (2048, 1024), read 3.37 | 4.55 and were NOT
-    taken: twice the body, a second more of every run's set-up on top of
-    the second that (1024, 1024) adds to the general kernels', and a
-    forward within 180 KB of the default VMEM limit. A window that none
-    of them divides keeps the general path's tiles.
+    band is as few pieces as can be. Mellum2's layer (S 8192, D 128,
+    window 1024; ms a layer, forward | backward, PR 37): (1024, 1024)
+    3.53 | 4.88, (1024, 512) 4.23 | 5.58, (512, 512) 4.48 | 6.07, the
+    general kernels 5.25 | 7.30: a grid step costs about a 512 x 512
+    tile's time whatever it holds, and short pieces fill the MXUs worse;
+    (2048, 1024), 3.37 | 4.55, NOT taken: twice the body, a second more of
+    every set-up. A window that none divides keeps the general tiles.
 
     Under ``block_mask`` (B, L): the LARGEST sub-tile of 1024, 512, 256
     or 128 that divides a copy and holds whole diffusion blocks, one a
     resident block (``_blocked`` then holds, nothing padded). SDAR's layer
-    (L 4096, B 4, D 128, 64 head-rows; ms a layer in the kernels, forward
-    | backward, edges 256 | 128 unless said; my chip run, PR 47):
-    (1024, 1024) 5.03 | 8.21, (1024, 512) 4.89 | 8.28, (512, 512)
-    5.62 | 9.10, and 10.02 | 15.07 for the general kernels' walk on
-    (512, 512) that it replaces. The pairs computed depend on the edge
-    alone, so larger tiles are fewer trips and grid steps for the same
-    work; two row groups a block, (1024, 512), are no faster than one of
-    1024 and twice the body. The TPU compiler takes 6.4 s for four
-    layers' pairs on (1024, 1024) where it takes 1.5 on (512, 512) (off
-    the chip; a cold set-up's, once a cache). A mask that none of them
-    fits keeps the general path's tiles."""
-    del head_dim  # 64 and 128 rank the tiles alike (PERF.md, PR 25)
+    (L 4096, B 4, D 128; ms a layer, PR 47): (1024, 1024) 5.03 | 8.21,
+    (1024, 512) 4.89 | 8.28, (512, 512) 5.62 | 9.10, the general walk
+    10.02 | 15.07. A mask that none fits keeps the general tiles."""
+    # head sizes rank the tiles alike (PRs 25, 52); 256 lanes compile longer
     unit = 8 if interpret else 128
     s_pad = _cdiv(seq, unit) * unit
     if nested and s_pad <= 2048:
         return s_pad, next(
             (sub for sub in (512, 256, 128) if s_pad % sub == 0), s_pad
         )
+    if nested and head_dim <= 128 and _cdiv(s_pad, 512) % 2 == 0:  # pads alike
+        return 1024, 512
     if window is not None and window < s_pad:
         for sub in (1024, 512, 256, 128):
             if window % sub == 0:
