@@ -517,6 +517,8 @@ FLASH_CASES = (
     # ling3-ft1's latent-attention layer: q.k 192 and v 128 both padded to
     # 256 lanes, which is what the kernels are compiled at, on (512, 512)
     ("ling_mla", 1, 8192, 8, 256, None),
+    # dsv2lite-ft1's: the same padded width, all 16 heads of a layer
+    ("dsv2_mla", 1, 8192, 16, 256, None),
     # a block mask that does not tile (B 6 straddles every tile's edge):
     # the general kernels' sweep, which no cell runs
     ("block_general", 2, 1920, 4, 128, None, (6, 960)),

@@ -263,6 +263,22 @@ def test_window_requires_causal():
         flash_attention(q, k, v, causal=False, window=8)
 
 
+@pytest.mark.parametrize("entry", ["rows", "heads"])
+@pytest.mark.parametrize("narrow", ["k", "v"])
+def test_a_key_or_value_of_another_width_is_refused(entry, narrow):
+    """The kernels take one lane width for q, k and v. A value of 128 beside
+    q.k of 256 compiled and ran on the chip and answered NaN in dq and dk
+    (PERF.md section 6, PR 50): it is refused before anything is traced."""
+    from torchft_tpu.ops import flash_attention_rows
+
+    shape = (2, 64, 256) if entry == "rows" else (1, 64, 2, 256)
+    q = jnp.zeros(shape, jnp.float32)
+    args = {"q": q, "k": q, "v": q, narrow: q[..., :128]}
+    call = flash_attention_rows if entry == "rows" else flash_attention
+    with pytest.raises(ValueError, match="share one"):
+        jax.eval_shape(lambda q, k, v: call(q, k, v), args["q"], args["k"], args["v"])
+
+
 def test_transformer_attn_window():
     import dataclasses
 
@@ -397,6 +413,7 @@ def test_auto_tiles_of_a_window(S, window, interpret, want):
         ("sdar-ft1", 8192, 128, None, (4, 4096), ("blocked", 1024, 1024, (256, 128))),
         # latent attention: q.k 192 and v 128, both padded to 256 lanes
         ("ling3-ft1-mla", 8192, 256, None, None, ("nested", 512, 512, (256, 128))),
+        ("dsv2lite-ft1-mla", 8192, 256, None, None, ("nested", 512, 512, (256, 128))),
         ("a-block-over-a-tile", 1920, 128, None, (6, 960), ("general", 128, 128, None)),
         ("one-block-a-copy", 4096, 128, None, (2048, 2048), ("general", 512, 512, None)),
         ("a-padded-length", 400, 128, None, (8, 200), ("general", 128, 128, None)),

@@ -537,3 +537,50 @@ def test_the_ling_cells_step_compiles_for_the_chip_with_what_its_family_states(o
         jax.config.update("jax_enable_compilation_cache", cache_was)
         compilation_cache.reset_cache()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_the_dsv2_cells_step_compiles_for_the_chip_with_what_its_family_states(one_chip, monkeypatch):
+    """``dsv2lite-ft1``'s gradient step as the generators lower it
+    (``mixed_precision_grad``), at the published head sizes (128 + 64
+    rotated, a latent of 512, YaRN's published numbers) and cut elsewhere -
+    the cell's five layers, 2 heads, 2 of 16 experts, 1,024 positions -
+    through the TPU compiler for the described chip: the Mosaic calls are
+    the flash pair of EVERY layer, ten, at 256 lanes, which the family
+    states; the held share and the balance loss a sequence compile as
+    plain XLA."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark import common
+
+    monkeypatch.setattr(
+        sys.modules["torchft_tpu.ops.flash_attention"], "_pick_interpret", lambda _i: False
+    )
+    sizes = common.load_json("configs", "dsv2-lite-l5-ep8.json")
+    published = sizes["rope_scaling"]
+    sizes = {**sizes, **sizes["rehearsal"], "hidden_size": 256, "qk_nope_head_dim": 128,
+             "v_head_dim": 128, "qk_rope_head_dim": 64, "kv_lora_rank": 512,
+             "n_routed_experts": 2, "rope_scaling": published, "seq": 1025}
+    family = common.load_family(sizes["family"])
+    cfg = family.build(sizes)
+    assert [type(k.mixer).__name__ for k in cfg.kinds] == ["Mla"] * 5
+    assert cfg.ff == (96, None, None, None, None) and cfg.held == (0, 2)
+    assert family.lowered_mosaic_calls(cfg) == 10
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), tree
+        )
+
+    params = on_chip(jax.eval_shape(lambda: family.init(cfg, jax.random.PRNGKey(0))))
+    tokens = jax.ShapeDtypeStruct((1, sizes["seq"]), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(common.mixed_precision_grad(family, cfg)).lower(params, tokens)
+    common.require_mosaic(lowered, 10, "dsv2lite-ft1")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 10

@@ -31,7 +31,9 @@ For activations ``x`` (B, S, D), per layer, pre-norm::
   ``load_balancing_loss_func``: ``E sum_e f_e P_e`` with ``f_e`` the share
   of the tokens that chose ``e`` (summed over the K choices) and ``P_e``
   the mean of ``p[:, e]``; z-loss the mean of ``logsumexp(r) ** 2``; all
-  three means over every token of the step and every layer.
+  three means over every token of the step and every layer. (``seq_balance``
+  is the other form, DeepSeek-V2's: the product taken a sequence and a
+  layer at a time, the layers summed; ``aux_losses``.)
 - Embedding without a position table, final RMSNorm, and a readout matrix
   of its own (untied).
 
@@ -109,7 +111,11 @@ file (``models/mellum.py`` is one):
   pairs, causal softmax at ``(head_dim + rope_dim) ** -0.5`` through the
   flash kernels (the two widths padded to one, exactly), ``y = W_o (o
   sigmoid(W_gate u))`` with one gate a head. ``n_heads`` is how many heads a
-  rank HOLDS: a head's part of either mixer's output is its own;
+  rank HOLDS: a head's part of either mixer's output is its own. The norm of
+  q and k, the gate and a factor on the softmax scale are data of ``Mla``,
+  and the rotated numbers turn at the kind's YaRN frequencies where it
+  names them (``models/dsv2.py``: DeepSeek-V2's own layer has neither norm
+  nor gate, and YaRN with ``mscale ** 2`` on the scale);
 - the router's other form (``router``: a ``SigmoidRouter``; DeepSeek-V3,
   arXiv:2412.19437; ``_sigmoid_choice``): each expert's score ``s =
   sigmoid(r)``; the K chosen on ``s + bias`` (the bias a leaf of ``moe``
@@ -212,10 +218,19 @@ class Mla:
     one shared vector of ``latent`` numbers a position, and ``rope_dim``
     rotated numbers, one vector for every head, beside each head's
     ``head_dim`` unrotated ones, so q.k is ``head_dim + rope_dim`` wide and
-    the value ``head_dim``."""
+    the value ``head_dim``. What the models that have it vary: ``qk_norm``,
+    the RMSNorm of q and of k over a head's whole width (off: no ``q_norm``
+    or ``k_norm`` leaf); ``gated``, the sigmoid gate a head on the output
+    (off: no ``w_gate`` leaf); ``softmax_factor``, what multiplies the
+    softmax scale ``(head_dim + rope_dim) ** -0.5`` (DeepSeek-V2 under YaRN:
+    ``mscale ** 2``). The rotated numbers turn at the layer's
+    ``AttentionKind.yarn`` frequencies where it has them."""
 
     latent: int
     rope_dim: int
+    qk_norm: bool = True
+    gated: bool = True
+    softmax_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -286,6 +301,7 @@ class OlmoeConfig:
     noise_floor: float = 1e-3  # t ~ U[noise_floor, 1]
     router: Optional[SigmoidRouter] = None  # None: a softmax over the experts
     shared_width: Optional[int] = None  # one expert every token meets; None: none
+    seq_balance: bool = False  # the balance loss a sequence and a layer (``aux_losses``)
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -397,22 +413,24 @@ def _kda_init(cfg: OlmoeConfig, kind: AttentionKind, bk: jax.Array) -> Dict[str,
 def _mla_init(cfg: OlmoeConfig, kind: AttentionKind, bk: jax.Array) -> Dict[str, Any]:
     """Latent attention's weights: q whole (``head_dim + rope_dim`` a head),
     the map down to the latent and the shared rotated key, the latent's
-    norm, the map up to each head's unrotated key and value, the two
-    QK-norm scales over a head's whole q.k width, the gate's map to one
-    number a head, ``wo``."""
+    norm, the map up to each head's unrotated key and value, ``wo``; where
+    the mixer has them, the two QK-norm scales over a head's whole q.k
+    width and the gate's map to one number a head."""
     d, h, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
     latent, rope_dim = kind.mixer.latent, kind.mixer.rope_dim
     ks = jax.random.split(bk[0], 5)
-    return {
+    p = {
         "wq": _dense_init(ks[0], (d, h * (dh + rope_dim)), d ** -0.5),
         "w_kva": _dense_init(ks[1], (d, latent + rope_dim), d ** -0.5),
         "kv_norm": _ones(latent),
         "w_kvb": _dense_init(ks[2], (latent, h * 2 * dh), latent ** -0.5),
-        "q_norm": _ones(dh + rope_dim),
-        "k_norm": _ones(dh + rope_dim),
-        "w_gate": _dense_init(ks[3], (d, h), d ** -0.5),
         "wo": _dense_init(ks[4], (h * dh, d), (h * dh) ** -0.5),
     }
+    if kind.mixer.qk_norm:
+        p.update(q_norm=_ones(dh + rope_dim), k_norm=_ones(dh + rope_dim))
+    if kind.mixer.gated:
+        p["w_gate"] = _dense_init(ks[3], (d, h), d ** -0.5)
+    return p
 
 
 def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
@@ -484,6 +502,18 @@ def _yarn_ramp(yarn: Yarn, theta: float, head_dim: int) -> jax.Array:
     return jnp.clip((pairs - low) / max(high - low, 1e-3), 0.0, 1.0)
 
 
+def _pair_frequencies(half: int, theta: float, yarn: Optional[Yarn]) -> jax.Array:
+    """What a position's number is multiplied by for the angle of each of
+    ``half`` pairs, (half,) float32: ``theta ** (-i / half)``, or ``yarn``'s
+    blend of that frequency with its ``1 / factor`` (``Yarn``). The one
+    place the blend is computed, whatever pairs a caller turns."""
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is not None:
+        ramp = _yarn_ramp(yarn, theta, 2 * half)
+        inv_freq = (1.0 - ramp) * inv_freq + ramp * inv_freq / yarn.factor
+    return inv_freq
+
+
 def _rotary_angles(
     S: int, head_dim: int, theta: float, yarn: Optional[Yarn],
     positions: Optional[jax.Array],
@@ -493,11 +523,7 @@ def _rotary_angles(
     0..S-1 where none are stated: the angle is ``pos * theta ** (-2 i /
     head_dim)``, or ``pos`` times ``yarn``'s blend of that frequency
     (``Yarn``), whose ``attention_factor`` multiplies both."""
-    half = head_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    if yarn is not None:
-        ramp = _yarn_ramp(yarn, theta, 2 * half)
-        inv_freq = (1.0 - ramp) * inv_freq + ramp * inv_freq / yarn.factor
+    inv_freq = _pair_frequencies(head_dim // 2, theta, yarn)
     if positions is None:
         positions = jnp.arange(S, dtype=jnp.float32)
     angle = positions.astype(jnp.float32)[:, None] * inv_freq  # (S, half)
@@ -734,16 +760,18 @@ def kda_mixer(
         return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
 
 
-def _rope_pairs(x: jax.Array, theta: float) -> jax.Array:
+def _rope_pairs(x: jax.Array, theta: float, yarn: Optional[Yarn]) -> jax.Array:
     """Rotary embedding with INTERLEAVED pairs: of ``x`` (B, S, ..., r)
     float32 the pair (``2 i``, ``2 i + 1``) turns by ``pos * theta ** (-2 i
-    / r)`` at position ``pos`` of axis 1."""
+    / r)`` at position ``pos`` of axis 1, or by ``pos`` times ``yarn``'s
+    blend of that frequency, whose ``attention_factor`` multiplies the
+    cosine and the sine (``Yarn``; ``_pair_frequencies``)."""
     S, r = x.shape[1], x.shape[-1]
-    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * theta ** (
-        -jnp.arange(r // 2, dtype=jnp.float32) / (r // 2)
-    )
+    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * _pair_frequencies(r // 2, theta, yarn)
     angle = angle.reshape((S,) + (1,) * (x.ndim - 3) + (r // 2,))
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     pairs = x.reshape(x.shape[:-1] + (r // 2, 2))
     a, b = pairs[..., 0], pairs[..., 1]
     return jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1).reshape(x.shape)
@@ -767,16 +795,19 @@ def mla_mixer(
         down = x @ p["w_kva"].astype(cfg.dtype)
         c = _rmsnorm(down[..., :latent], p["kv_norm"], cfg.rms_norm_eps)
         up = (c @ p["w_kvb"].astype(cfg.dtype)).reshape(B, S, h, 2 * dh)
-        gate = x @ p["w_gate"].astype(cfg.dtype)
+        if kind.mixer.gated:
+            gate = x @ p["w_gate"].astype(cfg.dtype)
     with jax.named_scope("qk_rows"):
         # one rotated key a position, the same for every head
         k_rope = jnp.broadcast_to(down[..., None, latent:], (B, S, h, r))
         k = jnp.concatenate([up[..., :dh], k_rope], axis=-1)
 
-        def normed_and_turned(t: jax.Array, scale: jax.Array) -> jax.Array:
-            t = _rmsnorm(t.astype(f32), scale, cfg.rms_norm_eps)
+        def normed_and_turned(t: jax.Array, norm: str) -> jax.Array:
+            t = t.astype(f32)
+            if kind.mixer.qk_norm:
+                t = _rmsnorm(t, p[norm], cfg.rms_norm_eps)
             return jnp.concatenate(
-                [t[..., :dh], _rope_pairs(t[..., dh:], cfg.rope_theta)], axis=-1
+                [t[..., :dh], _rope_pairs(t[..., dh:], cfg.rope_theta, kind.yarn)], axis=-1
             )
 
         lanes = -(-width // 128) * 128  # ONE width for q, k and v: v's too
@@ -785,13 +816,15 @@ def mla_mixer(
             t = t.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(B * h, S, -1)
             return jnp.pad(t, ((0, 0), (0, 0), (0, lanes - t.shape[-1])))
 
-        q = rows(normed_and_turned(q, p["q_norm"]) * width ** -0.5)
-        k = rows(normed_and_turned(k, p["k_norm"]))
+        q = rows(normed_and_turned(q, "q_norm") * (width ** -0.5 * kind.mixer.softmax_factor))
+        k = rows(normed_and_turned(k, "k_norm"))
         v = rows(up[..., dh:])
     out = flash_attention_rows(q, k, v)[..., :dh]
     with jax.named_scope("out"):
-        out = out.reshape(B, h, S, dh).transpose(0, 2, 1, 3).astype(f32)
-        out = (out * jax.nn.sigmoid(gate.astype(f32))[..., None]).astype(cfg.dtype)
+        out = out.reshape(B, h, S, dh).transpose(0, 2, 1, 3)
+        if kind.mixer.gated:
+            out = out.astype(f32) * jax.nn.sigmoid(gate.astype(f32))[..., None]
+            out = out.astype(cfg.dtype)
         return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
 
 
@@ -1181,7 +1214,10 @@ def moe_layer(
         the N x K claims fell on an expert held here (all of them where
         every expert is), and ``held_dense_layers``, the share of the held
         experts that were applied to every token (0.0 to 1.0; summed over
-        the layers, how many layers' worth of the dense form a step ran).
+        the layers, how many layers' worth of the dense form a step ran);
+        under ``cfg.seq_balance``, ``seq_balance``: THIS layer's balance
+        loss, ``mean_b sum_e f_be P_be`` over the batch's sequences (1.0 at
+        even routing), so that the layers' sum is the loss's own sum.
     """
     B, S, D = x.shape
     N, E, K = B * S, cfg.n_experts, cfg.experts_per_token
@@ -1218,6 +1254,15 @@ def moe_layer(
         "held_claims": jnp.asarray(held_claims, jnp.float32),
         "held_dense_layers": jnp.asarray(heavy, jnp.float32) / cfg.held[1],
     }
+    if cfg.seq_balance:
+        with jax.named_scope("router"):
+            # ``f_be``: sequence b's claims on expert e over the even share
+            # K S / E, a count and no function of the weights; ``P_be``: the
+            # sequence's mean of ``p[:, e]``, through which the gradient goes
+            mine = chosen.reshape(B, S * K, 1) == jnp.arange(E)
+            f = jnp.sum(mine, axis=1, dtype=jnp.float32) * (E / (K * S))
+            p_mean = jnp.mean(probs.reshape(B, S, E), axis=1)
+            stats["seq_balance"] = jnp.mean(jnp.sum(f * p_mean, axis=-1))
     if cfg.router is not None:
         with jax.named_scope("router"):
             # what ``_bias_pull`` makes the bias's gradient: each expert's
@@ -1479,8 +1524,16 @@ def aux_losses(
     cfg: OlmoeConfig, stats: Dict[str, jax.Array], n_tokens: int
 ) -> Tuple[jax.Array, jax.Array]:
     """(balance loss, router z-loss) from the router's sums over
-    ``n_tokens`` tokens a layer with experts and pass."""
+    ``n_tokens`` tokens a layer with experts and pass. The balance loss
+    pools every token and layer of the step before the product, ``E sum_e
+    f_e P_e`` of the pooled shares, or under ``cfg.seq_balance``
+    (DeepSeek-V2, arXiv:2405.04434, its ``seq_aux``) is taken a sequence
+    and a layer at a time and SUMMED over the layers, ``sum_l mean_b E sum_e
+    f_lbe P_lbe`` with ``f`` the sequence's share of claims over K and ``P``
+    its mean probability (``moe_layer``: ``seq_balance``)."""
     n = float(n_tokens * cfg.expert_layers * cfg.passes)
+    if cfg.seq_balance:
+        return stats["seq_balance"], stats["z"] / n
     balance = cfg.n_experts * jnp.sum((stats["claims"] / n) * (stats["probs"] / n))
     return balance, stats["z"] / n
 

@@ -1781,6 +1781,18 @@ def flash_attention_qkv(
     return out[:, :S]
 
 
+def _require_one_width(q: jax.Array, k: jax.Array, v: jax.Array) -> None:
+    """The kernels take ONE last width for q, k and v. A narrower value
+    beside wider q and k compiled, ran and answered NaN in dq and dk on the
+    chip (PERF.md section 6, PR 50): refused before anything is traced."""
+    widths = q.shape[-1], k.shape[-1], v.shape[-1]
+    if len(set(widths)) != 1:
+        raise ValueError(
+            "q, k and v must share one last width: q has %d, k %d, v %d "
+            "(pad the narrower with zeros)" % widths
+        )
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1861,6 +1873,7 @@ def flash_attention(
         (B, S, H, head_dim) attention output, dtype of q.
     """
     B, S, H, D = q.shape
+    _require_one_width(q, k, v)
     if sm_scale is None:
         sm_scale = D ** -0.5
     if mesh is not None:
@@ -1924,8 +1937,11 @@ def flash_attention_rows(
     their cotangents (``models/olmoe.py``: the q/k pass), so that no
     transpose stands between it and the kernels. ``window`` and
     ``block_mask`` as ``flash_attention`` checks them; the tiles are the
-    same ``_tiles``."""
+    same ``_tiles``. The kernels take ONE width for q, k and v: a key or a
+    value of another last width than q's is refused here (padded with zeros
+    to q's it is exact: ``models/olmoe.py``, ``mla_mixer``)."""
     _, S, D = q.shape
+    _require_one_width(q, k, v)
     if window is not None:
         if not causal:
             raise ValueError(
