@@ -191,7 +191,8 @@ def _dense_attention_f32(
 
 def _check_flash(
     name: str, B: int, S: int, H: int, D: int, window: Optional[int] = None,
-    block_mask: Optional[Tuple[int, int]] = None, fused: bool = False,
+    block_mask: Optional[Tuple[int, int]] = None, d_v: Optional[int] = None,
+    fused: bool = False,
 ) -> None:
     """Flash forward + backward at (B, S, H, D), compiled, against the
     dense float32 reference on a seeded sample of 2 batch rows x 2 heads
@@ -199,16 +200,21 @@ def _check_flash(
     and gradients are exactly the full problem's). ``fused``: through
     ``flash_attention_qkv`` on the three laid side by side as a fused
     projection has them (the dense models' call), not the three arrays.
-    ``block_mask``: the block-diffusion mask in place of the causal one."""
+    ``block_mask``: the block-diffusion mask in place of the causal one.
+    ``d_v``: v, the output and dv at that width beside q and k at D (latent
+    attention), through ``flash_attention_rows``, the one entry that takes
+    two widths: dq and dk were NaN here when the kernels' blocks were all
+    cut at q's (PERF.md section 6, PR 50)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from torchft_tpu.ops import flash_attention, flash_attention_qkv
+    from torchft_tpu.ops import flash_attention, flash_attention_qkv, flash_attention_rows
 
     keys = jax.random.split(jax.random.PRNGKey(B * 1000003 + S * 131 + D), 4)
     q, k, v, cot = (
-        jax.random.normal(kk, (B, S, H, D), jnp.bfloat16) for kk in keys
+        jax.random.normal(kk, (B, S, H, width), jnp.bfloat16)
+        for kk, width in zip(keys, (D, D, d_v or D, d_v or D))
     )
 
     def flash_loss(q, k, v, cot):
@@ -217,6 +223,11 @@ def _check_flash(
                 [t.reshape(B, S, H * D) for t in (q, k, v)], axis=-1
             )
             out = flash_attention_qkv(qkv, H).reshape(B, S, H, D)
+        elif d_v:
+            rows = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, -1)  # noqa: E731
+            out = flash_attention_rows(
+                rows((q * jnp.float32(D ** -0.5)).astype(q.dtype)), rows(k), rows(v)
+            ).reshape(B, H, S, d_v).transpose(0, 2, 1, 3)
         else:
             out = flash_attention(
                 q, k, v, window=window, causal=block_mask is None, block_mask=block_mask
@@ -265,7 +276,8 @@ def _check_flash(
                 f"reference by {err:.4f} of max|ref| (tolerance {FLASH_TOL})"
             )
     _say("kernels", (
-        f"flash {name} B{B} S{S} H{H} D{D} window={window} block_mask={block_mask}: compiled in "
+        f"flash {name} B{B} S{S} H{H} D{D} window={window} block_mask={block_mask}"
+        f"{f' d_v={d_v}' if d_v else ''}: compiled in "
         f"{compile_s:.1f}s, max err / max|ref| {errs} <= {FLASH_TOL}"
     ))
 
@@ -485,7 +497,7 @@ def _observe_link() -> Dict[str, float]:
     return out
 
 
-# (name, B, S, H, D, window[, block_mask]) of every flash shape the kernels
+# (name, B, S, H, D, window[, block_mask[, d_v]]) of every flash shape the kernels
 # phase runs:
 # the big shape (the model slices the last token off: S 2047) and the
 # head_dim 128 shape of the d_model 2048 point; the benchmark's shapes -
@@ -519,6 +531,11 @@ FLASH_CASES = (
     ("ling_mla", 1, 8192, 8, 256, None),
     # dsv2lite-ft1's: the same padded width, all 16 heads of a layer
     ("dsv2_mla", 1, 8192, 16, 256, None),
+    # both as the cells run them from PR 54 on: q.k at 192 and v, the output
+    # and dv at 128, nothing padded (the 256-lane cases above stay: a caller
+    # with one width of 256 takes the same kernels)
+    ("ling_mla_192_128", 1, 8192, 8, 192, None, None, 128),
+    ("dsv2_mla_192_128", 1, 8192, 16, 192, None, None, 128),
     # a block mask that does not tile (B 6 straddles every tile's edge):
     # the general kernels' sweep, which no cell runs
     ("block_general", 2, 1920, 4, 128, None, (6, 960)),

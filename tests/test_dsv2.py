@@ -219,7 +219,8 @@ def _only(tree, like):
 @pytest.mark.parametrize("head_dim,rope_dim", [(32, 8), (128, 64)])
 def test_the_mla_layer_is_dense_attention_a_head(head_dim, rope_dim):
     """At the tiny head (q.k 40 and v 32: one tile of lanes holds both) and
-    at the PUBLISHED one (q.k 192 is padded to 256 lanes and v 128 with it):
+    at the PUBLISHED one (q.k 192, a tile and a half, beside v 128; each goes
+    to the kernels at its own width):
     ``mla_mixer`` unnormed and ungated against the dense masked softmax of
     the reference, output and every weight's gradient."""
     kind = dataclasses.replace(KIND, mixer=dataclasses.replace(KIND.mixer, rope_dim=rope_dim))
@@ -233,6 +234,35 @@ def test_the_mla_layer_is_dense_attention_a_head(head_dim, rope_dim):
             jax.vmap(lambda u: reference_dsv2._mla(cfg, kind, u, w, ROPE))(x) ** 2))(p)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     _assert_leaves_close(grads, want_grads, GRAD_RTOL_F32)
+
+
+def test_every_latent_layer_is_one_forward_and_one_backward_call_at_192_and_128(monkeypatch):
+    """The model at the published head (128 + 64 rotated, v 128), two latent
+    layers, its loss's gradient lowered for the chip: one ``flash_fwd`` and
+    one ``flash_bwd`` Mosaic call a layer (what the family's
+    ``lowered_mosaic_calls`` states: 2 a layer), their rows 192 and 128
+    lanes wide and none padded to 256."""
+    import re
+    import sys
+
+    monkeypatch.setattr(
+        sys.modules["torchft_tpu.ops.flash_attention"], "_pick_interpret", lambda _i: False
+    )
+    cfg = dataclasses.replace(BF16, head_dim=128, layer_kinds=tuple(
+        dataclasses.replace(k, mixer=dataclasses.replace(k.mixer, rope_dim=64)) for k in BF16.kinds
+    ))
+    params = jax.tree_util.tree_map(
+        lambda l: l.astype(jnp.bfloat16), dsv2.init_params(cfg, jax.random.PRNGKey(0)))
+    tokens = jnp.zeros((1, 257), jnp.int32)
+    text = jax.jit(jax.grad(lambda w: dsv2.loss_fn(cfg, w, tokens))).trace(params).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    layers = len(cfg.kinds)
+    assert len(calls) == 2 * layers
+    for name in ("flash_fwd", "flash_bwd"):
+        assert len(re.findall(r'kernel_name = \\?"%s\\?"' % name, text)) == layers
+    widths = {int(w) for line in calls for w in re.findall(r"tensor<\d+x\d+x(\d+)xbf16>", line)}
+    assert widths == {192, 128}
 
 
 def _turned_by_hand(x, freq, factor=1.0):

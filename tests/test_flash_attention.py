@@ -263,20 +263,118 @@ def test_window_requires_causal():
         flash_attention(q, k, v, causal=False, window=8)
 
 
-@pytest.mark.parametrize("entry", ["rows", "heads"])
-@pytest.mark.parametrize("narrow", ["k", "v"])
-def test_a_key_or_value_of_another_width_is_refused(entry, narrow):
-    """The kernels take one lane width for q, k and v. A value of 128 beside
-    q.k of 256 compiled and ran on the chip and answered NaN in dq and dk
-    (PERF.md section 6, PR 50): it is refused before anything is traced."""
+def _dense_rows(q, k, v):
+    """``dense_attention`` on rows (BH, S, width), q already scaled: v, and
+    so the output, as wide as it likes."""
+    return dense_attention(q[:, :, None], k[:, :, None], v[:, :, None], sm_scale=1.0)[:, :, 0]
+
+
+# (q.k width, v width, S, blocks, edge): a tiny head whose two widths share
+# one tile of lanes and the PUBLISHED latent head (192 = a whole tile and
+# half of one, beside 128); the whole sequence one resident block, two row
+# groups in one, four blocks of two row groups (the dynamic loop, dq the
+# revisited row), a staircase finer than the sub-tile, and lengths that pad.
+TWO_WIDTHS = [
+    (40, 32, 96, None, None), (192, 128, 96, None, None),
+    (40, 32, 128, (128, 32), None), (192, 128, 64, (64, 32), 16),
+    (40, 32, 128, (32, 16), 8), (192, 128, 128, (32, 32), None),
+    (40, 32, 125, (64, 32), 8), (192, 128, 99, (32, 16), None),
+    (24, 56, 96, (32, 32), 16),  # and a value WIDER than q and k
+]
+
+
+@pytest.mark.parametrize(
+    "d_qk,d_v,S,blocks,edge", TWO_WIDTHS, ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v)
+)
+def test_a_value_width_of_its_own_matches_dense(d_qk, d_v, S, blocks, edge):
+    """The causal kernels with k at q's width and v, the output, dO and dv
+    at another: values and the three gradients against the dense float32
+    softmax at the true widths, each finite and as wide as its argument."""
     from torchft_tpu.ops import flash_attention_rows
 
-    shape = (2, 64, 256) if entry == "rows" else (1, 64, 2, 256)
-    q = jnp.zeros(shape, jnp.float32)
+    kw = dict(block_diag=edge)
+    if blocks:
+        kw.update(block_q=blocks[0], block_k=blocks[1])
+    ks = jax.random.split(jax.random.PRNGKey(d_qk + S), 3)
+    q = jax.random.normal(ks[0], (2, S, d_qk)) * d_qk ** -0.5
+    k, v = jax.random.normal(ks[1], (2, S, d_qk)), jax.random.normal(ks[2], (2, S, d_v))
+    schedule = _module()._tiles(
+        S, d_qk, True, kw.get("block_q"), kw.get("block_k"), edge, value_dim=d_v
+    )
+    assert (schedule.kind, schedule.d_v) == ("nested", d_v)
+    with jax.default_matmul_precision("highest"):
+        got, want = flash_attention_rows(q, k, v, **kw), _dense_rows(q, k, v)
+        grads = jax.grad(
+            lambda *a: jnp.sum(jnp.cos(flash_attention_rows(*a, **kw))), argnums=(0, 1, 2)
+        )(q, k, v)
+        wants = jax.grad(lambda *a: jnp.sum(jnp.cos(_dense_rows(*a))), argnums=(0, 1, 2))(q, k, v)
+    assert got.shape == v.shape
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    for name, a, b, like in zip("qkv", grads, wants, (q, k, v)):
+        assert a.shape == like.shape and np.all(np.isfinite(a)), f"d{name}"
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
+
+
+def test_equal_widths_carry_no_value_width():
+    """v as wide as q is today's call: the schedule, the static argument of
+    the kernels' builders, is the value it was."""
+    fa = _module()
+    plain = fa._tiles(8192, 128, False, None, None, None)
+    assert plain == fa._tiles(8192, 128, False, None, None, None, value_dim=128)
+    assert plain.d_v is None and plain._replace(d_v=64) != plain
+
+
+# What no kernel is built for is refused before anything is traced. k always
+# has q's width. v may have its own on the nested causal schedule through
+# ``flash_attention_rows`` and nowhere else: under a window (banded or
+# general), a block mask (blocked or general), non-causal, blocks that do
+# not nest, the (B, S, H, D) form. (The fused projection is ONE array of
+# three equal thirds: below.)
+_ROWS, _HEADS = (2, 64, 256), (1, 64, 2, 256)
+REFUSED = [
+    ("rows-k", "rows", "k", {}, "q and k"),
+    ("heads-k", "heads", "k", {}, "q and k"),
+    ("heads-v", "heads", "v", {}, "takes one width"),
+    ("heads-v-window", "heads", "v", {"window": 16}, "takes one width"),
+    ("rows-k-window", "rows", "k", {"window": 32, "block_q": 32, "block_k": 16}, "q and k"),
+    ("rows-v-banded", "rows", "v", {"window": 32, "block_q": 32, "block_k": 16}, "is banded"),
+    ("rows-v-window-general", "rows", "v", {"window": 24, "block_q": 32, "block_k": 16}, "is general"),
+    ("rows-v-blocked", "rows", "v",
+     {"causal": False, "block_mask": (4, 32), "block_q": 16, "block_k": 16}, "is blocked"),
+    ("rows-v-block-mask-general", "rows", "v", {"causal": False, "block_mask": (4, 32)}, "is general"),
+    ("rows-v-noncausal", "rows", "v", {"causal": False}, "is general"),
+    ("rows-v-blocks-do-not-nest", "rows", "v", {"block_q": 16, "block_k": 32}, "is general"),
+]
+
+
+@pytest.mark.parametrize("case,entry,narrow,kw,match", REFUSED, ids=[c[0] for c in REFUSED])
+def test_a_key_or_value_of_another_width_is_refused(case, entry, narrow, kw, match, monkeypatch):
+    """A value of 128 beside q.k of 256 through kernels whose blocks were
+    all cut at q's width compiled and ran on the chip and answered NaN in dq
+    and dk (PERF.md section 6, PR 50). The nested causal kernels now take
+    v at its own width; every other combination raises, and no kernel's
+    builder is reached."""
+    from torchft_tpu.ops import flash_attention_rows
+
+    fa = _module()
+    for name in ("_flash_fwd_call", "_flash_bwd_call", "_flash"):
+        monkeypatch.setattr(fa, name, lambda *a, **k: pytest.fail("a kernel was built"))
+    q = jnp.zeros(_ROWS if entry == "rows" else _HEADS, jnp.float32)
     args = {"q": q, "k": q, "v": q, narrow: q[..., :128]}
     call = flash_attention_rows if entry == "rows" else flash_attention
-    with pytest.raises(ValueError, match="share one"):
-        jax.eval_shape(lambda q, k, v: call(q, k, v), args["q"], args["k"], args["v"])
+    with pytest.raises(ValueError, match=match):
+        jax.eval_shape(lambda q, k, v: call(q, k, v, **kw), args["q"], args["k"], args["v"])
+
+
+@pytest.mark.parametrize("width", [2 * (192 + 192 + 128), 2 * 3 * 64 + 2])
+def test_the_fused_entry_takes_three_equal_thirds(width):
+    """``flash_attention_qkv`` cuts ONE array into q, k and v of one width:
+    a projection whose last width is no ``3 x heads x head_dim`` - a latent
+    layer's q, k and v side by side - is refused, not cut somewhere."""
+    with pytest.raises(ValueError, match="three equal"):
+        jax.eval_shape(
+            lambda qkv: flash_attention_qkv(qkv, 2), jnp.zeros((1, 64, width), jnp.float32)
+        )
 
 
 def test_transformer_attn_window():
@@ -411,19 +509,22 @@ def test_auto_tiles_of_a_window(S, window, interpret, want):
         ("mellum2-ft1-sliding", 8192, 128, 1024, None, ("banded", 1024, 1024, (256, 128))),
         ("mellum2-ft1-full", 8192, 128, None, None, ("nested", 1024, 512, (256, 128))),
         ("sdar-ft1", 8192, 128, None, (4, 4096), ("blocked", 1024, 1024, (256, 128))),
-        # latent attention: q.k 192 and v 128, both padded to 256 lanes
-        ("ling3-ft1-mla", 8192, 256, None, None, ("nested", 512, 512, (256, 128))),
-        ("dsv2lite-ft1-mla", 8192, 256, None, None, ("nested", 512, 512, (256, 128))),
+        # latent attention: q.k at 192 and v at 128, each at its own width
+        ("ling3-ft1-mla", 8192, (192, 128), None, None, ("nested", 512, 512, (256, 128))),
+        ("dsv2lite-ft1-mla", 8192, (192, 128), None, None, ("nested", 512, 512, (256, 128))),
         ("a-block-over-a-tile", 1920, 128, None, (6, 960), ("general", 128, 128, None)),
         ("one-block-a-copy", 4096, 128, None, (2048, 2048), ("general", 512, 512, None)),
         ("a-padded-length", 400, 128, None, (8, 200), ("general", 128, 128, None)),
     ],
 )
 def test_the_schedule_each_cell_runs(cell, S, head_dim, window, block_mask, want):
+    d_qk, d_v = head_dim if isinstance(head_dim, tuple) else (head_dim, head_dim)
     schedule = _module()._tiles(
-        S, head_dim, False, None, None, None, block_mask is None, window, block_mask
+        S, d_qk, False, None, None, None, block_mask is None, window, block_mask, d_v
     )
     assert schedule[:3] + (schedule.edges,) == want, cell
+    # a value width of its own engages where a cell's v is not as wide as its q
+    assert schedule.d_v == (None if d_v == d_qk else d_v), cell
     assert schedule.s_pad == -(-S // 128) * 128 and schedule.kv_len == S
     # the fused entry (the dense cells') asks the same value
     assert schedule.one_resident_block == cell.startswith("gpt2")

@@ -438,9 +438,11 @@ def test_conv4_is_the_loop():
 
 
 def test_mla_is_dense_attention_at_192_and_128():
-    """The flash kernels on q and k of 192 and v of 128 padded to 256 lanes
-    against dense causal attention at the true widths, output and the three
-    gradients: the padding is exact."""
+    """The flash kernels on q and k of 192 and v of 128, each at its own
+    width, equal the call that pads all three to 256 lanes (what the mixer
+    ran until PR 54: exact, and 512 lanes of products a pair for 320), and
+    both equal dense causal attention at the true widths: output and the
+    three gradients."""
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (2, 96, 192)) * 192 ** -0.5
     k, v = jax.random.normal(ks[1], (2, 96, 192)), jax.random.normal(ks[2], (2, 96, 128))
@@ -454,21 +456,29 @@ def test_mla_is_dense_attention_at_192_and_128():
         scores = jnp.where(jnp.tril(jnp.ones((96, 96), bool)), q @ k.swapaxes(1, 2), -jnp.inf)
         return jax.nn.softmax(scores, axis=-1) @ v
 
+    def grads_of(attend):
+        return jax.grad(lambda *a: jnp.sum(jnp.cos(attend(*a))), argnums=(0, 1, 2))(q, k, v)
+
     with jax.default_matmul_precision("highest"):
-        (got, rest), want = padded(q, k, v), dense(q, k, v)
+        got, (was, rest), want = flash_attention_rows(q, k, v), padded(q, k, v), dense(q, k, v)
+        assert got.shape == v.shape and not np.any(np.asarray(rest))
+        # the same products in the same tiles: a zero lane added nothing
+        np.testing.assert_allclose(got, was, atol=1e-6)
         np.testing.assert_allclose(got, want, atol=2e-6)
-        assert not np.any(np.asarray(rest))
-        grads = jax.grad(lambda *a: jnp.sum(jnp.cos(padded(*a)[0])), argnums=(0, 1, 2))(q, k, v)
-        wants = jax.grad(lambda *a: jnp.sum(jnp.cos(dense(*a))), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(grads, wants):
-        np.testing.assert_allclose(a, b, atol=5e-6 * float(jnp.max(jnp.abs(b))))
+        grads = grads_of(flash_attention_rows)
+        were = grads_of(lambda *a: padded(*a)[0])
+        wants = grads_of(dense)
+    for a, b, c, like in zip(grads, were, wants, (q, k, v)):
+        assert a.shape == like.shape
+        np.testing.assert_allclose(a, b, atol=1e-6 * float(jnp.max(jnp.abs(c))))
+        np.testing.assert_allclose(a, c, atol=5e-6 * float(jnp.max(jnp.abs(c))))
 
 
 @pytest.mark.parametrize("head_dim,rope_dim", [(32, 8), (128, 64)])
 def test_the_mla_layer_is_the_references(head_dim, rope_dim):
     """At the tiny head (q.k 40 and v 32: one tile of lanes holds both) and
-    at the PUBLISHED one (q.k 192 is padded to 256 lanes and v 128 must be
-    too: the kernels take one width)."""
+    at the PUBLISHED one (q.k 192, a tile and a half, beside v 128): the
+    kernels take each at its own width, output and every weight's gradient."""
     kind = olmoe.AttentionKind("mla", mixer=olmoe.Mla(latent=16, rope_dim=rope_dim))
     cfg = dataclasses.replace(
         F32, head_dim=head_dim, n_layers=1, layer_kinds=(kind,), dense_ff=(None,))
@@ -480,6 +490,31 @@ def test_the_mla_layer_is_the_references(head_dim, rope_dim):
             jax.vmap(lambda u: reference_ling._mla(cfg, kind, u, w))(x) ** 2))(p)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     _assert_leaves_close(grads, want_grads, GRAD_RTOL_F32)
+
+
+def test_a_latent_layer_is_one_forward_and_one_backward_call_at_192_and_128(monkeypatch):
+    """``ling3-ft1``'s latent layer at the published head (128 + 64 rotated,
+    v 128), normed and gated, its gradient lowered for the chip: ONE
+    ``flash_fwd`` and ONE ``flash_bwd`` Mosaic call (what the family's
+    ``lowered_mosaic_calls`` states a layer: 2), their rows 192 and 128
+    lanes wide and none padded to 256."""
+    import sys
+
+    monkeypatch.setattr(
+        sys.modules["torchft_tpu.ops.flash_attention"], "_pick_interpret", lambda _i: False
+    )
+    kind = olmoe.AttentionKind("mla", mixer=olmoe.Mla(latent=16, rope_dim=64))
+    cfg = dataclasses.replace(
+        BF16, head_dim=128, n_layers=1, layer_kinds=(kind,), dense_ff=(None,))
+    p = ling.init_params(cfg, jax.random.PRNGKey(0))["blocks"][0]["attn"]
+    x = jnp.zeros((1, 256, cfg.d_model), jnp.bfloat16)
+    text = jax.jit(
+        jax.grad(lambda w: jnp.sum(olmoe.mla_mixer(cfg, w, x, kind).astype(jnp.float32)))
+    ).trace(p).lower(lowering_platforms=("tpu",)).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sorted(re.findall(r'kernel_name = \\?"(\w+)\\?"', text)) == ["flash_bwd", "flash_fwd"]
+    widths = {int(w) for line in calls for w in re.findall(r"tensor<\d+x\d+x(\d+)xbf16>", line)}
+    assert len(calls) == 2 and widths == {192, 128}
 
 
 # ---------------------------------------------------------------------------

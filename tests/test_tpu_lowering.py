@@ -37,7 +37,8 @@ _FLASH_SHAPES = [
 
 
 # Every (positions, window) at head sizes 64 and 128; the long causal
-# calls also at 256 lanes, ``ling3-ft1``'s latent attention as it is padded:
+# calls also at 256 lanes (``ling3-ft1``'s latent attention as it was padded
+# until PR 54; its own widths: ``test_two_widths_compile_for_the_chip``):
 # each lowers at the blocks ``_auto_tiles`` gives its length.
 @pytest.mark.parametrize(
     "head_dim,seq,window",
@@ -412,6 +413,22 @@ def test_forward_returns_the_product_widened(family):
         assert abs(float(loss_fn(cfg, compute, tokens)) - float(want)) <= 1e-6 * float(want)
 
 
+def _compiled_text(lowered) -> str:
+    """What the TPU compiler makes of ``lowered`` for the described chip.
+    Such a compile is written to the persistent cache and cannot be read
+    back without a chip: the cache is kept out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     """A described (not attached) v5e chip for the TPU compiler; made in
@@ -438,8 +455,6 @@ def test_the_compiled_gradient_stores_no_float32_logits(family, one_chip):
     import dataclasses
     import re
 
-    from jax.experimental.compilation_cache import compilation_cache
-
     cfg, init, loss_fn, _ = _family(family)
     cfg = dataclasses.replace(
         cfg, vocab_size=8192, **({"max_seq_len": 512} if family == "dense" else {})
@@ -453,16 +468,7 @@ def test_the_compiled_gradient_stores_no_float32_logits(family, one_chip):
     def loss_and_grads(masters, tokens):
         return jax.value_and_grad(lambda q: loss_fn(cfg, q, tokens))(_bf16_copy(masters))
 
-    # such a compile is written to the persistent cache and cannot be read
-    # back without a chip: keep it out
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(loss_and_grads).lower(params, tokens).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    text = _compiled_text(jax.jit(loss_and_grads).lower(params, tokens))
     entry = text[text.index("ENTRY"):]
     logits = re.findall(r"= \(?[^=]*?\b(f32|bf16)\[4,512,8192\]", entry)
     assert "bf16" in logits, "the logits are not an array of this program at all"
@@ -476,20 +482,31 @@ def test_the_block_schedule_compiles_for_the_chip(one_chip):
     layout Mosaic accepts and the compiler does not, is refused here and
     not on the chip (about 4 s). What this cannot see is the last per
     cent of scoped VMEM inside the whole step (PR 37: 180 KB)."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     q = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16, sharding=one_chip)
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(
-            jax.grad(_block_pair, argnums=(0, 1, 2))
-        ).lower(q, q, q).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    text = _compiled_text(jax.jit(jax.grad(_block_pair, argnums=(0, 1, 2))).lower(q, q, q))
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+@pytest.mark.parametrize("seq", [8192, 4000])
+def test_two_widths_compile_for_the_chip(seq, one_chip):
+    """The latent cells' flash pair as they run it - q and k 192 lanes wide
+    beside v at 128, 8,192 positions on sixteen resident blocks of 512 (and
+    a length that pads), two head-rows - through the TPU compiler for the
+    described chip: a 192-lane block is the array's whole last dimension,
+    which Mosaic's lane rule allows, in a tile and a half of VMEM that
+    ``_resident_params`` counts as two (about 2 s)."""
+    from torchft_tpu.ops import flash_attention_rows
+
+    q = jax.ShapeDtypeStruct((2, seq, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, seq, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention_rows(q, k, v, interpret=False).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, v))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    grads = jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, q, v)
+    assert [g.shape for g in grads] == [(2, seq, 192), (2, seq, 192), (2, seq, 128)]
 
 
 def test_the_ling_cells_step_compiles_for_the_chip_with_what_its_family_states(one_chip, monkeypatch):
@@ -498,12 +515,10 @@ def test_the_ling_cells_step_compiles_for_the_chip_with_what_its_family_states(o
     rotated, a latent of 512) and cut elsewhere - the six layers of the
     cell's period, 2 heads, 2 of 16 experts, 1,024 positions - through the
     TPU compiler for the described chip: the Mosaic calls are the ONE
-    latent-attention layer's flash pair, at 256 lanes, which the family
-    states (its count is no ``2 x layers``, so the benchmark's own case
+    latent-attention layer's flash pair, q.k at 192 lanes and v at 128,
+    which the family states (its count is no ``2 x layers``, so the benchmark's own case
     cannot be borrowed); the delta rule's scan, the triangular solve under
     it and the held share compile as plain XLA."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from benchmark import common
 
     monkeypatch.setattr(
@@ -528,14 +543,7 @@ def test_the_ling_cells_step_compiles_for_the_chip_with_what_its_family_states(o
     lowered = jax.jit(common.mixed_precision_grad(family, cfg)).lower(params, tokens)
     common.require_mosaic(lowered, 2, "ling3-ft1")
     assert 'kernel_name = "flash_fwd"' in lowered.as_text()
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = lowered.compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    text = _compiled_text(lowered)
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
@@ -545,11 +553,9 @@ def test_the_dsv2_cells_step_compiles_for_the_chip_with_what_its_family_states(o
     rotated, a latent of 512, YaRN's published numbers) and cut elsewhere -
     the cell's five layers, 2 heads, 2 of 16 experts, 1,024 positions -
     through the TPU compiler for the described chip: the Mosaic calls are
-    the flash pair of EVERY layer, ten, at 256 lanes, which the family
-    states; the held share and the balance loss a sequence compile as
+    the flash pair of EVERY layer, ten, q.k at 192 lanes and v at 128,
+    which the family states; the held share and the balance loss a sequence compile as
     plain XLA."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from benchmark import common
 
     monkeypatch.setattr(
@@ -575,12 +581,5 @@ def test_the_dsv2_cells_step_compiles_for_the_chip_with_what_its_family_states(o
     tokens = jax.ShapeDtypeStruct((1, sizes["seq"]), jnp.int32, sharding=one_chip)
     lowered = jax.jit(common.mixed_precision_grad(family, cfg)).lower(params, tokens)
     common.require_mosaic(lowered, 10, "dsv2lite-ft1")
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = lowered.compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    text = _compiled_text(lowered)
     assert text.count('custom_call_target="tpu_custom_call"') == 10

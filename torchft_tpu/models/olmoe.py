@@ -109,7 +109,7 @@ file (``models/mellum.py`` is one):
   the one rotated key for every head, RMSNorm of q and of k over a head's
   whole width, the rotary embedding of the last ``rope_dim`` in INTERLEAVED
   pairs, causal softmax at ``(head_dim + rope_dim) ** -0.5`` through the
-  flash kernels (the two widths padded to one, exactly), ``y = W_o (o
+  flash kernels (q.k and v each at its own width), ``y = W_o (o
   sigmoid(W_gate u))`` with one gate a head. ``n_heads`` is how many heads a
   rank HOLDS: a head's part of either mixer's output is its own. The norm of
   q and k, the gate and a factor on the softmax scale are data of ``Mla``,
@@ -781,11 +781,11 @@ def mla_mixer(
     cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array, kind: AttentionKind
 ) -> jax.Array:
     """Latent attention (module docstring) in its training form, nothing
-    absorbed, over the held heads. The flash kernels take one width for q,
-    k and v: q and k (``head_dim + rope_dim`` wide) and v (``head_dim``)
-    are padded with zeros to the next multiple of 128 lanes and the
-    output's pad is dropped, which is exact - a zero lane adds nothing to a
-    score and a zero column of v gives a zero column of the output."""
+    absorbed, over the held heads. q and k go to the flash kernels at
+    their own width (``head_dim + rope_dim``) and v at its own
+    (``head_dim``), as rows; the output comes back ``head_dim`` wide
+    (``flash_attention_rows``: a value width of its own on the causal
+    schedule). Nothing is padded."""
     B, S, _ = x.shape
     h, dh, f32 = cfg.n_heads, cfg.head_dim, jnp.float32
     latent, r = kind.mixer.latent, kind.mixer.rope_dim
@@ -810,16 +810,13 @@ def mla_mixer(
                 [t[..., :dh], _rope_pairs(t[..., dh:], cfg.rope_theta, kind.yarn)], axis=-1
             )
 
-        lanes = -(-width // 128) * 128  # ONE width for q, k and v: v's too
-
-        def rows(t: jax.Array) -> jax.Array:  # (B x h, S, lanes)
-            t = t.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(B * h, S, -1)
-            return jnp.pad(t, ((0, 0), (0, 0), (0, lanes - t.shape[-1])))
+        def rows(t: jax.Array) -> jax.Array:  # (B x h, S, its own width)
+            return t.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(B * h, S, -1)
 
         q = rows(normed_and_turned(q, "q_norm") * (width ** -0.5 * kind.mixer.softmax_factor))
         k = rows(normed_and_turned(k, "k_norm"))
         v = rows(up[..., dh:])
-    out = flash_attention_rows(q, k, v)[..., :dh]
+    out = flash_attention_rows(q, k, v)
     with jax.named_scope("out"):
         out = out.reshape(B, h, S, dh).transpose(0, 2, 1, 3)
         if kind.mixer.gated:

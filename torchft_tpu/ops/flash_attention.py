@@ -127,6 +127,18 @@ pairs (``block_scores_computed``: 1.124, 1.061), 5.03 + 8.21 ms a layer
 of 64 head-rows where the general kernels' walk took 10.02 + 15.07
 (PERF.md section 6, PR 47).
 
+A VALUE WIDTH OF ITS OWN. Latent attention (``models/olmoe.py``,
+``mla_mixer``) has q and k 192 wide (128 and 64 rotated) beside v at 128.
+On the ``nested`` schedule the two causal kernels read both widths from
+their refs: q, k, dq and dk and their blocks are ``d_qk`` wide, v, the
+output, dO, dv, the forward's accumulator and delta's sum ``d_v`` wide
+(``Schedule.d_v``, set where v's width is not q's; the tiles and edges are
+chosen by q's). Through ``flash_attention_rows`` alone; every other
+schedule and entry holds q, k and v to one width (``_require_one_width``).
+Until PR 54 the mixer padded all three to 256 lanes: exact, and 14 passes
+of a 128-wide operand through the MXU a pair of positions where (192, 128)
+takes 11 (QK^T, dq and dk still two: 192 is a tile and a half).
+
 WHICH schedule a call runs is decided once, by ``_tiles``, from shapes
 alone, and carried as a ``Schedule`` whose ``kind`` the calls that build
 the kernels, the fused entry and the counter read: the two-level one
@@ -306,8 +318,13 @@ def _resident_params(*blocks, wide_body: bool = False) -> dict:
     named - the ``banded`` and ``blocked`` kinds (PR 37: (2048, 1024) was
     refused by 180 KB in the step, not alone) and SEVERAL resident blocks
     of over 512 rows (PR 52: ``nested`` on 1024 rows, by 188 KB in a step)."""
+    def lanes(width: int) -> int:
+        # a row past one tile of 128 lanes holds whole tiles: 192 takes 256
+        return width if width <= 128 else _cdiv(width, 128) * 128
+
     buffers = 2 * sum(
-        math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in blocks
+        math.prod(shape[:-1]) * lanes(shape[-1]) * jnp.dtype(dtype).itemsize
+        for shape, dtype in blocks
     )
     if buffers <= 3 * _VMEM_DEFAULT // 4 and not wide_body:
         return {}
@@ -386,6 +403,9 @@ class Schedule(NamedTuple):
     kv_len: int
     window: Optional[int]
     block: Optional[Tuple[int, int]]
+    # v's last width where it is not q's and k's (latent attention: q.k 192
+    # beside v 128); the ``nested`` kernels alone take one
+    d_v: Optional[int] = None
 
     @property
     def one_resident_block(self) -> bool:
@@ -544,7 +564,9 @@ def _fwd_causal_kernel(
     block sees no clean key at all, every noised row sees itself there,
     so every row has a live key in its first piece as above."""
     n_sub = block_q // block_k
-    D = q_ref.shape[-1] // heads
+    # q and k share a width, v and the output theirs (the same, but for a
+    # ``nested`` call whose value is narrower: ``Schedule.d_v``)
+    D, d_v = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
     # a static origin when there is one block: every slice is static
     block = 0 if num_blocks == 1 else pl.program_id(1)
     q0 = block * block_q
@@ -652,7 +674,7 @@ def _fwd_causal_kernel(
         state = [((
             jnp.full((edge, 1), _NEG_LARGE, jnp.float32),
             jnp.zeros((edge, 1), jnp.float32),
-            jnp.zeros((edge, D), jnp.float32),
+            jnp.zeros((edge, d_v), jnp.float32),
         ),) * strips] * len(groups)
         for g, (copy, r) in enumerate(groups):
             if copy:  # the noised rows' own blocks, before any clean key
@@ -787,18 +809,22 @@ def _fwd_kernel(
 def _flash_fwd_call(q: jax.Array, k: jax.Array, v: jax.Array, *, schedule: Schedule):
     """q (pre-scaled)/k/v: (BH, S_pad, D) -> out (BH, S_pad, D),
     lse (BH, 1, S_pad) f32. Positions >= kv_len are zero padding, masked
-    out of every softmax."""
+    out of every softmax. Where ``schedule.d_v`` says so v and out are
+    ``d_v`` wide (their blocks and the kernel's accumulator with them) and
+    D is q's and k's width alone."""
     BH, S, D = q.shape
+    d_v = schedule.d_v or D
     kind, block_q, block_k = schedule.kind, schedule.block_q, schedule.block_k
     num_q, num_k = _cdiv(S, block_q), _cdiv(S, block_k)
     blocked = kind == "blocked"
     qspec = pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0))
+    ospec = pl.BlockSpec((1, block_q, d_v), lambda bh, qi: (bh, qi, 0))
     if blocked:
         # a grid step holds both copies' row groups of the same positions:
         # q and out as (BH, 2, L, D), which moves nothing
         num_q = S // 2 // block_q
         q = q.reshape(BH, 2, S // 2, D)
-        qspec = pl.BlockSpec((1, 2, block_q, D), lambda bh, qi: (bh, 0, qi, 0))
+        qspec = ospec = pl.BlockSpec((1, 2, block_q, D), lambda bh, qi: (bh, 0, qi, 0))
     if kind != "general":
         kernel = functools.partial(
             _fwd_causal_kernel, block_q=block_q, block_k=block_k,
@@ -811,25 +837,27 @@ def _flash_fwd_call(q: jax.Array, k: jax.Array, v: jax.Array, *, schedule: Sched
             block_q=block_q, block_k=block_k, num_k=num_k, kv_len=schedule.kv_len,
             window=schedule.window, block=schedule.block,
         )
-    row = pl.BlockSpec((1, S, D), lambda bh, qi: (bh, 0, 0))
+    def row(width: int):
+        return pl.BlockSpec((1, S, width), lambda bh, qi: (bh, 0, 0))
+
     held = 2 * block_q if blocked else block_q  # query rows a step holds
     out, lse = pl.pallas_call(
         kernel,
         grid=(BH, num_q),
-        in_specs=[qspec, row, row],
+        in_specs=[qspec, row(D), row(d_v)],
         out_specs=[
-            qspec,
+            ospec,
             pl.BlockSpec((1, 1, S), lambda bh, qi: (bh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(q.shape[:-1] + (d_v,), q.dtype),
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
         ],
         interpret=schedule.interpret,
         name="flash_fwd",  # the kernel's name in a device trace
         **_resident_params(
-            ((held, D), q.dtype), ((S, D), k.dtype), ((S, D), v.dtype),
-            ((held, D), q.dtype), ((S,), jnp.float32),
+            ((held, D), q.dtype), ((S, D), k.dtype), ((S, d_v), v.dtype),
+            ((held, d_v), q.dtype), ((S,), jnp.float32),
             wide_body=kind in ("banded", "blocked") or (num_q > 1 and block_q > 512),
         ),
     )(q, k, v)
@@ -952,7 +980,8 @@ def _bwd_causal_kernel(
     n_sub = block_q // block_k
     one_block = num_blocks == 1
     assert sm_scale is None or one_block
-    D = q_ref.shape[-1] // heads
+    # k, dq and dk at q's width, dO and dv at v's (``_fwd_causal_kernel``)
+    D, d_v = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
     block = 0 if one_block else pl.program_id(1)
     k0 = block * block_q
     triangles, own = _block_masks(edge, block_mask, False)
@@ -1061,7 +1090,8 @@ def _bwd_causal_kernel(
             return _copy_rows(block_mask, copy, pos)
 
         zeros = jnp.zeros((block_k, D), jnp.float32)
-        dks, dvs = [zeros] * len(k_rows), [zeros] * len(k_rows)
+        zeros_v = zeros if d_v == D else jnp.zeros((block_k, d_v), jnp.float32)
+        dks, dvs = [zeros] * len(k_rows), [zeros_v] * len(k_rows)
         if shared_dq:
             # dq accumulates into a REVISITED full-row f32 output block: the
             # TPU grid is sequential, so every ki step of one bh row sees the
@@ -1286,6 +1316,7 @@ def _bwd_kernel(
 @_traced_once("schedule")
 def _flash_bwd_call(q, k, v, o, lse, do, *, schedule: Schedule):
     BH, S, D = q.shape
+    d_v = schedule.d_v or D  # v's, o's, dO's and dv's; D is q's, k's, dq's and dk's
     kind, block_q, block_k = schedule.kind, schedule.block_q, schedule.block_k
     num_q = S // block_q
     blocked = kind == "blocked"
@@ -1295,7 +1326,9 @@ def _flash_bwd_call(q, k, v, o, lse, do, *, schedule: Schedule):
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     )[:, None, :]  # (BH, 1, S) — same full-row layout as lse
 
-    row3 = pl.BlockSpec((1, S, D), lambda bh, i: (bh, 0, 0))
+    def row3(width: int):
+        return pl.BlockSpec((1, S, width), lambda bh, i: (bh, 0, 0))
+
     row2 = pl.BlockSpec((1, 1, S), lambda bh, i: (bh, 0, 0))
     if kind != "general":
         # The causal schedule applies NO padding mask: padded k/v rows
@@ -1318,12 +1351,15 @@ def _flash_bwd_call(q, k, v, o, lse, do, *, schedule: Schedule):
             block_q=block_q, block_k=block_k, num_q=num_q, kv_len=schedule.kv_len,
             window=schedule.window, block=schedule.block,
         )
-    kblk3 = pl.BlockSpec((1, key_rows, D), lambda bh, i: (bh, i, 0))
+    def kblk3(width: int):
+        if blocked:
+            return pl.BlockSpec((1, 2, key_rows, width), lambda bh, i: (bh, 0, i, 0))
+        return pl.BlockSpec((1, key_rows, width), lambda bh, i: (bh, i, 0))
+
     if blocked:
         # k, v, dk and dv as (BH, 2, L, D), which moves nothing: the clean
         # and the noised key block of the same positions a grid step
         k, v = k.reshape(BH, 2, S // 2, D), v.reshape(BH, 2, S // 2, D)
-        kblk3 = pl.BlockSpec((1, 2, key_rows, D), lambda bh, i: (bh, 0, i, 0))
     held = 2 * key_rows if blocked else key_rows  # key rows a step holds
     # over several key blocks dq is the revisited f32 accumulator (cast to
     # q.dtype below); one block writes it finished
@@ -1332,8 +1368,8 @@ def _flash_bwd_call(q, k, v, o, lse, do, *, schedule: Schedule):
     dq, dk, dv = pl.pallas_call(
         kernel,
         grid=(BH, S // held),
-        in_specs=[row3, kblk3, kblk3, row3, row2, row2],
-        out_specs=[row3, kblk3, kblk3],
+        in_specs=[row3(D), kblk3(D), kblk3(d_v), row3(d_v), row2, row2],
+        out_specs=[row3(D), kblk3(D), kblk3(d_v)],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, D), dq_dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -1342,8 +1378,9 @@ def _flash_bwd_call(q, k, v, o, lse, do, *, schedule: Schedule):
         interpret=schedule.interpret,
         name="flash_bwd",
         **_resident_params(
-            ((S, D), q.dtype), ((S, D), do.dtype), ((S, D), dq_dtype),
-            ((S,), jnp.float32), ((S,), jnp.float32), *[((held, D), k.dtype)] * 4,
+            ((S, D), q.dtype), ((S, d_v), do.dtype), ((S, D), dq_dtype),
+            ((S,), jnp.float32), ((S,), jnp.float32),
+            *[((held, D), k.dtype), ((held, d_v), v.dtype)] * 2,
             wide_body=kind in ("banded", "blocked") or (num_q > 1 and block_q > 512),
         ),
     )(q, k, v, do, lse, delta)
@@ -1500,7 +1537,12 @@ def _auto_tiles(
     (blocks a kernel, as ``edges`` are, would buy the 1.0); (2048, 1024),
     four times the body (9.5 s to compile a pair for 2.6), 1.3 s more of
     that cell's warm set-up; 256 lanes, 0.44 ms of ``ling3-ft1``'s step for
-    9.7 s of its cold one. The general path keeps (512, 512) or (128, 128).
+    9.7 s of its cold one. Since PR 54 the latent cells' q.k is 192 wide
+    beside v at 128 and still over 128, so on (512, 512): 16 head-rows
+    alone 3.50 | 7.20 ms where (1024, 512) reads 3.25 | 6.77 and compiles
+    a pair in 5.6 s for 1.8 (my chip run, PR 54): 3.4 ms of ``dsv2lite-ft1``'s
+    step, left to a PR that weighs it against that cell's set-up. The
+    general path keeps (512, 512) or (128, 128).
 
     A window shorter than the sequence: the LARGEST sub-tile of 1024,
     512, 256 or 128 that divides it, one a resident block, so that the
@@ -1657,6 +1699,7 @@ def _tiles(
     block_diag: Optional[int], causal: bool = True,
     window: Optional[int] = None,
     block_mask: Optional[Tuple[int, int]] = None,
+    value_dim: Optional[int] = None,
 ) -> Schedule:
     """The ``Schedule`` of a call, and the one place that decides it: the
     blocks it names, else ``_auto_tiles``, clamped to the sequence and
@@ -1669,7 +1712,10 @@ def _tiles(
     The edges are (forward, backward) where the call runs the two-level
     schedule (``_auto_edges``, or ``block_diag`` for both), else None.
     Under ``block_mask`` an edge holds whole diffusion blocks: one of
-    ``_auto_edges``' that does not is the whole sub-tile, which does."""
+    ``_auto_edges``' that does not is the whole sub-tile, which does.
+    ``head_dim`` is q's and k's last width; ``value_dim``, v's, is carried
+    (``d_v``) where it is another, and chooses nothing: the tiles and the
+    edges are those of ``head_dim``."""
     auto_q, auto_k = _auto_tiles(
         seq, head_dim, interpret, nested=causal and window is None,
         window=window if causal else None, block_mask=block_mask,
@@ -1686,6 +1732,7 @@ def _tiles(
     decided = functools.partial(
         Schedule, block_q=block_q, block_k=block_k, s_pad=s_pad, causal=causal,
         interpret=interpret, kv_len=seq, window=window, block=block_mask,
+        d_v=None if value_dim in (None, head_dim) else value_dim,
     )
     if _nested(causal, window, block_q, block_k):
         kind = "nested"
@@ -1760,6 +1807,12 @@ def flash_attention_qkv(
     not nest."""
     B, S, width = qkv.shape
     head_dim = width // (3 * n_heads)
+    if width != 3 * n_heads * head_dim:
+        raise ValueError(
+            f"a fused projection is three equal thirds, q, k and v of {n_heads} heads"
+            f" of one width: {width} columns are not (a value width of its own goes"
+            " to flash_attention_rows)"
+        )
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
     interp = _pick_interpret(interpret)
@@ -1781,15 +1834,27 @@ def flash_attention_qkv(
     return out[:, :S]
 
 
-def _require_one_width(q: jax.Array, k: jax.Array, v: jax.Array) -> None:
-    """The kernels take ONE last width for q, k and v. A narrower value
-    beside wider q and k compiled, ran and answered NaN in dq and dk on the
-    chip (PERF.md section 6, PR 50): refused before anything is traced."""
-    widths = q.shape[-1], k.shape[-1], v.shape[-1]
-    if len(set(widths)) != 1:
+def _require_one_width(
+    q: jax.Array, k: jax.Array, v: jax.Array, schedule: Optional[Schedule] = None
+) -> None:
+    """Refuses, before anything is traced, the widths no kernel is built
+    for. k always has q's last width. v has it too, but for a call on the
+    ``nested`` ``schedule`` (causal, no window, no block mask, blocks that
+    nest), whose two kernels take v, the output, dO and dv at a width of
+    their own (``Schedule.d_v``). Every other kernel's blocks are cut at
+    q's width: a narrower value through them compiled, ran and answered NaN
+    in dq and dk on the chip (PERF.md section 6, PR 50), so a caller
+    without a schedule - ``flash_attention``'s (B, S, H, D) form and,
+    one array, ``flash_attention_qkv`` - is held to one width for all."""
+    d, d_k, d_v = q.shape[-1], k.shape[-1], v.shape[-1]
+    if d_k != d:
+        raise ValueError(f"q and k must share one last width: q has {d}, k {d_k}")
+    if d_v != d and (schedule is None or schedule.kind != "nested"):
         raise ValueError(
-            "q, k and v must share one last width: q has %d, k %d, v %d "
-            "(pad the narrower with zeros)" % widths
+            f"v's last width ({d_v}) may differ from q's ({d}) in flash_attention_rows"
+            " on the nested causal schedule alone: this call "
+            + ("takes one width" if schedule is None else f"is {schedule.kind}")
+            + " (pad the narrower with zeros)"
         )
 
 
@@ -1937,11 +2002,13 @@ def flash_attention_rows(
     their cotangents (``models/olmoe.py``: the q/k pass), so that no
     transpose stands between it and the kernels. ``window`` and
     ``block_mask`` as ``flash_attention`` checks them; the tiles are the
-    same ``_tiles``. The kernels take ONE width for q, k and v: a key or a
-    value of another last width than q's is refused here (padded with zeros
-    to q's it is exact: ``models/olmoe.py``, ``mla_mixer``)."""
+    same ``_tiles``, of q's width. k has q's last width. v may have ANOTHER
+    (latent attention: q.k 192 beside v 128; ``models/olmoe.py``,
+    ``mla_mixer``) where the call is causal with no window and its blocks
+    nest: the output and dv are then as wide as v, and no lane of zeros is
+    multiplied. On any other schedule that is refused
+    (``_require_one_width``)."""
     _, S, D = q.shape
-    _require_one_width(q, k, v)
     if window is not None:
         if not causal:
             raise ValueError(
@@ -1959,8 +2026,9 @@ def flash_attention_rows(
             )
     schedule = _tiles(
         S, D, _pick_interpret(interpret), block_q, block_k, block_diag, bool(causal),
-        None if window is None else int(window), block_mask,
+        None if window is None else int(window), block_mask, v.shape[-1],
     )
+    _require_one_width(q, k, v, schedule)
     if schedule.s_pad != S:
         q, k, v = (
             jnp.pad(x, ((0, 0), (0, schedule.s_pad - S), (0, 0))) for x in (q, k, v)
