@@ -81,29 +81,22 @@ def _say(phase: str, msg: str) -> None:
 # ---------------------------------------------------------------------------
 
 
-class _CacheCounter:
-    """Counts persistent-compile-cache hits and misses of this process
-    (jax.monitoring events) — a cache that works shows up as hits in a
-    restarted group and in a second run of the same checkout."""
+def _cache_counts() -> Dict[str, int]:
+    """Persistent-compile-cache hits and misses of this process, from
+    the program's own count of JAX's events (torchft_tpu/startup.py,
+    heard from ``apply_compilation_cache_env`` on) — a cache that works
+    shows up as hits in a restarted group and in a second run of the
+    same checkout."""
+    from torchft_tpu import startup
 
-    def __init__(self) -> None:
-        import jax
-
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_: Any) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def as_dict(self) -> Dict[str, int]:
-        return {"cache_hits": self.hits, "cache_misses": self.misses}
+    counters = startup.record().snapshot()["counters"]
+    return {
+        "cache_hits": counters.get("compile_cache_hits", 0),
+        "cache_misses": counters.get("compile_cache_misses", 0),
+    }
 
 
-def _child_setup(expect_chips: Optional[int] = None) -> Tuple[Any, _CacheCounter]:
+def _child_setup(expect_chips: Optional[int] = None) -> Any:
     """First lines of every child that needs the chip: the one compile
     cache, then the backend — which must be the TPU."""
     from torchft_tpu.platform import apply_compilation_cache_env
@@ -111,7 +104,6 @@ def _child_setup(expect_chips: Optional[int] = None) -> Tuple[Any, _CacheCounter
     apply_compilation_cache_env()
     import jax
 
-    counter = _CacheCounter()
     devices = jax.devices()
     if devices[0].platform != "tpu":
         raise RuntimeError(
@@ -122,7 +114,7 @@ def _child_setup(expect_chips: Optional[int] = None) -> Tuple[Any, _CacheCounter
             f"expected {expect_chips} chip(s), this process sees "
             f"{len(devices)}: {devices}"
         )
-    return devices, counter
+    return devices
 
 
 def _assert_mosaic(lowered: Any, want: int, what: str) -> None:
@@ -546,7 +538,7 @@ FLASH_CASES = (
 
 
 def child_kernels() -> None:
-    devices, counter = _child_setup()
+    devices = _child_setup()
     _say("kernels", f"on {devices[0].device_kind}")
     for case in FLASH_CASES:
         _check_flash(*case)
@@ -564,7 +556,7 @@ def child_kernels() -> None:
     _check_wire_kernels("big_leaf", (1024, 4096), seed=1)
     _check_wire_kernels("odd", (70001,), seed=2)
     _say("kernels", f"link observation (not a metric): {_observe_link()}")
-    _say("kernels", f"compile cache: {counter.as_dict()}")
+    _say("kernels", f"compile cache: {_cache_counts()}")
 
 
 # ---------------------------------------------------------------------------
@@ -789,7 +781,7 @@ def run_group(
 
 
 def child_worker() -> None:
-    devices, counter = _child_setup(expect_chips=1)
+    devices = _child_setup(expect_chips=1)
     from torchft_tpu.models import big_config
 
     group = int(os.environ["REPLICA_GROUP_ID"])
@@ -809,7 +801,7 @@ def child_worker() -> None:
     )
     for r in records:
         _say("fleet", json.dumps(r))
-    _say("fleet", f"group {group}: compile cache {counter.as_dict()}")
+    _say("fleet", f"group {group}: compile cache {_cache_counts()}")
 
 
 # ---------------------------------------------------------------------------
@@ -874,11 +866,11 @@ def run_mesh(cfg: Any, batch_shape: Tuple[int, int]) -> str:
 
 
 def child_mesh() -> None:
-    _, counter = _child_setup()
+    _child_setup()
     from torchft_tpu.models import big_config
 
     _say("mesh", run_mesh(big_config(), (BATCH, SEQ)))
-    _say("mesh", f"compile cache: {counter.as_dict()}")
+    _say("mesh", f"compile cache: {_cache_counts()}")
 
 
 # ---------------------------------------------------------------------------

@@ -194,3 +194,16 @@ class TestChipSmoke:
         # host pack on the CPU backend: full-width bytes cross "d2h"
         assert plan["device_pack"] is False
         assert plan["d2h_bytes"] == plan["payload_bytes"]
+
+
+def test_a_child_reports_the_programs_own_cache_counts(monkeypatch):
+    # the children print hits and misses from the program's start-up
+    # record (torchft_tpu/startup.py), not from a listener of their own
+    from torchft_tpu import startup
+
+    fresh = startup.StartupRecord(started=0.0, imported=0.0)
+    monkeypatch.setattr(startup, "_record", fresh)
+    assert chip_smoke._cache_counts() == {"cache_hits": 0, "cache_misses": 0}
+    for hit in (True, True, False):
+        fresh.cache(hit)
+    assert chip_smoke._cache_counts() == {"cache_hits": 2, "cache_misses": 1}

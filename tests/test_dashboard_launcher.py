@@ -144,6 +144,134 @@ class TestLauncher:
         assert (tmp_path / "died").exists()
         assert (tmp_path / "promoted").exists()
 
+    def test_a_spawned_child_is_stamped_and_a_restart_says_so(self, tmp_path, caplog):
+        # The start-up record of a launcher's child counts from the
+        # launcher's Popen (startup.SPAWN_STAMP, set by the launcher and
+        # by nobody else); a restarted life also knows which restart it is
+        # and how long after the death it was spawned, and the parent
+        # logs one line a restart with it. The parent holds no JAX.
+        import json
+        import os
+
+        from torchft_tpu import startup
+
+        script = tmp_path / "stamped.py"
+        script.write_text(
+            "import json, os, sys, time\n"
+            f"sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})\n"
+            "time.sleep(0.2)  # the trainer's own imports before the package's\n"
+            "from torchft_tpu import startup\n"
+            "from torchft_tpu.metrics import Metrics\n"
+            "record = startup.record()\n"
+            "record.close(Metrics())\n"
+            "d = os.path.dirname(os.path.abspath(__file__))\n"
+            "life = len([n for n in os.listdir(d) if n.startswith('life_')])\n"
+            "with open(os.path.join(d, f'life_{life}.json'), 'w') as f:\n"
+            "    json.dump({'stamp': os.environ[startup.SPAWN_STAMP], 'ppid': os.getppid(),\n"
+            "               'jax': 'jax' in sys.modules, **record.snapshot()}, f)\n"
+            "sys.exit(1 if life == 0 else 0)\n"
+        )
+        with caplog.at_level("INFO", logger="torchft_tpu.launcher"):
+            rc = launch(
+                [sys.executable, str(script)], num_replica_groups=1,
+                lighthouse_addr="http://unused:1", max_restarts=2,
+            )
+        assert rc == 0
+        first, second = (
+            json.loads((tmp_path / f"life_{i}.json").read_text()) for i in (0, 1)
+        )
+        for life in (first, second):
+            assert life["ppid"] == os.getpid() == int(life["stamp"].split()[0])
+            assert not life["jax"]
+            # the interpreter's start and the sleep, from the Popen on
+            assert 0.2 <= life["timers_s"]["spawn_to_import"]["total_s"] < 30.0
+        assert len(first["stamp"].split()) == 3 and first["stamp"].split()[2] == "0"
+        assert "death_to_spawn" not in first["timers_s"] and "restart" not in first["counters"]
+        assert second["counters"]["restart"] == 1 and len(second["stamp"].split()) == 4
+        assert 0.0 <= second["timers_s"]["death_to_spawn"]["total_s"] < 5.0
+        (line,) = [r.getMessage() for r in caplog.records if "after its death" in r.getMessage()]
+        assert line.startswith("replica_group_0: restart 1 spawned ")
+        assert startup.SPAWN_STAMP not in os.environ  # the children's, not ours
+
+    def test_a_hand_started_process_reads_its_start_from_the_os(self, monkeypatch):
+        import os
+        import time
+
+        from torchft_tpu import startup
+
+        # no stamp, a stamp set for another process (a grandchild inherits
+        # the environment, not the start), a stamp that is no stamp
+        for stamp in (None, f"{os.getppid() + 1} {time.time()!r} 0", "x y", ""):
+            if stamp is None:
+                monkeypatch.delenv(startup.SPAWN_STAMP, raising=False)
+            else:
+                monkeypatch.setenv(startup.SPAWN_STAMP, stamp)
+            assert startup._read_stamp() is None
+            record = startup.StartupRecord.of_this_process()
+            age = record._imported - record._started
+            # this test process has lived a while, and the OS knows it
+            assert 0.0 < age < 24 * 3600.0
+            assert age == pytest.approx(
+                startup._os_age_s() - (time.monotonic() - startup._IMPORTED), abs=0.05
+            )
+        # our parent's stamp: spawned 3 s before the package's import
+        then = time.time() - (time.monotonic() - startup._IMPORTED) - 3.0
+        monkeypatch.setenv(
+            startup.SPAWN_STAMP, f"{os.getppid()} {then!r} 2 {then - 0.5!r}"
+        )
+        spawned, restart, died = startup._read_stamp()
+        assert restart == 2 and spawned - died == pytest.approx(0.5, abs=1e-3)
+        record = startup.StartupRecord.of_this_process()
+        assert record._imported - record._started == pytest.approx(3.0, abs=0.05)
+        snap = record.snapshot()
+        assert snap["counters"] == {"restart": 2}
+        assert snap["timers_s"]["death_to_spawn"]["total_s"] == pytest.approx(0.5, abs=1e-3)
+
+    def test_a_promotion_starts_the_record_again(self, tmp_path, monkeypatch):
+        # standby_gate() returns at the promotion: ``ready`` is then what
+        # the promotion cost, not the standby's idle life; the launcher
+        # wrote which restart this is and when it saw the death.
+        import time
+
+        from torchft_tpu import startup
+        from torchft_tpu.metrics import Metrics
+        from torchft_tpu.platform import standby_gate
+
+        now = time.monotonic()
+        idle = startup.StartupRecord(started=now - 500.0, imported=now - 499.0)
+        idle.compiled("jit(step)", 7.0)  # the standby's warm-up
+        with idle.manager_init():  # and a Manager built before the gate
+            pass
+        monkeypatch.setattr(startup, "_record", idle)
+        gate = tmp_path / "gate"
+        gate.write_text(f"3 {time.time() - 0.25!r}")
+        monkeypatch.setenv("TORCHFT_STANDBY_FILE", str(gate))
+        standby_gate()
+        assert (tmp_path / "gate.warm").exists()
+        line = idle.close(Metrics())
+        snap = idle.snapshot()
+        seconds = {k: v["total_s"] for k, v in snap["timers_s"].items()}
+        assert seconds["ready"] < 5.0 and seconds["spawn_to_import"] == 0.0
+        # the Manager of the idle life is not this life's: no negative interval
+        assert seconds["import_to_manager"] == seconds["manager_init"] == 0.0
+        assert seconds["death_to_spawn"] == pytest.approx(0.25, abs=0.05)
+        assert snap["counters"] == {
+            "restart": 3, "compiles": 1, "startup_cache_hits": 0, "startup_cache_misses": 0,
+        }
+        # the warm-up's compile is the life's, not this start-up's
+        assert seconds["startup_compile"] == 0.0 and seconds["compile"] == 7.0
+        assert idle._compiled == {"jit(step)": 1} and "compile 0.0 s" in line
+        # the line says which life this is: what `restart` is read for
+        assert line.startswith("ready in ") and " s (restart 3, 0.2" in line
+        assert " s after the death was seen): spawn_to_import 0.0, " in line
+        # activated by hand, with an empty file: the record starts again all the same
+        gate.write_text("")
+        again = startup.StartupRecord(started=now - 500.0, imported=now - 499.0)
+        monkeypatch.setattr(startup, "_record", again)
+        standby_gate()
+        assert again._started >= now and again.snapshot()["counters"] == {}
+        assert again.close(Metrics()).startswith("ready in 0.0 s: spawn_to_import")
+
     def test_supervised_standby_warm_marker(self, tmp_path):
         # standby_warm keys off the <standby_file>.warm marker that
         # standby_gate touches on arrival — the signal the warm-deadline

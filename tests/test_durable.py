@@ -748,3 +748,92 @@ def test_ctor_registers_durable_restore(tmp_path):
     plain = _FakeManager(0, 1, "rep1")
     cp2 = DurableCheckpointer(str(tmp_path), plain, _RepState(0))
     cp2.close()
+
+
+# ---------------------------------------------------------------------------
+# the phases on the one primitive: a span in a capture, the stats key of the
+# same seconds, and the Manager's timer where there is a Manager
+
+
+def test_every_phase_is_a_span_and_the_stats_key_of_the_same_seconds(rig, tmp_path):
+    from test_profiling import _captured, _one
+
+    state = FTTrainState({"w": jnp.ones((64,), jnp.float32)}, optax.sgd(1.0))
+    manager = rig(state)
+    ckpt = DurableCheckpointer(str(tmp_path / "d"), manager, state, every=1, mode="async")
+    restored = FTTrainState({"w": jnp.zeros((64,), jnp.float32)}, optax.sgd(1.0))
+    made = []
+
+    def body():
+        manager.start_quorum()
+        assert manager.should_commit()
+        assert ckpt.maybe_save() is not None
+        assert ckpt.flush(timeout=30)
+        manager.shutdown()  # the fresh process's manager is the only one alive
+        manager2 = rig(restored)
+        made.append((manager2, DurableCheckpointer(str(tmp_path / "d"), manager2, restored)))
+        assert made[0][1].restore_latest(device_put=True) == 1
+
+    try:
+        events = _captured(tmp_path / "trace", body)
+    finally:
+        ckpt.close()
+        for manager2, ckpt2 in made:
+            ckpt2.close()
+            manager2.shutdown()
+    (row,) = ckpt.snapshots
+    stats = ckpt2.last_restore_stats
+    assert row["committed"]
+    save = {"capture": row["stall_s"], "write": row["write_s"], "commit": row["commit_s"]}
+    for phase, seconds in save.items():
+        event = _one(events, f"torchft::durable_save/{phase}")
+        assert event[4] == {"step": 1}, phase
+        assert seconds == pytest.approx((event[3] - event[2]) / 1e9, abs=2e-3), phase
+    # the writer's two phases on its own thread, after the trainer's stall
+    capture, write = (_one(events, f"torchft::durable_save/{p}") for p in ("capture", "write"))
+    assert write[0] != capture[0] and write[2] >= capture[3]
+    # the same seconds in the Manager's timers
+    timers = manager.metrics().snapshot()["timers_s"]
+    for phase, seconds in save.items():
+        assert timers[f"durable_{phase}"]["n"] == 1
+        assert timers[f"durable_{phase}"]["total_s"] == pytest.approx(seconds, abs=2e-6)
+    load = {
+        "fetch": stats["shard_fetch_s"], "reshard": stats["reshard_s"],
+        "h2d": stats["h2d_s"],
+    }
+    for phase, seconds in load.items():
+        event = _one(events, f"torchft::durable_restore/{phase}")
+        assert event[4] == {"step": 1}, phase
+        assert seconds == pytest.approx((event[3] - event[2]) / 1e9, abs=2e-3), phase
+    # two replays: the first manager's cold-start consult, on its quorum
+    # thread, found an empty manifest; the restore's own is the later one
+    consult, replay = sorted(
+        (e for e in events if e[1] == "torchft::durable_restore/replay"), key=lambda e: e[2]
+    )
+    assert consult[0] != replay[0] and replay[4] == {}
+    # manifest_read_s keeps its meaning: the replay and the meta blob's read
+    manifest = _one(events, "torchft::durable_restore/manifest")
+    assert manifest[4] == {"step": 1}
+    assert stats["manifest_read_s"] == pytest.approx(
+        (replay[3] - replay[2] + manifest[3] - manifest[2]) / 1e9, abs=4e-3
+    )
+    assert "replay_s" not in stats  # no key the callers did not have
+
+
+def test_sync_mode_stalls_for_the_whole_pipeline(tmp_path):
+    # one capture span over the capture, the write and the commit; a
+    # manager with no Metrics (a stub) files no timer and needs none
+    store, mgrs, states, cps = _fleet(tmp_path, 1, mode="sync", every=1)
+    (saved,) = _fleet_step(mgrs, cps, 1)
+    assert saved is not None
+    (row,) = cps[0].snapshots
+    assert row["committed"] and cps[0]._metrics is None
+    assert row["stall_s"] >= row["write_s"] + row["commit_s"] > 0.0
+    cps[0].close()
+
+
+def test_the_file_keeps_no_clock_of_its_own():
+    import torchft_tpu.durable as durable
+
+    with open(durable.__file__) as f:
+        assert "perf_counter" not in f.read()

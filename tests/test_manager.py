@@ -7,6 +7,8 @@ healing, error latching, FIXED_WITH_SPARES numerics and commit votes are
 tested without any network or lighthouse.
 """
 
+import json
+import time
 from concurrent.futures import Future
 from datetime import timedelta
 from unittest.mock import MagicMock, patch
@@ -969,3 +971,143 @@ class TestDurableArbitration:
         m.wait_quorum()
         assert calls == [1]
         m.shutdown()
+
+
+class TestStartupRecord:
+    """The process's way to its first commit (startup.py): closed at the
+    first vote that passes, carried by the snapshot as ``process``."""
+
+    INTERVALS = (
+        "spawn_to_import", "import_to_manager", "manager_init",
+        "first_quorum", "heal", "first_step",
+    )
+
+    @pytest.fixture
+    def record(self, monkeypatch):
+        from torchft_tpu import startup
+
+        now = time.monotonic()
+        fresh = startup.StartupRecord(started=now - 2.0, imported=now - 1.5)
+        monkeypatch.setattr(startup, "_record", fresh)
+        return fresh
+
+    @staticmethod
+    def _step(m, commit=True):
+        m.start_quorum()
+        m.allreduce({"w": np.ones(2, np.float32)}).wait()
+        return m.should_commit()
+
+    def test_closes_at_the_first_commit_and_logs_once(self, store, record, caplog):
+        m, client, _, _ = _create_manager(store)
+        client.quorum.return_value = _quorum_result()
+        with caplog.at_level("INFO", logger="torchft_tpu.manager"):
+            client.should_commit.return_value = False
+            assert not self._step(m)  # a vote that fails closes nothing
+            assert "ready" not in m.metrics().snapshot()["process"]["timers_s"]
+            assert not [r for r in caplog.records if "ready in" in r.getMessage()]
+            client.should_commit.return_value = True
+            assert self._step(m)
+            first = m.metrics().snapshot()["process"]
+            assert self._step(m)  # a second commit changes nothing
+            assert m.metrics().snapshot()["process"] == first
+        (line,) = [r.getMessage() for r in caplog.records if "ready in" in r.getMessage()]
+        assert line.startswith("[testrep/1 - step 1] ready in ")
+        for word in (*self.INTERVALS, "compile", "hits", "misses"):
+            assert word in line, (word, line)
+        # another Manager of this process finds the record closed: no line
+        m2, client2, _, _ = _create_manager(store)
+        client2.quorum.return_value = _quorum_result()
+        client2.should_commit.return_value = True
+        with caplog.at_level("INFO", logger="torchft_tpu.manager"):
+            caplog.clear()
+            assert self._step(m2)
+        assert not [r for r in caplog.records if "ready in" in r.getMessage()]
+        assert m2.metrics().snapshot()["process"] == first
+        m.shutdown()
+        m2.shutdown()
+
+    def test_the_intervals_sum_to_ready(self, store, record):
+        m, client, _, _ = _create_manager(store)
+        client.quorum.return_value = _quorum_result()
+        client.should_commit.return_value = True
+        time.sleep(0.02)  # the trainer between the Manager and its first step
+        assert self._step(m)
+        snap = m.metrics().snapshot()
+        seconds = {k: v["total_s"] for k, v in snap["process"]["timers_s"].items()}
+        assert set(self.INTERVALS) | {"ready", "startup_compile"} <= set(seconds)
+        assert all(snap["process"]["timers_s"][k]["n"] == 1 for k in self.INTERVALS)
+        assert sum(seconds[k] for k in self.INTERVALS) == pytest.approx(seconds["ready"], abs=1e-3)
+        # the stamps the fixture planted, and what lies between the others
+        assert seconds["spawn_to_import"] == pytest.approx(0.5, abs=1e-3)
+        assert 1.5 <= seconds["import_to_manager"] < 1.5 + 5.0
+        assert 0.0 < seconds["manager_init"] < 5.0 and seconds["first_step"] >= 0.02
+        assert seconds["heal"] == 0.0 and 2.0 < seconds["ready"] < 12.0
+        assert seconds["first_quorum"] == pytest.approx(
+            snap["timers_s"]["quorum"]["total_s"] + snap["timers_s"]["reconfigure"]["total_s"],
+            abs=1e-5,
+        )
+        assert snap["process"]["counters"]["startup_cache_misses"] == 0
+        json.dumps(snap)  # a run file carries it
+        m.shutdown()
+
+    def test_a_later_slow_quorum_is_not_the_first(self, store, record):
+        m, client, _, _ = _create_manager(store)
+        client.quorum.return_value = _quorum_result()
+        client.should_commit.return_value = False
+        assert not self._step(m)  # the first quorum, and no commit yet
+
+        def slow(**kwargs):
+            time.sleep(0.3)
+            return _quorum_result(quorum_id=2)  # and a second reconfigure
+
+        client.quorum.side_effect = slow
+        client.should_commit.return_value = True
+        assert self._step(m)
+        snap = m.metrics().snapshot()
+        assert snap["timers_s"]["quorum"]["n"] == 2 and snap["timers_s"]["quorum"]["max"] >= 0.3
+        assert snap["timers_s"]["reconfigure"]["n"] == 2
+        seconds = {k: v["total_s"] for k, v in snap["process"]["timers_s"].items()}
+        assert seconds["first_quorum"] < 0.25
+        assert seconds["first_step"] >= 0.3  # the slow one is the first step's
+        assert sum(seconds[k] for k in self.INTERVALS) == pytest.approx(seconds["ready"], abs=1e-3)
+        m.shutdown()
+
+    def test_the_first_lifes_heal_is_in_heal(self, store, record):
+        def load(sd):
+            time.sleep(0.05)
+
+        m, client, _, transport = _create_manager(
+            store, use_async_quorum=False, load_state_dict=load,
+        )
+        client.quorum.return_value = _quorum_result(
+            quorum_id=2, replica_rank=1, heal=True, max_step=20, max_rank=None,
+            recover_src_manager_address="mock://peer", recover_src_rank=0,
+        )
+        client.checkpoint_metadata.return_value = "peer:meta"
+
+        def recv(**kwargs):
+            time.sleep(0.1)
+            return {"user": {"model": "w"}, "torchft": {"step": 20, "batches_committed": 40}}
+
+        transport.recv_checkpoint.side_effect = recv
+        client.should_commit.return_value = True
+        assert self._step(m)
+        snap = m.metrics().snapshot()
+        seconds = {k: v["total_s"] for k, v in snap["process"]["timers_s"].items()}
+        assert seconds["heal"] == pytest.approx(
+            snap["timers_s"]["heal_fetch"]["total_s"] + snap["timers_s"]["heal_apply"]["total_s"],
+            abs=1e-5,
+        )
+        assert seconds["heal"] >= 0.15
+        m.shutdown()
+
+    def test_manager_init_is_a_span_of_its_own(self, store, record, tmp_path):
+        from test_profiling import _captured, _one
+
+        made = []
+        events = _captured(tmp_path, lambda: made.append(_create_manager(store)[0]))
+        event = _one(events, "torchft::startup/manager_init")
+        assert (event[3] - event[2]) / 1e9 == pytest.approx(
+            record._built - record._entered, abs=2e-3
+        )
+        made[0].shutdown()

@@ -424,3 +424,140 @@ def test_allreduce_ready_parts_the_devices_wait_from_the_link(tmp_path):
     assert entry["ready"] == pytest.approx((ready[3] - ready[2]) / 1e9, abs=5e-3)
     # the device's work is in ``ready``; the read after it is a copy
     assert entry["ready"] > entry["d2h"]
+
+
+# -- JAX's compile events, heard by the process's start-up record ------------
+
+
+@pytest.fixture
+def record(monkeypatch):
+    """A fresh start-up record in place of the process's own, with the
+    listeners on (once a process, whichever test comes first)."""
+    from torchft_tpu import startup
+
+    fresh = startup.StartupRecord(started=time.monotonic(), imported=time.monotonic())
+    monkeypatch.setattr(startup, "_record", fresh)
+    startup.listen()
+    return fresh
+
+
+def _planted_step(x):
+    return x * 2 + 1
+
+
+def test_a_program_compiled_once_is_one_sample_and_no_recompile(record, caplog):
+    x = jnp.ones(3)
+    assert record.close(Metrics()) is not None  # past the first commit
+    with caplog.at_level("WARNING", logger="torchft_tpu.startup"):
+        jax.jit(_planted_step)(x).block_until_ready()
+        jax.jit(_planted_step)(x).block_until_ready()  # jit's own cache
+    assert record._compiled["jit(_planted_step)"] == 1
+    snap = record.snapshot()
+    assert snap["counters"]["compiles"] == snap["timers_s"]["compile"]["n"] >= 1
+    assert "recompiles" not in snap["counters"] and not caplog.records
+    # the persistent cache is off in the tests: nothing asked, nothing missed
+    assert set(snap) == {"counters", "timers_s"}
+    assert not {"compile_cache_hits", "compile_cache_misses"} & set(snap["counters"])
+
+
+def test_a_second_shape_is_a_recompile_logged_with_the_step(record, caplog):
+    manager_metrics = Metrics()
+    manager_metrics.step = 41
+    record.bind(manager_metrics)
+    step = jax.jit(_planted_step)
+    # made before the commit: past it JAX's own eager programs count too
+    # (``jnp.ones`` at a new shape is a compile inside that step)
+    first, second = jnp.ones(17), jnp.ones(19)  # shapes no other test compiles
+    with caplog.at_level("WARNING", logger="torchft_tpu.startup"):
+        step(first).block_until_ready()
+        assert record.close(manager_metrics) is not None  # the first commit
+        assert not caplog.records
+        step(second).block_until_ready()  # the planted recompile
+    assert record._compiled["jit(_planted_step)"] == 2
+    assert record.snapshot()["counters"]["recompiles"] == 1
+    (warning,) = caplog.records
+    text = warning.getMessage()
+    assert "jit(_planted_step)" in text and "step 41" in text and " s at " in text
+    # and the manager's snapshot carries the record
+    assert manager_metrics.snapshot()["process"]["counters"]["recompiles"] == 1
+
+
+def test_a_lambda_never_counts_and_nothing_does_before_the_first_commit(record, caplog):
+    with caplog.at_level("WARNING", logger="torchft_tpu.startup"):
+        for n in (3, 5, 7):  # a start-up: one name at many shapes, by design
+            jax.jit(_planted_step)(jnp.ones((n, 2))).block_until_ready()
+            (jnp.ones(n) + jnp.arange(n)).block_until_ready()  # jit(add) a shape
+        inputs = [jnp.ones(n) for n in (3, 5, 7)]
+        assert record.close(Metrics()) is not None
+        for x in inputs:
+            jax.jit(lambda x: x - 1)(x).block_until_ready()
+    snap = record.snapshot()
+    assert snap["counters"]["compiles"] >= 9  # all of them are compiles
+    assert record._compiled["jit(_planted_step)"] == 3
+    assert "recompiles" not in snap["counters"] and not caplog.records
+
+
+def test_a_listener_that_fails_logs_and_does_not_raise_into_the_compile(
+    record, monkeypatch, caplog
+):
+    def broken(*_):
+        raise RuntimeError("planted")
+
+    monkeypatch.setattr(record, "compiled", broken)
+    monkeypatch.setattr(record, "cache", broken)
+    with caplog.at_level("ERROR", logger="torchft_tpu.startup"):
+        # JAX calls these inside jit: profiling must not take down training
+        jax.jit(_planted_step)(jnp.ones((2, 23))).block_until_ready()
+        from torchft_tpu import startup
+
+        startup._on_event(startup._MISS)
+    assert len(caplog.records) >= 2
+    assert all("uncounted" in r.getMessage() for r in caplog.records)
+
+
+def test_compiles_up_to_the_first_commit_are_the_startups(record):
+    jax.jit(_planted_step)(jnp.ones(11)).block_until_ready()
+    before = record.snapshot()["timers_s"]["compile"]
+    assert record.close(Metrics()) is not None
+    jax.jit(_planted_step)(jnp.ones(13)).block_until_ready()  # after the commit
+    snap = record.snapshot()
+    assert snap["timers_s"]["compile"]["n"] > before["n"]  # the life's timer goes on
+    assert snap["timers_s"]["startup_compile"] == {
+        "n": 1, **{k: pytest.approx(before["total_s"], abs=2e-6) for k in ("total_s", "p50", "p90", "max")}
+    }
+    assert snap["counters"]["startup_cache_misses"] == 0
+
+
+@pytest.mark.parametrize("program, listening, has_jax", [
+    ("import torchft_tpu.launcher", False, False),
+    ("import torchft_tpu.manager; from torchft_tpu import FTTrainState", False, False),
+    ("import jax; import torchft_tpu", True, True),
+    ("import torchft_tpu; import jax; from torchft_tpu.profiling import span; span('x')", True, True),
+    ("import torchft_tpu; from torchft_tpu.platform import apply_compilation_cache_env as on; on()", True, True),
+    ("import torchft_tpu; import jax; torchft_tpu.FTTrainState(1, None, 1)", True, True),
+], ids=["launcher", "manager", "jax_first", "span", "cache_env", "train_state"])
+def test_who_listens(program, listening, has_jax):
+    """The launcher's parent holds no ``jax`` and registers nothing; a
+    trainer is heard from the package's import if it holds ``jax`` by
+    then, else from the package's first own use of it."""
+    import subprocess
+    import sys
+
+    check = (
+        f"{program}; import sys; from torchft_tpu import startup; "
+        f"assert ('jax' in sys.modules) is {has_jax}, sorted(sys.modules); "
+        f"assert startup._listening is {listening}"
+    )
+    if listening:
+        check += (
+            "; from jax._src import monitoring; "
+            "assert startup._on_event in monitoring.get_event_listeners(); "
+            "assert startup._on_duration in monitoring.get_event_duration_listeners(); "
+            "startup.listen(); assert monitoring.get_event_listeners().count(startup._on_event) == 1"
+        )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run(
+        [sys.executable, "-c", check], env=env, capture_output=True, text=True, timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert done.returncode == 0, done.stderr[-2000:]

@@ -7,6 +7,8 @@ training-step granularity, recovering replicas fetch live weights from a
 healthy peer, and every step ends in a distributed commit vote.
 """
 
+from torchft_tpu import startup  # first: its import is the record's stamp
+
 from torchft_tpu._native import (
     LeaseClient,
     Lighthouse,
@@ -60,6 +62,8 @@ from torchft_tpu.pipeline import pipeline_blocks, stack_blocks
 from torchft_tpu.profiling import Profiler
 from torchft_tpu.train_state import FTTrainState
 from torchft_tpu.xla_collectives import XLACollectives
+
+startup.listen()  # a trainer that holds jax by now; nobody else (startup.py)
 
 __all__ = [
     "AdaptiveDDP",

@@ -74,6 +74,7 @@ from .checkpointing import (
     rebuild_from_packed,
     serialize_state_dict,
 )
+from .profiling import timed_span
 
 logger = logging.getLogger(__name__)
 
@@ -521,6 +522,10 @@ class DurableCheckpointer:
                 a ``maybe_save`` call in the loop.
         """
         self._manager = manager
+        # the Manager's timers durable_capture / durable_write /
+        # durable_commit, where the manager has any (a stub has none)
+        metrics = getattr(manager, "metrics", None)
+        self._metrics = metrics() if callable(metrics) else None
         self._state = state
         self._loader = loader
         self._every = max(
@@ -636,15 +641,41 @@ class DurableCheckpointer:
         if rank is None:
             return None  # spare/healing member: no shard duty this set
         world = max(int(self._manager.num_participants()), 1)
-        t0 = time.perf_counter()
-        payload = {
-            "user": self._state.state_dict(),
-            "torchft": self._manager.state_dict(),
-        }
         row: Dict[str, Any] = {
             "step": step, "quorum_id": quorum_id, "rank": rank,
             "world": world, "mode": self._mode, "wire": self._wire or "none",
             "committed": False, "aborted": False, "skipped": False,
+        }
+        # The trainer's whole stall: the capture (d2h + owning host
+        # copies + skeleton pickle) and, in sync mode, the full pipeline
+        # - the baseline the async stall is benched against. Everything
+        # after this ``with`` is off the training path.
+        with timed_span("torchft::durable_save/capture", step) as capture:
+            snap = self._stage(step, quorum_id, rank, world, row)
+            if snap is not None and self._mode == "sync":
+                self._write_snapshot(snap)
+                if rank == 0 and not snap.abort.is_set():
+                    self._commit_snapshot(snap)
+                snap.done.set()
+        row["stall_s"] = capture.seconds
+        self._file("durable_capture", capture.seconds)
+        if snap is None:
+            return None
+        if self._mode != "sync":
+            self._ensure_writer()
+            self._queue.put(snap)
+        return snap.directory
+
+    def _stage(
+        self, step: int, quorum_id: int, rank: int, world: int,
+        row: Dict[str, Any],
+    ) -> Optional[_Snapshot]:
+        """The snapshot of this member's shard, staged on the host and
+        entered in ``snapshots`` and the in-flight list; None where the
+        staging cap skips it."""
+        payload = {
+            "user": self._state.state_dict(),
+            "torchft": self._manager.state_dict(),
         }
         if self._max_staging > 0:
             with self._inflight_lock:
@@ -658,7 +689,6 @@ class DurableCheckpointer:
                 # widens the restore gap; blocking the trainer on disk
                 # is exactly what v2 exists to remove.
                 row["skipped"] = True
-                row["stall_s"] = time.perf_counter() - t0
                 self.snapshots.append(row)
                 logger.warning(
                     "durable snapshot at step %d skipped: %d staged bytes "
@@ -689,27 +719,15 @@ class DurableCheckpointer:
         row["captured_bytes"] = staging.captured_bytes
         bounds = shard_bounds(staging.total, world)
         row["shard_bytes"] = bounds[rank + 1] - bounds[rank]
-        # The trainer's whole stall: the capture above (d2h + owning
-        # host copies + skeleton pickle). Everything after this line is
-        # off the training path in async mode.
-        row["stall_s"] = time.perf_counter() - t0
         self._last_saved = step
         self.snapshots.append(row)
         with self._inflight_lock:
             self._inflight.append(snap)
-        if self._mode == "sync":
-            t1 = time.perf_counter()
-            self._write_snapshot(snap)
-            if rank == 0 and not snap.abort.is_set():
-                self._commit_snapshot(snap)
-            snap.done.set()
-            # sync mode stalls for the full pipeline — the baseline the
-            # async stall is benched against
-            row["stall_s"] += time.perf_counter() - t1
-        else:
-            self._ensure_writer()
-            self._queue.put(snap)
-        return snap.directory
+        return snap
+
+    def _file(self, timer: str, seconds: float) -> None:
+        if self._metrics is not None:
+            self._metrics.record(timer, seconds)
 
     # -- writer (background thread) --
 
@@ -741,45 +759,46 @@ class DurableCheckpointer:
         bounds = shard_bounds(snap.staging.total, snap.world)
         begin, end = bounds[snap.rank], bounds[snap.rank + 1]
         row = snap.stats
-        t0 = time.perf_counter()
-        if snap.abort.is_set():
-            row["aborted"] = True
-            return
-        crc = snap.staging.range_crc32c(begin, end)
-        shard_name = f"{d}/shard_{snap.rank:04d}.bin"
-        self._store.put_from(
-            shard_name,
-            lambda f: snap.staging.write_range(f, begin, end),
-        )
-        marker: Dict[str, Any] = {
-            "v": 1, "step": snap.step, "quorum_id": snap.quorum_id,
-            "rank": snap.rank, "world": snap.world,
-            "begin": begin, "end": end, "nbytes": end - begin,
-            "crc": f"{crc:08x}", "wire": self._wire or "none",
-            "total": snap.staging.total, "name": shard_name,
-        }
-        if snap.rank == 0:
-            meta = snap.staging.meta
-            self._store.put(f"{d}/meta.pkl", meta)
-            marker["meta_nbytes"] = len(meta)
-            marker["meta_crc"] = f"{_crc32c(meta):08x}"
-        if snap.local_state is not None:
-            self._store.put(
-                f"{d}/member_{snap.replica_id}.local", snap.local_state
+        with timed_span("torchft::durable_save/write", snap.step) as write:
+            if snap.abort.is_set():
+                row["aborted"] = True
+                return
+            crc = snap.staging.range_crc32c(begin, end)
+            shard_name = f"{d}/shard_{snap.rank:04d}.bin"
+            self._store.put_from(
+                shard_name,
+                lambda f: snap.staging.write_range(f, begin, end),
             )
-        if snap.abort.is_set():
-            row["aborted"] = True
-            self._cleanup_member(snap)
-            return
-        # Marker publication is the member's durability vote: it lands
-        # (atomic, fsynced) strictly AFTER the shard payload is durable,
-        # so the committer polling markers can never commit over a shard
-        # still in flight.
-        self._store.put(
-            f"{d}/shard_{snap.rank:04d}.json",
-            json.dumps(marker, sort_keys=True).encode(),
-        )
-        row["write_s"] = time.perf_counter() - t0
+            marker: Dict[str, Any] = {
+                "v": 1, "step": snap.step, "quorum_id": snap.quorum_id,
+                "rank": snap.rank, "world": snap.world,
+                "begin": begin, "end": end, "nbytes": end - begin,
+                "crc": f"{crc:08x}", "wire": self._wire or "none",
+                "total": snap.staging.total, "name": shard_name,
+            }
+            if snap.rank == 0:
+                meta = snap.staging.meta
+                self._store.put(f"{d}/meta.pkl", meta)
+                marker["meta_nbytes"] = len(meta)
+                marker["meta_crc"] = f"{_crc32c(meta):08x}"
+            if snap.local_state is not None:
+                self._store.put(
+                    f"{d}/member_{snap.replica_id}.local", snap.local_state
+                )
+            if snap.abort.is_set():
+                row["aborted"] = True
+                self._cleanup_member(snap)
+                return
+            # Marker publication is the member's durability vote: it lands
+            # (atomic, fsynced) strictly AFTER the shard payload is durable,
+            # so the committer polling markers can never commit over a shard
+            # still in flight.
+            self._store.put(
+                f"{d}/shard_{snap.rank:04d}.json",
+                json.dumps(marker, sort_keys=True).encode(),
+            )
+        row["write_s"] = write.seconds
+        self._file("durable_write", write.seconds)
         row["durable_bytes"] = (end - begin) + (
             marker.get("meta_nbytes", 0)
             + (len(snap.local_state) if snap.local_state else 0)
@@ -805,71 +824,72 @@ class DurableCheckpointer:
         and mutually consistent, then appends the manifest commit record
         — the ONLY thing that makes the set restorable."""
         d = snap.directory
-        deadline = time.monotonic() + self._commit_timeout_s
-        t0 = time.perf_counter()
-        markers: Dict[int, Dict[str, Any]] = {}
-        while len(markers) < snap.world:
-            for r in range(snap.world):
-                if r in markers:
-                    continue
-                name = f"{d}/shard_{r:04d}.json"
-                if not self._store.exists(name):
-                    continue
-                try:
-                    markers[r] = json.loads(self._store.get(name))
-                except (OSError, ValueError):
-                    continue
-            if len(markers) >= snap.world:
-                break
-            if snap.abort.is_set() or time.monotonic() > deadline:
-                snap.stats["aborted"] = True
-                logger.warning(
-                    "durable snapshot %s abandoned: %d/%d shard markers "
-                    "after %.1fs", d, len(markers), snap.world,
-                    time.monotonic() - (deadline - self._commit_timeout_s),
-                )
-                return False
-            time.sleep(0.02)
-        bad = inconsistent_marker(
-            markers,
-            step=snap.step,
-            quorum_id=snap.quorum_id,
-            world=snap.world,
-            total=snap.staging.total,
-            wire=self._wire or "none",
-        )
-        if bad is not None:
-            logger.warning(
-                "durable snapshot %s abandoned: shard %d marker "
-                "inconsistent (%s)", d, bad[0], bad[1],
+        with timed_span("torchft::durable_save/commit", snap.step) as commit:
+            deadline = time.monotonic() + self._commit_timeout_s
+            markers: Dict[int, Dict[str, Any]] = {}
+            while len(markers) < snap.world:
+                for r in range(snap.world):
+                    if r in markers:
+                        continue
+                    name = f"{d}/shard_{r:04d}.json"
+                    if not self._store.exists(name):
+                        continue
+                    try:
+                        markers[r] = json.loads(self._store.get(name))
+                    except (OSError, ValueError):
+                        continue
+                if len(markers) >= snap.world:
+                    break
+                if snap.abort.is_set() or time.monotonic() > deadline:
+                    snap.stats["aborted"] = True
+                    logger.warning(
+                        "durable snapshot %s abandoned: %d/%d shard markers "
+                        "after %.1fs", d, len(markers), snap.world,
+                        time.monotonic() - (deadline - self._commit_timeout_s),
+                    )
+                    return False
+                time.sleep(0.02)
+            bad = inconsistent_marker(
+                markers,
+                step=snap.step,
+                quorum_id=snap.quorum_id,
+                world=snap.world,
+                total=snap.staging.total,
+                wire=self._wire or "none",
             )
-            snap.stats["aborted"] = True
-            return False
-        if snap.abort.is_set():
-            snap.stats["aborted"] = True
-            return False
-        record = {
-            "t": "commit", "step": snap.step, "quorum_id": snap.quorum_id,
-            "world": snap.world, "wire": self._wire or "none",
-            "total": snap.staging.total, "dir": d,
-            "meta": {
-                "name": f"{d}/meta.pkl",
-                "nbytes": markers[0]["meta_nbytes"],
-                "crc": markers[0]["meta_crc"],
-            },
-            "shards": [
-                {
-                    "rank": r, "name": markers[r]["name"],
-                    "begin": markers[r]["begin"], "end": markers[r]["end"],
-                    "nbytes": markers[r]["nbytes"], "crc": markers[r]["crc"],
-                }
-                for r in range(snap.world)
-            ],
-            "unix_ms": int(time.time() * 1000),
-        }
-        self._manifest.append(record)
-        snap.stats["committed"] = True
-        snap.stats["commit_s"] = time.perf_counter() - t0
+            if bad is not None:
+                logger.warning(
+                    "durable snapshot %s abandoned: shard %d marker "
+                    "inconsistent (%s)", d, bad[0], bad[1],
+                )
+                snap.stats["aborted"] = True
+                return False
+            if snap.abort.is_set():
+                snap.stats["aborted"] = True
+                return False
+            record = {
+                "t": "commit", "step": snap.step, "quorum_id": snap.quorum_id,
+                "world": snap.world, "wire": self._wire or "none",
+                "total": snap.staging.total, "dir": d,
+                "meta": {
+                    "name": f"{d}/meta.pkl",
+                    "nbytes": markers[0]["meta_nbytes"],
+                    "crc": markers[0]["meta_crc"],
+                },
+                "shards": [
+                    {
+                        "rank": r, "name": markers[r]["name"],
+                        "begin": markers[r]["begin"], "end": markers[r]["end"],
+                        "nbytes": markers[r]["nbytes"], "crc": markers[r]["crc"],
+                    }
+                    for r in range(snap.world)
+                ],
+                "unix_ms": int(time.time() * 1000),
+            }
+            self._manifest.append(record)
+            snap.stats["committed"] = True
+        snap.stats["commit_s"] = commit.seconds
+        self._file("durable_commit", commit.seconds)
         self._retire_old()
         return True
 
@@ -912,10 +932,9 @@ class DurableCheckpointer:
         quorum. A set that fails validation (missing object, CRC
         mismatch) falls back to the next older committed set — a torn
         snapshot can never win."""
-        t_replay = time.perf_counter()
-        records, dropped = self._manifest.replay()
-        commits = live_commits(records)
-        replay_s = time.perf_counter() - t_replay
+        with timed_span("torchft::durable_restore/replay") as replay:
+            records, dropped = self._manifest.replay()
+            commits = live_commits(records)
         for rec in reversed(commits):
             try:
                 payload, local, stats = self._fetch_committed(
@@ -927,7 +946,7 @@ class DurableCheckpointer:
                     "trying older", rec.get("dir"), e,
                 )
                 continue
-            stats["manifest_read_s"] += replay_s
+            stats["manifest_read_s"] += replay.seconds
             stats["dropped_tail_bytes"] = dropped
             self._state.load_state_dict(payload["user"])
             self._manager.load_state_dict(payload["torchft"])
@@ -954,90 +973,90 @@ class DurableCheckpointer:
             "bytes": rec["total"], "wire": rec["wire"],
             "h2d_s": 0.0, "compile_s": 0.0,
         }
-        t0 = time.perf_counter()
-        meta_raw = self._store.get(rec["meta"]["name"])
-        if len(meta_raw) != rec["meta"]["nbytes"] or (
-            f"{_crc32c(meta_raw):08x}" != rec["meta"]["crc"]
-        ):
-            raise ValueError("meta blob CRC/size mismatch")
-        meta = load_packed_meta(meta_raw)
-        if int(meta["total"]) != int(rec["total"]):
-            raise ValueError("meta/manifest total mismatch")
-        stats["manifest_read_s"] = time.perf_counter() - t0
+        step = int(rec["step"])
+        with timed_span("torchft::durable_restore/manifest", step) as manifest:
+            meta_raw = self._store.get(rec["meta"]["name"])
+            if len(meta_raw) != rec["meta"]["nbytes"] or (
+                f"{_crc32c(meta_raw):08x}" != rec["meta"]["crc"]
+            ):
+                raise ValueError("meta blob CRC/size mismatch")
+            meta = load_packed_meta(meta_raw)
+            if int(meta["total"]) != int(rec["total"]):
+                raise ValueError("meta/manifest total mismatch")
+        stats["manifest_read_s"] = manifest.seconds
 
         # Parallel range-fetch: each shard IS one contiguous range of the
         # packed stream, so W readers fill one preallocated buffer with
         # no reassembly pass — the streamed-heal receiver shape against
         # the durable tier instead of a donor.
-        t1 = time.perf_counter()
-        total = int(rec["total"])
-        buf = bytearray(total)
-        view = memoryview(buf)
-        errors: List[BaseException] = []
+        with timed_span("torchft::durable_restore/fetch", step) as fetched:
+            total = int(rec["total"])
+            buf = bytearray(total)
+            view = memoryview(buf)
+            errors: List[BaseException] = []
 
-        def fetch(shard: Dict[str, Any]) -> None:
-            try:
-                begin, end = int(shard["begin"]), int(shard["end"])
-                data = self._store.read_range(
-                    shard["name"], 0, end - begin
-                )
-                if len(data) != end - begin:
-                    raise ValueError(
-                        f"shard {shard['rank']} short read "
-                        f"({len(data)}/{end - begin})"
+            def fetch(shard: Dict[str, Any]) -> None:
+                try:
+                    begin, end = int(shard["begin"]), int(shard["end"])
+                    data = self._store.read_range(
+                        shard["name"], 0, end - begin
                     )
-                if f"{_crc32c(data):08x}" != shard["crc"]:
-                    raise ValueError(
-                        f"shard {shard['rank']} CRC32C mismatch"
-                    )
-                view[begin:end] = data
-            except BaseException as e:  # noqa: BLE001 - surface to caller
-                errors.append(e)
+                    if len(data) != end - begin:
+                        raise ValueError(
+                            f"shard {shard['rank']} short read "
+                            f"({len(data)}/{end - begin})"
+                        )
+                    if f"{_crc32c(data):08x}" != shard["crc"]:
+                        raise ValueError(
+                            f"shard {shard['rank']} CRC32C mismatch"
+                        )
+                    view[begin:end] = data
+                except BaseException as e:  # noqa: BLE001 - surface to caller
+                    errors.append(e)
 
-        threads = [
-            threading.Thread(target=fetch, args=(s,), daemon=True)
-            for s in rec["shards"]
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        if errors:
-            raise errors[0]
-        covered = sorted(
-            (int(s["begin"]), int(s["end"])) for s in rec["shards"]
-        )
-        pos = 0
-        for begin, end in covered:
-            if begin != pos:
-                raise ValueError("shard ranges do not tile the stream")
-            pos = end
-        if pos != total:
-            raise ValueError("shard ranges do not cover the stream")
-        stats["shard_fetch_s"] = time.perf_counter() - t1
+            threads = [
+                threading.Thread(target=fetch, args=(s,), daemon=True)
+                for s in rec["shards"]
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            if errors:
+                raise errors[0]
+            covered = sorted(
+                (int(s["begin"]), int(s["end"])) for s in rec["shards"]
+            )
+            pos = 0
+            for begin, end in covered:
+                if begin != pos:
+                    raise ValueError("shard ranges do not tile the stream")
+                pos = end
+            if pos != total:
+                raise ValueError("shard ranges do not cover the stream")
+        stats["shard_fetch_s"] = fetched.seconds
 
-        t2 = time.perf_counter()
-        payload = rebuild_from_packed(meta, buf, device_put=False)
-        stats["reshard_s"] = time.perf_counter() - t2
+        with timed_span("torchft::durable_restore/reshard", step) as reshard:
+            payload = rebuild_from_packed(meta, buf, device_put=False)
+        stats["reshard_s"] = reshard.seconds
         if device_put:
             import jax
             import jax.numpy as jnp
             import numpy as np
 
-            t3 = time.perf_counter()
+            with timed_span("torchft::durable_restore/h2d", step) as h2d:
+                def up(leaf: Any) -> Any:
+                    if isinstance(leaf, np.ndarray) and (
+                        jax.dtypes.canonicalize_dtype(leaf.dtype) == leaf.dtype
+                    ):
+                        return jnp.asarray(leaf)
+                    return leaf
 
-            def up(leaf: Any) -> Any:
-                if isinstance(leaf, np.ndarray) and (
-                    jax.dtypes.canonicalize_dtype(leaf.dtype) == leaf.dtype
-                ):
-                    return jnp.asarray(leaf)
-                return leaf
-
-            payload = jax.tree_util.tree_map(up, payload)
-            jax.block_until_ready(
-                [l for l in jax.tree_util.tree_leaves(payload)]
-            )
-            stats["h2d_s"] = time.perf_counter() - t3
+                payload = jax.tree_util.tree_map(up, payload)
+                jax.block_until_ready(
+                    [l for l in jax.tree_util.tree_leaves(payload)]
+                )
+            stats["h2d_s"] = h2d.seconds
 
         local = None
         local_name = (

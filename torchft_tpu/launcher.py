@@ -33,6 +33,8 @@ import time
 from dataclasses import dataclass
 from typing import Dict, Optional, Sequence
 
+from .startup import SPAWN_STAMP, spawn_stamp
+
 logger = logging.getLogger(__name__)
 
 
@@ -284,7 +286,11 @@ def launch(
             )
         )
 
-    def spawn(s: _Supervised, as_standby: bool = False) -> subprocess.Popen:
+    def spawn(
+        s: _Supervised, as_standby: bool = False,
+        died_at: Optional[float] = None,
+    ) -> subprocess.Popen:
+        """``died_at``: a restart, after a death seen then (wall clock)."""
         full_env = {**os.environ, **s.spec["env"]}  # type: ignore[arg-type]
         preexec = None
         if as_standby:
@@ -309,6 +315,11 @@ def launch(
                         pass
         else:
             full_env.pop("TORCHFT_STANDBY_FILE", None)
+        # the child's start-up record counts from here (startup.py); a
+        # standby is nobody's restart until its promotion says so
+        full_env[SPAWN_STAMP] = (
+            spawn_stamp() if as_standby else spawn_stamp(s.restarts, died_at)
+        )
         proc = subprocess.Popen(
             list(s.spec["cmd"]), env=full_env, preexec_fn=preexec,  # type: ignore[arg-type]
         )
@@ -320,9 +331,14 @@ def launch(
             s.standby_lifted = False
         else:
             s.proc = proc
+        if died_at is not None:
+            logger.info(
+                f"{s.spec['name']}: restart {s.restarts} spawned "
+                f"{time.time() - died_at:.3f} s after its death was seen"
+            )
         return proc
 
-    def promote_or_spawn(s: _Supervised) -> None:
+    def promote_or_spawn(s: _Supervised, died_at: float) -> None:
         """Restart path: activate the warm standby when one is ready,
         else fall back to a cold spawn."""
         if s.standby is not None and s.standby.poll() is None:
@@ -336,7 +352,11 @@ def launch(
                     "finished warming — heal pays the remaining "
                     "import/compile at full priority"
                 )
-            open(s.standby_file, "w").close()  # releases standby_gate()
+            # releases standby_gate(), which reads the restart and the
+            # death off it: whole or not there
+            with open(s.standby_file + ".tmp", "w") as f:
+                f.write(f"{s.restarts} {died_at!r}")
+            os.replace(s.standby_file + ".tmp", s.standby_file)
             s.proc = s.standby
             s.standby = None
             if lift_ok:
@@ -352,10 +372,14 @@ def launch(
                         "priority despite the spawn-time probe; promoted "
                         "worker may stay niced"
                     )
-            logger.info(f"{s.spec['name']}: promoted standby pid {s.proc.pid}")
+            logger.info(
+                f"{s.spec['name']}: restart {s.restarts} promoted standby pid "
+                f"{s.proc.pid} {time.time() - died_at:.3f} s after the death "
+                "was seen"
+            )
             spawn(s, as_standby=True)  # re-arm (idle priority again)
         else:
-            spawn(s)
+            spawn(s, died_at=died_at)
             if lift_ok and heal_boost:
                 # Heal-priority boost (platform.heal_boost_nice): a COLD
                 # restart is the cohort's degraded member — lend it
@@ -446,12 +470,13 @@ def launch(
                     s.returncode = 0
                     logger.info(f"{s.spec['name']}: exited cleanly")
                 elif s.restarts < int(s.spec["max_restarts"]):  # type: ignore[arg-type]
+                    died_at = time.time()  # as seen from here, by this poll
                     s.restarts += 1
                     logger.warning(
                         f"{s.spec['name']}: exited rc={rc}, restart "
                         f"{s.restarts}/{s.spec['max_restarts']}"
                     )
-                    promote_or_spawn(s)
+                    promote_or_spawn(s, died_at)
                     running += 1
                 else:
                     s.returncode = rc

@@ -22,6 +22,7 @@ control plane live here, so a dead slice can never wedge an ICI collective.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import socket
@@ -33,7 +34,7 @@ from datetime import timedelta
 from enum import Enum
 from typing import Any, Callable, Dict, List, Optional, TypeVar, cast
 
-from . import _native
+from . import _native, startup
 from ._native import ManagerClient, StoreClient
 from .checkpointing import CheckpointServer, CheckpointTransport
 from .collectives import Collectives, ReduceOp, Work, _completed
@@ -56,6 +57,18 @@ class WorldSizeMode(Enum):
     FIXED_WITH_SPARES = 1
 
 
+def _startup_span(init: Callable[..., None]) -> Callable[..., None]:
+    """``Manager.__init__`` whole as ``torchft::startup/manager_init``,
+    the start-up record's ``manager_init`` (startup.py)."""
+
+    @functools.wraps(init)
+    def timed(self: "Manager", *args: Any, **kwargs: Any) -> None:
+        with startup.record().manager_init():
+            init(self, *args, **kwargs)
+
+    return timed
+
+
 class Manager:
     """Fault tolerance manager for one rank of one replica group.
 
@@ -64,6 +77,7 @@ class Manager:
     wrapper so the train loop stays ``zero_grad(); grads; step()``-shaped.
     """
 
+    @_startup_span
     def __init__(
         self,
         collectives: Collectives,
@@ -243,6 +257,10 @@ class Manager:
         self._metrics = Metrics()
         # sampled only when a call really blocks (wait_quorum, Work.wait)
         self._metrics.declare("quorum_wait", "work_wait")
+        # the process's way to its first commit (startup.py): the snapshot
+        # carries it as ``process``; closed at the first vote that passes
+        startup.record().bind(self._metrics)
+        self._starting = True
         # the transport times its own work (the donor's staging and the
         # ranges it serves) on its serving threads: into our timers,
         # stamped with our step
@@ -1105,6 +1123,11 @@ class Manager:
             self._step += 1
             if count_batches:
                 self._batches_committed += self.num_participants()
+            if self._starting:
+                self._starting = False
+                ready = startup.record().close(self._metrics)
+                if ready is not None:  # the first commit of this life
+                    self._logger.info(ready)
         self._metrics.incr("commits" if should_commit else "aborts")
         if self._errored is not None:
             self._metrics.incr("errors")

@@ -10,7 +10,11 @@ dashboards, or tests::
 
     manager.metrics().snapshot()
     # {"counters": {"commits": 98, "aborts": 2, "heals": 1, ...},
-    #  "timers_s": {"quorum": {"n":100,"p50":0.0012,"p90":0.003,...}, ...}}
+    #  "timers_s": {"quorum": {"n":100,"p50":0.0012,"p90":0.003,...}, ...},
+    #  "process": {"counters": {"compiles": 9, "recompiles": 0, ...},
+    #              "timers_s": {"ready": {"n":1,"total_s":31.2,...}, ...}}}
+
+(``process``: the way to the first commit and every compile, startup.py).
 
 ``Metrics.timed(name)`` is the step path's span primitive: one ``with``
 records the timer ``name`` here and shows as ``torchft::<name>`` in an
@@ -39,6 +43,13 @@ class _Timer:
         self._samples.append(seconds)
         self.count += 1
         self.total_s += seconds
+
+    def first(self) -> float:
+        """The first duration ever recorded; 0.0 where there is none or
+        the reservoir has rolled past it."""
+        if not self.count or self.count != len(self._samples):
+            return 0.0
+        return self._samples[0]
 
     def snapshot(self) -> Dict[str, float]:
         samples = sorted(self._samples)
@@ -106,6 +117,9 @@ class Metrics:
         # the owner's step (the Manager keeps it current): the stat that
         # pairs a ``timed`` span on any thread with the trainer's step
         self.step: Optional[int] = None
+        # the process's start-up record (startup.py), where the owner is
+        # a Manager: what the snapshot carries as ``process``
+        self.process: Optional[Any] = None
 
     def incr(self, name: str, by: int = 1) -> None:
         with self._lock:
@@ -117,6 +131,13 @@ class Metrics:
             if timer is None:
                 timer = self._timers[name] = _Timer()
             timer.record(seconds)
+
+    def first_sample(self, name: str) -> float:
+        """The first duration the timer ``name`` ever recorded; 0.0 where
+        it has none, or has rolled past it (512 samples)."""
+        with self._lock:
+            timer = self._timers.get(name)
+            return 0.0 if timer is None else timer.first()
 
     def mark(self, name: str) -> None:
         """Records one occurrence of a timestamped event (for rolling
@@ -155,7 +176,7 @@ class Metrics:
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
-            return {
+            snap = {
                 "counters": dict(self._counters),
                 "timers_s": {
                     name: t.snapshot() for name, t in self._timers.items()
@@ -164,6 +185,9 @@ class Metrics:
                     name: w.snapshot() for name, w in self._events.items()
                 },
             }
+        if self.process is not None:
+            snap["process"] = self.process.snapshot()
+        return snap
 
 
 class _TimedBlock(timed_span):

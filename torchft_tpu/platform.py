@@ -49,7 +49,10 @@ def standby_gate() -> None:
     fully-warmed spare from one still importing/compiling — the
     warm-deadline re-arm policy (a half-warmed spare on a saturated host
     gets its idle priority lifted so the NEXT kill finds it parked here,
-    not mid-import) and promotion logging both key off it."""
+    not mid-import) and promotion logging both key off it.
+
+    On return the process's start-up record (startup.py) begins again:
+    its ``ready`` is then what the promotion cost."""
     path = os.environ.get("TORCHFT_STANDBY_FILE")
     if not path:
         return
@@ -65,6 +68,19 @@ def standby_gate() -> None:
         if os.getppid() != supervisor:
             sys.exit(0)  # orphaned: supervisor is gone, nobody can promote us
         time.sleep(0.05)
+    # The start-up record begins again here: ``ready`` is what the
+    # promotion cost, not the standby's idle life. The launcher writes
+    # which restart this is and when it saw the death into the file.
+    from . import startup
+
+    restart, died_at = 0, None
+    try:
+        with open(path) as f:
+            fields = f.read().split()
+        restart, died_at = int(fields[0]), float(fields[1])
+    except (OSError, ValueError, IndexError):
+        pass  # activated by hand: an empty file
+    startup.record().promoted(restart, died_at)
 
 
 def standby_should_warm() -> bool:
@@ -131,6 +147,9 @@ def apply_compilation_cache_env() -> None:
     loads the executables its predecessor compiled."""
     import jax
 
+    from . import startup
+
+    startup.listen()  # the cache's hits and misses, from its first
     path = compilation_cache_dir()
     if path is not None:
         os.makedirs(path, exist_ok=True)

@@ -55,6 +55,8 @@ import threading
 import time
 from typing import Optional
 
+from . import startup  # half made here (it imports us): used at call time only
+
 logger = logging.getLogger(__name__)
 
 _ENV_DIR = "TORCHFT_PROFILE_DIR"
@@ -71,6 +73,8 @@ def span(name: str, step: Optional[int] = None, **stats: int):
     """
     import jax.profiler
 
+    if not startup._listening:  # the package's first own use of jax
+        startup.listen()
     if step is None:
         return jax.profiler.TraceAnnotation(name, **stats)
     return jax.profiler.TraceAnnotation(name, step=step, **stats)

@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from . import startup
 from .profiling import span
 
 
@@ -69,6 +70,7 @@ class FTTrainState:
     """
 
     def __init__(self, params: Any, tx: Any, opt_state: Optional[Any] = None) -> None:
+        startup.listen()  # the optimizer state's programs compile next
         self.params = params
         self.tx = tx
         self.opt_state = opt_state if opt_state is not None else tx.init(params)
