@@ -220,10 +220,11 @@ def test_the_final_norm_is_carried_into_the_next_pass():
 # ---------------------------------------------------------------------------
 
 
-def _unrolled_loss(cfg, params, copies, tokens):
+def _unrolled_loss(cfg, params, copies, tokens, nll_of=transformer.next_token_losses):
     """The looped model's loss with pass t run on ``copies[t]`` of the
     stack: a Python loop over the program's own pieces, no scan and no
-    checkpoint."""
+    checkpoint, every exit's logits and cross entropy (``nll_of``) left to
+    autodiff."""
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     h = olmoe._embed(cfg, params, inputs)
     gates, nll, total = [], [], None
@@ -233,7 +234,7 @@ def _unrolled_loss(cfg, params, copies, tokens):
             total = stats if total is None else jax.tree_util.tree_map(jnp.add, total, stats)
         h = transformer._rmsnorm(u, params["ln_f"]["scale"], cfg.rms_norm_eps)
         gates.append(h.astype(jnp.float32) @ params["exit_gate"]["w"] + params["exit_gate"]["b"])
-        nll.append(transformer.next_token_losses(h @ params["readout"].astype(cfg.dtype), targets))
+        nll.append(nll_of(h @ params["readout"].astype(cfg.dtype), targets))
     log_p = olmoe.exit_log_probs(jnp.stack(gates))
     p = jnp.exp(log_p)
     loss = jnp.mean(jnp.sum(p * jnp.stack(nll) - cfg.exit_entropy_coef * -p * log_p, axis=0))
@@ -379,10 +380,9 @@ def _equations(jaxpr):
             yield from _equations(inner)
 
 
-def _scans_of_the_gradient(cfg, how):
-    """(forward, backward): of each of the gradient's two scans over the
-    passes, how often its body holds each primitive, and the shape and
-    type of every output it stacks over the passes."""
+def _gradient_and_its_scans(cfg, how="kept"):
+    """The jaxpr of the loss's gradient as the program runs it, and its two
+    scans over the passes, forward and backward."""
     params, tokens = _weights(cfg), _tokens(cfg)
     with _loop_body_under(how):
         jaxpr = jax.make_jaxpr(jax.grad(lambda p: olmoe.loss_fn(cfg, p, tokens)))(params)
@@ -390,6 +390,14 @@ def _scans_of_the_gradient(cfg, how):
     assert [(e.params["length"], e.params["reverse"]) for e in scans] == [
         (cfg.passes, False), (cfg.passes, True)
     ]
+    return jaxpr.jaxpr, scans
+
+
+def _scans_of_the_gradient(cfg, how):
+    """(forward, backward): of each of the gradient's two scans over the
+    passes, how often its body holds each primitive, and the shape and
+    type of every output it stacks over the passes."""
+    _, scans = _gradient_and_its_scans(cfg, how)
     return [
         (
             collections.Counter(
@@ -427,6 +435,107 @@ def test_the_kept_product_leaves_the_recomputed_pass_and_nothing_else_does():
     assert calls == (plain_fwd["pallas_call"], plain_bwd["pallas_call"])
     assert calls == (cfg.n_layers, 2 * cfg.n_layers)
     assert sum(calls) == ouro_lm.lowered_mosaic_calls(cfg)
+
+
+def _readout_products(jaxpr, cfg):
+    """``dot_general``s of ``jaxpr`` (nested programs included) with the
+    readout's (D, V) among their operands or as their result."""
+    width = (cfg.d_model, cfg.vocab_size)
+    return sum(
+        e.primitive.name == "dot_general"
+        and any(v.aval.shape == width for v in (*e.invars, *e.outvars))
+        for e in _equations(jaxpr)
+    )
+
+
+@pytest.mark.parametrize("model", list(LOOPED) + ["ouro_bf16"])
+def test_an_exits_logits_are_multiplied_once_a_step(model):
+    """The mechanism ENGAGES, in every looped model's gradient: NEITHER scan
+    over the passes holds a product with the readout - where the readout
+    ran inside the checkpointed pass, the forward body held the logits'
+    product and the backward body held it AGAIN beside the two cotangents' -
+    and the whole gradient holds three an exit, after the loop: the logits,
+    ``h_t``'s cotangent, the readout's own. Outside differentiation the
+    head is the logits' product alone, and the forward scan stacks one
+    (T, B, S, D) array for it, the passes' ``h_t``, and no logits."""
+    cfg = dict(LOOPED, ouro_bf16=BF16)[model]
+    gradient, (forward, backward) = _gradient_and_its_scans(cfg)
+    assert _readout_products(forward.params["jaxpr"].jaxpr, cfg) == 0
+    assert _readout_products(backward.params["jaxpr"].jaxpr, cfg) == 0
+    assert _readout_products(gradient, cfg) == 3 * cfg.passes
+    loss = jax.make_jaxpr(lambda p: olmoe.loss_fn(cfg, p, _tokens(cfg)))(_weights(cfg))
+    assert _readout_products(loss.jaxpr, cfg) == cfg.passes
+    batch, seq = _tokens(cfg).shape
+    stacked = [v.aval.shape for v in forward.outvars[forward.params["num_carry"]:]]
+    assert (cfg.passes, batch, seq - 1, cfg.d_model) in stacked
+    assert not any(shape[-1] == cfg.vocab_size for shape in stacked)
+
+
+def _plain_cross_entropies(logits, targets):
+    """Each position's cross entropy with nothing of its own: a log-softmax
+    in float32, autodiff's backward pass."""
+    log_q = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    return -jnp.take_along_axis(log_q, targets[..., None], axis=-1)[..., 0]
+
+
+def _written_out(cfg, tokens, nll_of, scale=1.0):
+    """``scale`` x the objective ``mean(sum_t p_t CE_t - beta H(p))`` as a
+    function of the weights: the passes unrolled on the one stack, no scan,
+    no checkpoint, no head of its own."""
+    return lambda p: scale * _unrolled_loss(cfg, p, [p["blocks"]] * cfg.passes, tokens, nll_of)
+
+
+@pytest.mark.parametrize("bias", [None, -40.0, 40.0], ids=["learned", "driven_to_0", "driven_to_1"])
+@pytest.mark.parametrize("coef", [0.0, 0.05])
+def test_the_exits_head_is_plain_autodiff_of_the_written_out_objective(coef, bias):
+    """``_exits_nll`` computes its cotangents in its forward rule, from the
+    weight ``p_t / (B S)`` and not from what arrives; held, in float32, to
+    autodiff of the objective written out with a plain log-softmax: the
+    loss, and the gradient of EVERY leaf (the readout, the stack, the
+    gate's ``w`` and ``b`` - which the head reaches only through its
+    weight's cotangent -, the final norm, the embedding), with and without
+    the entropy term, with a gate driven to either end (an exit's weight
+    0 at every position), and times 3: the scalar that arrives is applied.
+    Measured 1.1e-7 on the loss (the sum is taken in another order) and
+    2.0e-6 of a leaf's largest entry."""
+    cfg = dataclasses.replace(F32, exit_entropy_coef=coef)
+    params, tokens = _weights(cfg), _tokens()
+    if bias is not None:
+        params = dict(params, exit_gate={"w": 0.0 * params["exit_gate"]["w"], "b": jnp.float32(bias)})
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(lambda p: 3.0 * olmoe.loss_fn(cfg, p, tokens))(params)
+        want, want_grads = jax.value_and_grad(
+            _written_out(cfg, tokens, _plain_cross_entropies, 3.0)
+        )(params)
+        once = olmoe.loss_fn(cfg, params, tokens)  # outside differentiation: the sum alone
+    assert abs(float(loss) - float(want)) <= 1e-6 * float(want)
+    assert abs(3.0 * float(once) - float(loss)) <= 1e-6 * float(loss)
+    assert set(grads) == {"embed", "blocks", "ln_f", "exit_gate", "readout"}
+    for path, err in _leaf_errors(grads, want_grads):
+        assert err <= GRAD_RTOL_F32 / 10, (path, err)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_exits_head_in_bf16_rounds_where_autodiff_rounds(seed):
+    """The configuration's precision: the head's logits in bf16 and their
+    cotangent rounded to bf16 as ``next_token_losses``' backward pass rounds
+    it, so against autodiff of the unrolled objective (both compiled) the
+    loss is float32 rounding away (measured 5e-7), the gradient's norm
+    4e-5 and no leaf further than one bf16 step of its largest entry
+    (measured 0.9%: 2 ** -7). The readout's gradient is the float32 sum of
+    the exits', rounded once, like the stack's."""
+    params, tokens = _weights(BF16, seed), _tokens(seed=seed + 1)
+    compute = jax.tree_util.tree_map(lambda l: l.astype(jnp.bfloat16), params)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: olmoe.loss_fn(BF16, p, tokens)))(compute)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        _written_out(BF16, tokens, transformer.next_token_losses)
+    ))(compute)
+    assert all(g.dtype == jnp.bfloat16 for g in jax.tree_util.tree_leaves(grads))
+    assert abs(float(loss) - float(want)) <= LOSS_RTOL_BF16 / 10 * float(want)
+    assert abs(_norm(grads) - _norm(want_grads)) <= GRAD_NORM_RTOL_BF16 / 10 * _norm(want_grads)
+    widened = lambda tree: jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), tree)
+    for path, err in _leaf_errors(widened(grads), widened(want_grads)):
+        assert err <= 2.0 ** -6, (path, err)
 
 
 def test_a_looped_expert_layer_names_nothing_and_keeps_nothing():
@@ -470,13 +579,24 @@ def test_a_bf16_copys_gradient_is_summed_over_the_passes_in_float32():
     """The scan closes over the stack's weights widened to float32, so the
     T passes' gradients are added in float32 and rounded to bf16 once: the
     bf16 gradient is the ROUNDED float32 sum of the passes' bf16 gradients,
-    not a sum rounded after every addition."""
+    not a sum rounded after every addition. Both sides are compiled to
+    round every bf16 value where it is written: left to keep a fusion's
+    intermediate in float32 (the compiler's default), two programs that add
+    ``h_t``'s three cotangents in one order round them in different places -
+    the unrolled form takes the readout's from the product, the program from
+    the array the exits' head stacked - and that noise is as large as what
+    is measured here."""
     cfg = BF16
     params, tokens = _weights(cfg), _tokens()
     compute = jax.tree_util.tree_map(lambda l: l.astype(jnp.bfloat16), params)
-    grads = jax.jit(jax.grad(lambda p: ouro.loss_fn(cfg, p, tokens)))(compute)
-    by_copy = jax.jit(jax.grad(lambda c: _unrolled_loss(cfg, compute, c, tokens)))(
-        [compute["blocks"]] * cfg.passes
+
+    def rounded_as_written(f, x):
+        return jax.jit(f).lower(x).compile({"xla_allow_excess_precision": False})(x)
+
+    grads = rounded_as_written(jax.grad(lambda p: ouro.loss_fn(cfg, p, tokens)), compute)
+    by_copy = rounded_as_written(
+        jax.grad(lambda c: _unrolled_loss(cfg, compute, c, tokens)),
+        [compute["blocks"]] * cfg.passes,
     )
     in_f32 = jax.tree_util.tree_map(
         lambda *g: sum(x.astype(jnp.float32) for x in g).astype(jnp.bfloat16), *by_copy

@@ -92,6 +92,10 @@ file (``models/mellum.py`` is one):
   runs: one ``lax.scan`` over the passes, each pass recomputed in the
   backward pass but for what ``KEPT`` names (a dense layer's down
   product), the weights' gradient summed over the passes in float32.
+  The exits' logits are NOT of that loop: the scan hands out every
+  ``h_t``, and after it one head (``_exits_nll``) walks the T exits,
+  multiplies each exit's logits once a step and computes its own
+  cotangents beside the cross entropy, keeping no logits.
   T = 1 is the plain model above: no gate, no loop.
 - a layer's MIXER as a kind (``AttentionKind.mixer``; ``models/ling.py`` has
   both): softmax attention as above, or
@@ -159,7 +163,7 @@ import contextlib
 import functools
 import math
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -169,10 +173,11 @@ from ..ops import flash_attention_rows
 from ..ops.delta_rule import LEAST_LOG_DECAY, causal_conv, gated_delta_rule
 from .transformer import (
     _dense_init,
+    _position_losses,
     _rmsnorm,
+    _softmax_less_target,
     masked_token_loss,
     next_token_loss,
-    next_token_losses,
 )
 
 
@@ -1385,7 +1390,8 @@ def _noised_hidden(
 #   the SwiGLU's down product (``mlp_down``)    25.4 ms / 0.81 GB = 32
 #   the ``wo`` product; q, k or v, each          9.4 ms / 0.81 GB = 11.6
 #   the gate or the up product, each            25.4 ms / 2.21 GB = 11.5
-#   a pass's logits                             35.8 ms / 3.22 GB = 11
+# (The exits' logits are no row of this table: they are not of the pass.
+# ``_exits_nll`` multiplies them after the loop, once a step, and keeps none.)
 # The down product is read in the backward pass only by the second norm and
 # the residual add after it (its own matmul's backward pass reads ``hidden``
 # and the weight), so kept, its matmul leaves the recomputed pass: measured,
@@ -1401,22 +1407,24 @@ KEPT = ("mlp_down",)
 
 
 def _looped(
-    cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array,
-    exit_of: Callable[[jax.Array], Any],
-) -> Tuple[jax.Array, Any, Optional[Dict[str, jax.Array]]]:
+    cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
+) -> Tuple[jax.Array, jax.Array, Optional[Dict[str, jax.Array]]]:
     """The stack ``cfg.passes`` times on the same weights. Returns, each
     with the passes as its first axis, the gates' logits (T, B, S) in
-    float32 and ``exit_of(h_t)``, what the caller wants of every exit -
-    computed INSIDE the pass, so that a pass's logits do not outlive it -
-    and the routers' sums over every pass and layer (None without experts).
+    float32 and the passes' ``h_t`` (T, B, S, D) in ``cfg.dtype`` - what an
+    exit's readout is applied to, AFTER the loop (``forward``,
+    ``_exits_nll``): no pass computes its logits, so none computes them
+    again - and the routers' sums over every pass and layer (None without
+    experts).
 
     One ``lax.scan`` over the passes with the weights closed over: the
-    compiled program holds one stack however many times it runs. The body
-    is under ``jax.checkpoint`` with a save policy by name: the backward
-    pass keeps ``h_t`` and what ``KEPT`` names (a dense layer's down
-    product, (B, S, D) in ``cfg.dtype`` a layer and pass) between the
-    passes and computes the rest of one pass's activations again when it
-    comes to it, so the memory is one pass's and T times the kept arrays.
+    compiled program holds one stack however many times it runs. The body -
+    the stack, the final norm and the gate - is under ``jax.checkpoint``
+    with a save policy by name: the backward pass keeps ``h_t`` and what
+    ``KEPT`` names (a dense layer's down product, (B, S, D) in ``cfg.dtype``
+    a layer and pass) between the passes and computes the rest of one
+    pass's activations again when it comes to it, so the memory is one
+    pass's and T times the kept arrays.
     A kept value is the one the forward scan computed, in the type it was
     computed in: the loss and every gradient are bit for bit what they are
     with nothing kept (on the CPU, ``tests/test_ouro.py``; the TPU compiler
@@ -1426,13 +1434,9 @@ def _looped(
     therefore handed in widened to float32 (a bf16 compute copy comes back
     as it was at every use, ``astype(cfg.dtype)``), each pass's gradient is
     added in float32 and the sum rounded once, as a framework that keeps
-    float32 ``.grad`` under bf16 autocast sums it. What ``exit_of`` closes
-    over - the readout - is NOT widened: its T contributions are added in
-    the type it comes in, bf16 for a bf16 compute copy (widened too, the
-    gradient program of ``ouro-2.6b-l6`` takes 7.61 GB of temporaries for
-    7.00 by the compiler's memory analysis, PR 43). Scopes: ``loop`` holds
+    float32 ``.grad`` under bf16 autocast sums it. Scopes: ``loop`` holds
     the scan, the layers' ``attn`` and ``mlp`` inside it as ever, with
-    ``exits`` (the gate) and whatever ``exit_of`` names."""
+    ``exits`` (the gate)."""
     blocks, ln_f, gate = jax.tree_util.tree_map(
         lambda w: w.astype(jnp.float32),
         (params["blocks"], params["ln_f"]["scale"], params["exit_gate"]),
@@ -1446,11 +1450,11 @@ def _looped(
                 "bsd,d->bs", h, gate["w"].astype(h.dtype),
                 preferred_element_type=jnp.float32,
             ) + gate["b"]
-        return h, (logit, exit_of(h), stats)
+        return h, (logit, h, stats)
 
     h = _embed(cfg, params, tokens)
     with jax.named_scope("loop"):
-        _, (logits, exits, stats) = jax.lax.scan(
+        _, (logits, hs, stats) = jax.lax.scan(
             # a scan's body is not CSE'd with its backward pass
             jax.checkpoint(
                 one_pass, prevent_cse=False,
@@ -1459,7 +1463,7 @@ def _looped(
         )
     if stats is not None:
         stats = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), stats)
-    return logits, exits, stats
+    return logits, hs, stats
 
 
 def exit_log_probs(gate_logits: jax.Array) -> jax.Array:
@@ -1507,11 +1511,9 @@ def forward(
             logits = _readout_product(cfg, params, x).astype(jnp.float32)
         return logits, total
 
-    def exit_of(h: jax.Array) -> jax.Array:  # the pass's norm is the final norm
-        with jax.named_scope("readout"):
-            return (h @ params["readout"].astype(cfg.dtype)).astype(jnp.float32)
-
-    gates, logits, total = _looped(cfg, params, tokens, exit_of)
+    gates, hs, total = _looped(cfg, params, tokens)
+    with jax.named_scope("readout"):  # the pass's norm is the final norm
+        logits = (hs @ params["readout"].astype(cfg.dtype)).astype(jnp.float32)
     with jax.named_scope("exits"):
         exit_probs = jnp.mean(jnp.exp(exit_log_probs(gates)), axis=(1, 2))
     return logits, dict(total or {}, exit_probs=exit_probs)
@@ -1552,27 +1554,97 @@ def _bias_pull(loss: jax.Array, stats: Dict[str, jax.Array]) -> jax.Array:
     return loss + (pull - jax.lax.stop_gradient(pull))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _exits_nll(
+    cfg: OlmoeConfig, readout: jax.Array, hs: jax.Array, targets: jax.Array,
+    weight: jax.Array,
+) -> jax.Array:
+    """``sum_t sum_pos weight_t CE_t``, float32: the T exits' cross entropy
+    (``CE_t`` (B, S) of the logits ``hs[t] @ readout`` against ``targets``,
+    ``next_token_losses``' reading of them: the product in ``cfg.dtype``,
+    unwidened, read once) under a weight (T, B, S) a position and exit.
+
+    The head of a looped model's exits, with a backward pass of its own.
+    A cross entropy enters the sum linearly, so with the weight in hand
+    everything its backward pass will be asked for is known while the
+    logits are there: exit by exit, beside ``CE_t``, the logits' cotangent
+    ``weight_t (softmax - onehot)`` (rounded to the logits' type, as
+    ``next_token_losses``' own backward pass rounds it) and its two
+    products, with the readout (``hs[t]``'s cotangent) and with ``hs[t]``
+    (the readout's, the T exits' added in float32 and rounded once, as the
+    stack's is in ``_looped``). The backward rule scales them by the scalar
+    that arrives; the weight's cotangent is that scalar times ``CE``. Three
+    products an exit and none computed twice, where autodiff of a readout
+    inside the checkpointed pass multiplied the logits again in the
+    backward scan; no logits are kept. Called outside differentiation it
+    computes the sum alone."""
+    return _exits_sweep(cfg, readout, hs, targets, weight, cotangents=False)[0]
+
+
+def _exits_sweep(cfg, readout, hs, targets, weight, cotangents):
+    """(the weighted sum, and with ``cotangents``: ``CE`` (T, B, S), the
+    cotangents of ``hs`` and of ``readout`` for an incoming 1). The exits
+    are walked in a Python loop, T being small and static: as a ``lax.scan``
+    the same head ran 10 ms a step slower in ``ouro-2.6b-l6`` (the compiler
+    stacks ``hs`` positions-minor for the loop's slices and the readout's
+    gradient pays for it; PERF.md section 6, PR 57), and the compiler's
+    schedule holds the exits' logits apart without one."""
+    w_out = readout.astype(cfg.dtype)
+    nll, d_hs, d_readout = [], [], 0.0
+    for h, w in zip(hs, weight):
+        with jax.named_scope("readout"):
+            logits = h @ w_out
+        with jax.named_scope("loss"):
+            losses, top, norm = _position_losses(logits, targets)
+        nll.append(losses)
+        if not cotangents:
+            continue
+        with jax.named_scope("loss"):
+            d_logits = _softmax_less_target((logits, targets, top, norm), w)
+        with jax.named_scope("readout"):
+            d_hs.append(jnp.einsum("bsv,dv->bsd", d_logits, w_out))
+            d_readout = d_readout + jnp.einsum(
+                "bsd,bsv->dv", h, d_logits, preferred_element_type=jnp.float32
+            )
+    with jax.named_scope("loss"):
+        nll = jnp.stack(nll)
+        total = jnp.sum(weight * nll)
+    if not cotangents:
+        return total, None
+    return total, (nll, jnp.stack(d_hs), d_readout.astype(readout.dtype))
+
+
+def _exits_nll_fwd(cfg, readout, hs, targets, weight):
+    return _exits_sweep(cfg, readout, hs, targets, weight, cotangents=True)
+
+
+def _exits_nll_bwd(cfg, res, g):
+    nll, d_hs, d_readout = res
+    return (
+        (g * d_readout).astype(d_readout.dtype), (g * d_hs).astype(d_hs.dtype),
+        None, g * nll,
+    )
+
+
+_exits_nll.defvjp(_exits_nll_fwd, _exits_nll_bwd)
+
+
 def _exits_loss(
     cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """A looped model's loss over its exits (module docstring) and the
-    routers' sums: each pass hands out its exit's cross entropy a position
-    (``next_token_losses``: the readout's product unwidened, read once)
-    and its gate's logit, two (B, S) float32 arrays."""
+    routers' sums: the loop hands out every pass's ``h_t`` and its gate's
+    logit; the exit distribution and its entropy are plain autodiff's, the
+    expected cross entropy is ``_exits_nll``'s, each position's weight on
+    each exit ``p_t / (B S)``."""
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-
-    def exit_of(h: jax.Array) -> jax.Array:
-        with jax.named_scope("readout"):
-            logits = h @ params["readout"].astype(cfg.dtype)
-        return next_token_losses(logits, targets)
-
-    gates, nll, stats = _looped(cfg, params, inputs, exit_of)
+    gates, hs, stats = _looped(cfg, params, inputs)
     with jax.named_scope("exits"):
         log_p = exit_log_probs(gates)
         p = jnp.exp(log_p)
-        expected = jnp.sum(p * nll, axis=0)
         entropy = -jnp.sum(p * log_p, axis=0)
-        return jnp.mean(expected - cfg.exit_entropy_coef * entropy), stats
+        expected = _exits_nll(cfg, params["readout"], hs, targets, p / targets.size)
+        return expected - cfg.exit_entropy_coef * jnp.mean(entropy), stats
 
 
 def _diffusion_loss(

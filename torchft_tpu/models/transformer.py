@@ -306,7 +306,7 @@ def next_token_losses(logits: jax.Array, targets: jax.Array) -> jax.Array:
     before its mean, by the same sweep and under the same rules - the
     logits read and kept in the type they came in, the cotangent (one
     number a position) back in that type. For a loss that weights the
-    positions (``olmoe.loss_fn`` over a looped model's exits)."""
+    positions (``masked_token_loss``; ``olmoe._exits_nll`` runs the sweep itself)."""
     with jax.named_scope("loss"):
         return _cross_entropies(logits, targets)
 
