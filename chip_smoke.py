@@ -409,6 +409,76 @@ def _check_delta_rule(name: str, S: int = 8192, H: int = 8, D: int = 128) -> Non
     ))
 
 
+def _check_ssd(
+    name: str, S: int = 4096, H: int = 64, P: int = 64, N: int = 128, chunk: int = 256,
+) -> None:
+    """``ops.ssd.ssd_scan`` at a Mamba-2 layer's shape in ``granite4h-ft1`` -
+    bf16 x, B and C, float32 steps as the mixer draws them (a softplus of a
+    unit normal over a bias of log U(1e-3, 0.1)) under rates of -1 to -16 -
+    compiled under the chip's DEFAULT matmul precision, the output and every
+    cotangent of autodiff's backward against the recurrence a position at a
+    time in float32 at ``highest`` (``benchmark/reference_granite.py``).
+    Plain XLA: no Mosaic call."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import reference_granite
+    from torchft_tpu.ops.ssd import ssd_scan
+
+    keys = jax.random.split(jax.random.PRNGKey(S + P), 8)
+    x, cot = (jax.random.normal(kk, (1, S, H, P)).astype(jnp.bfloat16) for kk in keys[:2])
+    B, C = (jax.random.normal(kk, (1, S, N)).astype(jnp.bfloat16) for kk in keys[2:4])
+    drawn = jnp.exp(jax.random.uniform(keys[4], (H,), jnp.float32, np.log(1e-3), np.log(0.1)))
+    dt = jax.nn.softplus(
+        jax.random.normal(keys[5], (1, S, H)) + drawn + jnp.log(-jnp.expm1(-drawn))
+    )
+    A = -jax.random.uniform(keys[6], (H,), jnp.float32, 1.0, 16.0)
+    D = jnp.ones((H,), jnp.float32)
+
+    def grad_of(fn):
+        def loss(x, dt, A, B, C, D):
+            out = fn(x, dt, A, B, C, D)
+            return jnp.sum(out.astype(jnp.float32) * cot.astype(jnp.float32)), out
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)), has_aux=True))
+
+    def recurrence(x, dt, A, B, C, D):
+        return jax.vmap(
+            lambda x, dt, B, C: reference_granite.recurrence(x, dt, A, B, C, D)
+        )(x, dt, B, C)
+
+    lowered = grad_of(lambda *a: ssd_scan(*a, chunk=chunk)).lower(x, dt, A, B, C, D)
+    if "tpu_custom_call" in lowered.as_text():
+        raise AssertionError(f"ssd {name}: a Mosaic call in the op")
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    compile_s = time.perf_counter() - t0
+    (_, out), grads = jax.block_until_ready(compiled(x, dt, A, B, C, D))
+    f32 = jnp.float32
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = jax.block_until_ready(grad_of(recurrence)(
+            x.astype(f32), dt, A, B.astype(f32), C.astype(f32), D
+        ))
+    errs = {}
+    for label, got, ref in zip(
+        ("out", "dx", "ddt", "dA", "dB", "dC", "dD"), (out,) + grads, (want,) + want_grads
+    ):
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        if not np.all(np.isfinite(got)):
+            raise AssertionError(f"ssd {name}: non-finite {label}")
+        err = float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+        errs[label] = round(err, 5)
+        if err > FLASH_TOL:
+            raise AssertionError(
+                f"ssd {name}: {label} differs from the recurrence by "
+                f"{err:.4f} of max|ref| (tolerance {FLASH_TOL})"
+            )
+    _say("kernels", (
+        f"ssd {name} B1 S{S} H{H} P{P} N{N} chunk {chunk}: compiled in {compile_s:.1f}s, "
+        f"max err / max|ref| {errs} <= {FLASH_TOL}"
+    ))
+
+
 def _check_wire_kernels(name: str, shape: Sequence[int], seed: int) -> None:
     """quantize_q8_ef / dequantize_q8 / cast_bf16 on one payload, compiled,
     against the numpy oracle of the CPU tests
@@ -551,6 +621,8 @@ def child_kernels() -> None:
     _check_heads_to_rows("sdar_block", "stated")
     # ling3-ft1's other mixer: the gated delta rule in chunks, plain XLA
     _check_delta_rule("ling_kda")
+    # granite4h-ft1's mixer: the state-space scan in chunks, plain XLA
+    _check_ssd("granite_ssd")
     # the big model's largest leaf (128 grid blocks) and an odd length
     # that ends mid-block
     _check_wire_kernels("big_leaf", (1024, 4096), seed=1)

@@ -164,6 +164,42 @@ class TestChipSmoke:
         with pytest.raises(AssertionError, match="differs from the recurrence"):
             chip_smoke._check_delta_rule("tiny", S=200, H=2, D=16)
 
+    def test_kernels_phase_checks_the_state_space_scan(self, monkeypatch, capsys):
+        """The kernels phase holds ``ssd_scan`` and autodiff's backward to
+        the recurrence at a Mamba-2 layer's shape in ``granite4h-ft1`` (the
+        ``granite_ssd`` line); here the same check in miniature, and a
+        decay planted wrong - the running sums left out of a chunk's carried
+        factor - fails it."""
+        from benchmark import common
+        from torchft_tpu.ops import ssd
+
+        sizes = common.load_json("configs", "granite4-h-micro-l10-v8.json")
+        cfg = common.load_by_name("families", sizes["family"]).build(sizes)
+        mamba = cfg.kinds[0].mixer
+        want = (
+            sizes["seq"] - 1, mamba.inner_heads, mamba.inner_head_dim, mamba.state, mamba.chunk
+        )
+        assert want == tuple(
+            inspect.signature(chip_smoke._check_ssd).parameters[n].default
+            for n in ("S", "H", "P", "N", "chunk")
+        )
+        with jax.default_matmul_precision("highest"):
+            chip_smoke._check_ssd("tiny", S=100, H=2, P=8, N=16, chunk=16)
+        assert "ssd tiny B1 S100 H2 P8 N16 chunk 16" in capsys.readouterr().out
+        right = ssd.jnp.exp
+
+        class Wrong:  # ``jnp`` with an ``exp`` that forgets the carried decay
+            def __getattr__(self, name):
+                return getattr(jnp, name)
+
+            @staticmethod
+            def exp(x):
+                return jnp.ones_like(x) if x.ndim == 4 and x.shape[-1] == 1 else right(x)
+
+        monkeypatch.setattr(ssd, "jnp", Wrong())
+        with pytest.raises(AssertionError, match="differs from the recurrence"):
+            chip_smoke._check_ssd("tiny", S=100, H=2, P=8, N=16, chunk=16)
+
     def test_without_a_chip_it_fails_and_says_so(self):
         # the tier-1 environment pins the CPU; the script overrides that
         # for its children (JAX_PLATFORMS=tpu) and must find no chip

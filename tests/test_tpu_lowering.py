@@ -583,3 +583,44 @@ def test_the_dsv2_cells_step_compiles_for_the_chip_with_what_its_family_states(o
     common.require_mosaic(lowered, 10, "dsv2lite-ft1")
     text = _compiled_text(lowered)
     assert text.count('custom_call_target="tpu_custom_call"') == 10
+
+
+def test_the_granite_cells_step_compiles_for_the_chip_with_what_its_family_states(one_chip, monkeypatch):
+    """``granite4h-ft1``'s gradient step as the generators lower it
+    (``mixed_precision_grad``), at the published head sizes (attention 4
+    query heads over 1 key/value head of 64 at 1/64; Mamba-2 heads of 64
+    over a state of 128 in chunks of 256) and cut elsewhere - the rehearsal's
+    four layers, a width of 256, 1,024 positions - through the TPU compiler
+    for the described chip, every layer recomputed: the Mosaic calls are the
+    ONE attention layer's ``flash_fwd``, its recomputed ``flash_fwd`` and its
+    ``flash_bwd``, THREE, which the family states (its count is no ``2 x
+    layers``, so the benchmark's own case cannot be borrowed) and which the
+    compiler keeps apart (the recomputed forward call is not merged with the
+    first); the state-space scan compiles as plain XLA."""
+    from benchmark import common
+
+    monkeypatch.setattr(
+        sys.modules["torchft_tpu.ops.flash_attention"], "_pick_interpret", lambda _i: False
+    )
+    sizes = common.load_json("configs", "granite4-h-micro-l10-v8.json")
+    sizes = {**sizes, **sizes["rehearsal"], "hidden_size": 256, "num_attention_heads": 4,
+             "num_key_value_heads": 1, "attention_multiplier": 0.015625, "mamba_n_heads": 8,
+             "mamba_d_head": 64, "mamba_d_state": 128, "mamba_chunk_size": 256, "seq": 1025}
+    family = common.load_family(sizes["family"])
+    cfg = family.build(sizes)
+    assert [type(k.mixer).__name__ for k in cfg.kinds] == ["Mamba2", "Mamba2", "NoneType", "Mamba2"]
+    assert cfg.recompute_layers and (cfg.head_dim, cfg.kinds[2].softmax_scale) == (64, 1 / 64)
+    assert family.lowered_mosaic_calls(cfg) == 3
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), tree
+        )
+
+    params = on_chip(jax.eval_shape(lambda: family.init(cfg, jax.random.PRNGKey(0))))
+    tokens = jax.ShapeDtypeStruct((1, sizes["seq"]), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(common.mixed_precision_grad(family, cfg)).lower(params, tokens)
+    common.require_mosaic(lowered, 3, "granite4h-ft1")
+    assert lowered.as_text().count('kernel_name = "flash_fwd"') == 2
+    text = _compiled_text(lowered)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3

@@ -1,4 +1,4 @@
-from torchft_tpu.models import cnn, dsv2, ling, mellum, moe, olmoe, ouro, sdar
+from torchft_tpu.models import cnn, dsv2, granite, ling, mellum, moe, olmoe, ouro, sdar
 from torchft_tpu.models.cnn import CNNConfig, tiny_cnn_config
 from torchft_tpu.models.moe import MoEConfig, tiny_moe_config
 from torchft_tpu.models.olmoe import OlmoeConfig, tiny_olmoe_config
@@ -23,6 +23,7 @@ __all__ = [
     "dsv2",
     "tiny_cnn_config",
     "forward",
+    "granite",
     "init_params",
     "ling",
     "loss_fn",

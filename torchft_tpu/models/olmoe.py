@@ -119,7 +119,27 @@ file (``models/mellum.py`` is one):
   q and k, the gate and a factor on the softmax scale are data of ``Mla``,
   and the rotated numbers turn at the kind's YaRN frequencies where it
   names them (``models/dsv2.py``: DeepSeek-V2's own layer has neither norm
-  nor gate, and YaRN with ``mscale ** 2`` on the scale);
+  nor gate, and YaRN with ``mscale ** 2`` on the scale); or a *state-space
+  mixer* (``Mamba2``; Mamba-2, arXiv:2405.21060; ``mamba2_mixer``;
+  ``models/granite.py`` has it nine layers in ten): ``[z | xBC | dt] = W_in
+  u``; ``xBC = SiLU(conv(xBC) + b)``, depthwise and causal, ``[x | B | C] =
+  xBC`` with ``x`` as ``inner_heads`` heads of ``inner_head_dim``; a step a
+  head ``dt = softplus(dt + dt_bias)`` in float32, a rate ``A = -exp(a_log)``;
+  a state a head, ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t
+  C_t + D x_t``, in chunks (``ops/ssd.py``); ``y = W_o RMSNorm(y SiLU(z))``,
+  the gate BEFORE a norm whose one statistic spans every head's channels. The
+  mixer's head count and width are ITS data, not ``n_heads`` and ``head_dim``
+  (the attention layers'). Softmax attention itself may go without the rotary
+  embedding (``AttentionKind.rotary``) and at a stated softmax scale
+  (``softmax_scale``);
+- a SCALED RESIDUAL STREAM (muP's multipliers as Granite's ``config.json``
+  names them): the embedding's rows times ``embedding_multiplier``, every
+  sublayer's output times ``residual_multiplier`` before it joins the stream,
+  the logits over ``logits_scaling``; a readout TIED to the embedding
+  (``tied_readout``: no ``readout`` leaf, and the embedding's gradient is the
+  lookup's plus the readout's); and a stack RECOMPUTED A LAYER
+  (``recompute_layers``, ``_stack``) where a step's activations do not fit
+  beside its state;
 - the router's other form (``router``: a ``SigmoidRouter``; DeepSeek-V3,
   arXiv:2412.19437; ``_sigmoid_choice``): each expert's score ``s =
   sigmoid(r)``; the K chosen on ``s + bias`` (the bias a leaf of ``moe``
@@ -171,6 +191,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import flash_attention_rows
 from ..ops.delta_rule import LEAST_LOG_DECAY, causal_conv, gated_delta_rule
+from ..ops.ssd import ssd_scan
 from .transformer import (
     _dense_init,
     _position_losses,
@@ -239,6 +260,32 @@ class Mla:
 
 
 @dataclass(frozen=True)
+class Mamba2:
+    """A state-space mixer (module docstring): ``inner_heads`` heads of
+    ``inner_head_dim`` channels - the mixer's OWN, whatever ``n_heads`` the
+    attention layers beside it have - each with a state of ``inner_head_dim``
+    x ``state``, one group of input and output maps for all of them,
+    ``conv_taps`` positions under the one short convolution and ``chunk``
+    positions a chunk of the scan (``ops/ssd.py``)."""
+
+    state: int
+    conv_taps: int
+    chunk: int
+    inner_heads: int
+    inner_head_dim: int
+
+    @property
+    def inner(self) -> int:
+        """The heads' channels together: what ``z`` and ``x`` are wide."""
+        return self.inner_heads * self.inner_head_dim
+
+    @property
+    def convolved(self) -> int:
+        """The channels under the convolution: ``x | B | C``."""
+        return self.inner + 2 * self.state
+
+
+@dataclass(frozen=True)
 class SigmoidRouter:
     """The router that is no softmax (module docstring): a sigmoid score an
     expert, the experts in ``groups`` of which a token's ``kept`` best are
@@ -258,16 +305,20 @@ class AttentionKind:
     B: the layer's input is a clean and a noised copy of a sequence, L
     positions each, both at rotary positions 0..L-1, under the
     block-diffusion mask in blocks of B (``flash_attention``:
-    ``block_mask``) and no causal one. ``mixer`` a ``Kda`` or an ``Mla``:
-    that mixer, causal, with its own weights (``_MIXERS``). ``name`` is the
-    ``jax.named_scope`` the layer's mixer runs under, inside ``attn``; the
-    unnamed kind is OLMoE's and adds no scope."""
+    ``block_mask``) and no causal one; ``rotary`` False: q and k carry no
+    position signal at all (NoPE); ``softmax_scale``: what the scores are
+    multiplied by (None: ``head_dim ** -0.5``). ``mixer`` a ``Kda``, an
+    ``Mla`` or a ``Mamba2``: that mixer, causal, with its own weights
+    (``_MIXERS``). ``name`` is the ``jax.named_scope`` the layer's mixer runs
+    under, inside ``attn``; the unnamed kind is OLMoE's and adds no scope."""
 
     name: Optional[str] = None
     window: Optional[int] = None
     yarn: Optional[Yarn] = None
     block: Optional[int] = None
-    mixer: Optional[Any] = None  # a Kda or an Mla; None: softmax attention
+    mixer: Optional[Any] = None  # a Kda, an Mla or a Mamba2; None: softmax attention
+    rotary: bool = True
+    softmax_scale: Optional[float] = None
 
 
 @dataclass(frozen=True)
@@ -307,6 +358,12 @@ class OlmoeConfig:
     router: Optional[SigmoidRouter] = None  # None: a softmax over the experts
     shared_width: Optional[int] = None  # one expert every token meets; None: none
     seq_balance: bool = False  # the balance loss a sequence and a layer (``aux_losses``)
+    # the scaled residual stream (module docstring); 1, 1, 1 and untied: OLMoE's
+    embedding_multiplier: float = 1.0  # on the embedding's rows
+    residual_multiplier: float = 1.0  # on a sublayer's output, before it joins the stream
+    logits_scaling: float = 1.0  # what the logits are DIVIDED by
+    tied_readout: bool = False  # the readout is the embedding's transpose: no ``readout`` leaf
+    recompute_layers: bool = False  # each layer computed again in the backward pass (``_stack``)
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -331,6 +388,13 @@ class OlmoeConfig:
             raise ValueError("a diffusion model is not looped and names a mask token of its vocabulary")
         if self.router is not None and self.n_experts % self.router.groups:
             raise ValueError("the experts do not fall into whole groups")
+        if self.passes > 1 and (
+            self.tied_readout or self.logits_scaling != 1.0 or self.recompute_layers
+        ):
+            raise ValueError(
+                "a looped model's exits read an untied, unscaled readout, and its"
+                " recomputation is by pass"
+            )
 
     @property
     def kv_heads(self) -> int:
@@ -438,6 +502,31 @@ def _mla_init(cfg: OlmoeConfig, kind: AttentionKind, bk: jax.Array) -> Dict[str,
     return p
 
 
+def _mamba2_init(cfg: OlmoeConfig, kind: AttentionKind, bk: jax.Array) -> Dict[str, Any]:
+    """A Mamba-2 mixer's weights: ONE map of ``d`` to ``[z | x B C | dt]``
+    (the gate, the convolved channels, a step a head), the convolution's taps
+    and bias a channel of ``x B C``, and a head's step bias, rate and skip as
+    Mamba-2's code draws them - ``dt_bias`` the inverse softplus of ``dt ~
+    log U(1e-3, 0.1)``, ``a_log = log U(1, 16)``, ``d`` 1 - the gated norm's
+    scale over all inner channels, ``wo``."""
+    d, m = cfg.d_model, kind.mixer
+    inner, conved = m.inner, m.convolved
+    ks = jax.random.split(bk[0], 6)
+    dt = jnp.exp(jax.random.uniform(
+        ks[3], (m.inner_heads,), jnp.float32, math.log(1e-3), math.log(0.1)
+    ))
+    return {
+        "w_in": _dense_init(ks[0], (d, inner + conved + m.inner_heads), d ** -0.5),
+        "conv": _dense_init(ks[1], (m.conv_taps, conved), m.conv_taps ** -0.5),
+        "conv_bias": _dense_init(ks[2], (conved,), m.conv_taps ** -0.5),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus(dt_bias) = dt
+        "a_log": jnp.log(jax.random.uniform(ks[4], (m.inner_heads,), jnp.float32, 1.0, 16.0)),
+        "d": _ones(m.inner_heads),
+        "norm": _ones(inner),
+        "wo": _dense_init(ks[5], (inner, d), inner ** -0.5),
+    }
+
+
 def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
     """f32 master params; matmuls cast to cfg.dtype at use."""
     d, f, e = cfg.d_model, cfg.expert_width, cfg.n_experts
@@ -482,8 +571,9 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
         "embed": _dense_init(keys[0], (cfg.vocab_size, d), scale),
         "blocks": blocks,
         "ln_f": {"scale": _ones(d)},
-        "readout": _dense_init(keys[1], (d, cfg.vocab_size), scale),
     }
+    if not cfg.tied_readout:
+        params["readout"] = _dense_init(keys[1], (d, cfg.vocab_size), scale)
     if cfg.passes > 1:  # the exits' gate: one map of d to 1, with a bias
         gate_key = jax.random.fold_in(key, 2 + cfg.n_layers)
         params["exit_gate"] = {
@@ -706,10 +796,13 @@ def attention(
     # query head i meets key/value head i // (h / kv): each is copied to
     # its query heads (a grouped kernel would read it once)
     norm = (cfg.qk_norm_per_head, cfg.rms_norm_eps)
-    of_q, of_kv = HeadsToRows(h, 1, *norm, dh ** -0.5), HeadsToRows(kv, h // kv, *norm)
+    softmax_scale = dh ** -0.5 if kind.softmax_scale is None else kind.softmax_scale
+    of_q, of_kv = HeadsToRows(h, 1, *norm, softmax_scale), HeadsToRows(kv, h // kv, *norm)
     scales = (p["q_norm"], p["k_norm"]) if cfg.qk_norm else (None, None)
     with jax.named_scope("qk_rows"):
-        tables = rotary_tables(S, dh, cfg.rope_theta, kind.yarn, stated)
+        tables = None
+        if kind.rotary:
+            tables = rotary_tables(S, dh, cfg.rope_theta, kind.yarn, stated)
         q = _heads_to_rows(of_q, q, scales[0], tables)
         k = _heads_to_rows(of_kv, k, scales[1], tables)
         v = _heads_to_rows(of_kv, v, None, None)
@@ -830,11 +923,52 @@ def mla_mixer(
         return out.reshape(B, S, h * dh) @ p["wo"].astype(cfg.dtype)
 
 
+def _gated_norm(cfg: OlmoeConfig, p: Dict[str, Any], y: jax.Array, z: jax.Array) -> jax.Array:
+    """``N_g(y SiLU(z))`` in float32: the gate BEFORE the norm, and one
+    statistic over every head's channels."""
+    f32 = jnp.float32
+    y = y.astype(f32) * jax.nn.silu(z.astype(f32))
+    return _rmsnorm(y, p["norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+
+
+def mamba2_mixer(
+    cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array, kind: AttentionKind
+) -> jax.Array:
+    """A Mamba-2 mixer (module docstring) over its own heads: the one map,
+    the short convolution with its bias and SiLU over ``x B C``, the step a
+    head, the state-space scan in chunks (``ops/ssd.py``), the norm over all
+    inner channels of the GATED output, ``wo``."""
+    B, S, _ = x.shape
+    m, f32 = kind.mixer, jnp.float32
+    h, n, inner = m.inner_heads, m.state, m.inner
+    with jax.named_scope("proj"):
+        z, xbc, dt = jnp.split(
+            x @ p["w_in"].astype(cfg.dtype), (inner, inner + m.convolved), axis=-1
+        )
+    with jax.named_scope("conv"):
+        xbc = jax.nn.silu(causal_conv(xbc, p["conv"]) + p["conv_bias"].astype(f32))
+        u, to_state, from_state = jnp.split(xbc.astype(cfg.dtype), (inner, inner + n), axis=-1)
+    with jax.named_scope("gates"):
+        # float32 from the product on: the decays' running sums are taken of it
+        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+        rate = -jnp.exp(p["a_log"].astype(f32))
+    with jax.named_scope("scan"):
+        y = ssd_scan(
+            u.reshape(B, S, h, m.inner_head_dim), dt, rate, to_state, from_state,
+            p["d"], chunk=m.chunk,
+        )
+    with jax.named_scope("norm"):
+        y = _gated_norm(cfg, p, y.reshape(B, S, inner), z)
+    with jax.named_scope("out"):
+        return y @ p["wo"].astype(cfg.dtype)
+
+
 # a kind's ``mixer`` -> (its weights from a layer's keys, the mixer itself)
 _MIXERS = {
     type(None): (_attention_init, attention),
     Kda: (_kda_init, kda_mixer),
     Mla: (_mla_init, mla_mixer),
+    Mamba2: (_mamba2_init, mamba2_mixer),
 }
 
 
@@ -1300,8 +1434,13 @@ def _block(
     eps = cfg.rms_norm_eps
 
     def second(y: jax.Array, name: str) -> jax.Array:
-        """A sublayer's output through the sandwich's second norm."""
-        return _rmsnorm(y, p[name]["scale"], eps) if cfg.sandwich_norms else y
+        """A sublayer's output through the sandwich's second norm, and as
+        it joins the stream: times ``residual_multiplier``, in float32 and
+        rounded once."""
+        y = _rmsnorm(y, p[name]["scale"], eps) if cfg.sandwich_norms else y
+        if cfg.residual_multiplier != 1.0:
+            y = (y.astype(jnp.float32) * cfg.residual_multiplier).astype(y.dtype)
+        return y
 
     of_kind = jax.named_scope(kind.name) if kind.name else contextlib.nullcontext()
     with jax.named_scope("attn"), of_kind:
@@ -1321,10 +1460,28 @@ def _stack(
     cfg: OlmoeConfig, blocks: Any, x: jax.Array
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """The layers in order: (B, S, D) -> (the last block's output, the
-    routers' sums over the layers that have experts; None where none has)."""
+    routers' sums over the layers that have experts; None where none has).
+    Under ``cfg.recompute_layers`` each layer is under ``jax.checkpoint``:
+    the backward pass keeps a layer's input and computes the layer again
+    when it comes to it, its Mosaic calls too - the memory is one layer's
+    activations and ``n_layers`` inputs. (No save policy: ``_looped``'s
+    ``KEPT`` names the down product, whose VALUE a backward pass reads only
+    under a sandwich's second norm; no stack recomputed by layer has one, so
+    the policy would keep nothing.) The recomputed stack is scoped
+    ``layers``."""
     total = None
+    # a checkpoint's operations are named by the scope AROUND it (``transpose(
+    # jvp(layers))/checkpoint/rematted_computation/attn/..``): under none, a
+    # trace's reader finds no name at the head of the path and takes the
+    # layer's backward pass for unscoped (the reader's to mend, ROADMAP
+    # W14(i); this scope goes then)
+    around = jax.named_scope("layers") if cfg.recompute_layers else contextlib.nullcontext()
     for kind, width, p in zip(cfg.kinds, cfg.ff, blocks):
-        x, stats = _block(cfg, p, x, kind, width)
+        layer = functools.partial(_block, cfg, kind=kind, width=width)
+        if cfg.recompute_layers:
+            layer = jax.checkpoint(layer)
+        with around:
+            x, stats = layer(p, x)
         if stats is not None:
             total = stats if total is None else jax.tree_util.tree_map(jnp.add, total, stats)
     return x, total
@@ -1332,7 +1489,10 @@ def _stack(
 
 def _embed(cfg: OlmoeConfig, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
     with jax.named_scope("embed"):
-        return params["embed"].astype(cfg.dtype)[tokens]
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(cfg.dtype)
+        return x
 
 
 def _hidden(
@@ -1482,11 +1642,20 @@ def exit_log_probs(gate_logits: jax.Array) -> jax.Array:
 def _readout_product(
     cfg: OlmoeConfig, params: Dict[str, Any], x: jax.Array
 ) -> jax.Array:
-    """Final norm, then the untied readout matmul: logits (..., V) in
-    ``cfg.dtype``, the type the product is computed in. ``forward`` widens
-    it to float32; ``loss_fn`` hands it to ``next_token_loss`` as it is."""
+    """Final norm, then the readout matmul - with a matrix of its own, or
+    under ``cfg.tied_readout`` with the embedding's transpose, whose gradient
+    then has the readout's term beside the lookup's - over
+    ``cfg.logits_scaling``: logits (..., V) in ``cfg.dtype``, the type the
+    product is computed in. ``forward`` widens it to float32; ``loss_fn``
+    hands it to ``next_token_loss`` as it is."""
     x = _rmsnorm(x, params["ln_f"]["scale"], cfg.rms_norm_eps)
-    return x @ params["readout"].astype(cfg.dtype)
+    if cfg.tied_readout:
+        logits = jnp.einsum("...d,vd->...v", x, params["embed"].astype(cfg.dtype))
+    else:
+        logits = x @ params["readout"].astype(cfg.dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = (logits.astype(jnp.float32) / cfg.logits_scaling).astype(logits.dtype)
+    return logits
 
 
 def forward(
