@@ -19,6 +19,7 @@ precision) a model of width 64 is held to 3e-2 on the loss and 0.1 on the
 gradient norm.
 """
 
+import collections
 import dataclasses
 import json
 import os
@@ -486,26 +487,125 @@ def test_recomputation_changes_no_bit_of_the_loss_and_the_last_of_a_gradient(dty
     )
 
 
-def test_a_recomputed_layer_keeps_its_input_alone():
-    """What the backward pass of the recomputed stack holds between the two
-    passes: per layer the layer's input, (B, S, D) - no projection, no decay
-    matrix, nothing as wide as the mixer's inner channels. (``KEPT``'s down
-    product is kept where the backward pass reads its VALUE, under a
-    sandwich's second norm; here it meets a constant and an add, and nothing
-    reads it.)"""
-    tokens = _tokens(batch=1, seq=33)
-    params = _weights(BF16)
+def _residuals(loss, params):
+    """The shapes the backward pass of ``loss(params)`` holds between its
+    two passes, the arguments (the weights) left out."""
     from jax._src.ad_checkpoint import saved_residuals as saved
 
+    return collections.Counter(
+        tuple(aval.shape) for aval, why in saved(loss, params) if "argument" not in why
+    )
+
+
+def _recomputed_case(batch=1, seq=32):
+    """The recomputed tiny model with what the tests of its save policy
+    count: (the configuration, its loss of the weights, the weights, the
+    gradient's forward products, the shapes of the stream and of a SwiGLU's
+    hidden rows, the number of Mamba layers)."""
     cfg = granite.tiny_granite_config(True)
-    shapes = [
-        tuple(aval.shape) for aval, why in saved(lambda p: granite.loss_fn(cfg, p, tokens), params)
-        if "argument" not in why
-    ]
-    inner_wide = [s for s in shapes if s and s[-1] > max(cfg.d_model, cfg.vocab_size)]
-    square = [s for s in shapes if len(s) >= 2 and s[-1] == s[-2] == 16]
-    assert not inner_wide and not square, shapes
-    assert sum(s == (1, 32, cfg.d_model) for s in shapes) >= cfg.n_layers
+    tokens, params = _tokens(batch=batch, seq=seq + 1), _weights(BF16)
+
+    def products(of=cfg):
+        return _forward_products(of, _products(of, params, tokens), batch, seq)
+
+    return (
+        cfg, lambda p: granite.loss_fn(cfg, p, tokens), params, products,
+        (batch, seq, cfg.d_model), (batch, seq, cfg.ff[0]),
+        sum(isinstance(kind.mixer, olmoe.Mamba2) for kind in cfg.kinds),
+    )
+
+
+def _products(cfg, params, tokens):
+    """Every matrix product of the loss's gradient, forward, recomputed and
+    backward, nested programs included: (the left shape, the right shape,
+    the contracted axes) -> how many the jaxpr holds (a loop's body once)."""
+    found = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                left, right = (tuple(v.aval.shape) for v in eqn.invars)
+                found[left, right, eqn.params["dimension_numbers"][0]] += 1
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    compute = jax.tree_util.tree_map(lambda l: l.astype(cfg.dtype), params)
+    walk(jax.make_jaxpr(jax.grad(lambda p: granite.loss_fn(cfg, p, tokens)))(compute).jaxpr)
+    return found
+
+
+def _forward_products(cfg, products, batch, seq):
+    """Of ``_products``, the forward products ``x W`` a layer by what they
+    are: the SwiGLU's gate and up (one shape), a Mamba mixer's ``wo``, its
+    map to ``[z | xBC | dt]``, and the scan's (the decays' square pairs)."""
+    mixer = MAMBA.mixer
+    wide = 2 * mixer.inner + 2 * mixer.state + mixer.inner_heads
+    rows, last = (batch, seq), ((2,), (0,))
+
+    def onto(k, n):
+        return products[(*rows, k), (k, n), last]
+
+    return {
+        "gate_up": onto(cfg.d_model, cfg.ff[0]),
+        "wo": onto(mixer.inner, cfg.d_model),
+        "map": onto(cfg.d_model, wide),
+        "scan": sum(
+            count for (left, right, _), count in products.items()
+            if {left[-2:], right[-2:]} & {(mixer.chunk, mixer.chunk)}
+        ),
+    }
+
+
+def test_a_recomputed_layer_keeps_its_input_and_what_is_named(monkeypatch):
+    """What the backward pass of the recomputed stack holds between the two
+    passes: per layer the layer's input and exactly the products
+    ``STACK_KEPT`` names - the mixer's output, (B, S, D), and the SwiGLU's
+    gate and up products, (B, S, ff) each - no decay matrix, nothing as wide
+    as the Mamba map's product, no residual of a flash kernel. And in the
+    gradient's jaxpr no gate, up or ``wo`` product is computed a second
+    time, while the Mamba map's and the scan's are as often as with nothing
+    kept."""
+    cfg, loss, params, products, stream, hidden, mambas = _recomputed_case()
+    assert olmoe.STACK_KEPT == ("mixer_out", "mlp_gate", "mlp_up")  # no ``flash_out``, ``flash_lse``
+
+    kept, made = _residuals(loss, params), products()
+    inner_wide = [s for s in kept if s and s[-1] > max(cfg.d_model, cfg.vocab_size)]
+    square = [s for s in kept if len(s) >= 2 and s[-1] == s[-2] == MAMBA.mixer.chunk]
+    assert not inner_wide and not square, kept
+    assert kept[hidden] == 2 * cfg.n_layers and kept[stream] >= 2 * cfg.n_layers
+    assert made["gate_up"] == 2 * cfg.n_layers and made["wo"] == mambas
+
+    # against the layer's input alone (a policy that names nothing): the
+    # named products and NOTHING else - no flash residual, no other shape
+    monkeypatch.setattr(olmoe, "STACK_KEPT", ())
+    bare, again = _residuals(loss, params), products()
+    assert kept - bare == {stream: cfg.n_layers, hidden: 2 * cfg.n_layers}
+    assert not bare - kept and bare[stream] >= cfg.n_layers and not bare[hidden]
+    assert again["gate_up"] == 4 * cfg.n_layers and again["wo"] == 2 * mambas
+    assert (made["map"], made["scan"]) == (again["map"], again["scan"])
+    whole = products(granite.tiny_granite_config(False))
+    assert made["map"] == 2 * mambas == 2 * whole["map"] and whole["scan"] < made["scan"]
+
+
+@pytest.mark.parametrize("name", ["mixer_out", "mlp_gate", "mlp_up"])
+def test_a_name_out_of_the_tuple_is_computed_again(name, monkeypatch):
+    """Each name of ``STACK_KEPT`` is carried by a product and read by the
+    policy: taken out of the tuple, its product is no longer among the
+    residuals and is computed a second time; the other two stay."""
+    cfg, loss, params, products, stream, hidden, mambas = _recomputed_case()
+    assert name in olmoe.STACK_KEPT
+    kept = _residuals(loss, params)
+    monkeypatch.setattr(olmoe, "STACK_KEPT", tuple(n for n in olmoe.STACK_KEPT if n != name))
+    gone, made = kept - _residuals(loss, params), products()
+    if name == "mixer_out":
+        assert gone == {stream: cfg.n_layers}
+        assert (made["wo"], made["gate_up"]) == (2 * mambas, 2 * cfg.n_layers)
+    else:
+        assert gone == {hidden: cfg.n_layers}
+        assert (made["wo"], made["gate_up"]) == (mambas, 3 * cfg.n_layers)
 
 
 def test_a_looped_model_does_not_take_the_new_fields():
@@ -753,6 +853,53 @@ def test_the_older_configurations_never_meet_the_new_fields(model, monkeypatch):
     for kind in cfg.kinds:
         assert kind.rotary and kind.softmax_scale is None
         assert not isinstance(kind.mixer, olmoe.Mamba2)
+
+
+@pytest.mark.parametrize("model", sorted(OLDER))
+def test_the_older_configurations_lower_to_the_text_they_had_without_the_names(model, monkeypatch):
+    """A stack that is not recomputed a layer meets no policy that reads
+    ``STACK_KEPT``'s names, and a name lowers to no operation: with the
+    three names taken off their products the gradient step lowers to the
+    same text, letter for letter. The looped model runs ``_stack`` INSIDE
+    ``_looped``'s checkpoint, whose ``KEPT`` names none of the three: it
+    holds its down products between the passes and nothing of theirs."""
+    cfg = OLDER[model]()
+    params = olmoe.init_params(cfg, jax.random.PRNGKey(0))
+    seq = 32 if cfg.diffusion_block else 33
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, seq), 0, cfg.vocab_size, jnp.int32)
+    compute = jax.tree_util.tree_map(lambda l: l.astype(jnp.bfloat16), params)
+
+    def lowered():
+        text = jax.jit(jax.grad(lambda p: olmoe.loss_fn(cfg, p, tokens))).lower(compute).as_text()
+        # a private function's number counts the lowering's rules as they
+        # run: a name's moves it by one and leaves no operation
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    def residuals():
+        return _residuals(lambda p: olmoe.loss_fn(cfg, p, tokens), compute)
+
+    text, held = lowered(), residuals()
+    assert not set(olmoe.STACK_KEPT) & set(olmoe.KEPT)
+    named = olmoe.checkpoint_name
+    met = set()
+
+    def unnamed(x, name):
+        met.add(name)
+        return x if name in olmoe.STACK_KEPT else named(x, name)
+
+    monkeypatch.setattr(olmoe, "checkpoint_name", unnamed)
+    assert lowered() == text
+    assert "mixer_out" in met  # the patch is the one the program calls
+    assert residuals() == held
+    if cfg.passes > 1:
+        monkeypatch.undo()
+        wide = (cfg.passes, 2, 32, cfg.ff[0])
+        down = (cfg.passes, 2, 32, cfg.d_model)
+        assert not held[wide] and held[down] >= cfg.n_layers
+        monkeypatch.setattr(olmoe, "KEPT", ())
+        assert held - residuals() == {down: cfg.n_layers}  # ``mlp_down`` a layer, no more
+        monkeypatch.setattr(olmoe, "KEPT", ("mlp_down",) + olmoe.STACK_KEPT)
+        assert residuals() - held == {down: cfg.n_layers, wide: 2 * cfg.n_layers}
 
 
 # ---------------------------------------------------------------------------

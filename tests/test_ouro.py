@@ -550,9 +550,10 @@ def test_a_looped_expert_layer_names_nothing_and_keeps_nothing():
 def test_without_a_loop_a_name_lowers_to_nothing(model, monkeypatch):
     """T = 1: there is no checkpoint, and the lowered text of the loss's
     gradient is the text with ``checkpoint_name`` patched to the identity -
-    the sparse cells (experts in every layer: no name is ever met) and a
-    plain dense decoder (the name is met and lowers to no operation) pay
-    nothing for what a looped pass keeps."""
+    the sparse cells (experts in every layer: the mixer's output is the one
+    name met) and a plain dense decoder (the SwiGLU's three are met too, and
+    each lowers to no operation) pay nothing for what a looped pass or a
+    stack recomputed a layer keeps."""
     from torchft_tpu.models import mellum
 
     cfg = {
@@ -569,9 +570,12 @@ def test_without_a_loop_a_name_lowers_to_nothing(model, monkeypatch):
     named = []
     monkeypatch.setattr(olmoe, "checkpoint_name", lambda x, name: named.append(name) or x)
     again = lowered()
-    assert named == ["mlp_down"] * sum(width is not None for width in cfg.ff)
-    if named:  # a name met moves the numbers the private functions after it carry
-        text, again = (re.sub(r"(@\w+?)_\d+\b", r"\1", t) for t in (text, again))
+    assert named == [
+        name for width in cfg.ff
+        for name in ("mixer_out", *(("mlp_gate", "mlp_up", "mlp_down") if width is not None else ()))
+    ]
+    # a name met moves the numbers the private functions after it carry
+    text, again = (re.sub(r"(@\w+?)_\d+\b", r"\1", t) for t in (text, again))
     assert again == text
 
 

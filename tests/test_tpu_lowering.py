@@ -591,7 +591,8 @@ def test_the_granite_cells_step_compiles_for_the_chip_with_what_its_family_state
     query heads over 1 key/value head of 64 at 1/64; Mamba-2 heads of 64
     over a state of 128 in chunks of 256) and cut elsewhere - the rehearsal's
     four layers, a width of 256, 1,024 positions - through the TPU compiler
-    for the described chip, every layer recomputed: the Mosaic calls are the
+    for the described chip, every layer recomputed (``STACK_KEPT`` names no
+    kernel's result, so its flash call too): the Mosaic calls are the
     ONE attention layer's ``flash_fwd``, its recomputed ``flash_fwd`` and its
     ``flash_bwd``, THREE, which the family states (its count is no ``2 x
     layers``, so the benchmark's own case cannot be borrowed) and which the

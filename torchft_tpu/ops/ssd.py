@@ -53,9 +53,11 @@ relative error of ``L_ts`` - far under a bf16 rounding.
 
 The backward pass is autodiff's through this form. A layer that holds this op
 is recomputed in the backward pass where memory is short
-(``OlmoeConfig.recompute_layers``), so what the forward keeps lives for one
-layer: the decays (B, S / chunk, H, chunk, chunk) in float32 are the largest,
-0.27 GB at 4,096 positions and 64 heads.
+(``OlmoeConfig.recompute_layers``; the stack's save policy, ``STACK_KEPT`` in
+``models/olmoe.py``, names nothing of this op, so its forward runs again), so
+what the forward keeps lives for one layer: the decays (B, S / chunk, H,
+chunk, chunk) in float32 are the largest, 0.27 GB at 4,096 positions and 64
+heads.
 """
 
 from __future__ import annotations
