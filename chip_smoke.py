@@ -411,24 +411,29 @@ def _check_delta_rule(name: str, S: int = 8192, H: int = 8, D: int = 128) -> Non
 
 def _check_ssd(
     name: str, S: int = 4096, H: int = 64, P: int = 64, N: int = 128, chunk: int = 256,
+    groups: int = 1,
 ) -> None:
     """``ops.ssd.ssd_scan`` at a Mamba-2 layer's shape in ``granite4h-ft1`` -
     bf16 x, B and C, float32 steps as the mixer draws them (a softplus of a
     unit normal over a bias of log U(1e-3, 0.1)) under rates of -1 to -16 -
     compiled under the chip's DEFAULT matmul precision, the output and every
     cotangent of autodiff's backward against the recurrence a position at a
-    time in float32 at ``highest`` (``benchmark/reference_granite.py``).
-    Plain XLA: no Mosaic call."""
+    time in float32 at ``highest`` (``benchmark/reference_granite.py``; with
+    ``groups`` of B and C, ``nemotron3n-ft1``'s shape, the recurrence with
+    its groups of ``benchmark/reference_nemotron.py``). Plain XLA: no Mosaic
+    call."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import reference_granite
+    from benchmark import reference_granite, reference_nemotron
     from torchft_tpu.ops.ssd import ssd_scan
 
+    by_position = reference_granite.recurrence if groups == 1 else reference_nemotron.recurrence
+    maps = (1, S, N) if groups == 1 else (1, S, groups, N)
     keys = jax.random.split(jax.random.PRNGKey(S + P), 8)
     x, cot = (jax.random.normal(kk, (1, S, H, P)).astype(jnp.bfloat16) for kk in keys[:2])
-    B, C = (jax.random.normal(kk, (1, S, N)).astype(jnp.bfloat16) for kk in keys[2:4])
+    B, C = (jax.random.normal(kk, maps).astype(jnp.bfloat16) for kk in keys[2:4])
     drawn = jnp.exp(jax.random.uniform(keys[4], (H,), jnp.float32, np.log(1e-3), np.log(0.1)))
     dt = jax.nn.softplus(
         jax.random.normal(keys[5], (1, S, H)) + drawn + jnp.log(-jnp.expm1(-drawn))
@@ -444,7 +449,7 @@ def _check_ssd(
 
     def recurrence(x, dt, A, B, C, D):
         return jax.vmap(
-            lambda x, dt, B, C: reference_granite.recurrence(x, dt, A, B, C, D)
+            lambda x, dt, B, C: by_position(x, dt, A, B, C, D)
         )(x, dt, B, C)
 
     lowered = grad_of(lambda *a: ssd_scan(*a, chunk=chunk)).lower(x, dt, A, B, C, D)
@@ -474,8 +479,98 @@ def _check_ssd(
                 f"{err:.4f} of max|ref| (tolerance {FLASH_TOL})"
             )
     _say("kernels", (
-        f"ssd {name} B1 S{S} H{H} P{P} N{N} chunk {chunk}: compiled in {compile_s:.1f}s, "
+        f"ssd {name} B1 S{S} H{H} P{P} N{N}{f' G{groups}' if groups > 1 else ''} "
+        f"chunk {chunk}: compiled in {compile_s:.1f}s, "
         f"max err / max|ref| {errs} <= {FLASH_TOL}"
+    ))
+
+
+def _check_relu2_share(name: str, cfg: Any = None, N: int = 8192) -> None:
+    """``olmoe._held_share`` with UNGATED experts at ``nemotron3n-ft1``'s
+    shape - 8 of 128 experts of width 1,856 held at d 2,688, 6 a token,
+    8,192 tokens in bf16 - compiled under the chip's DEFAULT matmul
+    precision, against every held expert applied to every token in float32
+    at ``highest`` (``W_down relu(W_up u) ** 2`` weighted by the token's
+    weight on the expert, 0 where it chose another): the output and the
+    cotangents of the tokens, both maps and the weights. The routing is a
+    top-K of random scores with the FIRST held expert's raised, so that it
+    is heavy (applied to all tokens in place) and the others light (tiles).
+    Plain XLA: no Mosaic call."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torchft_tpu.models import olmoe
+
+    if cfg is None:
+        from benchmark import common
+
+        sizes = common.load_json("configs", "nemotron3-nano-l9-ep16.json")
+        cfg = common.load_by_name("families", sizes["family"]).build(sizes)
+    assert not cfg.gated
+    (first, held), E, K = cfg.held, cfg.n_experts, cfg.experts_per_token
+    d, f = cfg.d_model, cfg.expert_width
+    keys = jax.random.split(jax.random.PRNGKey(N + f), 6)
+    tokens, cot = (jax.random.normal(kk, (N, d)).astype(cfg.dtype) for kk in keys[:2])
+    p = {
+        "w_up": (jax.random.normal(keys[2], (held, d, f)) * d ** -0.5).astype(cfg.dtype),
+        "w_down": (jax.random.normal(keys[3], (held, f, d)) * f ** -0.5).astype(cfg.dtype),
+    }
+    score = jax.random.uniform(keys[4], (N, E)).at[:, first].add(
+        jnp.where(jnp.arange(N) % 8 == 0, 1.0, 0.0)  # an eighth of the tokens, and its own share
+    )
+    weights, chosen = jax.lax.top_k(score, K)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+
+    def grad_of(fn):
+        def loss(p, tokens, weights):
+            out = fn(p, tokens, weights)
+            return jnp.sum(out * cot.astype(jnp.float32)), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+
+    def share(p, tokens, weights):
+        return olmoe._held_share(cfg, p, tokens, weights, chosen)[0]
+
+    def every_expert(p, tokens, weights):
+        out = jnp.zeros(tokens.shape, jnp.float32)
+        for e in range(held):
+            gate = jnp.sum(jnp.where(chosen == first + e, weights, 0.0), axis=1)
+            hidden = jnp.square(jax.nn.relu(tokens @ p["w_up"][e]))
+            out = out + gate[:, None] * (hidden @ p["w_down"][e])
+        return out
+
+    lowered = grad_of(share).lower(p, tokens, weights)
+    if "tpu_custom_call" in lowered.as_text():
+        raise AssertionError(f"share {name}: a Mosaic call in the share")
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    compile_s = time.perf_counter() - t0
+    (_, out), grads = jax.block_until_ready(compiled(p, tokens, weights))
+    wide = jax.tree_util.tree_map(lambda t: t.astype(jnp.float32), (p, tokens))
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = jax.block_until_ready(grad_of(every_expert)(*wide, weights))
+
+    def labelled(out, grads):
+        return {"out": out, "dw_up": grads[0]["w_up"], "dw_down": grads[0]["w_down"],
+                "dtokens": grads[1], "dweights": grads[2]}
+
+    got, ref, errs = labelled(out, grads), labelled(want, want_grads), {}
+    for label in got:
+        a, b = np.asarray(got[label], np.float32), np.asarray(ref[label], np.float32)
+        if not np.all(np.isfinite(a)):
+            raise AssertionError(f"share {name}: non-finite {label}")
+        err = float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+        errs[label] = round(err, 5)
+        if err > FLASH_TOL:
+            raise AssertionError(
+                f"share {name}: {label} differs from every held expert on every token by "
+                f"{err:.4f} of max|ref| (tolerance {FLASH_TOL})"
+            )
+    rows, tile, light_up_to = olmoe._share_buffer(cfg, N)
+    _say("kernels", (
+        f"share {name} N{N} D{d} F{f} held {held} of {E} top-{K}, tiles of {tile} rows, "
+        f"heavy over {light_up_to}: compiled in {compile_s:.1f}s, max err / max|ref| {errs} "
+        f"<= {FLASH_TOL}"
     ))
 
 
@@ -623,6 +718,10 @@ def child_kernels() -> None:
     _check_delta_rule("ling_kda")
     # granite4h-ft1's mixer: the state-space scan in chunks, plain XLA
     _check_ssd("granite_ssd")
+    # nemotron3n-ft1's: the scan with eight groups of B and C in chunks of
+    # 128, and the held share of ungated experts, both plain XLA
+    _check_ssd("nemotron_ssd", S=8192, chunk=128, groups=8)
+    _check_relu2_share("relu2_share")
     # the big model's largest leaf (128 grid blocks) and an odd length
     # that ends mid-block
     _check_wire_kernels("big_leaf", (1024, 4096), seed=1)

@@ -200,6 +200,49 @@ class TestChipSmoke:
         with pytest.raises(AssertionError, match="differs from the recurrence"):
             chip_smoke._check_ssd("tiny", S=100, H=2, P=8, N=16, chunk=16)
 
+    def test_kernels_phase_checks_the_grouped_scan_and_the_ungated_share(self, monkeypatch, capsys):
+        """``nemotron3n-ft1``'s two lines (``nemotron_ssd``: the scan with
+        its groups of B and C at the cell's shape; ``relu2_share``: the held
+        share of ungated experts against every held expert on every token),
+        here in miniature; a head that reads another group's maps and an
+        activation without its square each fail their line."""
+        from benchmark import common
+        from torchft_tpu.models import nemotron, olmoe
+        from torchft_tpu.ops import ssd
+
+        sizes = common.load_json("configs", "nemotron3-nano-l9-ep16.json")
+        cfg = common.load_by_name("families", sizes["family"]).build(sizes)
+        mamba = cfg.kinds[0].mixer
+        assert (sizes["seq"] - 1, mamba.chunk, mamba.groups) == (8192, 128, 8)
+        assert (mamba.inner_heads, mamba.inner_head_dim, mamba.state) == tuple(
+            inspect.signature(chip_smoke._check_ssd).parameters[n].default for n in "HPN"
+        )
+        source = inspect.getsource(chip_smoke.child_kernels)
+        assert '_check_ssd("nemotron_ssd", S=8192, chunk=128, groups=8)' in source
+        assert '_check_relu2_share("relu2_share")' in source
+        assert inspect.signature(chip_smoke._check_relu2_share).parameters["N"].default == 8192
+
+        tiny = dict(S=100, H=4, P=8, N=16, chunk=16, groups=2)
+        with jax.default_matmul_precision("highest"):
+            chip_smoke._check_ssd("tiny", **tiny)
+        assert "ssd tiny B1 S100 H4 P8 N16 G2 chunk 16" in capsys.readouterr().out
+        scan = ssd.ssd_scan
+        monkeypatch.setattr(  # every head reads the OTHER group's maps
+            ssd, "ssd_scan",
+            lambda x, dt, A, B, C, D, chunk: scan(x, dt, A, B[:, :, ::-1], C[:, :, ::-1], D, chunk),
+        )
+        with pytest.raises(AssertionError, match="differs from the recurrence"):
+            chip_smoke._check_ssd("tiny", **tiny)
+        monkeypatch.undo()
+
+        held = nemotron.tiny_nemotron_config(held_experts=(2, 2))
+        with jax.default_matmul_precision("highest"):
+            chip_smoke._check_relu2_share("tiny", held, N=256)
+        assert "share tiny N256 D64 F24 held 2 of 8 top-2" in capsys.readouterr().out
+        monkeypatch.setattr(olmoe, "_swiglu", lambda c, into: jax.nn.relu(into))
+        with pytest.raises(AssertionError, match="differs from every held expert"):
+            chip_smoke._check_relu2_share("tiny", held, N=256)
+
     def test_without_a_chip_it_fails_and_says_so(self):
         # the tier-1 environment pins the CPU; the script overrides that
         # for its children (JAX_PLATFORMS=tpu) and must find no chip

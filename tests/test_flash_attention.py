@@ -514,6 +514,8 @@ def test_auto_tiles_of_a_window(S, window, interpret, want):
         ("dsv2lite-ft1-mla", 8192, (192, 128), None, None, ("nested", 512, 512, (256, 128))),
         # one NoPE layer in ten: 32 query rows a sequence at head size 64
         ("granite4h-ft1-nope", 4096, 64, None, None, ("nested", 1024, 512, (512, 128))),
+        # one NoPE layer in nine: 32 query rows a sequence (16 a key/value head) at 128
+        ("nemotron3n-ft1-nope", 8192, 128, None, None, ("nested", 1024, 512, (256, 128))),
         ("a-block-over-a-tile", 1920, 128, None, (6, 960), ("general", 128, 128, None)),
         ("one-block-a-copy", 4096, 128, None, (2048, 2048), ("general", 512, 512, None)),
         ("a-padded-length", 400, 128, None, (8, 200), ("general", 128, 128, None)),

@@ -625,3 +625,44 @@ def test_the_granite_cells_step_compiles_for_the_chip_with_what_its_family_state
     assert lowered.as_text().count('kernel_name = "flash_fwd"') == 2
     text = _compiled_text(lowered)
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_nemotron_cells_step_compiles_for_the_chip_with_what_its_family_states(one_chip, monkeypatch):
+    """``nemotron3n-ft1``'s gradient step as the generators lower it
+    (``mixed_precision_grad``), at the published head sizes (attention 16
+    query heads over 1 key/value head of 128, the published ratio; Mamba-2
+    heads of 64 over a state of 128 in 2 groups, chunks of 128) and cut
+    elsewhere - the rehearsal's five layers ``MEM*E``, a width of 256, 1,024
+    positions, 2 of 8 experts held - through the TPU compiler for the
+    described chip, every layer recomputed: a held share (a ``custom_vjp``
+    whose loops follow the routing) and a grouped scan under ONE stack's
+    checkpoints compile, and the Mosaic calls are the one attention layer's
+    ``flash_fwd``, its recomputed ``flash_fwd`` and its ``flash_bwd``, THREE,
+    which the family states; the scan and the share compile as plain XLA."""
+    from benchmark import common
+
+    monkeypatch.setattr(
+        sys.modules["torchft_tpu.ops.flash_attention"], "_pick_interpret", lambda _i: False
+    )
+    sizes = common.load_json("configs", "nemotron3-nano-l9-ep16.json")
+    sizes = {**sizes, **sizes["rehearsal"], "hidden_size": 256, "num_attention_heads": 16,
+             "num_key_value_heads": 1, "head_dim": 128, "mamba_num_heads": 8,
+             "mamba_head_dim": 64, "ssm_state_size": 128, "chunk_size": 128, "seq": 1025}
+    family = common.load_family(sizes["family"])
+    cfg = family.build(sizes)
+    assert cfg.parts == ("mixer", "ff", "mixer", "mixer", "ff") and cfg.recompute_layers
+    assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.held) == (16, 1, 128, (0, 2))
+    assert family.lowered_mosaic_calls(cfg) == 3
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip), tree
+        )
+
+    params = on_chip(jax.eval_shape(lambda: family.init(cfg, jax.random.PRNGKey(0))))
+    tokens = jax.ShapeDtypeStruct((1, sizes["seq"]), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(common.mixed_precision_grad(family, cfg)).lower(params, tokens)
+    common.require_mosaic(lowered, 3, "nemotron3n-ft1")
+    assert lowered.as_text().count('kernel_name = "flash_fwd"') == 2
+    text = _compiled_text(lowered)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3

@@ -1,4 +1,4 @@
-from torchft_tpu.models import cnn, dsv2, granite, ling, mellum, moe, olmoe, ouro, sdar
+from torchft_tpu.models import cnn, dsv2, granite, ling, mellum, moe, nemotron, olmoe, ouro, sdar
 from torchft_tpu.models.cnn import CNNConfig, tiny_cnn_config
 from torchft_tpu.models.moe import MoEConfig, tiny_moe_config
 from torchft_tpu.models.olmoe import OlmoeConfig, tiny_olmoe_config
@@ -30,6 +30,7 @@ __all__ = [
     "make_train_step",
     "mellum",
     "moe",
+    "nemotron",
     "olmoe",
     "ouro",
     "param_sharding_rules",

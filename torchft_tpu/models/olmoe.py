@@ -132,6 +132,19 @@ file (``models/mellum.py`` is one):
   (the attention layers'). Softmax attention itself may go without the rotary
   embedding (``AttentionKind.rotary``) and at a stated softmax scale
   (``softmax_scale``);
+- a layer that is ONE SUBLAYER (``sublayers``; ``models/nemotron.py`` has
+  nothing else): ``h' = h + Mixer(N(h))`` with ONE norm, the mixer being a
+  layer's kind OR its feed-forward, so that two mixers may follow each other
+  and a feed-forward another. The absent sublayer adds no operation and no
+  leaf (a ``"mixer"`` layer's tree is ``ln1`` and ``attn``, an ``"ff"``
+  layer's ``ln2`` and ``moe`` or ``mlp``); a feed-forward's ACTIVATION
+  (``ff_activation``): the gated SiLU above, or ``relu2``, ``W_down relu(W_up
+  u) ** 2`` - two matrices and no ``w_gate`` leaf, in the routed experts (the
+  share's ``w_in`` is then (held, d, f)), the shared expert and a dense
+  layer alike; and a state-space mixer's GROUPS (``Mamba2.groups`` = G): B
+  and C are ``G x state`` wide each, head ``h`` reads the maps of group ``h //
+  (inner_heads / G)``, and the gated norm takes its statistic over each
+  group's channels;
 - a SCALED RESIDUAL STREAM (muP's multipliers as Granite's ``config.json``
   names them): the embedding's rows times ``embedding_multiplier``, every
   sublayer's output times ``residual_multiplier`` before it joins the stream,
@@ -265,15 +278,22 @@ class Mamba2:
     """A state-space mixer (module docstring): ``inner_heads`` heads of
     ``inner_head_dim`` channels - the mixer's OWN, whatever ``n_heads`` the
     attention layers beside it have - each with a state of ``inner_head_dim``
-    x ``state``, one group of input and output maps for all of them,
-    ``conv_taps`` positions under the one short convolution and ``chunk``
-    positions a chunk of the scan (``ops/ssd.py``)."""
+    x ``state``, ``groups`` groups of input and output maps (one: for all the
+    heads; ``G``: head ``h`` reads the maps of group ``h // (inner_heads /
+    G)``, and the gated norm takes its statistic a group's channels at a
+    time), ``conv_taps`` positions under the one short convolution and
+    ``chunk`` positions a chunk of the scan (``ops/ssd.py``)."""
 
     state: int
     conv_taps: int
     chunk: int
     inner_heads: int
     inner_head_dim: int
+    groups: int = 1
+
+    def __post_init__(self) -> None:
+        if self.inner_heads % self.groups:
+            raise ValueError("the mixer's heads do not fall into whole groups")
 
     @property
     def inner(self) -> int:
@@ -282,8 +302,8 @@ class Mamba2:
 
     @property
     def convolved(self) -> int:
-        """The channels under the convolution: ``x | B | C``."""
-        return self.inner + 2 * self.state
+        """The channels under the convolution: ``x | B | C``, the maps a group."""
+        return self.inner + 2 * self.groups * self.state
 
 
 @dataclass(frozen=True)
@@ -365,6 +385,9 @@ class OlmoeConfig:
     logits_scaling: float = 1.0  # what the logits are DIVIDED by
     tied_readout: bool = False  # the readout is the embedding's transpose: no ``readout`` leaf
     recompute_layers: bool = False  # each layer computed again in the backward pass (``_stack``, ``STACK_KEPT``)
+    # a layer that is ONE sublayer (module docstring); None: every layer has both
+    sublayers: Optional[Tuple[str, ...]] = None  # per layer "both", "mixer" or "ff"
+    ff_activation: str = "swiglu"  # "relu2": ``W_down relu(W_up u) ** 2``, ungated, no ``w_gate`` leaf
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -376,6 +399,10 @@ class OlmoeConfig:
             raise ValueError("n_heads is no multiple of n_kv_heads")
         if len(self.kinds) != self.n_layers or len(self.ff) != self.n_layers:
             raise ValueError("layer_kinds or dense_ff names another number of layers than n_layers")
+        if len(self.parts) != self.n_layers or set(self.parts) - {"both", "mixer", "ff"}:
+            raise ValueError('sublayers names each of the n_layers "both", "mixer" or "ff"')
+        if self.ff_activation not in ("swiglu", "relu2"):
+            raise ValueError(f"no feed-forward activation {self.ff_activation!r}")
         if self.passes < 1:
             raise ValueError("the stack runs at least once")
         if first < 0 or count < 1 or first + count > self.n_experts:
@@ -411,8 +438,20 @@ class OlmoeConfig:
         return self.dense_ff or (None,) * self.n_layers
 
     @property
+    def parts(self) -> Tuple[str, ...]:
+        """Per layer, what it has: "both", or the one sublayer it is."""
+        return self.sublayers or ("both",) * self.n_layers
+
+    @property
     def expert_layers(self) -> int:
-        return sum(width is None for width in self.ff)
+        return sum(
+            width is None and part != "mixer" for width, part in zip(self.ff, self.parts)
+        )
+
+    @property
+    def gated(self) -> bool:
+        """Whether a feed-forward has a gate's product beside its up product."""
+        return self.ff_activation == "swiglu"
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -535,38 +574,41 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
     keys = jax.random.split(key, 2 + cfg.n_layers)
     scale = d ** -0.5
 
+    def feed_forward(ks: Any, at: int, lead: Tuple[int, ...], width: int) -> Dict[str, Any]:
+        """A feed-forward's maps from the three keys ``ks[at:]`` (gate, up,
+        down), ``lead`` the held experts' axis or none; ungated, it has no
+        ``w_gate``."""
+        p = {"w_gate": _dense_init(ks[at], lead + (d, width), scale)} if cfg.gated else {}
+        p["w_up"] = _dense_init(ks[at + 1], lead + (d, width), scale)
+        p["w_down"] = _dense_init(ks[at + 2], lead + (width, d), width ** -0.5)
+        return p
+
     blocks = []
-    for i, (kind, width) in enumerate(zip(cfg.kinds, cfg.ff)):
+    for i, (kind, width, part) in enumerate(zip(cfg.kinds, cfg.ff, cfg.parts)):
         bk = jax.random.split(keys[2 + i], 8)
-        block = {
-            "ln1": {"scale": _ones(d)},
-            "attn": _MIXERS[type(kind.mixer)][0](cfg, kind, bk),
-            "ln2": {"scale": _ones(d)},
-        }
+        block = {}
+        if part != "ff":
+            block.update(
+                ln1={"scale": _ones(d)}, attn=_MIXERS[type(kind.mixer)][0](cfg, kind, bk)
+            )
+        if part != "mixer":
+            block["ln2"] = {"scale": _ones(d)}
         if cfg.sandwich_norms:  # the second norm of each sublayer
-            block.update(ln1_post={"scale": _ones(d)}, ln2_post={"scale": _ones(d)})
-        if width is None:
+            block.update({
+                name + "_post": {"scale": _ones(d)} for name in ("ln1", "ln2") if name in block
+            })
+        if part != "mixer" and width is None:
             block["moe"] = {
                 "router": _dense_init(bk[4], (d, e), scale),
-                "w_gate": _dense_init(bk[5], (held, d, f), scale),
-                "w_up": _dense_init(bk[6], (held, d, f), scale),
-                "w_down": _dense_init(bk[7], (held, f, d), f ** -0.5),
+                **feed_forward(bk, 5, (held,), f),
             }
             if cfg.router is not None:
                 block["moe"]["bias"] = jnp.zeros((e,), jnp.float32)
             if cfg.shared_width is not None:
-                sk, fs = jax.random.split(jax.random.fold_in(bk[4], 1), 3), cfg.shared_width
-                block["moe"]["shared"] = {
-                    "w_gate": _dense_init(sk[0], (d, fs), scale),
-                    "w_up": _dense_init(sk[1], (d, fs), scale),
-                    "w_down": _dense_init(sk[2], (fs, d), fs ** -0.5),
-                }
-        else:
-            block["mlp"] = {
-                "w_gate": _dense_init(bk[5], (d, width), scale),
-                "w_up": _dense_init(bk[6], (d, width), scale),
-                "w_down": _dense_init(bk[7], (width, d), width ** -0.5),
-            }
+                sk = jax.random.split(jax.random.fold_in(bk[4], 1), 3)
+                block["moe"]["shared"] = feed_forward(sk, 0, (), cfg.shared_width)
+        elif part != "mixer":
+            block["mlp"] = feed_forward(bk, 5, (), width)
         blocks.append(block)
     params = {
         "embed": _dense_init(keys[0], (cfg.vocab_size, d), scale),
@@ -926,10 +968,11 @@ def mla_mixer(
 
 def _gated_norm(cfg: OlmoeConfig, p: Dict[str, Any], y: jax.Array, z: jax.Array) -> jax.Array:
     """``N_g(y SiLU(z))`` in float32: the gate BEFORE the norm, and one
-    statistic over every head's channels."""
+    statistic over ``y``'s last axis - every head's channels (B, S, inner),
+    or a group's (B, S, G, inner / G), the learned scale laid out alike."""
     f32 = jnp.float32
     y = y.astype(f32) * jax.nn.silu(z.astype(f32))
-    return _rmsnorm(y, p["norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+    return _rmsnorm(y, p["norm"].reshape(y.shape[2:]), cfg.rms_norm_eps).astype(cfg.dtype)
 
 
 def mamba2_mixer(
@@ -937,11 +980,15 @@ def mamba2_mixer(
 ) -> jax.Array:
     """A Mamba-2 mixer (module docstring) over its own heads: the one map,
     the short convolution with its bias and SiLU over ``x B C``, the step a
-    head, the state-space scan in chunks (``ops/ssd.py``), the norm over all
-    inner channels of the GATED output, ``wo``."""
+    head, the state-space scan in chunks (``ops/ssd.py``), the norm of the
+    GATED output over all inner channels or a group's at a time, ``wo``."""
     B, S, _ = x.shape
     m, f32 = kind.mixer, jnp.float32
-    h, n, inner = m.inner_heads, m.state, m.inner
+    h, n, inner = m.inner_heads, m.groups * m.state, m.inner
+    # one group: the maps are every head's and the norm's statistic spans the
+    # inner channels; more: a group axis before the state's and the channels'
+    maps = (B, S, n) if m.groups == 1 else (B, S, m.groups, m.state)
+    normed = (B, S, inner) if m.groups == 1 else (B, S, m.groups, inner // m.groups)
     with jax.named_scope("proj"):
         z, xbc, dt = jnp.split(
             x @ p["w_in"].astype(cfg.dtype), (inner, inner + m.convolved), axis=-1
@@ -955,11 +1002,11 @@ def mamba2_mixer(
         rate = -jnp.exp(p["a_log"].astype(f32))
     with jax.named_scope("scan"):
         y = ssd_scan(
-            u.reshape(B, S, h, m.inner_head_dim), dt, rate, to_state, from_state,
-            p["d"], chunk=m.chunk,
+            u.reshape(B, S, h, m.inner_head_dim), dt, rate, to_state.reshape(maps),
+            from_state.reshape(maps), p["d"], chunk=m.chunk,
         )
     with jax.named_scope("norm"):
-        y = _gated_norm(cfg, p, y.reshape(B, S, inner), z)
+        y = _gated_norm(cfg, p, y.reshape(normed), z.reshape(normed)).reshape(B, S, inner)
     with jax.named_scope("out"):
         return y @ p["wo"].astype(cfg.dtype)
 
@@ -1021,12 +1068,16 @@ def _experts(
 ) -> jax.Array:
     """``W_down,e (silu(W_gate,e x) * W_up,e x)`` of every row, the rows
     of expert ``e`` being the ``group_sizes[e]`` that follow those of the
-    experts before it: three grouped matmuls."""
+    experts before it: three grouped matmuls (ungated, ``W_down,e relu(W_up,e
+    x) ** 2``: two)."""
 
     def grouped(lhs: jax.Array, w: jax.Array) -> jax.Array:
         return jax.lax.ragged_dot(lhs, w.astype(cfg.dtype), group_sizes)
 
-    hidden = jax.nn.silu(grouped(rows, p["w_gate"])) * grouped(rows, p["w_up"])
+    if cfg.gated:
+        hidden = jax.nn.silu(grouped(rows, p["w_gate"])) * grouped(rows, p["w_up"])
+    else:
+        hidden = jnp.square(jax.nn.relu(grouped(rows, p["w_up"])))
     return grouped(hidden, p["w_down"])
 
 
@@ -1065,8 +1116,12 @@ def _tile(stack: jax.Array, c: jax.Array) -> jax.Array:
 
 
 def _swiglu(cfg: OlmoeConfig, into: jax.Array) -> jax.Array:
-    """``silu(gate) * up`` of the (rows, 2 f) products of gate and up."""
+    """A held expert's hidden rows from its products in: ``silu(gate) * up``
+    of the (rows, 2 f) products of gate and up, or ungated
+    (``cfg.ff_activation``) ``relu(up) ** 2`` of the (rows, f) up product."""
     f = cfg.expert_width
+    if not cfg.gated:
+        return jnp.square(jax.nn.relu(into))
     return jax.nn.silu(into[:, :f]) * into[:, f:]
 
 
@@ -1085,9 +1140,10 @@ def _held_experts(
     gate: jax.Array, layout: _Layout,
 ) -> jax.Array:
     """The held experts' part of the layer's output, (N, D) float32, from
-    the tokens, gate and up side by side (``w_in`` (held, D, 2 f)), the
-    weights down, the (held, N) weight of every token on every held expert
-    (0 where it chose another) and ``_held_share``'s layout. Two loops,
+    the tokens, gate and up side by side (``w_in`` (held, D, 2 f); ungated,
+    the up maps alone, (held, D, f)), the weights down, the (held, N) weight
+    of every token on every held expert (0 where it chose another) and
+    ``_held_share``'s layout. Two loops,
     each as long as the routing makes it:
 
     - over the light experts' tiles in use: a tile's rows gathered from
@@ -1272,7 +1328,10 @@ def _held_share(
         heavy_first = jnp.argsort(~is_heavy, stable=True).astype(jnp.int32)
         heavy = jnp.sum(is_heavy, dtype=jnp.int32)
     with jax.named_scope("experts"):
-        w_in = jnp.concatenate([p["w_gate"], p["w_up"]], axis=-1).astype(cfg.dtype)
+        w_in = p["w_up"]
+        if cfg.gated:
+            w_in = jnp.concatenate([p["w_gate"], w_in], axis=-1)
+        w_in = w_in.astype(cfg.dtype)
     y = _held_experts(cfg, tokens, w_in, p["w_down"].astype(cfg.dtype), gate, (
         token_of_row.reshape(-1, tile), claim_of_row.reshape(-1, tile),
         tile_expert, ends[-1] // tile, heavy_first, heavy,
@@ -1413,28 +1472,32 @@ def moe_layer(
 
 
 def dense_mlp(cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array) -> jax.Array:
-    """``W_down (silu(W_gate x) * W_up x)``: a layer's dense SwiGLU. Its
-    three products carry names for a checkpoint's save policy: ``mlp_gate``
+    """``W_down (silu(W_gate x) * W_up x)``: a layer's dense SwiGLU, or
+    ungated (``cfg.ff_activation``) ``W_down relu(W_up x) ** 2``. Its
+    products carry names for a checkpoint's save policy: ``mlp_gate``
     and ``mlp_up`` (``STACK_KEPT``, a stack recomputed a layer) and
     ``mlp_down`` (``KEPT``, a looped pass). A name lowers to no operation,
     so outside a checkpoint, or under a policy that does not read it,
     nothing changes."""
-    gate = checkpoint_name(x @ p["w_gate"].astype(cfg.dtype), "mlp_gate")
+    if cfg.gated:
+        gate = checkpoint_name(x @ p["w_gate"].astype(cfg.dtype), "mlp_gate")
     up = checkpoint_name(x @ p["w_up"].astype(cfg.dtype), "mlp_up")
-    hidden = jax.nn.silu(gate) * up
+    hidden = jax.nn.silu(gate) * up if cfg.gated else jnp.square(jax.nn.relu(up))
     return checkpoint_name(hidden @ p["w_down"].astype(cfg.dtype), "mlp_down")
 
 
 def _block(
     cfg: OlmoeConfig, p: Dict[str, Any], x: jax.Array,
     kind: AttentionKind = AttentionKind(), width: Optional[int] = None,
+    part: str = "both",
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     # the dense family's scope names (transformer._block) with the new
     # mechanisms nested in them: attn/qk_rows (the norm, the rotation and the
     # kernels' layout of q, k, v: ``_heads_to_rows``), mlp/moe/router,
     # mlp/moe/dispatch, mlp/moe/experts, mlp/moe/combine; a named kind of
     # layer puts its name between: attn/sliding/qk_rows, attn/full/flash_fwd;
-    # a dense feed-forward (``width``) is ``mlp`` alone and has no sums.
+    # a dense feed-forward (``width``) is ``mlp`` alone and has no sums; a
+    # layer that is one sublayer (``part``) runs, and names, that one alone.
     # Metadata only.
     eps = cfg.rms_norm_eps
 
@@ -1448,10 +1511,13 @@ def _block(
         return y
 
     of_kind = jax.named_scope(kind.name) if kind.name else contextlib.nullcontext()
-    with jax.named_scope("attn"), of_kind:
-        mixer = _MIXERS[type(kind.mixer)][1]
-        y = mixer(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"], eps), kind)
-        x = x + second(checkpoint_name(y, "mixer_out"), "ln1_post")
+    if part != "ff":
+        with jax.named_scope("attn"), of_kind:
+            mixer = _MIXERS[type(kind.mixer)][1]
+            y = mixer(cfg, p["attn"], _rmsnorm(x, p["ln1"]["scale"], eps), kind)
+            x = x + second(checkpoint_name(y, "mixer_out"), "ln1_post")
+    if part == "mixer":
+        return x, None
     with jax.named_scope("mlp"):
         if width is not None:
             y = dense_mlp(cfg, p["mlp"], _rmsnorm(x, p["ln2"]["scale"], eps))
@@ -1473,7 +1539,9 @@ def _stack(
     computed them) and computes the rest of the layer again when it comes
     to it, its Mosaic calls too - the memory is one layer's activations and
     ``n_layers`` times the input and the kept products. The recomputed
-    stack is scoped ``layers``."""
+    stack is scoped ``layers``. A layer that is one sublayer
+    (``cfg.sublayers``) is one checkpoint like any other; an ungated
+    feed-forward has no ``mlp_gate`` to keep."""
     total = None
     # a checkpoint's operations are named by the scope AROUND it (``transpose(
     # jvp(layers))/checkpoint/rematted_computation/attn/..``): under none, a
@@ -1481,16 +1549,16 @@ def _stack(
     # layer's backward pass for unscoped (the reader's to mend, ROADMAP
     # W14(i); this scope goes then)
     around = jax.named_scope("layers") if cfg.recompute_layers else contextlib.nullcontext()
-    for kind, width, p in zip(cfg.kinds, cfg.ff, blocks):
-        layer = functools.partial(_block, cfg, kind=kind, width=width)
+    for kind, width, part, p in zip(cfg.kinds, cfg.ff, cfg.parts, blocks):
+        layer = functools.partial(_block, cfg, kind=kind, width=width, part=part)
         if cfg.recompute_layers:
             layer = jax.checkpoint(
                 layer, policy=jax.checkpoint_policies.save_only_these_names(*STACK_KEPT)
             )
         with around:
             x, stats = layer(p, x)
-        if stats is not None:
-            total = stats if total is None else jax.tree_util.tree_map(jnp.add, total, stats)
+            if stats is not None:
+                total = stats if total is None else jax.tree_util.tree_map(jnp.add, total, stats)
     return x, total
 
 
@@ -1583,7 +1651,10 @@ KEPT = ("mlp_down",)
 # on a v5e (PERF.md section 6, PR 59): ms a step that leave the recomputed
 # pass, for the GB the TPU compiler's memory analysis of the gradient step
 # grows by (13.37 GB with nothing kept, 14.64 with the three names; the
-# configuration was admitted under 15.5):
+# configuration was admitted under 15.5; since PR 60 a second configuration
+# sets it, ``nemotron3-nano-l9-ep16``, whose gate holds with the same names -
+# its ``mixer_out`` and its shared experts' ``mlp_up`` are 0.46 GB of its 2.99
+# GB of temporaries - and which was not tuned: the tuple stays the program's):
 #   the mixer's output (``mixer_out``: ``wo``'s product)      4.0 ms / 0.02 GB
 #   the SwiGLU's gate and up products (``mlp_gate``,
 #   ``mlp_up``)                                              14.4 ms / 1.25 GB = 11.5

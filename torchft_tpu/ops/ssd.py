@@ -5,9 +5,10 @@ Pallas kernel is measured against it (``ssm_scan_roofline``). Nothing of a
 model is here: no projection, no convolution, no norm.
 
 Per head, with a state ``S`` (P x n), ``S_0 = 0``, and per position an input
-``x_t`` (P), a step ``dt_t > 0`` and, shared by every head (one group), an
-input map ``B_t`` (n) and an output map ``C_t`` (n); per head a rate ``A < 0``
-and a skip ``D``::
+``x_t`` (P), a step ``dt_t > 0`` and, shared by the heads of a GROUP (one
+group of all the heads, or ``G`` groups of ``H / G`` consecutive heads each:
+head ``h`` reads group ``h // (H / G)``), an input map ``B_t`` (n) and an
+output map ``C_t`` (n); per head a rate ``A < 0`` and a skip ``D``::
 
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T
     y_t = S_t C_t + D x_t
@@ -85,7 +86,9 @@ def ssd_scan(
         dt: (B, S, H), the positive steps (float32 is what a caller should
             bring; it is widened here).
         A: (H,) the negative rates; D: (H,) the skips.
-        B, C: (B, S, n), one group: the same for every head.
+        B, C: (B, S, n), one group: the same for every head; or (B, S, G, n),
+            a map a group, ``G`` dividing ``H`` (``C B^T`` is then taken a
+            group, and a head's products read its group's).
         chunk: positions a chunk.
     Any S: the sequence is padded to whole chunks with positions of step 0,
     which leave the state as it is.
@@ -94,28 +97,38 @@ def ssd_scan(
         (B, S, H, P) in ``x``'s type."""
     S, dtype, f32 = x.shape[1], x.dtype, jnp.float32
     dt = dt.astype(f32)
+    # the heads as the einsums below name them: ``h``, or under a group axis
+    # ``gk`` - group ``g``'s ``k``-th head - beside the maps' own ``g``
+    grouped = B.ndim == 4
+    hd, gr = ("gk", "g") if grouped else ("h", "")
+
+    def by_group(t: jax.Array) -> jax.Array:
+        """(B, N, H, ...) -> (B, N, G, H / G, ...) under a group axis."""
+        return t.reshape(t.shape[:2] + (B.shape[2], -1) + t.shape[3:]) if grouped else t
+
     # (B, N, H, chunk, ...): a head's chunk is one matrix of every product
-    xs = jnp.moveaxis(_chunks(x, chunk), 3, 2)  # (B, N, H, chunk, P)
-    dts = jnp.moveaxis(_chunks(dt, chunk), 3, 2)  # (B, N, H, chunk)
-    a = dts * A.astype(f32)[:, None]
-    Bs, Cs = _chunks(B.astype(dtype), chunk), _chunks(C.astype(dtype), chunk)  # (B, N, chunk, n)
+    xs = by_group(jnp.moveaxis(_chunks(x, chunk), 3, 2))  # (B, N, H, chunk, P)
+    dts = by_group(jnp.moveaxis(_chunks(dt, chunk), 3, 2))  # (B, N, H, chunk)
+    a = dts * A.astype(f32).reshape(dts.shape[2:-1])[..., None]
+    Bs, Cs = _chunks(B.astype(dtype), chunk), _chunks(C.astype(dtype), chunk)  # (B, N, chunk, [G,] n)
     position = jnp.arange(chunk)
     to_now = position[:, None] >= position[None, :]  # s <= t
-    G = jnp.einsum("ts,bnhs->bnht", to_now.astype(f32), a, precision=_EXACT)
+    G = jnp.einsum(f"ts,bn{hd}s->bn{hd}t", to_now.astype(f32), a, precision=_EXACT)
     last = G[..., -1:]  # (B, N, H, 1)
 
-    # inside a chunk: the scores every head shares, each head's decays on them
-    scores = jnp.einsum("bntc,bnsc->bnts", Cs, Bs, preferred_element_type=f32)
+    # inside a chunk: the scores a group's heads share, each head's decays on them
+    scores = jnp.einsum(f"bnt{gr}c,bns{gr}c->bn{gr}ts", Cs, Bs, preferred_element_type=f32)
     decays = jnp.exp(jnp.where(to_now, G[..., :, None] - G[..., None, :], -jnp.inf))
     stepped = xs.astype(f32) * dts[..., None]  # dt x, (B, N, H, chunk, P)
     y = jnp.einsum(
-        "bnhts,bnhsp->bnhtp", (scores[:, :, None] * decays).astype(dtype),
+        f"bn{hd}ts,bn{hd}sp->bn{hd}tp", (jnp.expand_dims(scores, -3) * decays).astype(dtype),
         stepped.astype(dtype), preferred_element_type=f32,
     )
 
     # a chunk's own state, then the serial part: S before every chunk
     own = jnp.einsum(
-        "bnhsp,bnsc->bnhpc", (stepped * jnp.exp(last - G)[..., None]).astype(dtype), Bs,
+        f"bn{hd}sp,bns{gr}c->bn{hd}pc",
+        (stepped * jnp.exp(last - G)[..., None]).astype(dtype), Bs,
         preferred_element_type=f32,
     )  # (B, N, H, P, n)
 
@@ -129,8 +142,9 @@ def ssd_scan(
     )
     states = jnp.moveaxis(states, 0, 1)  # (B, N, H, P, n)
     y = y + jnp.exp(G)[..., None] * jnp.einsum(
-        "bntc,bnhpc->bnhtp", Cs, states.astype(dtype), preferred_element_type=f32
+        f"bnt{gr}c,bn{hd}pc->bn{hd}tp", Cs, states.astype(dtype), preferred_element_type=f32
     )
-    y = y + D.astype(f32)[:, None, None] * xs.astype(f32)
+    y = y + D.astype(f32).reshape(dts.shape[2:-1])[..., None, None] * xs.astype(f32)
+    y = y.reshape(y.shape[:2] + (-1,) + y.shape[-2:])  # the groups' heads side by side again
     y = jnp.moveaxis(y, 2, 3)  # (B, N, chunk, H, P)
     return y.reshape(y.shape[0], -1, *y.shape[3:])[:, :S].astype(dtype)
