@@ -417,11 +417,12 @@ def _check_ssd(
     bf16 x, B and C, float32 steps as the mixer draws them (a softplus of a
     unit normal over a bias of log U(1e-3, 0.1)) under rates of -1 to -16 -
     compiled under the chip's DEFAULT matmul precision, the output and every
-    cotangent of autodiff's backward against the recurrence a position at a
-    time in float32 at ``highest`` (``benchmark/reference_granite.py``; with
-    ``groups`` of B and C, ``nemotron3n-ft1``'s shape, the recurrence with
-    its groups of ``benchmark/reference_nemotron.py``). Plain XLA: no Mosaic
-    call."""
+    cotangent of the op's OWN backward (``ssd_scan`` is a ``custom_vjp``:
+    ``ops/ssd.py``, ``_backward``) against autodiff of the recurrence a
+    position at a time in float32 at ``highest``
+    (``benchmark/reference_granite.py``; with ``groups`` of B and C,
+    ``nemotron3n-ft1``'s shape, the recurrence with its groups of
+    ``benchmark/reference_nemotron.py``). Plain XLA: no Mosaic call."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -480,7 +481,7 @@ def _check_ssd(
             )
     _say("kernels", (
         f"ssd {name} B1 S{S} H{H} P{P} N{N}{f' G{groups}' if groups > 1 else ''} "
-        f"chunk {chunk}: compiled in {compile_s:.1f}s, "
+        f"chunk {chunk}, the op's own backward: compiled in {compile_s:.1f}s, "
         f"max err / max|ref| {errs} <= {FLASH_TOL}"
     ))
 

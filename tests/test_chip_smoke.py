@@ -165,11 +165,13 @@ class TestChipSmoke:
             chip_smoke._check_delta_rule("tiny", S=200, H=2, D=16)
 
     def test_kernels_phase_checks_the_state_space_scan(self, monkeypatch, capsys):
-        """The kernels phase holds ``ssd_scan`` and autodiff's backward to
+        """The kernels phase holds ``ssd_scan`` and the op's own backward to
         the recurrence at a Mamba-2 layer's shape in ``granite4h-ft1`` (the
         ``granite_ssd`` line); here the same check in miniature, and a
         decay planted wrong - the running sums left out of a chunk's carried
-        factor - fails it."""
+        factor, in the forward's loop, the backward's and the whole decay's
+        own cotangent - fails it; planted in the backward ALONE (the forward
+        traced sound), the output passes and a cotangent fails."""
         from benchmark import common
         from torchft_tpu.ops import ssd
 
@@ -199,6 +201,25 @@ class TestChipSmoke:
         monkeypatch.setattr(ssd, "jnp", Wrong())
         with pytest.raises(AssertionError, match="differs from the recurrence"):
             chip_smoke._check_ssd("tiny", S=100, H=2, P=8, N=16, chunk=16)
+        monkeypatch.undo()
+        ssd._scan.defvjp(ssd._forward, self._with(ssd, "jnp", Wrong(), ssd._backward))
+        try:
+            with pytest.raises(AssertionError, match=r"ssd tiny: d\w+ differs from the recurrence"):
+                chip_smoke._check_ssd("tiny", S=100, H=2, P=8, N=16, chunk=16)
+        finally:
+            ssd._scan.defvjp(ssd._forward, ssd._backward)
+
+    @staticmethod
+    def _with(module, name, value, fn):
+        """``fn`` run with ``module.name`` set to ``value``, and put back."""
+        def run(*args):
+            right = getattr(module, name)
+            setattr(module, name, value)
+            try:
+                return fn(*args)
+            finally:
+                setattr(module, name, right)
+        return run
 
     def test_kernels_phase_checks_the_grouped_scan_and_the_ungated_share(self, monkeypatch, capsys):
         """``nemotron3n-ft1``'s two lines (``nemotron_ssd``: the scan with

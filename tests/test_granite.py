@@ -133,17 +133,43 @@ def test_ssd_scan_is_the_recurrence(batch, s, chunk):
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * scale)
 
 
-@pytest.mark.parametrize("batch,s,chunk", [(1, 64, 16), (2, 40, 16)])
+def _cotangents(fn, args, weight):
+    return jax.grad(lambda *a: jnp.sum(weight * fn(*a)), argnums=range(6))(*args)
+
+
+@pytest.mark.parametrize("batch,s,chunk", [
+    (1, 64, 16),  # several chunks: the reverse loop carries the states' cotangents
+    (2, 40, 16),  # batch 2, a padded last chunk
+    (1, 50, 16),  # a padded last chunk
+    (1, 7, 16),  # shorter than a chunk
+    (1, 16, 16),  # one chunk: nothing is carried
+])
 def test_ssd_scan_cotangents_are_the_recurrences(batch, s, chunk):
+    """The op's own backward (``ops/ssd.py``: ``_backward``) against autodiff
+    of the recurrence a position at a time, in float32: each of the six
+    cotangents to 1e-5 of its largest entry, in its argument's shape and
+    type - ``A``'s and ``D``'s a head."""
     args = _scan_inputs(batch, s, seed=3)
     weight = jax.random.normal(jax.random.PRNGKey(9), args[0].shape, jnp.float32)
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda *a: jnp.sum(weight * ssd_scan(*a, chunk=chunk)), argnums=range(6))(*args)
-        want = jax.grad(lambda *a: jnp.sum(weight * _by_position(*a)), argnums=range(6))(*args)
+        got = _cotangents(lambda *a: ssd_scan(*a, chunk=chunk), args, weight)
+        want = _cotangents(_by_position, args, weight)
+    assert got[2].shape == got[5].shape == (args[0].shape[2],)
     for name, a, b in zip("x dt A B C D".split(), got, want):
         assert a.shape == b.shape and a.dtype == b.dtype, name
         scale = float(jnp.max(jnp.abs(b)))
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-4 * scale, err_msg=name)
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * scale, err_msg=name)
+
+
+def test_ssd_scan_cotangents_come_back_in_their_arguments_types():
+    """Whatever comes in: bf16 steps, rates and skips beside float32 maps."""
+    x, dt, A, B, C, D = _scan_inputs(1, 40, dtype=jnp.bfloat16, seed=4)
+    bf16 = jnp.bfloat16
+    args = (x, dt.astype(bf16), A.astype(bf16), B.astype(jnp.float32), C, D.astype(bf16))
+    got = jax.grad(
+        lambda *a: jnp.sum(ssd_scan(*a, chunk=16).astype(jnp.float32)), argnums=range(6))(*args)
+    assert [(g.shape, g.dtype) for g in got] == [(a.shape, a.dtype) for a in args]
+    assert all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))) for g in got)
 
 
 def test_ssd_scan_in_bf16_comes_as_near_as_its_inputs_rounding():
@@ -176,11 +202,20 @@ def test_ssd_scan_carries_a_fast_decay_and_a_slow_one():
     x, dt, A, B, C, D = _scan_inputs(1, 48, h=2)
     dt = dt.at[..., 0].set(20.0).at[..., 1].set(1e-4)
     A = jnp.array([-16.0, -1.0])
+    weight = jax.random.normal(jax.random.PRNGKey(9), x.shape, jnp.float32)
     with jax.default_matmul_precision("highest"):
         got = ssd_scan(x, dt, A, B, C, D, chunk=16)
         want = _by_position(x, dt, A, B, C, D)
+        grads = _cotangents(lambda *a: ssd_scan(*a, chunk=16), (x, dt, A, B, C, D), weight)
+        wants = _cotangents(_by_position, (x, dt, A, B, C, D), weight)
     assert bool(jnp.all(jnp.isfinite(got)))
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    # the backward builds the same factors again: each in (0, 1], and a head
+    # that forgets at once has the cotangents of one that sees its own position
+    for name, a, b in zip("x dt A B C D".split(), grads, wants):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        np.testing.assert_allclose(
+            a, b, rtol=0, atol=1e-5 * float(jnp.max(jnp.abs(b))), err_msg=name)
 
 
 # ---------------------------------------------------------------------------
@@ -441,10 +476,17 @@ def test_a_wrong_term_is_caught(wrong, monkeypatch):
     )
 
 
-def test_bf16_running_sums_in_the_scan_are_seen():
+def test_bf16_running_sums_in_the_scan_are_seen(monkeypatch):
     """The decays' running sums rounded to bf16 (what ``controls_granite``
     plants on the chip): far outside float32's agreement with the
-    recurrence, at steps and rates as the mixer draws them."""
+    recurrence, at steps and rates as the mixer draws them. The plant itself -
+    ``ssd._EXACT`` at the DEFAULT precision while the loss is traced, put
+    back before its gradient is - reaches BOTH passes: the output and the
+    cotangents change, and the backward's sums are the forward's (a backward
+    that read the attribute when IT is traced would build other decays than
+    the forward used)."""
+    from torchft_tpu.ops import ssd
+
     x, dt, A, B, C, D = _scan_inputs(1, 64, seed=2)
     with jax.default_matmul_precision("highest"):
         want = _by_position(x, dt, A, B, C, D)
@@ -455,6 +497,41 @@ def test_bf16_running_sums_in_the_scan_are_seen():
     scale = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(jnp.abs(sound - want))) < 1e-5 * scale
     assert float(jnp.max(jnp.abs(rounded - want))) > 1e-3 * scale
+
+    # a CPU's DEFAULT product of float32s is float32's: plant what the TPU's
+    # does to the sums' terms, one bf16 pass, under a precision of that name
+    class Bf16Pass:
+        @staticmethod
+        def einsum(spec, *operands, precision=None, **how):
+            if precision == "one bf16 pass":
+                operands = [o.astype(jnp.bfloat16).astype(o.dtype) for o in operands]
+                precision = None
+            return jnp.einsum(spec, *operands, precision=precision, **how)
+
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+    monkeypatch.setattr(ssd, "jnp", Bf16Pass())
+    args, sq = (x, dt, A, B, C, D), lambda y: jnp.sum(y ** 2)  # noqa: E731
+
+    def planted(precision):
+        # as ``controls_granite.patched``: the attribute is back before the
+        # backward is traced
+        def loss(*a):
+            with monkeypatch.context() as m:
+                m.setattr(ssd, "_EXACT", precision)
+                y = ssd_scan(*a, chunk=16)
+                return sq(y), y
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(*args)
+
+    ((_, y), grads), ((_, wrong), wrong_grads) = planted(ssd._EXACT), planted("one bf16 pass")
+    assert float(jnp.max(jnp.abs(wrong - y))) > 1e-4 * scale  # the sound one: under 1e-5
+    for a, b in zip(wrong_grads, grads):
+        assert float(jnp.max(jnp.abs(a - b))) > 1e-4 * float(jnp.max(jnp.abs(b)))
+    both = jax.jit(jax.grad(
+        lambda *a: sq(ssd._scan(16, "one bf16 pass", *a)), argnums=(0, 1, 2)))(*args)
+    for a, b in zip(wrong_grads, both):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * float(jnp.max(jnp.abs(b))))
 
 
 # ---------------------------------------------------------------------------
@@ -497,12 +574,22 @@ def _residuals(loss, params):
     )
 
 
+def _chunks_of(cfg, chunk):
+    """``cfg`` with its scans in chunks of ``chunk``."""
+    return dataclasses.replace(cfg, layer_kinds=tuple(
+        k if k.mixer is None else dataclasses.replace(
+            k, mixer=dataclasses.replace(k.mixer, chunk=chunk))
+        for k in cfg.kinds
+    ))
+
+
 def _recomputed_case(batch=1, seq=32):
     """The recomputed tiny model with what the tests of its save policy
     count: (the configuration, its loss of the weights, the weights, the
     gradient's forward products, the shapes of the stream and of a SwiGLU's
-    hidden rows, the number of Mamba layers)."""
-    cfg = granite.tiny_granite_config(True)
+    hidden rows, the number of Mamba layers). Its scans run in chunks of 8,
+    so that a decay's (chunk, chunk) square is no state's (P, n)."""
+    cfg = _chunks_of(granite.tiny_granite_config(True), 8)
     tokens, params = _tokens(batch=batch, seq=seq + 1), _weights(BF16)
 
     def products(of=cfg):
@@ -541,7 +628,7 @@ def _forward_products(cfg, products, batch, seq):
     """Of ``_products``, the forward products ``x W`` a layer by what they
     are: the SwiGLU's gate and up (one shape), a Mamba mixer's ``wo``, its
     map to ``[z | xBC | dt]``, and the scan's (the decays' square pairs)."""
-    mixer = MAMBA.mixer
+    mixer = cfg.kinds[0].mixer
     wide = 2 * mixer.inner + 2 * mixer.state + mixer.inner_heads
     rows, last = (batch, seq), ((2,), (0,))
 
@@ -559,53 +646,76 @@ def _forward_products(cfg, products, batch, seq):
     }
 
 
+def _scan_kept(cfg, stream):
+    """The shapes of what a Mamba layer's scan keeps by name: its output (B,
+    S, H, P) and its chunks' starting states (B, S / chunk, H, P, n)."""
+    mixer, (batch, seq) = cfg.kinds[0].mixer, stream[:2]
+    heads = (mixer.inner_heads, mixer.inner_head_dim)
+    return (batch, seq, *heads), (batch, seq // mixer.chunk, *heads, mixer.state)
+
+
 def test_a_recomputed_layer_keeps_its_input_and_what_is_named(monkeypatch):
     """What the backward pass of the recomputed stack holds between the two
-    passes: per layer the layer's input and exactly the products
-    ``STACK_KEPT`` names - the mixer's output, (B, S, D), and the SwiGLU's
-    gate and up products, (B, S, ff) each - no decay matrix, nothing as wide
-    as the Mamba map's product, no residual of a flash kernel. And in the
-    gradient's jaxpr no gate, up or ``wo`` product is computed a second
-    time, while the Mamba map's and the scan's are as often as with nothing
-    kept."""
+    passes: per layer the layer's input and exactly what ``STACK_KEPT``
+    names - the mixer's output, (B, S, D), the SwiGLU's gate and up
+    products, (B, S, ff) each, and a Mamba layer's scan's output and starting
+    states - no decay matrix, nothing as wide as the Mamba map's product, no
+    residual of a flash kernel. And in the gradient's jaxpr no gate, up or
+    ``wo`` product is computed a second time and the scan's forward products
+    run ONCE a Mamba layer (its count is the unrecomputed stack's), while the
+    Mamba map's is computed as often as with nothing kept."""
     cfg, loss, params, products, stream, hidden, mambas = _recomputed_case()
-    assert olmoe.STACK_KEPT == ("mixer_out", "mlp_gate", "mlp_up")  # no ``flash_out``, ``flash_lse``
+    # no ``flash_out``, ``flash_lse``
+    assert olmoe.STACK_KEPT == ("mixer_out", "mlp_gate", "mlp_up", "ssd_y", "ssd_states")
+    scanned, states = _scan_kept(cfg, stream)
 
     kept, made = _residuals(loss, params), products()
     inner_wide = [s for s in kept if s and s[-1] > max(cfg.d_model, cfg.vocab_size)]
-    square = [s for s in kept if len(s) >= 2 and s[-1] == s[-2] == MAMBA.mixer.chunk]
+    square = [s for s in kept if len(s) >= 2 and s[-1] == s[-2] == cfg.kinds[0].mixer.chunk]
     assert not inner_wide and not square, kept
     assert kept[hidden] == 2 * cfg.n_layers and kept[stream] >= 2 * cfg.n_layers
+    assert kept[scanned] == kept[states] == mambas
     assert made["gate_up"] == 2 * cfg.n_layers and made["wo"] == mambas
 
     # against the layer's input alone (a policy that names nothing): the
-    # named products and NOTHING else - no flash residual, no other shape
+    # named values and NOTHING else - no flash residual, no other shape
     monkeypatch.setattr(olmoe, "STACK_KEPT", ())
     bare, again = _residuals(loss, params), products()
-    assert kept - bare == {stream: cfg.n_layers, hidden: 2 * cfg.n_layers}
+    assert kept - bare == {
+        stream: cfg.n_layers, hidden: 2 * cfg.n_layers, scanned: mambas, states: mambas,
+    }
     assert not bare - kept and bare[stream] >= cfg.n_layers and not bare[hidden]
     assert again["gate_up"] == 4 * cfg.n_layers and again["wo"] == 2 * mambas
-    assert (made["map"], made["scan"]) == (again["map"], again["scan"])
-    whole = products(granite.tiny_granite_config(False))
-    assert made["map"] == 2 * mambas == 2 * whole["map"] and whole["scan"] < made["scan"]
+    assert made["map"] == again["map"] and made["scan"] < again["scan"]
+    whole = products(_chunks_of(granite.tiny_granite_config(False), 8))
+    assert made["map"] == 2 * mambas == 2 * whole["map"] and made["scan"] == whole["scan"]
 
 
-@pytest.mark.parametrize("name", ["mixer_out", "mlp_gate", "mlp_up"])
+@pytest.mark.parametrize("name", ["mixer_out", "mlp_gate", "mlp_up", "ssd_y", "ssd_states"])
 def test_a_name_out_of_the_tuple_is_computed_again(name, monkeypatch):
-    """Each name of ``STACK_KEPT`` is carried by a product and read by the
-    policy: taken out of the tuple, its product is no longer among the
-    residuals and is computed a second time; the other two stay."""
+    """Each name of ``STACK_KEPT`` is carried by a value and read by the
+    policy: taken out of the tuple, its value is no longer among the
+    residuals and is computed a second time (out of the tuple, a scan's
+    output or states bring the scan's forward products back into the
+    recomputed pass); the others stay."""
     cfg, loss, params, products, stream, hidden, mambas = _recomputed_case()
     assert name in olmoe.STACK_KEPT
-    kept = _residuals(loss, params)
+    scanned, states = _scan_kept(cfg, stream)
+    kept, once = _residuals(loss, params), products()
     monkeypatch.setattr(olmoe, "STACK_KEPT", tuple(n for n in olmoe.STACK_KEPT if n != name))
     gone, made = kept - _residuals(loss, params), products()
     if name == "mixer_out":
         assert gone == {stream: cfg.n_layers}
         assert (made["wo"], made["gate_up"]) == (2 * mambas, 2 * cfg.n_layers)
-    else:
+    elif name.startswith("mlp"):
         assert gone == {hidden: cfg.n_layers}
         assert (made["wo"], made["gate_up"]) == (mambas, 3 * cfg.n_layers)
+    else:
+        assert gone == {scanned if name == "ssd_y" else states: mambas}
+        assert made["scan"] > once["scan"]
+        assert (made["wo"], made["gate_up"]) == (mambas, 2 * cfg.n_layers)
+    if not name.startswith("ssd"):
+        assert made["scan"] == once["scan"]
 
 
 def test_a_looped_model_does_not_take_the_new_fields():
@@ -623,9 +733,11 @@ def test_a_looped_model_does_not_take_the_new_fields():
 def test_every_operation_of_the_gradient_step_is_under_a_scope():
     """The compiled gradient of the recomputed model: no operation without a
     scope; the scan's ``while`` body under ``attn/mamba/scan`` in the forward
-    class and in the backward class, the recomputed forward under
-    ``rematted_computation``; the six scopes of the mixer and the attention
-    layer's kind all there."""
+    class and in the backward class (the op's own backward, a ``custom_vjp``'s,
+    keeps the scope it was called under), the recomputed forward under
+    ``rematted_computation`` WITHOUT the scan, whose output and states are
+    kept by name; the six scopes of the mixer and the attention layer's kind
+    all there."""
     cfg = granite.tiny_granite_config(True)
     params = jax.tree_util.tree_map(lambda l: l.astype(jnp.bfloat16), _weights(cfg))
     tokens = _tokens()
@@ -636,7 +748,9 @@ def test_every_operation_of_the_gradient_step_is_under_a_scope():
     assert not [n for n in named if not spans.scope_path(n)]
     both = {which for which, path in paths if "/attn/mamba/scan/" in f"/{path}/"}
     assert {"forward", "backward"} <= both
-    assert [p for w, p in paths if "rematted_computation" in p and "attn/mamba/scan" in p]
+    again = [p for _, p in paths if "rematted_computation" in p]
+    assert [p for p in again if "attn/mamba/proj" in p]
+    assert not [p for p in again if "attn/mamba/scan" in p]
     every = {path for _, path in paths}
     for scope in ("proj", "conv", "gates", "scan", "norm", "out"):
         assert [p for p in every if f"/attn/mamba/{scope}/" in f"/{p}/"], scope

@@ -1534,14 +1534,14 @@ def _stack(
     routers' sums over the layers that have experts; None where none has).
     Under ``cfg.recompute_layers`` each layer is under ``jax.checkpoint``
     with a save policy by name, as ``_looped``'s pass is: the backward pass
-    keeps a layer's input and what ``STACK_KEPT`` names (the mixer's output
-    and the SwiGLU's gate and up products, in ``cfg.dtype``, as the forward
-    computed them) and computes the rest of the layer again when it comes
-    to it, its Mosaic calls too - the memory is one layer's activations and
-    ``n_layers`` times the input and the kept products. The recomputed
-    stack is scoped ``layers``. A layer that is one sublayer
-    (``cfg.sublayers``) is one checkpoint like any other; an ungated
-    feed-forward has no ``mlp_gate`` to keep."""
+    keeps a layer's input and what ``STACK_KEPT`` names (the mixer's output,
+    the SwiGLU's gate and up products and a state-space scan's output and
+    starting states, in ``cfg.dtype``, as the forward computed them) and
+    computes the rest of the layer again when it comes to it, its Mosaic
+    calls too - the memory is one layer's activations and ``n_layers`` times
+    the input and the kept values. The recomputed stack is scoped ``layers``.
+    A layer that is one sublayer (``cfg.sublayers``) is one checkpoint like
+    any other; an ungated feed-forward has no ``mlp_gate`` to keep."""
     total = None
     # a checkpoint's operations are named by the scope AROUND it (``transpose(
     # jvp(layers))/checkpoint/rematted_computation/attn/..``): under none, a
@@ -1644,39 +1644,39 @@ KEPT = ("mlp_down",)
 # for a layer's backward pass beside the layer's input, as ``KEPT`` is for a
 # looped pass: the values under these ``checkpoint_name``s, in ``cfg.dtype``
 # as the forward computed them; every other operation of the layer is
-# computed again. One configuration sets ``recompute_layers``
+# computed again. Two configurations set ``recompute_layers``
 # (``granite4-h-micro-l10-v8``: 4,096 positions at d 2048, f 8192, nine
-# Mamba-2 layers and one attention layer), so the tuple is the program's;
-# when a second does, it becomes data of the configuration. Measured there
-# on a v5e (PERF.md section 6, PR 59): ms a step that leave the recomputed
-# pass, for the GB the TPU compiler's memory analysis of the gradient step
-# grows by (13.37 GB with nothing kept, 14.64 with the three names; the
-# configuration was admitted under 15.5; since PR 60 a second configuration
-# sets it, ``nemotron3-nano-l9-ep16``, whose gate holds with the same names -
-# its ``mixer_out`` and its shared experts' ``mlp_up`` are 0.46 GB of its 2.99
-# GB of temporaries - and which was not tuned: the tuple stays the program's):
+# Mamba-2 layers and one attention layer, where the tuple was tuned; since PR
+# 60 ``nemotron3-nano-l9-ep16``, which was not tuned: the tuple stays the
+# program's until it is data of the configuration, ROADMAP D22). Measured in
+# the first on a v5e (PERF.md section 6, PRs 59 and 61): ms a step that leave
+# the recomputed pass, for the GB the TPU compiler's memory analysis of the
+# gradient step grows by. The gate is the configurations' own, 15.5 GB of
+# state, two gradient trees and ``temp_size_in_bytes``: 13.37 GB with nothing
+# kept, 14.64 with the first three names, 14.95 with the five (temporaries
+# 2.59 GB, ``peak_memory_in_bytes`` 7.00 GB; the second configuration 13.66 ->
+# 13.35, temporaries 2.69 GB, peak 6.62 GB: its decays left the temporaries):
 #   the mixer's output (``mixer_out``: ``wo``'s product)      4.0 ms / 0.02 GB
 #   the SwiGLU's gate and up products (``mlp_gate``,
 #   ``mlp_up``)                                              14.4 ms / 1.25 GB = 11.5
+#   the scan's output and its chunks' starting states
+#   (``ssd_y`` 0.30 GB, ``ssd_states`` 0.15 GB; ``ops/ssd.py``) 8.2 ms / 0.31 GB = 26
+#     the scan's forward 5.72 and 2.52 of the gated norm's 3.22, which reads
+#     the kept output; a row only since the scan has a backward of its own
+#     (PR 61), which also took the decays' 0.27 GB a layer out of the
+#     temporaries: the analysis grew by 0.31 GB for 0.45 GB kept
 #   NOT named: the Mamba map's product ``[z | xBC | dt]``     7.0 ms / 0.56 GB = 12.5
-#     (4.6 ms of the step) when named AFTER its split; named whole, its
-#     parts are no longer read inside their consumers' fusions and the
-#     forward pass pays 4.7 ms for it (0.3 ms of the step). Both gates hold
-#     with it (15.20 GB); it edits the mixer and moves the scan's own time
-#     by 2%, so it is a PR of its own (ROADMAP S16)
-#   NOT named: the scan's output - under autodiff the backward reads the
-#     scan's INNER residuals, so its forward runs again whatever is kept
-#     (a row once the scan has a backward of its own, ROADMAP S16)
+#     (4.6 ms of the step, PR 59's tree) when named AFTER its split; named
+#     whole, its parts are no longer read inside their consumers' fusions and
+#     the forward pays 4.7 ms for it. By this count it would read 15.51 GB,
+#     AT the gate: read it first. It edits the mixer: a PR of its own (S16)
 # Nothing in the layer's backward reads the mixer's output's VALUE (it meets
 # a constant and the residual's add): kept, it feeds the SwiGLU's half of
-# the recomputed pass without the mixer's last product. With the three
-# names 18.98 of the 38.70 ms of recomputation leave (left: the map 6.87,
-# the scan 5.72, the norm 3.22, the convolution 1.61, the attention layer
-# 1.87, the SiLU's product of the kept two 0.40) and the forward pass costs
-# what it cost: XLA wrote the two products before, too. The flash kernel's
-# ``flash_out`` and ``flash_lse`` stay out: kept, the step's Mosaic calls
-# are 2 where the benchmark's family states 3 (ROADMAP W14(a)).
-STACK_KEPT = ("mixer_out", "mlp_gate", "mlp_up")
+# the recomputed pass without the mixer's last product. Left of the 38.70 ms
+# of recomputation, 11.96: the map 6.98, the convolution 1.98, the attention
+# layer 1.87, the norm 0.70, the SiLU's product 0.40. ``flash_out``, ``flash_lse``
+# stay out: kept, the step's Mosaic calls are 2, the family states 3 (W14(a)).
+STACK_KEPT = ("mixer_out", "mlp_gate", "mlp_up", "ssd_y", "ssd_states")
 
 
 def _looped(
